@@ -48,6 +48,8 @@ test-isolated: native
 	  python -m pytest "$$f" -q || fail=1; \
 	done; exit $$fail
 
+# On the chip only (no accelerator: it refuses to measure). The quickest
+# on-chip proof is `python chip_smoke.py`.
 bench: native
 	python bench.py
 

@@ -49,7 +49,7 @@ RULES = {
         Rule(
             "PICO-J003",
             "pl.program_id read inside a loop body",
-            "the jax 0.4.37 Pallas interpreter cannot resolve pl.program_id "
+            "the Pallas interpreter (jax 0.9.0 too) cannot lower pl.program_id "
             "inside a fori_loop/while_loop/scan body's sub-jaxpr; read grid "
             "ids once, outside the loop (the decode_attention.py incident)",
         ),

@@ -23,7 +23,7 @@ outside jit).  From each entry the intra-project call graph is walked
   compiled program.
 - **PICO-J003** — ``pl.program_id`` (or any ``*.program_id``) read inside
   a function passed as a ``fori_loop``/``while_loop``/``scan`` body: the
-  0.4.37 Pallas interpreter cannot resolve it in the sub-jaxpr (see
+  Pallas interpreter (jax 0.9.0 too) cannot lower it in the sub-jaxpr (see
   ``ops/pallas/decode_attention.py``).  Scanned everywhere, traced or
   not — the trap fires at kernel runtime.
 - **PICO-J004** — ``jax.jit``/``jax.pmap``/``pl.pallas_call`` evaluated
@@ -609,7 +609,7 @@ def _check_program_id(project: Project, mod: ModuleInfo,
                     context=enclosing_qualname(mod, node),
                     snippet=mod.snippet(node.lineno),
                     message=f"pl.program_id read inside a {wrapper} body: "
-                            f"the 0.4.37 Pallas interpreter cannot resolve "
+                            f"the Pallas interpreter cannot lower "
                             f"it in the sub-jaxpr — read grid ids once, "
                             f"before the loop (docs/ANALYSIS.md#pico-j003)"))
 

@@ -1,41 +1,16 @@
-"""The one-line JSON bench-record contract, shared by its producers and
-consumers.
+"""Names of the one-line JSON records the bench scripts print.
 
-The benches (`bench.py`, `bench_7b.py`) print exactly one line starting
-``{"metric"`` per run — a real capture, a stale in-round republish
-(``stale_from`` present), or a diagnosed null (``value: null``, optionally
-``code_failure: true``). The tunnel watcher and the orchestrator's
-stale-capture fallback both need to FIND and CLASSIFY those lines in step
-logs, and the watcher cannot import ``bench`` itself (it stays
-import-light: ``bench`` touches jax at module top). This module is the
-single home for the metric names and the line scan so a rename or framing
-change cannot silently desynchronize a consumer.
+The benches (`bench.py`, `bench_7b.py`, `bench_decode.py`) print exactly
+one line starting ``{"metric"`` per run, naming the device it was measured
+on. The metric names live here so a rename cannot desynchronize the three
+scripts (each touches jax at module top; this module does not).
 """
 
 from __future__ import annotations
 
-import json
-
-# the ON-TPU metric each bench script publishes, keyed by the agenda step
-# name (chip_agenda.STEP_TIMEOUTS) that runs it
+# the ON-TPU metric each bench script publishes, keyed by script name
 BENCH_METRICS = {
     "bench": "smollm_1.7b_mfu_1chip",
     "bench_7b": "llama2_7b_proxy_mfu_1chip",
     "bench_decode": "smollm_1.7b_decode_toks_s_chip",
 }
-
-
-def iter_metric_records(log_path: str):
-    """Yield every one-line JSON metric record in a step log. Missing or
-    unreadable logs yield nothing."""
-    try:
-        with open(log_path, errors="replace") as f:
-            for line in f:
-                if not line.startswith('{"metric"'):
-                    continue
-                try:
-                    yield json.loads(line)
-                except ValueError:
-                    continue
-    except OSError:
-        return

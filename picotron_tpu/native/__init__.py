@@ -1,15 +1,19 @@
 """ctypes bindings for the native (C++) data-loader kernels.
 
-Loads ``_build/libpicotron_data.so``, building it with g++ on first import if
-missing (cached afterwards). Every binding has a numpy fallback in
-``picotron_tpu.data`` producing bitwise-identical results, so the framework
-runs unchanged where a toolchain is unavailable; set
+Loads ``_build/libpicotron_data.<source hash>.so``, building it with g++ when
+no library for THIS ``dataloader.cc`` exists yet (``_build/`` is not
+committed, and a copied tree keeps no meaningful mtimes, so the library is
+keyed by the source's content: a stale one is never loaded). Every binding
+has a numpy fallback in ``picotron_tpu.data`` producing bitwise-identical
+results, so the framework runs unchanged where a toolchain is unavailable;
+``loader()`` says which of the two is in use; set
 ``PICOTRON_DISABLE_NATIVE=1`` to force the fallback.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import Optional
@@ -18,7 +22,15 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "dataloader.cc")
-_SO = os.path.join(_DIR, "_build", "libpicotron_data.so")
+
+
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha1(f.read()).hexdigest()[:12]
+    return os.path.join(_DIR, "_build", f"libpicotron_data.{digest}.so")
+
+
+_SO = _so_path()
 
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
@@ -63,10 +75,8 @@ def get_lib() -> Optional[ctypes.CDLL]:
     _load_attempted = True
     if os.environ.get("PICOTRON_DISABLE_NATIVE") == "1":
         return None
-    if not os.path.exists(_SO) or (
-            os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-        if not _build():
-            return None
+    if not os.path.exists(_SO) and not _build():
+        return None
     try:
         _lib = _declare(ctypes.CDLL(_SO))
     except OSError:
@@ -76,6 +86,11 @@ def get_lib() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return get_lib() is not None
+
+
+def loader() -> str:
+    """Which implementation serves the data-loader kernels in this process."""
+    return "native (g++)" if available() else "numpy fallback"
 
 
 def affine_chain(toks: np.ndarray, jumps: np.ndarray, jump_vals: np.ndarray,

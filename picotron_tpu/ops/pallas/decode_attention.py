@@ -8,97 +8,75 @@ the live sequences are, and whose int8 mode first materializes a
 dequantized fp32 copy of the entire block (4x the bytes the cache stores).
 This kernel removes both costs:
 
-- **Length-aware**: the grid is ``(slots, kv_heads, q_blocks)`` and each
-  instance walks KV blocks with a ``fori_loop`` bounded by
-  ``ceil(visible / block_t)`` — its OWN slot's live token count clipped to
-  the highest key its query rows can see (the same causal block-skip the
-  training flash kernel uses, shared via
-  ``flash_attention.causal_kv_blocks``) — so HBM reads track parked
-  tokens, not the cache window. Keys inside the last partial block are
-  masked per query row against the slot's ``lengths`` (the stale rows a
-  speculative rollback or a freed slot leaves beyond the length pointer
-  are never visible). Nothing beyond ``ceil(lengths[b]/block_t)*block_t``
-  rows is ever DMA'd.
-- **int8 dequant in registers**: K/V stay int8 on the wire — each block is
-  DMA'd from HBM in its storage dtype together with its per-row fp32
-  scales (``[block_t]`` vectors) and dequantized in VMEM right before the
-  matmul, so the quantized cache's ~2x byte saving reaches the attend
-  itself, not just storage.
-- **GQA native**: queries fold to ``[B, Hkv, S*g, D]`` (``g = Hq/Hkv``
-  grouped rows per compact kv head — the same trick the training flash
-  kernel's folded layout uses) and each grid instance serves one kv head's
-  whole query group; the cache stays compact, nothing is repeated.
+- **Length-aware**: the grid is ``(slots, q_blocks, kv_blocks)`` and the
+  K/V BlockSpec index maps read the slot's ``lengths`` entry (a
+  scalar-prefetch operand in SMEM): grid step ``j`` fetches KV block
+  ``min(j, nb - 1)`` where ``nb = ceil(visible / block_t)`` is the slot's
+  OWN live block count clipped to the highest key its query rows can see
+  (the causal block-skip of the training flash kernel, shared via
+  ``flash_attention.causal_kv_blocks``). A repeated block index is not
+  fetched again by the Pallas pipeline and steps ``j >= nb`` skip their
+  compute, so HBM reads track parked tokens, not the cache window (an
+  empty slot still costs its block 0: the pipeline always fetches the
+  first block of a grid row). Keys inside the last partial block are
+  masked per query row against ``lengths`` (the stale rows a speculative
+  rollback or a freed slot leaves beyond the length pointer are never
+  visible).
+- **int8 dequant on the score tile**: K/V stay int8 on the wire — each
+  block arrives in its storage dtype together with its per-row fp32
+  scales (``[block_t, Hkv]``) and the scales multiply the
+  ``[Hkv, rows, block_t]`` scores / probabilities (``q·(s·k) == s·(q·k)``
+  per key row), so the quantized cache's ~2x byte saving reaches the
+  attend itself and no dequantized K/V block ever exists.
+- **Every kv head per grid instance**: a KV block is the whole
+  ``[block_t, Hkv, D]`` slab. The ``(Hkv, D)`` plane is the tiled minor
+  plane of the cache in HBM and the TPU compiler refuses a one-head slice
+  of it, so heads are a batch dimension of the two contractions instead
+  of a grid axis; queries fold to ``[B, Hkv, S*g, D]`` (``g = Hq/Hkv``
+  grouped rows per compact kv head) and the cache stays compact, nothing
+  is repeated.
 - **S >= 1 queries per slot**: query row ``r`` sits at global position
   ``pos_q = lengths[b] - S + r // g`` (key ``t`` visible iff
   ``t <= pos_q``) — the exact masking convention of the dense kernel — so
   ONE kernel serves all three call sites: blocked decode (S = 1),
   speculative verify (S = spec_len + 1, B = slots), and chunked prefill
   (B = 1, S = chunk width).
-- **Blocked queries for chunked prefill**: wide query groups (S*g beyond
-  ``block_q`` folded rows — the chunked-prefill shape) split over the
-  third grid axis instead of shrinking the KV block to fit one giant
-  score tile: each q-block keeps a deep ``block_t``, walks only the KV
-  blocks its own causal band can see, and the q-blocks parallelize
-  across the grid — ``flash_attention.py``'s block machinery applied to
-  the cache-prefix+chunk window. Decode/verify shapes (a handful of
-  rows) fold to a single q-block, exactly the old layout.
+- **Blocked queries for chunked prefill**: wide query groups split over
+  the second grid axis; each q-block walks only the KV blocks its own
+  causal band can see.
 
 Softmax is the standard online (flash) recurrence in fp32: running max
-``m``, normalizer ``l``, and accumulator ``acc`` per query row, masked
-probabilities zeroed exactly so a fully-masked row (``lengths == 0`` — a
-fresh slot attended directly) comes out as **zeros**, a defined value,
-where the dense kernel emits an (equally unconsumed) uniform average.
-Every other row is allclose to the dense path for bf16/fp32 AND int8
-caches (tests/test_decode_kernel.py pins all three call shapes in
-interpret mode).
+``m``, normalizer ``l``, and accumulator ``acc`` per query row live in
+VMEM scratch across the kv grid axis, masked probabilities zeroed exactly
+so a fully-masked row (``lengths == 0`` — a fresh slot attended directly)
+comes out as **zeros**, a defined value, where the dense kernel emits an
+(equally unconsumed) uniform average. Every other row is allclose to the
+dense path for bf16/fp32 AND int8 caches (tests/test_decode_kernel.py
+pins all three call shapes in interpret mode; tests/test_chip_compile.py
+compiles every layout for a v5e at SmolLM widths; chip_smoke.py runs it
+compiled against the dense oracle).
 
-Hardware notes: K/V (+ scales) are handed to the kernel in ``pl.ANY``
-memory space (they stay in HBM) and each block is pulled with
-``pltpu.make_async_copy`` into VMEM scratch; query rows pad to a multiple
-of 8 sublanes. Block fetches are **double-buffered** (``pipeline=True``,
-the default): two VMEM scratch buffers per operand and iteration ``j``
-prefetches block ``j+1`` into the idle buffer before waiting on its own,
-so the next block's DMA commits while the current block's dots run — the
-async-send/compute overlap the reference survey credits for its MFU
-(SURVEY §5.7). ``pipeline=False`` keeps the serial fetch (one buffer,
-start-wait-compute per block) as the bitwise-identical reference the
-parity suite pins the pipelined path against. On CPU the kernel runs in
+Block fetches are the Pallas pipeline's own double-buffered DMA: there is
+no hand-written copy or semaphore in this file. On CPU the kernel runs in
 Pallas interpret mode (``interpret=True``), which is how the parity suite
 and the tier-1 gate exercise it. Dense remains the serving default
-(``inference.attend_impl``) until the kernel is A/B'd on a chip, the same
-staging discipline the ``bshd`` flash layout went through.
+(``inference.attend_impl``) until the kernel is A/B'd on a chip.
 
-``block_tables`` switches to the PAGED layout (one DMA per pool page);
+``block_tables`` switches to the PAGED layout (one block per pool page,
+the page id read from the block table in the index map);
 ``block_quant`` additionally enables the **mixed-precision page read**
 (``inference.kv_page_policy: "hot_bf16"`` — inference/paged_kv.py): each
 page carries a per-page flag choosing which of the two pool
-representations to DMA — the full-precision leaves for hot (radix-shared)
-prefix pages, the int8+scales leaves for cold unique tails — so shared
-prefixes keep full precision while the long tail moves ~half the bytes.
+representations to fetch — the full-precision leaves for hot
+(radix-shared) prefix pages, the int8+scales leaves for cold unique
+tails. The representation a page does NOT use maps to pool page 0, so a
+run of same-kind pages costs the other pool one fetch, not one per page.
 
-**The program_id trap (picolint rule PICO-J003).** ``pl.program_id`` must
-be read ONCE, outside the ``fori_loop`` body: the jax 0.4.37 Pallas
-interpreter cannot resolve grid ids inside a loop body's sub-jaxpr, so a
-kernel that reads ``pl.program_id`` under ``fori_loop``/``while_loop``
-traces fine on TPU but fails (or silently misindexes) on the interpret
-path every CPU test runs. This kernel hit exactly that during PR 5 — the
-fix is the ``b``/``h``/``qi`` reads at the top of
-``_flash_decode_kernel``, before ``body`` closes over them.
-
-**The two-buffer semaphore discipline (picolint rule PICO-J005).** With
-double buffering, iteration ``j`` owns buffer slot ``j % 2`` and its
-semaphore column ``sems[j % 2, :]``; the prefetch of block ``j+1``
-targets the OTHER slot, so the only write-after-read hazard (re-filling a
-buffer the current iteration still reads) is structurally impossible —
-the body runs sequentially and the j+2 prefetch happens one full
-iteration after slot ``j % 2``'s compute finished. Every ``start()`` has
-a matching ``wait()`` built from the same (source, destination,
-semaphore) triple — in the mixed-page mode both live under the SAME
-``pl.when`` predicate, so a wait can never block on a copy that was
-never started. A ``make_async_copy`` whose wait is missing (or sits off
-some fori_loop path its start runs on) is now flagged mechanically as
-PICO-J005 (picotron_tpu/analysis/jax_rules.py; catalog:
-docs/ANALYSIS.md#pico-j005), like the program_id trap before it.
+**The program_id rule (picolint PICO-J003).** ``pl.program_id`` is read
+ONCE at the top of the kernel, never inside a loop or ``pl.when`` body:
+the Pallas interpreter every CPU test runs (jax 0.9.0 too) has no lowering
+for it inside a ``fori_loop`` body's sub-jaxpr, so the kernel would
+compile for the chip and fail on the interpret path.
 """
 
 from __future__ import annotations
@@ -117,35 +95,43 @@ from picotron_tpu.ops.pallas.flash_attention import (
     causal_kv_blocks,
 )
 
-# KV rows fetched per DMA; halved automatically until the block divides the
-# cache window AND the [block_q, block_t] fp32 score tile stays under
-# _MAX_SCORE_TILE elements (see _pick_block_t).
+# KV rows per block; halved automatically until the block divides the
+# cache window, the [Hkv*block_q, block_t] fp32 score tile stays under
+# _MAX_SCORE_TILE elements, and one [block_t, Hkv, D] slab stays under
+# _MAX_KV_SLAB bytes (see _pick_block_t / _kv_block_cap).
 DEFAULT_BLOCK_T = 256
-# Folded query rows (S*g) per grid instance. Decode/verify shapes (S*g <= 8)
-# fold into one block; chunked-prefill windows wider than this split over
-# the q grid axis instead of shrinking block_t — the flash_attention.py
-# blocking applied to the decode kernel.
+# Folded query rows (S*g) per grid instance, summed over kv heads.
+# Decode/verify shapes fold into one block; chunked-prefill windows wider
+# than this split over the q grid axis instead of shrinking block_t.
 DEFAULT_BLOCK_Q = 256
 # score-tile budget: 256K fp32 elements = 1 MB, the same tile scale the
-# training flash kernel's 512x512 default occupies — decode shapes
-# (S*g <= 8 rows) keep the full DEFAULT_BLOCK_T, wide chunked-prefill query
-# groups (S*g in the thousands) first split over the q grid axis and only
-# then trade KV-block depth for row count, so VMEM never blows up with the
-# chunk width
+# training flash kernel's 512x512 default occupies
 _MAX_SCORE_TILE = 256 * 1024
 _SUBLANE = 8  # fp32 sublane quantum the padded query-row count respects
+# one K or V slab in VMEM (all kv heads of one KV block); x2 operands x2
+# pipeline buffers stays a quarter of the 16 MB scoped VMEM
+_MAX_KV_SLAB = 1024 * 1024
 
 
 def _pick_block_t(seq: int, want: int, rows: int = _SUBLANE) -> int:
     """KV block size: at or under ``want``, shrunk (a) so the
     ``[rows, block]`` fp32 score tile fits the VMEM budget and (b) by
     halving until it divides ``seq`` (flash_attention._pick_block — the
-    DMA slice size must be static, so the block must tile the cache window
-    exactly; this is what keeps windows that are NOT a multiple of the
-    preferred block correct instead of reading past the buffer)."""
+    block must tile the cache window exactly; this is what keeps windows
+    that are NOT a multiple of the preferred block correct instead of
+    reading past the buffer)."""
     while want > _SUBLANE and rows * want > _MAX_SCORE_TILE:
         want //= 2
     return _pick_block(seq, want)
+
+
+def _kv_block_cap(want: int, nkv: int, d: int, itemsize: int) -> int:
+    """Cap the KV block so one ``[block, Hkv, D]`` slab (D padded to the
+    128-lane tile in VMEM) stays within _MAX_KV_SLAB bytes."""
+    row = nkv * max(d, 128) * itemsize
+    while want > _SUBLANE and want * row > _MAX_KV_SLAB:
+        want //= 2
+    return want
 
 
 def _pick_block_q(sgp: int, want: int, block_t: int) -> int:
@@ -159,21 +145,30 @@ def _pick_block_q(sgp: int, want: int, block_t: int) -> int:
     return rq
 
 
-def _flash_decode_kernel(*refs, scale, block_t, S, g, rq, quantized, mixed,
-                         paged, pipeline):
-    """One (slot, kv head, q-block) grid instance: ``rq`` folded query
-    rows of slot ``b`` under kv head ``h`` against the slot's visible KV
-    blocks. ``paged`` mode walks the slot's block-table row instead of
-    contiguous blocks: iteration ``j`` DMAs pool page ``bt[b, j]`` (K/V
-    are the global ``[num_pages, page_len, Hkv, D]`` pool,
-    ``block_t == page_len``) — the indirection lives entirely in the DMA
-    source address, the online-softmax math is unchanged. ``mixed`` adds
-    the per-page dtype flag (``qt[b, j]``) choosing which pool
-    representation iteration ``j`` fetches. ``pipeline`` double-buffers
-    the fetches (see the module docstring's semaphore discipline)."""
+def _visible_blocks(L, qi, *, S, g, rq, block_t, max_nb):
+    """KV blocks q-tile ``qi`` of a slot with ``L`` live tokens walks.
+    Clipped twice: (a) to the highest key the tile's causal band can see
+    (early chunked-prefill q-blocks never walk the whole window), and (b)
+    to the window's block count: at the window edge the engine's
+    write-then-attend convention can pass lengths = pos + S > T (the
+    scatter dropped the out-of-bounds rows), and the walk must not read
+    past the cache. Shared by the index maps and the kernel body, so the
+    block a step fetches is the block it computes on."""
+    hi = jnp.clip(L - S + (qi * rq + rq - 1) // g, -1, L - 1)
+    return jnp.maximum(causal_kv_blocks(max_nb, hi, block_t), 0)
+
+
+def _flash_decode_kernel(*refs, scale, block_t, S, g, rq, max_nb, quantized,
+                         mixed, paged):
+    """One (slot, q-block, kv-block) grid step: ``rq`` folded query rows
+    of slot ``b`` under EVERY kv head against KV block ``j`` of the slot's
+    walk — already steered to the right cache rows (or pool page, or pool
+    representation) by the scalar-prefetch index maps. The online-softmax
+    state rides in VMEM scratch across the ``j`` axis."""
     refs = list(refs)
     len_ref = refs.pop(0)
-    bt_ref = refs.pop(0) if paged else None
+    if paged:
+        refs.pop(0)  # block tables: consumed by the index maps
     qt_ref = refs.pop(0) if mixed else None
     q_ref = refs.pop(0)
     k_ref = refs.pop(0)
@@ -183,194 +178,68 @@ def _flash_decode_kernel(*refs, scale, block_t, S, g, rq, quantized, mixed,
     scaled = quantized or mixed
     ks_ref = refs.pop(0) if scaled else None
     vs_ref = refs.pop(0) if scaled else None
-    o_ref = refs.pop(0)
-    kbuf, vbuf = refs.pop(0), refs.pop(0)
-    kqbuf = refs.pop(0) if mixed else None
-    vqbuf = refs.pop(0) if mixed else None
-    ksbuf = refs.pop(0) if scaled else None
-    vsbuf = refs.pop(0) if scaled else None
-    sems = refs.pop(0)
-    # program ids are read ONCE here: the 0.4.37 interpreter cannot resolve
-    # pl.program_id inside the fori_loop body's sub-jaxpr (enforced as
-    # picolint PICO-J003 — see the module docstring)
+    o_ref, acc_ref, m_ref, l_ref = refs
+    # program ids are read ONCE here, outside every pl.when body (picolint
+    # PICO-J003 — see the module docstring)
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    qi = pl.program_id(2)
-    L = len_ref[0]  # this slot's live token count
-    q = q_ref[0, 0].astype(jnp.float32)  # [rq, D]
-    r0 = qi * rq  # first folded query row of this tile
-    # query row r = s*g + g_idx sits at global position L - S + s
-    pos_q = (L - S
-             + (r0 + lax.broadcasted_iota(jnp.int32, (rq, block_t), 0)) // g)
-    kiota = lax.broadcasted_iota(jnp.int32, (rq, block_t), 1)
+    qi = pl.program_id(1)
+    j = pl.program_id(2)
+    L = len_ref[b]  # this slot's live token count
+    nb = _visible_blocks(L, qi, S=S, g=g, rq=rq, block_t=block_t,
+                         max_nb=max_nb)
+    isq = (qt_ref[b, jnp.minimum(j, max_nb - 1)] != 0) if mixed else None
 
-    def _srcs(j):
-        """Iteration j's DMA source slices (K, V, and the scale rows)."""
-        if paged:
-            pid = bt_ref[0, j]
-            return (lambda ref: ref.at[pid, :, h, :],
-                    lambda ref: ref.at[pid, :, h])
-        rows = pl.ds(j * block_t, block_t)
-        return (lambda ref: ref.at[b, rows, h, :],
-                lambda ref: ref.at[b, rows, h])
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
-    # start/wait pairs are built from the SAME (src, dst, sem) triples, so
-    # a wait always matches the copy its iteration/slot started — the
-    # PICO-J005 discipline. sems column layout: 0=K(+q), 1=V(+q),
-    # 2=k_scale, 3=v_scale.
-    if mixed:
-        def _flag(j):
-            return qt_ref[0, j] != 0
+    def _block(ref, qref, sref):
+        """This step's K or V slab as fp32 ``[block_t, Hkv, D]`` plus its
+        per-row scales as a ``[Hkv, 1, block_t]`` factor (None: unscaled)."""
+        if mixed:
+            blk = jnp.where(isq, qref[0].astype(jnp.float32),
+                            ref[0].astype(jnp.float32))
+            return blk, jnp.where(isq, sref[0].T[:, None, :], 1.0)
+        blk = ref[0].astype(jnp.float32)
+        return blk, (sref[0].T[:, None, :] if quantized else None)
 
-        def start(j, slot):
-            path, spath = _srcs(j)
-            isq = _flag(j)
-
-            @pl.when(isq)
-            def _():  # cold page: int8 bytes + per-row scales
-                pltpu.make_async_copy(path(kq_ref), kqbuf.at[slot],
-                                      sems.at[slot, 0]).start()
-                pltpu.make_async_copy(path(vq_ref), vqbuf.at[slot],
-                                      sems.at[slot, 1]).start()
-                pltpu.make_async_copy(spath(ks_ref), ksbuf.at[slot],
-                                      sems.at[slot, 2]).start()
-                pltpu.make_async_copy(spath(vs_ref), vsbuf.at[slot],
-                                      sems.at[slot, 3]).start()
-
-            @pl.when(~isq)
-            def _():  # hot page: the full-precision leaves
-                pltpu.make_async_copy(path(k_ref), kbuf.at[slot],
-                                      sems.at[slot, 0]).start()
-                pltpu.make_async_copy(path(v_ref), vbuf.at[slot],
-                                      sems.at[slot, 1]).start()
-
-        def wait_k(j, slot):
-            path, spath = _srcs(j)
-            isq = _flag(j)
-
-            @pl.when(isq)
-            def _():
-                pltpu.make_async_copy(path(kq_ref), kqbuf.at[slot],
-                                      sems.at[slot, 0]).wait()
-                pltpu.make_async_copy(spath(ks_ref), ksbuf.at[slot],
-                                      sems.at[slot, 2]).wait()
-
-            @pl.when(~isq)
-            def _():
-                pltpu.make_async_copy(path(k_ref), kbuf.at[slot],
-                                      sems.at[slot, 0]).wait()
-            deq = kqbuf[slot].astype(jnp.float32) * ksbuf[slot][:, None]
-            return jnp.where(isq, deq, kbuf[slot].astype(jnp.float32))
-
-        def wait_v(j, slot):
-            path, spath = _srcs(j)
-            isq = _flag(j)
-
-            @pl.when(isq)
-            def _():
-                pltpu.make_async_copy(path(vq_ref), vqbuf.at[slot],
-                                      sems.at[slot, 1]).wait()
-                pltpu.make_async_copy(spath(vs_ref), vsbuf.at[slot],
-                                      sems.at[slot, 3]).wait()
-
-            @pl.when(~isq)
-            def _():
-                pltpu.make_async_copy(path(v_ref), vbuf.at[slot],
-                                      sems.at[slot, 1]).wait()
-            deq = vqbuf[slot].astype(jnp.float32) * vsbuf[slot][:, None]
-            return jnp.where(isq, deq, vbuf[slot].astype(jnp.float32))
-    else:
-        def start(j, slot):
-            path, spath = _srcs(j)
-            pltpu.make_async_copy(path(k_ref), kbuf.at[slot],
-                                  sems.at[slot, 0]).start()
-            pltpu.make_async_copy(path(v_ref), vbuf.at[slot],
-                                  sems.at[slot, 1]).start()
-            if quantized:
-                pltpu.make_async_copy(spath(ks_ref), ksbuf.at[slot],
-                                      sems.at[slot, 2]).start()
-                pltpu.make_async_copy(spath(vs_ref), vsbuf.at[slot],
-                                      sems.at[slot, 3]).start()
-
-        def wait_k(j, slot):
-            path, spath = _srcs(j)
-            pltpu.make_async_copy(path(k_ref), kbuf.at[slot],
-                                  sems.at[slot, 0]).wait()
-            kb = kbuf[slot].astype(jnp.float32)
-            if quantized:
-                pltpu.make_async_copy(spath(ks_ref), ksbuf.at[slot],
-                                      sems.at[slot, 2]).wait()
-                kb = kb * ksbuf[slot][:, None]  # dequant in registers
-            return kb
-
-        def wait_v(j, slot):
-            path, spath = _srcs(j)
-            pltpu.make_async_copy(path(v_ref), vbuf.at[slot],
-                                  sems.at[slot, 1]).wait()
-            vb = vbuf[slot].astype(jnp.float32)
-            if quantized:
-                pltpu.make_async_copy(spath(vs_ref), vsbuf.at[slot],
-                                      sems.at[slot, 3]).wait()
-                vb = vb * vsbuf[slot][:, None]
-            return vb
-
-    # the whole point: the block walk is bounded by THIS slot's live
-    # length, never by max_seq_len — a fresh slot (L == 0) runs no
-    # iterations and costs no HBM reads at all. Clipped twice: (a) to the
-    # highest key this q-tile's causal band can see (the flash_attention
-    # block-skip — early chunked-prefill q-blocks never walk the whole
-    # window), and (b) to the window's block count: at the window edge the
-    # engine's write-then-attend convention can pass
-    # lengths = pos + S > T (the scatter dropped the out-of-bounds rows),
-    # and the walk must not DMA past the cache (the dense kernel's mask
-    # absorbs the same case for free). Paged mode clamps to the
-    # block-table width instead.
-    max_nb = bt_ref.shape[1] if paged else k_ref.shape[1] // block_t
-    hi = jnp.clip(L - S + (r0 + rq - 1) // g, -1, L - 1)  # last visible key
-    nb = jnp.maximum(causal_kv_blocks(max_nb, hi, block_t), 0)
-
-    def body(j, carry):
-        acc, m, l = carry
-        if pipeline:
-            slot = lax.rem(j, 2)
-
-            @pl.when(j + 1 < nb)
-            def _():  # commit block j+1 into the idle buffer NOW; the
-                # dots below overlap with its DMA (SURVEY §5.7's overlap)
-                start(j + 1, 1 - slot)
-        else:
-            slot = 0
-            start(j, slot)
-        kb = wait_k(j, slot)  # [bt, D] fp32
-        s = lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        mask = (j * block_t + kiota) <= pos_q
+    @pl.when(j < nb)
+    def _():
+        q = q_ref[0].astype(jnp.float32)  # [Hkv, rq, D]
+        # query row r = s*g + g_idx sits at global position L - S + s
+        pos_q = (L - S + (qi * rq + lax.broadcasted_iota(
+            jnp.int32, (rq, block_t), 0)) // g)
+        kpos = j * block_t + lax.broadcasted_iota(
+            jnp.int32, (rq, block_t), 1)
+        mask = (kpos <= pos_q)[None]
+        kb, ks = _block(k_ref, kq_ref, ks_ref)
+        s = jnp.einsum("hqd,thd->hqt", q, kb,
+                       preferred_element_type=jnp.float32) * scale
+        if ks is not None:
+            s = s * ks  # dequant on the score tile
         s = jnp.where(mask, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=2, keepdims=True))
         # zero masked probabilities EXACTLY (not just exp(-inf)): a row
         # whose every key so far is masked keeps l == 0 and lands on the
         # defined all-zeros output below instead of a uniform average
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        vb = wait_v(j, slot)
-        acc = acc * alpha + lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return acc, m_new, l
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
+        m_ref[...] = m_new
+        vb, vs = _block(v_ref, vq_ref, vs_ref)
+        if vs is not None:
+            p = p * vs
+        acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
+            "hqt,thd->hqd", p, vb, preferred_element_type=jnp.float32)
 
-    if pipeline:
-        @pl.when(nb > 0)
-        def _():  # warm-up: block 0's DMA is in flight before the loop
-            start(0, 0)
-
-    d = q.shape[1]
-    acc0 = jnp.zeros((rq, d), jnp.float32)
-    m0 = jnp.full((rq, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((rq, 1), jnp.float32)
-    acc, _, l = lax.fori_loop(0, nb, body, (acc0, m0, l0))
-    out = acc / jnp.where(l > 0, l, 1.0)
-    o_ref[0, 0] = jnp.where(l > 0, out, 0.0).astype(o_ref.dtype)
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        l = l_ref[...]
+        out = acc_ref[...] / jnp.where(l > 0, l, 1.0)
+        o_ref[0] = jnp.where(l > 0, out, 0.0).astype(o_ref.dtype)
 
 
 def flash_decode_attention(q, k, v, lengths, scale, *,
@@ -380,7 +249,6 @@ def flash_decode_attention(q, k, v, lengths, scale, *,
                            block_t: int | None = None,
                            block_q: int | None = None,
                            block_tables=None,
-                           pipeline: bool = True,
                            interpret: bool = False):
     """Fused masked attention of S fresh queries per slot against a KV
     cache block, reading only live rows.
@@ -413,9 +281,6 @@ def flash_decode_attention(q, k, v, lengths, scale, *,
     and page ``j`` of slot ``b`` is fetched from whichever representation
     ``block_quant[b, j]`` selects (0 = full precision, nonzero = int8).
 
-    ``pipeline=True`` (default) double-buffers the block DMA — page
-    ``j+1``'s copy commits while page ``j``'s dots run; ``False`` keeps
-    the serial fetch the pipelined path is pinned bitwise-identical to.
     ``block_q`` caps the folded query rows per grid instance (chunked
     prefill splits wide windows over the q grid axis)."""
     B, S, nh, D = q.shape
@@ -454,69 +319,96 @@ def flash_decode_attention(q, k, v, lengths, scale, *,
     g = nh // nkv
     sg = S * g
     sgp = -(-sg // _SUBLANE) * _SUBLANE  # pad query rows to the sublane tile
-    # paged: the DMA unit is a whole pool page, so the block size IS the
-    # page length (the allocator's granularity, already VMEM-sized) and
-    # the q-block count is the only VMEM-budget tunable
+    # every grid instance holds ALL kv heads of its rows, so the score
+    # tile is [nkv, rq, bt]: both pickers budget nkv*rq rows. paged: the
+    # block IS a whole pool page, so the q-block count is the only
+    # VMEM-budget tunable there
+    want_q = max(_SUBLANE, (block_q or DEFAULT_BLOCK_Q) // nkv)
     if paged:
         bt = k.shape[1]
-        rq = _pick_block_q(sgp, block_q or DEFAULT_BLOCK_Q, bt)
+        rq = _pick_block_q(sgp, want_q, nkv * bt)
+        max_nb = block_tables.shape[1]
     else:
-        rq = _pick_block(sgp, block_q or DEFAULT_BLOCK_Q)
-        bt = _pick_block_t(T, block_t or DEFAULT_BLOCK_T, rows=rq)
+        rq = _pick_block(sgp, want_q)
+        bt = _pick_block_t(T, _kv_block_cap(block_t or DEFAULT_BLOCK_T,
+                                            nkv, D, k.dtype.itemsize),
+                           rows=nkv * rq)
+        max_nb = T // bt
     # fold [B, S, nkv, g, D] -> [B, nkv, S*g, D]: one kv head's whole query
-    # group per grid instance (tiny copy — S is 1..chunk, never the cache)
+    # group per row block (tiny copy — S is 1..chunk, never the cache)
     qf = q.reshape(B, S, nkv, g, D).swapaxes(1, 2).reshape(B, nkv, sg, D)
     if sgp != sg:
         qf = jnp.pad(qf, ((0, 0), (0, 0), (0, sgp - sg), (0, 0)))
 
+    # lengths, block tables and page flags ride as scalar-prefetch
+    # operands: whole arrays in SMEM, read by the index maps and the
+    # kernel (a per-slot (1,) SMEM block is refused by the TPU lowering)
+    prefetch = [lengths.astype(jnp.int32)]
+    if paged:
+        prefetch.append(block_tables.astype(jnp.int32))
+    if mixed:
+        prefetch.append(block_quant.astype(jnp.int32))
+
+    def _walk(b, i, j, len_ref):
+        """Block index grid step j fetches: the walk's own block while it
+        lasts, then the last one again (a repeat costs no DMA)."""
+        nb = _visible_blocks(len_ref[b], i, S=S, g=g, rq=rq, block_t=bt,
+                             max_nb=max_nb)
+        return jnp.clip(jnp.minimum(j, nb - 1), 0, max_nb - 1)
+
+    def kv_map(want_quant=None):
+        """Index map of a K/V (or scale) operand. ``want_quant`` (mixed
+        mode): the page-flag value under which THIS pool representation is
+        the one read; otherwise the fetch parks on pool page 0."""
+        def index(b, i, j, len_ref, *tables):
+            jj = _walk(b, i, j, len_ref)
+            if not paged:
+                return (b, jj)
+            page = tables[0][b, jj]
+            if want_quant is not None:
+                page = jnp.where((tables[1][b, jj] != 0) == want_quant,
+                                 page, 0)
+            return (page, 0)
+        return index
+
+    def kv_spec(arr, want_quant=None):
+        rest = arr.shape[2:]  # (Hkv, D) for K/V, (Hkv,) for scales
+        imap = kv_map(want_quant)
+        return pl.BlockSpec(
+            (1, bt) + rest,
+            lambda *a: imap(*a) + (0,) * len(rest))
+
+    q_spec = pl.BlockSpec((1, nkv, rq, D), lambda b, i, j, *_: (b, 0, i, 0))
+    full = False if mixed else None
+    in_specs = [q_spec, kv_spec(k, full), kv_spec(v, full)]
+    operands = [qf, k, v]
+    if mixed:
+        in_specs += [kv_spec(k_quant, True), kv_spec(v_quant, True)]
+        operands += [k_quant, v_quant]
+    if quantized or mixed:
+        sq = True if mixed else None
+        in_specs += [kv_spec(k_scale, sq), kv_spec(v_scale, sq)]
+        operands += [k_scale, v_scale]
+
     kernel = functools.partial(
         _flash_decode_kernel, scale=float(scale), block_t=bt, S=S, g=g,
-        rq=rq, quantized=quantized, mixed=mixed, paged=paged,
-        pipeline=pipeline)
-    in_specs = [
-        pl.BlockSpec((1,), lambda b, h, i: (b,), memory_space=pltpu.SMEM),
-    ]
-    operands = [lengths.astype(jnp.int32)]
-    if paged:
-        maxp = block_tables.shape[1]
-        in_specs.append(pl.BlockSpec((1, maxp), lambda b, h, i: (b, 0),
-                                     memory_space=pltpu.SMEM))
-        operands.append(block_tables.astype(jnp.int32))
-    if mixed:
-        maxp = block_tables.shape[1]
-        in_specs.append(pl.BlockSpec((1, maxp), lambda b, h, i: (b, 0),
-                                     memory_space=pltpu.SMEM))
-        operands.append(block_quant.astype(jnp.int32))
-    in_specs += [
-        pl.BlockSpec((1, 1, rq, D), lambda b, h, i: (b, h, i, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),  # K stays in HBM
-        pl.BlockSpec(memory_space=pltpu.ANY),  # V stays in HBM
-    ]
-    operands += [qf, k, v]
-    nbuf = 2 if pipeline else 1
-    scratch = [pltpu.VMEM((nbuf, bt, D), k.dtype),
-               pltpu.VMEM((nbuf, bt, D), v.dtype)]
-    if mixed:
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
-        operands += [k_quant, v_quant]
-        scratch += [pltpu.VMEM((nbuf, bt, D), jnp.int8),
-                    pltpu.VMEM((nbuf, bt, D), jnp.int8)]
-    if quantized or mixed:
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
-        operands += [k_scale, v_scale]
-        scratch += [pltpu.VMEM((nbuf, bt), jnp.float32),
-                    pltpu.VMEM((nbuf, bt), jnp.float32)]
-    scratch.append(pltpu.SemaphoreType.DMA((nbuf, 4)))
-
+        rq=rq, max_nb=max_nb, quantized=quantized, mixed=mixed, paged=paged)
     out = pl.pallas_call(
         kernel,
-        grid=(B, nkv, sgp // rq),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rq, D), lambda b, h, i: (b, h, i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(B, sgp // rq, max_nb),
+            in_specs=in_specs,
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((nkv, rq, D), jnp.float32),
+                            pltpu.VMEM((nkv, rq, 1), jnp.float32),
+                            pltpu.VMEM((nkv, rq, 1), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, nkv, sgp, D), q.dtype),
-        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(*operands)
+        name="flash_decode_attention",
+    )(*prefetch, *operands)
     return (out[:, :, :sg]
             .reshape(B, nkv, S, g, D).swapaxes(1, 2)
             .reshape(B, S, nh, D))

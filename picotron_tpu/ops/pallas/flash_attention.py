@@ -14,8 +14,7 @@ Three data layouts share the same kernel bodies (``model.flash_layout``):
 - "bshd" (interpret-mode only — REJECTED on hardware): the kernels consume
   [B, S, H, D] directly — grid (batch, head, q-block), the head dimension
   squeezed out by a size-None BlockSpec entry — avoiding the fold's
-  transpose copies. Measured on a v5e chip 2026-07-30
-  (docs/chip_runs/20260730T221221Z/kernel_parity.log): Mosaic refuses to
+  transpose copies. On a v5e chip Mosaic refuses to
   lower it — the last two block dims must be (8k, 128m) or span the whole
   axis, and in [B, S, H, D] the head axis is second-to-last, so a
   squeezed head block is structurally un-lowerable regardless of D. The
@@ -210,6 +209,7 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, layout="folded"):
         functools.partial(_fwd_kernel, scale=scale, block_q=bq, block_k=bk,
                           causal=causal, blk_axis=blk_axis),
         grid=grid, in_specs=in_specs, out_specs=out_specs,
+        name="flash_fwd",
         out_shape=out_shape,
     )(q, k, v)
     return post(out, lse)
@@ -378,12 +378,14 @@ def _bwd(scale, causal, block_q, block_k, layout, res, dout):
         functools.partial(_bwd_dq_kernel, scale=scale, block_q=bq, block_k=bk,
                           causal=causal, blk_axis=blk_axis),
         grid=dq_grid, in_specs=dq_in, out_specs=dq_out, out_shape=dq_shape,
+        name="flash_bwd_dq",
     )(q, k, v, out, dout, lse)
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, block_q=bq,
                           block_k=bk, causal=causal, blk_axis=blk_axis),
         grid=dkv_grid, in_specs=dkv_in, out_specs=dkv_out,
+        name="flash_bwd_dkv",
         out_shape=dkv_shape,
     )(q, k, v, out, dout, lse)
     if layout == "merged":  # back to the [B, S, H, D] primal shape (free)

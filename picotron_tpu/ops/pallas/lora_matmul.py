@@ -109,6 +109,7 @@ def lora_matmul_pallas(x, a, b, ids, *, interpret: bool = False):
     )
     return pl.pallas_call(
         _lora_kernel,
+        name="lora_matmul",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, N), jnp.float32),
         interpret=interpret,

@@ -168,6 +168,7 @@ def quant_matmul_pallas(x2, q, s, *, block_m=None, block_n=None,
     kernel = functools.partial(_quant_matmul_kernel, block_k=bk)
     out = pl.pallas_call(
         kernel,
+        name="quant_matmul",
         grid=(Mp // bm, N // bn),
         in_specs=[
             pl.BlockSpec((bm, K), lambda i, j: (i, 0)),

@@ -60,6 +60,7 @@ def _run_fwd(x2d, w, eps):
     br = _pick_block(rows, h, x2d.dtype.itemsize)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
+        name="rmsnorm_fwd",
         grid=(rows // br,),
         in_specs=[
             pl.BlockSpec((br, h), lambda i: (i, 0)),
@@ -85,6 +86,7 @@ def _bwd_rule(eps, res, dy):
     br = _pick_block(rows, h, x2d.dtype.itemsize)
     dx, dw = pl.pallas_call(
         functools.partial(_bwd_kernel, eps=eps),
+        name="rmsnorm_bwd",
         grid=(rows // br,),
         in_specs=[
             pl.BlockSpec((br, h), lambda i: (i, 0)),
@@ -100,7 +102,7 @@ def _bwd_rule(eps, res, dy):
             jax.ShapeDtypeStruct((1, h), jnp.float32),
         ],
     )(x2d, w, dy)
-    return dx, dw[0].astype(w.dtype)
+    return dx, dw.astype(w.dtype)  # [1, H], the primal w's own shape
 
 
 _rms_norm_2d.defvjp(_fwd_rule, _bwd_rule)

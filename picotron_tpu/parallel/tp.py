@@ -106,22 +106,8 @@ def all_gather_dim_invariant(x, axis: str, dim: int):
     from picotron_tpu.utils import typeof_vma
 
     if axis in typeof_vma(x):
-        try:
-            # jax-internal: the invariant gather has no public spelling yet.
-            # Reached only under check_vma=True (a vma-typed trace), which
-            # itself requires a jax.shard_map-era release — so a failure
-            # here means a jax upgrade moved/removed the private symbol.
-            from jax._src.lax.parallel import all_gather_invariant
-        except ImportError as e:
-            import jax
-
-            raise ImportError(
-                "check_vma=True needs jax._src.lax.parallel."
-                "all_gather_invariant (present in jax >= 0.6 releases with "
-                f"jax.shard_map's vma checker); this jax build "
-                f"({jax.__version__}) does not provide it — upgrade/"
-                "downgrade jax or run with distributed.check_vma=false"
-            ) from e
+        # jax 0.9.0 has no public spelling of the invariant gather
+        from jax._src.lax.parallel import all_gather_invariant
 
         _trace("all_gather", axis, x, extra=f"dim={dim} invariant")
         return all_gather_invariant(x, axis, axis=dim, tiled=True)

@@ -220,6 +220,9 @@ def main(argv=None) -> int:
     if not (args.load_path or args.hf_path or args.random_init):
         ap.error("pass one of --load-path / --hf-path / --random-init")
     _ensure_devices(cfg)
+    from picotron_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()  # before the first compile
 
     from picotron_tpu.inference import ContinuousBatcher, InferenceEngine
 

@@ -26,10 +26,6 @@ def measure(n_million: int = 200) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from picotron_tpu.utils import honor_cpu_env_pin
-
-    honor_cpu_env_pin()
-
     from picotron_tpu.checkpoint import CheckpointManager
 
     n = n_million * 1_000_000

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 
 from picotron_tpu.config import Config
-from picotron_tpu.utils import honor_cpu_env_pin
 
 
 def _time(fn, *args, warmup=2, iters=10):
@@ -119,7 +118,6 @@ def main(argv=None) -> int:
     ap.add_argument("--small", action="store_true",
                     help="tiny geometry + small buffer (CPU/CI)")
     args = ap.parse_args(argv)
-    honor_cpu_env_pin()
 
     n = 16 << 20 if args.small else 1 << 30
     d2h, h2d = measure_link_bandwidth(n)

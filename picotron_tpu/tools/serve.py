@@ -1488,6 +1488,9 @@ def main(argv=None) -> int:
                          "tools/trace_dump.py)")
     args = ap.parse_args(argv)
 
+    from picotron_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()  # before the first compile
     cfg, engine, params, registry = _build_engine_and_params(args)
 
     server = Server(

@@ -148,6 +148,8 @@ class Scheduler:
                "--config", job.config_path] + (extra_args or [])
         with open(job.log_path, "w") as log:
             try:
+                # one process per chip: this parent imports the package (and so jax) but
+                # never initialises a backend, so the chip is free for the child
                 proc = subprocess.run(
                     cmd, stdout=log, stderr=subprocess.STDOUT,
                     timeout=timeout_s, cwd=job.root,

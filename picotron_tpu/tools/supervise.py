@@ -276,6 +276,8 @@ def _run_supervised_loop(cmd, env, budget, stop_req, *, max_restarts,
         print(f"supervise: launching (restarts used "
               f"{budget.attempt}/{max_restarts}): {' '.join(cmd)}",
               flush=True)
+        # one process per chip: this parent imports the package (and so jax) but
+        # never initialises a backend, so the chip is free for the child
         proc = subprocess.Popen(cmd, env=env)
         stop_req["proc"] = proc
         if stop_req["flag"] and proc.poll() is None:
@@ -418,6 +420,7 @@ def run_pod(cmd, num_procs: int, max_restarts: int = 3, backoff: float = 1.0,
                 env["PICOTRON_METRICS_JSONL"] = (
                     metrics_jsonl if i == 0 else f"{metrics_jsonl}.p{i}")
             hbs.append(hb)
+            # (as in run(): the supervisor itself never initialises a backend)
             procs.append(subprocess.Popen(cmd, env=env))
         rcs: list = [None] * num_procs
         stalled = False

@@ -553,6 +553,9 @@ def main(argv=None):
     cfg = Config.from_dict(raw)
     _ensure_devices(cfg)
     _maybe_init_distributed()
+    from picotron_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()  # before the first compile
     if cfg.obs.enabled:
         # kill -USR2 <pid> -> one timed jax.profiler capture into
         # obs.profile_dir: the "this run is slow RIGHT NOW" surface,

@@ -480,8 +480,8 @@ def build_train_step(cfg: Config, topo: Topology, multi_step: int = 1,
 
     # On-device training loop: scan `step` over `multi_step` stacked batches
     # in ONE dispatch. Removes per-step host round-trips (launch latency +
-    # the loss fetch the reference pays every step, train.py:242), which on
-    # a remote/tunneled TPU is tens of ms per step. Returns per-step losses.
+    # the loss fetch the reference pays every step, train.py:242). Returns
+    # per-step losses.
     def multi(params, opt_state, tokens, targets):
         def body(carry, batch):
             p, o = carry

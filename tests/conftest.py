@@ -7,20 +7,15 @@ a real multi-device program on one host. SURVEY.md §4 calls for exactly this.
 
 import os
 
-if os.environ.get("PICOTRON_TEST_TPU") == "1":
-    # real-TPU kernel runs (tests/test_tpu_kernels.py, invoked by bench.py's
-    # parity pre-flight): leave the platform alone so the TPU backend loads
-    import jax
-else:
-    os.environ["XLA_FLAGS"] = (
-        "--xla_force_host_platform_device_count=8 "
-        + os.environ.get("XLA_FLAGS", "")
-    )
-    os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (
+    "--xla_force_host_platform_device_count=8 "
+    + os.environ.get("XLA_FLAGS", "")
+)
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-    import jax
+import jax  # noqa: E402
 
-    jax.config.update("jax_platforms", "cpu")
+jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
 

@@ -98,3 +98,45 @@ def test_wandb_logging_path(tiny_model_kwargs, monkeypatch):
     logs = [e for e in events if e[0] == "log"]
     assert len(logs) == 2 and logs[0][1] == 1 and "loss" in logs[0][2]
     assert events[-1] == ("finish",)
+
+
+# --------------------------------------------------------------------------- #
+# chip_smoke.py rehearsals: the whole smoke at toy size on the CPU. They
+# compile a model per phase, so they live here, last in the suite.
+# --------------------------------------------------------------------------- #
+
+
+def _rehearse(*flags):
+    import json
+    import os
+    import subprocess
+    import sys
+
+    root = __file__.rsplit("/tests/", 1)[0]
+    # the smoke's parent must stay off jax, so it runs as a process of its
+    # own, without this suite's device-count and platform settings
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+    r = subprocess.run(
+        [sys.executable, os.path.join(root, "chip_smoke.py"), "--rehearse",
+         *flags], capture_output=True, text=True, timeout=600, env=env)
+    assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
+    assert r.stdout.startswith("REHEARSAL")
+    return r.stdout, json.loads(r.stdout.strip().splitlines()[-1])
+
+
+def test_chip_smoke_rehearsal_one_chip_phases():
+    out, last = _rehearse()
+    assert last == {"ok": True, "device": {"platform": "cpu", "kind": "cpu",
+                                           "count": 1}}
+    for phase in ("kernels", "train", "serve"):
+        assert f"=== phase {phase} passed" in out
+    assert "[serve:flash]" in out and "[serve:dense]" in out
+
+
+def test_chip_smoke_rehearsal_four_chip_phase():
+    out, last = _rehearse("--four-chip")
+    assert last["ok"] is True and last["device"]["platform"] == "cpu"
+    assert last["device"]["count"] == 4
+    assert "=== phase mesh passed" in out and "phase kernels" not in out
+    assert "pp2_cp2 vs one_device" in out and "dp2_tp2 vs one_device" in out
