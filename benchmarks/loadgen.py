@@ -1,0 +1,215 @@
+#!/usr/bin/env python3
+"""The load generator: a child process, standard library only, never JAX.
+
+It speaks HTTP to the server from outside the server's process, so it shares
+neither the dispatch loop's interpreter lock nor the chip. One general
+generator reads a traffic mix's parameters:
+
+- ``loop: "closed"``: ``clients`` callers, each sending its next request
+  when the last one ended (callers that wait);
+- ``loop: "open"``: arrivals on a schedule at ``rate_rps`` whatever the
+  server does (independent users); each request is timed from the instant
+  it was *due*, and how late it was sent is reported.
+
+The same work for every seed: the *set* of request shapes (prompt and
+output lengths, one from each quantile band of the mix's distributions) and
+of gaps between arrivals is drawn from the mix's own ``shape_seed``;
+``--seed`` only orders them and draws the token ids. An open loop of
+``span`` seconds holds ``round(rate_rps * span)`` requests whose gaps are
+exponential (Poisson arrivals), scaled to the span.
+
+``lead_in_seconds`` of the same load come before the window, so that the
+window opens on a server in its steady state and not on the first burst
+into an idle one; the record's ``t0`` is the window's first instant and the
+metrics read what falls inside [t0, t0 + seconds].
+
+Protocol with the parent: build the schedule, print ``READY``, wait for a
+line on stdin, offer the load for lead-in + ``--seconds``, let the requests
+in flight end (``drain_seconds`` at most), write the results as JSON to
+``--out``. All times are ``time.monotonic()``, the clock the parent reads.
+"""
+
+from __future__ import annotations
+
+import argparse
+import http.client
+import json
+import math
+import random
+import sys
+import threading
+import time
+
+
+def length_at(spec: dict, u: float) -> int:
+    """The length at quantile ``u`` in [0, 1) of the mix's distribution."""
+    lo, hi = int(spec["min"]), int(spec["max"])
+    if spec["dist"] == "uniform":
+        return min(hi, lo + int(u * (hi - lo + 1)))
+    if spec["dist"] == "log_uniform":
+        return min(hi, max(lo, round(math.exp(
+            math.log(lo) + u * (math.log(hi) - math.log(lo))))))
+    raise ValueError(f"unknown length distribution {spec['dist']!r}")
+
+
+def stratified(rng: random.Random, spec: dict, n: int) -> list:
+    """``n`` lengths, one from each of the distribution's ``n`` equal
+    quantile bands, in random order: every window holds the whole spread."""
+    out = [length_at(spec, (i + rng.random()) / n) for i in range(n)]
+    rng.shuffle(out)
+    return out
+
+
+def build_schedule(traffic: dict, seed: int, span: float,
+                   vocab: int) -> list:
+    """[{"due", "prompt", "max_new_tokens"}] for ``span`` seconds of load
+    (lead-in and window); ``due`` is seconds after the start (open loop) or
+    None (closed loop: taken in order by the clients)."""
+    shape_rng = random.Random(int(traffic["shape_seed"]))
+    rng = random.Random(seed)
+    if traffic["loop"] == "open":
+        n = max(1, round(float(traffic["rate_rps"]) * span))
+    else:
+        n = int(traffic["shapes"])
+    shapes = list(zip(stratified(shape_rng, traffic["prompt_len"], n),
+                      stratified(shape_rng, traffic["output_len"], n)))
+    rng.shuffle(shapes)
+    dues = [None] * n
+    if traffic["loop"] == "open":
+        gaps = [shape_rng.expovariate(1.0) for _ in range(n)]
+        rng.shuffle(gaps)
+        # the first arrival at 0, the last one gap short of the window's end
+        scale = span / sum(gaps)
+        t, dues = 0.0, []
+        for g in gaps:
+            dues.append(t)
+            t += g * scale
+    return [{"due": due, "max_new_tokens": out_len,
+             "prompt": [rng.randrange(1, vocab) for _ in range(p_len)]}
+            for due, (p_len, out_len) in zip(dues, shapes)]
+
+
+def one_request(port: int, prompt: list, max_new_tokens: int,
+                timeout: float = 300.0) -> dict:
+    """POST /generate as an NDJSON stream; the time of every token row."""
+    rec = {"prompt_len": len(prompt), "asked": max_new_tokens,
+           "status": None, "token_times": [], "error": None}
+    body = json.dumps({"prompt": prompt, "max_new_tokens": max_new_tokens,
+                       "temperature": 0.0, "stream": True})
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=timeout)
+    try:
+        rec["sent"] = time.monotonic()
+        conn.request("POST", "/generate", body,
+                     {"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        rec["status"] = resp.status
+        if resp.status != 200:
+            rec["error"] = resp.read(300).decode(errors="replace")
+            return rec
+        while True:
+            line = resp.readline()
+            if not line:
+                rec["error"] = "stream ended without a done row"
+                break
+            now = time.monotonic()
+            row = json.loads(line)
+            if row.get("event") == "token":
+                rec["token_times"].append(now)
+                continue
+            rec["done"] = now
+            rec["n_tokens"] = len(row.get("tokens", ()))
+            rec["finish_reason"] = row.get("finish_reason")
+            rec["server_ttft_s"] = row.get("ttft_s")
+            rec["server_queue_wait_s"] = row.get("queue_wait_s")
+            break
+    except (OSError, http.client.HTTPException, ValueError) as e:
+        rec["error"] = f"{type(e).__name__}: {e}"
+    finally:
+        conn.close()
+    return rec
+
+
+def request_ok(rec: dict) -> bool:
+    """200, ended by length, as many tokens as asked, each one streamed."""
+    return (rec.get("status") == 200 and rec.get("error") is None
+            and rec.get("finish_reason") == "length"
+            and rec.get("n_tokens") == rec["asked"]
+            and len(rec["token_times"]) == rec["asked"])
+
+
+def run_load(port: int, traffic: dict, schedule: list, seconds: float) -> dict:
+    results, lock = [], threading.Lock()
+    t_start = time.monotonic()
+    t0 = t_start + float(traffic.get("lead_in_seconds", 0))
+    t_end = t0 + seconds
+
+    def fire(i, item, due_abs):
+        rec = one_request(port, item["prompt"], item["max_new_tokens"])
+        rec["i"] = i
+        rec["due"] = due_abs if due_abs is not None else rec.get("sent")
+        with lock:
+            results.append(rec)
+
+    threads = []
+    if traffic["loop"] == "open":
+        for i, item in enumerate(schedule):
+            due_abs = t_start + item["due"]
+            delay = due_abs - time.monotonic()
+            if delay > 0:
+                time.sleep(delay)
+            th = threading.Thread(target=fire, args=(i, item, due_abs),
+                                  daemon=True)
+            th.start()
+            threads.append(th)
+        sent = len(schedule)
+    else:
+        cursor = [0]
+
+        def client():
+            while time.monotonic() < t_end:
+                with lock:
+                    i = cursor[0]
+                    cursor[0] += 1
+                fire(i, schedule[i % len(schedule)], None)
+
+        threads = [threading.Thread(target=client, daemon=True)
+                   for _ in range(int(traffic["clients"]))]
+        for th in threads:
+            th.start()
+        time.sleep(max(0.0, t_end - time.monotonic()))
+        sent = None
+    drain_until = t_end + float(traffic.get("drain_seconds", 60))
+    for th in threads:
+        th.join(max(0.0, drain_until - time.monotonic()))
+    with lock:
+        done = sorted(results, key=lambda r: r["i"])
+    if sent is None:
+        sent = cursor[0]
+    return {"t0": t0, "seconds": seconds, "sent": sent,
+            "unfinished": sent - len(done), "requests": done}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--port", type=int, required=True)
+    ap.add_argument("--traffic", required=True, help="the mix, as JSON")
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--vocab", type=int, required=True)
+    ap.add_argument("--out", required=True)
+    args = ap.parse_args(argv)
+    traffic = json.loads(args.traffic)
+    schedule = build_schedule(
+        traffic, args.seed,
+        args.seconds + float(traffic.get("lead_in_seconds", 0)), args.vocab)
+    print("READY", flush=True)
+    if not sys.stdin.readline():
+        return 1  # the parent went away before the window
+    out = run_load(args.port, traffic, schedule, args.seconds)
+    with open(args.out, "w") as f:
+        json.dump(out, f)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

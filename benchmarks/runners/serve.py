@@ -1,0 +1,272 @@
+"""The serve runner: this process holds the chip and runs the program's own
+server (``tools/serve.py``: ``_build_engine_and_params`` + ``Server`` on an
+ephemeral port, the recipe ``chip_smoke.py`` proved on the chip) with
+``InferenceConfig``'s shipped defaults; the configuration sets ``slots`` and
+``max_seq_len`` and nothing else. The load generator is a child process
+that speaks HTTP.
+
+Set-up: weights drawn on the device from ``--seed``; prefill of one seeded
+prompt and four decode steps through the cache against the reference's full
+forward, on logits; the server; warm-up requests for every prefill shape
+the mix can ask for (each bucket, and the chunked path when prompts pass
+``prefill_chunk``), one at a time and then all at once.
+Then the child offers the load: the mix's ``lead_in_seconds`` first (still
+set-up), then the window, in which this process only scrapes ``GET
+/metrics`` at its start and end and (``--trace 1``) traces a few seconds
+from a third of it on.
+"""
+
+from __future__ import annotations
+
+import argparse
+import gc
+import json
+import os
+import subprocess
+import sys
+import threading
+import time
+import urllib.request
+
+import numpy as np
+
+from benchmarks import common, loadgen
+
+# Serving logits, as chip_smoke.py states it: prefill/decode through the KV
+# cache in bf16 against the float32 reference over the same weights, compared
+# in absolute terms against the logits' own scale (max |logit|): bf16 keeps 8
+# bits, L layers of rounding in other orders land within 3 % of that scale
+# (chip_smoke read 1.6e-2 on values to 4.1 against a bf16 oracle), and the
+# argmax must agree wherever the reference's top-2 margin passes twice the
+# tolerance. A float32 engine (the rehearsal) is held to 1e-3.
+TOL_LOGITS_REL = {"bfloat16": 3e-2, "float32": 1e-3}
+CHECK_PROMPT_LEN = 32
+CHECK_DECODE_STEPS = 4
+
+
+def config_dict(ctx: dict) -> dict:
+    return {
+        "distributed": {"dp_size": 1, "pp_size": 1, "cp_size": 1,
+                        "tp_size": 1, "use_cpu": ctx["rehearse"]},
+        "model": common.model_section(ctx["config"]),
+        "training": {"seq_length": ctx["config"]["serve"]["max_seq_len"],
+                     "seed": ctx["seed31"]},
+        "dataset": {"name": "synthetic"},
+    }
+
+
+def logits_check(ctx, engine, params, prompt) -> tuple:
+    """(ok, rows of (what, err, scale, margin, ok))."""
+    import jax
+
+    from benchmarks.reference import dense_decoder
+
+    seq = list(prompt)
+    kv, last = engine.prefill(params, prompt)
+    got = [np.asarray(last, np.float32)[0]]
+    cache = engine.insert(engine.init_cache(), kv, 0, len(prompt))
+    slots = engine.slots
+    for _ in range(CHECK_DECODE_STEPS):
+        seq.append(int(np.argmax(got[-1])))
+        toks = np.zeros(slots, np.int32)
+        toks[0] = seq[-1]
+        cache, _, logits = engine.decode_step(
+            params, cache, toks, jax.random.PRNGKey(0),
+            np.zeros(slots, np.float32), np.zeros(slots, np.int32),
+            np.ones(slots, np.float32))
+        got.append(np.asarray(logits, np.float32)[0])
+    del cache, kv
+    want = dense_decoder.forward_logits(
+        params, np.asarray([seq], np.int32), ctx["config"],
+        jax.devices()[0])[0][len(prompt) - 1:]
+    tol = TOL_LOGITS_REL[ctx["config"].get("torch_dtype", "bfloat16")]
+    rows, all_ok = [], True
+    for i, (g, ref) in enumerate(zip(got, want)):
+        scale = float(np.max(np.abs(ref)))
+        err = float(np.max(np.abs(g - ref)))
+        top2 = np.sort(ref)[-2:]
+        margin = float(top2[1] - top2[0])
+        ok = err <= tol * scale
+        if margin > 2 * tol * scale:
+            ok = ok and int(np.argmax(g)) == int(np.argmax(ref))
+        rows.append(("prefill" if i == 0 else f"decode+{i}", err, scale,
+                     margin, ok))
+        all_ok = all_ok and ok
+    return all_ok, rows
+
+
+def warm_lengths(traffic: dict, engine) -> list:
+    """One prompt length for every prefill shape the mix can reach."""
+    lo, hi = traffic["prompt_len"]["min"], traffic["prompt_len"]["max"]
+    chunk = engine.prefill_chunk
+    lengths = {}
+    for n in range(lo, min(hi, chunk) + 1):
+        lengths.setdefault(engine.prefill_bucket(n), n)
+    out = sorted(lengths.values())
+    if hi > chunk:
+        out.append(hi)  # the chunked path: one shape whatever the length
+    return out
+
+
+def get_text(port: int, path: str) -> str:
+    with urllib.request.urlopen(f"http://127.0.0.1:{port}{path}",
+                                timeout=30) as r:
+        return r.read().decode()
+
+
+def run(ctx: dict) -> dict:
+    from picotron_tpu.tools import serve
+
+    log, traffic, config = ctx["log"], ctx["traffic"], ctx["config"]
+    compiles = common.CompileCounter()
+    name = ctx["cell"]["name"]
+    cfg_path = os.path.join(ctx["scratch"], name + ".config.json")
+    with open(cfg_path, "w") as f:
+        json.dump(config_dict(ctx), f, indent=1)
+    args = argparse.Namespace(
+        smoke=False, config=cfg_path, load_path="", hf_path="",
+        random_init=True, seed=ctx["seed31"],
+        slots=config["serve"]["slots"],
+        max_seq_len=config["serve"]["max_seq_len"], kv_layout=None,
+        role=None, overlap=False, tenant_manifest="")
+    cfg, engine, params, registry = serve._build_engine_and_params(args)
+    log(f"[serve] engine and weights after "
+        f"{time.perf_counter() - ctx['t0']:.1f} s; attend_impl "
+        f"{engine.attend_impl}, decode_block_len {engine.decode_block_len}, "
+        f"prefill_chunk {engine.prefill_chunk}")
+    vocab = cfg.model.vocab_size
+    rng = np.random.default_rng(ctx["seed31"])
+    check_prompt = [int(t) for t in rng.integers(1, vocab, CHECK_PROMPT_LEN)]
+    # before the server owns a cache: the chip never holds two caches
+    logits_ok, rows = logits_check(ctx, engine, params, check_prompt)
+    for what, err, scale, margin, ok in rows:
+        log(f"[serve] logits {what}: max|err| {err:.4f} vs max|logit| "
+            f"{scale:.3f}, top-2 margin {margin:.4f} "
+            f"{'ok' if ok else 'FAIL'}")
+    gc.collect()
+
+    server = serve.Server(engine, params, port=0, seed=ctx["seed31"],
+                          tenants=registry)
+    server.start()
+    child = None
+    try:
+        port = server.port
+        # warm-up, twice over every prefill shape, more than one block of
+        # tokens each: first one request at a time (a dispatch that compiles
+        # holds the front end's lock, and a request posted meanwhile is shed
+        # after 10 s), then all at once so the decode program runs full
+        lengths = warm_lengths(traffic, engine)
+        n_new = 2 * engine.decode_block_len + 1
+        t_warm = time.perf_counter()
+        recs = []
+
+        def warm(n):
+            prompt = [int(t) for t in rng.integers(1, vocab, n)]
+            recs.append(loadgen.one_request(port, prompt, n_new,
+                                            timeout=1500))
+
+        for n in lengths:
+            warm(n)
+        threads = [threading.Thread(target=warm, args=(n,)) for n in lengths]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join()
+        bad = [r for r in recs if not loadgen.request_ok(r)]
+        if bad or len(recs) != 2 * len(lengths):
+            raise SystemExit(f"[serve] warm-up request failed: {bad[:1]}")
+        log(f"[serve] warmed prompt lengths {lengths} twice in "
+            f"{time.perf_counter() - t_warm:.1f} s")
+
+        out_path = os.path.join(ctx["scratch"], name + ".load.json")
+        if os.path.exists(out_path):
+            os.unlink(out_path)
+        child = subprocess.Popen(
+            [sys.executable, os.path.join(os.path.dirname(loadgen.__file__),
+                                          "loadgen.py"),
+             "--port", str(port), "--traffic", json.dumps(traffic),
+             "--seed", str(ctx["seed"]), "--seconds", str(ctx["seconds"]),
+             "--vocab", str(vocab), "--out", out_path],
+            stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True)
+        if child.stdout.readline().strip() != "READY":
+            raise SystemExit("[serve] the load generator did not start")
+        child.stdin.write("GO\n")
+        child.stdin.flush()
+        # the lead-in is the last of the set-up: the window opens on a
+        # server in its steady state
+        time.sleep(float(traffic.get("lead_in_seconds", 0)))
+        before = get_text(port, "/metrics")
+        compiles.mark()
+        t_begin = time.perf_counter()
+        setup_s = t_begin - ctx["t0"]
+        tracer = common.Tracer(ctx) if ctx["trace"] else None
+        if tracer:
+            time.sleep(ctx["seconds"] / 3)
+            tracer.start()
+            time.sleep(min(float(traffic.get("trace_seconds", 3)),
+                           ctx["seconds"] / 3))
+            tracer.stop()
+        time.sleep(max(0.0, t_begin + ctx["seconds"] - time.perf_counter()))
+        after = get_text(port, "/metrics")
+        in_window = compiles.in_window
+        try:  # the requests in flight end, then the child writes and exits
+            rc = child.wait(
+                timeout=float(traffic.get("drain_seconds", 60)) + 30)
+        except subprocess.TimeoutExpired:
+            child.kill()
+            child.wait()
+            raise SystemExit("[serve] the load generator overran its time")
+        if rc != 0:
+            raise SystemExit(f"[serve] the load generator exited {rc}")
+    finally:
+        if child is not None and child.poll() is None:
+            child.kill()
+            child.wait()
+        server.drain_and_join(timeout=60)
+    with open(out_path) as f:
+        load = json.load(f)
+    if ctx["debug_dir"]:
+        os.replace(out_path, os.path.join(
+            ctx["debug_dir"], f"{name}.{ctx['seed']}.load.json"))
+    else:
+        os.unlink(out_path)
+    reqs = load["requests"]
+    for r in reqs:
+        r["ok"] = loadgen.request_ok(r)
+    failed = sum(not r["ok"] for r in reqs) + load["unfinished"]
+    notes = []
+    if not logits_ok:
+        notes.append("engine logits disagree with the reference")
+    if failed:
+        first_bad = next((r for r in reqs if not r["ok"]), None)
+        notes.append(f"{failed} requests failed or never ended; first: "
+                     f"{ {k: v for k, v in (first_bad or {}).items() if k != 'token_times'} }")
+    if in_window:
+        notes.append(f"{in_window} compiles inside the window")
+    if server.front.dead:
+        notes.append("the dispatch loop died")
+    n_tok = sum(len(r["token_times"]) for r in reqs)
+    t_end = load["t0"] + load["seconds"]
+    # sent, and not yet streaming when the window closed: the backlog
+    queued = sum(r["sent"] <= t_end and (not r["token_times"]
+                                         or r["token_times"][0] > t_end)
+                 for r in reqs) + load["unfinished"]
+    log(f"[serve] {load['sent']} requests sent, {len(reqs)} ended, {failed} "
+        f"failed; {n_tok} tokens streamed; {queued} waiting for a first "
+        f"token at the window's end")
+    return {
+        "setup_s": setup_s,
+        "window_s": load["seconds"],
+        "load": load,
+        "queued_at_end": queued,
+        "slots": engine.slots,
+        "decode_block_len": engine.decode_block_len,
+        "metrics_before": before, "metrics_after": after,
+        "attempted": load["sent"],
+        "failed": failed,
+        "correct": logits_ok and not failed and not in_window
+        and not server.front.dead,
+        "compiles_in_window": in_window,
+        "trace": tracer.reduce() if tracer else None,
+        "notes": notes,
+    }

@@ -1,0 +1,49 @@
+"""The harness end to end at toy size on the CPU (the rehearsal switch) for
+one train and one serve cell: the final line's keys, and no device metric
+printed under a CPU."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+
+
+def run_cell(cell, trace, extra=()):
+    p = subprocess.run(
+        [sys.executable, "benchmarks/run.py", "--workload", cell, "--seed",
+         "3000000001", "--seconds", "2", "--trace", str(trace), *extra],
+        cwd=ROOT, capture_output=True, text=True, timeout=600)
+    return p
+
+
+@pytest.mark.parametrize("cell,trace,computed", [
+    ("smollm-1.7b.train-2k", 0, {"train_tokens_per_s_chip", "setup_s"}),
+    ("smollm-1.7b.train-2k", 1, {"train_step.step_ms"}),
+    ("smollm-1.7b.serve-batch", 0,
+     {"serve_out_tokens_per_s", "serve_itl_p99_ms", "setup_s"}),
+    ("smollm-1.7b.serve-batch", 1, {"batcher.dispatch_gap_ms"}),
+])
+def test_rehearsal_final_line(cell, trace, computed):
+    p = run_cell(cell, trace, ["--rehearse"])
+    assert p.returncode == 0, p.stderr[-2000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert KEYS <= set(out)
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["attempted"] > 0 and out["compiles_in_window"] == 0
+    # a CPU run names the CPU and prints no number under a metric's name
+    assert out["device"]["platform"] == "cpu"
+    assert out["rehearsal"] is True and out["metrics"] == {}
+    assert "busy_s" not in out["device"] and "breakdown" not in out
+    assert computed <= set(out["computed"])
+
+
+def test_without_the_switch_a_cpu_is_refused():
+    p = run_cell("smollm-1.7b.train-2k", 0)
+    assert p.returncode == 2
+    assert not p.stdout.strip()
