@@ -1,12 +1,11 @@
 """What both runners share: the model section of the program's config, the
-compile counter, the traced stretch of the window, the benchmark's own host
+compile counter, the reduction of a traced stretch, the benchmark's own host
 spans."""
 
 from __future__ import annotations
 
 import os
 import shutil
-import time
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -62,8 +61,12 @@ def span(name: str):
 
 
 class Tracer:
-    """Traces one stretch of the window with ``jax.profiler``, reduces it
-    and deletes the files: a tree that grows by a trace per run becomes too
+    """Where a traced stretch is written and how it becomes numbers. The
+    program captures: the runner starts and stops the program's own
+    ``picotron_tpu.obs.ProfileCapture`` (the server's, the object ``POST
+    /profilez`` uses; one of its own around the train loop) through
+    ``open()`` and hands ``stop()``'s answer to ``reduce()``, which
+    deletes the files: a tree that grows by a trace per run becomes too
     large to copy."""
 
     def __init__(self, ctx: dict):
@@ -72,32 +75,29 @@ class Tracer:
         self.chips = ctx["chips"]
         self.log = ctx["log"]
         self.debug_dir = ctx["debug_dir"]
-        self.t_start = self.t_stop = None
 
-    def start(self) -> None:
-        import jax
-
+    def open(self, capture) -> None:
         shutil.rmtree(self.dir, ignore_errors=True)
-        os.makedirs(self.dir, exist_ok=True)
-        jax.profiler.start_trace(self.dir)
-        self.t_start = time.perf_counter()
+        os.makedirs(self.dir)
+        started = capture.start(self.dir)
+        if not started["ok"]:
+            raise SystemExit(f"trace: {started['error']}")
 
-    def stop(self) -> None:
-        import jax
+    def warm(self, capture) -> None:
+        """One capture started, stopped and thrown away, so that what the
+        profiler's first start costs falls into no number."""
+        self.open(capture)
+        capture.stop()
+        shutil.rmtree(self.dir, ignore_errors=True)
 
-        self.t_stop = time.perf_counter()
-        jax.profiler.stop_trace()
-
-    @property
-    def active(self) -> bool:
-        return self.t_start is not None and self.t_stop is None
-
-    def reduce(self) -> dict | None:
+    def reduce(self, stopped: dict) -> dict | None:
+        """``stopped`` is what ``ProfileCapture.stop()`` returned."""
         from benchmarks import trace_reduce
 
-        if self.t_stop is None:
-            return None
         try:
+            if not stopped["ok"]:
+                self.log(f"trace: {stopped['error']}")
+                return None
             pd = trace_reduce.load(self.dir)
             if pd is None:
                 self.log("trace: no .xplane.pb was written")
@@ -107,10 +107,10 @@ class Tracer:
                 with open(os.path.join(self.debug_dir,
                                        "trace_description.txt"), "w") as f:
                     f.write(trace_reduce.describe(pd))
-            out = trace_reduce.reduce(pd, self.t_stop - self.t_start,
-                                      self.chips)
+            t_start, t_stop = stopped["t_start"], stopped["t_stop"]
+            out = trace_reduce.reduce(pd, t_stop - t_start, self.chips)
             if out is not None:
-                out.update(t_start=self.t_start, t_stop=self.t_stop)
+                out.update(t_start=t_start, t_stop=t_stop)
             return out
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)

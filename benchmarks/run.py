@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """One run of one cell of BENCHMARK.json.
 
-    python3 benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+    python3 benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1|2>
 
 Everything that belongs to one cell is data found by name: the cell's
 configuration (``benchmarks/configs/<config>.json``), its traffic mix
@@ -19,9 +19,19 @@ the data files, and prints no number under a metric's name, so a rehearsal
 can never pass for a chip run.
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
-``failed``, ``metrics``, ``device`` (and ``breakdown`` with ``--trace 1``).
-With ``--trace 0`` the metrics are the cell's end-to-end metrics, with
-``--trace 1`` its per-layer metrics. Everything else goes to stderr.
+``failed``, ``metrics``, ``device`` (and ``breakdown`` when a trace was
+taken). With ``--trace 0`` the metrics are the cell's end-to-end metrics,
+measured with the profiler off. ``--trace 2`` is that very run followed by
+a short traced tail of the same traffic: the window, its ``setup_s``,
+``correct``, ``attempted``, ``failed`` and end-to-end numbers are a ``--trace
+0`` run's, and only when the window has closed does the runner open the
+program's own capture (``picotron_tpu.obs.ProfileCapture``: the program
+captures, the benchmark reduces), once to throw away and once for a few
+seconds or steps; the line then holds the end-to-end and the per-layer
+metrics side by side, and ``device`` gains ``busy_s`` and ``window_s`` of
+the traced stretch. ``--trace 1`` is the older run of its own that traces
+from the middle of its window and prints the per-layer metrics alone.
+Everything else goes to stderr.
 """
 
 from __future__ import annotations
@@ -126,7 +136,7 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     ap.add_argument("--rehearse", action="store_true",
                     help="toy sizes on the CPU; prints no metric value")
     ap.add_argument("--set", action="append", default=[],
@@ -189,7 +199,7 @@ def main(argv=None) -> int:
         "t0": T0, "root": ROOT, "scratch": SCRATCH, "cell": cell,
         "config": config, "traffic": traffic, "chips": chips,
         "seed": args.seed, "seed31": args.seed % SEED_MOD,
-        "seconds": args.seconds, "trace": bool(args.trace),
+        "seconds": args.seconds, "trace": args.trace,
         "rehearse": args.rehearse, "device": dev, "log": log,
         "debug_dir": debug_dir,
     }
@@ -202,8 +212,10 @@ def main(argv=None) -> int:
 
         run["peaks"] = opcount.peaks(dev["kind"])  # unknown kind: an error
 
-    kind = "per_layer" if args.trace else "end_to_end"
-    metrics = read_metrics(manifest, kind, cell["name"], run)
+    metrics = {}
+    for kind in (("end_to_end",), ("per_layer",),
+                 ("end_to_end", "per_layer"))[args.trace]:
+        metrics.update(read_metrics(manifest, kind, cell["name"], run))
     device = dict(dev, memory_peak_bytes=memory_peak_bytes(jax, chips))
     out = {"correct": bool(run["correct"]), "attempted": int(run["attempted"]),
            "failed": int(run["failed"]), "metrics": metrics, "device": device,

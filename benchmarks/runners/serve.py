@@ -14,6 +14,13 @@ Then the child offers the load: the mix's ``lead_in_seconds`` first (still
 set-up), then the window, in which this process only scrapes ``GET
 /metrics`` at its start and end and (``--trace 1``) traces a few seconds
 from a third of it on.
+
+``--trace 2`` leaves that window as ``--trace 0`` has it and traces a tail:
+once the window's child has drained and written its record, a second child
+offers the same mix for its own lead-in plus the mix's ``trace_seconds``,
+and the traced stretch is those last seconds. Either way the capture is
+the server's own ``ProfileCapture`` (``server.front.profiler``, the object
+``POST /profilez`` starts), opened and closed from this process.
 """
 
 from __future__ import annotations
@@ -114,6 +121,76 @@ def get_text(port: int, path: str) -> str:
         return r.read().decode()
 
 
+def start_load(ctx: dict, port: int, vocab: int, seconds: float,
+               out_path: str):
+    """A load-generator child, started and told to go: ``lead_in_seconds``
+    of the mix and then ``seconds`` more, its record written to
+    ``out_path`` when its requests have ended."""
+    if os.path.exists(out_path):
+        os.unlink(out_path)
+    child = subprocess.Popen(
+        [sys.executable, os.path.join(os.path.dirname(loadgen.__file__),
+                                      "loadgen.py"),
+         "--port", str(port), "--traffic", json.dumps(ctx["traffic"]),
+         "--seed", str(ctx["seed"]), "--seconds", str(seconds),
+         "--vocab", str(vocab), "--out", out_path],
+        stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True)
+    if child.stdout.readline().strip() != "READY":
+        child.kill()
+        child.wait()
+        raise SystemExit("[serve] the load generator did not start")
+    child.stdin.write("GO\n")
+    child.stdin.flush()
+    return child
+
+
+def end_load(ctx: dict, child, out_path: str) -> dict:
+    """Wait for the requests in flight to end and the child to write its
+    record and exit; the record, every request marked ``ok`` or not."""
+    try:
+        rc = child.wait(
+            timeout=float(ctx["traffic"].get("drain_seconds", 60)) + 30)
+    except subprocess.TimeoutExpired:
+        child.kill()
+        child.wait()
+        raise SystemExit("[serve] the load generator overran its time")
+    if rc != 0:
+        raise SystemExit(f"[serve] the load generator exited {rc}")
+    with open(out_path) as f:
+        load = json.load(f)
+    for r in load["requests"]:
+        r["ok"] = loadgen.request_ok(r)
+    return load
+
+
+def traced_tail(ctx: dict, server, vocab: int) -> tuple:
+    """(trace record, the tail's load record): after the window, the same
+    mix again from a second child, its last ``trace_seconds`` under the
+    server's own capture. A longer span for the window's child would be
+    another window: an open loop scales its gaps to the span, and the
+    requests in flight at the window's end would finish under load."""
+    traffic = ctx["traffic"]
+    seconds = float(traffic.get("trace_seconds", 3))
+    tracer = common.Tracer(ctx)
+    capture = server.front.profiler
+    tracer.warm(capture)  # on the idle server, before the tail's load
+    out_path = os.path.join(ctx["scratch"],
+                            ctx["cell"]["name"] + ".tail.json")
+    child = start_load(ctx, server.port, vocab, seconds, out_path)
+    try:
+        time.sleep(float(traffic.get("lead_in_seconds", 0)))
+        tracer.open(capture)
+        time.sleep(seconds)
+        stopped = capture.stop()
+        load = end_load(ctx, child, out_path)
+    finally:
+        if child.poll() is None:
+            child.kill()
+            child.wait()
+    os.unlink(out_path)
+    return tracer.reduce(stopped), load
+
+
 def run(ctx: dict) -> dict:
     from picotron_tpu.tools import serve
 
@@ -179,19 +256,7 @@ def run(ctx: dict) -> dict:
             f"{time.perf_counter() - t_warm:.1f} s")
 
         out_path = os.path.join(ctx["scratch"], name + ".load.json")
-        if os.path.exists(out_path):
-            os.unlink(out_path)
-        child = subprocess.Popen(
-            [sys.executable, os.path.join(os.path.dirname(loadgen.__file__),
-                                          "loadgen.py"),
-             "--port", str(port), "--traffic", json.dumps(traffic),
-             "--seed", str(ctx["seed"]), "--seconds", str(ctx["seconds"]),
-             "--vocab", str(vocab), "--out", out_path],
-            stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True)
-        if child.stdout.readline().strip() != "READY":
-            raise SystemExit("[serve] the load generator did not start")
-        child.stdin.write("GO\n")
-        child.stdin.flush()
+        child = start_load(ctx, port, vocab, ctx["seconds"], out_path)
         # the lead-in is the last of the set-up: the window opens on a
         # server in its steady state
         time.sleep(float(traffic.get("lead_in_seconds", 0)))
@@ -199,40 +264,36 @@ def run(ctx: dict) -> dict:
         compiles.mark()
         t_begin = time.perf_counter()
         setup_s = t_begin - ctx["t0"]
-        tracer = common.Tracer(ctx) if ctx["trace"] else None
-        if tracer:
+        trace = None
+        if ctx["trace"] == 1:
+            tracer = common.Tracer(ctx)
             time.sleep(ctx["seconds"] / 3)
-            tracer.start()
+            tracer.open(server.front.profiler)
             time.sleep(min(float(traffic.get("trace_seconds", 3)),
                            ctx["seconds"] / 3))
-            tracer.stop()
+            stopped = server.front.profiler.stop()
         time.sleep(max(0.0, t_begin + ctx["seconds"] - time.perf_counter()))
         after = get_text(port, "/metrics")
         in_window = compiles.in_window
-        try:  # the requests in flight end, then the child writes and exits
-            rc = child.wait(
-                timeout=float(traffic.get("drain_seconds", 60)) + 30)
-        except subprocess.TimeoutExpired:
-            child.kill()
-            child.wait()
-            raise SystemExit("[serve] the load generator overran its time")
-        if rc != 0:
-            raise SystemExit(f"[serve] the load generator exited {rc}")
+        # the requests in flight end, then the child writes and exits
+        load = end_load(ctx, child, out_path)
+        if ctx["debug_dir"]:
+            os.replace(out_path, os.path.join(
+                ctx["debug_dir"], f"{name}.{ctx['seed']}.load.json"))
+        else:
+            os.unlink(out_path)
+        tail = None
+        if ctx["trace"] == 1:
+            trace = tracer.reduce(stopped)
+        elif ctx["trace"] == 2:
+            trace, tail = traced_tail(ctx, server, vocab)
+        in_tail = compiles.in_window - in_window
     finally:
         if child is not None and child.poll() is None:
             child.kill()
             child.wait()
         server.drain_and_join(timeout=60)
-    with open(out_path) as f:
-        load = json.load(f)
-    if ctx["debug_dir"]:
-        os.replace(out_path, os.path.join(
-            ctx["debug_dir"], f"{name}.{ctx['seed']}.load.json"))
-    else:
-        os.unlink(out_path)
     reqs = load["requests"]
-    for r in reqs:
-        r["ok"] = loadgen.request_ok(r)
     failed = sum(not r["ok"] for r in reqs) + load["unfinished"]
     notes = []
     if not logits_ok:
@@ -254,6 +315,21 @@ def run(ctx: dict) -> dict:
     log(f"[serve] {load['sent']} requests sent, {len(reqs)} ended, {failed} "
         f"failed; {n_tok} tokens streamed; {queued} waiting for a first "
         f"token at the window's end")
+    tail_ok = True
+    if tail is not None:
+        # the tail's requests were live during the traced stretch, so they
+        # go where ``stats.live_tokens`` looks; every window reader filters
+        # on [t0, t0 + seconds], which they lie outside of. One that failed
+        # fails the run's ``correct``, not the window's ``failed`` share.
+        tail_bad = (sum(not r["ok"] for r in tail["requests"])
+                    + tail["unfinished"])
+        tail_ok = not tail_bad and not in_tail
+        if not tail_ok:
+            notes.append(f"traced tail: {tail_bad} requests failed or never "
+                         f"ended, {in_tail} compiles")
+        log(f"[serve] traced tail: {tail['sent']} requests sent, "
+            f"{len(tail['requests'])} ended, {tail_bad} failed")
+        reqs.extend(tail["requests"])
     return {
         "setup_s": setup_s,
         "window_s": load["seconds"],
@@ -265,8 +341,8 @@ def run(ctx: dict) -> dict:
         "attempted": load["sent"],
         "failed": failed,
         "correct": logits_ok and not failed and not in_window
-        and not server.front.dead,
+        and not server.front.dead and tail_ok,
         "compiles_in_window": in_window,
-        "trace": tracer.reduce() if tracer else None,
+        "trace": trace,
         "notes": notes,
     }

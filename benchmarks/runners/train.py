@@ -11,6 +11,11 @@ loss is compared with it; ``warm_steps`` more. Then the window: whole steps
 until ``--seconds`` have passed, each timed on the host clock around the
 ``block_until_ready`` of its loss; every loss finite, the last below the
 first step's.
+
+Tracing is the program's own control (``picotron_tpu.obs.ProfileCapture``)
+around ``trace_steps`` whole steps: from a third of the window on with
+``--trace 1``; with ``--trace 2`` after the window has closed and its steps
+and losses are final, so that the window is a ``--trace 0`` window.
 """
 
 from __future__ import annotations
@@ -99,7 +104,6 @@ def run(ctx: dict) -> dict:
     for _ in range(int(traffic.get("warm_steps", 2))):
         one_step(next(loader))
 
-    tracer = common.Tracer(ctx) if ctx["trace"] else None
     trace_steps = int(traffic.get("trace_steps", 4))
     steps = []
     compiles.mark()
@@ -107,12 +111,25 @@ def run(ctx: dict) -> dict:
     setup_s = t_begin - ctx["t0"]
     deadline = t_begin + ctx["seconds"]
     t_prev = t_begin
+    tracer = capture = stopped = None
+
+    def open_capture():
+        # the program's control; nothing of it exists before it is needed
+        nonlocal tracer, capture
+        from picotron_tpu.obs import ProfileCapture
+
+        tracer = common.Tracer(ctx)
+        capture = ProfileCapture(tracer.dir, log=log)
+        if ctx["trace"] == 2:
+            tracer.warm(capture)
+        tracer.open(capture)
+
     traced = 0
     while t_prev < deadline:
-        # a few whole steps from the middle of the window on are traced
-        if tracer and tracer.t_start is None \
+        # --trace 1: a few whole steps from the middle of the window on
+        if ctx["trace"] == 1 and tracer is None \
                 and t_prev - t_begin >= ctx["seconds"] / 3:
-            tracer.start()
+            open_capture()
             t_prev = time.perf_counter()
         batch = next(loader)
         loss = one_step(batch)
@@ -120,15 +137,23 @@ def run(ctx: dict) -> dict:
         steps.append({"t_start": t_prev - t_begin, "t_end": t_end - t_begin,
                       "loss": loss})
         t_prev = t_end
-        if tracer and tracer.active:
+        if capture is not None and capture.running:
             traced += 1
             if traced >= trace_steps:
-                tracer.stop()
+                stopped = capture.stop()
                 t_prev = time.perf_counter()
-    if tracer and tracer.active:
-        tracer.stop()
+    if capture is not None and capture.running:
+        stopped = capture.stop()
+    in_window = compiles.in_window
     losses = [s["loss"] for s in steps]
-    finite = all(math.isfinite(x) for x in losses)
+    tail_losses = []
+    if ctx["trace"] == 2:
+        # the window is closed and its steps and losses are final: the
+        # same steps go on under the profiler
+        open_capture()
+        tail_losses = [one_step(next(loader)) for _ in range(trace_steps)]
+        stopped = capture.stop()
+    finite = all(math.isfinite(x) for x in losses + tail_losses)
     # against the run's first step, not the window's: at a constant 3e-4
     # the loss of a 13-step window can end a little above where it began
     # (seed 3000000003 on four chips, 4.83 -> 5.24, PR 24) while training
@@ -141,8 +166,11 @@ def run(ctx: dict) -> dict:
     if not finite or not falling:
         notes.append(f"window losses finite={finite}; last {losses[-1]} "
                      f"against the first step's {first_loss}")
-    if compiles.in_window:
-        notes.append(f"{compiles.in_window} compiles inside the window")
+    if in_window:
+        notes.append(f"{in_window} compiles inside the window")
+    if compiles.in_window > in_window:
+        notes.append(f"{compiles.in_window - in_window} compiles in the "
+                     f"traced tail")
     log(f"[train] {len(steps)} steps in {steps[-1]['t_end']:.2f} s; loss "
         f"{losses[0]:.4f} -> {losses[-1]:.4f}")
     return {
@@ -156,7 +184,7 @@ def run(ctx: dict) -> dict:
         "failed": sum(not math.isfinite(x) for x in losses),
         "correct": loss_ok and finite and falling
         and not compiles.in_window,
-        "compiles_in_window": compiles.in_window,
-        "trace": tracer.reduce() if tracer else None,
+        "compiles_in_window": in_window,
+        "trace": tracer.reduce(stopped) if tracer else None,
         "notes": notes,
     }

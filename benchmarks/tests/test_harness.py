@@ -28,6 +28,18 @@ def run_cell(cell, trace, extra=()):
     ("smollm-1.7b.serve-batch", 0,
      {"serve_out_tokens_per_s", "serve_itl_p99_ms", "setup_s"}),
     ("smollm-1.7b.serve-batch", 1, {"batcher.dispatch_gap_ms"}),
+    # --trace 2: the --trace 0 run, then a traced tail; both kinds of metric
+    ("smollm-1.7b.train-2k", 2,
+     {"train_tokens_per_s_chip", "setup_s", "train_step.step_ms"}),
+    ("smollm-1.7b.serve-batch", 2,
+     {"serve_out_tokens_per_s", "serve_itl_p99_ms", "setup_s",
+      "batcher.dispatch_gap_ms", "front.loop_lock_wait_ms",
+      "front.results_ms", "batcher.plan_ms", "batcher.deliver_ms"}),
+    ("mistral-7b-v0.3-l16.serve-chat", 2,
+     {"serve_tpot_mean_ms", "setup_s", "batcher.dispatch_gap_ms.chat",
+      "front.loop_lock_wait_ms.chat", "front.results_ms.chat",
+      "batcher.plan_ms.chat", "batcher.deliver_ms.chat",
+      "engine.prefill_tokens_per_s.chat"}),
 ])
 def test_rehearsal_final_line(cell, trace, computed):
     p = run_cell(cell, trace, ["--rehearse"])
@@ -41,6 +53,8 @@ def test_rehearsal_final_line(cell, trace, computed):
     assert out["rehearsal"] is True and out["metrics"] == {}
     assert "busy_s" not in out["device"] and "breakdown" not in out
     assert computed <= set(out["computed"])
+    if trace == 0:  # the end-to-end metrics and nothing else
+        assert computed == set(out["computed"])
 
 
 def test_without_the_switch_a_cpu_is_refused():

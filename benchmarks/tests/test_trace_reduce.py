@@ -71,3 +71,34 @@ def test_reduce_busy_idle_and_named_gaps():
 def test_no_device_ops_gives_nothing():
     pd = NS(planes=[NS(name="/host:CPU", lines=[])])
     assert tr.reduce(pd, 1.0, 1) is None
+
+
+def test_gaps_are_named_by_the_loop_threads_spans_only():
+    """The program's scoped spans under a capture: ``pt:`` on the dispatch
+    loop's thread, ``pt.req:`` on the handler threads. A gap is named by
+    the innermost ``pt:`` span; a handler's span that covers it (a client
+    waiting in ``submit()`` for the whole gap) and the anchors name
+    nothing."""
+    ops = NS(name="XLA Ops", events=[ev("fusion.1", 0, 100),
+                                     ev("fusion.1", 200, 100)])
+    loop = NS(name="python3", events=[
+        ev("pt.anchor", 0, 1),
+        ev("pt:step/sync", 0, 110), ev("pt:step/deliver", 110, 30),
+        ev("pt:loop/results", 140, 20), ev("pt:loop/lock_wait", 160, 10),
+        ev("pt:step/plan", 170, 25),
+        ev("pt:step/admit", 175, 10),  # nested in plan: the innermost wins
+        ev("pt:step/issue", 195, 10)])
+    handler = NS(name="python3", events=[
+        ev("pt.req:submit/lock_wait", 90, 120)])
+    pd = NS(planes=[NS(name="/device:TPU:0", lines=[ops]),
+                    NS(name="/host:CPU", lines=[loop, handler])])
+    gaps = dict(tr.reduce(pd, window_s=300e-6, n_devices=1)["idle_gaps"])
+    assert gaps == {
+        "pt:step/sync": pytest.approx(10e-6),
+        "pt:step/deliver": pytest.approx(30e-6),
+        "pt:loop/results": pytest.approx(20e-6),
+        "pt:loop/lock_wait": pytest.approx(10e-6),
+        "pt:step/plan": pytest.approx(15e-6),
+        "pt:step/admit": pytest.approx(10e-6),
+        "pt:step/issue": pytest.approx(5e-6),
+    }

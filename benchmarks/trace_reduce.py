@@ -19,7 +19,9 @@ import os
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-HOST_SPAN_PREFIX = "bench:"  # the benchmark's own TraceAnnotation spans
+# TraceAnnotation spans that name device gaps: the benchmark's own, and the
+# program's loop-thread phases (handler threads write "pt.req:", left out)
+HOST_SPAN_PREFIX = ("bench:", "pt:")
 COLLECTIVES = ("all-gather", "reduce-scatter", "all-reduce",
                "collective-permute", "all-to-all")
 

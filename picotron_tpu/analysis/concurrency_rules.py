@@ -142,8 +142,9 @@ class _MethodWalker:
             else:
                 self._expr_events(item.context_expr, held)
         inner = held | frozenset(locks)
-        self.walk(stmt.body, inner)
-        return held
+        # a lock the body takes with acquire() outlives the block (a timed
+        # span around a bounded acquire); the block's own locks do not
+        return self.walk(stmt.body, inner) - (frozenset(locks) - held)
 
     def _if(self, stmt: ast.If, held: frozenset) -> frozenset:
         self._expr_events(stmt.test, held)

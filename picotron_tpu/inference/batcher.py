@@ -92,6 +92,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from picotron_tpu.inference import sampling
+from picotron_tpu.obs import RoundPhases
 from picotron_tpu.resilience.retry import retry
 from picotron_tpu.utils import log0
 
@@ -386,6 +387,13 @@ class ContinuousBatcher:
         self._host_work_hist = reg.histogram(
             "picotron_host_work_seconds",
             "per-round host scheduling work (step wall minus sync wait)")
+        # the round's wall time tiled into step/plan, step/admit,
+        # step/issue, step/sync, step/deliver (docs/OBSERVABILITY.md)
+        self._phases = RoundPhases(self.obs)
+        self._prefill_tokens_total = reg.counter(
+            "picotron_prefill_tokens_total",
+            "prompt tokens run through a prefill program (solo, chunked "
+            "or lane), cached prefix excluded")
         # ---- mixed prefill–decode dispatch (inference.mixed_dispatch) -----
         # one prefill LANE per dp shard rides every decode/verify
         # dispatch (engine._lane_chunk): a long-prompt admission is
@@ -418,7 +426,12 @@ class ContinuousBatcher:
 
     # ---- queue surface ----------------------------------------------------
 
-    def submit(self, req: Request) -> None:
+    def submit(self, req: Request, waited=None) -> None:
+        """Queue ``req``. ``waited`` is the caller's span of what came
+        before this call on the request's behalf (the front end's wait
+        for its lock): the request root begins where that span began and
+        adopts it as its first child, so the chain starts where the
+        client's wait does."""
         if not req.prompt:
             # fail at submission, not inside run(): an admit-time prefill
             # error would throw away every already-finished result
@@ -448,9 +461,11 @@ class ContinuousBatcher:
         # the request's root span: every later stage (queue wait, prefill,
         # per-dispatch decode/verify, the front end's delivery) parents to
         # it, so one request reads as one tree in a trace dump
-        self._req_spans[req.uid] = self.obs.tracer.begin(
+        root = self._req_spans[req.uid] = self.obs.tracer.begin(
             "request", uid=req.uid, prompt_tokens=len(req.prompt),
             max_new_tokens=req.max_new_tokens)
+        if waited is not None:
+            self.obs.tracer.adopt(root, waited)
         self._pending.append(req)
 
     @property
@@ -1246,9 +1261,14 @@ class ContinuousBatcher:
                 pf_span = self.obs.tracer.begin(
                     "prefill", parent=root, uid=req.uid,
                     prompt_tokens=len(req.prompt))
+                t_prefill = self._clock()
                 logits = retry(lambda: self._prefill_into(req, i, key),
                                **self._retry)
                 self.obs.tracer.end(pf_span, **self._last_prefill)
+                if self._last_prefill.get("dispatches", 1) > 0:
+                    self._prefill_tokens_total.inc(
+                        len(req.prompt)
+                        - self._last_prefill.get("cached_tokens", 0))
             except Exception as e:  # noqa: BLE001 - isolated to this request
                 # the failure costs only THIS request: it never held a slot,
                 # so release frees whatever partial prefill state landed and
@@ -1332,6 +1352,11 @@ class ContinuousBatcher:
                     np.float32([req.temperature]),
                     np.int32([req.top_k]),
                     np.float32([req.top_p]))[0])
+            if self._last_prefill.get("dispatches", 1) > 0:
+                # the prefill programs are enqueued async; the first
+                # token's arrival on the host is where their time ends
+                self.engine.observe_dispatch("prefill",
+                                             self._clock() - t_prefill)
             if self._overlap:
                 # seed the device-carried last-token row for the seat
                 # (round N+1's input): an in-flight round only reads it
@@ -1416,6 +1441,7 @@ class ContinuousBatcher:
         self.obs.tracer.end(ln["span"], error=reason,
                             dispatches=ln["chunks"],
                             cached_tokens=ln["cached"])
+        self._prefill_tokens_total.inc(ln["done_end"] - ln["cached"])
 
     def _lane_feed(self) -> tuple:
         """Build this round's engine lane operands from the per-shard
@@ -1547,6 +1573,7 @@ class ContinuousBatcher:
                                       "lane": True}
             self.obs.tracer.end(ln["span"], dispatches=ln["chunks"],
                                 cached_tokens=ln["cached"], lane=True)
+            self._prefill_tokens_total.inc(len(ln["ids"]) - ln["cached"])
             self._lanes[sh] = None
             s.prefilling = False
             if self._overlap:
@@ -1718,15 +1745,31 @@ class ContinuousBatcher:
         raises for an engine-side fault.
 
         With ``inference.overlap`` the round runs PIPELINED instead: see
-        ``_step_overlap`` (issue round N+1, then drain round N)."""
-        if self._overlap:
-            self._step_overlap()
-            return
+        ``_step_overlap`` (issue round N+1, then drain round N).
+
+        Either way the round's wall time is tiled into phases
+        (``self._phases``): plan until the issue (less admit), issue,
+        sync, deliver from the sync's end on."""
+        self._phases.to("step/plan")
+        try:
+            if self._overlap:
+                self._step_overlap()
+            else:
+                self._step_serial()
+        finally:
+            self._phases.close()
+
+    def _admit_phase(self) -> None:
+        self._phases.to("step/admit")
+        self._admit()
+        self._phases.to("step/plan")
+
+    def _step_serial(self) -> None:
         t_step0 = self._clock()
         self._step_sync_wait = 0.0
         self._expire_deadlines()
         self._rebalance()
-        self._admit()
+        self._admit_phase()
         if not any(s is not None for s in self._slots):
             return
         for i, s in enumerate(self._slots):
@@ -1758,6 +1801,7 @@ class ContinuousBatcher:
                                  for _ in range(block)])
 
             def dispatch(b):
+                self._phases.to("step/issue")
                 t0 = self._clock()
                 self._note_issue(t0)
                 out = self.engine.decode_block(
@@ -1792,11 +1836,13 @@ class ContinuousBatcher:
                     self._cache, toks, counts = out
                     hid = None
                 self.decode_dispatches += 1
+                self._phases.to("step/sync")
                 t_sync = self._clock()
                 self._synthetic_wait(t0)
                 out = np.asarray(toks), np.asarray(counts), None
                 self._merge_hidden(hid, out[1])
                 t1 = self._clock()
+                self._phases.to("step/deliver")
                 dt_sync = t1 - t_sync
                 with self._scratch_mu:
                     self._host_sync_s = dt_sync
@@ -1895,7 +1941,7 @@ class ContinuousBatcher:
         self._step_sync_wait = 0.0
         self._expire_deadlines()
         self._rebalance_overlap()
-        self._admit()
+        self._admit_phase()
         if not any(s is not None for s in self._slots):
             self._sync_inflight()
             return
@@ -1961,6 +2007,7 @@ class ContinuousBatcher:
                     self._eos, b, self._temp, self._top_k, self._top_p,
                     draft_len=spec_lens, adapter_ids=adapter, lead=lead,
                     lanes=lanes)
+        self._phases.to("step/issue")
         t0 = self._clock()
         self._note_issue(t0)
         epochs = self._epoch.copy()
@@ -1993,6 +2040,7 @@ class ContinuousBatcher:
         self._dev_last = ntok
         self.decode_dispatches += 1
         self._round_seq += 1
+        self._phases.to("step/plan")  # until the drain's sync claims it
         return dict(kind=kind, t_round=t_round, t0=t0,
                     budget=budget, epochs=epochs, toks=toks,
                     counts=counts, accepted=accepted, hid=hid,
@@ -2025,6 +2073,7 @@ class ContinuousBatcher:
         self._lane_scratch = None
 
         def dispatch(b):
+            self._phases.to("step/issue")
             t0 = self._clock()
             self._note_issue(t0)
             out = issue(b, self._dev_tok())
@@ -2049,6 +2098,7 @@ class ContinuousBatcher:
                 hid = None
             self._dev_last = ntok
             self.decode_dispatches += 1
+            self._phases.to("step/sync")
             t_sync = self._clock()
             self._synthetic_wait(t0)
             outs = (np.asarray(toks), np.asarray(counts),
@@ -2059,6 +2109,7 @@ class ContinuousBatcher:
             self.engine.apply_advance(outs[1])
             self._merge_hidden(hid, outs[1])
             t1 = self._clock()
+            self._phases.to("step/deliver")
             dt_sync = t1 - t_sync
             with self._scratch_mu:
                 self._host_sync_s = dt_sync
@@ -2116,6 +2167,7 @@ class ContinuousBatcher:
         if rec is None:
             return
         kind = rec["kind"]
+        self._phases.to("step/sync")
         t_sync = self._clock()
         try:
             toks = np.asarray(rec["toks"])
@@ -2144,6 +2196,7 @@ class ContinuousBatcher:
             return
         self._synthetic_wait(rec["t0"])
         t1 = self._clock()
+        self._phases.to("step/deliver")
         dt_sync = t1 - t_sync
         with self._scratch_mu:
             self._host_sync_s = dt_sync
@@ -2429,6 +2482,7 @@ class ContinuousBatcher:
                else self._split())
 
         def dispatch(b):
+            self._phases.to("step/issue")
             t0 = self._clock()
             self._note_issue(t0)
             out = self.engine.verify(
@@ -2459,12 +2513,14 @@ class ContinuousBatcher:
                 self._cache, emitted, counts, accepted = out
                 hid = None
             self.decode_dispatches += 1
+            self._phases.to("step/sync")
             t_sync = self._clock()
             self._synthetic_wait(t0)
             out = (np.asarray(emitted), np.asarray(counts),
                    np.asarray(accepted))
             self._merge_hidden(hid, out[1])
             t1 = self._clock()
+            self._phases.to("step/deliver")
             dt_sync = t1 - t_sync
             with self._scratch_mu:
                 self._host_sync_s = dt_sync

@@ -51,6 +51,66 @@ GLOBAL_TRACER = SpanTracer()
 _NULL_TRACER = NullTracer()
 
 
+# The serving dispatch loop's phases (docs/OBSERVABILITY.md "Dispatch-loop
+# phases"): one histogram family, one label value per phase.
+PHASE_HISTOGRAM = "picotron_round_phase_seconds"
+
+
+class _Timed:
+    """``Obs.timed``'s context: a scoped span and, from the span's own two
+    clock reads, one observation of a histogram."""
+
+    __slots__ = ("_scoped", "_hist", "_span")
+
+    def __init__(self, scoped, hist):
+        self._scoped = scoped
+        self._hist = hist
+
+    def __enter__(self) -> Span:
+        self._span = self._scoped.__enter__()
+        return self._span
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self._scoped.__exit__(exc_type, exc, tb)
+        if self._span.t1 is not None:  # the null span never ends
+            self._hist.observe(self._span.t1 - self._span.t0)
+
+
+class RoundPhases:
+    """Tiles one scheduler round into named phases: ``to(name)`` ends the
+    open phase and opens the next one as a scoped span (so it reaches an
+    open profiler capture), ``close()`` ends the round and observes each
+    phase's summed seconds ONCE in ``picotron_round_phase_seconds``. A
+    phase entered twice in a round (plan around admit; issue and sync of
+    an isolation re-dispatch) is two spans and one observation."""
+
+    def __init__(self, obs: "Obs"):
+        self._obs = obs
+        self._scoped = None
+        self._span = None
+        self._seconds: dict = {}
+
+    def to(self, name: str) -> None:
+        self._end()
+        self._scoped = self._obs.tracer.span(name)
+        self._span = self._scoped.__enter__()
+
+    def _end(self) -> None:
+        if self._scoped is None:
+            return
+        self._scoped.__exit__(None, None, None)
+        s, self._scoped = self._span, None
+        if s.t1 is not None:
+            self._seconds[s.name] = (self._seconds.get(s.name, 0.0)
+                                     + s.t1 - s.t0)
+
+    def close(self) -> None:
+        self._end()
+        for name, seconds in self._seconds.items():
+            self._obs.phase_histogram(name).observe(seconds)
+        self._seconds.clear()
+
+
 class Obs:
     """The bundle a subsystem carries: its registry + the shared tracer,
     with one ``enabled`` flag gating both."""
@@ -65,6 +125,25 @@ class Obs:
         else:
             self.registry = registry or MetricsRegistry()
             self.tracer = tracer or GLOBAL_TRACER
+        self._phase_hists: dict = {}
+
+    def phase_histogram(self, name: str) -> Histogram:
+        h = self._phase_hists.get(name)
+        if h is None:
+            h = self._phase_hists[name] = self.registry.histogram(
+                PHASE_HISTOGRAM,
+                "dispatch-loop host time by phase, one observation a round",
+                phase=name)
+        return h
+
+    def timed(self, name: str, hist: Histogram, **args) -> _Timed:
+        """``with obs.timed(name, hist):`` — ``tracer.span(name)`` plus
+        one observation of ``hist``, from the same two clock reads."""
+        return _Timed(self.tracer.span(name, **args), hist)
+
+    def phase(self, name: str) -> _Timed:
+        """``timed`` into ``picotron_round_phase_seconds{phase=name}``."""
+        return self.timed(name, self.phase_histogram(name))
 
     @classmethod
     def from_config(cls, ocfg) -> "Obs":

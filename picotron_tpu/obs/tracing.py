@@ -25,6 +25,22 @@ Three record styles:
 ``instant()`` records zero-duration marks (trace-time collective logs
 from ``comm_trace``).
 
+While a ``jax.profiler`` capture is open (``obs.profiler.ProfileCapture``
+sets the module flag below) a SCOPED span is written twice: into the ring
+as always, and as a ``jax.profiler.TraceAnnotation`` into the capture's
+``.xplane.pb``, on the clock the device events are on, so a host span can
+be laid against a device gap. Its name there is ``pt:<name>`` when the
+span was entered on a thread that claimed itself a loop
+(``claim_loop_thread``: the serving dispatch loop, the training loop) and
+``pt.req:<name>`` on any other thread (HTTP handlers), so a reduction that
+names device gaps by the innermost host span reads the loop's phases only.
+Args stay on the ring span: annotation kwargs would split one phase into
+many names. ``begin()``/``end()``/``record()`` spans reach the ring only;
+``anchor()`` (one at a capture's start, one at its stop) carries the ring
+clock's reading into the capture so they can be shifted onto its clock
+afterwards: ``trace_ns = ring_s * 1e9 + (anchor.start_ns - ring_ns)``.
+With no capture open the cost is one global read per scoped span.
+
 Thread-safety: one leaf lock guards the id counter and ring; nothing else
 is shared. The clock is ``time.monotonic`` (one timebase across threads);
 timestamps are exported in microseconds as Chrome expects.
@@ -39,6 +55,23 @@ from collections import deque
 from typing import Optional
 
 DEFAULT_RING = 4096
+
+LOOP_PREFIX = "pt:"      # scoped spans of a claimed loop thread
+REQ_PREFIX = "pt.req:"   # scoped spans of any other thread
+ANCHOR_NAME = "pt.anchor"
+
+# True while ProfileCapture holds a jax.profiler capture open. Process-wide
+# because the profiler is: jax refuses a second concurrent trace.
+_capture_open = False
+
+
+def set_capture_open(flag: bool) -> None:
+    global _capture_open
+    _capture_open = bool(flag)
+
+
+def capture_open() -> bool:
+    return _capture_open
 
 
 class Span:
@@ -86,6 +119,7 @@ class SpanTracer:
         self._clock = clock
         self._next_id = 1
         self._ring: deque = deque(maxlen=max(1, int(ring)))
+        self._loop_threads: set = set()  # idents that claimed "pt:"
 
     @property
     def enabled(self) -> bool:
@@ -119,16 +153,21 @@ class SpanTracer:
         return span
 
     class _Scoped:
-        __slots__ = ("_tracer", "_span")
+        __slots__ = ("_tracer", "_span", "_annotation")
 
         def __init__(self, tracer: "SpanTracer", span: Span):
             self._tracer = tracer
             self._span = span
+            self._annotation = None
 
         def __enter__(self) -> Span:
+            if _capture_open and self._span.span_id:  # not the null span
+                self._annotation = self._tracer._annotate(self._span.name)
             return self._span
 
         def __exit__(self, exc_type, exc, tb) -> None:
+            if self._annotation is not None:
+                self._annotation.__exit__(exc_type, exc, tb)
             if exc_type is not None:
                 self._span.args = {**self._span.args,
                                    "error": exc_type.__name__}
@@ -150,6 +189,45 @@ class SpanTracer:
     def instant(self, name: str, **args) -> Span:
         t = self._clock()
         return self.record(name, t, t, **args)
+
+    def adopt(self, parent: Span, child: Span) -> None:
+        """Make ``child``, a span that ended before ``parent`` could exist
+        (a wait that came before the request was known), the first link of
+        ``parent``'s chain: ``parent`` begins where ``child`` began."""
+        parent.t0 = child.t0
+        child.parent_id = parent.span_id
+
+    # ---- the profiler's trace ----------------------------------------------
+
+    def claim_loop_thread(self) -> None:
+        """The calling thread runs a dispatch or training loop: its scoped
+        spans are named ``pt:`` in a capture. Release when the loop ends
+        (thread idents are reused)."""
+        with self._mu:
+            self._loop_threads.add(threading.get_ident())
+
+    def release_loop_thread(self) -> None:
+        with self._mu:
+            self._loop_threads.discard(threading.get_ident())
+
+    def _annotate(self, name: str):
+        """An entered ``TraceAnnotation`` twin of a scoped span."""
+        import jax
+
+        prefix = (LOOP_PREFIX if threading.get_ident() in self._loop_threads
+                  else REQ_PREFIX)
+        annotation = jax.profiler.TraceAnnotation(prefix + name)
+        annotation.__enter__()
+        return annotation
+
+    def anchor(self) -> None:
+        """One ``pt.anchor`` annotation whose ``ring_ns`` stat is this
+        ring's clock at the annotation's start (module docstring)."""
+        import jax
+
+        with jax.profiler.TraceAnnotation(
+                ANCHOR_NAME, ring_ns=int(self._clock() * 1e9)):
+            pass
 
     # ---- read side ---------------------------------------------------------
 
@@ -209,6 +287,18 @@ class NullTracer(SpanTracer):
 
     def record(self, name, t0, t1, parent=None, **args) -> Span:
         return NULL_SPAN
+
+    def adopt(self, parent, child) -> None:
+        pass
+
+    def claim_loop_thread(self) -> None:
+        pass
+
+    def release_loop_thread(self) -> None:
+        pass
+
+    def anchor(self) -> None:
+        pass
 
     def spans(self) -> list:
         return []

@@ -104,6 +104,7 @@ drain with accounting) — exits nonzero on any malfunction.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import queue
 import sys
@@ -169,7 +170,8 @@ class FrontEnd:
         ocfg = engine.cfg.obs
         self.profiler = ProfileCapture(
             ocfg.profile_dir, ocfg.profile_seconds,
-            log=lambda m: self._event("profiler", note=m))
+            log=lambda m: self._event("profiler", note=m),
+            tracer=self.obs.tracer)
         self.max_queue = int(max_queue)
         self.token_budget = int(token_budget if token_budget is not None
                                 else engine.slots * engine.max_seq_len)
@@ -244,6 +246,11 @@ class FrontEnd:
         # timed-out handlers would lose increments). Always taken last
         # (inside _mu where both are held), never while waiting on _mu.
         self._rej_mu = threading.Lock()
+        # what a client waits in submit() before the batcher sees its
+        # request: the dispatch loop holds _mu for a whole step
+        self._submit_wait_hist = self.obs.registry.histogram(
+            "picotron_submit_lock_wait_seconds",
+            "handler thread's wait for the batcher lock in submit()")
         self._uid_seq = 0
         self._start_t = time.monotonic()
         self._progress_t = time.monotonic()
@@ -387,12 +394,15 @@ class FrontEnd:
         cost = self._batcher.commitment(req)
         # bounded wait for the batcher lock: during a wedged dispatch (the
         # stall the watchdog flags) admission SHEDS instead of parking
-        # handler threads on the lock forever
-        if not self._mu.acquire(timeout=10.0):
-            self._reject("stalled")
-            raise AdmissionError(
-                503, "dispatch stalled (admission unavailable)",
-                retry_after=10)
+        # handler threads on the lock forever. The wait is the request
+        # chain's first link (a shed one keeps its span, with the error).
+        with self.obs.timed("submit/lock_wait", self._submit_wait_hist,
+                            uid=req.uid) as waited:
+            if not self._mu.acquire(timeout=10.0):
+                self._reject("stalled")
+                raise AdmissionError(
+                    503, "dispatch stalled (admission unavailable)",
+                    retry_after=10)
         try:
             if self.stopped.is_set():
                 # the dispatch loop is gone (drain done, or it died on an
@@ -489,7 +499,8 @@ class FrontEnd:
             self._waiters[req.uid] = waiter
             self._req_t[req.uid] = time.monotonic()
             try:
-                self._batcher.submit(req)  # validates prompt vs max_seq_len
+                # validates prompt vs max_seq_len
+                self._batcher.submit(req, waited=waited)
             except ValueError as e:
                 self._waiters.pop(req.uid, None)
                 self._req_t.pop(req.uid, None)
@@ -743,26 +754,36 @@ class FrontEnd:
             w.put_token(tok)
 
     def _loop(self) -> None:
+        phase = self.obs.phase
+        # this thread's scoped spans are the loop's phases ("pt:" in a
+        # profiler capture; handler threads' are "pt.req:")
+        self.obs.tracer.claim_loop_thread()
         try:
             while True:
                 if self.guard.triggered and not self.draining:
                     self.begin_drain()
-                with self._mu:
-                    if self.draining:
-                        self._batcher.shed_pending()
-                    if self._batcher.busy:
-                        self._batcher.step()
-                    results = self._batcher.take_results()
-                    busy = self._batcher.busy
-                self._progress_t = time.monotonic()
-                for uid, res in results.items():
-                    self._deliver(uid, res)
+                with contextlib.ExitStack() as results_phase:
+                    with phase("loop/lock_wait"):
+                        self._mu.acquire()
+                    try:
+                        if self.draining:
+                            self._batcher.shed_pending()
+                        if self._batcher.busy:
+                            self._batcher.step()
+                        # take_results .. the round's last _deliver
+                        results_phase.enter_context(phase("loop/results"))
+                        results = self._batcher.take_results()
+                        busy = self._batcher.busy
+                    finally:
+                        self._mu.release()
+                    self._progress_t = time.monotonic()
+                    for uid, res in results.items():
+                        self._deliver(uid, res)
                 if self.draining and not busy:
                     self._event("drain_done")
                     return
                 if not busy:
-                    self._wake.wait(0.05)
-                    self._wake.clear()
+                    self._idle()
         except BaseException as e:  # noqa: BLE001 - loop death is fatal news
             self._event("dispatch_loop_died",
                         error=f"{type(e).__name__}: {e}")
@@ -785,8 +806,20 @@ class FrontEnd:
                 stranded = list(self._waiters)
             for uid in stranded:
                 self._deliver(uid, GenerationResult(uid, [], [], "error"))
+            self.obs.tracer.release_loop_thread()
             if self._on_drained is not None:
                 self._on_drained()
+
+    def _idle(self) -> None:
+        """Nothing is busy: wait for a submission, a drain or the guard,
+        in 50 ms slices. One ``loop/idle`` span and observation for the
+        whole stretch, and no lock taken in it: an idle server must not
+        push its last requests' chains out of the span ring."""
+        with self.obs.phase("loop/idle"):
+            while not (self._wake.wait(0.05) or self.draining
+                       or self.guard.triggered or self._batcher.busy):
+                self._progress_t = time.monotonic()
+            self._wake.clear()
 
     def _deliver(self, uid: str, res) -> None:
         # the pops happen under _mu: handler threads INSERT these entries
@@ -1028,8 +1061,10 @@ class _Handler(BaseHTTPRequestHandler):
             self._json(400, {"ok": False,
                              "error": f"seconds must be > 0, got {seconds}"})
             return
-        out = f.profiler.start(out_dir=spec.get("dir") or None,
-                               seconds=seconds)
+        # always timed: this surface has no stop verb
+        out = f.profiler.start(
+            out_dir=spec.get("dir") or None,
+            seconds=seconds if seconds is not None else f.profiler.seconds)
         self._json(200 if out["ok"] else 409, out)
 
     def do_POST(self) -> None:

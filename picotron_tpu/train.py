@@ -140,7 +140,12 @@ def train(cfg, max_steps_override: Optional[int] = None,
     from picotron_tpu import utils
     from picotron_tpu.data import MicroBatchDataLoader
     from picotron_tpu.models import llama
-    from picotron_tpu.obs import GLOBAL_REGISTRY, MetricsJsonl, Obs
+    from picotron_tpu.obs import (
+        GLOBAL_REGISTRY,
+        MetricsJsonl,
+        Obs,
+        ProfileCapture,
+    )
     from picotron_tpu.obs.jsonl import resolve_path as jsonl_path
     from picotron_tpu.resilience.anomaly import AnomalyAbort, LossAnomalyDetector
     from picotron_tpu.resilience.chaos import ChaosInjector
@@ -204,6 +209,11 @@ def train(cfg, max_steps_override: Optional[int] = None,
     step = last_saved_step = trained_tokens = 0
     loss = float("nan")
     profiling = profile_done = False
+    # the logging.profile_start/stop window, through the process's one
+    # profiler control (obs/profiler.py); a SIGUSR2 capture that happens
+    # to be open when the window starts wins, and the window is skipped
+    window = ProfileCapture(lg.profile_dir, log=utils.log0,
+                            tracer=obs.tracer)
     layout = (m.num_hidden_layers, cfg.distributed.pp_size,
               cfg.distributed.pp_interleave)
     z1 = (cfg.distributed.zero1, cfg.distributed.dp_size)
@@ -281,6 +291,7 @@ def train(cfg, max_steps_override: Optional[int] = None,
               f"setup {time.perf_counter() - t0_setup:.1f}s")
 
         rollbacks = 0
+        obs.tracer.claim_loop_thread()  # its scoped spans are "pt:" in a capture
         while step < max_steps and (t.max_tokens is None or trained_tokens < t.max_tokens):
             # Preemption check. With consensus on, the decision is collective:
             # every process all-reduces its local flag at the same boundaries,
@@ -312,12 +323,12 @@ def train(cfg, max_steps_override: Optional[int] = None,
             # dispatch still traces one full dispatch; the done latch makes the
             # window fire exactly once.
             if profiling and lg.profile_stop and step >= lg.profile_stop:
-                jax.profiler.stop_trace()
+                window.stop()
                 profiling, profile_done = False, True
             if (lg.profile_start and not profiling and not profile_done
                     and step >= lg.profile_start):
-                jax.profiler.start_trace(lg.profile_dir)
-                profiling = True
+                profiling = window.start()["ok"]
+                profile_done = not profiling
             t_start = time.perf_counter()
             step_before = step
             # spc optimizer steps per device dispatch; a tail shorter than spc
@@ -329,16 +340,16 @@ def train(cfg, max_steps_override: Optional[int] = None,
                 steps_left = min(steps_left, -(-tokens_left // cfg.tokens_per_step))
             k = spc if steps_left >= spc else 1
             poisoned = chaos.poison_step(step + 1)  # config pins spc==1 here
-            if k > 1:
-                tokens, targets = ts.shard_batch_stack(
-                    [next(loader) for _ in range(k)], topo)
-                t_disp = time.perf_counter()
-                params, opt_state, loss_arr = step_fn(params, opt_state, tokens, targets)
-                t_sync = time.perf_counter()
-                losses = [float(x) for x in utils.host_values(loss_arr)]
-            else:
-                tokens, targets = ts.shard_batch(next(loader), topo)
-                if poisoned:
+            # per-dispatch spans: data (batch build) -> dispatch (async
+            # submit) -> host_sync (blocked on device losses), parented
+            # under one train/dispatch root — the serving trace's exact
+            # counterpart, dumped at exit via obs.trace_path. Scoped, so a
+            # profiler capture of the run names its device gaps by them
+            with obs.tracer.span("train/dispatch", step=step_before + 1,
+                                 steps=k) as droot:
+                if k > 1:
+                    fn = step_fn
+                elif poisoned:
                     if step_fn_poison is None:
                         step_fn_poison = ts.build_train_step(
                             cfg, topo, poison_nonfinite=True)
@@ -347,22 +358,20 @@ def train(cfg, max_steps_override: Optional[int] = None,
                     if step_fn_single is None:
                         step_fn_single = ts.build_train_step(cfg, topo)
                     fn = step_fn_single
-                t_disp = time.perf_counter()
-                params, opt_state, loss_arr = fn(
-                    params, opt_state, tokens, targets)
-                t_sync = time.perf_counter()
-                losses = [float(utils.host_values(loss_arr))]
-            t_end = time.perf_counter()
-            dt_call = t_end - t_start
-            # per-dispatch spans: data (batch build) -> dispatch (async
-            # submit) -> host_sync (blocked on device losses), parented
-            # under one train/dispatch root — the serving trace's exact
-            # counterpart, dumped at exit via obs.trace_path
-            droot = obs.tracer.record("train/dispatch", t_start, t_end,
-                                      step=step_before + 1, steps=k)
-            obs.tracer.record("data", t_start, t_disp, parent=droot)
-            obs.tracer.record("dispatch", t_disp, t_sync, parent=droot)
-            obs.tracer.record("host_sync", t_sync, t_end, parent=droot)
+                with obs.tracer.span("data", parent=droot):
+                    if k > 1:
+                        tokens, targets = ts.shard_batch_stack(
+                            [next(loader) for _ in range(k)], topo)
+                    else:
+                        tokens, targets = ts.shard_batch(next(loader), topo)
+                with obs.tracer.span("dispatch", parent=droot):
+                    params, opt_state, loss_arr = fn(
+                        params, opt_state, tokens, targets)
+                with obs.tracer.span("host_sync", parent=droot):
+                    host = utils.host_values(loss_arr)
+                    losses = ([float(x) for x in host] if k > 1
+                              else [float(host)])
+            dt_call = time.perf_counter() - t_start
             obs.registry.histogram(
                 "picotron_train_dispatch_seconds",
                 "train dispatch wall time (k fused steps)").observe(dt_call)
@@ -475,7 +484,8 @@ def train(cfg, max_steps_override: Optional[int] = None,
                            f"{step}, replaying", flush=True)
     finally:
         if profiling:
-            jax.profiler.stop_trace()
+            window.stop()
+        obs.tracer.release_loop_thread()
         guard.uninstall()
         flush_abandoned = False
         try:

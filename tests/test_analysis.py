@@ -1077,6 +1077,42 @@ def test_c003_negative_scratch_snapshots_under_leaf_lock(tmp_path):
     assert found == []
 
 
+def test_c003_lock_acquired_inside_a_with_body_outlives_it(tmp_path):
+    """serve.py's submit() times its bounded acquire under a span: the
+    lock taken by ``acquire()`` INSIDE the with-body is held after the
+    block (the block's own context managers are not), so the guarded
+    writes that follow are clean — and the same write with no acquire
+    anywhere before it is still flagged."""
+    src = """
+        import threading
+
+        class Front:
+            def __init__(self):
+                self._mu = threading.Lock()
+                self._span_mu = threading.Lock()
+                self._waiters = {{}}
+
+            def _loop(self):
+                with self._mu:
+                    self._waiters.pop("k", None)
+
+            def submit(self, uid, timed):
+                with timed, self._span_mu:
+                    {acquire}
+                try:
+                    self._waiters[uid] = 1
+                finally:
+                    self._mu.release()
+        """
+    held = _scan(tmp_path, src.format(acquire=(
+        "if not self._mu.acquire(timeout=10.0):\n"
+        "                        raise RuntimeError('stalled')")))
+    assert held == []
+    bare = _scan(tmp_path, src.format(acquire="pass"))
+    assert _rules(bare) == ["PICO-C003"]
+    assert bare[0].context == "Front.submit"
+
+
 def test_c002_positive_device_sync_under_scratch_lock(tmp_path):
     """The tempting wrong fix for the stats() race — wrap the whole sync
     stage, blocking wait included, in the scratch lock — trades a race

@@ -632,3 +632,379 @@ def test_comm_trace_records_instant_events(monkeypatch, capsys):
     monkeypatch.setenv("PICOTRON_VERBOSE", "0")
     comm_trace.log("all_gather", "tp", x)
     assert GLOBAL_TRACER.spans() == []
+
+
+# --------------------------------------------------------------------------- #
+# ISSUE 25: the capture as a control, spans on the profiler's clock, the
+# dispatch loop's phases
+# --------------------------------------------------------------------------- #
+
+
+def _host_events(trace_dir):
+    """{name: [(start_ns, duration_ns, stats)]} of the host planes' events
+    in the newest capture under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(
+        str(trace_dir), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("pt"):
+                    out.setdefault(e.name, []).append(
+                        (e.start_ns, e.duration_ns, dict(e.stats)))
+    return out
+
+
+def test_capture_is_a_control_start_stop_busy_and_timed(tmp_path):
+    from picotron_tpu.obs import ProfileCapture, tracing
+
+    cap = ProfileCapture(str(tmp_path / "a"), seconds=0.3,
+                         tracer=SpanTracer(ring=8))
+    # nothing open: stop answers not-ok and changes nothing
+    assert cap.stop()["ok"] is False and not cap.running
+    assert not tracing.capture_open()
+    # explicit: open until stop(), a second start answers busy
+    t_before = time.monotonic()
+    started = cap.start()
+    assert started == {"ok": True, "dir": str(tmp_path / "a"),
+                       "seconds": None}
+    assert cap.running and tracing.capture_open()
+    busy = cap.start()
+    assert busy["ok"] is False and "already running" in busy["error"]
+    time.sleep(0.5)  # longer than `seconds`: no timer was armed
+    assert cap.running
+    stopped = cap.stop()
+    assert stopped["ok"] and stopped["dir"] == str(tmp_path / "a")
+    assert t_before <= stopped["t_start"] < stopped["t_stop"] \
+        <= time.monotonic()
+    assert stopped["t_stop"] - stopped["t_start"] >= 0.5
+    assert not cap.running and not tracing.capture_open()
+    assert cap.captures == 1 and cap.stop()["ok"] is False
+    # both anchors are in the trace, each with the ring clock's reading
+    anchors = _host_events(tmp_path / "a")["pt.anchor"]
+    assert len(anchors) == 2 and all("ring_ns" in a[2] for a in anchors)
+    # timed: closes itself through the same stop(); bad lengths refused
+    assert cap.start(seconds=0)["ok"] is False
+    assert cap.start(str(tmp_path / "b"), seconds=0.3)["ok"]
+    deadline = time.monotonic() + 30
+    while time.monotonic() < deadline and cap.running:
+        time.sleep(0.05)
+    assert not cap.running and cap.captures == 2
+    assert not tracing.capture_open()
+    assert list((tmp_path / "b").iterdir())
+    # an explicit stop beats the timer, whose late stop then closes nothing
+    assert cap.start(str(tmp_path / "c"), seconds=0.2)["ok"]
+    assert cap.stop()["ok"]
+    assert cap.start(str(tmp_path / "d"))["ok"]  # a new, open-ended one
+    time.sleep(0.4)  # the first capture's timer has fired by now
+    assert cap.running
+    assert cap.stop()["ok"] and cap.captures == 4
+
+
+def test_scoped_spans_land_in_the_capture_on_its_clock(tmp_path):
+    """A scoped span is written twice while a capture is open: ``pt:`` on
+    a claimed loop thread, ``pt.req:`` on any other; args stay on the ring
+    span; the anchor shifts the ring twin onto the trace's clock."""
+    from picotron_tpu.obs import ProfileCapture
+
+    tr = SpanTracer(ring=64)
+    cap = ProfileCapture(str(tmp_path), tracer=tr)
+    with tr.span("before"):  # no capture yet: ring only
+        pass
+    assert cap.start()["ok"]
+
+    def loop():
+        tr.claim_loop_thread()
+        try:
+            with tr.span("step/plan", slots=3):
+                time.sleep(0.02)
+                with tr.span("step/admit"):
+                    time.sleep(0.01)
+        finally:
+            tr.release_loop_thread()
+
+    def handler():
+        with tr.span("submit/lock_wait", uid="u1"):
+            time.sleep(0.015)
+
+    for fn in (loop, handler):
+        th = threading.Thread(target=fn)
+        th.start()
+        th.join(timeout=30)
+        assert not th.is_alive()
+    began = tr.begin("request")  # begin/end and record(): ring only
+    tr.end(began)
+    assert cap.stop()["ok"]
+    with tr.span("after"):
+        pass
+    ev = _host_events(tmp_path)
+    assert set(ev) == {"pt.anchor", "pt:step/plan", "pt:step/admit",
+                       "pt.req:submit/lock_wait"}
+    assert all(len(v) == 1 for k, v in ev.items() if k != "pt.anchor")
+    # the anchor's shift puts every ring twin within 1 ms of its event
+    a_start, _, a_stats = ev["pt.anchor"][0]
+    shift = a_start - a_stats["ring_ns"]
+    ring = {s.name: s for s in tr.spans()}
+    assert ring["step/plan"].args == {"slots": 3}
+    for name, twin in (("pt:step/plan", "step/plan"),
+                       ("pt:step/admit", "step/admit"),
+                       ("pt.req:submit/lock_wait", "submit/lock_wait")):
+        start, dur, _ = ev[name][0]
+        s = ring[twin]
+        assert abs(s.t0 * 1e9 + shift - start) < 1e6, name
+        assert abs(s.t1 * 1e9 + shift - (start + dur)) < 1e6, name
+
+
+def test_no_capture_no_annotation_and_null_tracer_writes_nothing(
+        tmp_path, monkeypatch):
+    from picotron_tpu.obs import ProfileCapture
+
+    made = []
+
+    class Counting:
+        def __init__(self, name, **kw):
+            made.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    tr = SpanTracer(ring=8)
+    with tr.span("closed"):
+        pass
+    assert made == []  # one global read, nothing constructed
+    # obs.enabled: false under an open capture: neither write happens
+    cap = ProfileCapture(str(tmp_path), tracer=NullTracer())
+    assert cap.start()["ok"]
+    try:
+        off = obs_mod.null_obs()
+        with off.phase("loop/results"):
+            pass
+        with off.tracer.span("x"):
+            pass
+        assert made == []
+        with tr.span("open"):  # a live tracer does write while it is open
+            pass
+        assert made == ["pt.req:open"]
+    finally:
+        assert cap.stop()["ok"]
+    assert off.registry.prometheus() == ""
+
+
+class _ManualClock:
+    """Advances only when told, so a phase's seconds are exactly what the
+    test put into it and the boundaries between phases cost nothing."""
+
+    def __init__(self):
+        self.t = 100.0
+
+    def __call__(self):
+        return self.t
+
+
+def _phase_reads(registry):
+    snap = parse_prometheus(registry.prometheus())
+    out = {}
+    for key, v in snap.items():
+        if key.startswith("picotron_round_phase_seconds_") \
+                and "_bucket" not in key:
+            kind, _, label = key[len("picotron_round_phase_seconds_"):] \
+                .partition("{")
+            out.setdefault(label.split('"')[1], {})[kind] = v
+    return out
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+def test_round_phases_tile_the_step(overlap, monkeypatch):
+    """N rounds give N observations of every phase, and the phases' sums
+    add up to the steps' wall time: plan (around admit), admit, issue,
+    sync and deliver tile the round, in the serial and the pipelined
+    step alike."""
+    cfg, engine, params = _engine(slots=2, overlap=overlap,
+                                  decode_block_len=2)
+    clock = _ManualClock()
+    engine.obs = Obs(enabled=True, registry=MetricsRegistry(),
+                     tracer=SpanTracer(ring=4096, clock=clock))
+    b = ContinuousBatcher(engine, params, clock=clock)
+
+    def costing(obj, name, seconds):
+        inner = getattr(obj, name)
+
+        def wrapped(*a, **kw):
+            clock.t += seconds
+            return inner(*a, **kw)
+
+        monkeypatch.setattr(obj, name, wrapped)
+
+    costing(b, "_expire_deadlines", 1e-3)   # step/plan, before admit
+    costing(b, "_admit", 2e-3)              # step/admit
+    costing(engine, "decode_block", 3e-3)   # step/issue
+    costing(b, "_synthetic_wait", 5e-3)     # step/sync
+    costing(b, "_token_done", 0.5e-3)       # step/deliver (and admit's
+    #                                         first token of a request)
+    for i in range(3):
+        b.submit(Request(f"p{i}", [3 + i, 5, 7], max_new_tokens=9))
+    rounds, wall = 0, 0.0
+    while b.busy:
+        t0 = clock()
+        b.step()
+        wall += clock() - t0
+        rounds += 1
+    assert rounds >= 4 and not b.take_results().keys() ^ {"p0", "p1", "p2"}
+    ph = _phase_reads(engine.obs.registry)
+    assert set(ph) == {"step/plan", "step/admit", "step/issue",
+                       "step/sync", "step/deliver"}
+    assert ph["step/plan"]["count"] == ph["step/admit"]["count"] == rounds
+    n = b.decode_dispatches
+    assert n in (rounds, rounds - 1)  # overlap: the last step only drains
+    for p in ("step/issue", "step/sync", "step/deliver"):
+        assert ph[p]["count"] == n, p
+    assert ph["step/plan"]["sum"] == pytest.approx(rounds * 1e-3)
+    assert ph["step/issue"]["sum"] == pytest.approx(n * 3e-3)
+    assert ph["step/sync"]["sum"] == pytest.approx(n * 5e-3)
+    tokens = 3 * 9
+    assert ph["step/admit"]["sum"] + ph["step/deliver"]["sum"] \
+        == pytest.approx(rounds * 2e-3 + tokens * 0.5e-3)
+    assert sum(v["sum"] for v in ph.values()) == pytest.approx(wall)
+    # the ring holds the same tiles: in time order they abut
+    tiles = sorted((s for s in engine.obs.tracer.spans()
+                    if s.name.startswith("step/")), key=lambda s: s.t0)
+    assert sum(s.t1 - s.t0 for s in tiles) == pytest.approx(wall)
+
+
+def test_contended_submit_is_the_request_roots_first_child():
+    """A handler thread that waits for the front end's lock observes the
+    wait, and the wait opens the request's chain: the root begins where
+    the wait began and the wait span is its first child."""
+    from picotron_tpu.tools import serve
+
+    GLOBAL_TRACER.clear()
+    cfg, srv = _server()
+    front = srv.front
+    try:
+        assert front._mu.acquire(timeout=10)
+        try:
+            got = {}
+            th = threading.Thread(target=lambda: got.update(
+                r=serve._post(srv.port, {"prompt": [1, 2, 3], "uid": "w1",
+                                         "max_new_tokens": 3})))
+            th.start()
+            time.sleep(0.25)
+        finally:
+            front._mu.release()
+        th.join(timeout=60)
+        assert not th.is_alive() and got["r"][0] == 200
+        prom = parse_prometheus(front.metrics_text())
+        assert prom["picotron_submit_lock_wait_seconds_count"] == 1
+        assert prom["picotron_submit_lock_wait_seconds_sum"] >= 0.2
+        spans = GLOBAL_TRACER.spans()
+        root = next(s for s in spans
+                    if s.name == "request" and s.args.get("uid") == "w1")
+        kids = sorted((s for s in spans if s.parent_id == root.span_id),
+                      key=lambda s: s.t0)
+        assert kids[0].name == "submit/lock_wait"
+        assert kids[0].t0 == root.t0 and kids[0].duration_s >= 0.2
+        assert {"queue_wait", "prefill", "delivery"} \
+            <= {s.name for s in kids}
+        chains = trace_dump.request_chains(GLOBAL_TRACER.chrome_trace())
+        assert chains["w1"]["complete"]
+        # the loop's own phases were observed on the way
+        for phase in ("loop/lock_wait", "loop/results", "loop/idle",
+                      "step/plan", "step/deliver"):
+            assert prom['picotron_round_phase_seconds_count'
+                        f'{{phase="{phase}"}}'] >= 1, phase
+    finally:
+        srv.drain_and_join(timeout=60)
+
+
+def test_shed_submit_keeps_its_wait_span_with_the_error():
+    from picotron_tpu.tools import serve
+
+    GLOBAL_TRACER.clear()
+    cfg, engine, params = _engine(slots=2)
+    front = serve.FrontEnd(engine, params, log=lambda *a, **k: None)
+
+    class Wedged:  # the dispatch loop never lets go
+        def acquire(self, timeout=None):
+            return False
+
+    front._mu = Wedged()
+    with pytest.raises(serve.AdmissionError) as e:
+        front.submit({"prompt": [1, 2], "uid": "s1"})
+    assert e.value.status == 503 and front.rejections["stalled"] == 1
+    wait, = [s for s in GLOBAL_TRACER.spans()
+             if s.name == "submit/lock_wait"]
+    assert wait.args == {"uid": "s1", "error": "AdmissionError"}
+    prom = parse_prometheus(engine.obs.registry.prometheus())
+    assert prom["picotron_submit_lock_wait_seconds_count"] == 1
+
+
+def _prefill_counts(engine):
+    prom = parse_prometheus(engine.obs.registry.prometheus())
+    return (prom.get("picotron_prefill_tokens_total", 0),
+            prom.get('picotron_dispatch_seconds_count{kind="prefill"}', 0))
+
+
+def test_prefill_tokens_total_solo_chunked_lane_and_cached_prefix():
+    """Prompt tokens that ran through a prefill program: every prompt
+    token of a solo and of a chunked prefill, the lane's chunks, and on
+    the paged layout the prompt less its radix-cached prefix."""
+    long_a = [(5 * i + 2) % 120 + 1 for i in range(20)]
+    long_b = [(3 * i + 7) % 120 + 1 for i in range(17)]
+    # solo (one bucketed program) and chunked (prompt > prefill_chunk)
+    cfg, engine, params = _engine(slots=2, prefill_chunk=8)
+    b = ContinuousBatcher(engine, params)
+    b.run([Request("solo", [3, 4, 5, 6, 7], max_new_tokens=2),
+           Request("chunked", long_a, max_new_tokens=2)])
+    assert _prefill_counts(engine) == (5 + 20, 2)
+    assert b.prefill_dispatches == 1 + 3
+    # the fused lane: no solo prefill dispatch, the same tokens counted
+    cfg, engine, params = _engine(slots=2, prefill_chunk=8,
+                                  decode_block_len=4, mixed_dispatch=True)
+    b = ContinuousBatcher(engine, params)
+    b.run([Request("a", long_a, max_new_tokens=6),
+           Request("b", long_b, max_new_tokens=6)])
+    prom = parse_prometheus(engine.obs.registry.prometheus())
+    lane = sum(v for k, v in prom.items()
+               if k.startswith("picotron_prefill_lane_tokens_total"))
+    assert lane > 0
+    assert _prefill_counts(engine)[0] == 20 + 17
+    # paged: the second request shares a radix-cached prefix
+    cfg, engine, params = _engine(slots=2, kv_layout="paged",
+                                  kv_page_len=8)
+    b = ContinuousBatcher(engine, params)
+    b.run([Request("first", long_a, max_new_tokens=2)])
+    assert _prefill_counts(engine)[0] == 20
+    b.run([Request("second", long_a[:16] + [9, 9, 9], max_new_tokens=2)])
+    cached = [s.args["cached_tokens"] for s in GLOBAL_TRACER.spans()
+              if s.name == "prefill" and s.args.get("uid") == "second"][-1]
+    assert cached == 16
+    assert _prefill_counts(engine)[0] == 20 + 19 - cached
+
+
+def test_train_window_and_loop_spans_go_through_the_capture(tmp_path):
+    """``logging.profile_start/stop`` is the same control, and a capture
+    of a training run holds its loop's scoped spans as ``pt:``."""
+    from picotron_tpu.obs import tracing
+    from picotron_tpu.train import train
+
+    cfg = _train_cfg(tmp_path)
+    cfg.logging.profile_start = 2
+    cfg.logging.profile_stop = 3
+    cfg.logging.profile_dir = str(tmp_path / "prof")
+    step, _, loss = train(cfg)
+    assert step == 4 and np.isfinite(loss) and not tracing.capture_open()
+    ev = _host_events(tmp_path / "prof")
+    assert {"pt.anchor", "pt:train/dispatch", "pt:data", "pt:dispatch",
+            "pt:host_sync"} <= set(ev)
+    assert len(ev["pt:train/dispatch"]) == 1  # steps [2, 3): one dispatch
+    assert not any(k.startswith("pt.req:") for k in ev)
