@@ -500,9 +500,6 @@ class InferenceEngine:
         # specs to the tp-only engine.
         dpP = P("dp") if self.dp_size > 1 else P()
 
-        chunk_impl = (self._prefill_chunk_impl_paged
-                      if self.kv_layout == "paged"
-                      else self._prefill_chunk_impl)
         # the on-device sampling epilogue changes the programs' I/O: the
         # prefill family gains (key, temperature, top_k, top_p) inputs and
         # returns a sampled token id [1] where the host path returns [1, V]
@@ -522,7 +519,7 @@ class InferenceEngine:
             in_specs=(self._dispatch_pspecs, P(), P()) + samp,
             out_specs=(kv_spec, P()) + hid))
         self._prefill_chunk_jit = jax.jit(shard_map(
-            chunk_impl, mesh,
+            self._prefill_chunk_impl, mesh,
             in_specs=(self._dispatch_pspecs, self._cspecs,
                       P(), P(), P(), P()) + samp,
             out_specs=(self._cspecs, P()) + hid),
@@ -849,16 +846,6 @@ class InferenceEngine:
             return self._pack_kv(K, V), out, h_last[:, 0]
         return self._pack_kv(K, V), out
 
-    def _split_cache(self, cache):
-        """(per-layer K/V leaves to scan, lengths) — the scan consumes every
-        [L, ...] cache leaf the way it consumes the stacked params. The
-        paged layout's ``block_tables`` (and the hot_bf16 policy's
-        ``page_quant`` flags) have no layer axis: they ride as scan
-        constants, injected per layer by ``_layer_body``."""
-        return ({n: a for n, a in cache.items()
-                 if n not in paged_kv.META_LEAVES},
-                cache["lengths"])
-
     def _meta(self, cache) -> dict:
         """The layer-less host-owned metadata leaves a paged cache carries
         (block tables; page_quant under the hot_bf16 policy)."""
@@ -908,31 +895,57 @@ class InferenceEngine:
             "prefill_owner_reduce", "dp",
             lax.psum(jnp.where(owner, x, jnp.zeros_like(x)), "dp"))
 
-    def _layer_body(self, cos_b, sin_b, pos, meta):
-        """Build the layer-scan body: decode one layer against its cache
-        leaves. For paged caches the (layer-less) metadata leaves are
-        spliced into each layer's dict on the way in —
-        kv_cache.cache_write/attend dispatch on their presence — and
-        stripped on the way out so the scan stacks only real [L, ...]
-        leaves."""
+    def _slot_meta(self, cache, slot, gate) -> dict:
+        """Addressing entries that point the layer scan at ONE (shard-
+        local) slot — the B == 1 programs: a prefill chunk, the mixed
+        lane. Contiguous: the slot index, and ``gate`` (None = always
+        open) choosing row for row between the chunk's K/V and the bytes
+        already there. Paged: the slot's block-table row, which a closed
+        gate points at this shard's NULL scratch page."""
+        if self.kv_layout != "paged":
+            return {"slot": slot} if gate is None else {"slot": slot,
+                                                        "gate": gate}
+        meta = self._local_meta(cache)
+        row = lax.dynamic_slice_in_dim(meta["block_tables"], slot, 1,
+                                       axis=0)  # [1, max_pages]
+        if gate is not None:
+            row = jnp.where(gate, row, jnp.zeros_like(row))
+        return {**meta, "block_tables": row}
 
-        def body(hc, xs):
-            lp, lc = xs
-            if meta:
-                lc = {**lc, **meta}
-            hc, lc = llama.decoder_layer(lp, hc, cos_b, sin_b, self.cfg,
-                                         cache=lc, pos=pos)
-            if meta:
-                lc = {n: a for n, a in lc.items() if n not in meta}
-            return hc, lc
+    def _scan_layers(self, layers, cache, h, cos_b, sin_b, pos, meta):
+        """THE layer scan of every serving program: run ``h`` through the
+        stacked ``layers`` against the cache, return (h, updated stacked
+        leaves). The [L, ...] cache leaves ride the scan's CARRY beside
+        the residual stream and what the scan iterates over is the
+        stacked params and the layer INDEX — nothing cache-shaped is a
+        scan input or output, so XLA keeps the cache in the one buffer
+        the program was donated: kv_cache.cache_write scatters the new
+        rows into it at ``[layer, ...]`` and kv_cache.attend reads the
+        layer through the index. ``meta`` holds the per-dispatch
+        addressing entries (paged tables, ``draft_valid``, ``slot`` /
+        ``gate``) spliced into the dict each layer sees; they are not
+        leaves and never enter the carry."""
+        leaves = {n: a for n, a in cache.items()
+                  if n not in paged_kv.META_LEAVES}
+        index = jnp.arange(self.cfg.model.num_hidden_layers,
+                           dtype=jnp.int32)
 
-        return body
+        def body(carry, xs):
+            hc, lv = carry
+            lp, layer = xs
+            hc, out = llama.decoder_layer(lp, hc, cos_b, sin_b, self.cfg,
+                                          cache={**lv, **meta}, pos=pos,
+                                          layer=layer)
+            return (hc, {n: out[n] for n in lv}), None
+
+        (h, leaves), _ = lax.scan(body, (h, leaves), (layers, index))
+        return h, leaves
 
     def _rebuild(self, cache, new_leaves, lengths):
-        """Reassemble a cache pytree from updated per-layer leaves +
-        lengths, carrying the paged layout's metadata leaves through
-        unchanged (the HOST allocator owns them; device programs only
-        read)."""
+        """Reassemble a cache pytree from the layer scan's updated stacked
+        leaves + lengths, carrying the paged layout's metadata leaves
+        through unchanged (the HOST allocator owns them; device programs
+        only read)."""
         return {**new_leaves, **self._meta(cache), "lengths": lengths}
 
     def _model_block(self, params, cache, tokens, rows, pos,
@@ -941,7 +954,7 @@ class InferenceEngine:
         [B, S] at RoPE positions ``rows`` [B, S], scan the layer stack
         writing each slot's S new K/V rows from ``pos`` [B]
         (kv_cache.cache_write), attend causally over cache prefix + block,
-        and return (updated per-layer leaves, logits [B, S, V] fp32,
+        and return (updated stacked leaves, logits [B, S, V] fp32,
         pre-final-norm hidden states [B, S, H]). S == 1 is the decode
         step; S > 1 the speculative verify block. ``extra_meta`` rides
         into each layer's cache dict alongside the paged metadata (the
@@ -949,18 +962,15 @@ class InferenceEngine:
         advanced here — callers apply their own activity rule."""
         cos_b, sin_b = rope_at_positions(self._cos, self._sin, rows)
         h = llama.embed_lookup(params["embed"], tokens).astype(self._dt)
-        leaves, _ = self._split_cache(cache)
-        meta = self._local_meta(cache)
-        if extra_meta:
-            meta = {**meta, **extra_meta}
-        body = self._layer_body(cos_b, sin_b, pos, meta)
-        h, new_leaves = lax.scan(body, h, (params["layers"], leaves))
+        meta = {**self._local_meta(cache), **(extra_meta or {})}
+        h, new_leaves = self._scan_layers(params["layers"], cache, h,
+                                          cos_b, sin_b, pos, meta)
         logits = tp_gather(llama.head_logits(params, h, self.cfg))
         return new_leaves, logits.astype(jnp.float32), h
 
     def _decode_core(self, params, cache, tokens):
         """One model step for all slots: ``tokens`` [B] at each slot's own
-        ``cache['lengths']`` position -> (updated per-layer leaves,
+        ``cache['lengths']`` position -> (updated stacked leaves,
         logits [B, V] fp32, hidden [B, H])."""
         pos = cache["lengths"]  # [B] write index of the incoming token
         new_leaves, logits, h = self._model_block(
@@ -1225,82 +1235,30 @@ class InferenceEngine:
         top_k, top_p) and the second return is the sampled token [1]
         int32 instead — every chunk samples from the SAME key (cheap next
         to the model body) and only the final chunk's draw is consumed,
-        so no key is ever burned on an intermediate chunk."""
+        so no key is ever burned on an intermediate chunk.
+
+        Both layouts run this body (``_slot_meta`` is the difference): the
+        contiguous cache is addressed at ``[layer, slot, start..]``, the
+        paged pool through the slot's block-table row — which is also the
+        prefix-sharing resume path: with ``start`` past a cached prefix,
+        the chunk attends over SHARED pages it never computed."""
         cfg = self.cfg
         C = tokens.shape[1]
         start = jnp.asarray(start, jnp.int32)
         pos_rows = (start + jnp.arange(C, dtype=jnp.int32))[None, :]  # [1,C]
         cos_b, sin_b = rope_at_positions(self._cos, self._sin, pos_rows)
         h = llama.embed_lookup(params["embed"], tokens).astype(self._dt)
-        leaves, lengths = self._split_cache(cache)
+        lengths = cache["lengths"]
         pos = jnp.full((1,), start, jnp.int32)
         # dp > 1: every shard traces the same chunk, but only the slot's
-        # owner keeps its writes — non-owners slice a clipped local slot,
-        # discard the updated rows (write-back of the unchanged slice is a
-        # no-op), and contribute zeros to the logits psum below
+        # owner keeps its writes — non-owners address a clipped local
+        # slot and write its own bytes back row for row (contiguous), or
+        # scribble their NULL scratch page (paged); their reads never
+        # feed the result (logits psum-masked below, lengths untouched)
         loc, owner = self._slot_owner(slot)
-
-        def body(hc, xs):
-            lp, lc = xs
-            # this slot's [1, T, ...] block rows, updated then scattered back
-            slot_c = {n: lax.dynamic_slice_in_dim(a, loc, 1, axis=0)
-                      for n, a in lc.items()}
-            hc, slot_new = llama.decoder_layer(lp, hc, cos_b, sin_b, cfg,
-                                               cache=slot_c, pos=pos)
-            if owner is not None:
-                slot_new = {n: jnp.where(owner, slot_new[n], slot_c[n])
-                            for n in slot_new}
-            lc = {n: lax.dynamic_update_slice_in_dim(lc[n], slot_new[n],
-                                                     loc, axis=0)
-                  for n in lc}
-            return hc, lc
-
-        h, new_leaves = lax.scan(body, h, (params["layers"], leaves))
-        idx = jnp.clip(valid - 1, 0, C - 1)
-        h_last = jnp.take_along_axis(
-            h, jnp.full((1, 1, 1), idx, jnp.int32), axis=1)
-        last = tp_gather(llama.head_logits(params, h_last, cfg))[:, 0]
-        last = self._owner_reduce(last.astype(jnp.float32), owner)
-        new_lengths = lengths.at[loc].set(start + valid)
-        if owner is not None:
-            new_lengths = jnp.where(owner, new_lengths, lengths)
-        new_cache = {**new_leaves, "lengths": new_lengths}
-        out = self._epilogue(last, *sample) if self.sample_on_device \
-            else last
-        if self.return_hidden:
-            return new_cache, out, self._owner_reduce(h_last[:, 0], owner)
-        return new_cache, out
-
-    def _prefill_chunk_impl_paged(self, params, cache, tokens, slot, start,
-                                  valid, *sample):
-        """Paged counterpart of ``_prefill_chunk_impl``: the slot's pages
-        cannot be sliced out as a contiguous block, so the layer scan runs
-        against the whole pool with the slot's block-table row (B = 1) —
-        writes scatter through the row, attention gathers/walks it. Also
-        the prefix-sharing resume path: with ``start`` past a cached
-        prefix, the chunk attends over SHARED pages it never computed."""
-        cfg = self.cfg
-        C = tokens.shape[1]
-        start = jnp.asarray(start, jnp.int32)
-        pos_rows = (start + jnp.arange(C, dtype=jnp.int32))[None, :]
-        cos_b, sin_b = rope_at_positions(self._cos, self._sin, pos_rows)
-        h = llama.embed_lookup(params["embed"], tokens).astype(self._dt)
-        leaves, lengths = self._split_cache(cache)
-        # dp > 1: non-owner shards force their (clipped) table row to the
-        # local NULL page — their chunk writes scribble the shard's
-        # designated scratch page and their reads never feed the result
-        # (logits psum-masked below, write-back of pool pages goes through
-        # the row, and lengths stay untouched)
-        loc, owner = self._slot_owner(slot)
-        local_meta = self._local_meta(cache)
-        row = lax.dynamic_slice_in_dim(local_meta["block_tables"], loc, 1,
-                                       axis=0)  # [1, max_pages]
-        if owner is not None:
-            row = jnp.where(owner, row, jnp.zeros_like(row))
-        pos = jnp.full((1,), start, jnp.int32)
-        meta = {**local_meta, "block_tables": row}
-        body = self._layer_body(cos_b, sin_b, pos, meta)
-        h, new_leaves = lax.scan(body, h, (params["layers"], leaves))
+        h, new_leaves = self._scan_layers(
+            params["layers"], cache, h, cos_b, sin_b, pos,
+            self._slot_meta(cache, loc, owner))
         idx = jnp.clip(valid - 1, 0, C - 1)
         h_last = jnp.take_along_axis(
             h, jnp.full((1, 1, 1), idx, jnp.int32), axis=1)
@@ -1369,34 +1327,11 @@ class InferenceEngine:
         cos_b, sin_b = rope_at_positions(self._cos, self._sin, pos_rows)
         h = llama.embed_lookup(lane_params["embed"],
                                tokens).astype(self._dt)
-        leaves, lengths = self._split_cache(cache)
+        lengths = cache["lengths"]
         pos = jnp.full((1,), start_i, jnp.int32)
-        if self.kv_layout == "paged":
-            local_meta = self._local_meta(cache)
-            row = lax.dynamic_slice_in_dim(local_meta["block_tables"],
-                                           slot_i, 1, axis=0)
-            # idle lane scribbles this shard's NULL scratch page
-            row = jnp.where(active, row, jnp.zeros_like(row))
-            meta = {**local_meta, "block_tables": row}
-            body = self._layer_body(cos_b, sin_b, pos, meta)
-            h, new_leaves = lax.scan(body, h,
-                                     (lane_params["layers"], leaves))
-        else:
-            def body(hc, xs):
-                lp, lc = xs
-                slot_c = {n: lax.dynamic_slice_in_dim(a, slot_i, 1, axis=0)
-                          for n, a in lc.items()}
-                hc, slot_new = llama.decoder_layer(lp, hc, cos_b, sin_b,
-                                                   cfg, cache=slot_c,
-                                                   pos=pos)
-                slot_new = {n: jnp.where(active, slot_new[n], slot_c[n])
-                            for n in slot_new}
-                lc = {n: lax.dynamic_update_slice_in_dim(
-                    lc[n], slot_new[n], slot_i, axis=0) for n in lc}
-                return hc, lc
-
-            h, new_leaves = lax.scan(body, h,
-                                     (lane_params["layers"], leaves))
+        h, new_leaves = self._scan_layers(
+            lane_params["layers"], cache, h, cos_b, sin_b, pos,
+            self._slot_meta(cache, slot_i, active))
         idx = jnp.clip(valid_i - 1, 0, C - 1)
         h_last = jnp.take_along_axis(
             h, jnp.full((1, 1, 1), idx, jnp.int32), axis=1)
@@ -1569,8 +1504,16 @@ class InferenceEngine:
         ([slots, max_pages] int32) and unconditional — simpler than dirty
         tracking and invisible next to a model dispatch. hot_bf16 policy
         engines refresh the per-page read flags from live refcounts in
-        the same breath, so sharing changes take effect next dispatch."""
-        out = {**cache, "block_tables": jnp.asarray(self.paged.tables)}
+        the same breath, so sharing changes take effect next dispatch.
+
+        The master is COPIED before it is handed over: the CPU backend
+        takes an aligned numpy array without copying it, dispatches run
+        asynchronously, and the allocator mutates its master in place —
+        an aliased table let a still-queued insert see the NEXT dispatch's
+        copy-on-write and park a prompt in the wrong page (the one-run-in-
+        ten failure of test_ragged_verify_matches_per_slot_sequential)."""
+        out = {**cache,
+               "block_tables": jnp.asarray(self.paged.tables.copy())}
         if self.page_policy:
             out["page_quant"] = jnp.asarray(self.paged.quant_flags())
         return out

@@ -8,10 +8,15 @@ row per sequence:
 
 - ``k``/``v``: ``[num_layers, slots, max_seq_len, n_kv_heads, head_dim]``.
   The layer axis leads (rather than the naive ``[batch, layers, ...]``
-  ordering) so the decode step's ``lax.scan`` over the stacked layer axis
-  consumes the cache exactly the way it consumes the stacked params; within
-  a layer a block is ``[B, T, H, D]`` — the layout ``ops/attention.py``
-  already uses. Heads are the COMPACT GQA count (``num_key_value_heads``,
+  ordering) so one layer is one contiguous ``[B, T, H, D]`` block — the
+  layout ``ops/attention.py`` already uses — that a layer index addresses.
+  The cache NEVER LEAVES ITS BUFFER inside a serving program: the stacked
+  leaves ride the engine's layer scan as CARRY (the layer index is what
+  the scan iterates over), ``cache_write`` scatters only the new rows at
+  ``[layer, slot, pos]`` and ``attend`` reads the layer through that
+  index, so a decode step moves one row per slot and reads the window
+  once — no per-layer slice out, no write-back, no per-step copy of the
+  cache. Heads are the COMPACT GQA count (``num_key_value_heads``,
   never repeated): repetition happens inside ``decode_attention`` via a
   grouped einsum, so GQA models pay ``Hkv/Hq`` of the naive cache bytes.
 - ``lengths``: ``[slots]`` int32 — each sequence's write index (= tokens
@@ -123,21 +128,38 @@ def quantized(cache: dict) -> bool:
 
 
 # --------------------------------------------------------------------------- #
-# per-layer cache ops (run inside the engine's layer scan / shard_map)
+# the write/attend seam (runs inside the engine's layer scan / shard_map)
 # --------------------------------------------------------------------------- #
+#
+# Both take the cache dict the engine hands ``llama.decoder_layer``: the
+# STACKED storage leaves ([L, ...], carried through the layer scan, never
+# sliced out of it) plus a ``layer`` index, and whatever per-dispatch
+# addressing entries the program spliced in — none of them a stored leaf:
+# ``block_tables``/``page_quant`` (paged layout), ``draft_valid`` (ragged
+# verify), ``slot`` (the one slot a B == 1 block addresses in a many-slot
+# contiguous cache) and ``gate`` (a bool scalar: keep this block's rows or
+# leave the bytes as they were).
 
 
-def cache_write(layer_cache: dict, k_new: jnp.ndarray, v_new: jnp.ndarray,
-                pos: jnp.ndarray) -> dict:
-    """Write fresh K/V rows into one layer's cache block and return the
-    updated block. Three shapes of write:
+def cache_write(cache: dict, k_new: jnp.ndarray, v_new: jnp.ndarray,
+                pos: jnp.ndarray, layer) -> dict:
+    """Write fresh K/V rows into ``layer`` of the stacked cache leaves, in
+    place: only the new rows move (a scatter or a ``dynamic_update_slice``
+    on the carried buffer — the layer's block is never sliced out and
+    written back). Returns the dict with the updated leaves. Three shapes
+    of write:
 
     - decode (``S == 1``): ``k_new``/``v_new`` [B, 1, H, D] with ``pos``
       [B] — every slot writes one row at its own position (a per-row
       scatter; free slots write their invisible row 0);
-    - chunked prefill (``S > 1``, ``B == 1``): [1, S, H, D] with ``pos``
-      [1] — one slot writes a contiguous block of rows starting at
-      ``pos[0]``;
+    - one slot's block (``B == 1`` and ``S > 1``, or a ``slot`` / ``gate``
+      entry at any ``S`` — a prefill chunk, the mixed lane): [1, S, H, D]
+      with ``pos`` [1] — the slot (``cache["slot"]``, default 0) writes a
+      contiguous block of rows starting at ``pos[0]``. A ``gate`` entry
+      (the dp owner / idle-lane gate) selects, row for row, between the
+      new rows and the bytes already there. What picks this shape is the
+      ADDRESSING, not the width: a chunk of one token still lands in its
+      own slot and still honours its gate;
     - speculative verify (``S > 1``, ``B > 1``): [B, S, H, D] with ``pos``
       [B] — EVERY slot writes S contiguous rows starting at its own
       position (engine._verify_impl's optimistic draft write). Rows past
@@ -152,70 +174,83 @@ def cache_write(layer_cache: dict, k_new: jnp.ndarray, v_new: jnp.ndarray,
     positions in ``k_scale``/``v_scale``.
 
     RAGGED verify (the per-slot spec_len controller): a ``draft_valid``
-    [B] int32 entry in ``layer_cache`` (spliced per dispatch by
-    engine._verify_impl, never a stored leaf) caps each slot's write at
-    its own count of REAL fed tokens — rows at or past it are redirected
-    out of the window and DROP under jax's out-of-bounds scatter
-    semantics, so a short-drafting slot never parks another slot's pad
-    junk. Only the batched scatter honors it (the verify shape); the
-    B == 1 dynamic-slice branch writes its whole block as before (a
-    one-slot verify's pad rows land beyond the post-acceptance length,
-    stale and unreachable — the pre-ragged contract).
+    [B] int32 entry (spliced per dispatch by engine._verify_impl) caps
+    each slot's write at its own count of REAL fed tokens — rows at or
+    past it are redirected out of the window and DROP under jax's
+    out-of-bounds scatter semantics, so a short-drafting slot never parks
+    another slot's pad junk. Only the batched scatter honors it (the
+    verify shape); the B == 1 block branch writes its whole block as
+    before (a one-slot verify's pad rows land beyond the post-acceptance
+    length, stale and unreachable — the pre-ragged contract).
 
-    Paged caches (``inference.kv_layout: "paged"`` — the per-layer dict
-    carries ``block_tables``) route to the page-indirect scatter
+    Paged caches (``inference.kv_layout: "paged"`` — the dict carries
+    ``block_tables``) route to the page-indirect scatter
     (inference/paged_kv.py): same three write shapes, rows land in pool
     pages instead of a contiguous strip (ragged rows hit the NULL page).
     """
-    if "block_tables" in layer_cache:
+    if "block_tables" in cache:
         from picotron_tpu.inference import paged_kv
 
-        return paged_kv.cache_write(layer_cache, k_new, v_new, pos)
-    out = dict(layer_cache)
+        return paged_kv.cache_write(cache, k_new, v_new, pos, layer)
+    out = dict(cache)
     valid = out.pop("draft_valid", None)
+    slot, gate = cache.get("slot", 0), cache.get("gate")
+    layer = jnp.asarray(layer, jnp.int32)
     B, S = k_new.shape[0], k_new.shape[1]
-    T = layer_cache["k"].shape[1]
+    T = cache["k"].shape[2]
+    one_slot = "slot" in cache or gate is not None or (B == 1 and S > 1)
 
-    def store(name, sname, new):
-        if quantized(layer_cache):
-            vals, scales = quantize_kv(new)
-        else:
-            vals, scales = new.astype(layer_cache[name].dtype), None
+    def put(name, vals):
+        leaf = cache[name]
+        vals = vals.astype(leaf.dtype)
+        if one_slot:
+            at = (layer, jnp.asarray(slot, jnp.int32),
+                  jnp.asarray(pos[0], jnp.int32))
+            at += (jnp.zeros((), jnp.int32),) * (leaf.ndim - len(at))
+            vals = vals[None]
+            if gate is not None:
+                vals = jnp.where(gate, vals,
+                                 lax.dynamic_slice(leaf, at, vals.shape))
+            return lax.dynamic_update_slice(leaf, vals, at)
         if S == 1:
-            rows = jnp.arange(B)
-            out[name] = layer_cache[name].at[rows, pos].set(vals[:, 0])
-            if scales is not None:
-                out[sname] = layer_cache[sname].at[rows, pos].set(
-                    scales[:, 0].astype(SCALE_DTYPE))
-        elif B == 1:
-            start = jnp.asarray(pos[0], jnp.int32)
-            out[name] = lax.dynamic_update_slice(
-                layer_cache[name], vals, (0, start, 0, 0))
-            if scales is not None:
-                out[sname] = lax.dynamic_update_slice(
-                    layer_cache[sname], scales.astype(SCALE_DTYPE),
-                    (0, start, 0))
-        else:
-            rows = pos[:, None] + jnp.arange(S, dtype=pos.dtype)[None, :]
-            if valid is not None:
-                # ragged mask: rows past the slot's own real-token count
-                # go out of bounds, where the scatter drops them
-                cols = jnp.arange(S, dtype=jnp.int32)[None, :]
-                rows = jnp.where(cols < valid[:, None], rows, T)
-            bidx = jnp.arange(B)[:, None]
-            out[name] = layer_cache[name].at[bidx, rows].set(vals)
-            if scales is not None:
-                out[sname] = layer_cache[sname].at[bidx, rows].set(
-                    scales.astype(SCALE_DTYPE))
+            return leaf.at[layer, jnp.arange(B), pos].set(vals[:, 0])
+        rows = pos[:, None] + jnp.arange(S, dtype=pos.dtype)[None, :]
+        if valid is not None:
+            # ragged mask: rows past the slot's own real-token count
+            # go out of bounds, where the scatter drops them
+            cols = jnp.arange(S, dtype=jnp.int32)[None, :]
+            rows = jnp.where(cols < valid[:, None], rows, T)
+        return leaf.at[layer, jnp.arange(B)[:, None], rows].set(vals)
 
-    store("k", "k_scale", k_new)
-    store("v", "v_scale", v_new)
+    for name, sname, new in (("k", "k_scale", k_new), ("v", "v_scale", v_new)):
+        if quantized(cache):
+            vals, scales = quantize_kv(new)
+            out[sname] = put(sname, scales)
+        else:
+            vals = new
+        out[name] = put(name, vals)
     return out
 
 
-def attend(q: jnp.ndarray, layer_cache: dict, lengths: jnp.ndarray,
-           scale: float, impl: str = "dense") -> jnp.ndarray:
-    """Masked attention of S fresh queries against one layer's cache block.
+def layer_block(cache: dict, name: str, layer):
+    """One layer's [B, T, ...] view of stacked leaf ``name`` — a read
+    through an index, which the compiler fuses into whatever consumes it
+    (the score and value contractions, the int8 dequantize); with a
+    ``slot`` entry, that one slot's [1, T, ...] strip. None when the cache
+    has no such leaf (the scales of an unquantized cache)."""
+    leaf = cache.get(name)
+    if leaf is None:
+        return None
+    leaf = lax.dynamic_index_in_dim(leaf, layer, 0, keepdims=False)
+    if "slot" in cache:
+        leaf = lax.dynamic_slice_in_dim(leaf, cache["slot"], 1, axis=0)
+    return leaf
+
+
+def attend(q: jnp.ndarray, cache: dict, lengths: jnp.ndarray,
+           scale: float, layer, impl: str = "dense") -> jnp.ndarray:
+    """Masked attention of S fresh queries against ``layer`` of the
+    stacked cache leaves (``layer_block``: read where it lies).
 
     ``impl`` picks the kernel (config ``inference.attend_impl``):
 
@@ -231,17 +266,20 @@ def attend(q: jnp.ndarray, layer_cache: dict, lengths: jnp.ndarray,
       chunked-prefill query windows split over a q-block grid axis
       (flash_attention's causal block-skip bounds each tile's walk).
       Runs in interpret mode off TPU; allclose-pinned against dense
-      (tests/test_decode_kernel.py).
+      (tests/test_decode_kernel.py). The kernel takes one layer's block,
+      so this path hands it the sliced layer.
 
-    Paged caches (the per-layer dict carries ``block_tables``) route to
-    the page-indirect attends (inference/paged_kv.py): dense gathers the
+    Paged caches (the dict carries ``block_tables``) route to the
+    page-indirect attends (inference/paged_kv.py): dense gathers the
     slots' pages into a contiguous window and runs the same masked
     einsum; flash walks the block table page by page in the kernel.
     """
-    if "block_tables" in layer_cache:
+    if "block_tables" in cache:
         from picotron_tpu.inference import paged_kv
 
-        return paged_kv.attend(q, layer_cache, lengths, scale, impl)
+        return paged_kv.attend(q, cache, lengths, scale, layer, impl)
+    k, v, k_scale, v_scale = (layer_block(cache, n, layer)
+                              for n in ("k", "v", "k_scale", "v_scale"))
     if impl == "flash":
         from picotron_tpu.ops.pallas.decode_attention import (
             flash_decode_attention,
@@ -249,20 +287,14 @@ def attend(q: jnp.ndarray, layer_cache: dict, lengths: jnp.ndarray,
         from picotron_tpu.utils import on_tpu
 
         return flash_decode_attention(
-            q, layer_cache["k"], layer_cache["v"], lengths, scale,
-            k_scale=layer_cache.get("k_scale"),
-            v_scale=layer_cache.get("v_scale"),
+            q, k, v, lengths, scale, k_scale=k_scale, v_scale=v_scale,
             interpret=not on_tpu())
     if impl != "dense":
         # a typo'd impl must not silently measure the wrong kernel
         raise ValueError(f"unknown attend impl {impl!r} (dense|flash)")
-    if quantized(layer_cache):
-        k = dequantize_kv(layer_cache["k"], layer_cache["k_scale"],
-                          jnp.float32)
-        v = dequantize_kv(layer_cache["v"], layer_cache["v_scale"],
-                          jnp.float32)
-    else:
-        k, v = layer_cache["k"], layer_cache["v"]
+    if quantized(cache):
+        k = dequantize_kv(k, k_scale, jnp.float32)
+        v = dequantize_kv(v, v_scale, jnp.float32)
     return decode_attention(q, k, v, lengths, scale)
 
 

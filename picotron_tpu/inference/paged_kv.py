@@ -171,10 +171,11 @@ def _targets(bt: jnp.ndarray, rows: jnp.ndarray, page_len: int):
     return pid, off
 
 
-def cache_write(layer_cache: dict, k_new: jnp.ndarray, v_new: jnp.ndarray,
-                pos: jnp.ndarray) -> dict:
+def cache_write(cache: dict, k_new: jnp.ndarray, v_new: jnp.ndarray,
+                pos: jnp.ndarray, layer) -> dict:
     """Paged counterpart of ``kv_cache.cache_write``: scatter each slot's
-    S fresh rows through its block-table row. One generic gather+scatter
+    S fresh rows through its block-table row into ``layer`` of the stacked
+    pool leaves, in place. One generic gather+scatter
     serves all three write shapes (decode S=1, verify B>1 S>1, chunked
     prefill B=1 S=C) — row ``pos[b] + s`` lands in pool page
     ``bt[b, (pos+s) // page_len]`` at offset ``(pos+s) % page_len``.
@@ -190,21 +191,25 @@ def cache_write(layer_cache: dict, k_new: jnp.ndarray, v_new: jnp.ndarray,
     its own real-token count: masked rows are pushed out of the logical
     window, which ``_targets`` routes to the NULL page.
     """
-    out = dict(layer_cache)
+    out = dict(cache)
     valid = out.pop("draft_valid", None)
+    layer = jnp.asarray(layer, jnp.int32)
     B, S = k_new.shape[0], k_new.shape[1]
-    bt = layer_cache["block_tables"]  # [B, max_pages] int32
-    page_len = layer_cache["k"].shape[1]
+    bt = cache["block_tables"]  # [B, max_pages] int32
+    page_len = cache["k"].shape[2]
     rows = pos[:, None].astype(jnp.int32) + jnp.arange(S, dtype=jnp.int32)
     if valid is not None and S > 1:
         cols = jnp.arange(S, dtype=jnp.int32)[None, :]
         rows = jnp.where(cols < valid[:, None], rows,
                          bt.shape[-1] * page_len)
-    pid, off = _targets(bt, rows, page_len)  # [B, S] each
-    policy = is_policy(layer_cache)
+    at = (layer,) + _targets(bt, rows, page_len)  # (layer, pid, off)
 
-    def store(name, qname, sname, new):
-        if policy:
+    def put(name, vals):
+        out[name] = cache[name].at[at].set(vals.astype(cache[name].dtype))
+
+    for name, qname, sname, new in (("k", "k_q", "k_scale", k_new),
+                                    ("v", "v_q", "v_scale", v_new)):
+        if is_policy(cache):
             # hot_bf16 dual write: the fresh rows land in BOTH pool
             # representations (full precision + int8 with scales), so the
             # per-page flag can flip as sharing changes without rewriting
@@ -212,96 +217,97 @@ def cache_write(layer_cache: dict, k_new: jnp.ndarray, v_new: jnp.ndarray,
             # traffic is S rows per dispatch, noise next to the attend's
             # window read the policy halves.
             qvals, scales = kv_cache.quantize_kv(new)
-            out[name] = layer_cache[name].at[pid, off].set(
-                new.astype(layer_cache[name].dtype))
-            out[qname] = layer_cache[qname].at[pid, off].set(qvals)
-            out[sname] = layer_cache[sname].at[pid, off].set(
-                scales.astype(kv_cache.SCALE_DTYPE))
-            return
-        if kv_cache.quantized(layer_cache):
+            put(name, new)
+            put(qname, qvals)
+            put(sname, scales)
+        elif kv_cache.quantized(cache):
             vals, scales = kv_cache.quantize_kv(new)
+            put(name, vals)
+            put(sname, scales)
         else:
-            vals, scales = new.astype(layer_cache[name].dtype), None
-        out[name] = layer_cache[name].at[pid, off].set(vals)
-        if scales is not None:
-            out[sname] = layer_cache[sname].at[pid, off].set(
-                scales.astype(kv_cache.SCALE_DTYPE))
-
-    store("k", "k_q", "k_scale", k_new)
-    store("v", "v_q", "v_scale", v_new)
+            put(name, new)
     return out
 
 
-def gather_window(pool: jnp.ndarray, bt: jnp.ndarray) -> jnp.ndarray:
-    """Materialize slots' logical windows from the pool: ``pool``
-    [P, page_len, ...] + ``bt`` [B, max_pages] -> [B, max_pages *
-    page_len, ...] — the contiguous view the dense reference attend
-    consumes. (The flash kernel never materializes this; it walks the
-    table page by page.)"""
-    g = pool[bt]  # [B, max_pages, page_len, ...]
-    return g.reshape((bt.shape[0], -1) + pool.shape[2:])
+def gather_window(pool: jnp.ndarray, bt: jnp.ndarray,
+                  layer) -> jnp.ndarray:
+    """Materialize slots' logical windows from ``layer`` of the stacked
+    pool: ``pool`` [L, P, page_len, ...] + ``bt`` [B, max_pages] ->
+    [B, max_pages * page_len, ...] — the contiguous view the dense
+    reference attend consumes. The pages are gathered by ONE flat index
+    ``layer * P + page`` over the pool seen as [L * P, page_len, ...] (a
+    free reshape of the leading axes): the only form of three the chip's
+    compiler leaves alone at every query width — slicing the layer first
+    lands it in a buffer of its own each layer, and a two-coordinate
+    gather of the stacked pool is re-laid out whole under a wide query
+    window (PERF.md, PR 26). (The flash kernel never materializes this;
+    it walks the table page by page.)"""
+    L, P = pool.shape[:2]
+    flat = pool.reshape((L * P,) + pool.shape[2:])
+    g = flat[jnp.asarray(layer, jnp.int32) * P + bt]
+    return g.reshape((bt.shape[0], -1) + g.shape[3:])
 
 
-def attend(q: jnp.ndarray, layer_cache: dict, lengths: jnp.ndarray,
-           scale: float, impl: str = "dense") -> jnp.ndarray:
-    """Masked attention of S fresh queries against one layer's paged
+def attend(q: jnp.ndarray, cache: dict, lengths: jnp.ndarray,
+           scale: float, layer, impl: str = "dense") -> jnp.ndarray:
+    """Masked attention of S fresh queries against ``layer`` of the paged
     cache. "dense" gathers the slots' pages into a contiguous window and
     runs the bit-pinned ``kv_cache.decode_attention`` (int8 first
     dequantizes the gathered window to fp32, the same reference
-    discipline as contiguous dense); "flash" hands the pool + block
-    tables to the Pallas kernel, which DMAs pages straight from HBM —
-    no gathered window ever exists on that path."""
-    bt = layer_cache["block_tables"]
-    policy = is_policy(layer_cache)
+    discipline as contiguous dense); "flash" hands the layer's pool +
+    block tables to the Pallas kernel, which DMAs pages straight from HBM
+    — no gathered window ever exists on that path."""
+    bt = cache["block_tables"]
+    policy = is_policy(cache)
     if impl == "flash":
         from picotron_tpu.ops.pallas.decode_attention import (
             flash_decode_attention,
         )
         from picotron_tpu.utils import on_tpu
 
+        def pool(name):
+            return kv_cache.layer_block(cache, name, layer)
+
         if policy:
             # mixed-precision page read: the per-page flag — gathered
             # through the block table into [B, max_pages] SMEM rows —
             # decides which pool representation each page's DMA fetches
             return flash_decode_attention(
-                q, layer_cache["k"], layer_cache["v"], lengths, scale,
-                k_quant=layer_cache["k_q"], v_quant=layer_cache["v_q"],
-                k_scale=layer_cache["k_scale"],
-                v_scale=layer_cache["v_scale"],
+                q, pool("k"), pool("v"), lengths, scale,
+                k_quant=pool("k_q"), v_quant=pool("v_q"),
+                k_scale=pool("k_scale"), v_scale=pool("v_scale"),
                 block_tables=bt,
-                block_quant=jnp.take(layer_cache["page_quant"], bt, axis=0),
+                block_quant=jnp.take(cache["page_quant"], bt, axis=0),
                 interpret=not on_tpu())
         return flash_decode_attention(
-            q, layer_cache["k"], layer_cache["v"], lengths, scale,
-            k_scale=layer_cache.get("k_scale"),
-            v_scale=layer_cache.get("v_scale"),
+            q, pool("k"), pool("v"), lengths, scale,
+            k_scale=pool("k_scale"), v_scale=pool("v_scale"),
             block_tables=bt, interpret=not on_tpu())
     if impl != "dense":
         raise ValueError(f"unknown attend impl {impl!r} (dense|flash)")
-    k = gather_window(layer_cache["k"], bt)
-    v = gather_window(layer_cache["v"], bt)
+
+    def window(name):
+        return gather_window(cache[name], bt, layer)
+
+    k, v = window("k"), window("v")
     if policy:
         # mixed dense read (the bit-pinned reference for the flash DMA
         # path above): gather both representations' windows, dequantize
         # the int8 one, and select per page — rows of a flagged page come
         # from the quantized bytes, exactly what the kernel DMAs
-        page_len = layer_cache["k"].shape[1]
-        flags = jnp.repeat(jnp.take(layer_cache["page_quant"], bt, axis=0),
+        page_len = k.shape[1] // bt.shape[1]
+        flags = jnp.repeat(jnp.take(cache["page_quant"], bt, axis=0),
                            page_len, axis=1)  # [B, max_pages*page_len]
         quant = (flags != 0)[..., None, None]
-        kq = kv_cache.dequantize_kv(
-            gather_window(layer_cache["k_q"], bt),
-            gather_window(layer_cache["k_scale"], bt), jnp.float32)
-        vq = kv_cache.dequantize_kv(
-            gather_window(layer_cache["v_q"], bt),
-            gather_window(layer_cache["v_scale"], bt), jnp.float32)
+        kq = kv_cache.dequantize_kv(window("k_q"), window("k_scale"),
+                                    jnp.float32)
+        vq = kv_cache.dequantize_kv(window("v_q"), window("v_scale"),
+                                    jnp.float32)
         k = jnp.where(quant, kq, k.astype(jnp.float32))
         v = jnp.where(quant, vq, v.astype(jnp.float32))
-    elif kv_cache.quantized(layer_cache):
-        k = kv_cache.dequantize_kv(
-            k, gather_window(layer_cache["k_scale"], bt), jnp.float32)
-        v = kv_cache.dequantize_kv(
-            v, gather_window(layer_cache["v_scale"], bt), jnp.float32)
+    elif kv_cache.quantized(cache):
+        k = kv_cache.dequantize_kv(k, window("k_scale"), jnp.float32)
+        v = kv_cache.dequantize_kv(v, window("v_scale"), jnp.float32)
     return kv_cache.decode_attention(q, k, v, lengths, scale)
 
 

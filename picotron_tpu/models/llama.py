@@ -447,15 +447,16 @@ def embed_lookup(w, tokens, sp: bool = False):
     return sp_scatter(e) if sp else tp_reduce(e)
 
 
-def _attention(q, k, v, cfg: Config, cache=None, pos=None):
+def _attention(q, k, v, cfg: Config, cache=None, pos=None, layer=None):
     """Full-sequence attention (training / prefill), or — when ``cache`` is
-    given — the incremental decode path: ``cache`` is this layer's UPDATED
-    cache block dict (``{"k","v"[, "k_scale","v_scale"]}``, each
-    [B, max_len, n_kv_local, ...] with compact GQA heads, never repeated)
-    and ``pos`` [B] is the first index just written per sequence; the
-    ``k``/``v`` positional args are ignored. The decode kernel is a masked
-    dot product over the cache (inference/kv_cache.py) — flash brings
-    nothing at query length 1.
+    given — the incremental decode path: ``cache`` is the UPDATED stacked
+    cache dict (``{"k","v"[, "k_scale","v_scale"]}``, each
+    [L, B, max_len, n_kv_local, ...] with compact GQA heads, never
+    repeated), ``layer`` the index of the layer to read and ``pos`` [B] the
+    first index just written per sequence; the ``k``/``v`` positional args
+    are ignored. The decode kernel is a masked dot product over the
+    layer's block, read where it lies (inference/kv_cache.py) — flash
+    brings nothing at query length 1.
     """
     scale = 1.0 / math.sqrt(cfg.model.head_dim)
     if cache is not None:
@@ -469,7 +470,7 @@ def _attention(q, k, v, cfg: Config, cache=None, pos=None):
         # dequantizes whole blocks on the fly). The impl string is a Python
         # value, so each choice traces its own program under jit.
         return attend(q, cache, pos + q.shape[1], scale,
-                      impl=cfg.inference.attend_impl)
+                      impl=cfg.inference.attend_impl, layer=layer)
     impl = cfg.model.attention_impl
     if impl == "auto":
         impl = "flash" if on_tpu() else "sdpa"
@@ -511,7 +512,7 @@ def _norm(x, w, cfg: Config):
 
 
 def decoder_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
-                  return_kv: bool = False):
+                  return_kv: bool = False, layer=None):
     """One decoder block with per-shard head counts (model.py:94-97,187-208).
 
     With sequence parallelism the residual stream ``h`` is seq-sharded over
@@ -524,11 +525,13 @@ def decoder_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
       but the layer also returns its compact pre-repeat rotated K/V block
       [B, S, n_kv_local, head_dim] for the caller to park in a KV cache —
       return value becomes ``(h, (k, v))``.
-    - ``cache={"k","v"[,"k_scale","v_scale"]}`` + ``pos`` [B] (decode /
-      chunked prefill / speculative verify): the new tokens' K/V are
-      written into the per-layer cache block starting at each sequence's
-      ``pos`` (int8 caches quantize on write — kv_cache.cache_write) and
-      attention runs as a masked dot product over the cache
+    - ``cache={"k","v"[,"k_scale","v_scale"]}`` + ``layer`` + ``pos`` [B]
+      (decode / chunked prefill / speculative verify): ``cache`` holds the
+      STACKED [L, ...] leaves the engine's layer scan carries and ``layer``
+      is this layer's index into them. The new tokens' K/V rows are written in place at
+      ``[layer, slot, pos..]`` (int8 caches quantize on write —
+      kv_cache.cache_write) and attention runs as a masked dot product
+      over that layer of the cache, read through the index
       (``_attention``'s decode path); ``cos``/``sin`` must then be the
       per-sequence [B, S, head_dim] tables from
       ``ops.rope.rope_at_positions``. S == 1 is the per-slot decode step;
@@ -575,11 +578,12 @@ def decoder_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
         # (S > 1, one slot's contiguous block), or speculative verify
         # (S > 1, every slot's contiguous block): write the fresh K/V at
         # each sequence's position (quantizing for int8 caches), attend
-        # over the whole cache block
+        # over this layer of the cache
         from picotron_tpu.inference.kv_cache import cache_write
 
-        new_cache = cache_write(cache, k, v, pos)
-        o = _attention(q, None, None, cfg, cache=new_cache, pos=pos)
+        new_cache = cache_write(cache, k, v, pos, layer)
+        o = _attention(q, None, None, cfg, cache=new_cache, pos=pos,
+                       layer=layer)
     else:
         kv_compact = (k, v)  # pre-repeat: what a prefill parks in the cache
         cp, cp_impl = cfg.distributed.cp_size, cfg.distributed.cp_impl

@@ -1,5 +1,7 @@
 """Compile every main-path Pallas kernel for a described v5e at SmolLM-1.7B
-widths (H 2048, 32/32 heads of 64, FFN 8192, vocab 49152, seq 2048, bf16).
+widths (H 2048, 32/32 heads of 64, FFN 8192, vocab 49152, seq 2048, bf16),
+and the serving programs themselves, to read off the compiled text that the
+KV cache never leaves its buffer inside them.
 
 No chip is needed and nothing runs: the TPU compiler installed with jax
 compiles for a ``v5e:2x2`` that is described, not attached, and raises what
@@ -14,7 +16,9 @@ collects the same tests and only the worker given this file loads the TPU
 library. Keep these compiles in this ONE file.
 """
 
+import math
 import os
+import re
 
 import pytest
 
@@ -168,3 +172,156 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), \
         f"{case}: the compiled program holds no Pallas kernel"
+
+
+# --------------------------------------------------------------------------- #
+# the serving programs: the KV cache never leaves its buffer
+# --------------------------------------------------------------------------- #
+#
+# Compiled for the chip, a cache leaf fed through a ``lax.scan`` as xs/ys
+# becomes, per layer, a fusion that slices the layer out into a buffer of
+# its own and one that writes the whole layer back, and per decode step a
+# copy of the whole stacked array into the step loop's carry (PERF.md,
+# PR 26: 56 % of SmolLM's decode step). The engine keeps the leaves in the
+# scan's carry and indexes them instead; these tests read the compiled
+# text and the compiler's own byte counts to hold it to that.
+
+SERVE_LAYERS, SERVE_SLOTS = 2, 4
+SERVE_TAIL = (HEADS, D)
+_SHAPE = re.compile(r"[a-z]+\d*\[([\d,]*)\]")
+_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$")
+_COMP = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$")
+_CALLED = re.compile(r"(?:body|condition|to_apply|calls)=%([\w.\-]+)")
+
+
+def _elems(shape: str, tail=SERVE_TAIL) -> int:
+    """Elements of the largest K/V-shaped array (dims end in ``tail``:
+    kv heads, head size) in an HLO shape string; a tuple has several."""
+    best = 0
+    for dims in _SHAPE.findall(shape):
+        dims = tuple(int(d) for d in dims.split(",") if d)
+        if dims[-len(tail):] == tail:
+            best = max(best, math.prod(dims))
+    return best
+
+
+def _computations(text: str) -> dict:
+    """HLO text -> {computation: [(name, shape, op, rest of the line)]}."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = _COMP.match(line)
+        if head:
+            cur = comps[head.group(1)] = []
+        elif cur is not None and _INSTR.match(line):
+            cur.append(_INSTR.match(line).groups())
+    return comps
+
+
+def _cache_movers(text: str, layer_elems: int) -> list:
+    """Instructions that run inside a loop of the program and move
+    ``layer_elems`` elements of K or V or more: a ``copy``, a (fused)
+    ``dynamic-slice`` that lands them in a buffer of their own, a (fused)
+    ``dynamic-update-slice`` whose update they are. A fusion that READS a
+    layer through a dynamic-slice and reduces it (the attention
+    contractions), or scatters a few rows into the stacked array in place,
+    is what the program should be made of and is not listed."""
+    comps = _computations(text)
+    shapes = {c: {n: sh for n, sh, _, _ in ins} for c, ins in comps.items()}
+
+    def update_elems(comp, rest):
+        # dynamic-update-slice(%operand, %update, %idx...): the update
+        ops = re.findall(r"%([\w.\-]+)", rest)
+        return _elems(shapes[comp].get(ops[1], "")) if len(ops) > 1 else 0
+
+    def moves(comp, name, shape, op, rest):
+        if op in ("copy", "dynamic-slice"):
+            return _elems(shape) >= layer_elems
+        if op == "dynamic-update-slice":
+            return update_elems(comp, rest) >= layer_elems
+        if op == "fusion":
+            inner = _CALLED.search(rest).group(1)
+            for n, sh, iop, irest in comps.get(inner, []):
+                if iop == "dynamic-slice" and _elems(shape) >= layer_elems \
+                        and _elems(sh) >= layer_elems:
+                    return True
+                if iop == "dynamic-update-slice" \
+                        and update_elems(inner, irest) >= layer_elems:
+                    return True
+        return False
+
+    # every computation a while loop runs, through calls and nested loops
+    # but not into fusions (a fusion's inside never touches memory itself)
+    todo = [c for ins in comps.values() for _, _, op, rest in ins
+            if op == "while" for c in _CALLED.findall(rest)]
+    looped = set()
+    while todo:
+        c = todo.pop()
+        if c in looped or c not in comps:
+            continue
+        looped.add(c)
+        todo += [x for _, _, op, rest in comps[c] if op != "fusion"
+                 for x in _CALLED.findall(rest)]
+    return [f"{c}: %{name} = {shape} {op}"
+            for c in sorted(looped) for name, shape, op, rest in comps[c]
+            if moves(c, name, shape, op, rest)]
+
+
+def _serving_program(topo, prog, layout):
+    """(lowered-and-compiled ``prog`` of a SERVE_LAYERS-layer engine at
+    SmolLM's head geometry on one described chip, elements of one layer's
+    K, bytes of the lane-padded cache)."""
+    from picotron_tpu.config import Config
+    from picotron_tpu.inference.engine import InferenceEngine
+    from picotron_tpu.models import llama
+    from picotron_tpu.topology import build_topology, named_shardings
+
+    cfg = Config.from_dict({
+        "model": dict(hidden_size=HID, intermediate_size=FFN,
+                      num_attention_heads=HEADS, num_key_value_heads=HEADS,
+                      vocab_size=VOCAB, num_hidden_layers=SERVE_LAYERS,
+                      max_position_embeddings=SEQ, dtype="bfloat16"),
+        "inference": {"kv_layout": layout, "kv_page_len": PAGE}})
+    mesh = build_topology(1, 1, 1, 1, devices=topo.devices)
+    eng = InferenceEngine(cfg, mesh, slots=SERVE_SLOTS, max_seq_len=SEQ)
+
+    def abstract(tree, specs):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            tree, named_shardings(mesh, specs))
+
+    params = abstract(jax.eval_shape(
+        lambda: llama.init_params(jax.random.key(0), cfg.model)), eng._pspecs)
+    cache = abstract(jax.eval_shape(eng._init_cache_jit), eng._cspecs)
+    rep = named_shardings(mesh, jax.sharding.PartitionSpec())
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep)
+    B = SERVE_SLOTS
+    if prog == "decode_block":
+        jitted = eng._decode_block_jit
+        args = (arg((B,), I32), arg((eng.decode_block_len, 2), jnp.uint32),
+                arg((B,), I32), arg((B,), I32), arg((B,), F32),
+                arg((B,), I32), arg((B,), F32))
+    else:
+        jitted = eng._prefill_chunk_jit
+        args = (arg((1, eng.prefill_chunk), I32),) + (arg((), I32),) * 3
+        if eng.sample_on_device:
+            args += (arg((2,), jnp.uint32), arg((1,), F32), arg((1,), I32),
+                     arg((1,), F32))
+    kv = cache["k"].shape  # [L, slots | pages, rows, Hkv, D]
+    layer_elems = kv[1] * kv[2] * kv[3] * kv[4]
+    padded = 2 * kv[0] * layer_elems // kv[4] * max(kv[4], 128) * 2
+    return jitted.lower(params, cache, *args).compile(), layer_elems, padded
+
+
+@pytest.mark.parametrize("prog,layout", [
+    ("decode_block", "contiguous"), ("prefill_chunk", "contiguous"),
+    ("decode_block", "paged"), ("prefill_chunk", "paged")])
+def test_serving_program_leaves_cache_in_place(prog, layout, topo, one_chip):
+    compiled, layer_elems, padded = _serving_program(topo, prog, layout)
+    movers = _cache_movers(compiled.as_text(), layer_elems)
+    assert not movers, (
+        f"{prog}/{layout}: a loop of the compiled program moves a whole "
+        "layer of the KV cache or more at once:\n" + "\n".join(movers))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1.5 * padded, (
+        f"{prog}/{layout}: {temp / 1e6:.0f} MB of temporaries against a "
+        f"lane-padded cache of {padded / 1e6:.0f} MB")

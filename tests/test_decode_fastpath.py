@@ -303,6 +303,48 @@ def test_chunked_prefill_ragged_cache_window(tiny_model_kwargs):
             == np.argmax(np.asarray(lg_chk)[0]))
 
 
+@pytest.mark.parametrize("case", ["bf16", "int8", "dp2"])
+def test_chunk_of_one_token_lands_in_its_own_slot(tiny_model_kwargs, case):
+    """A prefill chunk ONE token wide (``inference.prefill_chunk: 1`` is a
+    legal config) is still a one-slot write: prefilled into slot > 0 beside
+    a parked neighbour it leaves the neighbour's rows byte for byte alone
+    and parks the same rows and logits as the chunk-4 run — on the dp-
+    sharded mesh too, where the non-owner shard's gate must hold at width
+    1 (regression: the S == 1 write ignored ``slot`` and ``gate``)."""
+    kw = {"cache_dtype": "int8"} if case == "int8" else {}
+    n_tokens, slot = 9, 3 if case == "dp2" else 1
+
+    def run(chunk):
+        cfg = make_config(tiny_model_kwargs, seq=MAX_LEN)
+        cfg.inference.dp_size = 2 if case == "dp2" else 1
+        engine = InferenceEngine(cfg, slots=4 if case == "dp2" else 2,
+                                 max_seq_len=MAX_LEN, prefill_chunk=chunk,
+                                 **kw)
+        params = _params(cfg, engine)
+        V = cfg.model.vocab_size
+        parked, _ = engine.prefill_chunked(
+            params, engine.init_cache(), [(3 * i + 1) % V for i in range(6)],
+            0)
+        before = jax.device_get(parked)  # the next call donates ``parked``
+        out, logits = engine.prefill_chunked(
+            params, parked, [(7 * i + 3) % V for i in range(n_tokens)], slot)
+        return before, jax.device_get(out), logits
+
+    parked1, one, lg_one = run(1)
+    _, four, lg_four = run(4)
+    np.testing.assert_array_equal(one["lengths"], four["lengths"])
+    for name in (n for n in one if n != "lengths"):
+        a, b = (np.asarray(x[name], np.float32) for x in (one, four))
+        np.testing.assert_allclose(a[:, slot, :n_tokens],
+                                   b[:, slot, :n_tokens],
+                                   rtol=1e-5, atol=1e-5)
+        others = [i for i in range(a.shape[1]) if i != slot]
+        np.testing.assert_array_equal(
+            a[:, others], np.asarray(parked1[name], np.float32)[:, others])
+    np.testing.assert_allclose(np.asarray(lg_one), np.asarray(lg_four),
+                               rtol=1e-4, atol=1e-4)
+
+
 def test_cache_dtype_keyword_overrides_config(tiny_model_kwargs):
     """An explicit cache_dtype wins over inference.kv_cache_dtype in BOTH
     directions — int8 on, and back off."""

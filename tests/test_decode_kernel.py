@@ -168,15 +168,18 @@ def test_flash_path_never_materializes_dequantized_cache(monkeypatch):
     rng = np.random.default_rng(5)
     q, (k, v, ks, vs), (dk, dv) = _blocks(rng, 2, 32, 8, 4, 16, 1,
                                           "float32", True)
-    cache = {"k": k, "v": v, "k_scale": ks, "v_scale": vs}
+    cache = {"k": k[None], "v": v[None], "k_scale": ks[None],
+             "v_scale": vs[None]}  # stacked leaves of a one-layer cache
     lengths = jnp.asarray([9, 20], jnp.int32)
-    want = np.asarray(kv_cache.attend(q, cache, lengths, 0.25, impl="dense"))
+    want = np.asarray(kv_cache.attend(q, cache, lengths, 0.25, 0,
+                                      impl="dense"))
 
     def boom(*a, **kw):
         raise AssertionError("flash attend materialized a dequantized copy")
 
     monkeypatch.setattr(kv_cache, "dequantize_kv", boom)
-    got = np.asarray(kv_cache.attend(q, cache, lengths, 0.25, impl="flash"))
+    got = np.asarray(kv_cache.attend(q, cache, lengths, 0.25, 0,
+                                     impl="flash"))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
@@ -455,6 +458,8 @@ def test_attend_impl_validated(tiny_model_kwargs):
         Config.from_dict(raw)
     # the attend helper itself must not silently fall through to dense
     q = jnp.zeros((1, 1, 2, 4))
-    cache = {"k": jnp.zeros((1, 8, 2, 4)), "v": jnp.zeros((1, 8, 2, 4))}
+    cache = {"k": jnp.zeros((1, 1, 8, 2, 4)),
+             "v": jnp.zeros((1, 1, 8, 2, 4))}
     with pytest.raises(ValueError, match="attend impl"):
-        kv_cache.attend(q, cache, jnp.ones(1, jnp.int32), 0.5, impl="Flash")
+        kv_cache.attend(q, cache, jnp.ones(1, jnp.int32), 0.5, 0,
+                        impl="Flash")

@@ -25,6 +25,7 @@ from picotron_tpu.inference import (
     ContinuousBatcher,
     InferenceEngine,
     Request,
+    kv_cache,
     sampling,
 )
 from picotron_tpu.models import llama
@@ -339,3 +340,81 @@ def test_generate_cli_end_to_end_from_checkpoint(tiny_model_kwargs, tmp_path,
     assert rc == 0
     assert "loaded step 3" in out
     assert "[req0]" in out and "[req1]" in out
+
+
+# --------------------------------------------------------------------------- #
+# the write/attend seam: a layer index on the stacked leaves
+# --------------------------------------------------------------------------- #
+
+
+def _stacked_cache(rng, L, B, T, H, D, quantized):
+    def leaf():
+        return jnp.asarray(rng.normal(size=(L, B, T, H, D)), jnp.bfloat16)
+
+    if not quantized:
+        return {"k": leaf(), "v": leaf()}
+    (qk, ks), (qv, vs) = (kv_cache.quantize_kv(leaf()) for _ in "kv")
+    return {"k": qk, "v": qv, "k_scale": ks, "v_scale": vs}
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("shape", [
+    "decode", "block", "block_of_one", "open_block", "open_block_of_one",
+    "shut_block", "shut_block_of_one", "verify", "ragged_verify"])
+def test_layer_indexed_seam_matches_per_layer(shape, quantized):
+    """``cache_write`` / ``attend`` addressed ``[layer, ...]`` into the
+    STACKED leaves (what the engine's layer scan carries) hold bit for bit
+    the bytes a row-by-row placement puts there, and attend like the
+    per-layer ``decode_attention`` on that layer's slice — for the three
+    write shapes, the ragged ``draft_valid`` mask, the one-slot ``slot`` /
+    ``gate`` addressing of a prefill chunk AT ANY WIDTH (a chunk of one
+    token is still its slot's, and still gated), and int8 storage with its
+    scales — and touch no other layer and no other slot."""
+    rng = np.random.default_rng(7)
+    L, B, T, H, D, layer = 3, 3, 16, 2, 8, 1
+    cache = _stacked_cache(rng, L, B, T, H, D, quantized)
+    addr, valid, gate = {}, None, True
+    if shape == "decode":
+        slots, s, pos = range(B), 1, [6, 3, 0]
+    elif "block" in shape:
+        slots, s, pos = [2], (1 if shape.endswith("of_one") else 4), [5]
+        addr = {"slot": jnp.asarray(2, jnp.int32)}
+        if shape.startswith(("open", "shut")):
+            gate = shape.startswith("open")
+            addr["gate"] = jnp.asarray(gate)
+    else:
+        slots, s, pos = range(B), 4, [6, 14, 2]  # slot 1 runs off the end
+        if shape == "ragged_verify":
+            valid = [4, 1, 2]
+            addr = {"draft_valid": jnp.asarray(valid, jnp.int32)}
+    b = len(slots)
+    k_new, v_new = (jnp.asarray(rng.normal(size=(b, s, H, D)), jnp.bfloat16)
+                    for _ in "kv")
+    q = jnp.asarray(rng.normal(size=(b, s, 2 * H, D)), jnp.bfloat16)
+    f32 = lambda x: np.array(x.astype(jnp.float32))
+
+    got = kv_cache.cache_write({**cache, **addr}, k_new, v_new,
+                               jnp.asarray(pos, jnp.int32), layer)
+    # the oracle: every live row placed one at a time, nothing else touched
+    new = {"k": k_new, "v": v_new}
+    if quantized:
+        (new["k"], new["k_scale"]), (new["v"], new["v_scale"]) = (
+            kv_cache.quantize_kv(x) for x in (k_new, v_new))
+    want = {n: f32(a) for n, a in cache.items()}
+    for n, rows in new.items():
+        for i, slot in enumerate(slots):
+            live = s if valid is None else valid[i]
+            for j in range(live if gate else 0):
+                if pos[i] + j < T:
+                    want[n][layer, slot, pos[i] + j] = f32(rows)[i, j]
+    for n in cache:
+        np.testing.assert_array_equal(f32(got[n]), want[n])
+    lengths = jnp.asarray(pos, jnp.int32) + s
+    k, v = (got[n][layer, jnp.asarray(list(slots))] for n in "kv")
+    if quantized:
+        k, v = (kv_cache.dequantize_kv(
+            x, got[n][layer, jnp.asarray(list(slots))], jnp.float32)
+            for x, n in ((k, "k_scale"), (v, "v_scale")))
+    np.testing.assert_array_equal(
+        f32(kv_cache.attend(q, {**got, **addr}, lengths, 0.3, layer)),
+        f32(kv_cache.decode_attention(q, k, v, lengths, 0.3)))

@@ -197,6 +197,64 @@ def _engines(tp=1, slots=3, **kw):
     return cfg, ec, ep, params
 
 
+@pytest.mark.parametrize("storage", ["bf16", "int8", "hot_bf16"])
+@pytest.mark.parametrize("shape", ["decode", "chunk", "verify",
+                                   "ragged_verify"])
+def test_layer_indexed_seam_matches_per_layer(shape, storage):
+    """The paged ``cache_write`` / ``attend`` addressed
+    ``[layer, page, row]`` into the STACKED pool leaves (what the engine's
+    layer scan carries) produce bit for bit the pool bytes and the
+    attention output of the same functions on that layer's slice alone —
+    the three write shapes, the ragged ``draft_valid`` mask, int8 and the
+    hot_bf16 dual pool — and touch no other layer."""
+    rng = np.random.default_rng(11)
+    L, B, P, H, D, layer, maxp = 3, 3, 13, 2, 8, 2, 4
+    pool = lambda: jnp.asarray(rng.normal(size=(L, P, PAGE, H, D)),
+                               jnp.bfloat16)
+    cache = {"k": pool(), "v": pool()}
+    if storage != "bf16":
+        (qk, ks), (qv, vs) = (paged_kv.kv_cache.quantize_kv(cache[n])
+                              for n in "kv")
+        cache = ({"k": qk, "v": qv} if storage == "int8"
+                 else {**cache, "k_q": qk, "v_q": qv})
+        cache.update(k_scale=ks, v_scale=vs)
+    meta = {"block_tables": jnp.asarray(
+        1 + rng.permutation(P - 1)[:B * maxp].reshape(B, maxp), jnp.int32)}
+    if storage == "hot_bf16":
+        meta["page_quant"] = jnp.asarray(rng.integers(0, 2, P), jnp.int32)
+    if shape == "decode":
+        b, s, pos = B, 1, [6, 19, 0]
+    elif shape == "chunk":
+        b, s, pos = 1, 12, [5]  # crosses a page boundary
+        meta["block_tables"] = meta["block_tables"][1:2]
+    else:
+        b, s, pos = B, 4, [6, 30, 2]  # slot 1 runs off its window's end
+        if shape == "ragged_verify":
+            meta["draft_valid"] = jnp.asarray([4, 1, 2], jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    k_new, v_new = (jnp.asarray(rng.normal(size=(b, s, H, D)), jnp.bfloat16)
+                    for _ in "kv")
+    q = jnp.asarray(rng.normal(size=(b, s, 2 * H, D)), jnp.bfloat16)
+
+    # the per-layer twin: that layer's slice as a pool of its own
+    got = paged_kv.cache_write({**cache, **meta}, k_new, v_new, pos, layer)
+    want = paged_kv.cache_write(
+        {**{n: a[layer:layer + 1] for n, a in cache.items()}, **meta},
+        k_new, v_new, pos, 0)
+    for n, a in cache.items():
+        new = np.asarray(got[n].astype(jnp.float32))
+        np.testing.assert_array_equal(
+            new[layer], np.asarray(want[n].astype(jnp.float32))[0])
+        other = [i for i in range(L) if i != layer]
+        np.testing.assert_array_equal(
+            new[other], np.asarray(a.astype(jnp.float32))[other])
+    for impl in ("dense", "flash"):
+        out = paged_kv.attend(q, got, pos + s, 0.3, layer, impl)
+        ref = paged_kv.attend(q, want, pos + s, 0.3, 0, impl)
+        np.testing.assert_array_equal(np.asarray(out.astype(jnp.float32)),
+                                      np.asarray(ref.astype(jnp.float32)))
+
+
 @pytest.mark.parametrize("cache_dtype", [None, "int8"])
 def test_insert_bytes_match_contiguous(cache_dtype):
     """A one-shot prefill parked through page indirection holds byte-

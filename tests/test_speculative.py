@@ -321,9 +321,9 @@ def test_rejected_draft_rows_invisible_to_attend(quantized):
     dt = jnp.bfloat16
 
     def block():
-        base = {
-            "k": jnp.asarray(rng.normal(size=(B, T, H, D)), dt),
-            "v": jnp.asarray(rng.normal(size=(B, T, H, D)), dt),
+        base = {  # stacked leaves of a one-layer cache
+            "k": jnp.asarray(rng.normal(size=(1, B, T, H, D)), dt),
+            "v": jnp.asarray(rng.normal(size=(1, B, T, H, D)), dt),
         }
         if quantized:
             qk, ks = kv_cache.quantize_kv(base["k"])
@@ -338,22 +338,22 @@ def test_rejected_draft_rows_invisible_to_attend(quantized):
     v_new = jnp.asarray(rng.normal(size=(B, S, H, D)), dt)
     # speculative write: all S rows land; suppose 0 drafts accepted, so the
     # post-acceptance lengths advance past the fed token only
-    spec = kv_cache.cache_write(base, k_new, v_new, pos)
-    clean = kv_cache.cache_write(base, k_new[:, :1], v_new[:, :1], pos)
+    spec = kv_cache.cache_write(base, k_new, v_new, pos, 0)
+    clean = kv_cache.cache_write(base, k_new[:, :1], v_new[:, :1], pos, 0)
     lengths = pos + 1
 
     q = jnp.asarray(rng.normal(size=(B, 1, H, D)), dt)
-    out_spec = kv_cache.attend(q, spec, lengths, 0.3)
-    out_clean = kv_cache.attend(q, clean, lengths, 0.3)
+    out_spec = kv_cache.attend(q, spec, lengths, 0.3, 0)
+    out_clean = kv_cache.attend(q, clean, lengths, 0.3, 0)
     np.testing.assert_array_equal(np.asarray(out_spec, np.float32),
                                   np.asarray(out_clean, np.float32))
     # and the next decode step's write simply overwrites a stale row
     k2 = jnp.asarray(rng.normal(size=(B, 1, H, D)), dt)
     v2 = jnp.asarray(rng.normal(size=(B, 1, H, D)), dt)
-    again_spec = kv_cache.cache_write(spec, k2, v2, lengths)
-    again_clean = kv_cache.cache_write(clean, k2, v2, lengths)
-    out2s = kv_cache.attend(q, again_spec, lengths + 1, 0.3)
-    out2c = kv_cache.attend(q, again_clean, lengths + 1, 0.3)
+    again_spec = kv_cache.cache_write(spec, k2, v2, lengths, 0)
+    again_clean = kv_cache.cache_write(clean, k2, v2, lengths, 0)
+    out2s = kv_cache.attend(q, again_spec, lengths + 1, 0.3, 0)
+    out2c = kv_cache.attend(q, again_clean, lengths + 1, 0.3, 0)
     np.testing.assert_array_equal(np.asarray(out2s, np.float32),
                                   np.asarray(out2c, np.float32))
 
@@ -363,11 +363,11 @@ def test_batched_write_drops_out_of_window_rows():
     out-of-range rows instead of clamping them onto earlier positions
     (the chunked-prefill bug class, pinned for the batched write)."""
     B, T, H, D = 2, 8, 2, 4
-    base = {"k": jnp.zeros((B, T, H, D)), "v": jnp.zeros((B, T, H, D))}
+    base = {"k": jnp.zeros((1, B, T, H, D)), "v": jnp.zeros((1, B, T, H, D))}
     k_new = jnp.ones((B, 3, H, D))
     out = kv_cache.cache_write(base, k_new, k_new,
-                               jnp.asarray([6, 2], jnp.int32))
-    got = np.asarray(out["k"][:, :, 0, 0])
+                               jnp.asarray([6, 2], jnp.int32), 0)
+    got = np.asarray(out["k"][0, :, :, 0, 0])
     want = np.zeros((B, T))
     want[0, 6:8] = 1  # row at pos 8 dropped
     want[1, 2:5] = 1
