@@ -1,11 +1,14 @@
 """What both runners share: the model section of the program's config, the
-compile counter, the reduction of a traced stretch, the benchmark's own host
-spans."""
+reference a configuration names, the compile counter, the reduction of a
+traced stretch, the benchmark's own host spans."""
 
 from __future__ import annotations
 
+import dataclasses
+import importlib.util
 import os
 import shutil
+import sys
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -13,21 +16,75 @@ MODEL_KEYS = ("num_hidden_layers", "num_attention_heads",
               "num_key_value_heads", "hidden_size", "intermediate_size",
               "vocab_size", "rms_norm_eps", "rope_theta",
               "max_position_embeddings")
+DEFAULT_REFERENCE = "dense_decoder"
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+def refuse(message: str):
+    """A configuration this program or this benchmark cannot run: a message
+    and exit code 2 at once, before any device work, so that a commit that
+    lacks what a later configuration asks for fails quickly and never
+    hangs."""
+    print("run.py: " + message, file=sys.stderr, flush=True)
+    raise SystemExit(2)
+
+
+def load_file(kind: str, name: str):
+    """The module ``benchmarks/<kind>/<name>.py``: what belongs to one metric
+    or one configuration is a file found by its name."""
+    spec = importlib.util.spec_from_file_location(
+        f"benchmarks.{kind}.{name.replace('.', '_').replace('-', '_')}",
+        os.path.join(HERE, kind, name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def model_section(config: dict) -> dict:
-    """The published keys ``ModelConfig`` has, by the same names; a key it
-    lacks (``sliding_window``, ``head_dim``) is checked, never passed."""
-    m = {k: config[k] for k in MODEL_KEYS}
+    """What the program's ``ModelConfig`` is given: ``MODEL_KEYS`` and, after
+    them, the published keys the configuration lists under ``model_keys``,
+    all by the same names. A listed key ``ModelConfig`` has no field for is
+    refused by name. ``head_dim`` and ``sliding_window`` are checked, never
+    passed, unless listed."""
+    from picotron_tpu.config import ModelConfig
+
+    listed = [k for k in config.get("model_keys", ()) if k not in MODEL_KEYS]
+    fields = {f.name for f in dataclasses.fields(ModelConfig)}
+    for k in listed:
+        if k not in fields:
+            refuse(f"configuration {config['name']!r} lists model key "
+                   f"{k!r}: ModelConfig cannot express it")
+        if k not in config:
+            refuse(f"configuration {config['name']!r} lists model key "
+                   f"{k!r} and gives it no value")
+    m = {k: config[k] for k in (*MODEL_KEYS, *listed)}
     m["name"] = config["name"]
     m["dtype"] = config.get("torch_dtype", "bfloat16")
     hd = config.get("head_dim")
-    if hd and hd != m["hidden_size"] // m["num_attention_heads"]:
-        raise SystemExit(f"head_dim {hd} is not hidden_size/heads: "
-                         f"ModelConfig cannot express it")
-    if config.get("sliding_window"):
-        raise SystemExit("ModelConfig has no sliding window")
+    if "head_dim" not in listed and hd \
+            and hd != m["hidden_size"] // m["num_attention_heads"]:
+        refuse(f"configuration {config['name']!r}: head_dim {hd} is not "
+               f"hidden_size/heads: ModelConfig cannot express it")
+    if "sliding_window" not in listed and config.get("sliding_window"):
+        refuse(f"configuration {config['name']!r}: ModelConfig has no "
+               f"sliding window")
     return m
+
+
+def load_reference(config: dict):
+    """The plain reference the configuration names under ``reference``
+    (absent: ``dense_decoder``): the module ``benchmarks/reference/<name>.py``
+    with ``forward_logits(params, tokens, config, device)`` and
+    ``loss(params, ids, targets, config, device)`` (README: the contract)."""
+    name = config.get("reference", DEFAULT_REFERENCE)
+    if not os.path.isfile(os.path.join(HERE, "reference", f"{name}.py")):
+        refuse(f"configuration {config['name']!r} names the reference "
+               f"{name!r}: there is no benchmarks/reference/{name}.py")
+    mod = load_file("reference", name)
+    for fn in ("forward_logits", "loss"):
+        if not callable(getattr(mod, fn, None)):
+            refuse(f"reference {name!r} has no function {fn}()")
+    return mod
 
 
 class CompileCounter:

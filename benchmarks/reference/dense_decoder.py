@@ -139,6 +139,9 @@ def _per_sequence(params, tokens, model, device, targets):
         hs = [block(lp, h, cos, sin, n_heads=n_heads, n_kv=n_kv, eps=eps)
               for h in hs]
         del lp
+        # one layer's copy at a time: dispatched ahead of a long sequence's
+        # blocks, the next layers' copies would all be resident at once
+        jax.block_until_ready(hs)
     fn = jax.device_put(params["final_norm"], device)
     lm = jax.device_put(params["lm_head"], device)
     out = []

@@ -4,7 +4,8 @@
     python3 benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1|2>
 
 Everything that belongs to one cell is data found by name: the cell's
-configuration (``benchmarks/configs/<config>.json``), its traffic mix
+configuration (``benchmarks/configs/<config>.json``, which may list further
+``model_keys`` for the program and name its ``reference``), its traffic mix
 (``benchmarks/traffic/<traffic>.json``, whose ``runner`` names a module of
 ``benchmarks/runners/``), and one reader per metric
 (``benchmarks/end_to_end/<name>.py``, ``benchmarks/layer_metrics/<name>.py``,
@@ -42,7 +43,6 @@ T0 = time.perf_counter()  # set-up is counted from here
 
 import argparse
 import importlib
-import importlib.util
 import json
 import os
 import sys
@@ -93,12 +93,9 @@ def apply_sets(traffic: dict, sets) -> dict:
 
 
 def load_reader(kind: str, name: str):
-    path = os.path.join(HERE, kind, name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        f"benchmarks.{kind}.{name.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    from benchmarks import common
+
+    return common.load_file(kind, name).read
 
 
 def read_metrics(manifest: dict, kind: str, cell: str, run: dict) -> dict:
@@ -164,6 +161,13 @@ def main(argv=None) -> int:
     traffic = apply_sets(traffic, args.set)
 
     sys.path.insert(0, ROOT)
+    from benchmarks import common
+
+    # a configuration that asks for what this program or this benchmark
+    # lacks ends here (exit 2), before the backend is touched
+    model = common.model_section(config)
+    reference = common.load_reference(config)
+
     import jax
 
     from picotron_tpu.utils import enable_compile_cache
@@ -198,6 +202,7 @@ def main(argv=None) -> int:
     ctx = {
         "t0": T0, "root": ROOT, "scratch": SCRATCH, "cell": cell,
         "config": config, "traffic": traffic, "chips": chips,
+        "model": model, "reference": reference,
         "seed": args.seed, "seed31": args.seed % SEED_MOD,
         "seconds": args.seconds, "trace": args.trace,
         "rehearse": args.rehearse, "device": dev, "log": log,

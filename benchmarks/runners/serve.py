@@ -6,8 +6,10 @@ ephemeral port, the recipe ``chip_smoke.py`` proved on the chip) with
 that speaks HTTP.
 
 Set-up: weights drawn on the device from ``--seed``; prefill of one seeded
-prompt and four decode steps through the cache against the reference's full
-forward, on logits; the server; warm-up requests for every prefill shape
+prompt (32 tokens, or the mix's ``check_prompt_len``: past ``prefill_chunk``
+it goes in chunks, as the batcher sends a long prompt) and four decode steps
+through the cache against the reference's full forward, on logits; the
+server; warm-up requests for every prefill shape
 the mix can ask for (each bucket, and the chunked path when prompts pass
 ``prefill_chunk``), one at a time and then all at once.
 Then the child offers the load: the mix's ``lead_in_seconds`` first (still
@@ -55,26 +57,35 @@ def config_dict(ctx: dict) -> dict:
     return {
         "distributed": {"dp_size": 1, "pp_size": 1, "cp_size": 1,
                         "tp_size": 1, "use_cpu": ctx["rehearse"]},
-        "model": common.model_section(ctx["config"]),
+        "model": ctx["model"],
         "training": {"seq_length": ctx["config"]["serve"]["max_seq_len"],
                      "seed": ctx["seed31"]},
         "dataset": {"name": "synthetic"},
     }
 
 
-def logits_check(ctx, engine, params, prompt) -> tuple:
-    """(ok, rows of (what, err, scale, margin, ok))."""
+def program_logits(engine, params, prompt, follow=None) -> tuple:
+    """(tokens, logits): the prompt through the engine's prefill (past
+    ``prefill_chunk`` in chunks written straight into the slot, as the
+    batcher admits a long prompt) and ``CHECK_DECODE_STEPS`` decode steps
+    through the cache; the last prompt position's logits and each step's.
+    Each step feeds the argmax of the last, or ``follow``'s next token (a
+    control is read along the tokens the sound program chose)."""
     import jax
 
-    from benchmarks.reference import dense_decoder
-
     seq = list(prompt)
-    kv, last = engine.prefill(params, prompt)
+    if len(prompt) > engine.prefill_chunk:
+        cache, last = engine.prefill_chunked(params, engine.init_cache(),
+                                             prompt, 0)
+    else:
+        kv, last = engine.prefill(params, prompt)
+        cache = engine.insert(engine.init_cache(), kv, 0, len(prompt))
+        del kv
     got = [np.asarray(last, np.float32)[0]]
-    cache = engine.insert(engine.init_cache(), kv, 0, len(prompt))
     slots = engine.slots
-    for _ in range(CHECK_DECODE_STEPS):
-        seq.append(int(np.argmax(got[-1])))
+    for i in range(CHECK_DECODE_STEPS):
+        seq.append(int(follow[i]) if follow is not None
+                   else int(np.argmax(got[-1])))
         toks = np.zeros(slots, np.int32)
         toks[0] = seq[-1]
         cache, _, logits = engine.decode_step(
@@ -82,11 +93,13 @@ def logits_check(ctx, engine, params, prompt) -> tuple:
             np.zeros(slots, np.float32), np.zeros(slots, np.int32),
             np.ones(slots, np.float32))
         got.append(np.asarray(logits, np.float32)[0])
-    del cache, kv
-    want = dense_decoder.forward_logits(
-        params, np.asarray([seq], np.int32), ctx["config"],
-        jax.devices()[0])[0][len(prompt) - 1:]
-    tol = TOL_LOGITS_REL[ctx["config"].get("torch_dtype", "bfloat16")]
+    return seq, got
+
+
+def compare_logits(got, want, tol: float) -> tuple:
+    """(ok, rows of (what, err, scale, margin, ok)): max |err| within
+    ``tol`` of max |logit|, and the argmax equal where the reference's
+    top-2 margin passes twice that."""
     rows, all_ok = [], True
     for i, (g, ref) in enumerate(zip(got, want)):
         scale = float(np.max(np.abs(ref)))
@@ -100,6 +113,51 @@ def logits_check(ctx, engine, params, prompt) -> tuple:
                      margin, ok))
         all_ok = all_ok and ok
     return all_ok, rows
+
+
+def reference_logits(ctx, params, seq, n_prompt: int):
+    """The reference's logits at the positions ``program_logits`` reads."""
+    import jax
+
+    return ctx["reference"].forward_logits(
+        params, np.asarray([seq], np.int32), ctx["config"],
+        jax.devices()[0])[0][n_prompt - 1:]
+
+
+def logits_check(ctx, engine, params, prompt) -> tuple:
+    """(ok, rows of (what, err, scale, margin, ok))."""
+    seq, got = program_logits(engine, params, prompt)
+    want = reference_logits(ctx, params, seq, len(prompt))
+    return compare_logits(got, want, TOL_LOGITS_REL[
+        ctx["config"].get("torch_dtype", "bfloat16")])
+
+
+def check_prompt(ctx, vocab: int, rng) -> list:
+    """The check's seeded prompt: 32 tokens, or the mix's
+    ``check_prompt_len``."""
+    return [int(t) for t in rng.integers(1, vocab, int(
+        ctx["traffic"].get("check_prompt_len", CHECK_PROMPT_LEN)))]
+
+
+def build_engine(ctx: dict) -> tuple:
+    """(cfg, engine, params, registry) as ``tools/serve.py`` builds them
+    from the configuration this writes: ``InferenceConfig``'s defaults,
+    the cell's slots and window, weights drawn on the device from the
+    seed."""
+    from picotron_tpu.tools import serve
+
+    config = ctx["config"]
+    cfg_path = os.path.join(ctx["scratch"],
+                            ctx["cell"]["name"] + ".config.json")
+    with open(cfg_path, "w") as f:
+        json.dump(config_dict(ctx), f, indent=1)
+    args = argparse.Namespace(
+        smoke=False, config=cfg_path, load_path="", hf_path="",
+        random_init=True, seed=ctx["seed31"],
+        slots=config["serve"]["slots"],
+        max_seq_len=config["serve"]["max_seq_len"], kv_layout=None,
+        role=None, overlap=False, tenant_manifest="")
+    return serve._build_engine_and_params(args)
 
 
 def warm_lengths(traffic: dict, engine) -> list:
@@ -122,7 +180,7 @@ def get_text(port: int, path: str) -> str:
 
 
 def start_load(ctx: dict, port: int, vocab: int, seconds: float,
-               out_path: str):
+               out_path: str, seed: int | None = None):
     """A load-generator child, started and told to go: ``lead_in_seconds``
     of the mix and then ``seconds`` more, its record written to
     ``out_path`` when its requests have ended."""
@@ -132,7 +190,8 @@ def start_load(ctx: dict, port: int, vocab: int, seconds: float,
         [sys.executable, os.path.join(os.path.dirname(loadgen.__file__),
                                       "loadgen.py"),
          "--port", str(port), "--traffic", json.dumps(ctx["traffic"]),
-         "--seed", str(ctx["seed"]), "--seconds", str(seconds),
+         "--seed", str(ctx["seed"] if seed is None else seed),
+         "--seconds", str(seconds),
          "--vocab", str(vocab), "--out", out_path],
         stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True)
     if child.stdout.readline().strip() != "READY":
@@ -165,8 +224,9 @@ def end_load(ctx: dict, child, out_path: str) -> dict:
 
 def traced_tail(ctx: dict, server, vocab: int) -> tuple:
     """(trace record, the tail's load record): after the window, the same
-    mix again from a second child, its last ``trace_seconds`` under the
-    server's own capture. A longer span for the window's child would be
+    mix again from a second child under the next seed (the same shapes,
+    other tokens), its last ``trace_seconds`` under the server's own
+    capture. A longer span for the window's child would be
     another window: an open loop scales its gaps to the span, and the
     requests in flight at the window's end would finish under load."""
     traffic = ctx["traffic"]
@@ -176,7 +236,9 @@ def traced_tail(ctx: dict, server, vocab: int) -> tuple:
     tracer.warm(capture)  # on the idle server, before the tail's load
     out_path = os.path.join(ctx["scratch"],
                             ctx["cell"]["name"] + ".tail.json")
-    child = start_load(ctx, server.port, vocab, seconds, out_path)
+    # under the next seed: the window's prompts do not come again
+    child = start_load(ctx, server.port, vocab, seconds, out_path,
+                       seed=ctx["seed"] + 1)
     try:
         time.sleep(float(traffic.get("lead_in_seconds", 0)))
         tracer.open(capture)
@@ -194,28 +256,19 @@ def traced_tail(ctx: dict, server, vocab: int) -> tuple:
 def run(ctx: dict) -> dict:
     from picotron_tpu.tools import serve
 
-    log, traffic, config = ctx["log"], ctx["traffic"], ctx["config"]
+    log, traffic = ctx["log"], ctx["traffic"]
     compiles = common.CompileCounter()
     name = ctx["cell"]["name"]
-    cfg_path = os.path.join(ctx["scratch"], name + ".config.json")
-    with open(cfg_path, "w") as f:
-        json.dump(config_dict(ctx), f, indent=1)
-    args = argparse.Namespace(
-        smoke=False, config=cfg_path, load_path="", hf_path="",
-        random_init=True, seed=ctx["seed31"],
-        slots=config["serve"]["slots"],
-        max_seq_len=config["serve"]["max_seq_len"], kv_layout=None,
-        role=None, overlap=False, tenant_manifest="")
-    cfg, engine, params, registry = serve._build_engine_and_params(args)
+    cfg, engine, params, registry = build_engine(ctx)
     log(f"[serve] engine and weights after "
         f"{time.perf_counter() - ctx['t0']:.1f} s; attend_impl "
         f"{engine.attend_impl}, decode_block_len {engine.decode_block_len}, "
         f"prefill_chunk {engine.prefill_chunk}")
     vocab = cfg.model.vocab_size
     rng = np.random.default_rng(ctx["seed31"])
-    check_prompt = [int(t) for t in rng.integers(1, vocab, CHECK_PROMPT_LEN)]
     # before the server owns a cache: the chip never holds two caches
-    logits_ok, rows = logits_check(ctx, engine, params, check_prompt)
+    logits_ok, rows = logits_check(ctx, engine, params,
+                                   check_prompt(ctx, vocab, rng))
     for what, err, scale, margin, ok in rows:
         log(f"[serve] logits {what}: max|err| {err:.4f} vs max|logit| "
             f"{scale:.3f}, top-2 margin {margin:.4f} "

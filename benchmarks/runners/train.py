@@ -47,7 +47,7 @@ def config_dict(ctx: dict) -> dict:
     training["total_train_steps"] = 10**9
     return {
         "distributed": dict(t["distributed"], use_cpu=ctx["rehearse"]),
-        "model": common.model_section(ctx["config"]),
+        "model": ctx["model"],
         "training": training,
         "dataset": {"name": t.get("dataset", "synthetic")},
     }
@@ -56,7 +56,6 @@ def config_dict(ctx: dict) -> dict:
 def run(ctx: dict) -> dict:
     import jax
 
-    from benchmarks.reference import dense_decoder
     from picotron_tpu import train_step as ts
     from picotron_tpu.config import Config
     from picotron_tpu.data import MicroBatchDataLoader
@@ -80,9 +79,9 @@ def run(ctx: dict) -> dict:
     ids, tgt = batch["input_ids"], batch["target_ids"]
     S = ids.shape[-1]
     t_ref = time.perf_counter()
-    ref_loss = dense_decoder.loss(params, ids.reshape(-1, S),
-                                  tgt.reshape(-1, S), ctx["config"],
-                                  jax.devices()[0])
+    ref_loss = ctx["reference"].loss(params, ids.reshape(-1, S),
+                                     tgt.reshape(-1, S), ctx["config"],
+                                     jax.devices()[0])
     log(f"[train] reference loss {ref_loss:.5f} on {ids.size} tokens in "
         f"{time.perf_counter() - t_ref:.1f} s")
 

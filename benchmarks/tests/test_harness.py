@@ -39,7 +39,13 @@ def run_cell(cell, trace, extra=()):
      {"serve_tpot_mean_ms", "setup_s", "batcher.dispatch_gap_ms.chat",
       "front.loop_lock_wait_ms.chat", "front.results_ms.chat",
       "batcher.plan_ms.chat", "batcher.deliver_ms.chat",
-      "engine.prefill_tokens_per_s.chat"}),
+      "batcher.admit_ms.chat", "engine.prefill_tokens_per_s.chat",
+      "engine.prompt_reuse_pct.chat"}),
+    # documents asked more than once, by clients that wait
+    ("mistral-7b-v0.3-l16.serve-longdoc", 2,
+     {"serve_out_tokens_per_s.longdoc", "serve_tpot_mean_ms", "setup_s",
+      "batcher.admit_ms.chat", "batcher.itl_p99_ms.chat",
+      "engine.prefill_tokens_per_s.chat", "engine.prompt_reuse_pct.chat"}),
 ])
 def test_rehearsal_final_line(cell, trace, computed):
     p = run_cell(cell, trace, ["--rehearse"])

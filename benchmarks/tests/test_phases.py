@@ -35,6 +35,15 @@ def test_phase_means_add_up_per_round():
     assert phases.phase_mean_ms(RUN, "step/plan") == pytest.approx(3.0)
     assert phases.phase_mean_ms(RUN, "step/plan", "step/issue") \
         == pytest.approx(4.0)
+    from benchmarks.run import load_reader
+
+    assert load_reader("layer_metrics", "batcher.plan_ms.chat")(RUN) \
+        == pytest.approx(4.0)
+    # admission alone: the serial prefills of a round
+    admit = {k: v.replace("step/plan", "step/admit") for k, v in RUN.items()}
+    assert load_reader("layer_metrics", "batcher.admit_ms.chat")(admit) \
+        == pytest.approx(3.0)
+    assert load_reader("layer_metrics", "batcher.admit_ms.chat")(RUN) is None
     # a phase the window never observed, or a program without the family
     assert phases.phase_mean_ms(RUN, "loop/idle") is None
     assert phases.phase_mean_ms({"metrics_before": "", "metrics_after": ""},
@@ -47,3 +56,18 @@ def test_prefill_rate_takes_the_lane_off_and_its_own_seconds():
     assert phases.prefill_tokens_per_s(RUN) == pytest.approx(3500.0)
     assert phases.prefill_tokens_per_s(
         {"metrics_before": "", "metrics_after": ""}) is None
+
+
+def test_prompt_reuse_is_what_no_prefill_ran_of_the_prompts_asked():
+    from benchmarks.run import load_reader
+
+    read = load_reader("layer_metrics", "engine.prompt_reuse_pct.chat")
+    req = lambda n, first: {"prompt_len": n, "token_times": first}
+    load = {"t0": 100.0, "seconds": 30.0, "requests": [
+        req(6000, [101.0, 101.1]), req(4000, [129.0]),
+        req(7000, [99.0, 100.5]),  # its first token came before the window
+        req(7000, [131.0]), req(7000, [])]}  # after it; never
+    # 8000 prompt tokens prefilled of the 10000 asked: a fifth was reused
+    assert read(dict(RUN, load=load)) == pytest.approx(20.0)
+    assert read(dict(RUN, load=dict(load, requests=[]))) is None
+    assert read({"load": load}) is None and read(RUN) is None
