@@ -117,6 +117,14 @@ class _MethodWalker:
         if isinstance(stmt, ast.While):
             self._expr_events(stmt.test, held)
             self.walk(stmt.body, held)
+            acq = self._acquire_in(stmt.test)
+            if acq is not None and acq[1]:
+                # `while not X.acquire(timeout=...): <shed, or ask again>`
+                # — a bounded acquire in slices: the loop ends when one
+                # succeeds, so the lock is held from the statement after it
+                lid = acq[0]
+                self.sum.acquires.append((lid, held, stmt.test.lineno))
+                held = held | {lid}
             self.walk(stmt.orelse, held)
             return held
         if isinstance(stmt, ast.Try):

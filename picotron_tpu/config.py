@@ -154,6 +154,41 @@ class ModelConfig:
     # materializes fp32 logits), "gathered" (reference-parity
     # all-gather + plain CE), "vocab_parallel" (local logits, psum'd stats).
     loss_impl: str = "auto"
+    # Which block ``models/`` builds (models.model_module): "llama" (every
+    # dense MHA/GQA + SwiGLU model) or "deepseek_v32" (latent attention
+    # with a learned sparse selection, routed and shared experts —
+    # models/deepseek_v32.py, serving path only). The fields below are the
+    # published ``config.json`` keys of that block, by their own names, and
+    # are read by no other block.
+    model_type: str = "llama"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # experts HELD here: this chip's share of the layer's
+    # n_routed_experts * ep_size, those from ep_rank * n_routed_experts on.
+    # The router keeps its whole width and its experts per token; what the
+    # absent experts would add is left out (docs/INFERENCE.md).
+    n_routed_experts: int = 0
+    n_shared_experts: int = 0
+    num_experts_per_tok: int = 0
+    moe_intermediate_size: int = 0
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    scoring_func: str = "sigmoid"
+    topk_method: str = "noaux_tc"
+    norm_topk_prob: bool = True
+    moe_layer_freq: int = 1
+    first_k_dense_replace: int = 0
+    num_nextn_predict_layers: int = 0
+    rope_scaling: Optional[dict] = None  # the published YaRN group, whole
+    ep_size: int = 1
+    ep_rank: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -884,9 +919,12 @@ class Config:
     def tokens_per_step(self) -> int:
         return self.global_batch_size * self.training.seq_length
 
-    def validate(self) -> None:
+    def validate(self, for_training: bool = False) -> None:
         """Divisibility constraints, surfaced as errors the way the reference
-        uses asserts (train.py:85-86, model.py:94-95, tensor_parallel.py:226)."""
+        uses asserts (train.py:85-86, model.py:94-95, tensor_parallel.py:226).
+        ``for_training`` is what the training entry points pass
+        (train_step.init_state / build_train_step): a block that only
+        serves refuses there, by name."""
         d, m, t = self.distributed, self.model, self.training
         if t.seq_length % d.cp_size != 0:
             raise ValueError(f"seq_length {t.seq_length} % cp_size {d.cp_size} != 0")
@@ -992,6 +1030,11 @@ class Config:
                     f"fsdp needs hidden_size ({m.hidden_size}) divisible by "
                     f"dp_size ({d.dp_size}) — every layer param shards on an "
                     f"H-sized axis")
+        if m.model_type not in ("llama", "deepseek_v32"):
+            raise ValueError(
+                f"unknown model_type {m.model_type!r} (llama|deepseek_v32)")
+        if m.model_type == "deepseek_v32":
+            self._validate_deepseek_v32(for_training)
         if m.attention_impl not in ("auto", "sdpa", "flash"):
             raise ValueError(
                 f"unknown attention_impl {m.attention_impl!r} (auto|sdpa|flash)")
@@ -1292,6 +1335,100 @@ class Config:
             # silently never fire — refuse instead
             raise ValueError(
                 "chaos_*_step injection requires training.steps_per_call == 1")
+
+    def _validate_deepseek_v32(self, for_training: bool) -> None:
+        """What ``models/deepseek_v32.py`` needs of its keys, and what it
+        cannot do yet, each refused by name so that nothing runs the Llama
+        block under this model's name."""
+        d, m, inf = self.distributed, self.model, self.inference
+        who = "model_type 'deepseek_v32'"
+        if for_training:
+            raise ValueError(
+                f"{who} is served, not trained: training is not implemented "
+                "for this block (no backward through the selection and the "
+                "expert share; train_step builds the Llama block only)")
+        if d.tp_size > 1:
+            raise ValueError(
+                f"{who} does not support tp_size > 1 (got {d.tp_size}): the "
+                "latent cache has no head axis to shard and the block holds "
+                "no tp collectives; its share of a layer is ep_size/ep_rank")
+        if inf.kv_layout == "paged":
+            raise ValueError(
+                f"{who} does not support inference.kv_layout 'paged': the "
+                "latent cache is contiguous only (paged_kv.py pages K/V "
+                "heads); set kv_layout: 'contiguous'")
+        if inf.kv_cache_dtype == "int8":
+            raise ValueError(
+                f"{who} does not support inference.kv_cache_dtype 'int8': "
+                "the latent cache is stored in the model's dtype")
+        if inf.weight_dtype == "int8":
+            raise ValueError(
+                f"{who} does not support inference.weight_dtype 'int8': its "
+                "matmuls take dense weights only")
+        if inf.tenancy.tenants or inf.tenancy.manifest:
+            raise ValueError(
+                f"{who} does not support LoRA adapters (inference.tenancy): "
+                "the adapter pack is shaped for the Llama block's seven "
+                "projections")
+        if inf.spec_len > 0:
+            raise ValueError(
+                f"{who} does not support speculation (inference.spec_len "
+                f"{inf.spec_len}): there is no verify program for this "
+                "block and the MTP module is cut with the depth")
+        if inf.attend_impl != "dense":
+            raise ValueError(
+                f"{who} does not support inference.attend_impl "
+                f"{inf.attend_impl!r}: the flash-decode kernel reads K/V "
+                "heads, not latent rows")
+        if inf.overlap or inf.mixed_dispatch or inf.key_schedule == "slot" \
+                or inf.dp_size > 1:
+            raise ValueError(
+                f"{who} serves through the serial round-keyed programs "
+                "only: inference.overlap, mixed_dispatch, key_schedule "
+                "'slot' and dp_size > 1 are not implemented for it")
+        for name in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                     "qk_rope_head_dim", "v_head_dim", "index_n_heads",
+                     "index_head_dim", "index_topk", "n_routed_experts",
+                     "n_shared_experts", "num_experts_per_tok",
+                     "moe_intermediate_size", "n_group", "topk_group",
+                     "ep_size"):
+            if getattr(m, name) < 1:
+                raise ValueError(f"{who} needs model.{name} >= 1")
+        if m.qk_rope_head_dim % 2 or m.index_head_dim < m.qk_rope_head_dim:
+            raise ValueError(
+                f"{who}: qk_rope_head_dim ({m.qk_rope_head_dim}) must be "
+                f"even and fit index_head_dim ({m.index_head_dim})")
+        if not 0 <= m.ep_rank < m.ep_size:
+            raise ValueError(
+                f"{who}: ep_rank {m.ep_rank} outside [0, ep_size "
+                f"{m.ep_size})")
+        width = m.n_routed_experts * m.ep_size
+        if width % m.n_group or m.topk_group > m.n_group \
+                or m.num_experts_per_tok > m.topk_group * (width // m.n_group) \
+                or width // m.n_group < 2:
+            raise ValueError(
+                f"{who}: the router's width {width} (n_routed_experts x "
+                f"ep_size) must split into n_group {m.n_group} groups of at "
+                f"least 2, with topk_group {m.topk_group} <= n_group and "
+                f"num_experts_per_tok {m.num_experts_per_tok} experts "
+                "inside the kept groups")
+        if not 0 <= m.first_k_dense_replace <= m.num_hidden_layers:
+            raise ValueError(
+                f"{who}: first_k_dense_replace {m.first_k_dense_replace} "
+                f"outside [0, num_hidden_layers {m.num_hidden_layers}]")
+        for name, want in (("scoring_func", "sigmoid"),
+                           ("topk_method", "noaux_tc"),
+                           ("norm_topk_prob", True), ("moe_layer_freq", 1),
+                           ("num_nextn_predict_layers", 0)):
+            if getattr(m, name) != want:
+                raise ValueError(
+                    f"{who} implements model.{name} = {want!r} only (got "
+                    f"{getattr(m, name)!r})")
+        rs = m.rope_scaling
+        if rs is not None and rs.get("type", rs.get("rope_type")) != "yarn":
+            raise ValueError(
+                f"{who} implements rope_scaling type 'yarn' only (got "
+                f"{rs!r})")
 
     # ---- JSON round-trip (reference: train.py:62-63 consumes one JSON file) ----
 

@@ -390,6 +390,12 @@ class ContinuousBatcher:
         # the round's wall time tiled into step/plan, step/admit,
         # step/issue, step/sync, step/deliver (docs/OBSERVABILITY.md)
         self._phases = RoundPhases(self.obs)
+        # what the block's layers count (``STAT_NAMES``; none of the Llama
+        # block), added where a round is delivered
+        self._model_counters = [
+            reg.counter(f"picotron_{name}_total",
+                        "counted inside the programs by the model's layers")
+            for name in engine.model.STAT_NAMES]
         self._prefill_tokens_total = reg.counter(
             "picotron_prefill_tokens_total",
             "prompt tokens run through a prefill program (solo, chunked "
@@ -1843,6 +1849,7 @@ class ContinuousBatcher:
                 self._merge_hidden(hid, out[1])
                 t1 = self._clock()
                 self._phases.to("step/deliver")
+                self._count_model_stats()
                 dt_sync = t1 - t_sync
                 with self._scratch_mu:
                     self._host_sync_s = dt_sync
@@ -1883,6 +1890,18 @@ class ContinuousBatcher:
             max(0.0, self._clock() - t_step0 - self._step_sync_wait))
 
     # ---- overlapped (zero-bubble) scheduling ------------------------------
+
+    def _count_model_stats(self) -> None:
+        """Add what the model's layers counted since the last round (this
+        round's decode block, and the prefills admitted before it) to the
+        registry: ``picotron_<name>_total`` for each of the block's
+        ``STAT_NAMES`` (docs/OBSERVABILITY.md). A block that does not
+        count costs one attribute read."""
+        stats = self.engine.take_stats()
+        if stats is None:
+            return
+        for counter, n in zip(self._model_counters, stats):
+            counter.inc(float(n))
 
     def _note_issue(self, t0: float) -> None:
         """Record the issue-to-issue scheduling gap: host time between

@@ -62,12 +62,12 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from picotron_tpu import comm_trace
+from picotron_tpu import comm_trace, models
 from picotron_tpu.config import Config
 from picotron_tpu.inference import kv_cache, paged_kv, sampling
 from picotron_tpu.obs import Obs
 from picotron_tpu.models import llama
-from picotron_tpu.ops.rope import precompute_rope, rope_at_positions
+from picotron_tpu.ops.rope import rope_at_positions
 from picotron_tpu.parallel.tp import tp_gather
 from picotron_tpu.topology import Topology, build_topology, named_shardings
 from picotron_tpu.utils import log0, shard_map
@@ -133,6 +133,13 @@ class InferenceEngine:
         self.cfg = inference_config(cfg)
         m, d = self.cfg.model, self.cfg.distributed
         inf = self.cfg.inference
+        # the block this engine serves (models/__init__.py: the seam).
+        # ``_n_stats`` > 0: its layers count, and every program of the
+        # prefill and decode families returns one more small array, a row
+        # of counters a layer (``take_stats``)
+        self.model = models.model_module(m)
+        self._n_stats = len(self.model.STAT_NAMES)
+        self._stats_pending: list = []
         self.dp_size = int(inf.dp_size or 1)
         if self.dp_size < 1:
             raise ValueError("inference.dp_size must be >= 1")
@@ -379,12 +386,23 @@ class InferenceEngine:
                     self.num_pages, prefix_cache=inf.prefix_cache)
                 self.pages_per_shard = self.num_pages
 
+        if m.model_type != "llama":
+            # what another block cannot do yet is refused by name
+            # (``Config.validate``), whether it was asked for in the config
+            # or by a keyword here
+            inf.spec_len = self.spec_len
+            self.cfg.validate()
+            if adapters is not None or self.quantized:
+                raise ValueError(
+                    f"model_type {m.model_type!r} serves without adapters "
+                    "and with its cache in the model's dtype")
         # angle tables cover the whole cache window; decode gathers rows at
         # each slot's own offset
-        self._cos, self._sin = precompute_rope(
-            self.max_seq_len, m.head_dim, m.rope_theta, self._dt)
+        self._cos, self._sin = self.model.serving_rope_tables(
+            m, self.max_seq_len, self._dt)
 
-        self._pspecs = llama.param_pspecs(m, weight_dtype=self.weight_dtype)
+        self._pspecs = self.model.param_pspecs(
+            m, weight_dtype=self.weight_dtype)
         # Multi-tenant adapter pack (inference/tenancy.py): when present,
         # every dispatch binds per-row adapter ids into the params tree
         # (llama.bind_adapters) and the compiled programs grow the
@@ -427,8 +445,8 @@ class InferenceEngine:
                                                  policy=self.page_policy,
                                                  dp=self.dp_size)
         else:
-            self._cspecs = kv_cache.cache_pspecs(self.quantized,
-                                                 dp=self.dp_size)
+            self._cspecs = self.model.cache_pspecs(m, self.quantized,
+                                                   dp=self.dp_size)
         self._build_programs()
         # kv_cache.release works on both layouts (a paged release is the
         # same 1-element length write; the host manager frees the pages)
@@ -473,7 +491,7 @@ class InferenceEngine:
                                        donate_argnums=(0,),
                                        out_shardings=cache_sh)
             self._init_cache_jit = jax.jit(
-                partial(kv_cache.init_cache, m, self.slots,
+                partial(self.model.init_cache, m, self.slots,
                         self.max_seq_len, dtype=self.cache_dtype,
                         quantized=self.quantized),
                 out_shardings=named_shardings(topo, self._cspecs))
@@ -490,7 +508,8 @@ class InferenceEngine:
         base_cspecs = (paged_kv.cache_pspecs(self.quantized,
                                              policy=self.page_policy)
                        if self.paged is not None
-                       else kv_cache.cache_pspecs(self.quantized))
+                       else self.model.cache_pspecs(self.cfg.model,
+                                                    self.quantized))
         kv_spec = {n: s for n, s in base_cspecs.items()
                    if n not in paged_kv.META_LEAVES}
         mesh = self.topo.mesh
@@ -514,22 +533,24 @@ class InferenceEngine:
         # programs
         hid = (P(),) if self.return_hidden else ()
         hidB = (dpP,) if self.return_hidden else ()
+        # a block that counts appends its layers' counters, last of all
+        st = (P(),) if self._n_stats else ()
         self._prefill_jit = jax.jit(shard_map(
             self._prefill_impl, mesh,
             in_specs=(self._dispatch_pspecs, P(), P()) + samp,
-            out_specs=(kv_spec, P()) + hid))
+            out_specs=(kv_spec, P()) + hid + st))
         self._prefill_chunk_jit = jax.jit(shard_map(
             self._prefill_chunk_impl, mesh,
             in_specs=(self._dispatch_pspecs, self._cspecs,
                       P(), P(), P(), P()) + samp,
-            out_specs=(self._cspecs, P()) + hid),
+            out_specs=(self._cspecs, P()) + hid + st),
             donate_argnums=(1,))
         self._decode_jit = jax.jit(shard_map(
             self._decode_impl, mesh,
             in_specs=(self._decode_dispatch_pspecs, self._cspecs,
                       dpP, P(), dpP, dpP, dpP),
             out_specs=((self._cspecs, dpP) if sod
-                       else (self._cspecs, dpP, dpP)) + hidB),
+                       else (self._cspecs, dpP, dpP)) + hidB + st),
             donate_argnums=(1,))
         self._decode_block_jit = self._make_decode_block_jit()
         self._decode_block_poison_jit = None  # chaos-only; built on demand
@@ -617,7 +638,8 @@ class InferenceEngine:
             partial(self._decode_block_impl, poison=poison), self.topo.mesh,
             in_specs=(self._decode_dispatch_pspecs, self._cspecs,
                       dpP, P(), dpP, dpP, dpP, dpP, dpP),
-            out_specs=(self._cspecs, dpP, dpP) + hidB),
+            out_specs=(self._cspecs, dpP, dpP) + hidB
+            + ((P(),) if self._n_stats else ())),
             donate_argnums=(1,))
 
     def _decode_block_prog(self, poison: bool):
@@ -826,25 +848,51 @@ class InferenceEngine:
         S = tokens.shape[1]
         cos_l = lax.dynamic_slice_in_dim(self._cos, 0, S, 0)
         sin_l = lax.dynamic_slice_in_dim(self._sin, 0, S, 0)
-        h = llama.embed_lookup(params["embed"], tokens).astype(self._dt)
+        h = self.model.embed_lookup(params["embed"], tokens).astype(self._dt)
+        if self._n_stats:
+            live = jnp.arange(S, dtype=jnp.int32)[None, :] < length[:, None]
+            h, kv, stats = self._prefill_groups(params, h, cos_l, sin_l,
+                                                live)
+        else:
 
-        def body(hc, lp):
-            hc, kv = llama.decoder_layer(lp, hc, cos_l, sin_l, cfg,
-                                         return_kv=True)
-            return hc, kv
+            def body(hc, lp):
+                hc, kv = llama.decoder_layer(lp, hc, cos_l, sin_l, cfg,
+                                             return_kv=True)
+                return hc, kv
 
-        h, (K, V) = lax.scan(body, h, params["layers"])
+            h, (K, V) = lax.scan(body, h, params["layers"])
+            kv, stats = self._pack_kv(K, V), None
         # only the last real token's logits are consumed: slice its hidden
         # row BEFORE the LM-head matmul and the vocab tp-gather, so the
         # bucket pays one [1, H] @ [H, V] row instead of S_bucket of them
         h_last = jnp.take_along_axis(h, (length - 1)[:, None, None], axis=1)
-        last = tp_gather(llama.head_logits(params, h_last, cfg))[:, 0]
+        last = tp_gather(self.model.head_logits(params, h_last, cfg))[:, 0]
         last = last.astype(jnp.float32)
         out = self._epilogue(last, *sample) if self.sample_on_device \
             else last
-        if self.return_hidden:
-            return self._pack_kv(K, V), out, h_last[:, 0]
-        return self._pack_kv(K, V), out
+        out = (kv, out, h_last[:, 0]) if self.return_hidden else (kv, out)
+        return out if stats is None else out + (stats,)
+
+    def _prefill_groups(self, params, h, cos_l, sin_l, live):
+        """The one-shot prefill of a block that counts: its groups of
+        layers scanned one after the other over the whole sequence, each
+        layer returning the rows it would write to a cache and its stats.
+        (h, the rows stacked over all layers in the cache's dtype, the
+        stats [layers, counters])."""
+        rows, stats = [], []
+        for name, layer_fn, count in self.model.layer_groups(self.cfg.model):
+            xs, whole = self._group_xs(params[name], count)
+
+            def body(hc, lp):
+                return layer_fn({**lp, **whole}, hc, cos_l, sin_l, self.cfg,
+                                return_kv=True, live=live)
+
+            h, out = lax.scan(body, h, xs)
+            stats.append(out.pop(models.STATS))
+            rows.append(out)
+        kv = {n: jnp.concatenate([r[n] for r in rows]).astype(
+            self.cache_dtype) for n in rows[0]}
+        return h, kv, jnp.concatenate(stats)
 
     def _meta(self, cache) -> dict:
         """The layer-less host-owned metadata leaves a paged cache carries
@@ -912,10 +960,15 @@ class InferenceEngine:
             row = jnp.where(gate, row, jnp.zeros_like(row))
         return {**meta, "block_tables": row}
 
-    def _scan_layers(self, layers, cache, h, cos_b, sin_b, pos, meta):
+    def _scan_layers(self, params, cache, h, cos_b, sin_b, pos, meta):
         """THE layer scan of every serving program: run ``h`` through the
-        stacked ``layers`` against the cache, return (h, updated stacked
-        leaves). The [L, ...] cache leaves ride the scan's CARRY beside
+        model's groups of stacked layers (``layer_groups``: one for the
+        Llama block; dense layers, then expert layers), one scan after the
+        other against the one cache, return (h, updated stacked leaves).
+        Of a block that counts, the returned dict also holds the stats
+        [layers, counters] (``models.STATS``: not a leaf; the programs
+        take it out before they rebuild the cache; a layer's own row, so
+        that no int32 sums over layers). The [L, ...] cache leaves ride the scan's CARRY beside
         the residual stream and what the scan iterates over is the
         stacked params and the layer INDEX — nothing cache-shaped is a
         scan input or output, so XLA keeps the cache in the one buffer
@@ -927,19 +980,42 @@ class InferenceEngine:
         leaves and never enter the carry."""
         leaves = {n: a for n, a in cache.items()
                   if n not in paged_kv.META_LEAVES}
-        index = jnp.arange(self.cfg.model.num_hidden_layers,
-                           dtype=jnp.int32)
+        first, stats = 0, None
+        for name, layer_fn, count in self.model.layer_groups(self.cfg.model):
+            index = jnp.arange(first, first + count, dtype=jnp.int32)
+            stack, whole = self._group_xs(params[name], count)
 
-        def body(carry, xs):
-            hc, lv = carry
-            lp, layer = xs
-            hc, out = llama.decoder_layer(lp, hc, cos_b, sin_b, self.cfg,
-                                          cache={**lv, **meta}, pos=pos,
-                                          layer=layer)
-            return (hc, {n: out[n] for n in lv}), None
+            def body(carry, xs):
+                hc, lv = carry
+                lp, layer = xs
+                hc, out = layer_fn({**lp, **whole}, hc, cos_b, sin_b,
+                                   self.cfg, cache={**lv, **meta}, pos=pos,
+                                   layer=layer)
+                # what a block that counts counted in this layer
+                return (hc, {n: out[n] for n in lv}), out.get(models.STATS)
 
-        (h, leaves), _ = lax.scan(body, (h, leaves), (layers, index))
+            (h, leaves), counted = lax.scan(body, (h, leaves),
+                                            (stack, index))
+            if counted is not None:
+                stats = counted if stats is None else jnp.concatenate(
+                    [stats, counted])
+            first += count
+        if stats is not None:
+            leaves[models.STATS] = stats
         return h, leaves
+
+    def _group_xs(self, stack, count: int) -> tuple:
+        """(what a scan over a group of ``count`` stacked layers iterates,
+        the leaves its layers are handed whole): the model's ``UNSLICED``
+        leaves stay out of the scan's slices, and the layer finds its own
+        row of them under ``"row"``. A block without such leaves scans its
+        stack as it is."""
+        whole = {n: stack[n] for n in self.model.UNSLICED if n in stack}
+        if not whole:
+            return stack, {}
+        xs = {n: v for n, v in stack.items() if n not in whole}
+        xs["row"] = jnp.arange(count, dtype=jnp.int32)
+        return xs, whole
 
     def _rebuild(self, cache, new_leaves, lengths):
         """Reassemble a cache pytree from the layer scan's updated stacked
@@ -961,11 +1037,14 @@ class InferenceEngine:
         ragged verify's ``draft_valid`` write mask). Lengths are NOT
         advanced here — callers apply their own activity rule."""
         cos_b, sin_b = rope_at_positions(self._cos, self._sin, rows)
-        h = llama.embed_lookup(params["embed"], tokens).astype(self._dt)
+        h = self.model.embed_lookup(params["embed"], tokens).astype(self._dt)
         meta = {**self._local_meta(cache), **(extra_meta or {})}
-        h, new_leaves = self._scan_layers(params["layers"], cache, h,
+        if self._n_stats:
+            # free slots (length 0) ride along uncounted and unrouted
+            meta["live"] = jnp.broadcast_to((pos > 0)[:, None], rows.shape)
+        h, new_leaves = self._scan_layers(params, cache, h,
                                           cos_b, sin_b, pos, meta)
-        logits = tp_gather(llama.head_logits(params, h, self.cfg))
+        logits = tp_gather(self.model.head_logits(params, h, self.cfg))
         return new_leaves, logits.astype(jnp.float32), h
 
     def _decode_core(self, params, cache, tokens):
@@ -988,6 +1067,7 @@ class InferenceEngine:
         states [B, H] — the learned drafter's input."""
         pos = cache["lengths"]
         new_leaves, logits, h = self._decode_core(params, cache, tokens)
+        stats = new_leaves.pop(models.STATS, None)
         next_tok = sampling.sample(logits, key, temperature, top_k, top_p)
         # free slots (length 0) ride along for shape stability but stay at
         # length 0 — their row-0 writes are never visible
@@ -995,7 +1075,8 @@ class InferenceEngine:
                                   jnp.where(pos > 0, pos + 1, 0))
         out = ((new_cache, next_tok) if self.sample_on_device
                else (new_cache, next_tok, logits))
-        return out + (h,) if self.return_hidden else out
+        out = out + (h,) if self.return_hidden else out
+        return out if stats is None else out + (stats,)
 
     def _decode_block_impl(self, params, cache, tokens, keys, eos_id,
                            budget, temperature, top_k, top_p,
@@ -1034,6 +1115,9 @@ class InferenceEngine:
             pos = cache["lengths"]
             active = (pos > 0) & (budget > 0)
             new_leaves, logits, h = self._decode_core(params, cache, tok)
+            # a block that counts: its step's stats leave with the tokens
+            counted = ((new_leaves.pop(models.STATS),) if self._n_stats
+                       else ())
             if poison:
                 logits = jnp.full_like(logits, jnp.nan)
             sampled = sampling.sample(logits, key_t, temperature,
@@ -1046,13 +1130,15 @@ class InferenceEngine:
                                       jnp.where(active, pos + 1, pos))
             next_tok = jnp.where(active, sampled, tok)
             new_hid = jnp.where(active[:, None], h, hid) if rh else hid
-            return (new_cache, next_tok, new_budget, new_hid), (emit, active)
+            return (new_cache, next_tok, new_budget, new_hid), \
+                (emit, active) + counted
 
-        (cache, _, _, hid), (toks, actives) = lax.scan(
+        (cache, _, _, hid), (toks, actives, *counted) = lax.scan(
             step, (cache, tokens, budget, hid0), keys)
         out = (cache, jnp.swapaxes(toks, 0, 1),
                jnp.sum(actives.astype(jnp.int32), axis=0))
-        return out + (hid,) if rh else out
+        out = out + (hid,) if rh else out
+        return out + tuple(jnp.sum(c, axis=0) for c in counted)
 
     def _verify_impl(self, params, cache, tokens, valid, key, eos_id,
                      budget, temperature, top_k, top_p, poison=False):
@@ -1247,7 +1333,7 @@ class InferenceEngine:
         start = jnp.asarray(start, jnp.int32)
         pos_rows = (start + jnp.arange(C, dtype=jnp.int32))[None, :]  # [1,C]
         cos_b, sin_b = rope_at_positions(self._cos, self._sin, pos_rows)
-        h = llama.embed_lookup(params["embed"], tokens).astype(self._dt)
+        h = self.model.embed_lookup(params["embed"], tokens).astype(self._dt)
         lengths = cache["lengths"]
         pos = jnp.full((1,), start, jnp.int32)
         # dp > 1: every shard traces the same chunk, but only the slot's
@@ -1256,13 +1342,17 @@ class InferenceEngine:
         # scribble their NULL scratch page (paged); their reads never
         # feed the result (logits psum-masked below, lengths untouched)
         loc, owner = self._slot_owner(slot)
-        h, new_leaves = self._scan_layers(
-            params["layers"], cache, h, cos_b, sin_b, pos,
-            self._slot_meta(cache, loc, owner))
+        meta = self._slot_meta(cache, loc, owner)
+        if self._n_stats:
+            # the chunk's pad rows are neither counted nor routed
+            meta["live"] = (jnp.arange(C, dtype=jnp.int32) < valid)[None, :]
+        h, new_leaves = self._scan_layers(params, cache, h, cos_b, sin_b,
+                                          pos, meta)
+        stats = new_leaves.pop(models.STATS, None)
         idx = jnp.clip(valid - 1, 0, C - 1)
         h_last = jnp.take_along_axis(
             h, jnp.full((1, 1, 1), idx, jnp.int32), axis=1)
-        last = tp_gather(llama.head_logits(params, h_last, cfg))[:, 0]
+        last = tp_gather(self.model.head_logits(params, h_last, cfg))[:, 0]
         last = self._owner_reduce(last.astype(jnp.float32), owner)
         new_lengths = lengths.at[loc].set(start + valid)
         if owner is not None:
@@ -1270,9 +1360,9 @@ class InferenceEngine:
         new_cache = self._rebuild(cache, new_leaves, new_lengths)
         out = self._epilogue(last, *sample) if self.sample_on_device \
             else last
-        if self.return_hidden:
-            return new_cache, out, self._owner_reduce(h_last[:, 0], owner)
-        return new_cache, out
+        out = ((new_cache, out, self._owner_reduce(h_last[:, 0], owner))
+               if self.return_hidden else (new_cache, out))
+        return out if stats is None else out + (stats,)
 
     def _lane_chunk(self, params, cache, tokens, slot, start, valid, *rest):
         """The fused prefill LANE: one fixed-width chunk for one slot per
@@ -1330,7 +1420,7 @@ class InferenceEngine:
         lengths = cache["lengths"]
         pos = jnp.full((1,), start_i, jnp.int32)
         h, new_leaves = self._scan_layers(
-            lane_params["layers"], cache, h, cos_b, sin_b, pos,
+            lane_params, cache, h, cos_b, sin_b, pos,
             self._slot_meta(cache, slot_i, active))
         idx = jnp.clip(valid_i - 1, 0, C - 1)
         h_last = jnp.take_along_axis(
@@ -1375,6 +1465,29 @@ class InferenceEngine:
         return (ln[0],) + d[1:] + ln[1:]
 
     # ---- host-facing API ---------------------------------------------------
+
+    def _strip_stats(self, out: tuple) -> tuple:
+        """Take a counting block's stats (the dispatch's last output,
+        [layers, counters] int32) off ``out`` and keep it, still on the device, for
+        ``take_stats``; every caller sees the tuple the Llama block
+        returns."""
+        if not self._n_stats:
+            return out
+        self._stats_pending.append(out[-1])
+        return out[:-1]
+
+    def take_stats(self):
+        """What the block counted in the dispatches since the last call,
+        summed over them and over the layers (int64, one number for each
+        of ``model.STAT_NAMES``; None of a block that does not count). The batcher calls it where it delivers a round, when the
+        round's results are on the host anyway."""
+        if not self._n_stats:
+            return None
+        pending, self._stats_pending = self._stats_pending, []
+        total = np.zeros(self._n_stats, np.int64)
+        for v in pending:
+            total += np.asarray(v, np.int64).sum(axis=0)
+        return total
 
     def shard_params(self, params):
         """Place a (global) parameter pytree onto this engine's mesh with
@@ -1714,9 +1827,9 @@ class InferenceEngine:
         self._hook("prefill")
         # resolved inside the lambda like every hot-path program, so the
         # flash->dense fallback's rebuilt jit is what a re-dispatch runs
-        return self._dispatch(lambda: self._prefill_jit(
+        return self._strip_stats(self._dispatch(lambda: self._prefill_jit(
             params, jnp.asarray(padded),
-            jnp.asarray([ids.size], jnp.int32), *samp))
+            jnp.asarray([ids.size], jnp.int32), *samp)))
 
     def prefill_chunked(self, params, cache, prompt_ids, slot: int,
                         start: int = 0, sample=None,
@@ -1780,11 +1893,12 @@ class InferenceEngine:
                 cache = self._ensure(cache, slot, w0, end)
                 cache = self._sync_tables(cache)
             self._hook("prefill_chunk")
-            out = self._dispatch(lambda: self._prefill_chunk_jit(
-                params, cache, jnp.asarray(padded),
-                jnp.asarray(slot, jnp.int32),
-                jnp.asarray(w0, jnp.int32),
-                jnp.asarray(chunk.size, jnp.int32), *samp))
+            out = self._strip_stats(self._dispatch(
+                lambda: self._prefill_chunk_jit(
+                    params, cache, jnp.asarray(padded),
+                    jnp.asarray(slot, jnp.int32),
+                    jnp.asarray(w0, jnp.int32),
+                    jnp.asarray(chunk.size, jnp.int32), *samp)))
             if self.return_hidden:
                 cache, logits, hidden = out
             else:
@@ -2028,12 +2142,12 @@ class InferenceEngine:
             params = self.bind_adapter_ids(params, adapter_ids, self.slots)
         if self.paged is not None:
             cache = self._pre_write(cache, 1)
-        out = self._dispatch(lambda: self._decode_jit(
+        out = self._strip_stats(self._dispatch(lambda: self._decode_jit(
             params, cache,
             jnp.asarray(np.asarray(tokens, np.int32)), key,
             jnp.asarray(np.asarray(temperature, np.float32)),
             jnp.asarray(np.asarray(top_k, np.int32)),
-            jnp.asarray(np.asarray(top_p, np.float32))))
+            jnp.asarray(np.asarray(top_p, np.float32)))))
         if self.paged is not None:
             # mirror the device rule: parked slots advanced by one
             self.paged.advance((self.paged.host_len > 0).astype(np.int64))
@@ -2102,13 +2216,14 @@ class InferenceEngine:
                   else jnp.asarray(np.asarray(tokens, np.int32)))
         # the program is resolved INSIDE the lambda so the flash->dense
         # fallback's rebuilt jits are what a re-dispatch runs
-        out = self._dispatch(lambda: self._decode_block_prog(poison)(
-            params, cache, tok_in, keys,
-            jnp.asarray(np.asarray(eos_id, np.int32)),
-            jnp.asarray(np.asarray(budget, np.int32)),
-            jnp.asarray(np.asarray(temperature, np.float32)),
-            jnp.asarray(np.asarray(top_k, np.int32)),
-            jnp.asarray(np.asarray(top_p, np.float32)), *lane_args))
+        out = self._strip_stats(self._dispatch(
+            lambda: self._decode_block_prog(poison)(
+                params, cache, tok_in, keys,
+                jnp.asarray(np.asarray(eos_id, np.int32)),
+                jnp.asarray(np.asarray(budget, np.int32)),
+                jnp.asarray(np.asarray(temperature, np.float32)),
+                jnp.asarray(np.asarray(top_k, np.int32)),
+                jnp.asarray(np.asarray(top_p, np.float32)), *lane_args)))
         if self.paged is not None and not self.defer_advance:
             # mirror device length advancement (counts per slot). The
             # host sync this forces is the block's ONE sync, just moved

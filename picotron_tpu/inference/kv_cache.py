@@ -95,6 +95,39 @@ def init_cache(m: ModelConfig, slots: int, max_seq_len: int,
     return cache
 
 
+# The latent cache (models/deepseek_v32.py): one row a token and layer and no
+# head axis. ``ckv`` is the normalised compressed K/V every head expands
+# from (kv_lora_rank wide) and, behind it in the same row, the one RoPE key
+# all heads share (qk_rope_head_dim), zero-padded to whole lanes: the TPU
+# lays out a leaf whose rows are not whole lanes (64 wide, or 576) with the
+# tokens minor-most, and every program would copy it whole on its way in and
+# out. ``ki`` is the sparse selection's indexer key (index_head_dim). Laid
+# out ``[L, slots, T, width]`` like K and V, so the whole-cache ops below
+# (``insert_prefill``, ``release``) and ``write_rows`` take it as it is;
+# there is nothing for 'tp' to shard.
+LATENT_LEAVES = ("ckv", "ki")
+LANE = 128
+
+
+def latent_widths(m: ModelConfig) -> dict:
+    return {"ckv": -(-(m.kv_lora_rank + m.qk_rope_head_dim) // LANE) * LANE,
+            "ki": m.index_head_dim}
+
+
+def latent_cache_pspecs() -> dict:
+    return {**{n: P() for n in LATENT_LEAVES}, "lengths": P()}
+
+
+def init_latent_cache(m: ModelConfig, slots: int, max_seq_len: int,
+                      dtype=None) -> dict:
+    """Zeroed latent cache for ``slots`` concurrent sequences."""
+    dt = jnp.dtype(dtype if dtype is not None else m.dtype)
+    cache = {n: jnp.zeros((m.num_hidden_layers, slots, max_seq_len, w), dt)
+             for n, w in latent_widths(m).items()}
+    cache["lengths"] = jnp.zeros((slots,), jnp.int32)
+    return cache
+
+
 def cache_bytes(cache: dict) -> int:
     """Total bytes the cache pytree occupies (K/V + scales + lengths) —
     the HBM-budget metric the int8 mode halves."""
@@ -193,43 +226,49 @@ def cache_write(cache: dict, k_new: jnp.ndarray, v_new: jnp.ndarray,
 
         return paged_kv.cache_write(cache, k_new, v_new, pos, layer)
     out = dict(cache)
-    valid = out.pop("draft_valid", None)
-    slot, gate = cache.get("slot", 0), cache.get("gate")
-    layer = jnp.asarray(layer, jnp.int32)
-    B, S = k_new.shape[0], k_new.shape[1]
-    T = cache["k"].shape[2]
-    one_slot = "slot" in cache or gate is not None or (B == 1 and S > 1)
-
-    def put(name, vals):
-        leaf = cache[name]
-        vals = vals.astype(leaf.dtype)
-        if one_slot:
-            at = (layer, jnp.asarray(slot, jnp.int32),
-                  jnp.asarray(pos[0], jnp.int32))
-            at += (jnp.zeros((), jnp.int32),) * (leaf.ndim - len(at))
-            vals = vals[None]
-            if gate is not None:
-                vals = jnp.where(gate, vals,
-                                 lax.dynamic_slice(leaf, at, vals.shape))
-            return lax.dynamic_update_slice(leaf, vals, at)
-        if S == 1:
-            return leaf.at[layer, jnp.arange(B), pos].set(vals[:, 0])
-        rows = pos[:, None] + jnp.arange(S, dtype=pos.dtype)[None, :]
-        if valid is not None:
-            # ragged mask: rows past the slot's own real-token count
-            # go out of bounds, where the scatter drops them
-            cols = jnp.arange(S, dtype=jnp.int32)[None, :]
-            rows = jnp.where(cols < valid[:, None], rows, T)
-        return leaf.at[layer, jnp.arange(B)[:, None], rows].set(vals)
-
+    out.pop("draft_valid", None)
     for name, sname, new in (("k", "k_scale", k_new), ("v", "v_scale", v_new)):
         if quantized(cache):
             vals, scales = quantize_kv(new)
-            out[sname] = put(sname, scales)
+            out[sname] = write_rows(cache, sname, scales, pos, layer)
         else:
             vals = new
-        out[name] = put(name, vals)
+        out[name] = write_rows(cache, name, vals, pos, layer)
     return out
+
+
+def write_rows(cache: dict, name: str, vals: jnp.ndarray, pos: jnp.ndarray,
+               layer) -> jnp.ndarray:
+    """Stacked leaf ``name`` with ``vals`` [B, S, ...] written into
+    ``layer`` in place, in whichever of ``cache_write``'s three shapes the
+    addressing entries of ``cache`` (``slot``, ``gate``, ``draft_valid``)
+    and the widths pick. Any leaf laid out ``[L, slots, T, ...]`` is
+    written this way: K and V, their scales, the rows of a latent cache."""
+    leaf = cache[name]
+    valid = cache.get("draft_valid")
+    slot, gate = cache.get("slot", 0), cache.get("gate")
+    layer = jnp.asarray(layer, jnp.int32)
+    B, S = vals.shape[0], vals.shape[1]
+    T = leaf.shape[2]
+    vals = vals.astype(leaf.dtype)
+    if "slot" in cache or gate is not None or (B == 1 and S > 1):
+        at = (layer, jnp.asarray(slot, jnp.int32),
+              jnp.asarray(pos[0], jnp.int32))
+        at += (jnp.zeros((), jnp.int32),) * (leaf.ndim - len(at))
+        vals = vals[None]
+        if gate is not None:
+            vals = jnp.where(gate, vals,
+                             lax.dynamic_slice(leaf, at, vals.shape))
+        return lax.dynamic_update_slice(leaf, vals, at)
+    if S == 1:
+        return leaf.at[layer, jnp.arange(B), pos].set(vals[:, 0])
+    rows = pos[:, None] + jnp.arange(S, dtype=pos.dtype)[None, :]
+    if valid is not None:
+        # ragged mask: rows past the slot's own real-token count
+        # go out of bounds, where the scatter drops them
+        cols = jnp.arange(S, dtype=jnp.int32)[None, :]
+        rows = jnp.where(cols < valid[:, None], rows, T)
+    return leaf.at[layer, jnp.arange(B)[:, None], rows].set(vals)
 
 
 def layer_block(cache: dict, name: str, layer):

@@ -1,0 +1,618 @@
+"""The DeepSeek-V3.2 block as pure functions over a parameter pytree: latent
+attention (MLA) with a learned sparse selection (DSA), routed and shared
+experts. Serving path only (``Config.validate`` refuses the rest by name).
+
+The equations (``x`` the normed residual stream; RMSNorm unless said):
+
+- queries: ``c_q = norm(x W_qa)``; ``q = c_q W_qb`` -> heads x (nope | rope),
+  RoPE on the rope part;
+- latent K/V: ``x W_kva`` -> (kv_lora_rank | rope); ``c_kv = norm(first)``,
+  ``k_r = RoPE(last)``, one for all heads. The cache holds ``[c_kv | k_r]`` in one row;
+- indexer: ``q^I = c_q W^I_qb`` -> index heads x index dim, ``k^I =
+  LayerNorm(x W^I_k)`` (with bias), RoPE on the first ``qk_rope_head_dim`` of
+  each, ``w = (x W^I_w) * heads^-0.5 * dim^-0.5`` in float32. Key ``s``
+  scores ``I[t, s] = sum_h w[t, h] * ReLU(q^I[t, h] . k^I[s])`` for ``s <=
+  t``; the selected set is the ``min(index_topk, t + 1)`` keys of largest
+  score, exact, ties to the lower index. The cache holds ``k^I``;
+- attention over the selected rows, in the latent space (``W_kvb`` viewed
+  ``[rank, heads, nope + v]``): ``q~[h] = q_nope[h] . W_kvb[:, h, :nope]``,
+  ``a[t, h, s] = (q~[t, h] . c_kv[s] + q_r[t, h] . k_r[s]) * scale``, softmax
+  in float32, ``o[h] = (sum_s p c_kv[s]) . W_kvb[:, h, nope:]``, ``x +=
+  concat(o) W_o``; ``scale = (nope + rope)^-0.5 * mscale^2`` (ops/rope.py);
+- experts (behind the ``first_k_dense_replace`` dense SwiGLU layers): ``s =
+  sigmoid(x W_g)`` in float32 over the router's whole width
+  (``n_routed_experts * ep_size``); the choice is made on ``s + b``: groups
+  scored by the sum of their two best, the best ``topk_group`` groups kept,
+  the best ``num_experts_per_tok`` experts among them; their weights are the
+  unbiased ``s``, normalised to sum 1, times ``routed_scaling_factor``. This
+  chip holds ``n_routed_experts`` of the experts (``ep_rank * n_routed_experts``
+  onward) and adds their part and the shared expert's; what the absent
+  experts would add is left out. No token is ever dropped.
+
+Departures from the published inference code: the indexer's Hadamard rotation
+of ``q^I``, ``k^I`` is left out (orthogonal: the dot product is unchanged) and
+its keys are kept in the model's dtype, not FP8.
+
+RoPE pairing as published: adjacent pairs in the attention
+(``apply_rope_interleaved``), halves in the indexer (``apply_rope``).
+
+Every layer function returns, beside the updated cache leaves, what it
+counted (``STATS``: an int32 vector in the order of ``STAT_NAMES``; the
+programs return a row a layer, summed over a block's steps, the engine adds
+the rows up on the host and the batcher adds them to the registry;
+docs/OBSERVABILITY.md). A layer's largest count is the keys scored by a
+one-shot prefill, S^2 / 2: under 2^31 while S < 65,536, far past where its
+[S, S] scores fit a chip.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import PartitionSpec as P
+
+from picotron_tpu.config import Config, ModelConfig
+from picotron_tpu.inference import kv_cache
+from picotron_tpu.models import STATS
+from picotron_tpu.models.llama import (  # noqa: F401 - the seam's shared parts
+    embed_lookup,
+    head_logits,
+    param_bytes,
+)
+from picotron_tpu.ops.attention import NEG_INF
+from picotron_tpu.ops.rmsnorm import rms_norm
+from picotron_tpu.ops.rope import (
+    apply_rope,
+    apply_rope_interleaved,
+    precompute_rope,
+    yarn_mscale,
+)
+
+# what a layer counts, in the order of the vector (under ``STATS``)
+STAT_NAMES = ("moe_assignments", "moe_experts_hit", "moe_layer_steps",
+              "dsa_keys_selected", "dsa_keys_scored")
+
+# keys scored by the indexer, and queries attended, at a time: bounds the
+# [B, S, index heads, keys] products and the [B, S, selected, rank] rows
+KEY_BLOCK = 2048
+QUERY_BLOCK = 128
+
+LAYER_NORM_EPS = 1e-6  # the indexer's LayerNorm
+
+
+# --------------------------------------------------------------------------- #
+# parameters
+# --------------------------------------------------------------------------- #
+
+
+def router_width(m: ModelConfig) -> int:
+    return m.n_routed_experts * m.ep_size
+
+
+def _attn_shapes(m: ModelConfig) -> dict:
+    H, nh = m.hidden_size, m.num_attention_heads
+    return {
+        "wq_a": (H, m.q_lora_rank),
+        "wq_b": (m.q_lora_rank, nh * (m.qk_nope_head_dim
+                                      + m.qk_rope_head_dim)),
+        "wkv_a": (H, m.kv_lora_rank + m.qk_rope_head_dim),
+        "wkv_b": (m.kv_lora_rank, nh * (m.qk_nope_head_dim + m.v_head_dim)),
+        "wo": (nh * m.v_head_dim, H),
+        "wi_q": (m.q_lora_rank, m.index_n_heads * m.index_head_dim),
+        "wi_k": (H, m.index_head_dim),
+        "wi_w": (H, m.index_n_heads),
+    }
+
+
+def _group_shapes(m: ModelConfig, dense: bool) -> dict:
+    """Matmul leaves of one layer of a group, (in, out) like every weight
+    here; the routed experts lead with the experts held."""
+    H = m.hidden_size
+    shapes = _attn_shapes(m)
+    if dense:
+        I = m.intermediate_size
+        shapes.update(w_gate=(H, I), w_up=(H, I), w_down=(I, H))
+        return shapes
+    E, I = m.n_routed_experts, m.moe_intermediate_size
+    Is = m.n_shared_experts * I
+    shapes.update(router=(H, router_width(m)),
+                  w1=(E, H, I), w3=(E, H, I), w2=(E, I, H),
+                  ws_gate=(H, Is), ws_up=(H, Is), ws_down=(Is, H))
+    return shapes
+
+
+# leaves of a group the layer scan does not slice a layer at a time: the
+# layer function is handed the whole stack and its row in it (``lp["row"]``)
+UNSLICED = ("w1", "w3", "w2")
+
+
+def layer_groups(m: ModelConfig) -> list:
+    """[(name of the stacked group in the tree, its layer function, how many
+    layers)]: the leading dense layers, then the expert layers. The engine
+    scans one group after the other over one cache."""
+    k = m.first_k_dense_replace
+    groups = [("dense_layers", dense_layer, k),
+              ("layers", moe_layer, m.num_hidden_layers - k)]
+    return [g for g in groups if g[2] > 0]
+
+
+# Seeded weights are drawn so that the block's mechanisms matter to a
+# comparison of logits. With every matrix U(+-sqrt(1 / fan_in)) the
+# attention's output is a mean of ~1400 rows, a hundredth of the residual
+# stream beside an MLP ten times as loud: neither which keys were selected
+# nor a cache row that is wrong moves a logit by more than bf16's own
+# rounding does, and a check of logits guards neither. ``wo`` four times
+# wider makes the attention as loud as the MLP. The softmax stays as flat as
+# the draw gives it (its logits spread by 0.6): a sharper one (``wq_b``
+# wider) turns bf16's rounding of a logit into the weight's, and the sound
+# program's own error grew faster than any fault's (PERF.md, PR 28). The
+# routed experts' ``w2`` half as wide: a held expert chosen in bf16 and not
+# in float32 (a tie of the router broken by rounding, which is no fault)
+# then moves a logit by a third of the check's limit and not by two thirds.
+INIT_GAIN = {"wo": 4.0, "w2": 0.5}
+
+
+def init_params(key, m: ModelConfig, pp_size: int = 1,
+                interleave: int = 1) -> dict:
+    """Global parameter pytree from ``key``: linear weights U(+-gain *
+    sqrt(1 / fan_in)) (``INIT_GAIN``, else 1) drawn in the model's dtype (no
+    float32 copy of an 8 GB tree is ever made), embedding N(0, 1), norm
+    weights ones; the router's correction bias and the indexer's LayerNorm
+    bias are drawn small, so that they matter to a comparison."""
+    if pp_size != 1 or interleave != 1:
+        raise ValueError("deepseek_v32 is served on one stage (pp_size 1)")
+    dt = jnp.dtype(m.dtype)
+    H, V = m.hidden_size, m.vocab_size
+
+    def uniform(k, shape, fan_in, gain=1.0):
+        bound = gain * math.sqrt(1.0 / fan_in)
+        return jax.random.uniform(k, shape, dt, -bound, bound)
+
+    def group(gkey, n: int, dense: bool) -> dict:
+        ones = lambda w: jnp.ones((n, w), dt)
+        out = {"attn_norm": ones(H), "q_norm": ones(m.q_lora_rank),
+               "kv_norm": ones(m.kv_lora_rank), "mlp_norm": ones(H),
+               "ki_norm": ones(m.index_head_dim)}
+        shapes = sorted(_group_shapes(m, dense).items())
+        for i, (name, shape) in enumerate(shapes):
+            out[name] = uniform(jax.random.fold_in(gkey, i), (n,) + shape,
+                                shape[-2], INIT_GAIN.get(name, 1.0))
+        kb = jax.random.fold_in(gkey, len(shapes))
+        out["ki_bias"] = jax.random.uniform(
+            kb, (n, m.index_head_dim), dt, -0.1, 0.1)
+        if not dense:
+            out["router_bias"] = jax.random.uniform(
+                jax.random.fold_in(kb, 1), (n, router_width(m)),
+                jnp.float32, -0.02, 0.02)
+        return out
+
+    params = {
+        "embed": jax.random.normal(jax.random.fold_in(key, 0), (V, H), dt),
+        "final_norm": jnp.ones((H,), dt),
+        "lm_head": uniform(jax.random.fold_in(key, 1), (H, V), H),
+    }
+    for i, (name, fn, n) in enumerate(layer_groups(m)):
+        params[name] = group(jax.random.fold_in(key, 2 + i), n,
+                             fn is dense_layer)
+    return params
+
+
+def param_pspecs(m: ModelConfig, fsdp: bool = False,
+                 weight_dtype: str = "bf16") -> dict:
+    """Every leaf replicated: the block is served at tp_size 1 (its share
+    of a layer is ``ep_size``/``ep_rank``, a cut and not a mesh axis)."""
+    if fsdp or weight_dtype != "bf16":
+        raise ValueError("deepseek_v32 serves dense weights, unsharded")
+    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
+    return jax.tree.map(lambda _: P(), shapes)
+
+
+def num_params(m: ModelConfig) -> int:
+    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
+    return sum(math.prod(s.shape) for s in jax.tree.leaves(shapes))
+
+
+# --------------------------------------------------------------------------- #
+# serving state: the RoPE tables and the latent cache
+# --------------------------------------------------------------------------- #
+
+
+def serving_rope_tables(m: ModelConfig, seq_len: int, dtype) -> tuple:
+    """(cos, sin) [seq_len, qk_rope_head_dim]: YaRN's blended frequencies
+    when the configuration scales its RoPE."""
+    return precompute_rope(seq_len, m.qk_rope_head_dim, m.rope_theta, dtype,
+                           scaling=m.rope_scaling)
+
+
+def softmax_scale(m: ModelConfig) -> float:
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    if m.rope_scaling is not None:
+        scale *= yarn_mscale(m.rope_scaling) ** 2
+    return scale
+
+
+def cache_pspecs(m: ModelConfig, quantized: bool = False,
+                 dp: int = 1) -> dict:
+    """The latent cache is served whole on one chip, in the model's dtype
+    (``Config.validate`` refuses the rest by name)."""
+    assert not quantized and dp == 1
+    return kv_cache.latent_cache_pspecs()
+
+
+def init_cache(m: ModelConfig, slots: int, max_seq_len: int, dtype=None,
+               quantized: bool = False) -> dict:
+    assert not quantized
+    return kv_cache.init_latent_cache(m, slots, max_seq_len, dtype=dtype)
+
+
+# --------------------------------------------------------------------------- #
+# latent attention with the learned sparse selection
+# --------------------------------------------------------------------------- #
+
+
+def _key_block(src: dict, name: str, layer, t0, n: int):
+    """Keys ``t0 .. t0 + n`` of stacked leaf ``name`` at ``layer``, read
+    where they lie: [B, n, width] ([1, n, width] of the one slot a ``slot``
+    entry names)."""
+    leaf = src[name]
+    B = 1 if "slot" in src else leaf.shape[1]
+    at = (jnp.asarray(layer, jnp.int32),
+          jnp.asarray(src.get("slot", 0), jnp.int32),
+          jnp.asarray(t0, jnp.int32), jnp.zeros((), jnp.int32))
+    return lax.dynamic_slice(leaf, at, (1, B, n, leaf.shape[3]))[0]
+
+
+def _key_blocks(T: int) -> int:
+    """Keys handled at a time in a window of ``T``: ``KEY_BLOCK``, or what
+    of it divides the window."""
+    return T if T <= KEY_BLOCK else math.gcd(T, KEY_BLOCK)
+
+
+def _live_blocks(pos_q, T: int, Tb: int):
+    """How many leading blocks of ``Tb`` keys some query at ``pos_q`` can
+    see: the rest of the window is never read."""
+    return jnp.minimum(jnp.max(pos_q), T - 1) // Tb + 1
+
+
+def index_scores(qi, wi, src: dict, layer, pos_q):
+    """The indexer's scores [B, S, T] float32 of every key of the window for
+    queries at ``pos_q`` [B, S]: ``sum_h w[h] * ReLU(q^I[h] . k^I[s])`` for
+    ``s <= pos_q``, -inf past it. Keys are scored ``KEY_BLOCK`` at a time,
+    and only the blocks a query can see are read."""
+    B, S = pos_q.shape
+    T = src["ki"].shape[2]
+    Tb = _key_blocks(T)
+
+    def body(j, buf):
+        kb = _key_block(src, "ki", layer, j * Tb, Tb)  # [B, Tb, D]
+        s = jnp.einsum("bshd,btd->bsht", qi, kb,
+                       preferred_element_type=jnp.float32)
+        s = jnp.sum(jax.nn.relu(s) * wi[..., None], axis=2)  # [B, S, Tb]
+        t = j * Tb + jnp.arange(Tb, dtype=jnp.int32)
+        s = jnp.where(t[None, None, :] <= pos_q[..., None], s, -jnp.inf)
+        return lax.dynamic_update_slice(buf, s, (0, 0, j * Tb))
+
+    buf = jnp.full((B, S, T), -jnp.inf, jnp.float32)
+    if T == Tb:
+        return body(0, buf)
+    return lax.fori_loop(0, _live_blocks(pos_q, T, Tb), body, buf)
+
+
+def select_keys(scores, k: int):
+    """[B, S, T] bool: for each query the ``k`` keys of largest score,
+    exact, ties to the lower index; keys whose score is -inf (past the
+    query, or a query that sees fewer than ``k``) are never chosen.
+
+    No sort: the k-th largest score is found four bits at a time (eight
+    passes over the row, each counting the keys at or above fifteen
+    candidates: floats compare like the integers their bits spell, once
+    negatives are flipped), everything above it is chosen, and of the keys
+    equal to it the first ones, until ``k`` are. An exact ``lax.top_k`` of
+    2048 out of 24576 is a whole sort on the TPU: a millisecond a decode
+    step and layer, and 60 times that a prefill chunk."""
+    T = scores.shape[-1]
+    valid = scores > -jnp.inf
+    if k >= T:
+        return valid
+    bits = lax.bitcast_convert_type(scores, jnp.int32)
+    # order-preserving: float order -> signed int order -> unsigned order
+    key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    key = lax.bitcast_convert_type(key, jnp.uint32) ^ jnp.uint32(0x80000000)
+    nibble = jnp.arange(1, 16, dtype=jnp.uint32)
+
+    def body(i, thr):
+        shift = jnp.uint32(28) - 4 * i.astype(jnp.uint32)
+        cands = thr[..., None] | (nibble << shift)  # [B, S, 15], rising
+        n = jnp.sum(key[..., None, :] >= cands[..., None], axis=-1,
+                    dtype=jnp.int32)
+        # the counts fall as the candidates rise: as many candidates have
+        # k keys at or above them as the largest such candidate's nibble
+        return thr | (jnp.sum(n >= k, axis=-1).astype(jnp.uint32) << shift)
+
+    # the largest value with at least k keys at or above it: the k-th largest
+    thr = lax.fori_loop(0, 8, body, jnp.zeros(scores.shape[:-1], jnp.uint32))
+    above = key > thr[..., None]
+    ties = key == thr[..., None]
+    room = k - jnp.sum(above, axis=-1, dtype=jnp.int32)
+    first = jnp.cumsum(ties, axis=-1, dtype=jnp.int32) <= room[..., None]
+    return (above | (ties & first)) & valid
+
+
+def _attend_selected(q, chosen, src: dict, layer, scale: float, rank: int,
+                     pos_q):
+    """Softmax attention of each query ``q`` [B, S, heads, row width] (its
+    latent part, its RoPE part, zeros) over the keys ``chosen`` [B, S, T]
+    for it, in the latent space: [B, S, heads, rank] float32. The window's
+    live blocks of keys are read once each, where they lie, the softmax
+    kept running over them (max, sum, weighted rows)."""
+    B, S, nh, _ = q.shape
+    T = src["ckv"].shape[2]
+    Tb = _key_blocks(T)
+
+    def body(j, carry):
+        m, l, acc = carry
+        kb = _key_block(src, "ckv", layer, j * Tb, Tb)  # [B, Tb, width]
+        s = jnp.einsum("bshc,btc->bsht", q, kb,
+                       preferred_element_type=jnp.float32) * scale
+        on = lax.dynamic_slice_in_dim(chosen, j * Tb, Tb, axis=2)
+        on = on[:, :, None, :]
+        m_new = jnp.maximum(m, jnp.max(jnp.where(on, s, NEG_INF), axis=-1))
+        p = jnp.where(on, jnp.exp(s - m_new[..., None]), 0.0)
+        fade = jnp.exp(m - m_new)
+        l = l * fade + jnp.sum(p, axis=-1)
+        acc = acc * fade[..., None] + jnp.einsum(
+            "bsht,btc->bshc", p.astype(kb.dtype), kb[..., :rank],
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    carry = (jnp.full((B, S, nh), NEG_INF, jnp.float32),
+             jnp.zeros((B, S, nh), jnp.float32),
+             jnp.zeros((B, S, nh, rank), jnp.float32))
+    if T == Tb:
+        _, l, acc = body(0, carry)
+    else:
+        _, l, acc = lax.fori_loop(0, _live_blocks(pos_q, T, Tb), body, carry)
+    return acc / l[..., None]
+
+
+def _layer_norm(x, w, b):
+    x32 = x.astype(jnp.float32)
+    mu = jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean((x32 - mu) ** 2, axis=-1, keepdims=True)
+    return ((x32 - mu) * lax.rsqrt(var + LAYER_NORM_EPS)).astype(x.dtype) \
+        * w + b
+
+
+def attention(lp, x, cos, sin, m: ModelConfig, cache, pos, layer, live):
+    """The attention half of a layer on the normed stream ``x`` [B, S, H]:
+    (output [B, S, H], the cache dict with this layer's rows written (or,
+    without a cache, the rows themselves as a one-layer stack), keys
+    selected, keys scored). ``pos`` [B] is each sequence's first position;
+    ``live`` [B, S] marks the queries that are counted."""
+    B, S, _ = x.shape
+    nh, dn, dr = m.num_attention_heads, m.qk_nope_head_dim, m.qk_rope_head_dim
+    dv, R = m.v_head_dim, m.kv_lora_rank
+    eps = m.rms_norm_eps
+    if cos.ndim == 2:
+        cos, sin = cos[None], sin[None]
+    if pos is None:
+        pos = jnp.zeros((B,), jnp.int32)
+    pos_q = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+
+    with jax.named_scope("mla_proj"):
+        c_q = rms_norm(x @ lp["wq_a"], lp["q_norm"], eps)
+        q = (c_q @ lp["wq_b"]).reshape(B, S, nh, dn + dr)
+        q_r = apply_rope_interleaved(q[..., dn:], cos, sin)
+        kv = x @ lp["wkv_a"]
+        ckv = rms_norm(kv[..., :R], lp["kv_norm"], eps)
+        kr = apply_rope_interleaved(kv[..., None, R:], cos, sin)[:, :, 0]
+        wkv_b = lp["wkv_b"].reshape(R, nh, dn + dv)
+        q_lat = jnp.einsum("bshd,chd->bshc", q[..., :dn], wkv_b[..., :dn])
+        # a cache row is [c_kv | k_r | 0 ..] out to whole lanes
+        # (kv_cache.latent_widths), and a query is laid out the same
+        lanes = jnp.zeros((B, S, kv_cache.latent_widths(m)["ckv"] - R - dr),
+                          x.dtype)
+        q_cat = jnp.concatenate(
+            [q_lat, q_r, jnp.broadcast_to(lanes[:, :, None],
+                                          (B, S, nh, lanes.shape[-1]))],
+            axis=-1)
+    with jax.named_scope("dsa_index"):
+        qi = (c_q @ lp["wi_q"]).reshape(B, S, m.index_n_heads,
+                                        m.index_head_dim)
+        qi = jnp.concatenate(
+            [apply_rope(qi[..., :dr], cos, sin), qi[..., dr:]], axis=-1)
+        ki = _layer_norm(x @ lp["wi_k"], lp["ki_norm"], lp["ki_bias"])
+        ki = jnp.concatenate(
+            [apply_rope(ki[..., None, :dr], cos, sin)[:, :, 0],
+             ki[..., dr:]], axis=-1)
+        wi = (x @ lp["wi_w"]).astype(jnp.float32) \
+            * (m.index_n_heads ** -0.5 * m.index_head_dim ** -0.5)
+
+    rows = {"ckv": jnp.concatenate([ckv, kr, lanes], axis=-1), "ki": ki}
+    if cache is None:
+        # a whole sequence at once: its own rows are the keys
+        src = {n: r[None] for n, r in rows.items()}
+        layer = 0
+    else:
+        src = dict(cache)
+        for n, r in rows.items():
+            src[n] = kv_cache.write_rows(cache, n, r, pos, layer)
+
+    with jax.named_scope("dsa_index"):
+        scores = index_scores(qi, wi, src, layer, pos_q)
+    with jax.named_scope("dsa_select"):
+        chosen = select_keys(scores, m.index_topk)
+    with jax.named_scope("mla_attend"):
+        scale = softmax_scale(m)
+        Sb = S if S <= QUERY_BLOCK else math.gcd(S, QUERY_BLOCK)
+        if Sb == S:
+            o_lat = _attend_selected(q_cat, chosen, src, layer, scale, R,
+                                     pos_q)
+        else:
+            def blocks(a):  # [B, S, ...] -> [S / Sb, B, Sb, ...]
+                return jnp.moveaxis(
+                    a.reshape(B, S // Sb, Sb, *a.shape[2:]), 1, 0)
+
+            o_lat = lax.map(
+                lambda xs: _attend_selected(xs[0], xs[1], src, layer, scale,
+                                            R, xs[2]),
+                tuple(blocks(a) for a in (q_cat, chosen, pos_q)))
+            o_lat = jnp.moveaxis(o_lat, 0, 1).reshape(B, S, nh, R)
+        o = jnp.einsum("bshc,chd->bshd", o_lat.astype(x.dtype),
+                       wkv_b[..., dn:])
+        out = o.reshape(B, S, nh * dv) @ lp["wo"]
+
+    selected = jnp.sum(jnp.where(live[..., None], chosen, False),
+                       dtype=jnp.int32)
+    scored = jnp.sum(jnp.where(live, pos_q + 1, 0), dtype=jnp.int32)
+    return out, src, selected, scored
+
+
+# --------------------------------------------------------------------------- #
+# experts
+# --------------------------------------------------------------------------- #
+
+
+def route(scores, bias, m: ModelConfig) -> tuple:
+    """(experts [N, num_experts_per_tok] int32, weights [N, the same]
+    float32) from the sigmoid scores [N, width]: the choice is made on
+    ``scores + bias`` (groups by the sum of their two best, the best
+    ``topk_group`` groups, the best experts among them; ties to the lower
+    index), the weights are the unbiased scores of the chosen, normalised
+    to sum 1, times ``routed_scaling_factor``."""
+    N, W = scores.shape
+    choice = scores + bias
+    groups = choice.reshape(N, m.n_group, W // m.n_group)
+    group_score = jnp.sum(lax.top_k(groups, 2)[0], axis=-1)  # [N, n_group]
+    _, kept = lax.top_k(group_score, m.topk_group)
+    keep = jnp.zeros((N, m.n_group), bool).at[
+        jnp.arange(N)[:, None], kept].set(True)
+    choice = jnp.where(keep[:, :, None], groups, -jnp.inf).reshape(N, W)
+    _, experts = lax.top_k(choice, m.num_experts_per_tok)
+    w = jnp.take_along_axis(scores, experts, axis=-1)
+    w = w / jnp.sum(w, axis=-1, keepdims=True) * m.routed_scaling_factor
+    return experts.astype(jnp.int32), w
+
+
+def held_weights(experts, weights, m: ModelConfig):
+    """[N, n_routed_experts] float32: each token's weight on each expert
+    held here (``ep_rank * n_routed_experts`` onward), 0 where the token
+    did not choose it."""
+    held = m.ep_rank * m.n_routed_experts + jnp.arange(m.n_routed_experts)
+    return jnp.sum(jnp.where(experts[:, :, None] == held[None, None, :],
+                             weights[:, :, None], 0.0), axis=1)
+
+
+def _swiglu(x, w_gate, w_up, w_down):
+    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+
+
+def routed_experts(x, w_held, lp):
+    """``sum_e w_held[:, e] * E_e(x)`` over the experts held here, float32
+    [N, H], one expert after the other. Every held expert runs at every
+    step, chosen or not, as in the deployment this share is cut from (there
+    each has tokens at every step): skipping the ones no token chose
+    (``lax.cond``) made a decode step's time follow the seed's routing (8
+    tokens reach 1.5-2.2 of the 8; measured 450-467 tokens/s over seeds),
+    which is a property of the cut, not of the model. With a ``row``
+    entry, ``lp``'s expert leaves are the group's whole stacks
+    (``UNSLICED``) and this layer is that row of them: each expert's
+    matrices are then read in place (a layer's slice of the stack, handed
+    to the loop over experts, is a copy of all of them)."""
+    row = lp.get("row")
+
+    def weights(name, e):
+        w = lp[name]
+        return w[e] if row is None else w[row, e]
+
+    def one(acc, xs):
+        w, e = xs
+        y = _swiglu(x, weights("w1", e), weights("w3", e), weights("w2", e))
+        return acc + y.astype(jnp.float32) * w[:, None], None
+
+    n = w_held.shape[1]
+    acc, _ = lax.scan(one, jnp.zeros(x.shape, jnp.float32),
+                      (w_held.T, jnp.arange(n, dtype=jnp.int32)))
+    return acc
+
+
+def expert_mlp(lp, x, m: ModelConfig, live) -> tuple:
+    """The expert half of a layer on the normed stream ``x`` [B, S, H]:
+    (this chip's part of the routed sum + the shared expert, held
+    assignments, held experts hit). Rows that are not ``live`` are routed
+    nowhere."""
+    B, S, H = x.shape
+    x2 = x.reshape(B * S, H)
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(x2.astype(jnp.float32),
+                         lp["router"].astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        experts, weights = route(jax.nn.sigmoid(logits), lp["router_bias"],
+                                 m)
+        w_held = held_weights(experts, weights, m) \
+            * live.reshape(B * S, 1).astype(jnp.float32)
+    with jax.named_scope("moe_experts"):
+        y = routed_experts(x2, w_held, lp)
+    with jax.named_scope("shared_expert"):
+        y = y.astype(x.dtype) + _swiglu(x2, lp["ws_gate"], lp["ws_up"],
+                                        lp["ws_down"])
+    assigned = jnp.sum(w_held > 0, dtype=jnp.int32)
+    hit = jnp.sum(jnp.any(w_held > 0, axis=0), dtype=jnp.int32)
+    return y.reshape(B, S, H), assigned, hit
+
+
+# --------------------------------------------------------------------------- #
+# the two kinds of layer
+# --------------------------------------------------------------------------- #
+
+
+def _layer(lp, h, cos, sin, cfg: Config, cache, pos, return_kv, layer,
+           live, dense: bool):
+    m = cfg.model
+    if live is None:
+        live = (cache or {}).get("live")
+    if live is None:
+        live = jnp.ones(h.shape[:2], bool)
+    attn_cache = None if cache is None else {
+        n: v for n, v in cache.items() if n != "live"}
+    a, src, selected, scored = attention(
+        lp, rms_norm(h, lp["attn_norm"], m.rms_norm_eps), cos, sin, m,
+        attn_cache, pos, layer, live)
+    h = h + a
+    x = rms_norm(h, lp["mlp_norm"], m.rms_norm_eps)
+    zero = jnp.zeros((), jnp.int32)
+    if dense:
+        h = h + _swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
+        moe = (zero, zero, zero)
+    else:
+        y, assigned, hit = expert_mlp(lp, x, m, live)
+        h = h + y
+        moe = (assigned, hit, zero + 1)
+    stats = jnp.stack(moe + (selected, scored))
+    if return_kv:
+        # the sequence's rows, [1, S, width] each: a prefill's blocks
+        out = {n: src[n][0] for n in kv_cache.LATENT_LEAVES}
+    else:
+        out = dict(src)
+    out[STATS] = stats
+    return h, out
+
+
+def dense_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
+                return_kv: bool = False, layer=None, live=None):
+    """A leading dense layer: the attention, then a SwiGLU of
+    ``intermediate_size``. Same contract as ``llama.decoder_layer``; the
+    returned dict also holds ``STATS``."""
+    return _layer(lp, h, cos, sin, cfg, cache, pos, return_kv, layer, live,
+                  dense=True)
+
+
+def moe_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
+              return_kv: bool = False, layer=None, live=None):
+    """An expert layer: the attention, then the routed experts held here
+    and the shared expert."""
+    return _layer(lp, h, cos, sin, cfg, cache, pos, return_kv, layer, live,
+                  dense=False)

@@ -694,6 +694,37 @@ def layers_forward(stacked, h, cos, sin, cfg: Config):
     return h
 
 
+# What the serving stack takes from a model module besides the functions
+# above (models/__init__.py): the groups of stacked layers it scans, the
+# angle tables of a cache window, the contiguous cache, and the names of the
+# counters a layer returns.
+STAT_NAMES = ()
+UNSLICED = ()  # every leaf of a layer is sliced out of its stack by the scan
+
+
+def layer_groups(m: ModelConfig) -> list:
+    return [("layers", decoder_layer, m.num_hidden_layers)]
+
+
+def serving_rope_tables(m: ModelConfig, seq_len: int, dtype) -> tuple:
+    return precompute_rope(seq_len, m.head_dim, m.rope_theta, dtype)
+
+
+def cache_pspecs(m: ModelConfig, quantized: bool = False,
+                 dp: int = 1) -> dict:
+    from picotron_tpu.inference import kv_cache
+
+    return kv_cache.cache_pspecs(quantized, dp=dp)
+
+
+def init_cache(m: ModelConfig, slots: int, max_seq_len: int, dtype=None,
+               quantized: bool = False) -> dict:
+    from picotron_tpu.inference import kv_cache
+
+    return kv_cache.init_cache(m, slots, max_seq_len, dtype=dtype,
+                               quantized=quantized)
+
+
 def _head_input(params, h, cfg: Config):
     """Final norm + tp copy — the shared prefix of logits and loss paths.
     With sequence parallelism the norm runs on the local seq shard and the

@@ -11,15 +11,23 @@ Pallas kernel is needed for parity.
 
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 import numpy as np
 
 
-def precompute_rope(seq_length: int, head_dim: int, base: float, dtype) -> tuple:
+def precompute_rope(seq_length: int, head_dim: int, base: float, dtype,
+                    scaling: dict | None = None) -> tuple:
     """Return (cos, sin), each [seq_length, head_dim], computed in float64/32
-    on host for stable numerics (reference computes on CPU fp32, model.py:23)."""
+    on host for stable numerics (reference computes on CPU fp32, model.py:23).
+    ``scaling``: a YaRN group, whose blended frequencies (``yarn_inv_freq``)
+    take the plain ones' place."""
     assert head_dim % 2 == 0
-    inv_freq = 1.0 / (base ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
+    if scaling is not None:
+        inv_freq = yarn_inv_freq(head_dim, base, scaling)
+    else:
+        inv_freq = 1.0 / (base ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
     pos = np.arange(seq_length, dtype=np.float64)[:, None]  # [S, 1]
     angles = pos * inv_freq[None, :]  # [S, head_dim/2]
     cos = np.concatenate([np.cos(angles), np.cos(angles)], axis=-1)
@@ -54,3 +62,60 @@ def rope_at_positions(cos: jnp.ndarray, sin: jnp.ndarray,
         pos = pos[:, None]
     pos = jnp.clip(pos, 0, cos.shape[0] - 1)
     return jnp.take(cos, pos, axis=0), jnp.take(sin, pos, axis=0)
+
+
+# --------------------------------------------------------------------------- #
+# YaRN (models/deepseek_v32.py): a frequency blend and the softmax's mscale
+# --------------------------------------------------------------------------- #
+
+
+def yarn_inv_freq(dim: int, base: float, scaling: dict) -> np.ndarray:
+    """The ``dim/2`` inverse frequencies of a YaRN-scaled RoPE (Peng et al.
+    2023, as DeepSeek-V3's published code computes them): each is a blend of
+    ``base^(-2i/dim)`` (kept: the pairs that turn more than ``beta_fast``
+    times over the original window) and that over ``factor`` (the pairs
+    that turn less than ``beta_slow`` times), by a linear ramp over the
+    pair index between the two correction dims."""
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+
+    def correction_dim(rotations: float) -> float:
+        return dim * math.log(orig / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(float(scaling["beta_fast"]))), 0)
+    high = min(math.ceil(correction_dim(float(scaling["beta_slow"]))),
+               dim - 1)
+    if low == high:
+        high += 0.001  # the published code's guard against a zero ramp
+    plain = 1.0 / (base ** (np.arange(0, dim, 2, dtype=np.float64) / dim))
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def yarn_mscale(scaling: dict) -> float:
+    """``0.1 * mscale_all_dim * ln(factor) + 1``: the attention's softmax
+    scale is multiplied by its square when the window passes the original
+    one (the tables themselves stay unscaled: ``mscale == mscale_all_dim``
+    in the published configuration)."""
+    factor = float(scaling["factor"])
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * float(scaling.get("mscale_all_dim", 1.0)) \
+        * math.log(factor) + 1.0
+
+
+def apply_rope_interleaved(x: jnp.ndarray, cos: jnp.ndarray,
+                           sin: jnp.ndarray) -> jnp.ndarray:
+    """RoPE on adjacent pairs ``(x[2i], x[2i+1])``, the pairing DeepSeek's
+    published attention uses (``apply_rope`` pairs ``(x[i], x[i + D/2])``).
+    x: [batch, seq, heads, D]; cos/sin: per-sequence tables [batch, seq, D]
+    in the tiled layout, of which the first half holds the D/2 angles."""
+    half = x.shape[-1] // 2
+    c = cos[:, :, None, :half]
+    s = sin[:, :, None, :half]
+    pairs = x.reshape(*x.shape[:-1], half, 2)
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * c - b * s, a * s + b * c], axis=-1)
+    return out.reshape(x.shape)

@@ -51,11 +51,17 @@ def _load_weights(args, cfg, engine):
     import jax
 
     from picotron_tpu import checkpoint as ckpt
-    from picotron_tpu.models import llama
+    from picotron_tpu.models import llama, model_module
     from picotron_tpu.topology import named_shardings
 
     quant = getattr(engine, "quant_weights", False)
     wdt = "int8" if quant else "bf16"
+    model = model_module(cfg.model)
+    if model is not llama and (args.hf_path or args.load_path):
+        raise SystemExit(
+            f"model_type {cfg.model.model_type!r} is served from seeded "
+            "weights only (--random-init): the checkpoint readers know "
+            "the Llama tree")
     if args.hf_path:
         return ckpt.load_hf_safetensors(args.hf_path, cfg.model, engine.topo,
                                         weight_dtype=wdt)
@@ -82,7 +88,7 @@ def _load_weights(args, cfg, engine):
         print(f"loaded step {step} ({tokens} trained tokens) "
               f"from {args.load_path}")
         return engine.shard_params(params) if quant else params
-    params = jax.jit(lambda k: llama.init_params(k, cfg.model))(
+    params = jax.jit(lambda k: model.init_params(k, cfg.model))(
         jax.random.PRNGKey(args.seed))
     if quant:
         params = llama.quantize_params(params)

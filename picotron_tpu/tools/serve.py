@@ -155,6 +155,10 @@ class FrontEnd:
     the CLI installs it on the main thread; tests drive ``begin_drain``
     directly)."""
 
+    # submit() asks for the batcher lock this long at a time, and looks at
+    # the watchdog's verdict between asks
+    SUBMIT_WAIT_SLICE_S = 10.0
+
     def __init__(self, engine, params, *, seed: int = 0,
                  max_queue: int = 64, token_budget: Optional[int] = None,
                  default_timeout_s: Optional[float] = None,
@@ -193,9 +197,7 @@ class FrontEnd:
         # values + per-channel scales included, so a quantized replica
         # reports ~half its bf16 twin (docs/INFERENCE.md "Quantized
         # weights"); set once: weights never change size mid-serve
-        from picotron_tpu.models import llama
-
-        self.weight_bytes = llama.param_bytes(params)
+        self.weight_bytes = engine.model.param_bytes(params)
         self.obs.registry.gauge(
             "picotron_weight_bytes",
             "model weight bytes resident on this replica").set(
@@ -398,11 +400,20 @@ class FrontEnd:
         # chain's first link (a shed one keeps its span, with the error).
         with self.obs.timed("submit/lock_wait", self._submit_wait_hist,
                             uid=req.uid) as waited:
-            if not self._mu.acquire(timeout=10.0):
-                self._reject("stalled")
-                raise AdmissionError(
-                    503, "dispatch stalled (admission unavailable)",
-                    retry_after=10)
+            # a long step (several long prompts prefilled one after the
+            # other) is not a wedged one, and the watchdog is the one judge
+            # of that: wait in slices, and shed at the end of the first
+            # slice that finds the loop called stalled (or, should no
+            # watchdog be running, once this wait is itself as long as a
+            # step may take; with the watchdog off that is the first slice)
+            t0 = time.monotonic()
+            while not self._mu.acquire(timeout=self.SUBMIT_WAIT_SLICE_S):
+                if self.stalled or \
+                        time.monotonic() - t0 >= self.stall_timeout_s:
+                    self._reject("stalled")
+                    raise AdmissionError(
+                        503, "dispatch stalled (admission unavailable)",
+                        retry_after=10)
         try:
             if self.stopped.is_set():
                 # the dispatch loop is gone (drain done, or it died on an

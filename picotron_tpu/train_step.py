@@ -246,12 +246,21 @@ def sync_pp_replicated_grads(grads, pspecs):
     return tree_unflatten(tree_g, synced)
 
 
+def _llama_only(cfg: Config) -> None:
+    """The training programs build the Llama block; a block that only
+    serves is refused by name (``Config.validate(for_training=True)``)
+    and never trained as a Llama under its own name."""
+    if cfg.model.model_type != "llama":
+        cfg.validate(for_training=True)
+
+
 def init_state(cfg: Config, topo: Topology, seed: int | None = None):
     """Initialize params + optimizer state directly as sharded arrays:
     jit with out_shardings materializes each device's shard without ever
     building the global array — replacing the reference's meta-device init +
     per-rank materialization (checkpoint.py:15-48, 50-102)."""
     seed = cfg.training.seed if seed is None else seed
+    _llama_only(cfg)
     pspecs = llama.param_pspecs(cfg.model, fsdp=cfg.distributed.fsdp)
     shardings = named_shardings(topo, pspecs)
     key = jax.random.PRNGKey(seed)
@@ -292,6 +301,7 @@ def build_train_step(cfg: Config, topo: Topology, multi_step: int = 1,
     simulating a numerically blown step (resilience/chaos.py). Used by the
     fault-injection suite to drive the non-finite gate below; never enabled
     in production programs."""
+    _llama_only(cfg)
     mesh = topo.mesh
     pp = cfg.distributed.pp_size
     engine = cfg.distributed.pp_engine

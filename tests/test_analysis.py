@@ -1108,6 +1108,12 @@ def test_c003_lock_acquired_inside_a_with_body_outlives_it(tmp_path):
         "if not self._mu.acquire(timeout=10.0):\n"
         "                        raise RuntimeError('stalled')")))
     assert held == []
+    # the same acquire in slices: the loop ends when one succeeds
+    sliced = _scan(tmp_path, src.format(acquire=(
+        "while not self._mu.acquire(timeout=10.0):\n"
+        "                        if self.stalled:\n"
+        "                            raise RuntimeError('stalled')")))
+    assert sliced == []
     bare = _scan(tmp_path, src.format(acquire="pass"))
     assert _rules(bare) == ["PICO-C003"]
     assert bare[0].context == "Front.submit"

@@ -325,3 +325,76 @@ def test_serving_program_leaves_cache_in_place(prog, layout, topo, one_chip):
     assert temp < 1.5 * padded, (
         f"{prog}/{layout}: {temp / 1e6:.0f} MB of temporaries against a "
         f"lane-padded cache of {padded / 1e6:.0f} MB")
+
+
+# ---- the latent cache of the DeepSeek-V3.2 block (PR 28) -------------------
+
+
+def _latent_program(topo, prog):
+    """``prog`` of a 2-layer engine (one dense, one expert layer) at the
+    published DeepSeek-V3.2 widths and the benchmark cell's cache geometry
+    (8 slots x 24576), compiled for one described chip."""
+    import json
+
+    from picotron_tpu.config import Config
+    from picotron_tpu.inference.engine import InferenceEngine
+    from picotron_tpu.topology import build_topology, named_shardings
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "deepseek-v3.2-ep32-l7.json")) as f:
+        pub = json.load(f)
+    model = {k: pub[k] for k in pub["model_keys"]}
+    model.update({k: pub[k] for k in (
+        "num_attention_heads", "num_key_value_heads", "hidden_size",
+        "intermediate_size", "vocab_size", "rms_norm_eps", "rope_theta",
+        "max_position_embeddings")}, num_hidden_layers=2, dtype="bfloat16")
+    cfg = Config.from_dict({"model": model,
+                            "training": {"seq_length": 24576}})
+    mesh = build_topology(1, 1, 1, 1, devices=topo.devices)
+    eng = InferenceEngine(cfg, mesh, slots=8, max_seq_len=24576)
+
+    def abstract(tree, specs):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            tree, named_shardings(mesh, specs))
+
+    params = abstract(jax.eval_shape(
+        lambda: eng.model.init_params(jax.random.key(0), cfg.model)),
+        eng._pspecs)
+    cache = abstract(jax.eval_shape(eng._init_cache_jit), eng._cspecs)
+    rep = named_shardings(mesh, jax.sharding.PartitionSpec())
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep)
+    if prog == "decode_block":
+        jitted = eng._decode_block_jit
+        args = (arg((8,), I32), arg((eng.decode_block_len, 2), jnp.uint32),
+                arg((8,), I32), arg((8,), I32), arg((8,), F32),
+                arg((8,), I32), arg((8,), F32))
+    else:
+        jitted = eng._prefill_chunk_jit
+        args = (arg((1, eng.prefill_chunk), I32),) + (arg((), I32),) * 3
+    return jitted.lower(params, cache, *args).compile()
+
+
+@pytest.mark.parametrize("prog", ["decode_block", "prefill_chunk"])
+def test_latent_cache_is_row_major_and_never_copied(prog, topo, one_chip):
+    """The TPU lays a cache leaf whose rows are not whole lanes (64 wide, or
+    576) out with the tokens minor-most and copies it whole, in the layer
+    loop or at the program's entry and exit (PR 28 read both here): the
+    lane-padded ``[c_kv | k_r]`` row stays row-major, and no instruction
+    copies a whole leaf; nor is a layer's slice of the experts' stacks
+    copied out before the loop over experts."""
+    text = _latent_program(topo, prog).as_text()
+    leaf = r"bf16\[2,8,24576,(?:640|128)\]"
+    lines = text.splitlines()
+    copies = [l.strip()[:160] for l in lines
+              if re.search(rf"= {leaf}\S* copy\(", l)]
+    assert not copies, "\n".join(copies)
+    params = [l for l in lines if re.search(rf"cache__c?k[vi]__\S* = {leaf}", l)
+              and " parameter(" in l]
+    assert len(params) == 2, params
+    assert all("{3,2,1,0" in l for l in params), params
+    sliced = [l.strip()[:160] for l in lines
+              if re.search(r"= bf16\[(?:1,)?8,(?:7168,2048|2048,7168)\]", l)
+              and " parameter(" not in l and "get-tuple-element" not in l]
+    assert not sliced, "\n".join(sliced)

@@ -931,7 +931,8 @@ def test_shed_submit_keeps_its_wait_span_with_the_error():
 
     GLOBAL_TRACER.clear()
     cfg, engine, params = _engine(slots=2)
-    front = serve.FrontEnd(engine, params, log=lambda *a, **k: None)
+    front = serve.FrontEnd(engine, params, log=lambda *a, **k: None,
+                           stall_timeout_s=0)  # no watchdog: one slice
 
     class Wedged:  # the dispatch loop never lets go
         def acquire(self, timeout=None):
@@ -946,6 +947,46 @@ def test_shed_submit_keeps_its_wait_span_with_the_error():
     assert wait.args == {"uid": "s1", "error": "AdmissionError"}
     prom = parse_prometheus(engine.obs.registry.prometheus())
     assert prom["picotron_submit_lock_wait_seconds_count"] == 1
+
+
+@pytest.mark.parametrize("verdict", ["long_step", "stalled", "no_watchdog"])
+def test_submit_waits_in_slices_and_sheds_on_the_watchdogs_verdict(verdict):
+    """A step longer than one slice is waited out; the wait ends with 503
+    at the end of the slice that finds ``stalled`` set, or, with no
+    watchdog to say so, once it is as long as a step may take."""
+    from picotron_tpu.tools import serve
+
+    cfg, engine, params = _engine(slots=2)
+    front = serve.FrontEnd(engine, params, log=lambda *a, **k: None,
+                           stall_timeout_s=60.0)
+    front.SUBMIT_WAIT_SLICE_S = 0.01
+    real, asked = front._mu, []
+
+    class Busy:  # the loop holds the lock through three slices
+        def acquire(self, timeout=None):
+            asked.append(timeout)
+            if verdict == "stalled" and len(asked) == 3:
+                front.stalled = True
+            if verdict == "long_step" and len(asked) == 4:
+                return real.acquire(timeout=timeout)
+            return False
+
+        def release(self):
+            real.release()
+
+    front._mu = Busy()
+    if verdict == "long_step":
+        uid, _ = front.submit({"prompt": [1, 2], "uid": "s1"})
+        assert uid == "s1" and asked == [0.01] * 4
+        assert front.rejections["stalled"] == 0
+        return
+    if verdict == "no_watchdog":
+        front.stall_timeout_s = 0.05
+    with pytest.raises(serve.AdmissionError) as e:
+        front.submit({"prompt": [1, 2], "uid": "s1"})
+    assert e.value.status == 503 and front.rejections["stalled"] == 1
+    if verdict == "stalled":
+        assert len(asked) == 3
 
 
 def _prefill_counts(engine):
