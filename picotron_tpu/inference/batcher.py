@@ -213,7 +213,11 @@ class ContinuousBatcher:
         # registry (and the process span ring) covers engine + batcher +
         # front end, so /metrics is a single coherent page
         self.obs = obs if obs is not None else engine.obs
-        self._key = jax.random.PRNGKey(seed)
+        # the key chain lives on the engine's mesh from the start, so the
+        # round's key program (engine.round_keys) and the eager _split()
+        # each see ONE input sharding, the first call and every later one
+        self._key = jax.device_put(jax.random.PRNGKey(seed),
+                                   engine.key_sharding)
         # streaming hook: called as on_token(uid, token) for every token a
         # request emits, from inside step()/run() — the serve front end
         # pushes these straight into the response stream
@@ -1797,14 +1801,14 @@ class ContinuousBatcher:
                                                     spec_kinds,
                                                     lanes=lanes)
         else:
-            block = self.engine.decode_block_len
             if self._sched == "slot":
                 # per-slot bases: the program folds each row's position
                 # in-trace, so the operand is round-count-independent
                 keys = self._base_keys
             else:
-                keys = np.stack([np.asarray(self._split())
-                                 for _ in range(block)])
+                # one program advances the chain by the block's links and
+                # the keys stay on the device: nothing here waits for it
+                self._key, keys = self.engine.round_keys(self._key)
 
             def dispatch(b):
                 self._phases.to("step/issue")

@@ -79,6 +79,16 @@ from picotron_tpu.utils import log0, shard_map
 _FLASH_BROKEN = False
 
 
+def _key_chain(key, block: int):
+    """``block`` links of the batcher's key chain in one trace: each link
+    is the eager ``key, sub = jax.random.split(key)``, so the carried key
+    and the ``[block, 2]`` subkeys equal the eager loop's bit for bit."""
+    def link(k, _):
+        k, sub = jax.random.split(k)
+        return k, sub
+    return lax.scan(link, key, None, length=block)
+
+
 def inference_config(cfg: Config) -> Config:
     """Derive the serving config from a training config: same model, but a
     ('dp','tp') topology (pp=cp=1) with the training-only rewrites (sequence
@@ -448,6 +458,12 @@ class InferenceEngine:
             self._cspecs = self.model.cache_pspecs(m, self.quantized,
                                                    dp=self.dp_size)
         self._build_programs()
+        # the round schedule's keys (``round_keys``): made and kept on the
+        # device, replicated over the mesh as the round programs take them
+        self.key_sharding = named_shardings(topo, P())
+        self._round_keys_jit = jax.jit(
+            _key_chain, static_argnums=1,
+            out_shardings=(self.key_sharding, self.key_sharding))
         # kv_cache.release works on both layouts (a paged release is the
         # same 1-element length write; the host manager frees the pages)
         # dp>1: pin cache-shaped outputs of the host-side helper jits to
@@ -2158,6 +2174,16 @@ class InferenceEngine:
             cache, toks = out
             return cache, toks, None
         return out
+
+    def round_keys(self, key) -> tuple:
+        """One round of the batcher's key chain as ONE program and no
+        host copy: ``key`` (placed with ``key_sharding``) -> (the carried
+        key, keys [decode_block_len, 2]), both device arrays replicated
+        over the mesh. ``keys[j]`` is what the j-th of ``decode_block_len``
+        eager ``jax.random.split`` links would have handed out, bit for
+        bit; ``decode_block`` takes the array as it is and donates
+        nothing of it, so an isolation re-dispatch may reuse it."""
+        return self._round_keys_jit(key, self.decode_block_len)
 
     def decode_block(self, params, cache, tokens, keys, eos_id, budget,
                      temperature, top_k, top_p, adapter_ids=None,

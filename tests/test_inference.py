@@ -35,9 +35,9 @@ from picotron_tpu.utils import shard_map as shard_map_compat
 MAX_LEN = 96
 
 
-def _engine(tiny_model_kwargs, tp=1, slots=2):
+def _engine(tiny_model_kwargs, tp=1, slots=2, **kw):
     cfg = make_config(tiny_model_kwargs, tp=tp, seq=MAX_LEN)
-    return cfg, InferenceEngine(cfg, slots=slots, max_seq_len=MAX_LEN)
+    return cfg, InferenceEngine(cfg, slots=slots, max_seq_len=MAX_LEN, **kw)
 
 
 def _params(cfg, engine, seed=0):
@@ -273,6 +273,112 @@ def test_batcher_eos_terminates_early(tiny_model_kwargs):
         [Request("a", prompt, max_new_tokens=10, eos_id=eos)])["a"]
     assert res.finish_reason == "eos"
     assert res.tokens == free.tokens[:3]
+
+
+# --------------------------------------------------------------------------- #
+# (c') the round schedule's keys: one program a round, kept on the device
+# --------------------------------------------------------------------------- #
+
+
+def _eager_round(key, block):
+    """The chain as the batcher walked it before ``engine.round_keys``:
+    one eager split and one host copy a decode step."""
+    subs = []
+    for _ in range(block):
+        key, sub = jax.random.split(key)
+        subs.append(np.asarray(sub))
+    return key, np.stack(subs)
+
+
+@pytest.mark.parametrize("block", [1, 8])
+def test_round_keys_equal_eager_chain(tiny_model_kwargs, block):
+    """Three rounds of the key program, an admission's eager split between
+    them: keys and carried key equal the eager chain bit for bit."""
+    _, engine = _engine(tiny_model_kwargs, decode_block_len=block)
+    key = jax.device_put(jax.random.PRNGKey(11), engine.key_sharding)
+    eager = jax.random.PRNGKey(11)
+    for _ in range(3):
+        key, keys = engine.round_keys(key)
+        eager, want = _eager_round(eager, block)
+        assert isinstance(keys, jax.Array) and keys.shape == (block, 2)
+        assert np.array_equal(np.asarray(keys), want)
+        assert np.array_equal(np.asarray(key), np.asarray(eager))
+        key, sub = jax.random.split(key)  # as _admit's _split() does
+        eager, esub = jax.random.split(eager)
+        assert np.array_equal(np.asarray(sub), np.asarray(esub))
+
+
+# tokens of the tree before the key program (PR 28, jax 0.9.0 CPU): the
+# chain is the same chain, so a sampled stream is the same stream
+_SAMPLED_PINS = {
+    8: {"a": [66, 139, 106, 255, 186, 222, 113, 105, 30, 187, 224, 78, 9,
+              57, 65, 240, 161, 139, 108, 32],
+        "b": [19, 96, 122, 111, 67, 199, 113, 236, 10, 225, 128, 179]},
+    1: {"a": [66, 139, 106, 186, 222, 53, 191, 110, 190, 97, 134, 233, 97,
+              62, 0, 196, 208, 118, 160, 66],
+        "b": [237, 236, 37, 175, 222, 196, 208, 145, 228, 50, 143, 231]},
+}
+
+
+@pytest.mark.parametrize("block", [8, 1])
+def test_sampled_streams_equal_parent(tiny_model_kwargs, block):
+    """Two sampled requests admitted in different rounds draw the tokens
+    they drew while the batcher split the round's keys eagerly."""
+    cfg, engine = _engine(tiny_model_kwargs, decode_block_len=block)
+    assert engine.key_schedule == "round"
+    b = ContinuousBatcher(engine, _params(cfg, engine), seed=7)
+    b.submit(Request("a", [5, 6, 7, 8], max_new_tokens=20, temperature=0.9,
+                     top_k=40))
+    b.step()
+    b.step()
+    b.submit(Request("b", [9, 10, 11], max_new_tokens=12, temperature=1.3,
+                     top_k=8, top_p=0.95))
+    res = b.run()
+    assert {u: r.tokens for u, r in res.items()} == _SAMPLED_PINS[block]
+
+
+def test_round_hands_decode_block_device_keys(tiny_model_kwargs,
+                                              monkeypatch):
+    """A round-keyed ``_step_serial`` round: one key-program dispatch, no
+    eager ``jax.random.split`` between admission's end and the issue, and
+    ``engine.decode_block`` is handed the keys as a device array."""
+    cfg, engine = _engine(tiny_model_kwargs)
+    assert engine.key_schedule == "round"
+    b = ContinuousBatcher(engine, _params(cfg, engine))
+    b.submit(Request("a", [1, 2, 3], max_new_tokens=40, temperature=0.8))
+    b.step()  # admission and a first round: the key program is traced
+    seen = {"splits": 0, "programs": 0, "issues": []}
+    real_split, real_admit = jax.random.split, b._admit
+    real_keys, real_block = engine.round_keys, engine.decode_block
+
+    def split(*a, **kw):
+        seen["splits"] += 1
+        return real_split(*a, **kw)
+
+    def admit():
+        real_admit()
+        seen["splits"] = 0  # admission's own split is not the round's
+
+    def round_keys(key):
+        seen["programs"] += 1
+        return real_keys(key)
+
+    def decode_block(params, cache, tokens, keys, *a, **kw):
+        seen["issues"].append((isinstance(keys, jax.Array),
+                               tuple(keys.shape), seen["splits"],
+                               seen["programs"]))
+        return real_block(params, cache, tokens, keys, *a, **kw)
+
+    monkeypatch.setattr(jax.random, "split", split)
+    monkeypatch.setattr(b, "_admit", admit)
+    monkeypatch.setattr(engine, "round_keys", round_keys)
+    monkeypatch.setattr(engine, "decode_block", decode_block)
+    b.submit(Request("b", [4, 5], max_new_tokens=8, temperature=0.8))
+    b.step()
+    (on_device, shape, splits, programs), = seen["issues"]
+    assert on_device
+    assert shape == (engine.decode_block_len, 2)
+    assert splits == 0 and programs == 1
 
 
 # --------------------------------------------------------------------------- #

@@ -382,6 +382,29 @@ def _poll_statz(port, cond, deadline_s=10.0):
         time.sleep(0.01)
 
 
+class _SlowDecode:
+    """Engine hooks that say when a request decodes and keep it decoding
+    (50 ms a dispatch). For tests that need a request in flight: polling
+    /statz for ``active_slots > 0`` raced it, because the loop holds the
+    front end's lock through each step and ``stats()`` then answers with a
+    partial snapshot, so on a busy host the 24-40 tokens could be done
+    before one whole snapshot came back, and the condition never held."""
+
+    def __init__(self):
+        self.decoding = threading.Event()
+
+    def before_dispatch(self, kind, slots):
+        if kind == "decode":
+            self.decoding.set()
+            time.sleep(0.05)
+
+    def poison_logits(self, kind):
+        return False
+
+    def wait(self):
+        assert self.decoding.wait(60), "the request never reached decode"
+
+
 def test_http_admission_bounds_shed_with_retry_after():
     # token budget first: one live request exhausts it. The slow request
     # runs per-token (block 1) with a big budget, so it is live for many
@@ -670,7 +693,8 @@ def test_readyz_distinguishes_draining_from_dead():
     router must stop placing on a draining replica without tripping its
     circuit breaker, and must treat a dead one as a failure — before the
     "state" field, both were indistinguishable 503s."""
-    cfg, srv = _server(slots=1, inf={"decode_block_len": 1})
+    slow = _SlowDecode()
+    cfg, srv = _server(slots=1, hooks=slow, inf={"decode_block_len": 1})
     try:
         port = srv.port
         st, body = serve._get(port, "/readyz")
@@ -685,7 +709,7 @@ def test_readyz_distinguishes_draining_from_dead():
 
         t = threading.Thread(target=bg)
         t.start()
-        _poll_statz(port, lambda s: s.get("active_slots", 0) > 0)
+        slow.wait()
         srv.front.begin_drain()
         st, body = serve._get(port, "/readyz")
         assert st == 503
@@ -884,7 +908,8 @@ def test_http_drain_202_then_409_and_sigterm_races_one_drain():
     begin_drain again — the controller sends both on purpose,
     belt-and-braces) must not double-run the drain — ``drain_begins``
     stays 1 and the loop exits clean, the serve CLI's exit-0 path."""
-    cfg, srv = _server(slots=1, inf={"decode_block_len": 1})
+    slow = _SlowDecode()
+    cfg, srv = _server(slots=1, hooks=slow, inf={"decode_block_len": 1})
     try:
         port = srv.port
         srv.front._on_drained = None  # keep the listener observable
@@ -896,7 +921,7 @@ def test_http_drain_202_then_409_and_sigterm_races_one_drain():
 
         t = threading.Thread(target=bg)
         t.start()
-        _poll_statz(port, lambda s: s.get("active_slots", 0) > 0)
+        slow.wait()
         st, body = _post_path(port, "/drain")
         assert st == 202 and body["ok"] and body["state"] == "draining"
         st, body = _post_path(port, "/drain")
@@ -939,7 +964,8 @@ def test_metrics_renders_during_drain_and_after_shutdown():
     """The controller scrapes /metrics every tick, including while its
     drain is in flight and after the batcher has exited — the render
     must answer 200 (bounded work, no dead-batcher 500, no deadlock)."""
-    cfg, srv = _server(slots=1, inf={"decode_block_len": 1})
+    slow = _SlowDecode()
+    cfg, srv = _server(slots=1, hooks=slow, inf={"decode_block_len": 1})
     try:
         port = srv.port
         srv.front._on_drained = None
@@ -951,7 +977,7 @@ def test_metrics_renders_during_drain_and_after_shutdown():
 
         t = threading.Thread(target=bg)
         t.start()
-        _poll_statz(port, lambda s: s.get("active_slots", 0) > 0)
+        slow.wait()
         srv.front.begin_drain()
         st, text = _get_text(port, "/metrics")  # mid-drain
         assert st == 200 and "picotron_queue_depth" in text

@@ -132,6 +132,28 @@ def test_dp2_greedy_matches_dp1(tiny_model_kwargs, program, attend,
     assert st["slots_total"] == 2 * b2.engine.slots_per_shard
 
 
+def test_dp2_round_keys_replicated_and_sampled_pinned(tiny_model_kwargs):
+    """The round schedule under dp=2: the key program's outputs lie
+    replicated over the dp mesh (the round program takes keys as P()), and
+    a sampled run draws what it drew while the batcher split eagerly and
+    shipped host keys (tokens of PR 28's tree; shard 1's rows differ from a
+    dp=1 run's, there as here: each shard draws for its own rows)."""
+    cfg, eng = _engine(tiny_model_kwargs, 2)
+    assert eng.key_schedule == "round"
+    b = ContinuousBatcher(eng, _params(cfg, eng), seed=3)
+    res = b.run([Request(f"r{i}", [1 + i, 2 + i, 3 + i], max_new_tokens=10,
+                         temperature=0.9, top_k=20) for i in range(4)])
+    assert {u: r.tokens for u, r in res.items()} == {
+        "r0": [36, 93, 200, 59, 183, 165, 61, 101, 64, 153],
+        "r1": [74, 37, 252, 150, 184, 2, 227, 98, 189, 170],
+        "r2": [206, 93, 200, 59, 183, 165, 61, 101, 208, 186],
+        "r3": [11, 228, 23, 121, 228, 228, 205, 208, 133, 170]}
+    key, keys = eng.round_keys(b._key)
+    for out in (key, keys):
+        assert out.sharding.is_fully_replicated
+        assert len(out.sharding.device_set) == 2
+
+
 def test_dp2_mixed_tenants_match_dp1(tiny_model_kwargs):
     """Mixed-tenant batches (2 LoRA tenants + anonymous base rows in ONE
     continuous batch, per-tenant radix salts) survive the dp split: the
