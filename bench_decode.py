@@ -258,9 +258,10 @@ def run(cfg, *, slots: int, max_seq_len: int, prompt_len: int,
                 subs.append(np.asarray(sub))
             budget = np.full(slots, block_len, np.int32)
             td = time.perf_counter()
-            cache, out, counts = engine.decode_block(
+            r = engine.decode_block(
                 params, cache, toks, np.stack(subs), eos, budget,
                 temp, top_k, top_p)
+            cache, out, counts = r.cache, r.tokens, r.counts
             out = np.asarray(out)  # one host sync per block, not per token
             engine.observe_dispatch("decode", time.perf_counter() - td)
             assert np.all(np.asarray(counts) == block_len)
@@ -372,11 +373,11 @@ def run_spec(cfg, *, slots: int, max_seq_len: int, prompt_len: int,
         td = time.perf_counter()
         out = engine.verify(
             params, cache, tokens, sub, eos, budget, temp, top_k, top_p)
-        cache, emitted, counts, accepted = out[:4]
+        cache, emitted, accepted = out.cache, out.tokens, out.accepted
         emitted = np.asarray(emitted)  # ONE host sync per dispatch
-        counts = np.asarray(counts)
+        counts = np.asarray(out.counts)
         if learned:
-            hidden = jnp.where(jnp.asarray(counts > 0)[:, None], out[4],
+            hidden = jnp.where(jnp.asarray(counts > 0)[:, None], out.hidden,
                                hidden)
         engine.observe_dispatch("verify", time.perf_counter() - td)
         for s in np.flatnonzero(counts):

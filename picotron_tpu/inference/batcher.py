@@ -1814,43 +1814,24 @@ class ContinuousBatcher:
                 self._phases.to("step/issue")
                 t0 = self._clock()
                 self._note_issue(t0)
-                out = self.engine.decode_block(
+                res = self.engine.decode_block(
                     self.params, self._cache, self._last_tok, keys,
                     self._eos, b, self._temp, self._top_k, self._top_p,
                     adapter_ids=(self._adapter if self.engine.adapters
                                  is not None else None), lanes=lanes)
-                if self._mixed:
-                    # strip the fused lane tail (token/logits row
-                    # [+ lane hidden]) — _lane_land consumes it after
-                    # the round delivers. An isolation re-dispatch
-                    # re-runs the lane chunk too: same rows, same bytes,
-                    # so restashing is idempotent.
-                    lane_hid = None
-                    if self.engine.return_hidden:
-                        *out, lane_out, lane_hid = out
-                    else:
-                        *out, lane_out = out
-                    self._lane_scratch = (lane_out, lane_hid)
-                if self._sched == "slot":
-                    # the slot program's extra next-token output feeds the
-                    # overlap pipeline; the synchronous path ignores it
-                    # (_last_tok, updated by the walk, stays authoritative)
-                    if self.engine.return_hidden:
-                        self._cache, toks, counts, _ntok, hid = out
-                    else:
-                        self._cache, toks, counts, _ntok = out
-                        hid = None
-                elif self.engine.return_hidden:
-                    self._cache, toks, counts, hid = out
-                else:
-                    self._cache, toks, counts = out
-                    hid = None
+                # the fused lane's outputs wait for _lane_land, after the
+                # round delivers. An isolation re-dispatch re-runs the
+                # lane chunk too: same rows, same bytes, so restashing is
+                # idempotent. The slot schedule's next_tok feeds the
+                # overlap pipeline; this synchronous path ignores it
+                # (_last_tok, updated by the walk, stays authoritative)
+                self._cache, self._lane_scratch = res.cache, res.lane
                 self.decode_dispatches += 1
                 self._phases.to("step/sync")
                 t_sync = self._clock()
                 self._synthetic_wait(t0)
-                out = np.asarray(toks), np.asarray(counts), None
-                self._merge_hidden(hid, out[1])
+                out = np.asarray(res.tokens), np.asarray(res.counts), None
+                self._merge_hidden(res.hidden, out[1])
                 t1 = self._clock()
                 self._phases.to("step/deliver")
                 self._count_model_stats()
@@ -2042,35 +2023,18 @@ class ContinuousBatcher:
             self._round_fallback(kind, t_round, budget, spec_lens,
                                  spec_kinds, issue, feeds=feeds)
             return None
-        lane_out = lane_hid = None
-        if self._mixed:
-            if self.engine.return_hidden:
-                *out, lane_out, lane_hid = out
-            else:
-                *out, lane_out = out
-        if spec_lens is None:
-            accepted = None
-            if self.engine.return_hidden:
-                self._cache, toks, counts, ntok, hid = out
-            else:
-                self._cache, toks, counts, ntok = out
-                hid = None
-        elif self.engine.return_hidden:
-            self._cache, toks, counts, accepted, ntok, hid = out
-        else:
-            self._cache, toks, counts, accepted, ntok = out
-            hid = None
-        self._dev_last = ntok
+        self._cache, self._dev_last = out.cache, out.next_tok
         self.decode_dispatches += 1
         self._round_seq += 1
         self._phases.to("step/plan")  # until the drain's sync claims it
         return dict(kind=kind, t_round=t_round, t0=t0,
-                    budget=budget, epochs=epochs, toks=toks,
-                    counts=counts, accepted=accepted, hid=hid,
+                    budget=budget, epochs=epochs, toks=out.tokens,
+                    counts=out.counts, accepted=out.accepted,
+                    hid=out.hidden,
                     spec_lens=spec_lens, spec_kinds=spec_kinds,
                     # lane futures + feed records: the sync stage lands
                     # them after the round's outputs materialize
-                    lane=(lane_out, lane_hid), feeds=feeds,
+                    lane=out.lane, feeds=feeds,
                     # the NEXT issue's _pre_write reach: this round may
                     # advance each slot by up to lead rows before the
                     # stale host_len catches up at sync
@@ -2099,38 +2063,21 @@ class ContinuousBatcher:
             self._phases.to("step/issue")
             t0 = self._clock()
             self._note_issue(t0)
-            out = issue(b, self._dev_tok())
-            if self._mixed:
-                lane_hid = None
-                if self.engine.return_hidden:
-                    *out, lane_out, lane_hid = out
-                else:
-                    *out, lane_out = out
-                self._lane_scratch = (lane_out, lane_hid)
-            if kind == "decode":
-                accepted = None
-                if self.engine.return_hidden:
-                    self._cache, toks, counts, ntok, hid = out
-                else:
-                    self._cache, toks, counts, ntok = out
-                    hid = None
-            elif self.engine.return_hidden:
-                self._cache, toks, counts, accepted, ntok, hid = out
-            else:
-                self._cache, toks, counts, accepted, ntok = out
-                hid = None
-            self._dev_last = ntok
+            res = issue(b, self._dev_tok())
+            self._cache, self._lane_scratch = res.cache, res.lane
+            self._dev_last = res.next_tok
             self.decode_dispatches += 1
             self._phases.to("step/sync")
             t_sync = self._clock()
             self._synthetic_wait(t0)
-            outs = (np.asarray(toks), np.asarray(counts),
-                    None if accepted is None else np.asarray(accepted))
+            outs = (np.asarray(res.tokens), np.asarray(res.counts),
+                    None if res.accepted is None
+                    else np.asarray(res.accepted))
             # deferred page-table advance (engine.defer_advance): lands
             # here per successful dispatch, so isolation re-dispatches
             # compose exactly like the legacy per-dispatch advance
             self.engine.apply_advance(outs[1])
-            self._merge_hidden(hid, outs[1])
+            self._merge_hidden(res.hidden, outs[1])
             t1 = self._clock()
             self._phases.to("step/deliver")
             dt_sync = t1 - t_sync
@@ -2404,7 +2351,7 @@ class ContinuousBatcher:
         drafting stage, shared with the overlap issue path (where it runs
         INSIDE the device-busy window, from host state that is one round
         stale; a stale guess only costs acceptance, never correctness —
-        the slot verify program's sample-and-match emission is independent
+        the slot-keyed verify's sample-and-match emission is independent
         of the draft values). Returns the [slots, spec_len + 1] token
         block; column 0 is the host's last-token view (the overlap path
         overrides it with the device-carried row at dispatch)."""
@@ -2508,40 +2455,21 @@ class ContinuousBatcher:
             self._phases.to("step/issue")
             t0 = self._clock()
             self._note_issue(t0)
-            out = self.engine.verify(
+            res = self.engine.verify(
                 self.params, self._cache, tokens, key, self._eos,
                 b, self._temp, self._top_k, self._top_p, draft_len=lens,
                 adapter_ids=(self._adapter if self.engine.adapters
                              is not None else None), lanes=lanes)
-            if self._mixed:
-                # strip the fused lane tail for _lane_land (idempotent
-                # under isolation re-dispatch — see step()'s closure)
-                lane_hid = None
-                if self.engine.return_hidden:
-                    *out, lane_out, lane_hid = out
-                else:
-                    *out, lane_out = out
-                self._lane_scratch = (lane_out, lane_hid)
-            if self._sched == "slot":
-                # extra next-token output (overlap feed) — ignored here
-                if self.engine.return_hidden:
-                    (self._cache, emitted, counts, accepted, _ntok,
-                     hid) = out
-                else:
-                    self._cache, emitted, counts, accepted, _ntok = out
-                    hid = None
-            elif self.engine.return_hidden:
-                self._cache, emitted, counts, accepted, hid = out
-            else:
-                self._cache, emitted, counts, accepted = out
-                hid = None
+            # the lane's outputs wait for _lane_land; next_tok (the
+            # overlap feed) is ignored here — see step()'s closure
+            self._cache, self._lane_scratch = res.cache, res.lane
             self.decode_dispatches += 1
             self._phases.to("step/sync")
             t_sync = self._clock()
             self._synthetic_wait(t0)
-            out = (np.asarray(emitted), np.asarray(counts),
-                   np.asarray(accepted))
-            self._merge_hidden(hid, out[1])
+            out = (np.asarray(res.tokens), np.asarray(res.counts),
+                   np.asarray(res.accepted))
+            self._merge_hidden(res.hidden, out[1])
             t1 = self._clock()
             self._phases.to("step/deliver")
             dt_sync = t1 - t_sync

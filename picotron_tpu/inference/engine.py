@@ -54,7 +54,7 @@ reallocation.
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -87,6 +87,23 @@ def _key_chain(key, block: int):
         k, sub = jax.random.split(k)
         return k, sub
     return lax.scan(link, key, None, length=block)
+
+
+class RoundResult(NamedTuple):
+    """What one round dispatch (``decode_block`` | ``verify``) returns,
+    every field a device array (or pytree) still in flight; a field is None
+    where the engine's options produce nothing."""
+    cache: dict
+    tokens: jax.Array    # [slots, decode_block_len | spec_len + 1]
+    counts: jax.Array    # [slots]: leading entries of each tokens row
+    accepted: Optional[jax.Array] = None  # verify: drafts accepted [slots]
+    # key_schedule "slot": each slot's post-round last token [slots]
+    next_tok: Optional[jax.Array] = None
+    # return_hidden: [slots, H] at each slot's last emitted position
+    hidden: Optional[jax.Array] = None
+    # mixed_dispatch: (the lane's sampled token [dp] or logits [dp, V],
+    # the lane's hidden [dp, H] or None)
+    lane: Optional[tuple] = None
 
 
 def inference_config(cfg: Config) -> Config:
@@ -568,177 +585,59 @@ class InferenceEngine:
             out_specs=((self._cspecs, dpP) if sod
                        else (self._cspecs, dpP, dpP)) + hidB + st),
             donate_argnums=(1,))
-        self._decode_block_jit = self._make_decode_block_jit()
-        self._decode_block_poison_jit = None  # chaos-only; built on demand
-        self._verify_jit = None
-        self._verify_poison_jit = None  # chaos-only; built on demand
-        if self.spec_len > 0:
-            self._verify_jit = self._make_verify_jit()
-        # per-slot key schedule variants (key_schedule="slot"): same
-        # programs with [B, 2] base keys folded per position IN-TRACE and
-        # an extra next-token output the overlap pipeline carries on
-        # device. jax.jit is lazy, but only the active schedule's
-        # variants are referenced at all.
-        self._decode_block_slot_jit = None
-        self._decode_block_slot_poison_jit = None
-        self._verify_slot_jit = None
-        self._verify_slot_poison_jit = None
-        if self.key_schedule == "slot":
-            self._decode_block_slot_jit = self._make_decode_block_slot_jit()
-            if self.spec_len > 0:
-                self._verify_slot_jit = self._make_verify_slot_jit()
-        # mixed prefill–decode dispatch variants (mixed_dispatch): the
-        # slot-keyed programs + one fused prefill lane. Only built (and
-        # only dispatched) on a mixed engine — a mixed-off engine's
-        # program set stays byte-identical.
-        self._decode_block_mixed_jit = None
-        self._decode_block_mixed_poison_jit = None
-        self._verify_mixed_jit = None
-        self._verify_mixed_poison_jit = None
-        if self.mixed:
-            self._decode_block_mixed_jit = \
-                self._make_decode_block_mixed_jit()
-            if self.spec_len > 0:
-                self._verify_mixed_jit = self._make_verify_mixed_jit()
+        # the round programs (decode block, verify) are built where they
+        # are first asked for (``_program``); a rebuild starts the table anew
+        self._programs: dict = {}
 
-    def _make_verify_jit(self, poison: bool = False):
-        dpP = P("dp") if self.dp_size > 1 else P()
-        hidB = (dpP,) if self.return_hidden else ()
-        return jax.jit(shard_map(
-            partial(self._verify_impl, poison=poison), self.topo.mesh,
-            in_specs=(self._decode_dispatch_pspecs, self._cspecs,
-                      dpP, dpP, P(), dpP, dpP, dpP, dpP, dpP),
-            out_specs=(self._cspecs, dpP, dpP, dpP) + hidB),
-            donate_argnums=(1,))
+    def _round_fields(self, kind: str) -> tuple:
+        """The names of what a round program (``"decode_block"`` |
+        ``"verify"``) returns, in order: THE one place that knows which
+        outputs this engine's options add. The bodies return them so, the
+        builder derives ``out_specs`` from them, and the host reads them
+        back into a ``RoundResult`` by name."""
+        rh = self.return_hidden
+        return (("cache", "tokens", "counts")
+                + (("accepted",) if kind == "verify" else ())
+                # the post-round last token the overlap pipeline carries
+                + (("next_tok",) if self.key_schedule == "slot" else ())
+                + (("hidden",) if rh else ())
+                + (("lane_out",) if self.mixed else ())
+                + (("lane_hidden",) if self.mixed and rh else ())
+                # a block that counts appends its counters, last of all
+                + (("stats",) if self._n_stats and kind == "decode_block"
+                   else ()))
 
-    def _verify_prog(self, poison: bool):
-        """The verify executable to run (lazily builds the chaos
-        NaN-poisoned variant)."""
-        if self.mixed:
-            if not poison:
-                return self._verify_mixed_jit
-            if self._verify_mixed_poison_jit is None:
-                self._verify_mixed_poison_jit = self._make_verify_mixed_jit(
-                    poison=True)
-            return self._verify_mixed_poison_jit
-        if self.key_schedule == "slot":
-            if not poison:
-                return self._verify_slot_jit
-            if self._verify_slot_poison_jit is None:
-                self._verify_slot_poison_jit = self._make_verify_slot_jit(
-                    poison=True)
-            return self._verify_slot_poison_jit
-        if not poison:
-            return self._verify_jit
-        if self._verify_poison_jit is None:
-            self._verify_poison_jit = self._make_verify_jit(poison=True)
-        return self._verify_poison_jit
-
-    def _make_verify_slot_jit(self, poison: bool = False):
-        """Per-slot-key verify: base keys [B, 2] shard with their slots,
-        and the program returns each row's post-round last token so the
-        overlap pipeline can feed the next dispatch without a sync."""
-        dpP = P("dp") if self.dp_size > 1 else P()
-        hidB = (dpP,) if self.return_hidden else ()
-        return jax.jit(shard_map(
-            partial(self._verify_slot_impl, poison=poison), self.topo.mesh,
-            in_specs=(self._decode_dispatch_pspecs, self._cspecs,
-                      dpP, dpP, dpP, dpP, dpP, dpP, dpP, dpP),
-            out_specs=(self._cspecs, dpP, dpP, dpP, dpP) + hidB),
-            donate_argnums=(1,))
-
-    def _make_decode_block_jit(self, poison: bool = False):
-        dpP = P("dp") if self.dp_size > 1 else P()
-        hidB = (dpP,) if self.return_hidden else ()
-        return jax.jit(shard_map(
-            partial(self._decode_block_impl, poison=poison), self.topo.mesh,
-            in_specs=(self._decode_dispatch_pspecs, self._cspecs,
-                      dpP, P(), dpP, dpP, dpP, dpP, dpP),
-            out_specs=(self._cspecs, dpP, dpP) + hidB
-            + ((P(),) if self._n_stats else ())),
-            donate_argnums=(1,))
-
-    def _decode_block_prog(self, poison: bool):
-        """The decode-block executable to run (lazily builds the chaos
-        NaN-poisoned variant)."""
-        if self.mixed:
-            if not poison:
-                return self._decode_block_mixed_jit
-            if self._decode_block_mixed_poison_jit is None:
-                self._decode_block_mixed_poison_jit = \
-                    self._make_decode_block_mixed_jit(poison=True)
-            return self._decode_block_mixed_poison_jit
-        if self.key_schedule == "slot":
-            if not poison:
-                return self._decode_block_slot_jit
-            if self._decode_block_slot_poison_jit is None:
-                self._decode_block_slot_poison_jit = \
-                    self._make_decode_block_slot_jit(poison=True)
-            return self._decode_block_slot_poison_jit
-        if not poison:
-            return self._decode_block_jit
-        if self._decode_block_poison_jit is None:
-            self._decode_block_poison_jit = self._make_decode_block_jit(
-                poison=True)
-        return self._decode_block_poison_jit
-
-    def _make_decode_block_slot_jit(self, poison: bool = False):
-        """Per-slot-key decode block: [B, 2] base keys (sharded with
-        their slots) replace the [block, 2] round keys; each scan step
-        folds the live length in-trace, and the final carry token comes
-        back as an extra output for the overlap pipeline."""
-        dpP = P("dp") if self.dp_size > 1 else P()
-        hidB = (dpP,) if self.return_hidden else ()
-        return jax.jit(shard_map(
-            partial(self._decode_block_slot_impl, poison=poison),
-            self.topo.mesh,
-            in_specs=(self._decode_dispatch_pspecs, self._cspecs,
-                      dpP, dpP, dpP, dpP, dpP, dpP, dpP),
-            out_specs=(self._cspecs, dpP, dpP, dpP) + hidB),
-            donate_argnums=(1,))
-
-    def _lane_specs(self):
-        """(in_specs, out_specs) tails the prefill lane adds to a mixed
-        program: every lane operand/output is a per-shard [dp, ...] row
-        set, so they all shard over dp exactly like the per-slot batch
-        operands (dp == 1 collapses to replicated)."""
-        dpP = P("dp") if self.dp_size > 1 else P()
-        lane_in = (dpP, dpP, dpP, dpP)  # tokens, slot, start, valid
-        if self.sample_on_device:
-            lane_in += (dpP, dpP, dpP, dpP)  # key, temp, top_k, top_p
-        if self.adapters is not None:
-            lane_in += (dpP,)
-        lane_out = (dpP,) + ((dpP,) if self.return_hidden else ())
-        return lane_in, lane_out
-
-    def _make_decode_block_mixed_jit(self, poison: bool = False):
-        """The fused decode-block + prefill-lane program
-        (mixed_dispatch): the slot-keyed decode block's operands followed
-        by the lane tail (``_lane_chunk``)."""
-        dpP = P("dp") if self.dp_size > 1 else P()
-        hidB = (dpP,) if self.return_hidden else ()
-        lane_in, lane_out = self._lane_specs()
-        return jax.jit(shard_map(
-            partial(self._decode_block_mixed_impl, poison=poison),
-            self.topo.mesh,
-            in_specs=(self._decode_dispatch_pspecs, self._cspecs,
-                      dpP, dpP, dpP, dpP, dpP, dpP, dpP) + lane_in,
-            out_specs=(self._cspecs, dpP, dpP, dpP) + hidB + lane_out),
-            donate_argnums=(1,))
-
-    def _make_verify_mixed_jit(self, poison: bool = False):
-        """The fused verify + prefill-lane program (mixed_dispatch)."""
-        dpP = P("dp") if self.dp_size > 1 else P()
-        hidB = (dpP,) if self.return_hidden else ()
-        lane_in, lane_out = self._lane_specs()
-        return jax.jit(shard_map(
-            partial(self._verify_mixed_impl, poison=poison),
-            self.topo.mesh,
-            in_specs=(self._decode_dispatch_pspecs, self._cspecs,
-                      dpP, dpP, dpP, dpP, dpP, dpP, dpP, dpP) + lane_in,
-            out_specs=(self._cspecs, dpP, dpP, dpP, dpP) + hidB
-            + lane_out),
-            donate_argnums=(1,))
+    def _program(self, kind: str, poison: bool = False):
+        """The round program to run: ``kind`` is ``"decode_block"`` or
+        ``"verify"``, the one body of each under every key schedule, with
+        the fused prefill lane on a mixed engine. Built on first use and
+        kept; ``poison`` (chaos only) is a trace-time build of the same
+        body, compiled only when a hook asks for it."""
+        prog = self._programs.get((kind, poison))
+        if prog is None:
+            dpP = P("dp") if self.dp_size > 1 else P()
+            # tokens[, valid], then the keys: [B, 2] bases shard with
+            # their slots, the round's key (rows) is replicated
+            rows = (dpP, dpP) if kind == "verify" else (dpP,)
+            keys = dpP if self.key_schedule == "slot" else P()
+            # the prefill lane's operands (``_lane_args``), every one a
+            # per-shard [dp, ...] row set: tokens, slot, start, valid[,
+            # key, temperature, top_k, top_p][, adapter]
+            lane = (dpP,) * (4 + 4 * self.sample_on_device
+                             + (self.adapters is not None)
+                             if self.mixed else 0)
+            impl = (self._verify_impl if kind == "verify"
+                    else self._decode_block_impl)
+            prog = self._programs[kind, poison] = jax.jit(shard_map(
+                partial(impl, poison=poison), self.topo.mesh,
+                in_specs=(self._decode_dispatch_pspecs, self._cspecs)
+                + rows + (keys,) + (dpP,) * 5 + lane,
+                out_specs=tuple(
+                    self._cspecs if n == "cache"
+                    else P() if n == "stats" else dpP
+                    for n in self._round_fields(kind))),
+                donate_argnums=(1,))
+        return prog
 
     # ---- dispatch hooks + graceful degradation ----------------------------
 
@@ -1095,34 +994,49 @@ class InferenceEngine:
         return out if stats is None else out + (stats,)
 
     def _decode_block_impl(self, params, cache, tokens, keys, eos_id,
-                           budget, temperature, top_k, top_p,
+                           budget, temperature, top_k, top_p, *lane,
                            poison=False):
-        """``decode_block_len`` autoregressive steps in one program.
+        """``decode_block_len`` autoregressive steps in one program, under
+        every key schedule.
 
-        tokens [B] (each slot's current last token), keys [block_len, 2]
-        (one PRNG key per in-block step — the host's per-round split chain,
-        so block_len == 1 reproduces the per-token loop bit-for-bit),
-        eos_id [B] int32 (−1 = none), budget [B] int32 remaining tokens.
+        tokens [B] (each slot's current last token), eos_id [B] int32
+        (−1 = none), budget [B] int32 remaining tokens. ``keys`` is the
+        engine's ``key_schedule`` (a constant of the trace): ``"round"``
+        takes [block_len, 2], one PRNG key per in-block step — the host's
+        per-round split chain, so block_len == 1 reproduces the per-token
+        loop bit-for-bit; ``"slot"`` takes the per-slot BASE keys [B, 2],
+        and every row's draw at pre-step length ℓ uses
+        ``fold_in(keys[b], ℓ)`` — the key that position owns no matter how
+        steps are grouped into rounds, the invariant the overlap
+        pipeline's bit-identity rests on (docs/INFERENCE.md "Overlapped
+        scheduling").
         A slot is active while it has a parked sequence AND budget; hitting
         EOS zeroes its budget. Inactive slots emit pad token 0, stop
         advancing their cache length, and their (recomputed) row writes
         land beyond the length mask — invisible, exactly like the free
         slots that already ride through the single-step program.
 
-        Returns (cache, tokens [B, block_len], counts [B]): ``counts[b]``
-        leading entries of row b are the tokens slot b actually produced.
+        Returns ``_round_fields("decode_block")``: cache, tokens
+        [B, block_len], counts [B] (``counts[b]`` leading entries of row b
+        are the tokens slot b actually produced); under the slot schedule
+        next_tok [B], the final carry token (each slot's post-block last
+        token, the input token where a slot never ran), which the
+        lookahead dispatch consumes without a host sync; on a
+        ``return_hidden`` engine hidden [B, H], each slot's
+        pre-final-norm hidden state at its LAST active step — the position
+        whose logits produced the slot's final emitted token, exactly what
+        the learned drafter needs to draft its continuation; on a mixed
+        engine the lane's outputs (``_lane_chunk``, run on ``lane`` after
+        the block: the lane slot rides the block inactive — budget 0, so
+        its ghost row lands at its current length and the lane
+        immediately overwrites it); of a block that counts, its counters.
 
         ``poison`` (trace-time, chaos only) replaces every step's logits
         with NaN — the build that proves the sampler's non-finite gate
         keeps emitting defined tokens, the exact counterpart of
-        train_step's ``poison_nonfinite``.
-
-        A ``return_hidden`` engine also returns hidden [B, H]: each
-        slot's pre-final-norm hidden state at its LAST active step — the
-        position whose logits produced the slot's final emitted token,
-        exactly what the learned drafter needs to draft its continuation.
-        """
+        train_step's ``poison_nonfinite``."""
         rh = self.return_hidden
+        by_slot = self.key_schedule == "slot"
         hid0 = jnp.zeros((tokens.shape[0], self.cfg.model.hidden_size),
                          self._dt)
 
@@ -1130,14 +1044,16 @@ class InferenceEngine:
             cache, tok, budget, hid = carry
             pos = cache["lengths"]
             active = (pos > 0) & (budget > 0)
+            if by_slot:
+                key_t = jax.vmap(jax.random.fold_in)(keys, pos)
             new_leaves, logits, h = self._decode_core(params, cache, tok)
             # a block that counts: its step's stats leave with the tokens
             counted = ((new_leaves.pop(models.STATS),) if self._n_stats
                        else ())
             if poison:
                 logits = jnp.full_like(logits, jnp.nan)
-            sampled = sampling.sample(logits, key_t, temperature,
-                                      top_k, top_p)
+            sample = sampling.sample_rowkeys if by_slot else sampling.sample
+            sampled = sample(logits, key_t, temperature, top_k, top_p)
             emit = jnp.where(active, sampled, 0)
             new_budget = jnp.where(active, budget - 1, budget)
             hit_eos = active & (eos_id >= 0) & (sampled == eos_id)
@@ -1149,15 +1065,20 @@ class InferenceEngine:
             return (new_cache, next_tok, new_budget, new_hid), \
                 (emit, active) + counted
 
-        (cache, _, _, hid), (toks, actives, *counted) = lax.scan(
-            step, (cache, tokens, budget, hid0), keys)
-        out = (cache, jnp.swapaxes(toks, 0, 1),
-               jnp.sum(actives.astype(jnp.int32), axis=0))
-        out = out + (hid,) if rh else out
-        return out + tuple(jnp.sum(c, axis=0) for c in counted)
+        (cache, tok, _, hid), (toks, actives, *counted) = lax.scan(
+            step, (cache, tokens, budget, hid0),
+            None if by_slot else keys,
+            length=self.decode_block_len if by_slot else None)
+        out = {"cache": cache, "tokens": jnp.swapaxes(toks, 0, 1),
+               "counts": jnp.sum(actives.astype(jnp.int32), axis=0),
+               "next_tok": tok, "hidden": hid}
+        if counted:
+            out["stats"] = jnp.sum(counted[0], axis=0)
+        return self._round_outputs("decode_block", params, out, lane)
 
     def _verify_impl(self, params, cache, tokens, valid, key, eos_id,
-                     budget, temperature, top_k, top_p, poison=False):
+                     budget, temperature, top_k, top_p, *lane,
+                     poison=False):
         """The speculative verify pass: tokens [B, S] (S = spec_len + 1 —
         each slot's current last token followed by its spec_len drafted
         continuation tokens), scored in ONE model dispatch. ``valid`` [B]
@@ -1176,8 +1097,16 @@ class InferenceEngine:
         attention runs causally over the cache prefix plus the fed block —
         the same masked kernel the chunked prefill uses, batched over
         slots. The resulting logits[b, i] score the token FOLLOWING fed
-        token i, so ``sampling.speculative_accept`` can accept the
-        matching draft prefix and draw the one fresh token, all on device.
+        token i. Under the round schedule (``key`` one PRNG key)
+        ``sampling.speculative_accept`` accepts the matching draft prefix
+        and draws the one fresh token, all on device. Under the slot
+        schedule (``key`` the per-slot base keys [B, 2]) acceptance is
+        sample-and-match (``sampling.speculative_match``): the program
+        draws the target chain's own token at every fed position with
+        that position's folded key and accepts the matching draft prefix,
+        so the emitted stream never depends on the draft VALUES and equals
+        the per-position decode chain bit for bit (the property that lets
+        the overlap pipeline verify against one-round-stale drafts).
 
         Rollback is the length pointer: ``lengths`` advances by the
         emitted count only (accepted prefix + the fresh token's slot-feed
@@ -1188,12 +1117,15 @@ class InferenceEngine:
         decode_block's budget. Free slots (length 0) ride along inactive:
         they emit count 0 and their length stays 0.
 
-        Returns (cache, emitted [B, S], counts [B], accepted [B]) where
-        ``accepted`` is the number of DRAFT tokens that made it into the
-        emitted stream (the accept-rate numerator). A ``return_hidden``
-        engine appends hidden [B, H]: each slot's pre-final-norm hidden
-        state at the position whose logits produced its final emitted
-        token (row ``counts - 1``) — the learned drafter's next input.
+        Returns ``_round_fields("verify")``: cache, tokens (the emitted
+        run) [B, S], counts [B], accepted [B] — the number of DRAFT tokens
+        that made it into the emitted stream (the accept-rate numerator);
+        under the slot schedule next_tok [B], the last emitted token where
+        the row ran, else the fed last token; on a ``return_hidden``
+        engine hidden [B, H], each slot's pre-final-norm hidden state at
+        the position whose logits produced its final emitted token (row
+        ``counts - 1``) — the learned drafter's next input; on a mixed
+        engine the lane's outputs, as ``_decode_block_impl`` appends them.
         """
         B, S = tokens.shape
         pos0 = cache["lengths"]
@@ -1202,13 +1134,21 @@ class InferenceEngine:
             params, cache, tokens, rows, pos0,
             extra_meta={"draft_valid": valid})  # logits [B, S, V]
         if poison:
-            # chaos only (trace-time): the build that proves
-            # speculative_accept's sanitized argmax keeps the emitted
-            # stream defined — decode_block's ``poison`` counterpart
+            # chaos only (trace-time): the build that proves the accept
+            # rule's sanitized argmax keeps the emitted stream defined —
+            # decode_block's ``poison`` counterpart
             logits = jnp.full_like(logits, jnp.nan)
-        emitted, counts = sampling.speculative_accept(
-            logits, tokens[:, 1:], key, temperature, top_k, top_p,
-            draft_len=valid - 1)
+        if self.key_schedule == "slot":
+            # rows[b, i] is exactly the fold_in data the non-speculative
+            # chain uses for the token following fed token i (its
+            # pre-step length)
+            emitted, counts = sampling.speculative_match(
+                logits, tokens[:, 1:], key, rows, temperature, top_k,
+                top_p, draft_len=valid - 1)
+        else:
+            emitted, counts = sampling.speculative_accept(
+                logits, tokens[:, 1:], key, temperature, top_k, top_p,
+                draft_len=valid - 1)
         raw = counts  # pre-clip: accepted drafts + 1 fresh token
         active = (pos0 > 0) & (budget > 0)
         counts = jnp.where(active, jnp.minimum(counts, budget), 0)
@@ -1221,107 +1161,32 @@ class InferenceEngine:
         # of the emitted run, all but (possibly) the last token are drafts:
         # when nothing clipped, raw - 1 drafts + 1 fresh; when EOS/budget
         # clipped below that, every emitted token was a draft
-        accepted = jnp.minimum(raw - 1, counts)
-        new_cache = self._rebuild(cache, new_leaves,
-                                  jnp.where(active, pos0 + counts, pos0))
-        out = (new_cache, emitted, counts, accepted)
-        if not self.return_hidden:
-            return out
+        out = {"tokens": emitted, "counts": counts,
+               "accepted": jnp.minimum(raw - 1, counts),
+               "cache": self._rebuild(
+                   cache, new_leaves,
+                   jnp.where(active, pos0 + counts, pos0))}
         # the last emitted token (greedy: == argmax over this row's
         # logits) came from row counts - 1; clip covers inactive rows
-        idx = jnp.clip(counts - 1, 0, S - 1)[:, None, None]
-        return out + (jnp.take_along_axis(h, idx, axis=1)[:, 0],)
+        last = jnp.clip(counts - 1, 0, S - 1)
+        if self.key_schedule == "slot":
+            out["next_tok"] = jnp.where(
+                counts > 0,
+                jnp.take_along_axis(emitted, last[:, None], axis=1)[:, 0],
+                tokens[:, 0])
+        if self.return_hidden:
+            out["hidden"] = jnp.take_along_axis(
+                h, last[:, None, None], axis=1)[:, 0]
+        return self._round_outputs("verify", params, out, lane)
 
-    def _decode_block_slot_impl(self, params, cache, tokens, base_keys,
-                                eos_id, budget, temperature, top_k, top_p,
-                                poison=False):
-        """``_decode_block_impl`` under the per-slot key schedule: instead
-        of one shared key per in-block step, every row's draw at pre-step
-        length ℓ uses ``fold_in(base_keys[b], ℓ)`` — the key that position
-        owns no matter how steps are grouped into rounds, which is the
-        invariant the overlap pipeline's bit-identity rests on
-        (docs/INFERENCE.md "Overlapped scheduling"). Also returns the
-        final carry token [B] (each slot's post-block last token, the
-        input token where a slot never ran) so the lookahead dispatch can
-        consume it without a host sync."""
-        rh = self.return_hidden
-        hid0 = jnp.zeros((tokens.shape[0], self.cfg.model.hidden_size),
-                         self._dt)
-
-        def step(carry, _):
-            cache, tok, budget, hid = carry
-            pos = cache["lengths"]
-            active = (pos > 0) & (budget > 0)
-            keys = jax.vmap(jax.random.fold_in)(base_keys, pos)
-            new_leaves, logits, h = self._decode_core(params, cache, tok)
-            if poison:
-                logits = jnp.full_like(logits, jnp.nan)
-            sampled = sampling.sample_rowkeys(logits, keys, temperature,
-                                              top_k, top_p)
-            emit = jnp.where(active, sampled, 0)
-            new_budget = jnp.where(active, budget - 1, budget)
-            hit_eos = active & (eos_id >= 0) & (sampled == eos_id)
-            new_budget = jnp.where(hit_eos, 0, new_budget)
-            new_cache = self._rebuild(cache, new_leaves,
-                                      jnp.where(active, pos + 1, pos))
-            next_tok = jnp.where(active, sampled, tok)
-            new_hid = jnp.where(active[:, None], h, hid) if rh else hid
-            return (new_cache, next_tok, new_budget, new_hid), (emit, active)
-
-        (cache, tok, _, hid), (toks, actives) = lax.scan(
-            step, (cache, tokens, budget, hid0), None,
-            length=self.decode_block_len)
-        out = (cache, jnp.swapaxes(toks, 0, 1),
-               jnp.sum(actives.astype(jnp.int32), axis=0), tok)
-        return out + (hid,) if rh else out
-
-    def _verify_slot_impl(self, params, cache, tokens, valid, base_keys,
-                          eos_id, budget, temperature, top_k, top_p,
-                          poison=False):
-        """``_verify_impl`` under the per-slot key schedule: acceptance is
-        sample-and-match (sampling.speculative_match) — the program draws
-        the target chain's own token at every fed position with that
-        position's folded key and accepts the matching draft prefix, so
-        the emitted stream never depends on the draft VALUES and equals
-        the per-position decode chain bit for bit (the property that lets
-        the overlap pipeline verify against one-round-stale drafts).
-        Returns an extra next-token output [B]: the last emitted token
-        where the row ran, else the fed last token."""
-        B, S = tokens.shape
-        pos0 = cache["lengths"]
-        rows = pos0[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-        new_leaves, logits, h = self._model_block(
-            params, cache, tokens, rows, pos0,
-            extra_meta={"draft_valid": valid})  # logits [B, S, V]
-        if poison:
-            logits = jnp.full_like(logits, jnp.nan)
-        # rows[b, i] is exactly the fold_in data the non-speculative chain
-        # uses for the token following fed token i (its pre-step length)
-        emitted, counts = sampling.speculative_match(
-            logits, tokens[:, 1:], base_keys, rows, temperature,
-            top_k, top_p, draft_len=valid - 1)
-        raw = counts
-        active = (pos0 > 0) & (budget > 0)
-        counts = jnp.where(active, jnp.minimum(counts, budget), 0)
-        cols = jnp.arange(S, dtype=jnp.int32)[None, :]
-        is_eos = ((eos_id >= 0)[:, None] & (emitted == eos_id[:, None])
-                  & (cols < counts[:, None]))
-        counts = jnp.where(jnp.any(is_eos, axis=1),
-                           jnp.argmax(is_eos, axis=1) + 1, counts)
-        emitted = jnp.where(cols < counts[:, None], emitted, 0)
-        accepted = jnp.minimum(raw - 1, counts)
-        new_cache = self._rebuild(cache, new_leaves,
-                                  jnp.where(active, pos0 + counts, pos0))
-        last_idx = jnp.clip(counts - 1, 0, S - 1)[:, None]
-        next_tok = jnp.where(
-            counts > 0,
-            jnp.take_along_axis(emitted, last_idx, axis=1)[:, 0],
-            tokens[:, 0])
-        out = (new_cache, emitted, counts, accepted, next_tok)
-        if not self.return_hidden:
-            return out
-        idx = jnp.clip(counts - 1, 0, S - 1)[:, None, None]
-        return out + (jnp.take_along_axis(h, idx, axis=1)[:, 0],)
+    def _round_outputs(self, kind: str, params, out: dict, lane) -> tuple:
+        """A round program's tail: on a mixed engine the fused prefill
+        lane, run on the cache the round just updated; then what the
+        program returns, in ``_round_fields``'s order."""
+        if self.mixed:
+            out["cache"], out["lane_out"], out["lane_hidden"] = \
+                self._lane_chunk(params, out["cache"], *lane)
+        return tuple(out[n] for n in self._round_fields(kind))
 
     def _prefill_chunk_impl(self, params, cache, tokens, slot, start, valid,
                             *sample):
@@ -1402,7 +1267,9 @@ class InferenceEngine:
         scratch page (paged), its lengths stay untouched, and its
         sampled token is garbage the host discards. Unlike the serial
         chunk program there is NO dp owner psum — each shard runs its
-        OWN lane and keeps its result in its [dp] output row."""
+        OWN lane and keeps its result in its [dp] output row. Returns
+        (cache, the sampled token or logits row, the lane's hidden state
+        or None)."""
         cfg = self.cfg
         rest = list(rest)
         sample = ()
@@ -1449,36 +1316,8 @@ class InferenceEngine:
         new_cache = self._rebuild(cache, new_leaves, new_lengths)
         out = self._epilogue(last, *sample) if self.sample_on_device \
             else last
-        if self.return_hidden:
-            return new_cache, out, h_last[:, 0]
-        return new_cache, out
-
-    def _decode_block_mixed_impl(self, params, cache, tokens, base_keys,
-                                 eos_id, budget, temperature, top_k,
-                                 top_p, *lane, poison=False):
-        """``_decode_block_slot_impl`` + one prefill lane in the SAME
-        program: the decode half runs first (the lane slot rides through
-        it inactive — budget 0, so its ghost row lands at its current
-        length and the lane immediately overwrites it), then the lane
-        chunk advances on the updated cache. Appends the lane outputs
-        (sampled token / logits row[, lane hidden]) after the decode
-        family's."""
-        d = self._decode_block_slot_impl(
-            params, cache, tokens, base_keys, eos_id, budget,
-            temperature, top_k, top_p, poison=poison)
-        ln = self._lane_chunk(params, d[0], *lane)
-        return (ln[0],) + d[1:] + ln[1:]
-
-    def _verify_mixed_impl(self, params, cache, tokens, valid, base_keys,
-                           eos_id, budget, temperature, top_k, top_p,
-                           *lane, poison=False):
-        """``_verify_slot_impl`` + one prefill lane, same contract as
-        ``_decode_block_mixed_impl``."""
-        d = self._verify_slot_impl(
-            params, cache, tokens, valid, base_keys, eos_id, budget,
-            temperature, top_k, top_p, poison=poison)
-        ln = self._lane_chunk(params, d[0], *lane)
-        return (ln[0],) + d[1:] + ln[1:]
+        return (new_cache, out,
+                h_last[:, 0] if self.return_hidden else None)
 
     # ---- host-facing API ---------------------------------------------------
 
@@ -2151,8 +1990,8 @@ class InferenceEngine:
             raise ValueError(
                 "decode_step is round-keyed (one shared key per step) and "
                 "a key_schedule='slot' engine samples with per-slot "
-                "position-folded keys — use decode_block, whose slot "
-                "variant owns the schedule")
+                "position-folded keys — use decode_block, which folds "
+                "them in-trace")
         self._hook("decode")
         if self.adapters is not None or adapter_ids is not None:
             params = self.bind_adapter_ids(params, adapter_ids, self.slots)
@@ -2187,7 +2026,7 @@ class InferenceEngine:
 
     def decode_block(self, params, cache, tokens, keys, eos_id, budget,
                      temperature, top_k, top_p, adapter_ids=None,
-                     lead=None, lanes=None) -> tuple:
+                     lead=None, lanes=None) -> RoundResult:
         """``decode_block_len`` tokens for every slot in one dispatch.
         ``keys`` is [decode_block_len, 2] (one PRNG key per in-block step)
         on a round-keyed engine, or the per-slot BASE keys [slots, 2] on a
@@ -2195,98 +2034,56 @@ class InferenceEngine:
         ``eos_id`` [slots] int32 (−1 = none), ``budget`` [slots] int32
         remaining tokens (0 for free slots). ``tokens`` may be a device
         array — it stays lazy (the overlap pipeline feeds the previous
-        round's on-device next-token output straight back in). Returns
-        (cache, tokens [slots, decode_block_len], produced counts
-        [slots]); a slot-keyed engine appends next_tok [slots] (each
-        slot's post-block last token, on device) and a ``return_hidden``
-        engine appends hidden [slots, H] — each slot's hidden state at
-        its last active step. Consumes ``cache``. ``lead`` forwards to
-        ``_pre_write`` (overlap's stale-host_len reach allowance); with
-        ``defer_advance`` set the paged length bookkeeping is skipped
-        here — the caller's sync stage applies it (``apply_advance``).
+        round's on-device next-token output straight back in). Returns a
+        ``RoundResult``: tokens [slots, decode_block_len], produced counts
+        [slots], and whatever else the engine's options add (``accepted``
+        is None). Consumes ``cache``. ``lead`` forwards to ``_pre_write``
+        (overlap's stale-host_len reach allowance); with ``defer_advance``
+        set the paged length bookkeeping is skipped here — the caller's
+        sync stage applies it (``apply_advance``).
 
         ``lanes`` (mixed_dispatch engines only — see ``_lane_args``)
         feeds each dp shard's fused prefill lane; a mixed engine ALWAYS
         runs the fused program (idle padded lanes when None), so the
-        compiled shape never changes. The lane outputs ride at the end
-        of the returned tuple: the lane token [dp] (sample_on_device) or
-        logits [dp, V], then lane hidden [dp, H] on a return_hidden
-        engine."""
-        if lanes is not None and not self.mixed:
-            raise ValueError(
-                "lanes requires a mixed_dispatch engine (construct with "
-                "mixed_dispatch=True or set inference.mixed_dispatch)")
-        keys = jnp.asarray(keys)
-        if self.key_schedule == "slot":
-            if keys.shape != (self.slots, 2):
+        compiled shape never changes."""
+        if self.key_schedule != "slot":
+            keys = jnp.asarray(keys)
+            if keys.shape[0] != self.decode_block_len:
                 raise ValueError(
-                    f"key_schedule='slot' takes per-slot base keys "
-                    f"[slots, 2] = [{self.slots}, 2]; got "
-                    f"{tuple(keys.shape)}")
-        elif keys.shape[0] != self.decode_block_len:
-            raise ValueError(
-                f"keys has {keys.shape[0]} rows; decode_block_len is "
-                f"{self.decode_block_len} (one key per in-block step)")
-        self._hook("decode", budget)
-        if self.adapters is not None or adapter_ids is not None:
-            params = self.bind_adapter_ids(params, adapter_ids, self.slots)
-        poison = self._poison("decode")
-        if self.paged is not None:
-            cache = self._lane_ensure(cache, lanes)
-            cache = self._pre_write(cache, self.decode_block_len,
-                                    budget=budget, lead=lead)
-        lane_args = self._lane_args(lanes) if self.mixed else ()
+                    f"keys has {keys.shape[0]} rows; decode_block_len is "
+                    f"{self.decode_block_len} (one key per in-block step)")
         # a device tokens array must NOT round-trip through np.asarray —
         # that sync is exactly what the overlap pipeline exists to avoid
-        tok_in = (tokens if isinstance(tokens, jax.Array)
-                  else jnp.asarray(np.asarray(tokens, np.int32)))
-        # the program is resolved INSIDE the lambda so the flash->dense
-        # fallback's rebuilt jits are what a re-dispatch runs
-        out = self._strip_stats(self._dispatch(
-            lambda: self._decode_block_prog(poison)(
-                params, cache, tok_in, keys,
-                jnp.asarray(np.asarray(eos_id, np.int32)),
-                jnp.asarray(np.asarray(budget, np.int32)),
-                jnp.asarray(np.asarray(temperature, np.float32)),
-                jnp.asarray(np.asarray(top_k, np.int32)),
-                jnp.asarray(np.asarray(top_p, np.float32)), *lane_args)))
-        if self.paged is not None and not self.defer_advance:
-            # mirror device length advancement (counts per slot). The
-            # host sync this forces is the block's ONE sync, just moved
-            # ahead of the batcher's own np.asarray on the same buffers.
-            self.paged.advance(np.asarray(out[2], np.int64))
-        return out
+        if not isinstance(tokens, jax.Array):
+            tokens = jnp.asarray(np.asarray(tokens, np.int32))
+        return self._round("decode_block", params, cache, (tokens,), keys,
+                           eos_id, budget, temperature, top_k, top_p,
+                           self.decode_block_len, budget, adapter_ids,
+                           lead, lanes)
 
     def verify(self, params, cache, tokens, key, eos_id, budget,
                temperature, top_k, top_p, draft_len=None,
-               adapter_ids=None, lead=None, lanes=None) -> tuple:
+               adapter_ids=None, lead=None, lanes=None) -> RoundResult:
         """One speculative draft-verify dispatch for every slot
         (``spec_len > 0`` engines only). ``tokens`` is
         [slots, spec_len + 1] int32 — column 0 is each slot's current last
         token, columns 1..spec_len its drafted continuation; the remaining
         arguments are [slots] arrays exactly as ``decode_block`` takes
-        them. ``draft_len`` [slots] int32 (optional) makes the dispatch
+        them (on a ``key_schedule='slot'`` engine ``key`` is the per-slot
+        base keys [slots, 2] and ``tokens`` may be a device array).
+        ``draft_len`` [slots] int32 (optional) makes the dispatch
         RAGGED: slot b proposed only ``draft_len[b] <= spec_len`` real
         drafts (the controller's per-slot choice) — pad columns past it
         are masked out of acceptance and the K/V write while the compiled
         shape stays [slots, spec_len + 1], so mixed per-slot lengths cost
         no recompile. None = every slot drafted the full spec_len.
-        Returns (cache, emitted [slots, spec_len + 1], counts
-        [slots], accepted-draft counts [slots]) — ``counts[b]`` leading
-        entries of emitted row b are the tokens slot b produced this
-        dispatch (1..spec_len + 1 per active slot); a slot-keyed engine
-        (``key_schedule='slot'``, where ``key`` is the per-slot base keys
-        [slots, 2] and ``tokens`` may be a device array) appends next_tok
-        [slots] — each row's on-device last emitted token — and a
-        ``return_hidden`` engine appends hidden [slots, H]. Consumes
-        ``cache``. ``lead``/``defer_advance``/``lanes``: see
-        ``decode_block``."""
-        if lanes is not None and not self.mixed:
-            raise ValueError(
-                "lanes requires a mixed_dispatch engine (construct with "
-                "mixed_dispatch=True or set inference.mixed_dispatch)")
-        if (self._verify_jit is None and self._verify_slot_jit is None
-                and self._verify_mixed_jit is None):
+        Returns a ``RoundResult``: tokens (the emitted runs)
+        [slots, spec_len + 1], counts [slots] — ``counts[b]`` leading
+        entries of row b are the tokens slot b produced this dispatch
+        (1..spec_len + 1 per active slot) — accepted-draft counts [slots],
+        and whatever else the engine's options add. Consumes ``cache``.
+        ``lead``/``defer_advance``/``lanes``: see ``decode_block``."""
+        if self.spec_len < 1:
             raise ValueError(
                 "speculative decoding is off for this engine (spec_len == "
                 "0); construct it with spec_len > 0 or set "
@@ -2313,36 +2110,67 @@ class InferenceEngine:
                     f"draft_len entries must be in [0, spec_len = "
                     f"{self.spec_len}]; got {draft_len.tolist()}")
             valid = draft_len + 1
+        # the verify writes spec_len + 1 rows OPTIMISTICALLY for every
+        # parked slot whatever its budget; ensuring them all exclusive
+        # BEFORE the dispatch is what makes the rollback free — rejected
+        # rows strand in pages only this slot holds, never in a shared one
+        return self._round("verify", params, cache,
+                           (jnp.asarray(tokens), jnp.asarray(valid)), key,
+                           eos_id, budget, temperature, top_k, top_p,
+                           self.spec_len + 1, None, adapter_ids, lead,
+                           lanes)
+
+    def _round(self, kind: str, params, cache, rows, keys, eos_id, budget,
+               temperature, top_k, top_p, nwrite: int, reach,
+               adapter_ids, lead, lanes) -> RoundResult:
+        """The host half ``decode_block`` and ``verify`` share (the
+        batcher's ``step/issue``): checks, hooks, adapter binding, the
+        paged pre-write of up to ``nwrite`` rows a slot (``reach``: see
+        ``_pre_write``'s ``budget``), the dispatch of ``_program(kind)``
+        on ``rows`` (the program's leading device operands) and the
+        result by name."""
+        if lanes is not None and not self.mixed:
+            raise ValueError(
+                "lanes requires a mixed_dispatch engine (construct with "
+                "mixed_dispatch=True or set inference.mixed_dispatch)")
         if self.key_schedule == "slot":
             # per-slot base keys [slots, 2]; positions fold in-trace
-            key = jnp.asarray(key)
-            if key.shape != (self.slots, 2):
+            keys = jnp.asarray(keys)
+            if keys.shape != (self.slots, 2):
                 raise ValueError(
                     f"key_schedule='slot' takes per-slot base keys "
                     f"[slots, 2] = [{self.slots}, 2]; got "
-                    f"{tuple(key.shape)}")
-        self._hook("verify", budget)
+                    f"{tuple(keys.shape)}")
+        hook = "decode" if kind == "decode_block" else kind
+        self._hook(hook, budget)
         if self.adapters is not None or adapter_ids is not None:
             params = self.bind_adapter_ids(params, adapter_ids, self.slots)
-        poison = self._poison("verify")
+        poison = self._poison(hook)
         if self.paged is not None:
-            # the verify writes spec_len + 1 rows OPTIMISTICALLY for every
-            # parked slot; ensuring them all exclusive BEFORE the dispatch
-            # is what makes the rollback free — rejected rows strand in
-            # pages only this slot holds, never in a shared one
             cache = self._lane_ensure(cache, lanes)
-            cache = self._pre_write(cache, self.spec_len + 1, lead=lead)
+            cache = self._pre_write(cache, nwrite, budget=reach, lead=lead)
         lane_args = self._lane_args(lanes) if self.mixed else ()
-        # resolved inside the lambda, exactly like decode_block's program
-        out = self._dispatch(lambda: self._verify_prog(poison)(
-            params, cache, jnp.asarray(tokens), jnp.asarray(valid), key,
-            jnp.asarray(np.asarray(eos_id, np.int32)),
-            jnp.asarray(np.asarray(budget, np.int32)),
-            jnp.asarray(np.asarray(temperature, np.float32)),
-            jnp.asarray(np.asarray(top_k, np.int32)),
-            jnp.asarray(np.asarray(top_p, np.float32)), *lane_args))
+        # the program is resolved INSIDE the lambda so the flash->dense
+        # fallback's rebuilt table is what a re-dispatch reads
+        out = dict(zip(self._round_fields(kind), self._dispatch(
+            lambda: self._program(kind, poison)(
+                params, cache, *rows, keys,
+                jnp.asarray(np.asarray(eos_id, np.int32)),
+                jnp.asarray(np.asarray(budget, np.int32)),
+                jnp.asarray(np.asarray(temperature, np.float32)),
+                jnp.asarray(np.asarray(top_k, np.int32)),
+                jnp.asarray(np.asarray(top_p, np.float32)), *lane_args))))
+        if "stats" in out:
+            # kept, still on the device, for ``take_stats``
+            self._stats_pending.append(out.pop("stats"))
+        lane = ((out.pop("lane_out"), out.pop("lane_hidden", None))
+                if self.mixed else None)
+        res = RoundResult(lane=lane, **out)
         if self.paged is not None and not self.defer_advance:
-            # device lengths advanced by the ACCEPTED counts (the length
-            # pointer is the rollback) — mirror exactly that
-            self.paged.advance(np.asarray(out[2], np.int64))
-        return out
+            # mirror device length advancement (counts per slot; a verify
+            # advanced by the ACCEPTED counts — the length pointer is the
+            # rollback). The host sync this forces is the round's ONE
+            # sync, just moved ahead of the batcher's own np.asarray on
+            # the same buffers.
+            self.paged.advance(np.asarray(res.counts, np.int64))
+        return res

@@ -296,7 +296,7 @@ def _serving_program(topo, prog, layout):
     arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep)
     B = SERVE_SLOTS
     if prog == "decode_block":
-        jitted = eng._decode_block_jit
+        jitted = eng._program("decode_block")
         args = (arg((B,), I32), arg((eng.decode_block_len, 2), jnp.uint32),
                 arg((B,), I32), arg((B,), I32), arg((B,), F32),
                 arg((B,), I32), arg((B,), F32))
@@ -366,7 +366,7 @@ def _latent_program(topo, prog):
     rep = named_shardings(mesh, jax.sharding.PartitionSpec())
     arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep)
     if prog == "decode_block":
-        jitted = eng._decode_block_jit
+        jitted = eng._program("decode_block")
         args = (arg((8,), I32), arg((eng.decode_block_len, 2), jnp.uint32),
                 arg((8,), I32), arg((8,), I32), arg((8,), F32),
                 arg((8,), I32), arg((8,), F32))

@@ -156,10 +156,11 @@ def test_decode_block_counts_and_matches_single_steps():
                      for i in range(engine.decode_block_len)])
     toks = np.zeros(2, np.int32)
     toks[0] = seq[len(PROMPT)]
-    cache, out, counts = engine.decode_block(
+    r = engine.decode_block(
         params, cache, toks, keys, -np.ones(2, np.int32),
         np.array([2, 0], np.int32), np.zeros(2, np.float32),
         np.zeros(2, np.int32), np.ones(2, np.float32))
+    cache, out, counts = r.cache, r.tokens, r.counts
     assert list(np.asarray(counts)) == [2, 0]
     assert list(np.asarray(out)[0, :2]) == seq[len(PROMPT) + 1:]
     stats = engine.take_stats()

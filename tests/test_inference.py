@@ -381,6 +381,131 @@ def test_round_hands_decode_block_device_keys(tiny_model_kwargs,
     assert splits == 0 and programs == 1
 
 
+# tokens of PR 29's tree, whose slot-keyed programs were separate bodies
+# (`_decode_block_slot_impl`, `_verify_slot_impl` and their `_mixed_impl`
+# wrappers): a slot-keyed stream is a function of (base key, position)
+# alone, so the one pin holds with and without speculation and the lane
+_SLOT_PINS = {"a": [39, 6, 173, 138, 36, 251, 59, 3, 43, 189, 205, 79, 254,
+                    56],
+              "b": [133, 67, 42, 82, 179, 156, 215, 111, 130, 185]}
+
+
+@pytest.mark.parametrize("mixed", [False, True])
+@pytest.mark.parametrize("spec_len", [0, 3])
+def test_slot_keyed_streams_equal_parent(tiny_model_kwargs, mixed, spec_len):
+    """Two sampled requests admitted in different rounds under
+    ``key_schedule: slot`` draw the tokens the parent's twin bodies drew."""
+    cfg, engine = _engine(tiny_model_kwargs, decode_block_len=4,
+                          prefill_chunk=8, spec_len=spec_len,
+                          key_schedule="slot", mixed_dispatch=mixed)
+    b = ContinuousBatcher(engine, _params(cfg, engine), seed=7)
+    b.submit(Request("a", list(range(5, 25)), max_new_tokens=14,
+                     temperature=0.9, top_k=40))
+    b.step()
+    b.step()
+    b.submit(Request("b", [9, 10, 11, 9, 10, 11, 9, 10], max_new_tokens=10,
+                     temperature=1.3, top_k=8, top_p=0.95))
+    res = b.run()
+    assert {u: r.tokens for u, r in res.items()} == _SLOT_PINS
+
+
+class _PoisonOnDemand:
+    """Dispatch hooks that poison a round only while ``on`` is set."""
+    on = False
+
+    def before_dispatch(self, kind, slots):
+        pass
+
+    def poison_logits(self, kind):
+        return self.on
+
+
+@pytest.mark.parametrize("family", ["round", "slot", "mixed"])
+@pytest.mark.parametrize("kind", ["decode_block", "verify"])
+def test_round_program_table_name_and_record(tiny_model_kwargs, monkeypatch,
+                                             kind, family):
+    """One body, one table, one record, for each round program under each
+    family of options: (a) the table holds what was asked for and a
+    poisoned build only once a hook asks; (b) the lowered module is named
+    after the one body whatever the schedule (benchmarks/stats.py finds
+    the decode program by ``_decode_block_impl``); (c) the record's fields
+    are None exactly where the options produce nothing."""
+    hooks = _PoisonOnDemand()
+    rh = family != "round"
+    cfg, eng = _engine(
+        tiny_model_kwargs, spec_len=3 if kind == "verify" else 0,
+        decode_block_len=4, prefill_chunk=8, return_hidden=rh, hooks=hooks,
+        mixed_dispatch=family == "mixed",
+        key_schedule="round" if family == "round" else "slot")
+    params = _params(cfg, eng)
+    assert eng._programs == {}
+    seen = {}
+    build = eng._program
+
+    def program(k, poison=False):
+        def run(*args):  # the cache is donated: keep the shapes
+            seen[k, poison] = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
+            return build(k, poison)(*args)
+        return run
+
+    monkeypatch.setattr(eng, "_program", program)
+    n = eng.slots
+    rows = (np.full(n, -1, np.int32), np.array([4, 0], np.int32),
+            np.zeros(n, np.float32), np.zeros(n, np.int32),
+            np.ones(n, np.float32))
+    keys = {"round": (jax.random.split(jax.random.PRNGKey(1), 4)
+                      if kind == "decode_block" else jax.random.PRNGKey(1))
+            }.get(family, np.zeros((n, 2), np.uint32))
+
+    def one_round(cache):
+        cache = eng.insert(cache, eng.prefill(params, [1, 2, 3, 4, 5])[0],
+                           0, 5)
+        if kind == "verify":
+            return eng.verify(params, cache,
+                              np.array([[6, 7, 8, 9], [0] * 4], np.int32),
+                              keys, *rows)
+        return eng.decode_block(params, cache, np.array([6, 0], np.int32),
+                                keys, *rows)
+
+    r = one_round(eng.init_cache())
+    # (a) built by the first round that asked, and nothing else
+    assert set(eng._programs) == {(kind, False)}
+    if kind == "decode_block":
+        with pytest.raises(ValueError, match="spec_len"):
+            eng.verify(params, r.cache, np.zeros((n, 1), np.int32), keys,
+                       *rows)
+    # (c) fixed fields, None where the options produce nothing
+    assert (r.accepted is None) == (kind == "decode_block")
+    assert (r.next_tok is None) == (family == "round")
+    assert (r.hidden is None) == (not rh)
+    assert (r.lane is None) == (family != "mixed")
+    assert 1 <= int(np.asarray(r.counts)[0]) <= 4
+    assert int(np.asarray(r.counts)[1]) == 0
+    if r.hidden is not None:
+        assert r.hidden.shape == (n, cfg.model.hidden_size)
+    if r.next_tok is not None:
+        c = int(np.asarray(r.counts)[0])
+        assert int(np.asarray(r.next_tok)[0]) == \
+            int(np.asarray(r.tokens)[0, c - 1])
+    if r.lane is not None:
+        out, hid = r.lane  # an idle lane: its shapes, not its values
+        assert out.shape[0] == eng.dp_size and hid.shape[0] == eng.dp_size
+    # (b) the module's name is the one body's
+    text = build(kind).lower(*seen[kind, False]).as_text()
+    body = "_verify_impl" if kind == "verify" else "_decode_block_impl"
+    assert f"module @jit_{body} " in text.split("\n", 1)[0]
+    # (a) a poisoned build exists only once chaos asks; its tokens are
+    # defined (the sampler's non-finite gate)
+    hooks.on = True
+    p = one_round(eng.init_cache())
+    assert set(eng._programs) == {(kind, False), (kind, True)}
+    assert (kind, True) in seen
+    toks = np.asarray(p.tokens)
+    assert ((toks >= 0) & (toks < cfg.model.vocab_size)).all()
+    assert int(np.asarray(p.counts)[0]) >= 1
+
+
 # --------------------------------------------------------------------------- #
 # (d) checkpoint -> engine round trip
 # --------------------------------------------------------------------------- #

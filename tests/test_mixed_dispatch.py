@@ -217,7 +217,7 @@ def test_mixed_off_default_leaves_serial_path(tiny_model_kwargs):
     before the lane existed."""
     cfg, eng = _engine(tiny_model_kwargs, False)
     assert eng.mixed is False
-    assert getattr(eng, "_decode_block_mixed_jit", None) is None
+    assert "lane_out" not in eng._round_fields("decode_block")
     b = ContinuousBatcher(eng, _params(cfg, eng), seed=7)
     assert b._mixed is False and all(ln is None for ln in b._lanes)
     cache = eng.init_cache()

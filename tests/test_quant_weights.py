@@ -248,10 +248,11 @@ def test_decode_block_matches_fakequant(tiny_model_kwargs, attend_impl,
         cache = eng.insert(cache, kv, 0, 8)
         toks = np.array([int(np.argmax(np.asarray(logits)[0])), 0], np.int32)
         keys = jnp.stack([jax.random.PRNGKey(7)] * 4)
-        cache, blk, counts = eng.decode_block(
+        r = eng.decode_block(
             params, cache, toks, keys, np.full(2, -1, np.int32),
             np.array([8, 0], np.int32), np.zeros(2, np.float32),
             np.zeros(2, np.int32), np.ones(2, np.float32))
+        cache, blk, counts = r.cache, r.tokens, r.counts
         outs.append((int(toks[0]), np.asarray(blk), np.asarray(counts)))
     assert outs[0][0] == outs[1][0]  # prefill argmax
     np.testing.assert_array_equal(outs[0][1], outs[1][1])
@@ -274,11 +275,13 @@ def test_verify_matches_fakequant(tiny_model_kwargs, attend_impl):
             kv, _ = eng.prefill(params, list(range(1 + slot, 9 + slot)))
             cache = eng.insert(cache, kv, slot, 8)
         tokens = np.array([[3, 5, 7, 9], [4, 6, 8, 10]], np.int32)
-        cache, emitted, counts, accepted = eng.verify(
+        r = eng.verify(
             params, cache, tokens, jax.random.PRNGKey(3),
             np.full(2, -1, np.int32), np.full(2, 8, np.int32),
             np.zeros(2, np.float32), np.zeros(2, np.int32),
             np.ones(2, np.float32))
+        cache, emitted = r.cache, r.tokens
+        counts, accepted = r.counts, r.accepted
         outs.append(tuple(np.asarray(x) for x in
                           (emitted, counts, accepted, cache["lengths"])))
     for a, b in zip(*outs):

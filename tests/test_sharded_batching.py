@@ -222,9 +222,10 @@ def _decode_rounds(eng, params, cache, last_by_slot, rounds=2):
         for s, t in last_by_slot.items():
             feed[s], budget[s] = t, eng.decode_block_len
         key, *subs = jax.random.split(key, eng.decode_block_len + 1)
-        cache, toks, counts = eng.decode_block(
+        r = eng.decode_block(
             params, cache, feed, np.asarray(subs), eos, budget,
             temp, top_k, top_p)
+        cache, toks, counts = r.cache, r.tokens, r.counts
         toks = np.asarray(toks)
         for s in list(last_by_slot):
             got = [int(t) for t in toks[s, :int(np.asarray(counts)[s])]]
@@ -301,10 +302,11 @@ def test_migration_after_speculative_verify_exports_accepted_only(
         toks[0] = [first, first, first]
         budget = np.zeros(n, np.int32)
         budget[0] = 8
-        cache, emitted, counts, _acc = eng.verify(
+        r = eng.verify(
             params, cache, toks, jax.random.PRNGKey(1),
             np.full(n, -1, np.int32), budget, np.zeros(n, np.float32),
             np.zeros(n, np.int32), np.ones(n, np.float32))
+        cache, emitted, counts = r.cache, r.tokens, r.counts
         got = [int(t) for t in
                np.asarray(emitted)[0, :int(np.asarray(counts)[0])]]
         slot = 0

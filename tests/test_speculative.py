@@ -295,8 +295,9 @@ def test_spec_sampled_e2e_distribution(tiny_model_kwargs):
     first = np.zeros(n, np.int32)
     for i in range(n):
         cache = jax.tree.map(jnp.asarray, cache0)
-        _, emitted, counts, _ = engine.verify(
+        r = engine.verify(
             params, cache, tokens, jax.random.PRNGKey(i), *args)
+        emitted, counts = r.tokens, r.counts
         assert int(np.asarray(counts)[0]) >= 1
         first[i] = np.asarray(emitted)[0, 0]
     freq = np.bincount(first, minlength=cfg.model.vocab_size) / n
@@ -564,9 +565,11 @@ def test_ragged_verify_matches_per_slot_sequential(tiny_model_kwargs, tp,
         tokens = np.concatenate(
             [np.asarray([[p[-1]] for p in prompts], np.int32), drafts],
             axis=1)
-        cache, emitted, counts, accepted = engine.verify(
+        r = engine.verify(
             params, cache, tokens, key, eos, budget, temps, tk, tp_,
             draft_len=draft_len)
+        cache, emitted = r.cache, r.tokens
+        counts, accepted = r.counts, r.accepted
         return (np.asarray(emitted), np.asarray(counts),
                 np.asarray(accepted), np.asarray(cache["lengths"]))
 
@@ -608,9 +611,10 @@ def test_ragged_zero_draft_row_matches_decode_step(tiny_model_kwargs):
         params, park(), np.asarray([4, 7], np.int32), key, *args[2:])
     want = np.asarray(want)  # greedy: the sampled token IS the argmax
     tokens = np.asarray([[4, 111, 112, 113], [7, 114, 115, 116]], np.int32)
-    _, emitted, counts, _ = engine.verify(
+    r = engine.verify(
         params, park(), tokens, key, *args,
         draft_len=np.zeros(2, np.int32))
+    emitted, counts = r.tokens, r.counts
     counts = np.asarray(counts)
     np.testing.assert_array_equal(counts, [1, 1])
     np.testing.assert_array_equal(np.asarray(emitted)[:, 0], want)
@@ -661,8 +665,9 @@ def test_return_hidden_is_the_logits_producing_state(tiny_model_kwargs):
     args = (np.full(2, -1, np.int32), np.asarray([4, 2], np.int32),
             np.zeros(2, np.float32), np.zeros(2, np.int32),
             np.ones(2, np.float32))
-    cache, toks, counts, hid = engine.decode_block(
+    r = engine.decode_block(
         params, cache, first, keys, *args)
+    cache, toks, counts, hid = r.cache, r.tokens, r.counts, r.hidden
     toks, counts = np.asarray(toks), np.asarray(counts)
     for s in range(2):
         last = toks[s, counts[s] - 1]
@@ -675,11 +680,12 @@ def test_return_hidden_is_the_logits_producing_state(tiny_model_kwargs):
     tokens = np.zeros((2, 4), np.int32)
     tokens[:, 0] = last_toks
     tokens[0, 1:3] = [7, 7]
-    cache, emitted, vcounts, _, vhid = engine.verify(
+    r = engine.verify(
         params, cache, tokens, jax.random.PRNGKey(9),
         np.full(2, -1, np.int32), np.full(2, 8, np.int32),
         np.zeros(2, np.float32), np.zeros(2, np.int32),
         np.ones(2, np.float32), draft_len=np.asarray([2, 0], np.int32))
+    cache, emitted, vcounts, vhid = r.cache, r.tokens, r.counts, r.hidden
     emitted, vcounts = np.asarray(emitted), np.asarray(vcounts)
     for s in range(2):
         last = emitted[s, vcounts[s] - 1]
