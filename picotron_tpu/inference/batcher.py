@@ -772,6 +772,11 @@ class ContinuousBatcher:
             queue_wait_s=self._queue_wait_hist.percentiles(),
             ttft_s=self._ttft_hist.percentiles(),
         )
+        # what the cache is (engine.__init__ reads both off its shapes):
+        # resident bytes, and for K/V leaves the heads a lane row holds
+        d["kv_cache_bytes"] = self.engine.kv_cache_bytes
+        if self.engine.kv_pack is not None:
+            d["kv_pack_factor"] = self.engine.kv_pack
         if self.draft_proposed:
             d["accept_rate"] = self.accept_rate
         if self.engine.spec_len > 0:

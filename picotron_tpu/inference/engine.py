@@ -526,8 +526,23 @@ class InferenceEngine:
             self._init_cache_jit = jax.jit(
                 partial(self.model.init_cache, m, self.slots,
                         self.max_seq_len, dtype=self.cache_dtype,
-                        quantized=self.quantized),
+                        quantized=self.quantized, tp=topo.tp_size),
                 out_shardings=named_shardings(topo, self._cspecs))
+        # what the cache is, read off its own shapes: the heads a row of
+        # K and V holds (kv_cache.pack_factor; None without such leaves)
+        # and the bytes resident for the life of the server
+        shapes = jax.eval_shape(self._init_cache_jit)
+        self.kv_pack = kv_cache.kv_pack(shapes, m.head_dim)
+        self.kv_cache_bytes = kv_cache.cache_bytes(shapes)
+        if self.kv_pack is not None:
+            self.obs.registry.gauge(
+                "picotron_kv_pack_factor",
+                "kv heads stored side by side in one cache row").set(
+                    self.kv_pack)
+        self.obs.registry.gauge(
+            "picotron_kv_cache_bytes",
+            "bytes of the resident KV cache leaves").set(
+                self.kv_cache_bytes)
 
     def _build_programs(self) -> None:
         """(Re)build the compiled model programs. Runs at construction and
@@ -731,13 +746,16 @@ class InferenceEngine:
         """Prefill K/V blocks in cache storage form: quantize (int8 mode)
         or cast to the cache dtype. hot_bf16 policy engines pack BOTH
         representations (full precision + int8 with scales) — the paged
-        insert parks them side by side, the per-page flag picks the read."""
+        insert parks them side by side, the per-page flag picks the read.
+        K and V leave as the cache's rows lie: ``kv_pack`` heads a row."""
+        pack = partial(kv_cache.pack_heads, p=self.kv_pack)
         if self.quantized:
             qk, ks = kv_cache.quantize_kv(K)
             qv, vs = kv_cache.quantize_kv(V)
-            return {"k": qk, "v": qv, "k_scale": ks, "v_scale": vs}
-        out = {"k": K.astype(self.cache_dtype),
-               "v": V.astype(self.cache_dtype)}
+            return {"k": pack(qk), "v": pack(qv),
+                    "k_scale": ks, "v_scale": vs}
+        out = {"k": pack(K.astype(self.cache_dtype)),
+               "v": pack(V.astype(self.cache_dtype))}
         if self.page_policy:
             qk, ks = kv_cache.quantize_kv(K)
             qv, vs = kv_cache.quantize_kv(V)

@@ -6,7 +6,10 @@ must attend over all previous keys without recomputing them — so the cache
 preallocates the whole attention past once and every decode step writes one
 row per sequence:
 
-- ``k``/``v``: ``[num_layers, slots, max_seq_len, n_kv_heads, head_dim]``.
+- ``k``/``v``: ``[num_layers, slots, max_seq_len, n_kv_heads / p,
+  p * head_dim]`` — ``p`` neighbouring kv heads side by side in one row
+  (``pack_factor``; "The packed row" below). ``p == 1`` for heads of 128
+  and wider: the plain ``[.., n_kv_heads, head_dim]`` leaf.
   The layer axis leads (rather than the naive ``[batch, layers, ...]``
   ordering) so one layer is one contiguous ``[B, T, H, D]`` block — the
   layout ``ops/attention.py`` already uses — that a layer index addresses.
@@ -38,6 +41,40 @@ resharding; the scale tensors shard their (trailing) head axis the same
 way; everything else is replicated (``cache_pspecs``). Unquantized dtype
 follows the model's param dtype (bf16 on the production configs; fp32 tiny
 CPU models stay exact against the ``forward_logits`` oracle).
+
+The packed row. The TPU tiles an array's two minor dimensions into
+(8, 128) registers, 128 lanes wide. A leaf whose minor dimension is a head
+of 64 fills half of each lane row, so the compiler lays the resident
+array out with the TOKENS minor-most (``{2,4,3,1,0}``, unpadded) and every
+decode program converts it on entry to head-minor, lane-padded 64 -> 128
+(twice the bytes), works on that, and converts it back on exit: at
+SmolLM's 32 heads of 64 the compiled text held four whole-leaf copies
+(``copy.18/.19`` in, ``copy.25/.26`` out; 17 % of the device's time in the
+``smollm-1.7b.serve-batch`` trace of PR 30) and attention read a window
+that was half padding. So a leaf's row is always whole lanes:
+``p = 128 // head_dim`` neighbouring kv heads lie side by side in one
+``p * head_dim``-wide row (``pack_factor``: when 128 divides by the head,
+the head is narrower than 128 and the LOCAL kv head count after 'tp'
+divides by ``p``; else ``p == 1``). The bytes are those of the row-major
+``[.., n_kv_heads, head_dim]`` array, so packing fresh rows is a reshape
+(``pack_heads``), the compiler keeps the leaf row-major (``{4,3,2,1,0}``)
+and no program copies it (tests/test_chip_compile.py reads both off the
+compiled text). ``decode_attention`` contracts whole rows: each query
+head sits in its own head's lanes of a row that is zero elsewhere (an
+exact zero times a finite key adds an exact zero), and of the value
+contraction's ``p`` candidate blocks each head keeps its own lanes: twice
+the step's attention FLOPs, K and V read from HBM once and unpadded. (What
+the compiler makes of it, as of Mistral's heads of 128: a fusion slices
+the layer of K, 33.5 MB at SmolLM's 4 x 2048, into on-chip memory at HBM
+speed, and the contraction's fusion re-lays it head-major from there; the
+two run one after the other, 42 + 37 us on a v5e, PERF.md PR 31, where one
+pass at HBM speed would be 42.) The pack
+factor of a leaf is read off its own shape (``leaf.shape[-1] //
+head_dim``); this module alone knows the layout, and whatever needs
+``[.., n_kv_heads, head_dim]`` (the flash decode kernel, the int8 scales)
+takes ``unpack_heads``. With ``p == 1`` every function here runs the
+operations it ran before the packed row existed. The paged pool
+(paged_kv.py) is not packed.
 """
 
 from __future__ import annotations
@@ -45,6 +82,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
 from picotron_tpu.config import ModelConfig
@@ -54,6 +92,7 @@ from picotron_tpu.ops.attention import NEG_INF
 # multiply with no double-rounding
 INT8_MAX = 127.0
 SCALE_DTYPE = jnp.float32
+LANE = 128  # lanes of a TPU register row: what a leaf's row is made whole to
 
 
 def cache_pspecs(quantized: bool = False, dp: int = 1) -> dict:
@@ -74,19 +113,47 @@ def cache_pspecs(quantized: bool = False, dp: int = 1) -> dict:
     return specs
 
 
+def pack_factor(head_dim: int, kv_heads: int) -> int:
+    """Heads that share one lane row of a K/V leaf, given the head size
+    and the kv heads ONE device holds (after 'tp')."""
+    p = LANE // head_dim
+    if head_dim < LANE and LANE % head_dim == 0 and kv_heads % p == 0:
+        return p
+    return 1
+
+
+def pack_heads(x: jnp.ndarray, p: int) -> jnp.ndarray:
+    """[..., H, D] -> [..., H / p, p * D]: the same bytes, ``p`` heads a
+    row."""
+    return x.reshape(x.shape[:-2] + (x.shape[-2] // p, p * x.shape[-1]))
+
+
+def unpack_heads(x: jnp.ndarray, head_dim: int) -> jnp.ndarray:
+    """[..., H / p, p * D] -> [..., H, D], the inverse of ``pack_heads``."""
+    return x.reshape(x.shape[:-2] + (-1, head_dim))
+
+
+def kv_pack(cache: dict, head_dim: int):
+    """Heads a row of this cache's K/V leaves holds, read off the leaf's
+    shape; None for a cache without them (the latent cache)."""
+    return cache["k"].shape[-1] // head_dim if "k" in cache else None
+
+
 def init_cache(m: ModelConfig, slots: int, max_seq_len: int,
-               dtype=None, quantized: bool = False) -> dict:
-    """Zeroed global-shape cache for ``slots`` concurrent sequences. Jit
-    with out_shardings (engine.init_cache) to materialize each device's
-    shard directly."""
-    shape = (m.num_hidden_layers, slots, max_seq_len,
-             m.num_key_value_heads, m.head_dim)
+               dtype=None, quantized: bool = False, tp: int = 1) -> dict:
+    """Zeroed global-shape cache for ``slots`` concurrent sequences on a
+    mesh whose 'tp' axis is ``tp`` wide. Jit with out_shardings
+    (engine.init_cache) to materialize each device's shard directly."""
+    p = pack_factor(m.head_dim, m.num_key_value_heads // tp)
+    per_head = (m.num_hidden_layers, slots, max_seq_len,
+                m.num_key_value_heads)  # a scale a head, packed or not
+    shape = per_head[:-1] + (per_head[-1] // p, p * m.head_dim)
     if quantized:
         cache = {
             "k": jnp.zeros(shape, jnp.int8),
             "v": jnp.zeros(shape, jnp.int8),
-            "k_scale": jnp.zeros(shape[:-1], SCALE_DTYPE),
-            "v_scale": jnp.zeros(shape[:-1], SCALE_DTYPE),
+            "k_scale": jnp.zeros(per_head, SCALE_DTYPE),
+            "v_scale": jnp.zeros(per_head, SCALE_DTYPE),
         }
     else:
         dt = jnp.dtype(dtype if dtype is not None else m.dtype)
@@ -106,7 +173,6 @@ def init_cache(m: ModelConfig, slots: int, max_seq_len: int,
 # (``insert_prefill``, ``release``) and ``write_rows`` take it as it is;
 # there is nothing for 'tp' to shard.
 LATENT_LEAVES = ("ckv", "ki")
-LANE = 128
 
 
 def latent_widths(m: ModelConfig) -> dict:
@@ -204,7 +270,8 @@ def cache_write(cache: dict, k_new: jnp.ndarray, v_new: jnp.ndarray,
       written it).
 
     int8 caches quantize on write; the scale rows land at the same
-    positions in ``k_scale``/``v_scale``.
+    positions in ``k_scale``/``v_scale``. The rows reach the leaf as it
+    lies: ``p`` heads a row (``pack_heads``, a reshape of the new rows).
 
     RAGGED verify (the per-slot spec_len controller): a ``draft_valid``
     [B] int32 entry (spliced per dispatch by engine._verify_impl) caps
@@ -233,7 +300,15 @@ def cache_write(cache: dict, k_new: jnp.ndarray, v_new: jnp.ndarray,
             out[sname] = write_rows(cache, sname, scales, pos, layer)
         else:
             vals = new
-        out[name] = write_rows(cache, name, vals, pos, layer)
+        p = cache[name].shape[-1] // new.shape[-1]
+        out[name] = write_rows(cache, name, pack_heads(vals, p), pos, layer)
+        if p > 1:
+            # whole lanes a row: hold the carried leaf to the row-major
+            # layout it is resident in. Left free, a prefill chunk's
+            # contractions pull the whole leaf head-major on entry and
+            # push it back on exit (two copies of the cache a chunk)
+            out[name] = with_layout_constraint(
+                out[name], Layout(major_to_minor=tuple(range(out[name].ndim))))
     return out
 
 
@@ -319,7 +394,10 @@ def attend(q: jnp.ndarray, cache: dict, lengths: jnp.ndarray,
         return paged_kv.attend(q, cache, lengths, scale, layer, impl)
     k, v, k_scale, v_scale = (layer_block(cache, n, layer)
                               for n in ("k", "v", "k_scale", "v_scale"))
+    D = q.shape[-1]
     if impl == "flash":
+        # the kernel takes a head a row
+        k, v = unpack_heads(k, D), unpack_heads(v, D)
         from picotron_tpu.ops.pallas.decode_attention import (
             flash_decode_attention,
         )
@@ -332,8 +410,10 @@ def attend(q: jnp.ndarray, cache: dict, lengths: jnp.ndarray,
         # a typo'd impl must not silently measure the wrong kernel
         raise ValueError(f"unknown attend impl {impl!r} (dense|flash)")
     if quantized(cache):
-        k = dequantize_kv(k, k_scale, jnp.float32)
-        v = dequantize_kv(v, v_scale, jnp.float32)
+        # a scale a head: dequantize a head a row, hand the rows on packed
+        k, v = (pack_heads(dequantize_kv(unpack_heads(x, D), s, jnp.float32),
+                           x.shape[-1] // D)
+                for x, s in ((k, k_scale), (v, v_scale)))
     return decode_attention(q, k, v, lengths, scale)
 
 
@@ -343,10 +423,19 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     q: [B, S, n_heads, D] — the new tokens, the LAST of which sits at global
     position ``lengths[b] - 1`` (its K/V are already written); k/v:
-    [B, T, n_kv_heads, D] cache blocks; lengths: [B] int32 valid-key counts.
+    [B, T, n_kv_heads / p, p * D] cache blocks, ``p`` heads a row (read off
+    the shapes; ``p == 1`` is [B, T, n_kv_heads, D]); lengths: [B] int32
+    valid-key counts.
     GQA is handled natively by a grouped einsum over the compact kv heads —
     no repeat, no extra cache bytes. fp32 softmax with the same NEG_INF
     masking convention as ops/attention.py, output cast back to q.dtype.
+
+    Packed rows (``p > 1``) are contracted whole, as they lie: the ``p * g``
+    query heads of a row ride the group axis, each in its own head's ``D``
+    lanes and zero in the others (``_own_lanes_only``), and of the
+    ``p * D`` lanes the value contraction returns each keeps its own head's
+    (``_own_lanes``). A lane multiplied by an exact zero adds an exact
+    zero: the same products reach the same fp32 sums.
 
     S == 1 is the autoregressive decode step; S > 1 is chunked continuation
     — prefill chunks (B == 1) or speculative verify batches (B > 1)
@@ -354,9 +443,12 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     masks keys past its own position).
     """
     B, S, nh, D = q.shape
-    T, nkv = k.shape[1], k.shape[2]
-    g = nh // nkv
+    T, nkv = k.shape[1], k.shape[2]  # nkv rows of p heads
+    pack = k.shape[3] // D
+    g = nh // nkv  # query heads a row: p * (heads a kv head)
     qg = q.reshape(B, S, nkv, g, D)
+    if pack > 1:
+        qg = _own_lanes_only(qg, pack)
     scores = jnp.einsum("bskgd,btkd->bkgst", qg, k,
                         preferred_element_type=jnp.float32) * scale
     # query s has global position lengths - S + s; key t visible iff t <= it
@@ -367,7 +459,29 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     p = jnp.exp(scores - m)
     p = p / jnp.sum(p, axis=-1, keepdims=True)
     out = jnp.einsum("bkgst,btkd->bskgd", p, v.astype(jnp.float32))
+    if pack > 1:
+        out = _own_lanes(out, pack)
     return out.reshape(B, S, nh, D).astype(q.dtype)
+
+
+def _own_lanes_only(qg: jnp.ndarray, p: int) -> jnp.ndarray:
+    """[..., p * g, D] query heads of a packed row -> [..., p * g, p * D]:
+    head ``j`` of the row's ``p`` kv heads (its ``g`` query heads) in lanes
+    ``j * D .. (j + 1) * D``, exact zeros in the others."""
+    *lead, pg, D = qg.shape
+    own = jnp.eye(p, dtype=bool)[:, None, :, None]  # [p, 1, p, 1]
+    spread = jnp.where(own, qg.reshape(*lead, p, pg // p, 1, D), 0)
+    return spread.reshape(*lead, pg, p * D)
+
+
+def _own_lanes(out: jnp.ndarray, p: int) -> jnp.ndarray:
+    """The inverse selection on the value contraction's [..., p * g, p * D]:
+    of each query head's ``p`` candidate blocks the one under its own kv
+    head's lanes -> [..., p * g, D]."""
+    *lead, pg, pD = out.shape
+    blocks = out.reshape(*lead, p, pg // p, p, pD // p)
+    return jnp.stack([blocks[..., j, :, j, :] for j in range(p)],
+                     axis=-3).reshape(*lead, pg, pD // p)
 
 
 # --------------------------------------------------------------------------- #

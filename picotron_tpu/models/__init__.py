@@ -14,8 +14,9 @@ whatever the block:
   whole, with the layer's row in them under ``"row"`` (``()``: none);
 - ``serving_rope_tables(m, seq_len, dtype)``: the angle tables of the cache window;
 - ``cache_pspecs(m, quantized, dp=...)`` and ``init_cache(m, slots,
-  max_seq_len, dtype=..., quantized=...)``: the contiguous cache (K/V heads
-  for the Llama block, latent rows for ``deepseek_v32``);
+  max_seq_len, dtype=..., quantized=..., tp=...)``: the contiguous cache
+  (K/V heads for the Llama block, as many to a row as fill its lanes on a
+  'tp' axis that wide; latent rows for ``deepseek_v32``);
 - ``STAT_NAMES``: the counters a layer returns, an int32 vector under
   ``"stats"`` in its dict (``()``: none, and the programs have no such
   output).

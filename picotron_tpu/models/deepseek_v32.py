@@ -243,8 +243,8 @@ def cache_pspecs(m: ModelConfig, quantized: bool = False,
 
 
 def init_cache(m: ModelConfig, slots: int, max_seq_len: int, dtype=None,
-               quantized: bool = False) -> dict:
-    assert not quantized
+               quantized: bool = False, tp: int = 1) -> dict:
+    assert not quantized and tp == 1
     return kv_cache.init_latent_cache(m, slots, max_seq_len, dtype=dtype)
 
 

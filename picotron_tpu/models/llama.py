@@ -718,11 +718,11 @@ def cache_pspecs(m: ModelConfig, quantized: bool = False,
 
 
 def init_cache(m: ModelConfig, slots: int, max_seq_len: int, dtype=None,
-               quantized: bool = False) -> dict:
+               quantized: bool = False, tp: int = 1) -> dict:
     from picotron_tpu.inference import kv_cache
 
     return kv_cache.init_cache(m, slots, max_seq_len, dtype=dtype,
-                               quantized=quantized)
+                               quantized=quantized, tp=tp)
 
 
 def _head_input(params, h, cfg: Config):

@@ -40,6 +40,33 @@ def tiny_model_kwargs():
     )
 
 
+# Head geometries of the tiny model, for the serving cache's rows
+# (inference/kv_cache.py::pack_factor: as many kv heads to a row as fill 128
+# lanes). The tiny model itself has heads of 8 (a head a row: 4 kv heads
+# are not 16); 256 / 4 gives heads of 64, two a row (SmolLM's); "d64gqa"
+# two query heads a kv head, and under tp 2 ONE kv head a shard, which
+# falls back to a head a row; heads of 32 lie four a row (two a shard under
+# tp 2 do not: a head a row); a head of 128 is a row (Mistral's).
+HEADS = {
+    "d8": {},
+    "d64": dict(hidden_size=256, num_attention_heads=4,
+                num_key_value_heads=4),
+    "d64gqa": dict(hidden_size=256, num_attention_heads=4,
+                   num_key_value_heads=2),
+    "d32": dict(hidden_size=128, num_attention_heads=4,
+                num_key_value_heads=4),
+    "d128": dict(hidden_size=256, num_attention_heads=2,
+                 num_key_value_heads=2),
+}
+# heads a row on a 'tp' axis 1 and 2 wide
+KV_PACK = {"d8": (1, 1), "d64": (2, 2), "d64gqa": (2, 1), "d32": (4, 1),
+           "d128": (1, 1)}
+
+
+def with_heads(tiny_model_kwargs, heads: str) -> dict:
+    return {**tiny_model_kwargs, **HEADS[heads]}
+
+
 def make_config(tiny_model_kwargs, dp=1, pp=1, cp=1, tp=1, seq=32, mbs=2, acc=1,
                 engine="1f1b", dtype=None, zigzag=False, sp=False, zero1=False,
                 cp_impl="ring", interleave=1, fsdp=False, stage_gating="auto",

@@ -24,7 +24,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from conftest import make_config
+from conftest import KV_PACK, make_config, with_heads
 from picotron_tpu.inference import (
     ContinuousBatcher,
     InferenceEngine,
@@ -189,15 +189,21 @@ def test_decode_dispatch_count(tiny_model_kwargs, block_len):
 # --------------------------------------------------------------------------- #
 
 
+@pytest.mark.parametrize("heads", ["d8", "d64", "d32"])
 @pytest.mark.parametrize("tp", [1, 2])
-def test_int8_cache_tracks_fp32_oracle(tiny_model_kwargs, tp):
+def test_int8_cache_tracks_fp32_oracle(tiny_model_kwargs, tp, heads):
     """Greedy decode from the int8 cache must track the fp32-cache oracle:
     first-step logits within INT8_LOGITS_ATOL, ≥ INT8_TOKEN_MATCH_RATE of
     24 greedy tokens identical (tp=2 shards the scale tensors' head axis
-    alongside K/V)."""
+    alongside K/V). With two or four heads a row the int8 rows lie packed
+    and the scales stay one a head."""
+    tiny_model_kwargs = with_heads(tiny_model_kwargs, heads)
     cfg, eng_f = _engine(tiny_model_kwargs, tp=tp)
     _, eng_q = _engine(tiny_model_kwargs, tp=tp, cache_dtype="int8")
-    assert eng_q.quantized
+    assert eng_q.quantized and eng_q.kv_pack == KV_PACK[heads][tp - 1]
+    shapes = jax.eval_shape(eng_q.init_cache)
+    assert shapes["k"].dtype == jnp.int8 and shapes["k_scale"].shape \
+        == shapes["k"].shape[:3] + (cfg.model.num_key_value_heads,)
     params = _params(cfg, eng_f)
     prompt = list(range(1, 9))
 
@@ -251,14 +257,18 @@ def test_int8_cache_halves_bytes():
 # --------------------------------------------------------------------------- #
 
 
-@pytest.mark.parametrize("tp", [1, 2])
-@pytest.mark.parametrize("n_tokens", [10, 16, 23, 32, 41])
-def test_chunked_prefill_matches_one_shot(tiny_model_kwargs, tp, n_tokens):
+@pytest.mark.parametrize("tp,n_tokens,heads", [
+    (tp, n, "d8") for n in (10, 16, 23, 32, 41) for tp in (1, 2)] + [
+    (1, 23, "d64"), (2, 41, "d64"), (1, 41, "d64gqa"), (2, 23, "d64gqa"),
+    (1, 32, "d32"), (1, 23, "d128")])
+def test_chunked_prefill_matches_one_shot(tiny_model_kwargs, tp, n_tokens,
+                                          heads):
     """prefill_chunked (chunk width 16; prompts spanning 1–3 chunks, ragged
     finals included) must reproduce the bucketed one-shot prefill: K/V rows
     allclose, lengths equal, last-token logits allclose with identical
-    argmax."""
-    cfg, engine = _engine(tiny_model_kwargs, tp=tp, prefill_chunk=16)
+    argmax — with a head a row of the cache and with two or four."""
+    cfg, engine = _engine(with_heads(tiny_model_kwargs, heads), tp=tp,
+                          prefill_chunk=16)
     params = _params(cfg, engine)
     prompt = [(7 * i + 3) % cfg.model.vocab_size for i in range(n_tokens)]
 
@@ -303,14 +313,17 @@ def test_chunked_prefill_ragged_cache_window(tiny_model_kwargs):
             == np.argmax(np.asarray(lg_chk)[0]))
 
 
+@pytest.mark.parametrize("heads", ["d8", "d64"])
 @pytest.mark.parametrize("case", ["bf16", "int8", "dp2"])
-def test_chunk_of_one_token_lands_in_its_own_slot(tiny_model_kwargs, case):
+def test_chunk_of_one_token_lands_in_its_own_slot(tiny_model_kwargs, case,
+                                                  heads):
     """A prefill chunk ONE token wide (``inference.prefill_chunk: 1`` is a
     legal config) is still a one-slot write: prefilled into slot > 0 beside
     a parked neighbour it leaves the neighbour's rows byte for byte alone
     and parks the same rows and logits as the chunk-4 run — on the dp-
     sharded mesh too, where the non-owner shard's gate must hold at width
     1 (regression: the S == 1 write ignored ``slot`` and ``gate``)."""
+    tiny_model_kwargs = with_heads(tiny_model_kwargs, heads)
     kw = {"cache_dtype": "int8"} if case == "int8" else {}
     n_tokens, slot = 9, 3 if case == "dp2" else 1
 

@@ -23,7 +23,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from conftest import make_config
+from conftest import make_config, with_heads
 from picotron_tpu.inference import InferenceEngine, kv_cache
 from picotron_tpu.inference.kv_cache import (
     decode_attention,
@@ -161,14 +161,19 @@ def test_stale_rows_beyond_mask_invisible():
     np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
 
 
-def test_flash_path_never_materializes_dequantized_cache(monkeypatch):
+@pytest.mark.parametrize("D", [16, 64])
+def test_flash_path_never_materializes_dequantized_cache(monkeypatch, D):
     """The int8 flash attend must read int8 bytes + scales inside the
     kernel — if it ever routed through ``dequantize_kv`` (the dense path's
-    whole-block fp32 materialization) this raises."""
+    whole-block fp32 materialization) this raises. Heads of 64 lie two to
+    a row of the cache: flash takes the head-a-row view of the same bytes."""
     rng = np.random.default_rng(5)
-    q, (k, v, ks, vs), (dk, dv) = _blocks(rng, 2, 32, 8, 4, 16, 1,
+    q, (k, v, ks, vs), (dk, dv) = _blocks(rng, 2, 32, 8, 4, D, 1,
                                           "float32", True)
-    cache = {"k": k[None], "v": v[None], "k_scale": ks[None],
+    pack = kv_cache.pack_factor(D, 4)
+    assert pack == {16: 1, 64: 2}[D]
+    cache = {"k": kv_cache.pack_heads(k, pack)[None],
+             "v": kv_cache.pack_heads(v, pack)[None], "k_scale": ks[None],
              "v_scale": vs[None]}  # stacked leaves of a one-layer cache
     lengths = jnp.asarray([9, 20], jnp.int32)
     want = np.asarray(kv_cache.attend(q, cache, lengths, 0.25, 0,
@@ -346,11 +351,14 @@ def _params(cfg, engine):
     return engine.shard_params(p)
 
 
+@pytest.mark.parametrize("heads", ["d8", "d64"])
 @pytest.mark.parametrize("cache_dtype", [None, "int8"])
 def test_engine_flash_decode_block_matches_dense(tiny_model_kwargs,
-                                                 cache_dtype):
+                                                 cache_dtype, heads):
     """The blocked decode dispatch (S=1 site) generates the same greedy
-    tokens under both impls, fp32 and int8 caches."""
+    tokens under both impls, fp32 and int8 caches; with two heads a row of
+    the cache the kernel is handed the head-a-row view of it."""
+    tiny_model_kwargs = with_heads(tiny_model_kwargs, heads)
     outs = {}
     for impl in ("dense", "flash"):
         cfg, eng = _engine(tiny_model_kwargs, impl, decode_block_len=4,
@@ -398,10 +406,12 @@ def test_engine_flash_verify_matches_dense(tiny_model_kwargs):
         np.testing.assert_array_equal(a, b)
 
 
-def test_engine_flash_chunked_prefill_matches_dense(tiny_model_kwargs):
+@pytest.mark.parametrize("heads", ["d8", "d64"])
+def test_engine_flash_chunked_prefill_matches_dense(tiny_model_kwargs, heads):
     """The chunked-prefill dispatch (B=1, S=chunk site): final-chunk logits
     agree across impls AND with the one-shot prefill oracle (ragged final
     chunk included: 20 tokens over width-8 chunks)."""
+    tiny_model_kwargs = with_heads(tiny_model_kwargs, heads)
     prompt = [(5 * i + 2) % 199 + 1 for i in range(20)]
     logits = {}
     for impl in ("dense", "flash"):

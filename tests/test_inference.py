@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from conftest import make_config
+from conftest import HEADS, KV_PACK, make_config, with_heads
 from picotron_tpu import checkpoint as ckpt
 from picotron_tpu import train_step as ts
 from picotron_tpu.inference import (
@@ -63,12 +63,18 @@ def _oracle_logits(cfg, engine, params, seq):
 # --------------------------------------------------------------------------- #
 
 
+@pytest.mark.parametrize("heads", list(HEADS))
 @pytest.mark.parametrize("tp", [1, 2])
-def test_greedy_decode_matches_full_forward(tiny_model_kwargs, tp):
+def test_greedy_decode_matches_full_forward(tiny_model_kwargs, tp, heads):
     """32 greedy tokens from prefill + decode_step must equal the
     full-sequence argmax chain, exactly, on tp=1 and a tp=2 dryrun mesh
-    (the tiny model is GQA: 8 q-heads over 4 kv-heads)."""
-    cfg, engine = _engine(tiny_model_kwargs, tp=tp)
+    (the tiny model is GQA: 8 q-heads over 4 kv-heads), whatever the heads
+    a row of the cache holds: one, two of 64, four of 32, and the fallback
+    to one where tp leaves a shard fewer kv heads than fill a row."""
+    cfg, engine = _engine(with_heads(tiny_model_kwargs, heads), tp=tp)
+    assert engine.kv_pack == KV_PACK[heads][tp - 1]
+    assert engine.init_cache()["k"].shape[-1] \
+        == engine.kv_pack * cfg.model.head_dim
     params = _params(cfg, engine)
     prompt = list(range(1, 9))
     n_new = 32
@@ -83,17 +89,31 @@ def test_greedy_decode_matches_full_forward(tiny_model_kwargs, tp):
         assert pred[i] == seq[i + 1], (i, pred[i], seq[i + 1])
 
 
-def test_prefill_logits_match_full_forward(tiny_model_kwargs):
+@pytest.mark.parametrize("heads", ["d8", "d64", "d32", "d128"])
+def test_prefill_logits_match_full_forward(tiny_model_kwargs, heads):
     """The prefill's last-token logits are the full forward's, to fp32
-    tolerance, for several prompt lengths (bucket padding must be inert)."""
-    cfg, engine = _engine(tiny_model_kwargs)
+    tolerance, for several prompt lengths (bucket padding must be inert),
+    and so are a decode step's through the cache the prefill's blocks were
+    parked in, packed or a head a row."""
+    cfg, engine = _engine(with_heads(tiny_model_kwargs, heads))
     params = _params(cfg, engine)
     for n in (1, 5, 16):
         prompt = [(7 * i + 3) % cfg.model.vocab_size for i in range(n)]
-        _, last = engine.prefill(params, prompt)
+        kv, last = engine.prefill(params, prompt)
         want = _oracle_logits(cfg, engine, params, prompt)[n - 1]
         np.testing.assert_allclose(np.asarray(last)[0], want,
                                    rtol=1e-5, atol=1e-5)
+    assert kv["k"].shape[3:] == engine.init_cache()["k"].shape[3:]
+    cache = engine.insert(engine.init_cache(), kv, 1, n)
+    feed = np.zeros(engine.slots, np.int32)
+    feed[1] = 11
+    zeros = np.zeros(engine.slots, np.float32)
+    _, _, logits = engine.decode_step(
+        params, cache, feed, jax.random.PRNGKey(0), zeros,
+        np.zeros(engine.slots, np.int32), zeros + 1)
+    want = _oracle_logits(cfg, engine, params, prompt + [11])[n]
+    np.testing.assert_allclose(np.asarray(logits)[1], want,
+                               rtol=1e-4, atol=1e-4)
 
 
 # --------------------------------------------------------------------------- #
@@ -579,20 +599,47 @@ def test_generate_cli_end_to_end_from_checkpoint(tiny_model_kwargs, tmp_path,
 
 
 def _stacked_cache(rng, L, B, T, H, D, quantized):
+    """Random stacked leaves as ``init_cache`` lays them out: as many heads
+    to a row as fill its lanes, a scale a head."""
+    pack = kv_cache.pack_factor(D, H)
+
     def leaf():
         return jnp.asarray(rng.normal(size=(L, B, T, H, D)), jnp.bfloat16)
 
     if not quantized:
-        return {"k": leaf(), "v": leaf()}
+        return {n: kv_cache.pack_heads(leaf(), pack) for n in "kv"}
     (qk, ks), (qv, vs) = (kv_cache.quantize_kv(leaf()) for _ in "kv")
-    return {"k": qk, "v": qv, "k_scale": ks, "v_scale": vs}
+    return {"k": kv_cache.pack_heads(qk, pack),
+            "v": kv_cache.pack_heads(qv, pack), "k_scale": ks, "v_scale": vs}
+
+
+# kv heads x head size: (2, 8) keeps a head a row (128 lanes are not 16
+# heads of 8 when there are 2); heads of 64 lie two to a row, heads of 32
+# four; a head of 128 is a row
+SEAM_HEADS = {"d8": (2, 8), "d64": (4, 64), "d32": (4, 32), "d128": (2, 128)}
+
+
+def test_pack_factor_reads_head_size_and_local_heads():
+    assert [kv_cache.pack_factor(d, h) for h, d in SEAM_HEADS.values()] \
+        == [1, 2, 4, 1]
+    # SmolLM, Mistral; tp leaving an odd head count falls back to a row a head
+    assert kv_cache.pack_factor(64, 32) == 2
+    assert kv_cache.pack_factor(128, 8) == 1
+    assert kv_cache.pack_factor(64, 1) == kv_cache.pack_factor(64, 3) == 1
+    assert kv_cache.pack_factor(96, 4) == kv_cache.pack_factor(256, 4) == 1
+
+
+SEAM_SHAPES = ["decode", "block", "block_of_one", "open_block",
+               "open_block_of_one", "shut_block", "shut_block_of_one",
+               "verify", "ragged_verify"]
 
 
 @pytest.mark.parametrize("quantized", [False, True])
-@pytest.mark.parametrize("shape", [
-    "decode", "block", "block_of_one", "open_block", "open_block_of_one",
-    "shut_block", "shut_block_of_one", "verify", "ragged_verify"])
-def test_layer_indexed_seam_matches_per_layer(shape, quantized):
+@pytest.mark.parametrize("shape,heads", [
+    (s, h) for h in ("d8", "d64") for s in SEAM_SHAPES] + [
+    (s, "d32") for s in ("decode", "open_block", "ragged_verify")] + [
+    ("decode", "d128")])
+def test_layer_indexed_seam_matches_per_layer(shape, quantized, heads):
     """``cache_write`` / ``attend`` addressed ``[layer, ...]`` into the
     STACKED leaves (what the engine's layer scan carries) hold bit for bit
     the bytes a row-by-row placement puts there, and attend like the
@@ -600,10 +647,15 @@ def test_layer_indexed_seam_matches_per_layer(shape, quantized):
     write shapes, the ragged ``draft_valid`` mask, the one-slot ``slot`` /
     ``gate`` addressing of a prefill chunk AT ANY WIDTH (a chunk of one
     token is still its slot's, and still gated), and int8 storage with its
-    scales — and touch no other layer and no other slot."""
+    scales — and touch no other layer and no other slot. With heads
+    narrower than a lane row the leaves hold ``pack_factor`` heads a row:
+    the same bytes land, and the attention over whole rows is the attention
+    over a head a row."""
     rng = np.random.default_rng(7)
-    L, B, T, H, D, layer = 3, 3, 16, 2, 8, 1
+    L, B, T, layer = 3, 3, 16, 1
+    H, D = SEAM_HEADS[heads]
     cache = _stacked_cache(rng, L, B, T, H, D, quantized)
+    assert cache["k"].shape[-1] == kv_cache.pack_factor(D, H) * D
     addr, valid, gate = {}, None, True
     if shape == "decode":
         slots, s, pos = range(B), 1, [6, 3, 0]
@@ -631,7 +683,9 @@ def test_layer_indexed_seam_matches_per_layer(shape, quantized):
     if quantized:
         (new["k"], new["k_scale"]), (new["v"], new["v_scale"]) = (
             kv_cache.quantize_kv(x) for x in (k_new, v_new))
-    want = {n: f32(a) for n, a in cache.items()}
+    heads_of = lambda n, a: (kv_cache.unpack_heads(a, D) if n in "kv"
+                             else a)
+    want = {n: f32(heads_of(n, a)) for n, a in cache.items()}
     for n, rows in new.items():
         for i, slot in enumerate(slots):
             live = s if valid is None else valid[i]
@@ -639,13 +693,21 @@ def test_layer_indexed_seam_matches_per_layer(shape, quantized):
                 if pos[i] + j < T:
                     want[n][layer, slot, pos[i] + j] = f32(rows)[i, j]
     for n in cache:
-        np.testing.assert_array_equal(f32(got[n]), want[n])
+        assert got[n].shape == cache[n].shape
+        np.testing.assert_array_equal(f32(heads_of(n, got[n])), want[n])
     lengths = jnp.asarray(pos, jnp.int32) + s
-    k, v = (got[n][layer, jnp.asarray(list(slots))] for n in "kv")
+    k, v = (heads_of(n, got[n])[layer, jnp.asarray(list(slots))]
+            for n in "kv")
     if quantized:
         k, v = (kv_cache.dequantize_kv(
             x, got[n][layer, jnp.asarray(list(slots))], jnp.float32)
             for x, n in ((k, "k_scale"), (v, "v_scale")))
-    np.testing.assert_array_equal(
-        f32(kv_cache.attend(q, {**got, **addr}, lengths, 0.3, layer)),
-        f32(kv_cache.decode_attention(q, k, v, lengths, 0.3)))
+    out = f32(kv_cache.attend(q, {**got, **addr}, lengths, 0.3, layer))
+    pack = cache["k"].shape[-1] // D
+    np.testing.assert_array_equal(out, f32(kv_cache.decode_attention(
+        q, kv_cache.pack_heads(k, pack), kv_cache.pack_heads(v, pack),
+        lengths, 0.3)))
+    # a head a row: the same products, summed with zeros among them
+    np.testing.assert_allclose(
+        out, f32(kv_cache.decode_attention(q, k, v, lengths, 0.3)),
+        rtol=0, atol=2 ** -6)

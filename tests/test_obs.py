@@ -477,6 +477,14 @@ def test_serve_metrics_tracez_profilez(tmp_path):
         assert stats["weight_bytes"] == srv.front.weight_bytes > 0
         assert stats["weight_dtype"] == "bf16"
         assert prom["picotron_weight_bytes"] == stats["weight_bytes"]
+        # what the cache is (ISSUE 31): the heads a 128-lane row of K and
+        # V holds (the tiny model's 4 heads of 8 are not 16: a head a row)
+        # and the resident leaves' bytes, on both pages
+        cache = srv.front._batcher.engine.init_cache()
+        assert prom["picotron_kv_pack_factor"] == stats["kv_pack_factor"] \
+            == 1
+        assert prom["picotron_kv_cache_bytes"] == stats["kv_cache_bytes"] \
+            == sum(a.nbytes for a in jax.tree.leaves(cache))
         # /tracez: the request's chain is COMPLETE (queue wait ->
         # prefill -> >= 1 dispatch -> delivery), all parented
         tst, trace = serve._get(port, "/tracez")

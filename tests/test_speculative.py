@@ -29,7 +29,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from conftest import make_config
+from conftest import make_config, with_heads
 from picotron_tpu.config import Config, SpecControllerConfig
 from picotron_tpu.inference import (
     ContinuousBatcher,
@@ -80,11 +80,14 @@ class ScriptedDrafter(Drafter):
 # --------------------------------------------------------------------------- #
 
 
-@pytest.mark.parametrize("tp,spec_len", [(1, 2), (1, 4), (2, 3)])
-def test_greedy_spec_matches_spec_off(tiny_model_kwargs, tp, spec_len):
+@pytest.mark.parametrize("tp,spec_len,heads", [
+    (1, 2, "d8"), (1, 4, "d8"), (2, 3, "d8"), (1, 4, "d64"), (2, 3, "d64"),
+    (1, 3, "d32")])
+def test_greedy_spec_matches_spec_off(tiny_model_kwargs, tp, spec_len, heads):
     """Mixed-length greedy requests through the speculative batcher (the
     real NgramDrafter — accepts and rejections both occur) must produce
     the spec-off engine's streams token for token."""
+    tiny_model_kwargs = with_heads(tiny_model_kwargs, heads)
     cfg, eng_off = _engine(tiny_model_kwargs, tp=tp)
     _, eng_on = _engine(tiny_model_kwargs, tp=tp, spec_len=spec_len)
     params = _params(cfg, eng_off)
@@ -311,15 +314,18 @@ def test_spec_sampled_e2e_distribution(tiny_model_kwargs):
 # --------------------------------------------------------------------------- #
 
 
+@pytest.mark.parametrize("D", [8, 64])
 @pytest.mark.parametrize("quantized", [False, True])
-def test_rejected_draft_rows_invisible_to_attend(quantized):
+def test_rejected_draft_rows_invisible_to_attend(quantized, D):
     """Optimistically written draft rows beyond the post-acceptance length
     must leave ``attend`` output BIT-IDENTICAL to never having written
-    them — for bf16 and int8 (scales included) caches. This is the whole
-    rollback mechanism: rewinding is one length-pointer write."""
+    them — for bf16 and int8 (scales included) caches, a head a row
+    (heads of 8) and two a row (heads of 64). This is the whole rollback
+    mechanism: rewinding is one length-pointer write."""
     rng = np.random.default_rng(0)
-    B, T, H, D = 2, 16, 4, 8
+    B, T, H = 2, 16, 4
     dt = jnp.bfloat16
+    pack = kv_cache.pack_factor(D, H)
 
     def block():
         base = {  # stacked leaves of a one-layer cache
@@ -330,7 +336,8 @@ def test_rejected_draft_rows_invisible_to_attend(quantized):
             qk, ks = kv_cache.quantize_kv(base["k"])
             qv, vs = kv_cache.quantize_kv(base["v"])
             base = {"k": qk, "v": qv, "k_scale": ks, "v_scale": vs}
-        return base
+        return {n: kv_cache.pack_heads(a, pack) if n in "kv" else a
+                for n, a in base.items()}
 
     base = block()
     pos = jnp.asarray([6, 3], jnp.int32)  # per-slot write offsets
@@ -516,19 +523,25 @@ def test_ngram_stale_ctx_rebuilds_on_shrunk_history():
 # --------------------------------------------------------------------------- #
 
 
-@pytest.mark.parametrize("tp,impl,layout,quant,temp", [
-    (1, "dense", "contiguous", False, 0.0),
-    (1, "dense", "contiguous", True, 0.0),
-    (1, "dense", "contiguous", False, 1.0),
-    (1, "flash", "contiguous", False, 0.0),
-    (1, "dense", "paged", False, 0.0),
-    (1, "flash", "paged", True, 0.0),
-    (2, "dense", "contiguous", False, 0.0),
-    (2, "dense", "paged", True, 0.0),
+@pytest.mark.parametrize("tp,impl,layout,quant,temp,heads", [
+    (1, "dense", "contiguous", False, 0.0, "d8"),
+    (1, "dense", "contiguous", True, 0.0, "d8"),
+    (1, "dense", "contiguous", False, 1.0, "d8"),
+    (1, "flash", "contiguous", False, 0.0, "d8"),
+    (1, "dense", "paged", False, 0.0, "d8"),
+    (1, "flash", "paged", True, 0.0, "d8"),
+    (2, "dense", "contiguous", False, 0.0, "d8"),
+    (2, "dense", "paged", True, 0.0, "d8"),
+    # two heads of 64 a row of the contiguous cache (the pool is not packed)
+    (1, "dense", "contiguous", False, 0.0, "d64"),
+    (1, "dense", "contiguous", True, 0.0, "d64"),
+    (2, "dense", "contiguous", False, 1.0, "d64"),
+    (1, "flash", "contiguous", False, 0.0, "d64"),
+    (1, "dense", "paged", False, 0.0, "d64"),
 ])
 def test_ragged_verify_matches_per_slot_sequential(tiny_model_kwargs, tp,
                                                    impl, layout, quant,
-                                                   temp):
+                                                   temp, heads):
     """One RAGGED verify dispatch (per-slot draft_len) must emit, count,
     accept, and advance lengths exactly as per-slot SEQUENTIAL solo
     verifies (each slot alone with its own draft length) — across tp,
@@ -537,7 +550,7 @@ def test_ragged_verify_matches_per_slot_sequential(tiny_model_kwargs, tp,
     dispatch is the sum of its solo parts."""
     slots = 3
     cfg, engine = _engine(
-        tiny_model_kwargs, tp=tp, slots=slots, spec_len=4,
+        with_heads(tiny_model_kwargs, heads), tp=tp, slots=slots, spec_len=4,
         attend_impl=impl, kv_layout=layout,
         cache_dtype="int8" if quant else None)
     params = _params(cfg, engine)
