@@ -155,11 +155,15 @@ class ModelConfig:
     # all-gather + plain CE), "vocab_parallel" (local logits, psum'd stats).
     loss_impl: str = "auto"
     # Which block ``models/`` builds (models.model_module): "llama" (every
-    # dense MHA/GQA + SwiGLU model) or "deepseek_v32" (latent attention
+    # dense MHA/GQA + SwiGLU model), "deepseek_v32" (latent attention
     # with a learned sparse selection, routed and shared experts —
-    # models/deepseek_v32.py, serving path only). The fields below are the
-    # published ``config.json`` keys of that block, by their own names, and
-    # are read by no other block.
+    # models/deepseek_v32.py, serving path only) or "granitemoehybrid"
+    # (Mamba-2 and NoPE attention layers by ``layer_types``, routed and
+    # shared experts behind each — models/granite_hybrid.py, serving path
+    # only; its fields are at the end). The fields below are the
+    # published ``config.json`` keys of the DeepSeek block, by their own
+    # names, and are read by no other block (but ``num_experts_per_tok``,
+    # ``ep_size`` and ``ep_rank``, which both expert blocks read).
     model_type: str = "llama"
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -189,6 +193,29 @@ class ModelConfig:
     rope_scaling: Optional[dict] = None  # the published YaRN group, whole
     ep_size: int = 1
     ep_rank: int = 0
+    # "granitemoehybrid": the published keys of that block. ``layer_types``
+    # names each layer's mixer ("mamba" | "attention"), one entry a layer.
+    # ``num_local_experts`` counts the experts HELD here, those from
+    # ``ep_rank * num_local_experts`` on of a router ``num_local_experts *
+    # ep_size`` wide; ``intermediate_size`` is one expert's width.
+    layer_types: Optional[list] = None
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_n_groups: int = 1
+    mamba_expand: int = 2
+    mamba_chunk_size: int = 256
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    num_local_experts: int = 0
+    shared_intermediate_size: int = 0
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    position_embedding_type: str = "rope"
+    tie_word_embeddings: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -1030,11 +1057,14 @@ class Config:
                     f"fsdp needs hidden_size ({m.hidden_size}) divisible by "
                     f"dp_size ({d.dp_size}) — every layer param shards on an "
                     f"H-sized axis")
-        if m.model_type not in ("llama", "deepseek_v32"):
+        if m.model_type not in ("llama", "deepseek_v32", "granitemoehybrid"):
             raise ValueError(
-                f"unknown model_type {m.model_type!r} (llama|deepseek_v32)")
+                f"unknown model_type {m.model_type!r} "
+                "(llama|deepseek_v32|granitemoehybrid)")
         if m.model_type == "deepseek_v32":
             self._validate_deepseek_v32(for_training)
+        if m.model_type == "granitemoehybrid":
+            self._validate_granite_hybrid(for_training)
         if m.attention_impl not in ("auto", "sdpa", "flash"):
             raise ValueError(
                 f"unknown attention_impl {m.attention_impl!r} (auto|sdpa|flash)")
@@ -1429,6 +1459,98 @@ class Config:
             raise ValueError(
                 f"{who} implements rope_scaling type 'yarn' only (got "
                 f"{rs!r})")
+
+    def _validate_granite_hybrid(self, for_training: bool) -> None:
+        """What ``models/granite_hybrid.py`` needs of its keys, and what it
+        cannot do yet, each refused by name."""
+        d, m, inf = self.distributed, self.model, self.inference
+        who = "model_type 'granitemoehybrid'"
+        if for_training:
+            raise ValueError(
+                f"{who} is served, not trained: training is not implemented "
+                "for this block (no backward through the chunked scan and "
+                "the expert share; train_step builds the Llama block only)")
+        if d.tp_size > 1:
+            raise ValueError(
+                f"{who} does not support tp_size > 1 (got {d.tp_size}): the "
+                "recurrent state has no tp sharding and the block holds no "
+                "tp collectives; its share of a layer is ep_size/ep_rank")
+        if inf.kv_layout == "paged":
+            raise ValueError(
+                f"{who} does not support inference.kv_layout 'paged' (nor "
+                "the prefix reuse that rests on it): a recurrent state has "
+                "no token axis to page and no snapshot to resume a shared "
+                "prefix from; set kv_layout: 'contiguous'")
+        if inf.kv_cache_dtype == "int8":
+            raise ValueError(
+                f"{who} does not support inference.kv_cache_dtype 'int8': "
+                "the state is float32 and K/V are stored in the model's "
+                "dtype")
+        if inf.weight_dtype == "int8":
+            raise ValueError(
+                f"{who} does not support inference.weight_dtype 'int8': its "
+                "matmuls take dense weights only")
+        if inf.tenancy.tenants or inf.tenancy.manifest:
+            raise ValueError(
+                f"{who} does not support LoRA adapters (inference.tenancy): "
+                "the adapter pack is shaped for the Llama block's seven "
+                "projections")
+        if inf.spec_len > 0:
+            raise ValueError(
+                f"{who} does not support speculation (inference.spec_len "
+                f"{inf.spec_len}): a rejected draft cannot be rolled back "
+                "out of a recurrent state by rewinding a length")
+        if inf.attend_impl != "dense":
+            raise ValueError(
+                f"{who} does not support inference.attend_impl "
+                f"{inf.attend_impl!r}: the flash-decode kernel scales by "
+                "head_dim^-0.5, not by attention_multiplier")
+        if inf.overlap or inf.mixed_dispatch or inf.key_schedule == "slot" \
+                or inf.dp_size > 1:
+            raise ValueError(
+                f"{who} serves through the serial round-keyed programs "
+                "only: inference.overlap, mixed_dispatch, key_schedule "
+                "'slot' and dp_size > 1 are not implemented for it")
+        for name in ("mamba_n_heads", "mamba_d_head", "mamba_d_state",
+                     "mamba_d_conv", "mamba_chunk_size", "num_local_experts",
+                     "num_experts_per_tok", "shared_intermediate_size",
+                     "ep_size"):
+            if getattr(m, name) < 1:
+                raise ValueError(f"{who} needs model.{name} >= 1")
+        lt = m.layer_types
+        if not lt or len(lt) != m.num_hidden_layers \
+                or any(t not in ("mamba", "attention") for t in lt):
+            raise ValueError(
+                f"{who} needs model.layer_types: one of 'mamba' | "
+                f"'attention' for each of the {m.num_hidden_layers} layers "
+                f"(got {lt!r})")
+        if len(set(lt)) < 2:
+            raise ValueError(
+                f"{who} needs at least one 'mamba' and one 'attention' layer "
+                "in model.layer_types: the cache holds a leaf of each kind")
+        if m.mamba_n_heads * m.mamba_d_head != m.mamba_expand * m.hidden_size:
+            raise ValueError(
+                f"{who}: mamba_n_heads {m.mamba_n_heads} x mamba_d_head "
+                f"{m.mamba_d_head} must be mamba_expand {m.mamba_expand} x "
+                f"hidden_size {m.hidden_size}")
+        if not 0 <= m.ep_rank < m.ep_size:
+            raise ValueError(
+                f"{who}: ep_rank {m.ep_rank} outside [0, ep_size "
+                f"{m.ep_size})")
+        if m.num_experts_per_tok > m.num_local_experts * m.ep_size:
+            raise ValueError(
+                f"{who}: num_experts_per_tok {m.num_experts_per_tok} passes "
+                f"the router's width {m.num_local_experts * m.ep_size} "
+                "(num_local_experts x ep_size)")
+        for name, want in (("mamba_n_groups", 1), ("mamba_conv_bias", True),
+                           ("mamba_proj_bias", False),
+                           ("position_embedding_type", "nope"),
+                           ("tie_word_embeddings", True),
+                           ("rope_scaling", None)):
+            if getattr(m, name) != want:
+                raise ValueError(
+                    f"{who} implements model.{name} = {want!r} only (got "
+                    f"{getattr(m, name)!r})")
 
     # ---- JSON round-trip (reference: train.py:62-63 consumes one JSON file) ----
 

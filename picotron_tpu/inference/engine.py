@@ -423,6 +423,15 @@ class InferenceEngine:
                 raise ValueError(
                     f"model_type {m.model_type!r} serves without adapters "
                     "and with its cache in the model's dtype")
+            if getattr(self.model, "CARRIES_STATE", False) \
+                    and self.max_seq_len % self.prefill_chunk:
+                raise ValueError(
+                    f"model_type {m.model_type!r} carries a recurrent state "
+                    f"from chunk to chunk: max_seq_len ({self.max_seq_len}) "
+                    f"must be a multiple of prefill_chunk "
+                    f"({self.prefill_chunk}), or a prompt's last chunk "
+                    "slides back inside the window and feeds the state its "
+                    "overlap twice (prefill_chunked)")
         # angle tables cover the whole cache window; decode gathers rows at
         # each slot's own offset
         self._cos, self._sin = self.model.serving_rope_tables(
@@ -532,6 +541,8 @@ class InferenceEngine:
         # K and V holds (kv_cache.pack_factor; None without such leaves)
         # and the bytes resident for the life of the server
         shapes = jax.eval_shape(self._init_cache_jit)
+        self._leaf_dtypes = {n: a.dtype for n, a in shapes.items()
+                             if n not in paged_kv.META_LEAVES}
         self.kv_pack = kv_cache.kv_pack(shapes, m.head_dim)
         self.kv_cache_bytes = kv_cache.cache_bytes(shapes)
         if self.kv_pack is not None:
@@ -770,6 +781,13 @@ class InferenceEngine:
         int32 token ids."""
         return sampling.sample(logits, key, temperature, top_k, top_p)
 
+    def _embed(self, params, tokens):
+        """``tokens`` into the residual stream as the block does it (the
+        seam's ``embed_lookup``, handed the config as ``head_logits`` is),
+        in the model's dtype."""
+        return self.model.embed_lookup(params["embed"], tokens,
+                                       cfg=self.cfg).astype(self._dt)
+
     def _prefill_impl(self, params, tokens, length, *sample):
         """tokens [1, S_bucket] int32, length [1] -> (kv blocks, last-token
         logits [1, V]). Pad tokens beyond ``length`` produce K/V rows the
@@ -781,7 +799,7 @@ class InferenceEngine:
         S = tokens.shape[1]
         cos_l = lax.dynamic_slice_in_dim(self._cos, 0, S, 0)
         sin_l = lax.dynamic_slice_in_dim(self._sin, 0, S, 0)
-        h = self.model.embed_lookup(params["embed"], tokens).astype(self._dt)
+        h = self._embed(params, tokens)
         if self._n_stats:
             live = jnp.arange(S, dtype=jnp.int32)[None, :] < length[:, None]
             h, kv, stats = self._prefill_groups(params, h, cos_l, sin_l,
@@ -809,9 +827,10 @@ class InferenceEngine:
     def _prefill_groups(self, params, h, cos_l, sin_l, live):
         """The one-shot prefill of a block that counts: its groups of
         layers scanned one after the other over the whole sequence, each
-        layer returning the rows it would write to a cache and its stats.
-        (h, the rows stacked over all layers in the cache's dtype, the
-        stats [layers, counters])."""
+        layer returning the rows it would write to a cache (of a recurrent
+        layer: the state it would leave) and its stats. (h, each cache
+        leaf's block stacked over the layers that have it, in the leaf's
+        dtype, the stats [layers, counters])."""
         rows, stats = [], []
         for name, layer_fn, count in self.model.layer_groups(self.cfg.model):
             xs, whole = self._group_xs(params[name], count)
@@ -823,8 +842,9 @@ class InferenceEngine:
             h, out = lax.scan(body, h, xs)
             stats.append(out.pop(models.STATS))
             rows.append(out)
-        kv = {n: jnp.concatenate([r[n] for r in rows]).astype(
-            self.cache_dtype) for n in rows[0]}
+        # a leaf runs over the layers of the groups that have it, in order
+        kv = {n: jnp.concatenate([r[n] for r in rows if n in r]).astype(dt)
+              for n, dt in self._leaf_dtypes.items()}
         return h, kv, jnp.concatenate(stats)
 
     def _meta(self, cache) -> dict:
@@ -896,8 +916,9 @@ class InferenceEngine:
     def _scan_layers(self, params, cache, h, cos_b, sin_b, pos, meta):
         """THE layer scan of every serving program: run ``h`` through the
         model's groups of stacked layers (``layer_groups``: one for the
-        Llama block; dense layers, then expert layers), one scan after the
-        other against the one cache, return (h, updated stacked leaves).
+        Llama block; dense layers, then expert layers; the runs of a
+        per-layer pattern), one scan after the other against the one
+        cache, return (h, updated stacked leaves).
         Of a block that counts, the returned dict also holds the stats
         [layers, counters] (``models.STATS``: not a leaf; the programs
         take it out before they rebuild the cache; a layer's own row, so
@@ -970,7 +991,7 @@ class InferenceEngine:
         ragged verify's ``draft_valid`` write mask). Lengths are NOT
         advanced here — callers apply their own activity rule."""
         cos_b, sin_b = rope_at_positions(self._cos, self._sin, rows)
-        h = self.model.embed_lookup(params["embed"], tokens).astype(self._dt)
+        h = self._embed(params, tokens)
         meta = {**self._local_meta(cache), **(extra_meta or {})}
         if self._n_stats:
             # free slots (length 0) ride along uncounted and unrouted
@@ -980,13 +1001,18 @@ class InferenceEngine:
         logits = tp_gather(self.model.head_logits(params, h, self.cfg))
         return new_leaves, logits.astype(jnp.float32), h
 
-    def _decode_core(self, params, cache, tokens):
+    def _decode_core(self, params, cache, tokens, active=None):
         """One model step for all slots: ``tokens`` [B] at each slot's own
         ``cache['lengths']`` position -> (updated stacked leaves,
-        logits [B, V] fp32, hidden [B, H])."""
+        logits [B, V] fp32, hidden [B, H]). ``active`` [B] (a decode
+        block's: parked and in budget) reaches every layer as an
+        ``"active"`` entry: a slot's ghost K/V row hides behind its
+        length, so the blocks that keep K/V alone do not read it; a
+        recurrent state must not advance."""
         pos = cache["lengths"]  # [B] write index of the incoming token
         new_leaves, logits, h = self._model_block(
-            params, cache, tokens[:, None], pos[:, None], pos)
+            params, cache, tokens[:, None], pos[:, None], pos,
+            extra_meta=None if active is None else {"active": active})
         return new_leaves, logits[:, 0], h[:, 0]
 
     def _decode_impl(self, params, cache, tokens, key, temperature,
@@ -1064,7 +1090,8 @@ class InferenceEngine:
             active = (pos > 0) & (budget > 0)
             if by_slot:
                 key_t = jax.vmap(jax.random.fold_in)(keys, pos)
-            new_leaves, logits, h = self._decode_core(params, cache, tok)
+            new_leaves, logits, h = self._decode_core(params, cache, tok,
+                                                      active=active)
             # a block that counts: its step's stats leave with the tokens
             counted = ((new_leaves.pop(models.STATS),) if self._n_stats
                        else ())
@@ -1232,7 +1259,7 @@ class InferenceEngine:
         start = jnp.asarray(start, jnp.int32)
         pos_rows = (start + jnp.arange(C, dtype=jnp.int32))[None, :]  # [1,C]
         cos_b, sin_b = rope_at_positions(self._cos, self._sin, pos_rows)
-        h = self.model.embed_lookup(params["embed"], tokens).astype(self._dt)
+        h = self._embed(params, tokens)
         lengths = cache["lengths"]
         pos = jnp.full((1,), start, jnp.int32)
         # dp > 1: every shard traces the same chunk, but only the slot's

@@ -240,6 +240,13 @@ def quantized(cache: dict) -> bool:
 # leave the bytes as they were).
 
 
+def row_major(x: jnp.ndarray) -> jnp.ndarray:
+    """Hold a carried cache leaf (or what is written into it) to the
+    row-major layout it is resident in."""
+    return with_layout_constraint(
+        x, Layout(major_to_minor=tuple(range(x.ndim))))
+
+
 def cache_write(cache: dict, k_new: jnp.ndarray, v_new: jnp.ndarray,
                 pos: jnp.ndarray, layer) -> dict:
     """Write fresh K/V rows into ``layer`` of the stacked cache leaves, in
@@ -307,8 +314,7 @@ def cache_write(cache: dict, k_new: jnp.ndarray, v_new: jnp.ndarray,
             # layout it is resident in. Left free, a prefill chunk's
             # contractions pull the whole leaf head-major on entry and
             # push it back on exit (two copies of the cache a chunk)
-            out[name] = with_layout_constraint(
-                out[name], Layout(major_to_minor=tuple(range(out[name].ndim))))
+            out[name] = row_major(out[name])
     return out
 
 

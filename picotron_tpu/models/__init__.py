@@ -6,10 +6,17 @@ whatever the block:
 
 - ``init_params(key, m)``, ``param_pspecs(m, weight_dtype=...)``,
   ``param_bytes(params)``: the tree, its shardings, its size;
-- ``embed_lookup``, ``head_logits``: into and out of the residual stream;
+- ``embed_lookup(embed, tokens, cfg=...)``, ``head_logits(params, h,
+  cfg)``: into and out of the residual stream, both ends the block's own
+  (its multipliers; a block whose head is its embedding reads
+  ``params["embed"]`` in both);
 - ``layer_groups(m)``: ``[(name of a stacked group in the tree, its layer
   function, how many layers)]``, scanned one after the other over one cache;
-  every layer function keeps ``llama.decoder_layer``'s contract;
+  every layer function keeps ``llama.decoder_layer``'s contract and is
+  handed its global index (a block whose cache leaves run over different
+  layers, ``granite_hybrid``, finds its own row from it); in a decode
+  block its cache dict also holds ``"active"`` [B] (parked and in budget),
+  which a block that keeps K/V alone need not read;
 - ``UNSLICED``: names of a group's leaves the scan hands its layers
   whole, with the layer's row in them under ``"row"`` (``()``: none);
 - ``serving_rope_tables(m, seq_len, dtype)``: the angle tables of the cache window;
@@ -17,6 +24,9 @@ whatever the block:
   max_seq_len, dtype=..., quantized=..., tp=...)``: the contiguous cache
   (K/V heads for the Llama block, as many to a row as fill its lanes on a
   'tp' axis that wide; latent rows for ``deepseek_v32``);
+- ``CARRIES_STATE`` (absent: false): the cache holds a state with no token
+  axis, which cannot be fed a token twice; the engine then holds the
+  window to whole prefill chunks;
 - ``STAT_NAMES``: the counters a layer returns, an int32 vector under
   ``"stats"`` in its dict (``()``: none, and the programs have no such
   output).
@@ -36,6 +46,10 @@ def model_module(m):
         from picotron_tpu.models import deepseek_v32
 
         return deepseek_v32
+    if m.model_type == "granitemoehybrid":
+        from picotron_tpu.models import granite_hybrid
+
+        return granite_hybrid
     if m.model_type == "llama":
         return llama
     raise ValueError(f"unknown model_type {m.model_type!r}")

@@ -57,6 +57,8 @@ from jax.sharding import PartitionSpec as P
 from picotron_tpu.config import Config, ModelConfig
 from picotron_tpu.inference import kv_cache
 from picotron_tpu.models import STATS
+from picotron_tpu.models import experts as expert_share
+from picotron_tpu.models.experts import swiglu as _swiglu
 from picotron_tpu.models.llama import (  # noqa: F401 - the seam's shared parts
     embed_lookup,
     head_logits,
@@ -124,9 +126,7 @@ def _group_shapes(m: ModelConfig, dense: bool) -> dict:
     return shapes
 
 
-# leaves of a group the layer scan does not slice a layer at a time: the
-# layer function is handed the whole stack and its row in it (``lp["row"]``)
-UNSLICED = ("w1", "w3", "w2")
+UNSLICED = expert_share.UNSLICED
 
 
 def layer_groups(m: ModelConfig) -> list:
@@ -501,49 +501,16 @@ def held_weights(experts, weights, m: ModelConfig):
     """[N, n_routed_experts] float32: each token's weight on each expert
     held here (``ep_rank * n_routed_experts`` onward), 0 where the token
     did not choose it."""
-    held = m.ep_rank * m.n_routed_experts + jnp.arange(m.n_routed_experts)
-    return jnp.sum(jnp.where(experts[:, :, None] == held[None, None, :],
-                             weights[:, :, None], 0.0), axis=1)
-
-
-def _swiglu(x, w_gate, w_up, w_down):
-    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
-
-
-def routed_experts(x, w_held, lp):
-    """``sum_e w_held[:, e] * E_e(x)`` over the experts held here, float32
-    [N, H], one expert after the other. Every held expert runs at every
-    step, chosen or not, as in the deployment this share is cut from (there
-    each has tokens at every step): skipping the ones no token chose
-    (``lax.cond``) made a decode step's time follow the seed's routing (8
-    tokens reach 1.5-2.2 of the 8; measured 450-467 tokens/s over seeds),
-    which is a property of the cut, not of the model. With a ``row``
-    entry, ``lp``'s expert leaves are the group's whole stacks
-    (``UNSLICED``) and this layer is that row of them: each expert's
-    matrices are then read in place (a layer's slice of the stack, handed
-    to the loop over experts, is a copy of all of them)."""
-    row = lp.get("row")
-
-    def weights(name, e):
-        w = lp[name]
-        return w[e] if row is None else w[row, e]
-
-    def one(acc, xs):
-        w, e = xs
-        y = _swiglu(x, weights("w1", e), weights("w3", e), weights("w2", e))
-        return acc + y.astype(jnp.float32) * w[:, None], None
-
-    n = w_held.shape[1]
-    acc, _ = lax.scan(one, jnp.zeros(x.shape, jnp.float32),
-                      (w_held.T, jnp.arange(n, dtype=jnp.int32)))
-    return acc
+    return expert_share.held_weights(
+        experts, weights, m.ep_rank * m.n_routed_experts,
+        m.n_routed_experts)
 
 
 def expert_mlp(lp, x, m: ModelConfig, live) -> tuple:
     """The expert half of a layer on the normed stream ``x`` [B, S, H]:
     (this chip's part of the routed sum + the shared expert, held
-    assignments, held experts hit). Rows that are not ``live`` are routed
-    nowhere."""
+    assignments, held experts hit; ``models/experts.py``). Rows that are
+    not ``live`` are routed nowhere."""
     B, S, H = x.shape
     x2 = x.reshape(B * S, H)
     with jax.named_scope("moe_route"):
@@ -554,13 +521,7 @@ def expert_mlp(lp, x, m: ModelConfig, live) -> tuple:
                                  m)
         w_held = held_weights(experts, weights, m) \
             * live.reshape(B * S, 1).astype(jnp.float32)
-    with jax.named_scope("moe_experts"):
-        y = routed_experts(x2, w_held, lp)
-    with jax.named_scope("shared_expert"):
-        y = y.astype(x.dtype) + _swiglu(x2, lp["ws_gate"], lp["ws_up"],
-                                        lp["ws_down"])
-    assigned = jnp.sum(w_held > 0, dtype=jnp.int32)
-    hit = jnp.sum(jnp.any(w_held > 0, axis=0), dtype=jnp.int32)
+    y, assigned, hit = expert_share.share(lp, x2, w_held)
     return y.reshape(B, S, H), assigned, hit
 
 

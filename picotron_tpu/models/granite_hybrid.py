@@ -1,0 +1,554 @@
+"""The Granite-4.0-H block (``model_type: "granitemoehybrid"``) as pure
+functions over a parameter pytree: Mamba-2 layers and NoPE attention layers
+in the order ``layer_types`` gives, each followed by routed experts and a
+shared MLP. Serving path only (``Config.validate`` refuses the rest by name).
+
+The equations (``x`` the normed stream; RMSNorm, eps ``rms_norm_eps``; no
+bias anywhere but the conv's):
+
+- stream: ``h = E[tokens] * embedding_multiplier``; a layer: ``h +=
+  residual_multiplier * mixer(norm(h))``, then ``h += residual_multiplier *
+  (experts(norm(h)) + shared(norm(h)))``; out: ``logits = norm(h) E^T /
+  logits_scaling`` (the head is the embedding, tied as published);
+- attention layer (no position embedding): ``q = x W_q``, ``k, v = x W_k, x
+  W_v`` (GQA, no rotation), causal softmax of ``q k^T *
+  attention_multiplier`` (not ``head_dim^-0.5``), ``W_o``;
+- Mamba-2 layer (``d_inner = mamba_n_heads * mamba_d_head``, one group, state
+  ``mamba_d_state``, conv ``mamba_d_conv``): ``[z | u | dt] = x W_in``
+  (``d_inner | d_inner + 2 d_state | heads``); ``u_t <- silu(b + sum_j w[:,
+  j] u_{t-3+j})`` (causal, depthwise, zeros before the sequence); ``[x_s | B
+  | C] = u``; ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)`` per head;
+  the state ``S[h]`` [d_head, d_state] in float32: ``S_t = exp(dt_t A) S_{t-1}
+  + dt_t x_t (x) B_t``; ``y_t = S_t C_t + D x_t``; ``y <- norm(y *
+  silu(z))`` over all of ``d_inner`` (gate first, then the norm); ``W_out``;
+- experts: ``l = x W_r`` in float32 over the router's whole width
+  (``num_local_experts * ep_size``); the ``num_experts_per_tok`` largest
+  logits (ties to the lower index), weights = softmax over those; ``E_e(x) =
+  (silu(x W1_e) * (x W3_e)) W2_e``; the shared MLP the same at
+  ``shared_intermediate_size``. This chip holds ``num_local_experts`` of the
+  experts (``ep_rank * num_local_experts`` onward) and adds their part and
+  the shared MLP's (``models/experts.py``); what the absent experts would
+  add is left out. No token is ever dropped.
+
+Prefill runs a Mamba layer as the chunked scan in matmul form
+(``ssm_scan``), decode as the one-step recurrence (``ssm_step``). The state
+has no token axis, so nothing hides a previous occupant or a row that is not
+live: ``dt = 0`` where a row is not ``live`` freezes ``S`` exactly (``exp(0)
+S + 0``), the conv tail is taken behind the last live row, and the first
+chunk of a prompt (``pos == 0``) starts from zeros whatever the slot held.
+
+The tree: one stacked group a run of ``layer_types`` (``layer_groups``),
+``mamba_<i>`` or ``attention_<i>``; a layer finds its row of its own kind's
+cache leaf from the scan's global index (``_row``).
+
+Every layer function returns, beside the updated cache leaves, what it
+counted (``STATS``, in the order of ``STAT_NAMES``; docs/OBSERVABILITY.md).
+"""
+
+from __future__ import annotations
+
+import math
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import PartitionSpec as P
+
+from picotron_tpu.config import Config, ModelConfig
+from picotron_tpu.inference import kv_cache
+from picotron_tpu.models import STATS, llama
+from picotron_tpu.models import experts as expert_share
+from picotron_tpu.models.llama import param_bytes  # noqa: F401 - the seam
+from picotron_tpu.ops.rmsnorm import rms_norm
+
+# what a layer counts, in the order of the vector (under ``STATS``):
+# the expert share's three (as ``deepseek_v32``), live slot-layers a decode
+# step advanced, Mamba layers decode steps ran, live tokens through a
+# prefill scan (a layer)
+STAT_NAMES = ("moe_assignments", "moe_experts_hit", "moe_layer_steps",
+              "ssm_state_updates", "ssm_layer_steps", "ssm_tokens_scanned")
+
+UNSLICED = expert_share.UNSLICED
+# the state has no token axis and cannot be fed a token twice: the engine
+# holds the window to whole prefill chunks (``prefill_chunked``'s last
+# chunk slides back, and re-feeds its overlap, where it would pass the end)
+CARRIES_STATE = True
+F32 = jnp.float32
+HIGHEST = lax.Precision.HIGHEST
+
+
+# --------------------------------------------------------------------------- #
+# shapes, groups, parameters
+# --------------------------------------------------------------------------- #
+
+
+def d_inner(m: ModelConfig) -> int:
+    return m.mamba_n_heads * m.mamba_d_head
+
+
+def conv_width(m: ModelConfig) -> int:
+    """Channels the conv runs over: ``x_s``, ``B`` and ``C``."""
+    return d_inner(m) + 2 * m.mamba_n_groups * m.mamba_d_state
+
+
+def router_width(m: ModelConfig) -> int:
+    return m.num_local_experts * m.ep_size
+
+
+def runs(layer_types) -> list:
+    """[(kind, first layer, layers of that kind before it, count)] of the
+    runs of equal entries in ``layer_types``."""
+    out, seen = [], {}
+    for i, kind in enumerate(layer_types):
+        if out and out[-1][0] == kind:
+            out[-1][3] += 1
+        else:
+            out.append([kind, i, seen.get(kind, 0), 1])
+        seen[kind] = seen.get(kind, 0) + 1
+    return [tuple(r) for r in out]
+
+
+def layer_groups(m: ModelConfig) -> list:
+    """[(name of the stacked group in the tree, its layer function, how many
+    layers)]: one group a run of ``layer_types``, scanned in turn. Each
+    function knows where its run begins, among all layers and among those
+    of its kind."""
+    fns = {"mamba": mamba_layer, "attention": attention_layer}
+    return [(f"{kind}_{i}", partial(fns[kind], first=first, kind_first=kf), n)
+            for i, (kind, first, kf, n) in enumerate(runs(m.layer_types))]
+
+
+def kind_counts(m: ModelConfig) -> dict:
+    return {k: sum(t == k for t in m.layer_types)
+            for k in ("mamba", "attention")}
+
+
+def _mixer_shapes(m: ModelConfig, kind: str) -> dict:
+    """Matmul leaves of a layer's mixer, (in, out) like every weight here."""
+    H = m.hidden_size
+    if kind == "mamba":
+        return {"in_proj": (H, d_inner(m) + conv_width(m) + m.mamba_n_heads),
+                "out_proj": (d_inner(m), H)}
+    hd = m.head_dim
+    return {"wq": (H, m.num_attention_heads * hd),
+            "wk": (H, m.num_key_value_heads * hd),
+            "wv": (H, m.num_key_value_heads * hd),
+            "wo": (m.num_attention_heads * hd, H)}
+
+
+def _expert_shapes(m: ModelConfig) -> dict:
+    H, I, Is = m.hidden_size, m.intermediate_size, m.shared_intermediate_size
+    E = m.num_local_experts
+    return {"router": (H, router_width(m)),
+            "w1": (E, H, I), "w3": (E, H, I), "w2": (E, I, H),
+            "ws_gate": (H, Is), "ws_up": (H, Is), "ws_down": (Is, H)}
+
+
+# Seeded weights are drawn so that each mechanism of the block is loud
+# enough in the logits for a comparison to see a fault in it (as
+# ``deepseek_v32.INIT_GAIN``; PERF.md, PR 32, has the readings).
+# ``in_proj``'s B and C columns are drawn ``BC`` times wider: with the flat
+# draw ``S C`` is a fiftieth of the skip ``D x`` beside it and neither a
+# lost state nor a shifted conv tail moves a logit. ``wo`` wider: a flat
+# softmax over a thousand keys is a mean, a thirtieth of the stream.
+INIT_GAIN = {"wo": 8.0, "w2": 0.5}
+BC_GAIN = 6.0
+# the embedding's draw: N(0, 1) / (embedding_multiplier * sqrt(H)). The
+# head is the embedding, so the stream's part along the token's own row
+# comes back as that token's logit, sqrt(H) times louder than the rest: a
+# unit-norm entry keeps it among the others.
+
+
+def init_params(key, m: ModelConfig, pp_size: int = 1,
+                interleave: int = 1) -> dict:
+    """Global parameter pytree from ``key``: linear weights U(+-gain *
+    sqrt(1 / fan_in)) drawn in the model's dtype, norm weights ones, conv
+    taps U(+-sqrt(1 / d_conv)) with a small bias, ``A_log = log U(1, 16)``,
+    ``dt_bias`` the inverse softplus of dt log-uniform in [1e-3, 1e-1], ``D
+    = 1`` (the three in float32). No ``lm_head``: the head is ``embed``."""
+    if pp_size != 1 or interleave != 1:
+        raise ValueError("granitemoehybrid is served on one stage (pp_size 1)")
+    dt = jnp.dtype(m.dtype)
+    H, nh, K = m.hidden_size, m.mamba_n_heads, m.mamba_d_conv
+    W, Di = conv_width(m), d_inner(m)
+
+    def uniform(k, shape, fan_in, gain=1.0, dtype=dt):
+        bound = gain * math.sqrt(1.0 / fan_in)
+        return jax.random.uniform(k, shape, dtype, -bound, bound)
+
+    def group(gkey, n: int, kind: str) -> dict:
+        ones = lambda w: jnp.ones((n, w), dt)
+        out = {"mixer_norm": ones(H), "mlp_norm": ones(H)}
+        shapes = sorted({**_mixer_shapes(m, kind),
+                         **_expert_shapes(m)}.items())
+        for i, (name, shape) in enumerate(shapes):
+            out[name] = uniform(jax.random.fold_in(gkey, i), (n,) + shape,
+                                shape[-2], INIT_GAIN.get(name, 1.0))
+        if kind == "mamba":
+            # B and C louder (BC_GAIN): their columns of in_proj
+            cols = jnp.arange(out["in_proj"].shape[-1])
+            bc = (cols >= 2 * Di) & (cols < Di + W)
+            out["in_proj"] = out["in_proj"] * jnp.where(
+                bc, BC_GAIN, 1.0).astype(dt)
+            ks = [jax.random.fold_in(gkey, len(shapes) + j) for j in range(4)]
+            out["gate_norm"] = ones(Di)
+            out["conv_w"] = uniform(ks[0], (n, W, K), K)
+            out["conv_b"] = uniform(ks[1], (n, W), 1, 0.1)
+            out["A_log"] = jnp.log(jax.random.uniform(
+                ks[2], (n, nh), F32, 1.0, 16.0))
+            step = jnp.exp(jax.random.uniform(
+                ks[3], (n, nh), F32, math.log(1e-3), math.log(1e-1)))
+            out["dt_bias"] = step + jnp.log(-jnp.expm1(-step))
+            out["D"] = jnp.ones((n, nh), F32)
+        return out
+
+    params = {
+        "embed": (jax.random.normal(jax.random.fold_in(key, 0),
+                                    (m.vocab_size, H), F32)
+                  / (m.embedding_multiplier * math.sqrt(H))).astype(dt),
+        "final_norm": jnp.ones((H,), dt),
+    }
+    for i, (name, _, n) in enumerate(layer_groups(m)):
+        params[name] = group(jax.random.fold_in(key, 2 + i), n,
+                             name.split("_")[0])
+    return params
+
+
+def param_pspecs(m: ModelConfig, fsdp: bool = False,
+                 weight_dtype: str = "bf16") -> dict:
+    """Every leaf replicated: the block is served at tp_size 1 (its share
+    of a layer is ``ep_size``/``ep_rank``, a cut and not a mesh axis)."""
+    if fsdp or weight_dtype != "bf16":
+        raise ValueError("granitemoehybrid serves dense weights, unsharded")
+    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
+    return jax.tree.map(lambda _: P(), shapes)
+
+
+def num_params(m: ModelConfig) -> int:
+    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
+    return sum(math.prod(s.shape) for s in jax.tree.leaves(shapes))
+
+
+# --------------------------------------------------------------------------- #
+# into and out of the stream; serving state
+# --------------------------------------------------------------------------- #
+
+
+def embed_lookup(w, tokens, cfg: Config):
+    """``E[tokens] * embedding_multiplier``, in the embedding's dtype."""
+    return llama.embed_lookup(w, tokens) * jnp.asarray(
+        cfg.model.embedding_multiplier, w.dtype)
+
+
+def head_logits(params, h, cfg: Config):
+    """Final norm, then the embedding as the head, over ``logits_scaling``."""
+    m = cfg.model
+    x = rms_norm(h, params["final_norm"], m.rms_norm_eps)
+    logits = jnp.einsum("bsh,vh->bsv", x, params["embed"])
+    return logits / jnp.asarray(m.logits_scaling, logits.dtype)
+
+
+def serving_rope_tables(m: ModelConfig, seq_len: int, dtype) -> tuple:
+    """No position embedding: tables nothing reads, of the window's
+    length (the programs slice and gather them by position)."""
+    t = jnp.zeros((seq_len, 2), dtype)
+    return t, t
+
+
+def cache_pspecs(m: ModelConfig, quantized: bool = False,
+                 dp: int = 1) -> dict:
+    """State and K/V are served whole on one chip (``Config.validate``
+    refuses the rest by name)."""
+    assert not quantized and dp == 1
+    return {n: P() for n in ("k", "v", "ssm", "conv", "lengths")}
+
+
+def init_cache(m: ModelConfig, slots: int, max_seq_len: int, dtype=None,
+               quantized: bool = False, tp: int = 1) -> dict:
+    """Zeroed cache for ``slots`` sequences, three kinds of leaf, each over
+    the layers of its own kind: ``k``/``v`` [attention layers, slots, T, kv
+    heads, head_dim]; ``ssm`` [Mamba layers, slots, heads, d_head, d_state]
+    float32; ``conv`` [Mamba layers, slots, d_conv - 1, conv width], the
+    last inputs of the conv."""
+    assert not quantized and tp == 1
+    dt = jnp.dtype(dtype if dtype is not None else m.dtype)
+    n = kind_counts(m)
+    kv = (n["attention"], slots, max_seq_len, m.num_key_value_heads,
+          m.head_dim)
+    return {
+        "k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt),
+        "ssm": jnp.zeros((n["mamba"], slots, m.mamba_n_heads, m.mamba_d_head,
+                          m.mamba_d_state), F32),
+        "conv": jnp.zeros((n["mamba"], slots, m.mamba_d_conv - 1,
+                           conv_width(m)), dt),
+        "lengths": jnp.zeros((slots,), jnp.int32),
+    }
+
+
+# --------------------------------------------------------------------------- #
+# the Mamba-2 mixer
+# --------------------------------------------------------------------------- #
+
+
+def ssm_scan(xs, dt, A, Bm, Cm, S_in, chunk: int) -> tuple:
+    """The recurrence over a whole block of rows, ``chunk`` at a time in
+    matmul form: (y [B, S, heads, d_head] float32 without the ``D`` skip,
+    the state after the last row). ``xs`` [B, S, heads, d_head], ``dt`` [B,
+    S, heads] float32 (0: the row leaves the state as it is), ``A`` [heads],
+    ``Bm``/``Cm`` [B, S, d_state], ``S_in`` [B, heads, d_head, d_state]
+    float32. Within a chunk, with ``L = cumsum(dt A)``: ``Y = ((C B^T) *
+    exp(L_t - L_s) * [s <= t]) (dt x) + exp(L_t) C S_in`` and ``S_out =
+    exp(L_Q) S_in + sum_s exp(L_Q - L_s) dt_s x_s (x) B_s``."""
+    B, S, nh, hd = xs.shape
+    Q = min(chunk, S)
+    pad = -S % Q
+    if pad:
+        # rows past the block: dt 0, so they leave the state alone
+        xs, dt, Bm, Cm = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) *
+                                  (a.ndim - 2)) for a in (xs, dt, Bm, Cm))
+
+    def chunks(a):  # [B, S, ...] -> [S / Q, B, Q, ...]
+        return jnp.moveaxis(a.reshape(B, -1, Q, *a.shape[2:]), 1, 0)
+
+    tri = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
+
+    def one(state, c):
+        x_c, dt_c, B_c, C_c = c
+        L = jnp.cumsum(jnp.moveaxis(dt_c * A, 2, 1), axis=-1)  # [B, nh, Q]
+        G = jnp.einsum("btn,bsn->bts", C_c, B_c,
+                       preferred_element_type=F32)
+        decay = jnp.exp(jnp.where(tri, L[..., :, None] - L[..., None, :],
+                                  -jnp.inf))  # [B, nh, t, s]
+        xdt = x_c.astype(F32) * dt_c[..., None]  # [B, Q, nh, hd]
+        y = jnp.einsum("bhts,bshp->bthp", G[:, None] * decay, xdt)
+        C32, B32 = C_c.astype(F32), B_c.astype(F32)
+        y = y + jnp.einsum("btn,bhpn->bthp", C32, state, precision=HIGHEST) \
+            * jnp.moveaxis(jnp.exp(L), 1, 2)[..., None]
+        to_end = jnp.moveaxis(jnp.exp(L[..., -1:] - L), 1, 2)  # [B, Q, nh]
+        state = jnp.exp(L[..., -1])[..., None, None] * state + jnp.einsum(
+            "bshp,bsn->bhpn", xdt * to_end[..., None], B32,
+            precision=HIGHEST)
+        return state, y
+
+    state, y = lax.scan(one, S_in, tuple(chunks(a)
+                                         for a in (xs, dt, Bm, Cm)))
+    y = jnp.moveaxis(y, 0, 1).reshape(B, -1, nh, hd)
+    return y[:, :S], state
+
+
+def ssm_step(xs, dt, A, Bm, Cm, S_in) -> tuple:
+    """One row a sequence, the recurrence as it is written: (y [B, 1,
+    heads, d_head] float32 without the skip, the new state). One pass over
+    the state: elementwise in float32, the read-out a sum over d_state."""
+    x32 = xs[:, 0].astype(F32) * dt[:, 0, :, None]  # [B, nh, hd]
+    state = jnp.exp(dt[:, 0] * A)[..., None, None] * S_in \
+        + x32[..., None] * Bm[:, 0].astype(F32)[:, None, None, :]
+    y = jnp.sum(state * Cm[:, 0].astype(F32)[:, None, None, :], axis=-1)
+    return y[:, None], state
+
+
+def mamba_mixer(lp, x, conv_in, ssm_in, live, m: ModelConfig,
+                one_step: bool) -> tuple:
+    """The mixer on the normed stream ``x`` [B, S, H] from the conv's last
+    inputs ``conv_in`` [B, d_conv - 1, width] and the state ``ssm_in``:
+    (output [B, S, H], the conv's last inputs and the state behind the last
+    ``live`` row). ``live`` [B, S] marks the real rows, a leading run of
+    each sequence."""
+    B, S, _ = x.shape
+    nh, hd, N, K = (m.mamba_n_heads, m.mamba_d_head, m.mamba_d_state,
+                    m.mamba_d_conv)
+    Di = nh * hd
+    with jax.named_scope("ssm_proj"):
+        proj = x @ lp["in_proj"]
+        z, u, dt = proj[..., :Di], proj[..., Di:-nh], proj[..., -nh:]
+    with jax.named_scope("ssm_conv"):
+        padded = jnp.concatenate([conv_in.astype(u.dtype), u], axis=1)
+        w = lp["conv_w"].astype(F32)
+        conv = lp["conv_b"].astype(F32) + sum(
+            padded[:, j:j + S].astype(F32) * w[:, j] for j in range(K))
+        u = jax.nn.silu(conv).astype(x.dtype)
+        # the last inputs behind the last live row: rows n .. n + K - 2 of
+        # the padded block, n the live rows (0: the tail stays as it was)
+        at = jnp.sum(live, axis=1, dtype=jnp.int32)[:, None] \
+            + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
+        conv_out = jnp.take_along_axis(padded, at[:, :, None], axis=1)
+    xs = u[..., :Di].reshape(B, S, nh, hd)
+    Bm, Cm = u[..., Di:Di + N], u[..., Di + N:]
+    dt = jax.nn.softplus(dt.astype(F32) + lp["dt_bias"]) \
+        * live[..., None].astype(F32)
+    A = -jnp.exp(lp["A_log"])
+    if one_step:
+        with jax.named_scope("ssm_step"):
+            y, ssm_out = ssm_step(xs, dt, A, Bm, Cm, ssm_in)
+    else:
+        with jax.named_scope("ssm_scan"):
+            y, ssm_out = ssm_scan(xs, dt, A, Bm, Cm, ssm_in,
+                                  m.mamba_chunk_size)
+    with jax.named_scope("ssm_gate_out"):
+        y = y + lp["D"][:, None] * xs.astype(F32)
+        y = y.reshape(B, S, Di) * jax.nn.silu(z.astype(F32))
+        y = rms_norm(y, lp["gate_norm"], m.rms_norm_eps).astype(x.dtype)
+        out = y @ lp["out_proj"]
+    return out, conv_out, ssm_out
+
+
+# --------------------------------------------------------------------------- #
+# experts
+# --------------------------------------------------------------------------- #
+
+
+def route(logits, k: int) -> tuple:
+    """(experts [N, k] int32, weights [N, k] float32): the ``k`` largest
+    logits, ties to the lower index, and the softmax over those."""
+    top, experts = lax.top_k(logits, k)
+    return experts.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+
+
+def expert_mlp(lp, x, m: ModelConfig, live) -> tuple:
+    """The expert half of a layer on the normed stream ``x`` [B, S, H]:
+    (this chip's part of the routed sum + the shared MLP, held assignments,
+    held experts hit). Rows that are not ``live`` are routed nowhere."""
+    B, S, H = x.shape
+    x2 = x.reshape(B * S, H)
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(x2.astype(F32), lp["router"].astype(F32),
+                         precision=HIGHEST)
+        experts, weights = route(logits, m.num_experts_per_tok)
+        w_held = expert_share.held_weights(
+            experts, weights, m.ep_rank * m.num_local_experts,
+            m.num_local_experts) * live.reshape(B * S, 1).astype(F32)
+    y, assigned, hit = expert_share.share(lp, x2, w_held)
+    return y.reshape(B, S, H), assigned, hit
+
+
+# --------------------------------------------------------------------------- #
+# the two kinds of layer
+# --------------------------------------------------------------------------- #
+
+
+def _live(cache, live, h):
+    """[B, S] bool: the rows that are counted, routed and advance a state:
+    the engine's ``live`` (real tokens of parked slots), less the slots a
+    decode block's ``active`` entry leaves out (parked, out of budget)."""
+    cache = cache or {}
+    if live is None:
+        live = cache.get("live")
+    if live is None:
+        live = jnp.ones(h.shape[:2], bool)
+    if "active" in cache:
+        live = live & cache["active"][:, None]
+    return live
+
+
+def _row(layer, first: int, kind_first: int):
+    """This layer's row of its own kind's cache leaf, from the scan's
+    global index."""
+    return jnp.asarray(layer, jnp.int32) - first + kind_first
+
+
+def _finish(lp, h, m: ModelConfig, live, out: dict, ssm_stats: tuple):
+    """The expert half, and the layer's counters beside its cache leaves."""
+    y, assigned, hit = expert_mlp(
+        lp, rms_norm(h, lp["mlp_norm"], m.rms_norm_eps), m, live)
+    h = h + jnp.asarray(m.residual_multiplier, h.dtype) * y
+    one = jnp.ones((), jnp.int32)
+    out[STATS] = jnp.stack((assigned, hit, one) + ssm_stats)
+    return h, out
+
+
+def mamba_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
+                return_kv: bool = False, layer=None, live=None, *,
+                first: int = 0, kind_first: int = 0):
+    """A Mamba-2 layer, then the experts. ``llama.decoder_layer``'s
+    contract; the returned dict also holds ``STATS``. Three shapes of call:
+    no cache (a whole sequence from zeros: the state and conv tail behind
+    its last live row are returned as a one-slot block), a ``slot`` entry (a
+    prefill chunk carries that slot's state on, from zeros where ``pos`` is
+    0), neither (a decode step advances every live slot)."""
+    m = cfg.model
+    B = h.shape[0]
+    live = _live(cache, live, h)
+    n_live = jnp.sum(live, dtype=jnp.int32)
+    zero = jnp.zeros((), jnp.int32)
+    decode = cache is not None and "slot" not in cache
+    if cache is None:
+        conv_in = jnp.zeros((B, m.mamba_d_conv - 1, conv_width(m)), h.dtype)
+        ssm_in = jnp.zeros((B, m.mamba_n_heads, m.mamba_d_head,
+                            m.mamba_d_state), F32)
+    else:
+        row = _row(layer, first, kind_first)
+        # a decode step's elementwise pass takes the leaves as they lie. A
+        # chunk's contractions must be held to that: left free they pull
+        # the whole state leaf into their own order on entry and push it
+        # back on exit (two copies of 2.4 GB a chunk; as
+        # ``kv_cache.cache_write`` holds a packed K/V leaf)
+        pin = (lambda x: x) if decode else kv_cache.row_major
+        conv_in = lax.dynamic_index_in_dim(pin(cache["conv"]), row, 0, False)
+        ssm_in = lax.dynamic_index_in_dim(pin(cache["ssm"]), row, 0, False)
+        if not decode:
+            slot = jnp.asarray(cache["slot"], jnp.int32)
+            conv_in = lax.dynamic_slice_in_dim(conv_in, slot, 1, axis=0)
+            ssm_in = lax.dynamic_slice_in_dim(ssm_in, slot, 1, axis=0)
+            # admission: a prompt's first chunk starts from zeros, whatever
+            # the slot's last occupant left
+            fresh = pos[0] == 0
+            conv_in = jnp.where(fresh, jnp.zeros_like(conv_in), conv_in)
+            ssm_in = jnp.where(fresh, jnp.zeros_like(ssm_in), ssm_in)
+    y, conv_out, ssm_out = mamba_mixer(
+        lp, rms_norm(h, lp["mixer_norm"], m.rms_norm_eps), conv_in, ssm_in,
+        live, m, one_step=decode and h.shape[1] == 1)
+    h = h + jnp.asarray(m.residual_multiplier, h.dtype) * y
+    if cache is None:
+        out = {"ssm": ssm_out, "conv": conv_out} if return_kv else {}
+    else:
+        out = {n: v for n, v in cache.items() if n not in ("live", "active")}
+        for name, new, old in (("conv", conv_out, conv_in),
+                               ("ssm", ssm_out, ssm_in)):
+            new = new.astype(cache[name].dtype)
+            if decode:
+                out[name] = lax.dynamic_update_index_in_dim(
+                    cache[name], new, row, 0)
+            else:
+                if cache.get("gate") is not None:
+                    new = jnp.where(cache["gate"], new, old)
+                at = (row, slot) + (zero,) * (new.ndim - 1)
+                out[name] = pin(lax.dynamic_update_slice(
+                    cache[name], pin(new)[None], at))
+    ssm_stats = ((n_live, zero + 1, zero) if decode
+                 else (zero, zero, n_live))
+    return _finish(lp, h, m, live, out, ssm_stats)
+
+
+def attention_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
+                    return_kv: bool = False, layer=None, live=None, *,
+                    first: int = 0, kind_first: int = 0):
+    """A NoPE attention layer (GQA, no rotation, scores times
+    ``attention_multiplier``), then the experts. ``cos``/``sin`` are not
+    read. K/V go through ``kv_cache.cache_write`` / ``attend`` at this
+    layer's row of the ``k``/``v`` leaves."""
+    m = cfg.model
+    B, S, _ = h.shape
+    hd = m.head_dim
+    live = _live(cache, live, h)
+    with jax.named_scope("attn_nope"):
+        x = rms_norm(h, lp["mixer_norm"], m.rms_norm_eps)
+        q = (x @ lp["wq"]).reshape(B, S, m.num_attention_heads, hd)
+        k = (x @ lp["wk"]).reshape(B, S, m.num_key_value_heads, hd)
+        v = (x @ lp["wv"]).reshape(B, S, m.num_key_value_heads, hd)
+        if cache is None:
+            a = kv_cache.decode_attention(
+                q, k, v, jnp.full((B,), S, jnp.int32),
+                m.attention_multiplier)
+            out = {"k": k, "v": v} if return_kv else {}
+        else:
+            row = _row(layer, first, kind_first)
+            out = kv_cache.cache_write(
+                {n: c for n, c in cache.items()
+                 if n not in ("live", "active")}, k, v, pos, row)
+            a = kv_cache.attend(q, out, pos + S, m.attention_multiplier,
+                                row, impl="dense")
+        a = a.reshape(B, S, -1) @ lp["wo"]
+    h = h + jnp.asarray(m.residual_multiplier, h.dtype) * a
+    zero = jnp.zeros((), jnp.int32)
+    return _finish(lp, h, m, live, out, (zero, zero, zero))

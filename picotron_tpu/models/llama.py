@@ -433,11 +433,12 @@ def use_sp(cfg: Config) -> bool:
     return cfg.distributed.tp_sequence_parallel and cfg.distributed.tp_size > 1
 
 
-def embed_lookup(w, tokens, sp: bool = False):
+def embed_lookup(w, tokens, sp: bool = False, cfg=None):
     """Vocab-parallel embedding: mask out-of-shard tokens, psum partials
     (reference VocabParallelEmbedding, tensor_parallel.py:246-271). With
     sequence parallelism the partial sums are reduce-scattered straight to
-    this rank's seq shard instead of fully reduced."""
+    this rank's seq shard instead of fully reduced. ``cfg`` is the serving
+    seam's (``models/__init__.py``); this block reads nothing of it."""
     v_local = w.shape[0]
     start = lax.axis_index("tp") * v_local
     local = tokens - start

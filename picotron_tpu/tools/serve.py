@@ -76,7 +76,8 @@ API (all bodies JSON):
 
 Admission control (checked atomically at POST time):
 
-- **bounded wait queue** — more than ``--max-queue`` waiting requests is a
+- **bounded wait queue** — more than ``--max-queue`` waiting requests
+  (default: 64, or twice the slots where that is more) is a
   503 (the queue is where latency hides; past the bound, waiting is worse
   for the client than retrying another replica);
 - **token budget** — the worst-case token commitment (prompt +
@@ -160,7 +161,8 @@ class FrontEnd:
     SUBMIT_WAIT_SLICE_S = 10.0
 
     def __init__(self, engine, params, *, seed: int = 0,
-                 max_queue: int = 64, token_budget: Optional[int] = None,
+                 max_queue: Optional[int] = None,
+                 token_budget: Optional[int] = None,
                  default_timeout_s: Optional[float] = None,
                  stall_timeout_s: float = 60.0,
                  watchdog_poll_s: float = 0.25,
@@ -176,7 +178,11 @@ class FrontEnd:
             ocfg.profile_dir, ocfg.profile_seconds,
             log=lambda m: self._event("profiler", note=m),
             tracer=self.obs.tracer)
-        self.max_queue = int(max_queue)
+        # two batches of waiting requests at the least (64 up to 32 slots):
+        # a closed loop's clients all arrive at once, and at 64 slots a
+        # bound of 64 shed the ones behind the first batch
+        self.max_queue = int(max_queue if max_queue is not None
+                             else max(64, 2 * engine.slots))
         self.token_budget = int(token_budget if token_budget is not None
                                 else engine.slots * engine.max_seq_len)
         self.default_timeout_s = default_timeout_s
@@ -1507,8 +1513,9 @@ def main(argv=None) -> int:
                     help="zero-bubble scheduling: issue dispatch N+1 "
                          "before syncing dispatch N (sets "
                          "inference.overlap; bit-identical streams)")
-    ap.add_argument("--max-queue", type=int, default=64,
-                    help="bounded wait queue: excess submissions get 503")
+    ap.add_argument("--max-queue", type=int, default=None,
+                    help="bounded wait queue: excess submissions get 503 "
+                         "(default: 64, or 2 x slots where that is more)")
     ap.add_argument("--token-budget", type=int, default=None,
                     help="cap on live prompt+generation tokens (default: "
                          "slots * max_seq_len); excess gets 429")
@@ -1556,7 +1563,7 @@ def main(argv=None) -> int:
     server.start()
     server.front._event(
         "serving", port=server.port, slots=engine.slots,
-        max_seq_len=engine.max_seq_len, max_queue=args.max_queue,
+        max_seq_len=engine.max_seq_len, max_queue=server.front.max_queue,
         token_budget=server.front.token_budget,
         attend_impl=engine.attend_impl, role=server.front.role,
         kv=str(engine.cache_dtype), kv_layout=engine.kv_layout,

@@ -442,3 +442,80 @@ def test_latent_cache_is_row_major_and_never_copied(prog, topo, one_chip):
               if re.search(r"= bf16\[(?:1,)?8,(?:7168,2048|2048,7168)\]", l)
               and " parameter(" not in l and "get-tuple-element" not in l]
     assert not sliced, "\n".join(sliced)
+
+
+# ---- the recurrent state of the Granite-4.0-H block (PR 32) -----------------
+
+
+def _state_program(topo, prog):
+    """``prog`` of the benchmark cell's own engine: the ten layers of
+    ``granite-4.0-h-small-ep2-l10`` at the published widths and 64 slots x
+    4096, compiled for one described chip (at three layers the compiler
+    left the state where it lay, pinned or not)."""
+    import json
+
+    from picotron_tpu.config import Config
+    from picotron_tpu.inference.engine import InferenceEngine
+    from picotron_tpu.topology import build_topology, named_shardings
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "granite-4.0-h-small-ep2-l10.json")) as f:
+        pub = json.load(f)
+    model = {k: pub[k] for k in pub["model_keys"]}
+    model.update({k: pub[k] for k in (
+        "num_attention_heads", "num_key_value_heads", "hidden_size",
+        "intermediate_size", "vocab_size", "rms_norm_eps", "rope_theta",
+        "max_position_embeddings", "num_hidden_layers")}, dtype="bfloat16")
+    cfg = Config.from_dict({"model": model, "training": {"seq_length": 4096}})
+    mesh = build_topology(1, 1, 1, 1, devices=topo.devices)
+    eng = InferenceEngine(cfg, mesh, slots=64, max_seq_len=4096)
+
+    def abstract(tree, specs):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            tree, named_shardings(mesh, specs))
+
+    params = abstract(jax.eval_shape(
+        lambda: eng.model.init_params(jax.random.key(0), cfg.model)),
+        eng._pspecs)
+    cache = abstract(jax.eval_shape(eng._init_cache_jit), eng._cspecs)
+    rep = named_shardings(mesh, jax.sharding.PartitionSpec())
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep)
+    if prog == "decode_block":
+        jitted = eng._program("decode_block")
+        args = (arg((64,), I32), arg((eng.decode_block_len, 2), jnp.uint32),
+                arg((64,), I32), arg((64,), I32), arg((64,), F32),
+                arg((64,), I32), arg((64,), F32))
+    else:
+        jitted = eng._prefill_chunk_jit
+        args = (arg((1, eng.prefill_chunk), I32),) + (arg((), I32),) * 3
+    return jitted.lower(params, cache, *args).compile()
+
+
+@pytest.mark.parametrize("prog", ["decode_block", "prefill_chunk"])
+def test_recurrent_state_is_row_major_and_never_copied(prog, topo, one_chip):
+    """Left free, a prefill chunk's contractions pulled the whole float32
+    state leaf (2.4 GB) into their own order on entry and pushed it back on
+    exit (PR 32 read both copies here, 2.46 GB of temporaries, 15.5 GB in
+    all; ``kv_cache.row_major``): the leaf stays row-major, no instruction copies it, and
+    K and V of the attention layer stay in place beside it; nor is a layer's
+    slice of the experts' stacks copied out before the loop over experts."""
+    compiled = _state_program(topo, prog)
+    text = compiled.as_text()
+    lines = text.splitlines()
+    state = r"f32\[9,64,128,64,128\]"
+    kv = r"bf16\[1,64,4096,8,128\]"
+    copies = [l.strip()[:160] for l in lines
+              if re.search(rf"= (?:{state}|{kv})\S* copy\(", l)]
+    assert not copies, "\n".join(copies)
+    params = [l for l in lines if re.search(rf"cache__ssm__\S* = {state}", l)
+              and " parameter(" in l]
+    assert len(params) == 1 and "{4,3,2,1,0" in params[0], params
+    # resident: 9.51 GB of weights + 3.52 GB of cache; a chunk's
+    # temporaries read 0.36 GB, a block's 0.08 (a layer's state is 0.27)
+    assert compiled.memory_analysis().temp_size_in_bytes < 500e6
+    sliced = [l.strip()[:160] for l in lines
+              if re.search(r"= bf16\[(?:1,)?36,(?:4096,768|768,4096)\]", l)
+              and " parameter(" not in l and "get-tuple-element" not in l]
+    assert not sliced, "\n".join(sliced)
