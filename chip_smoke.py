@@ -442,7 +442,7 @@ def phase_kernels(rehearse: bool) -> dict:
               TOL_FWD)
 
     _kernels_held("kernels", rehearse, {"": [
-        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "rmsnorm_fwd",
+        "flash_fwd", "flash_bwd_dkv", "rmsnorm_fwd",
         "rmsnorm_bwd", "flash_decode_attention", "quant_matmul"]})
     return {"device": dev}
 
@@ -485,7 +485,7 @@ def phase_train(rehearse: bool) -> dict:
     if not rehearse and "mfu" not in last:
         raise SystemExit("[train] the trainer logged no MFU on the chip")
     _kernels_held("train", rehearse, {"jit__step": [
-        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "rmsnorm_fwd",
+        "flash_fwd", "flash_bwd_dkv", "rmsnorm_fwd",
         "rmsnorm_bwd"]})
     return {"device": dev}
 
@@ -780,7 +780,7 @@ def phase_mesh(rehearse: bool) -> dict:
               f"meshes; the one-device run then raised device 0 alone to "
               f"{peaks[0] / 1e9:.2f} GB", flush=True)
         _kernels_held("mesh", rehearse, {"jit__step": [
-            "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "rmsnorm_fwd"]})
+            "flash_fwd", "flash_bwd_dkv", "rmsnorm_fwd"]})
     return {"device": dev}
 
 

@@ -28,18 +28,39 @@ Three data layouts share the same kernel bodies (``model.flash_layout``):
   bshd experiment wanted, within the tiling rules.
 
 K/V for one head live whole in VMEM (S*D*2B ~ 1 MB at S=8192, D=64)
-while scores exist only as a [block_q, block_k] VMEM tile — the MXU sees
-(block_q x D) @ (D x block_k) and (block_q x block_k) @ (block_k x D)
-matmuls, all 128-aligned. The per-row LSE is materialized with a broadcast
-128-lane minor dim ([BH, S, 128] / [B, S, H, 128]) — Mosaic requires the
-last two block dims be (8k, 128m), so a lane-less layout can't be tiled
-per-q-block (the in-tree TPU flash kernel uses the same trick).
+while scores exist only as a [block_q, block_k] VMEM tile. The forward's
+per-row LSE is materialized with a broadcast 128-lane minor dim
+([BH, S, 128] / [B, S, H, 128]) — Mosaic requires the last two block dims be
+(8k, 128m), so a lane-less layout can't be tiled per-q-block (the in-tree TPU
+flash kernel uses the same trick); the residual and the backward's operands
+are compact. GQA repetition happens in the model before the call (as the
+reference repeats before its kernel, model.py:141-142).
 
-Causality is handled at two levels: whole key-blocks strictly above the
-diagonal are skipped (the fori_loop upper bound), the diagonal block gets an
-iota mask. The softmax-backward row term delta = rowsum(dO * O) is computed
-in-kernel from the O/dO blocks. GQA repetition happens in the model before
-the call (as the reference repeats before its kernel, model.py:141-142).
+Causality: whole key-blocks strictly above the diagonal are skipped (the
+fori_loop bounds), every tile walked gets the iota mask.
+
+What a tile pair costs, as the compiler schedules it for a v5e (the final
+bundles of a described-chip compile; one bundle ~0.73 ns on the chip, PERF.md
+PR 33). The [512, 512] float32 score tile is 256 vector registers of a file
+of 64, so every stage streams through VMEM and the loop body is bound by its
+MXU pushes first (a 16-row bf16 push a ~15 cycles; heads of 64 half-fill the
+array, so D 64 and D 128 cost the same) and by the single vector-store slot a
+bundle second, not by vector ALU work: the forward's pair is ~1,350 bundles
+for ~930 of MXU time, the backward's ~2,500 for ~2,330. So:
+
+- the mask stays on every walked tile. Masking only the tiles the diagonal
+  crosses needs a second loop, and handing the carried accumulators from one
+  loop to the next costs more (~600 bundles a query tile) than the mask (~40
+  a pair): measured 1.34 ms a forward call against 1.20 at S 2048;
+- the scale leaves the score tile: a power of two (1/8 at D 64) is folded
+  into q (k in the backward), exact in bf16; in the backward ds * scale is
+  linear and is applied to the float32 [rows, D] accumulators at the end;
+- the forward's row sum stays a [bq, 128] lane-partial sum (vector adds)
+  until the walk ends, one cross-lane reduction a query tile;
+- the backward is ONE kernel: the score tile is built once a pair, keys along
+  rows, and feeds dV, dK and dQ (five matmuls a pair where a dQ kernel of its
+  own made it seven; no tile transpose), with delta = rowsum(dO * O)
+  computed once a call outside it.
 """
 
 from __future__ import annotations
@@ -52,13 +73,18 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANE = 128  # minor-dim width for the broadcast LSE layout
 
-# 512 measured ~1.6x faster than 256 on v5e at S=2048, D=64 (the QK^T and
-# PV matmuls are contraction/width-limited by D=64, so bigger tiles amortize
-# better); VMEM still fits the fp32 [bq, bk] score tile comfortably.
+# 512 x 512 measured fastest on v5e at both training shapes (PR 33, ms a
+# call forward / backward at [3, 2048, 32, 64]: 512x512 1.20 / 2.07, 1024x512
+# 1.36 / 2.31, 512x1024 1.28 / 2.44, 1024x1024 1.32 / 2.32, 256x512 1.33 /
+# 2.62, 512x256 1.79 / 2.44; at [1, 4096, 16, 128] 0.667 / 1.175 against
+# 0.651-1.02 / 1.23-1.53): a loop iteration carries a fixed cost (the
+# accumulators through VMEM, pipeline fill) that a bigger tile amortizes,
+# and past 512 the causal walk wastes more of its diagonal tiles.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 
@@ -82,10 +108,38 @@ def causal_kv_blocks(nk, q_hi, block_k):
     return jnp.minimum(nk, (q_hi + block_k) // block_k)
 
 
-def _causal_band(s, q0, k0, bq, bk):
-    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+def _scale_folds(scale: float) -> bool:
+    """A power of two (1/8 at heads of 64): multiplying one matmul operand
+    by it beforehand is exact in bf16 and float32 alike, so the [bq, bk]
+    score tile needs no multiply."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _causal_band(s, q0, k0, q_axis=0):
+    """Scores above the diagonal to NEG_INF. ``s`` holds query rows from
+    ``q0`` along ``q_axis`` and key rows from ``k0`` along the other."""
+    qpos = (q0 - k0) + lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    kpos = lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
     return jnp.where(qpos >= kpos, s, NEG_INF)
+
+
+def _dot_nt(a, b):
+    """a @ b.T: [m, d] x [n, d] -> [m, n] in float32."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _dot(a, b):
+    """a @ b: [m, n] x [n, d] -> [m, d] in float32."""
+    return lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _dot_tn(a, b):
+    """a.T @ b: [n, m] x [n, d] -> [m, d] in float32 (the MXU takes the
+    transposed operand as it is pushed: no transpose of ``a``)."""
+    return lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32)
 
 
 # --------------------------------------------------------------------------- #
@@ -101,35 +155,48 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_q,
     # Softmax state (m, l, acc) is fp32. blk_axis: which grid axis walks the
     # q-blocks (1 = folded (BH, nq) grid, 2 = bshd (B, H, nq) grid).
     qi = pl.program_id(blk_axis)
+    fold = _scale_folds(scale)
     q = q_ref[0]  # [bq, D]
-    seq_k = k_ref.shape[1]
-    nk = seq_k // block_k
+    if fold:
+        q = q * scale  # exact; once a query tile, not once a score
+    nk = k_ref.shape[1] // block_k
     if causal:
         # key blocks that intersect rows <= this q block's last row
         nk = causal_kv_blocks(nk, (qi + 1) * block_q - 1, block_k)
+    # The row sum stays a [bq, LANE] partial sum over lane-wide column
+    # blocks (plain vector adds) until the walk ends: one cross-lane
+    # reduction a query tile, not one a tile pair.
+    cols = block_k // LANE if block_k % LANE == 0 else 0
 
     def body(j, carry):
         acc, m, l = carry
-        k = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        k0 = pl.multiple_of(j * block_k, block_k)
+        k = k_ref[0, pl.ds(k0, block_k), :]
+        v = v_ref[0, pl.ds(k0, block_k), :]
+        s = _dot_nt(q, k)
+        if not fold:
+            s = s * scale
         if causal:
-            s = _causal_band(s, qi * block_q, j * block_k, block_q, block_k)
+            s = _causal_band(s, qi * block_q, k0)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        if cols:
+            psum = p[:, :LANE]
+            for c in range(1, cols):
+                psum = psum + p[:, c * LANE:(c + 1) * LANE]
+        else:
+            psum = jnp.sum(p, axis=1, keepdims=True)
+        l = l * alpha + psum
+        acc = acc * alpha + _dot(p.astype(v.dtype), v)
         return acc, m_new, l
 
     bq, d = q.shape
     acc0 = jnp.zeros((bq, d), jnp.float32)
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
+    l0 = jnp.zeros((bq, LANE if cols else 1), jnp.float32)
     acc, m, l = lax.fori_loop(0, nk, body, (acc0, m0, l0))
+    l = jnp.sum(l, axis=1, keepdims=True)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), (bq, LANE))
 
@@ -218,78 +285,67 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, layout="folded"):
 # --------------------------------------------------------------------------- #
 # backward
 # --------------------------------------------------------------------------- #
+#
+# One kernel a call, named for the dK/dV it walks by: the grid walks key tiles,
+# each program loops over the query tiles that see its keys, and the score
+# tile is built once a pair and feeds all three gradients (five matmuls a
+# pair; a dQ kernel of its own rebuilt s, p and dp: seven). The tile lies in
+# the orientation dK and dV want: keys along rows, queries along lanes
+# ([bk, bq] = k @ q.T), so dV += p.T @ dO and dK += ds.T @ q are plain
+# row-by-column matmuls of the tile as it lies; dQ += ds @ k contracts the
+# tile's rows, which the MXU takes as a transposed operand push with no
+# transpose of the tile. dQ gathers over the key tiles in a float32 [Sq, D]
+# scratch and leaves with the head's last key tile (the key-tile grid axis is
+# declared sequential: ``dimension_semantics``). lse and the softmax-backward row term delta = rowsum(dO * O)
+# depend on the query row alone: ``_bwd`` computes delta once a call and both
+# enter as rows along lanes, [nq, bq], still compact. ds = p * (dp - delta) *
+# scale is linear in the scale, so the scale leaves the [bk, bq] tile for the
+# float32 [rows, D] accumulators at the end.
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, *,
-                   scale, block_q, block_k, causal, blk_axis=1):
-    qi = pl.program_id(blk_axis)
-    q = q_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0][:, 0:1]
-    delta = jnp.sum(do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
-                    axis=1, keepdims=True)
-    seq_k = k_ref.shape[1]
-    nk = seq_k // block_k
-    if causal:
-        nk = causal_kv_blocks(nk, (qi + 1) * block_q - 1, block_k)
-
-    def body(j, dq):
-        k = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_band(s, qi * block_q, j * block_k, block_q, block_k)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        return dq + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    dq = lax.fori_loop(0, nk, body, jnp.zeros(q.shape, jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                    dk_ref, dv_ref, *, scale, block_q, block_k, causal,
-                    blk_axis=1):
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, *, scale, block_q, block_k,
+                causal, blk_axis=1):
     kj = pl.program_id(blk_axis)
+    fold = _scale_folds(scale)
     k = k_ref[0]  # [bk, D]
     v = v_ref[0]
-    seq_q = q_ref.shape[1]
-    nq = seq_q // block_q
+    ks = k * scale if fold else k  # exact; once a key tile
+    nq = q_ref.shape[1] // block_q
     # first q block that can see this k block
-    j0 = (kj * block_k) // block_q if causal else 0
+    i0 = (kj * block_k) // block_q if causal else 0
+
+    @pl.when(kj == 0)
+    def _():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
 
     def body(i, carry):
         dk, dv = carry
-        q = q_ref[0, pl.ds(i * block_q, block_q), :]
-        do = do_ref[0, pl.ds(i * block_q, block_q), :]
-        o = o_ref[0, pl.ds(i * block_q, block_q), :]
-        lse = lse_ref[0, pl.ds(i * block_q, block_q), 0:1]
-        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=1, keepdims=True)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        q0 = pl.multiple_of(i * block_q, block_q)
+        q = q_ref[0, pl.ds(q0, block_q), :]
+        do = do_ref[0, pl.ds(q0, block_q), :]
+        lse = lse_ref[0, pl.ds(i, 1), :]  # [1, bq]
+        delta = delta_ref[0, pl.ds(i, 1), :]
+        st = _dot_nt(ks, q)  # [bk, bq]
+        if not fold:
+            st = st * scale
         if causal:
-            s = _causal_band(s, i * block_q, kj * block_k, block_q, block_k)
-        p = jnp.exp(s - lse)
-        pt = p.astype(do.dtype)
-        dv = dv + jax.lax.dot_general(pt, do, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * scale).astype(q.dtype)
-        dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
+            st = _causal_band(st, q0, kj * block_k, q_axis=1)
+        pt = jnp.exp(st - lse)
+        dv = dv + _dot(pt.astype(do.dtype), do)
+        dst = (pt * (_dot_nt(v, do) - delta)).astype(q.dtype)
+        dk = dk + _dot(dst, q)
+        dq_acc[pl.ds(q0, block_q), :] += _dot_tn(dst, k)
         return dk, dv
 
     z = jnp.zeros(k.shape, jnp.float32)
-    dk, dv = lax.fori_loop(j0, nq, body, (z, z))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
+    dk, dv = lax.fori_loop(i0, nq, body, (z, z))
+    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    @pl.when(kj == pl.num_programs(blk_axis) - 1)
+    def _():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _bwd(scale, causal, block_q, block_k, layout, res, dout):
@@ -298,98 +354,59 @@ def _bwd(scale, causal, block_q, block_k, layout, res, dout):
     d = q.shape[-1]
     bq = _pick_block(sq, block_q)
     bk = _pick_block(sk, block_k)
-    # Residuals carry the compact (lane-less) LSE (the broadcast LANE layout
-    # is 128x larger, which matters when a remat policy saves it);
-    # re-broadcast to the Mosaic-tileable layout here, transiently.
-    lse = jnp.broadcast_to(lse_c[..., None], lse_c.shape + (LANE,))
-
+    nq = sq // bq
+    # Residuals carry the compact (lane-less) LSE ([BH, Sq] / [B, Sq, H]: the
+    # forward's broadcast LANE layout is 128x larger, which matters when a
+    # remat policy saves it). delta is computed here, once a call, in the
+    # same layout; both reach the kernel as [.., nq, bq] rows (``rows``).
+    delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)
+    # q_spec: a head's whole q / dO / dQ; k_spec: one key tile of k / v /
+    # dK / dV. Operand order is layout-independent; only these vary.
     if layout == "folded":
         bh = q.shape[0]
-        dq_grid, dkv_grid, blk_axis = (bh, sq // bq), (bh, sk // bk), 1
-
-        def spec(n, lane=False):  # block of n rows (or whole axis), d/LANE wide
-            w = LANE if lane else d
-            if n is None:  # whole seq axis
-                return pl.BlockSpec((1, sq, w), lambda b, i: (b, 0, 0))
-            return pl.BlockSpec((1, n, w), lambda b, i: (b, i, 0))
-
-        def kspec(n):
-            if n is None:
-                return pl.BlockSpec((1, sk, d), lambda b, i: (b, 0, 0))
-            return pl.BlockSpec((1, n, d), lambda b, i: (b, i, 0))
-
-        dq_shape = jax.ShapeDtypeStruct((bh, sq, d), q.dtype)
-        dkv_shape = [jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-                     jax.ShapeDtypeStruct((bh, sk, d), v.dtype)]
-    elif layout == "merged":
-        b, h = q.shape[0], q.shape[2]
-        hd = h * d
-        q, out, dout = (x.reshape(b, sq, hd) for x in (q, out, dout))
-        k, v = (x.reshape(b, sk, hd) for x in (k, v))
-        lse = lse.reshape(b, sq, h * LANE)
-        dq_grid, dkv_grid, blk_axis = (b, h, sq // bq), (b, h, sk // bk), 2
-
-        def spec(n, lane=False):
-            w = LANE if lane else d
-            if n is None:
-                return pl.BlockSpec((1, sq, w), lambda b_, hh, i: (b_, 0, hh))
-            return pl.BlockSpec((1, n, w), lambda b_, hh, i: (b_, i, hh))
-
-        def kspec(n):
-            if n is None:
-                return pl.BlockSpec((1, sk, d), lambda b_, hh, i: (b_, 0, hh))
-            return pl.BlockSpec((1, n, d), lambda b_, hh, i: (b_, i, hh))
-
-        dq_shape = jax.ShapeDtypeStruct((b, sq, hd), q.dtype)
-        dkv_shape = [jax.ShapeDtypeStruct((b, sk, hd), k.dtype),
-                     jax.ShapeDtypeStruct((b, sk, hd), v.dtype)]
+        grid, blk_axis = (bh, sk // bk), 1
+        rows = lambda x: x.reshape(bh, nq, bq)
+        row_spec = pl.BlockSpec((1, nq, bq), lambda b, j: (b, 0, 0))
+        q_spec = pl.BlockSpec((1, sq, d), lambda b, j: (b, 0, 0))
+        k_spec = pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0))
+        shape = lambda s: (bh, s, d)
     else:
         b, h = q.shape[0], q.shape[2]
-        dq_grid, dkv_grid, blk_axis = (b, h, sq // bq), (b, h, sk // bk), 2
+        grid, blk_axis = (b, h, sk // bk), 2
+        rows = lambda x: x.transpose(0, 2, 1).reshape(b, h, nq, bq)
+        row_spec = pl.BlockSpec((1, None, nq, bq),
+                                lambda b_, hh, j: (b_, hh, 0, 0))
+        if layout == "merged":
+            q, dout = (x.reshape(b, sq, h * d) for x in (q, dout))
+            k, v = (x.reshape(b, sk, h * d) for x in (k, v))
+            q_spec = pl.BlockSpec((1, sq, d), lambda b_, hh, j: (b_, 0, hh))
+            k_spec = pl.BlockSpec((1, bk, d), lambda b_, hh, j: (b_, j, hh))
+            shape = lambda s: (b, s, h * d)
+        else:
+            q_spec = pl.BlockSpec((1, sq, None, d),
+                                  lambda b_, hh, j: (b_, 0, hh, 0))
+            k_spec = pl.BlockSpec((1, bk, None, d),
+                                  lambda b_, hh, j: (b_, j, hh, 0))
+            shape = lambda s: (b, s, h, d)
 
-        def spec(n, lane=False):
-            w = LANE if lane else d
-            if n is None:
-                return pl.BlockSpec((1, sq, None, w),
-                                    lambda b, hh, i: (b, 0, hh, 0))
-            return pl.BlockSpec((1, n, None, w),
-                                lambda b, hh, i: (b, i, hh, 0))
-
-        def kspec(n):
-            if n is None:
-                return pl.BlockSpec((1, sk, None, d),
-                                    lambda b, hh, i: (b, 0, hh, 0))
-            return pl.BlockSpec((1, n, None, d),
-                                lambda b, hh, i: (b, i, hh, 0))
-
-        dq_shape = jax.ShapeDtypeStruct((b, sq, h, d), q.dtype)
-        dkv_shape = [jax.ShapeDtypeStruct((b, sk, h, d), k.dtype),
-                     jax.ShapeDtypeStruct((b, sk, h, d), v.dtype)]
-
-    # operand order is layout-independent; only spec/kspec/grids/shapes vary
-    dq_in = [spec(bq), kspec(None), kspec(None), spec(bq), spec(bq),
-             spec(bq, lane=True)]
-    dq_out = spec(bq)
-    dkv_in = [spec(None), kspec(bk), kspec(bk), spec(None), spec(None),
-              spec(None, lane=True)]
-    dkv_out = [kspec(bk), kspec(bk)]
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, block_q=bq, block_k=bk,
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, block_q=bq, block_k=bk,
                           causal=causal, blk_axis=blk_axis),
-        grid=dq_grid, in_specs=dq_in, out_specs=dq_out, out_shape=dq_shape,
-        name="flash_bwd_dq",
-    )(q, k, v, out, dout, lse)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, block_q=bq,
-                          block_k=bk, causal=causal, blk_axis=blk_axis),
-        grid=dkv_grid, in_specs=dkv_in, out_specs=dkv_out,
+        grid=grid,
+        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        out_specs=[q_spec, k_spec, k_spec],
         name="flash_bwd_dkv",
-        out_shape=dkv_shape,
-    )(q, k, v, out, dout, lse)
+        out_shape=[jax.ShapeDtypeStruct(shape(sq), q.dtype),
+                   jax.ShapeDtypeStruct(shape(sk), k.dtype),
+                   jax.ShapeDtypeStruct(shape(sk), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((sq, d), jnp.float32)],
+        # dq_acc gathers over a head's key tiles: that axis runs in order,
+        # innermost, whatever a later compiler does with the others
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * blk_axis + ("arbitrary",)),
+    )(q, k, v, dout, rows(lse_c), rows(delta))
     if layout == "merged":  # back to the [B, S, H, D] primal shape (free)
-        b, h = dq.shape[0], dq.shape[-1] // d
         dq = dq.reshape(b, sq, h, d)
         dk = dk.reshape(b, sk, h, d)
         dv = dv.reshape(b, sk, h, d)

@@ -30,6 +30,11 @@ from jax.sharding import SingleDeviceSharding
 from picotron_tpu.ops.pallas import quant_matmul as qm
 from picotron_tpu.ops.pallas.decode_attention import flash_decode_attention
 from picotron_tpu.ops.pallas.flash_attention import (
+    DEFAULT_BLOCK_K,
+    DEFAULT_BLOCK_Q,
+    _pick_block,
+    _scale_folds,
+    causal_kv_blocks,
     flash_attention,
     flash_attention_with_lse,
     flash_block_grads,
@@ -84,6 +89,27 @@ def _flash_fwd():
 def _flash_bwd():
     q = ((4, SEQ, HEADS, D), BF16)
     return jax.grad(lambda q, k, v: _sum32(flash_attention(q, k, v, SCALE)),
+                    argnums=(0, 1, 2)), [q, q, q]
+
+
+# The two training cells' own attention calls (BENCHMARK.json): micro-batch 3
+# of SmolLM at 2048, and one sequence of Mistral's 16 tp-local heads of 128
+# at 4096; with what the kernels decide for each at trace time: the tile
+# pairs a head's causal walk visits (every one masked) and whether the scale
+# is folded into an operand.
+CELL_SHAPES = {
+    "train2k": ((3, 2048, 32, 64), dict(tiles=10, scale_folded=True)),
+    "pp2tp2": ((1, 4096, 16, 128), dict(tiles=36, scale_folded=False)),
+}
+
+
+def _flash_cell(cell, backward):
+    shape, _ = CELL_SHAPES[cell]
+    q = (shape, BF16)
+    attend = lambda q, k, v: flash_attention(q, k, v, shape[-1] ** -0.5)
+    if not backward:
+        return attend, [q, q, q]
+    return jax.grad(lambda q, k, v: _sum32(attend(q, k, v)),
                     argnums=(0, 1, 2)), [q, q, q]
 
 
@@ -153,6 +179,9 @@ CASES = {
     "flash_bwd": _flash_bwd,
     "flash_block_grads": _flash_block_grads,
     "flash_with_lse": _flash_with_lse,
+    **{f"flash_{'bwd' if bwd else 'fwd'}_{cell}":
+       (lambda cell=cell, bwd=bwd: _flash_cell(cell, bwd))
+       for cell in CELL_SHAPES for bwd in (False, True)},
     "rmsnorm_fwd": _rms_fwd,
     "rmsnorm_bwd": _rms_bwd,
     **{f"decode_{layout}_{name}":
@@ -173,6 +202,26 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), \
         f"{case}: the compiled program holds no Pallas kernel"
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_flash_walk_at_cell_shapes(cell, one_chip):
+    """What the compiled training kernels walk at each cell's shape, from
+    the kernels' own loop bound and fold test: the tile pairs a head visits,
+    whether the scale is folded into an operand; and the backward is one
+    kernel a call."""
+    (_, seq, _, d), want = CELL_SHAPES[cell]
+    bq = _pick_block(seq, DEFAULT_BLOCK_Q)
+    bk = _pick_block(seq, DEFAULT_BLOCK_K)
+    walked = sum(int(causal_kv_blocks(seq // bk, (qi + 1) * bq - 1, bk))
+                 for qi in range(seq // bq))
+    assert dict(tiles=walked, scale_folded=_scale_folds(d ** -0.5)) == want
+    fn, shapes = _flash_cell(cell, backward=True)
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    # the forward (for its residuals) and one backward kernel
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2, text
 
 
 # --------------------------------------------------------------------------- #

@@ -23,8 +23,14 @@ if not hasattr(pltpu, "force_tpu_interpret_mode"):
 
 from picotron_tpu.ops.attention import sdpa
 from picotron_tpu.ops.pallas.flash_attention import (
+    DEFAULT_BLOCK_K,
+    DEFAULT_BLOCK_Q,
+    _pick_block,
+    _scale_folds,
+    causal_kv_blocks,
     flash_attention,
     flash_attention_with_lse,
+    flash_block_grads,
 )
 from picotron_tpu.ops.pallas.rmsnorm import rms_norm_pallas
 from picotron_tpu.ops.rmsnorm import rms_norm
@@ -91,6 +97,108 @@ def test_flash_grads_match_sdpa(layout, d):
     for got, want, name in zip(g_got, g_want, "qkv"):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=5e-5, atol=5e-5, err_msg=f"d{name}")
+
+
+def _tiles_walked(seq, bq, bk):
+    """Tile pairs a head's causal walk visits (every one masked; the rest
+    lie wholly above the diagonal), from the bound the kernels loop to."""
+    return sum(int(causal_kv_blocks(seq // bk, (qi + 1) * bq - 1, bk))
+               for qi in range(seq // bq))
+
+
+# Several tiles a row and a column (S 512 with blocks of 128), the diagonal
+# crossing two tiles of a row (block_q != block_k, both ways), the scale
+# folded into an operand (1/8 at D 64) and kept on the scores (D 128), in
+# float32 (the tolerances of the tests above) and in bf16 (the kernels'
+# training dtype: against the float32 oracle on the same rounded inputs,
+# relative to its largest entry).
+TILINGS = [(128, 128), (256, 128), (128, 256)]
+TILED_LAYOUT_D = [("folded", 64), ("folded", 128), ("merged", 128)]
+
+
+def _close(got, want, dtype, f32_tol, name=""):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, rtol=f32_tol, atol=f32_tol,
+                                   err_msg=name)
+    else:
+        tol = 2e-2 * max(float(np.max(np.abs(want))), 1.0)
+        np.testing.assert_allclose(got, want, rtol=2e-2, atol=tol,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("layout,d", TILED_LAYOUT_D)
+@pytest.mark.parametrize("bq,bk", TILINGS)
+def test_flash_tiled_matches_oracle(bq, bk, layout, d, dtype):
+    """Forward, lse and all three gradients over a multi-tile causal walk."""
+    from picotron_tpu.ops.attention import _causal_mask, block_attention
+
+    q, k, v = (x.astype(dtype) for x in _qkv(b=1, s=512, d=d, seed=11))
+    scale = d ** -0.5
+    assert _scale_folds(scale) == (d == 64)
+    assert _tiles_walked(512, bq, bk) > 512 // bq  # several tiles a row
+
+    def loss(attend):
+        def f(q, k, v):
+            out = attend(q, k, v).astype(jnp.float32)
+            return jnp.sum(out * jnp.cos(out))
+        return f
+
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, scale, causal=True, block_q=bq, block_k=bk, layout=layout)
+    with pltpu.force_tpu_interpret_mode():
+        out, lse = flash_attention_with_lse(
+            q, k, v, scale, causal=True, block_q=bq, block_k=bk,
+            layout=layout)
+        grads = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
+    want_out, want_lse = block_attention(q32, k32, v32, scale,
+                                         _causal_mask(512, 512, 0))
+    want = jax.grad(loss(lambda q, k, v: sdpa(q, k, v, scale, causal=True)),
+                    argnums=(0, 1, 2))(q32, k32, v32)
+    _close(out, want_out, dtype, 2e-5, "out")
+    _close(lse, want_lse, dtype, 2e-5, "lse")
+    for g, w, name in zip(grads, want, "qkv"):
+        _close(g, w, dtype, 5e-5, f"d{name}")
+
+
+@pytest.mark.parametrize("layout,d", [("folded", 64), ("merged", 128)])
+@pytest.mark.parametrize("sq,sk", [(256, 512), (512, 256)])
+def test_flash_rect_block_matches_einsum(sq, sk, layout, d):
+    """The ring's entry points on a full-attend half block, Sq != Sk: the
+    forward with its lse, and the block gradients from that out/lse."""
+    from picotron_tpu.ops.attention import block_attention
+
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    q, do = (jax.random.normal(kk, (1, sq, 2, d)) for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (1, sk, 2, d)) for kk in ks[2:])
+    scale = d ** -0.5
+    kw = dict(causal=False, block_q=128, block_k=128, layout=layout)
+    with pltpu.force_tpu_interpret_mode():
+        out, lse = flash_attention_with_lse(q, k, v, scale, **kw)
+        grads = flash_block_grads(q, k, v, out, lse, do, scale, **kw)
+    want_out, want_lse = block_attention(q, k, v, scale, mask=None)
+    want = jax.grad(lambda q, k, v: jnp.sum(
+        block_attention(q, k, v, scale, mask=None)[0] * do),
+        argnums=(0, 1, 2))(q, k, v)
+    _close(out, want_out, jnp.float32, 2e-5, "out")
+    _close(lse, want_lse, jnp.float32, 2e-5, "lse")
+    for g, w, name in zip(grads, want, "qkv"):
+        _close(g, w, jnp.float32, 5e-5, f"d{name}")
+
+
+@pytest.mark.parametrize("seq,d,bq,bk,tiles,folded", [
+    (2048, 64, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, 10, True),    # train-2k
+    (4096, 128, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, 36, False),  # train-pp2tp2
+    (512, 64, 256, 128, 6, True),   # of 8: two skipped in the first row
+    (512, 64, 128, 256, 6, True),
+    (512, 80, 128, 128, 10, False),
+])
+def test_causal_walk_and_scale_fold(seq, d, bq, bk, tiles, folded):
+    bq, bk = _pick_block(seq, bq), _pick_block(seq, bk)
+    assert _tiles_walked(seq, bq, bk) == tiles
+    assert _scale_folds(d ** -0.5) == folded
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
