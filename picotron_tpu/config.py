@@ -160,7 +160,10 @@ class ModelConfig:
     # models/deepseek_v32.py, serving path only) or "granitemoehybrid"
     # (Mamba-2 and NoPE attention layers by ``layer_types``, routed and
     # shared experts behind each — models/granite_hybrid.py, serving path
-    # only; its fields are at the end). The fields below are the
+    # only; its fields follow DeepSeek's) or "minicpm_sala" (lightning
+    # linear-attention and block-sparse NoPE attention layers by
+    # ``mixer_types``, a SwiGLU behind each — models/minicpm_sala.py,
+    # serving path only; its fields are at the end). The fields below are the
     # published ``config.json`` keys of the DeepSeek block, by their own
     # names, and are read by no other block (but ``num_experts_per_tok``,
     # ``ep_size`` and ``ep_rank``, which both expert blocks read).
@@ -216,6 +219,32 @@ class ModelConfig:
     logits_scaling: float = 1.0
     position_embedding_type: str = "rope"
     tie_word_embeddings: bool = False
+    # "minicpm_sala": the published keys of that block. ``mixer_types``
+    # names each layer's mixer ("minicpm4": block-sparse attention |
+    # "lightning-attn"), one entry a layer held here. ``sparse_config`` is
+    # the sparse layers' group (kernel_size, kernel_stride, block_size,
+    # init_blocks, window_size, topk, dense_len). The held layers are
+    # ``first_layer`` onward of a model ``total_layers`` deep (0: as deep as
+    # what is held): the residual multiplier ``scale_depth /
+    # sqrt(total_layers)`` and the decay slopes are the whole model's.
+    mixer_types: Optional[list] = None
+    lightning_nh: int = 0
+    lightning_nkv: int = 0
+    lightning_head_dim: int = 0
+    lightning_scale: str = "1/sqrt(d)"
+    lightning_use_rope: bool = True
+    attn_use_rope: bool = True
+    attn_use_output_gate: bool = False
+    qk_norm: bool = False
+    use_output_norm: bool = False
+    use_output_gate: bool = False
+    scale_emb: float = 1.0
+    scale_depth: float = 1.0
+    dim_model_base: int = 0
+    mup_denominator: int = 0
+    sparse_config: Optional[dict] = None
+    first_layer: int = 0
+    total_layers: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -1057,14 +1086,17 @@ class Config:
                     f"fsdp needs hidden_size ({m.hidden_size}) divisible by "
                     f"dp_size ({d.dp_size}) — every layer param shards on an "
                     f"H-sized axis")
-        if m.model_type not in ("llama", "deepseek_v32", "granitemoehybrid"):
+        if m.model_type not in ("llama", "deepseek_v32", "granitemoehybrid",
+                                "minicpm_sala"):
             raise ValueError(
                 f"unknown model_type {m.model_type!r} "
-                "(llama|deepseek_v32|granitemoehybrid)")
+                "(llama|deepseek_v32|granitemoehybrid|minicpm_sala)")
         if m.model_type == "deepseek_v32":
             self._validate_deepseek_v32(for_training)
         if m.model_type == "granitemoehybrid":
             self._validate_granite_hybrid(for_training)
+        if m.model_type == "minicpm_sala":
+            self._validate_minicpm_sala(for_training)
         if m.attention_impl not in ("auto", "sdpa", "flash"):
             raise ValueError(
                 f"unknown attention_impl {m.attention_impl!r} (auto|sdpa|flash)")
@@ -1551,6 +1583,141 @@ class Config:
                 raise ValueError(
                     f"{who} implements model.{name} = {want!r} only (got "
                     f"{getattr(m, name)!r})")
+
+    def _validate_minicpm_sala(self, for_training: bool) -> None:
+        """What ``models/minicpm_sala.py`` needs of its keys, and what it
+        cannot do yet, each refused by name."""
+        d, m, inf = self.distributed, self.model, self.inference
+        who = "model_type 'minicpm_sala'"
+        if for_training:
+            raise ValueError(
+                f"{who} is served, not trained: training is not implemented "
+                "for this block (no backward through the block selection "
+                "and the chunked scan; train_step builds the Llama block "
+                "only)")
+        if d.tp_size > 1:
+            raise ValueError(
+                f"{who} does not support tp_size > 1 (got {d.tp_size}): the "
+                "lightning state and the compressed keys have no tp "
+                "sharding and the block holds no tp collectives")
+        if inf.dp_size > 1:
+            raise ValueError(
+                f"{who} does not support inference.dp_size > 1 (got "
+                f"{inf.dp_size}): the state and the compressed keys have "
+                "no slot axis over 'dp'")
+        if inf.kv_layout == "paged":
+            raise ValueError(
+                f"{who} does not support inference.kv_layout 'paged' (nor "
+                "the prefix reuse that rests on it): the block selection "
+                "gathers key blocks of a contiguous leaf, and neither the "
+                "compressed keys nor the lightning state are paged; set "
+                "kv_layout: 'contiguous'")
+        if inf.kv_cache_dtype == "int8":
+            raise ValueError(
+                f"{who} does not support inference.kv_cache_dtype 'int8': "
+                "the state is float32 and K, V and the compressed keys are "
+                "stored in the model's dtype")
+        if inf.weight_dtype == "int8":
+            raise ValueError(
+                f"{who} does not support inference.weight_dtype 'int8': its "
+                "matmuls take dense weights only")
+        if inf.tenancy.tenants or inf.tenancy.manifest:
+            raise ValueError(
+                f"{who} does not support LoRA adapters (inference.tenancy): "
+                "the adapter pack is shaped for the Llama block's seven "
+                "projections")
+        if inf.spec_len > 0:
+            raise ValueError(
+                f"{who} does not support speculation (inference.spec_len "
+                f"{inf.spec_len}): a rejected draft cannot be rolled back "
+                "out of a recurrent state by rewinding a length")
+        if inf.attend_impl != "dense":
+            raise ValueError(
+                f"{who} does not support inference.attend_impl "
+                f"{inf.attend_impl!r}: the flash-decode kernel reads every "
+                "live key, not the chosen blocks")
+        if inf.overlap:
+            raise ValueError(
+                f"{who} does not support inference.overlap: the lookahead "
+                "dispatch is not implemented for a block that carries a "
+                "state")
+        if inf.mixed_dispatch:
+            raise ValueError(
+                f"{who} does not support inference.mixed_dispatch: the "
+                "fused prefill lane embeds and heads through the Llama "
+                "block")
+        if inf.key_schedule == "slot":
+            raise ValueError(
+                f"{who} does not support inference.key_schedule 'slot': it "
+                "serves through the round-keyed programs only")
+        for name in ("lightning_nh", "lightning_nkv", "lightning_head_dim",
+                     "dim_model_base"):
+            if getattr(m, name) < 1:
+                raise ValueError(f"{who} needs model.{name} >= 1")
+        mt = m.mixer_types
+        kinds = ("minicpm4", "lightning-attn")
+        if not mt or len(mt) != m.num_hidden_layers \
+                or any(t not in kinds for t in mt):
+            raise ValueError(
+                f"{who} needs model.mixer_types: one of 'minicpm4' | "
+                f"'lightning-attn' for each of the {m.num_hidden_layers} "
+                f"layers (got {mt!r})")
+        if len(set(mt)) < 2:
+            raise ValueError(
+                f"{who} needs at least one 'minicpm4' and one "
+                "'lightning-attn' layer in model.mixer_types: the cache "
+                "holds a leaf of each kind")
+        if m.lightning_nkv != m.lightning_nh:
+            raise ValueError(
+                f"{who}: lightning_nkv {m.lightning_nkv} must equal "
+                f"lightning_nh {m.lightning_nh} (a state a head)")
+        if m.lightning_head_dim % 2:
+            raise ValueError(
+                f"{who}: lightning_head_dim {m.lightning_head_dim} must be "
+                "even (RoPE rotates halves)")
+        if m.total_layers and m.first_layer + m.num_hidden_layers \
+                > m.total_layers or m.first_layer < 0:
+            raise ValueError(
+                f"{who}: layers first_layer {m.first_layer} .. + "
+                f"num_hidden_layers {m.num_hidden_layers} lie outside "
+                f"total_layers {m.total_layers}")
+        for name, want in (("lightning_scale", "1/sqrt(d)"),
+                           ("lightning_use_rope", True),
+                           ("attn_use_rope", False),
+                           ("attn_use_output_gate", True),
+                           ("qk_norm", True), ("use_output_norm", True),
+                           ("use_output_gate", True),
+                           ("tie_word_embeddings", False),
+                           ("rope_scaling", None)):
+            if getattr(m, name) != want:
+                raise ValueError(
+                    f"{who} implements model.{name} = {want!r} only (got "
+                    f"{getattr(m, name)!r})")
+        sc = m.sparse_config or {}
+        need = ("kernel_size", "kernel_stride", "block_size", "init_blocks",
+                "window_size", "topk", "dense_len")
+        if any(int(sc.get(k, 0)) < 1 for k in need):
+            raise ValueError(
+                f"{who} needs model.sparse_config with {', '.join(need)} "
+                f"each >= 1 (got {m.sparse_config!r})")
+        ks, st, bs = sc["kernel_size"], sc["kernel_stride"], sc["block_size"]
+        if ks != 2 * st or bs % st or sc["window_size"] % bs \
+                or sc["dense_len"] % bs:
+            raise ValueError(
+                f"{who}: sparse_config needs kernel_size {ks} = 2 x "
+                f"kernel_stride {st}, and block_size {bs}, window_size "
+                f"{sc['window_size']} and dense_len {sc['dense_len']} in "
+                "whole strides and blocks")
+        if sc["init_blocks"] + sc["window_size"] // bs > sc["topk"]:
+            raise ValueError(
+                f"{who}: sparse_config's forced blocks (init_blocks "
+                f"{sc['init_blocks']} + window_size / block_size "
+                f"{sc['window_size'] // bs}) pass topk {sc['topk']}")
+        if inf.prefill_chunk % st:
+            raise ValueError(
+                f"{who}: inference.prefill_chunk ({inf.prefill_chunk}) must "
+                f"be a multiple of sparse_config.kernel_stride ({st}): a "
+                "chunk writes whole rows of compressed keys")
 
     # ---- JSON round-trip (reference: train.py:62-63 consumes one JSON file) ----
 

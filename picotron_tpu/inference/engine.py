@@ -418,6 +418,7 @@ class InferenceEngine:
             # (``Config.validate``), whether it was asked for in the config
             # or by a keyword here
             inf.spec_len = self.spec_len
+            inf.prefill_chunk = self.prefill_chunk
             self.cfg.validate()
             if adapters is not None or self.quantized:
                 raise ValueError(

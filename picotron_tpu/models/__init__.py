@@ -14,7 +14,8 @@ whatever the block:
   function, how many layers)]``, scanned one after the other over one cache;
   every layer function keeps ``llama.decoder_layer``'s contract and is
   handed its global index (a block whose cache leaves run over different
-  layers, ``granite_hybrid``, finds its own row from it); in a decode
+  layers, ``granite_hybrid`` or ``minicpm_sala``, finds its own row from
+  it); in a decode
   block its cache dict also holds ``"active"`` [B] (parked and in budget),
   which a block that keeps K/V alone need not read;
 - ``UNSLICED``: names of a group's leaves the scan hands its layers
@@ -23,7 +24,8 @@ whatever the block:
 - ``cache_pspecs(m, quantized, dp=...)`` and ``init_cache(m, slots,
   max_seq_len, dtype=..., quantized=..., tp=...)``: the contiguous cache
   (K/V heads for the Llama block, as many to a row as fill its lanes on a
-  'tp' axis that wide; latent rows for ``deepseek_v32``);
+  'tp' axis that wide; latent rows for ``deepseek_v32``; K/V, compressed
+  keys and a float32 state side by side for ``minicpm_sala``);
 - ``CARRIES_STATE`` (absent: false): the cache holds a state with no token
   axis, which cannot be fed a token twice; the engine then holds the
   window to whole prefill chunks;
@@ -34,8 +36,48 @@ whatever the block:
 
 from picotron_tpu.models import llama  # noqa: F401
 
+import jax.numpy as jnp
+
 # the key under which a layer that counts returns its counters
 STATS = "stats"
+
+
+# What the blocks whose layers alternate between kinds of mixer
+# (``granite_hybrid``, ``minicpm_sala``) share: the runs of the per-layer
+# pattern, a layer's row of its own kind's cache leaves, the rows that count.
+
+
+def runs(kinds) -> list:
+    """[(kind, first layer, layers of that kind before it, count)] of the
+    runs of equal entries in ``kinds``, one entry a layer."""
+    out, seen = [], {}
+    for i, kind in enumerate(kinds):
+        if out and out[-1][0] == kind:
+            out[-1][3] += 1
+        else:
+            out.append([kind, i, seen.get(kind, 0), 1])
+        seen[kind] = seen.get(kind, 0) + 1
+    return [tuple(r) for r in out]
+
+
+def leaf_row(layer, first: int, kind_first: int):
+    """A layer's row of its own kind's cache leaves, from the scan's global
+    index and where its run begins (``runs``)."""
+    return jnp.asarray(layer, jnp.int32) - first + kind_first
+
+
+def live_rows(cache, live, h):
+    """[B, S] bool: the rows that are counted, routed and advance a state:
+    the engine's ``live`` (real tokens of parked slots), less the slots a
+    decode block's ``active`` entry leaves out (parked, out of budget)."""
+    cache = cache or {}
+    if live is None:
+        live = cache.get("live")
+    if live is None:
+        live = jnp.ones(h.shape[:2], bool)
+    if "active" in cache:
+        live = live & cache["active"][:, None]
+    return live
 
 
 def model_module(m):
@@ -50,6 +92,10 @@ def model_module(m):
         from picotron_tpu.models import granite_hybrid
 
         return granite_hybrid
+    if m.model_type == "minicpm_sala":
+        from picotron_tpu.models import minicpm_sala
+
+        return minicpm_sala
     if m.model_type == "llama":
         return llama
     raise ValueError(f"unknown model_type {m.model_type!r}")

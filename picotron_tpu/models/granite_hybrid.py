@@ -31,7 +31,8 @@ bias anywhere but the conv's):
   add is left out. No token is ever dropped.
 
 Prefill runs a Mamba layer as the chunked scan in matmul form
-(``ssm_scan``), decode as the one-step recurrence (``ssm_step``). The state
+(``ops/ssm.py``: ``ssm_scan``), decode as the one-step recurrence
+(``ssm_step``). The state
 has no token axis, so nothing hides a previous occupant or a row that is not
 live: ``dt = 0`` where a row is not ``live`` freezes ``S`` exactly (``exp(0)
 S + 0``), the conv tail is taken behind the last live row, and the first
@@ -39,7 +40,7 @@ chunk of a prompt (``pos == 0``) starts from zeros whatever the slot held.
 
 The tree: one stacked group a run of ``layer_types`` (``layer_groups``),
 ``mamba_<i>`` or ``attention_<i>``; a layer finds its row of its own kind's
-cache leaf from the scan's global index (``_row``).
+cache leaf from the scan's global index (``models.leaf_row``).
 
 Every layer function returns, beside the updated cache leaves, what it
 counted (``STATS``, in the order of ``STAT_NAMES``; docs/OBSERVABILITY.md).
@@ -57,10 +58,11 @@ from jax.sharding import PartitionSpec as P
 
 from picotron_tpu.config import Config, ModelConfig
 from picotron_tpu.inference import kv_cache
-from picotron_tpu.models import STATS, llama
+from picotron_tpu.models import STATS, leaf_row, live_rows, llama, runs
 from picotron_tpu.models import experts as expert_share
 from picotron_tpu.models.llama import param_bytes  # noqa: F401 - the seam
 from picotron_tpu.ops.rmsnorm import rms_norm
+from picotron_tpu.ops.ssm import ssm_scan, ssm_step
 
 # what a layer counts, in the order of the vector (under ``STATS``):
 # the expert share's three (as ``deepseek_v32``), live slot-layers a decode
@@ -94,19 +96,6 @@ def conv_width(m: ModelConfig) -> int:
 
 def router_width(m: ModelConfig) -> int:
     return m.num_local_experts * m.ep_size
-
-
-def runs(layer_types) -> list:
-    """[(kind, first layer, layers of that kind before it, count)] of the
-    runs of equal entries in ``layer_types``."""
-    out, seen = [], {}
-    for i, kind in enumerate(layer_types):
-        if out and out[-1][0] == kind:
-            out[-1][3] += 1
-        else:
-            out.append([kind, i, seen.get(kind, 0), 1])
-        seen[kind] = seen.get(kind, 0) + 1
-    return [tuple(r) for r in out]
 
 
 def layer_groups(m: ModelConfig) -> list:
@@ -291,63 +280,6 @@ def init_cache(m: ModelConfig, slots: int, max_seq_len: int, dtype=None,
 # --------------------------------------------------------------------------- #
 
 
-def ssm_scan(xs, dt, A, Bm, Cm, S_in, chunk: int) -> tuple:
-    """The recurrence over a whole block of rows, ``chunk`` at a time in
-    matmul form: (y [B, S, heads, d_head] float32 without the ``D`` skip,
-    the state after the last row). ``xs`` [B, S, heads, d_head], ``dt`` [B,
-    S, heads] float32 (0: the row leaves the state as it is), ``A`` [heads],
-    ``Bm``/``Cm`` [B, S, d_state], ``S_in`` [B, heads, d_head, d_state]
-    float32. Within a chunk, with ``L = cumsum(dt A)``: ``Y = ((C B^T) *
-    exp(L_t - L_s) * [s <= t]) (dt x) + exp(L_t) C S_in`` and ``S_out =
-    exp(L_Q) S_in + sum_s exp(L_Q - L_s) dt_s x_s (x) B_s``."""
-    B, S, nh, hd = xs.shape
-    Q = min(chunk, S)
-    pad = -S % Q
-    if pad:
-        # rows past the block: dt 0, so they leave the state alone
-        xs, dt, Bm, Cm = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) *
-                                  (a.ndim - 2)) for a in (xs, dt, Bm, Cm))
-
-    def chunks(a):  # [B, S, ...] -> [S / Q, B, Q, ...]
-        return jnp.moveaxis(a.reshape(B, -1, Q, *a.shape[2:]), 1, 0)
-
-    tri = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
-
-    def one(state, c):
-        x_c, dt_c, B_c, C_c = c
-        L = jnp.cumsum(jnp.moveaxis(dt_c * A, 2, 1), axis=-1)  # [B, nh, Q]
-        G = jnp.einsum("btn,bsn->bts", C_c, B_c,
-                       preferred_element_type=F32)
-        decay = jnp.exp(jnp.where(tri, L[..., :, None] - L[..., None, :],
-                                  -jnp.inf))  # [B, nh, t, s]
-        xdt = x_c.astype(F32) * dt_c[..., None]  # [B, Q, nh, hd]
-        y = jnp.einsum("bhts,bshp->bthp", G[:, None] * decay, xdt)
-        C32, B32 = C_c.astype(F32), B_c.astype(F32)
-        y = y + jnp.einsum("btn,bhpn->bthp", C32, state, precision=HIGHEST) \
-            * jnp.moveaxis(jnp.exp(L), 1, 2)[..., None]
-        to_end = jnp.moveaxis(jnp.exp(L[..., -1:] - L), 1, 2)  # [B, Q, nh]
-        state = jnp.exp(L[..., -1])[..., None, None] * state + jnp.einsum(
-            "bshp,bsn->bhpn", xdt * to_end[..., None], B32,
-            precision=HIGHEST)
-        return state, y
-
-    state, y = lax.scan(one, S_in, tuple(chunks(a)
-                                         for a in (xs, dt, Bm, Cm)))
-    y = jnp.moveaxis(y, 0, 1).reshape(B, -1, nh, hd)
-    return y[:, :S], state
-
-
-def ssm_step(xs, dt, A, Bm, Cm, S_in) -> tuple:
-    """One row a sequence, the recurrence as it is written: (y [B, 1,
-    heads, d_head] float32 without the skip, the new state). One pass over
-    the state: elementwise in float32, the read-out a sum over d_state."""
-    x32 = xs[:, 0].astype(F32) * dt[:, 0, :, None]  # [B, nh, hd]
-    state = jnp.exp(dt[:, 0] * A)[..., None, None] * S_in \
-        + x32[..., None] * Bm[:, 0].astype(F32)[:, None, None, :]
-    y = jnp.sum(state * Cm[:, 0].astype(F32)[:, None, None, :], axis=-1)
-    return y[:, None], state
-
-
 def mamba_mixer(lp, x, conv_in, ssm_in, live, m: ModelConfig,
                 one_step: bool) -> tuple:
     """The mixer on the normed stream ``x`` [B, S, H] from the conv's last
@@ -427,26 +359,6 @@ def expert_mlp(lp, x, m: ModelConfig, live) -> tuple:
 # --------------------------------------------------------------------------- #
 
 
-def _live(cache, live, h):
-    """[B, S] bool: the rows that are counted, routed and advance a state:
-    the engine's ``live`` (real tokens of parked slots), less the slots a
-    decode block's ``active`` entry leaves out (parked, out of budget)."""
-    cache = cache or {}
-    if live is None:
-        live = cache.get("live")
-    if live is None:
-        live = jnp.ones(h.shape[:2], bool)
-    if "active" in cache:
-        live = live & cache["active"][:, None]
-    return live
-
-
-def _row(layer, first: int, kind_first: int):
-    """This layer's row of its own kind's cache leaf, from the scan's
-    global index."""
-    return jnp.asarray(layer, jnp.int32) - first + kind_first
-
-
 def _finish(lp, h, m: ModelConfig, live, out: dict, ssm_stats: tuple):
     """The expert half, and the layer's counters beside its cache leaves."""
     y, assigned, hit = expert_mlp(
@@ -468,7 +380,7 @@ def mamba_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
     0), neither (a decode step advances every live slot)."""
     m = cfg.model
     B = h.shape[0]
-    live = _live(cache, live, h)
+    live = live_rows(cache, live, h)
     n_live = jnp.sum(live, dtype=jnp.int32)
     zero = jnp.zeros((), jnp.int32)
     decode = cache is not None and "slot" not in cache
@@ -477,7 +389,7 @@ def mamba_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
         ssm_in = jnp.zeros((B, m.mamba_n_heads, m.mamba_d_head,
                             m.mamba_d_state), F32)
     else:
-        row = _row(layer, first, kind_first)
+        row = leaf_row(layer, first, kind_first)
         # a decode step's elementwise pass takes the leaves as they lie. A
         # chunk's contractions must be held to that: left free they pull
         # the whole state leaf into their own order on entry and push it
@@ -530,7 +442,7 @@ def attention_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
     m = cfg.model
     B, S, _ = h.shape
     hd = m.head_dim
-    live = _live(cache, live, h)
+    live = live_rows(cache, live, h)
     with jax.named_scope("attn_nope"):
         x = rms_norm(h, lp["mixer_norm"], m.rms_norm_eps)
         q = (x @ lp["wq"]).reshape(B, S, m.num_attention_heads, hd)
@@ -542,7 +454,7 @@ def attention_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
                 m.attention_multiplier)
             out = {"k": k, "v": v} if return_kv else {}
         else:
-            row = _row(layer, first, kind_first)
+            row = leaf_row(layer, first, kind_first)
             out = kv_cache.cache_write(
                 {n: c for n, c in cache.items()
                  if n not in ("live", "active")}, k, v, pos, row)

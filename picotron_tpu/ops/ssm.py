@@ -1,0 +1,91 @@
+"""The diagonal-decay recurrence both recurrent mixers run over a float32
+state ``S[h]`` [d_head, d_state]: ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x)
+B_t``, ``y_t = S_t C_t``. ``models/granite_hybrid.py`` (Mamba-2: ``dt`` from
+the input, one ``B``/``C`` for all heads) and ``models/minicpm_sala.py``
+(lightning attention: ``dt = 1``, ``A = -slope``, ``x = v``, ``B = k``, ``C
+= q``, each per head) prefill with the chunked scan in matmul form
+(``ssm_scan``) and decode with the one-row step (``ssm_step``).
+
+``Bm``/``Cm`` are [B, S, d_state] (shared by the heads) or [B, S, heads,
+d_state] (a head's own); the rank picks the contraction, nothing else
+differs. ``dt = 0`` leaves the state exactly as it is (``exp(0) S + 0``):
+how a pad row or a parked slot is kept out of it."""
+
+from __future__ import annotations
+
+import jax.numpy as jnp
+from jax import lax
+
+F32 = jnp.float32
+HIGHEST = lax.Precision.HIGHEST
+
+
+def ssm_scan(xs, dt, A, Bm, Cm, S_in, chunk: int) -> tuple:
+    """The recurrence over a whole block of rows, ``chunk`` at a time in
+    matmul form: (y [B, S, heads, d_head] float32 without the ``D`` skip,
+    the state after the last row). ``xs`` [B, S, heads, d_head], ``dt`` [B,
+    S, heads] float32 (0: the row leaves the state as it is), ``A`` [heads],
+    ``Bm``/``Cm`` [B, S, d_state] or [B, S, heads, d_state], ``S_in`` [B,
+    heads, d_head, d_state] float32. Within a chunk, with ``L = cumsum(dt
+    A)``: ``Y = ((C B^T) * exp(L_t - L_s) * [s <= t]) (dt x) + exp(L_t) C
+    S_in`` and ``S_out = exp(L_Q) S_in + sum_s exp(L_Q - L_s) dt_s x_s (x)
+    B_s``."""
+    B, S, nh, hd = xs.shape
+    per_head = Bm.ndim == 4
+    Q = min(chunk, S)
+    pad = -S % Q
+    if pad:
+        # rows past the block: dt 0, so they leave the state alone
+        xs, dt, Bm, Cm = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) *
+                                  (a.ndim - 2)) for a in (xs, dt, Bm, Cm))
+
+    def chunks(a):  # [B, S, ...] -> [S / Q, B, Q, ...]
+        return jnp.moveaxis(a.reshape(B, -1, Q, *a.shape[2:]), 1, 0)
+
+    tri = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
+
+    def one(state, c):
+        x_c, dt_c, B_c, C_c = c
+        L = jnp.cumsum(jnp.moveaxis(dt_c * A, 2, 1), axis=-1)  # [B, nh, Q]
+        decay = jnp.exp(jnp.where(tri, L[..., :, None] - L[..., None, :],
+                                  -jnp.inf))  # [B, nh, t, s]
+        xdt = x_c.astype(F32) * dt_c[..., None]  # [B, Q, nh, hd]
+        C32, B32 = C_c.astype(F32), B_c.astype(F32)
+        if per_head:
+            G = jnp.einsum("bthn,bshn->bhts", C_c, B_c,
+                           preferred_element_type=F32) * decay
+            read = jnp.einsum("bthn,bhpn->bthp", C32, state,
+                              precision=HIGHEST)
+        else:
+            G = jnp.einsum("btn,bsn->bts", C_c, B_c,
+                           preferred_element_type=F32)[:, None] * decay
+            read = jnp.einsum("btn,bhpn->bthp", C32, state,
+                              precision=HIGHEST)
+        y = jnp.einsum("bhts,bshp->bthp", G, xdt)
+        y = y + read * jnp.moveaxis(jnp.exp(L), 1, 2)[..., None]
+        to_end = jnp.moveaxis(jnp.exp(L[..., -1:] - L), 1, 2)  # [B, Q, nh]
+        state = jnp.exp(L[..., -1])[..., None, None] * state + jnp.einsum(
+            "bshp,bshn->bhpn" if per_head else "bshp,bsn->bhpn",
+            xdt * to_end[..., None], B32, precision=HIGHEST)
+        return state, y
+
+    state, y = lax.scan(one, S_in, tuple(chunks(a)
+                                         for a in (xs, dt, Bm, Cm)))
+    y = jnp.moveaxis(y, 0, 1).reshape(B, -1, nh, hd)
+    return y[:, :S], state
+
+
+def ssm_step(xs, dt, A, Bm, Cm, S_in) -> tuple:
+    """One row a sequence, the recurrence as it is written: (y [B, 1,
+    heads, d_head] float32 without the skip, the new state). One pass over
+    the state: elementwise in float32, the read-out a sum over d_state."""
+
+    def over_heads(a):  # -> broadcasts against [B, heads, d_head, d_state]
+        a = a[:, 0].astype(F32)
+        return a[:, :, None, :] if a.ndim == 3 else a[:, None, None, :]
+
+    x32 = xs[:, 0].astype(F32) * dt[:, 0, :, None]  # [B, nh, hd]
+    state = jnp.exp(dt[:, 0] * A)[..., None, None] * S_in \
+        + x32[..., None] * over_heads(Bm)
+    y = jnp.sum(state * over_heads(Cm), axis=-1)
+    return y[:, None], state

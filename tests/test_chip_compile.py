@@ -496,11 +496,11 @@ def test_latent_cache_is_row_major_and_never_copied(prog, topo, one_chip):
 # ---- the recurrent state of the Granite-4.0-H block (PR 32) -----------------
 
 
-def _state_program(topo, prog):
-    """``prog`` of the benchmark cell's own engine: the ten layers of
-    ``granite-4.0-h-small-ep2-l10`` at the published widths and 64 slots x
-    4096, compiled for one described chip (at three layers the compiler
-    left the state where it lay, pinned or not)."""
+def _cell_program(topo, prog, name):
+    """``prog`` of a benchmark cell's own engine: configuration ``name`` at
+    its published widths and its cell's slots and window, compiled for one
+    described chip (Granite's ten layers: at three the compiler left the
+    state where it lay, pinned or not)."""
     import json
 
     from picotron_tpu.config import Config
@@ -509,16 +509,18 @@ def _state_program(topo, prog):
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
-                           "granite-4.0-h-small-ep2-l10.json")) as f:
+                           name + ".json")) as f:
         pub = json.load(f)
     model = {k: pub[k] for k in pub["model_keys"]}
     model.update({k: pub[k] for k in (
         "num_attention_heads", "num_key_value_heads", "hidden_size",
         "intermediate_size", "vocab_size", "rms_norm_eps", "rope_theta",
         "max_position_embeddings", "num_hidden_layers")}, dtype="bfloat16")
-    cfg = Config.from_dict({"model": model, "training": {"seq_length": 4096}})
+    slots, window = pub["serve"]["slots"], pub["serve"]["max_seq_len"]
+    cfg = Config.from_dict({"model": model,
+                            "training": {"seq_length": window}})
     mesh = build_topology(1, 1, 1, 1, devices=topo.devices)
-    eng = InferenceEngine(cfg, mesh, slots=64, max_seq_len=4096)
+    eng = InferenceEngine(cfg, mesh, slots=slots, max_seq_len=window)
 
     def abstract(tree, specs):
         return jax.tree.map(
@@ -533,9 +535,10 @@ def _state_program(topo, prog):
     arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep)
     if prog == "decode_block":
         jitted = eng._program("decode_block")
-        args = (arg((64,), I32), arg((eng.decode_block_len, 2), jnp.uint32),
-                arg((64,), I32), arg((64,), I32), arg((64,), F32),
-                arg((64,), I32), arg((64,), F32))
+        args = (arg((slots,), I32),
+                arg((eng.decode_block_len, 2), jnp.uint32),
+                arg((slots,), I32), arg((slots,), I32), arg((slots,), F32),
+                arg((slots,), I32), arg((slots,), F32))
     else:
         jitted = eng._prefill_chunk_jit
         args = (arg((1, eng.prefill_chunk), I32),) + (arg((), I32),) * 3
@@ -550,7 +553,7 @@ def test_recurrent_state_is_row_major_and_never_copied(prog, topo, one_chip):
     all; ``kv_cache.row_major``): the leaf stays row-major, no instruction copies it, and
     K and V of the attention layer stay in place beside it; nor is a layer's
     slice of the experts' stacks copied out before the loop over experts."""
-    compiled = _state_program(topo, prog)
+    compiled = _cell_program(topo, prog, "granite-4.0-h-small-ep2-l10")
     text = compiled.as_text()
     lines = text.splitlines()
     state = r"f32\[9,64,128,64,128\]"
@@ -568,3 +571,35 @@ def test_recurrent_state_is_row_major_and_never_copied(prog, topo, one_chip):
               if re.search(r"= bf16\[(?:1,)?36,(?:4096,768|768,4096)\]", l)
               and " parameter(" not in l and "get-tuple-element" not in l]
     assert not sliced, "\n".join(sliced)
+
+
+# ---- the four leaves of the MiniCPM-SALA block (PR 34) ----------------------
+
+
+@pytest.mark.parametrize("prog", ["decode_block", "prefill_chunk"])
+def test_sala_cache_leaves_are_never_copied_whole(prog, topo, one_chip):
+    """K and V a kv head's keys one after the other ([layers, slots, kv
+    heads, T, d]): tokens-major, the decode block re-laid both leaves inside
+    its step loop (four copies of 0.8 GB a step) and a prefill chunk on
+    entry and exit (PR 34 read them here); left free, a chunk's contractions
+    still pull V tokens-minor (``kv_cache.row_major`` on the chunk's write).
+    No instruction copies K, V, the compressed keys or the float32 state,
+    and each stays row-major as it is resident."""
+    compiled = _cell_program(topo, prog, "minicpm-sala-l12")
+    lines = compiled.as_text().splitlines()
+    leaves = {"k": r"bf16\[3,8,2,65536,128\]", "v": r"bf16\[3,8,2,65536,128\]",
+              "kc": r"bf16\[3,8,2,4096,128\]",
+              "state": r"f32\[9,8,32,128,128\]"}
+    shapes = "|".join(sorted(set(leaves.values())))
+    copies = [l.strip()[:160] for l in lines
+              if re.search(rf"= (?:{shapes})\S* copy\(", l)]
+    assert not copies, "\n".join(copies)
+    for name, shape in leaves.items():
+        params = [l for l in lines
+                  if re.search(rf"cache__{name}__\S* = {shape}", l)
+                  and " parameter(" in l]
+        assert len(params) == 1 and "{4,3,2,1,0" in params[0], (name, params)
+    # resident: 7.86 GB of weights + 1.81 GB of cache; a decode block's
+    # temporaries read 0.92 GB (the lightning layers' q, k, v weights
+    # re-laid once a block), a chunk's 0.01
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
