@@ -305,7 +305,7 @@ def phase_kernels(rehearse: bool) -> dict:
     from picotron_tpu.ops.attention import block_attention, sdpa
     from picotron_tpu.ops.pallas import quant_matmul as qm
     from picotron_tpu.ops.pallas.decode_attention import (
-        flash_decode_attention)
+        flash_decode_attention, flash_decode_stacked)
     from picotron_tpu.ops.pallas.flash_attention import (
         flash_attention, flash_attention_with_lse, flash_block_grads)
     from picotron_tpu.ops.pallas.rmsnorm import rms_norm_pallas
@@ -429,6 +429,32 @@ def phase_kernels(rehearse: bool) -> dict:
                 qd, dk, dv, lens, scale))(qd, dk[:b], dv[:b], lens)
             close(f"decode attention {lname} {sname} vs dense", got, want,
                   TOL_FWD)
+
+    # --- the S == 1 step on the stacked leaf as ``attend_impl: auto`` runs
+    # it on a chip, at both pack factors: SmolLM's rows (two heads of 64)
+    # and Mistral's (a head of 128, four query heads each), a traced layer
+    layers = 2 if rehearse else 3
+    lens = jnp.asarray([T, T // 3 + 1, 0, T - 1, 3, T // 2, 8, T - 5][:slots],
+                       jnp.int32)
+    live = np.asarray(lens) > 0
+    for what, heads, kv_heads, d in (("two heads of 64 a row", 32, 32, 64),
+                                     ("a head of 128 a row", 32, 8, 128)):
+        if rehearse:
+            heads, kv_heads = heads // 4, kv_heads // 4
+        pk = kv_cache.pack_factor(d, kv_heads)
+        leaf = (layers, slots, T, kv_heads // pk, pk * d)
+        qd, kl, vl = rnd(11, (slots, 1, heads, d)), rnd(12, leaf), \
+            rnd(13, leaf)
+        got = jax.jit(lambda qd, kl, vl, lens, layer: flash_decode_stacked(
+            qd, kl, vl, lens, d ** -0.5, layer, interpret=interp))(
+                qd, kl, vl, lens, jnp.int32(layers - 1))
+        want = jax.jit(lambda qd, kl, vl, lens: kv_cache.decode_attention(
+            qd, kl[layers - 1], vl[layers - 1], lens, d ** -0.5))(
+                qd, kl, vl, lens)
+        close(f"decode attention stacked leaf, {what}, vs dense",
+              f32(got)[live], f32(want)[live], TOL_FWD)
+        if np.any(f32(got)[~live] != 0.0):
+            raise SystemExit("[kernels] a free slot's rows are not zeros")
 
     # --- int8 weight matmul vs its XLA twin, the three SmolLM projections
     for m_, k_, n_ in ((8, hid, 4 * hid), (8, 4 * hid, hid),

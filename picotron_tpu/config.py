@@ -491,6 +491,8 @@ class TenancyConfig:
     adapter_slots: int = 8
     adapter_rank: int = 16
 
+ATTEND_IMPLS = ("auto", "dense", "flash")  # inference.attend_impl
+
 
 @dataclass
 class InferenceConfig:
@@ -586,16 +588,23 @@ class InferenceConfig:
     # pow-2-bucketed one-shot prefill.
     prefill_chunk: int = 512
     # Which kernel serves KV-cache attention on the decode/verify/chunked-
-    # prefill hot path: "dense" = the masked einsum+softmax over the whole
-    # cache window (kv_cache.decode_attention — the bit-pinned reference,
-    # always the default); "flash" = the Pallas flash-decode kernel
-    # (ops/pallas/decode_attention.py) — online softmax over KV blocks
+    # prefill hot path. "auto" (the shipped default, as
+    # model.attention_impl: auto is for training): on a TPU the plain
+    # decode step (one query a slot against a contiguous bfloat16 cache of
+    # whole-lane rows) runs the stacked flash-decode kernel
+    # (ops/pallas/decode_attention.py::flash_decode_stacked: K and V read
+    # out of the stacked leaf where they lie, one pass, live rows only);
+    # every other call (prefill chunks, verify, the mixed lane, int8 and
+    # paged caches) and every call off a TPU runs "dense". "dense" = the
+    # masked einsum+softmax over the whole cache window everywhere
+    # (kv_cache.decode_attention, the bit-pinned reference); "flash" = the
+    # Pallas flash-decode kernels everywhere: online softmax over KV blocks
     # bounded by each slot's LIVE length, int8 K/V dequantized inside the
-    # kernel (no whole-cache fp32 materialization), GQA-native. On CPU the
-    # flash kernel runs in Pallas interpret mode (slow — a parity/test
-    # surface, not a serving one); allclose-pinned against dense in
+    # kernel (no whole-cache fp32 materialization), GQA-native. Off a TPU
+    # "flash" runs in Pallas interpret mode (slow: a parity/test surface,
+    # not a serving one); allclose-pinned against dense in
     # tests/test_decode_kernel.py.
-    attend_impl: str = "dense"
+    attend_impl: str = "auto"
     # Fused on-device sampling epilogue: the prefill / chunked-prefill /
     # decode_step dispatches sample their next token INSIDE the jitted
     # program (temperature -> top-k -> top-p -> categorical, the same
@@ -684,8 +693,9 @@ class InferenceConfig:
             known = {f.name for f in dataclasses.fields(TenancyConfig)}
             self.tenancy = TenancyConfig(
                 **{k: v for k, v in self.tenancy.items() if k in known})
-    # Graceful degradation for the flash attend path: when a
-    # attend_impl="flash" dispatch fails, log once, rebuild the engine's
+    # Graceful degradation for the flash attend path: when a dispatch
+    # fails under attend_impl "flash" (or "auto" on a TPU, where the plain
+    # decode step runs the kernel), log once, rebuild the engine's
     # compiled programs on "dense", and keep serving — for the REST OF THE
     # PROCESS (new engines start dense too; a kernel that broke once is
     # not re-trusted mid-serve). False = the failure propagates.
@@ -1265,10 +1275,10 @@ class Config:
                 f"inference.sample_on_device must be a JSON boolean "
                 f"(true/false), got {inf.sample_on_device!r} — quoted "
                 f"'true'/'false' strings are not parsed as booleans")
-        if inf.attend_impl not in ("dense", "flash"):
+        if inf.attend_impl not in ATTEND_IMPLS:
             raise ValueError(
                 f"unknown inference.attend_impl {inf.attend_impl!r} "
-                "(dense|flash)")
+                f"({'|'.join(ATTEND_IMPLS)})")
         if inf.spec_len < 0:
             raise ValueError("inference.spec_len must be >= 0 (0 = off)")
         if inf.spec_ngram < 1:
@@ -1437,7 +1447,7 @@ class Config:
                 f"{who} does not support speculation (inference.spec_len "
                 f"{inf.spec_len}): there is no verify program for this "
                 "block and the MTP module is cut with the depth")
-        if inf.attend_impl != "dense":
+        if inf.attend_impl == "flash":
             raise ValueError(
                 f"{who} does not support inference.attend_impl "
                 f"{inf.attend_impl!r}: the flash-decode kernel reads K/V "
@@ -1532,11 +1542,12 @@ class Config:
                 f"{who} does not support speculation (inference.spec_len "
                 f"{inf.spec_len}): a rejected draft cannot be rolled back "
                 "out of a recurrent state by rewinding a length")
-        if inf.attend_impl != "dense":
+        if inf.attend_impl == "flash":
             raise ValueError(
                 f"{who} does not support inference.attend_impl "
-                f"{inf.attend_impl!r}: the flash-decode kernel scales by "
-                "head_dim^-0.5, not by attention_multiplier")
+                f"{inf.attend_impl!r}: the recurrent state has no "
+                "kernel, and forcing one for the attention layer's prefill "
+                "chunks is untested ('auto' runs it for the decode step)")
         if inf.overlap or inf.mixed_dispatch or inf.key_schedule == "slot" \
                 or inf.dp_size > 1:
             raise ValueError(
@@ -1631,7 +1642,7 @@ class Config:
                 f"{who} does not support speculation (inference.spec_len "
                 f"{inf.spec_len}): a rejected draft cannot be rolled back "
                 "out of a recurrent state by rewinding a length")
-        if inf.attend_impl != "dense":
+        if inf.attend_impl == "flash":
             raise ValueError(
                 f"{who} does not support inference.attend_impl "
                 f"{inf.attend_impl!r}: the flash-decode kernel reads every "

@@ -63,20 +63,27 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from picotron_tpu import comm_trace, models
-from picotron_tpu.config import Config
+from picotron_tpu.config import ATTEND_IMPLS, Config
 from picotron_tpu.inference import kv_cache, paged_kv, sampling
 from picotron_tpu.obs import Obs
 from picotron_tpu.models import llama
 from picotron_tpu.ops.rope import rope_at_positions
 from picotron_tpu.parallel.tp import tp_gather
 from picotron_tpu.topology import Topology, build_topology, named_shardings
-from picotron_tpu.utils import log0, shard_map
+from picotron_tpu.utils import log0, on_tpu, shard_map
 
 # Process-wide graceful-degradation latch (inference.attend_fallback): once
 # a flash dispatch has failed, every engine in this process — current and
 # future — serves on "dense". A kernel that broke once is not re-trusted
 # mid-serve; restarting the process is the way to re-arm flash.
 _FLASH_BROKEN = False
+
+
+def _runs_flash(impl: str) -> bool:
+    """Whether ``attend_impl`` puts a flash-decode kernel on some path of
+    this process: "flash" everywhere, "auto" on a TPU (off it "auto" is
+    "dense": nothing to degrade from)."""
+    return impl == "flash" or (impl == "auto" and on_tpu())
 
 
 def _key_chain(key, block: int):
@@ -134,7 +141,9 @@ class InferenceEngine:
     ``attend_impl`` default from ``cfg.inference`` (config.InferenceConfig);
     keyword overrides win. ``attend_impl="flash"`` routes every cache
     attend (decode, verify, chunked prefill) through the length-aware
-    Pallas flash-decode kernel instead of the dense whole-window einsum.
+    Pallas flash-decode kernels instead of the dense whole-window einsum;
+    the default ``"auto"`` does so for the plain decode step on a TPU
+    (``kv_cache.attend``).
     """
 
     def __init__(self, cfg: Config, topo: Optional[Topology] = None, *,
@@ -231,21 +240,25 @@ class InferenceEngine:
                              and self.drafter_kind == "learned")
         self.return_hidden = bool(return_hidden)
         # KV-cache attention kernel for decode/verify/chunked prefill:
-        # "dense" (whole-window reference) or "flash" (length-aware Pallas
-        # flash decode). A Python-level choice, so every jitted program
-        # below traces the selected kernel statically — no runtime branch,
-        # one executable per impl. The override lands in self.cfg BEFORE
-        # the jit wrappers close over it.
+        # "auto" (the flash-decode kernel for the plain decode step on a
+        # TPU, dense elsewhere: kv_cache.attend), "dense" (whole-window
+        # reference) or "flash" (the Pallas kernels everywhere). A
+        # Python-level choice, so every jitted program below traces the
+        # selected kernel statically — no runtime branch, one executable
+        # per impl. The override lands in self.cfg BEFORE the jit wrappers
+        # close over it.
         if attend_impl is not None:
-            if attend_impl not in ("dense", "flash"):
+            if attend_impl not in ATTEND_IMPLS:
                 raise ValueError(
-                    f"unknown attend_impl {attend_impl!r} (dense|flash)")
+                    f"unknown attend_impl {attend_impl!r} "
+                    f"({'|'.join(ATTEND_IMPLS)})")
             inf.attend_impl = attend_impl
-        if (inf.attend_impl == "flash" and inf.attend_fallback
+        if (_runs_flash(inf.attend_impl) and inf.attend_fallback
                 and _FLASH_BROKEN):
             # the process-wide degradation latch: flash already failed here
-            log0("attend_impl 'flash' already failed in this process; "
-                 "this engine starts on 'dense' (inference.attend_fallback)")
+            log0(f"attend_impl {inf.attend_impl!r}: the flash kernel "
+                 "already failed in this process; this engine starts on "
+                 "'dense' (inference.attend_fallback)")
             inf.attend_impl = "dense"
         self.attend_impl = inf.attend_impl
         # Fused on-device sampling epilogue: prefill/chunked-prefill/
@@ -724,13 +737,13 @@ class InferenceEngine:
         """Degrade flash->dense after a failed dispatch: latch the process
         flag, log once, rebuild the compiled programs on dense. Returns
         whether the caller should re-dispatch."""
-        if (self.attend_impl != "flash"
-                or not self.cfg.inference.attend_fallback):
+        impl = self.attend_impl
+        if not (_runs_flash(impl) and self.cfg.inference.attend_fallback):
             return False
         global _FLASH_BROKEN
         if not _FLASH_BROKEN:
             _FLASH_BROKEN = True
-            log0(f"attend_impl 'flash' failed at dispatch "
+            log0(f"attend_impl {impl!r} failed at dispatch "
                  f"({type(exc).__name__}: {exc}); falling back to 'dense' "
                  f"for the rest of the process", flush=True)
         self.attend_impl = self.cfg.inference.attend_impl = "dense"

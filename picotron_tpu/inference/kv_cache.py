@@ -68,11 +68,13 @@ the compiler makes of it, as of Mistral's heads of 128: a fusion slices
 the layer of K, 33.5 MB at SmolLM's 4 x 2048, into on-chip memory at HBM
 speed, and the contraction's fusion re-lays it head-major from there; the
 two run one after the other, 42 + 37 us on a v5e, PERF.md PR 31, where one
-pass at HBM speed would be 42.) The pack
+pass at HBM speed would be 42: on a TPU the plain decode step therefore
+runs ``ops/pallas/decode_attention.py::flash_decode_stacked`` over the
+stacked leaf, rows whole, one pass, live rows only: ``attend``.) The pack
 factor of a leaf is read off its own shape (``leaf.shape[-1] //
 head_dim``); this module alone knows the layout, and whatever needs
-``[.., n_kv_heads, head_dim]`` (the flash decode kernel, the int8 scales)
-takes ``unpack_heads``. With ``p == 1`` every function here runs the
+``[.., n_kv_heads, head_dim]`` (the sliced flash decode kernel, the int8
+scales) takes ``unpack_heads``. With ``p == 1`` every function here runs the
 operations it ran before the packed row existed. The paged pool
 (paged_kv.py) is not packed.
 """
@@ -87,6 +89,7 @@ from jax.sharding import PartitionSpec as P
 
 from picotron_tpu.config import ModelConfig
 from picotron_tpu.ops.attention import NEG_INF
+from picotron_tpu.utils import on_tpu
 
 # int8 symmetric range; scales are stored in fp32 so dequantization is one
 # multiply with no double-rounding
@@ -367,6 +370,20 @@ def layer_block(cache: dict, name: str, layer):
     return leaf
 
 
+def plain_decode(q: jnp.ndarray, cache: dict) -> bool:
+    """Whether an ``attend`` call is the plain decode shape the stacked
+    flash-decode kernel serves: one fresh query a slot against a
+    contiguous bfloat16 cache of whole-lane rows, every slot at its own
+    length, with no addressing entry spliced in (``slot`` / ``gate``: one
+    slot's block; ``draft_valid``: a ragged verify)."""
+    k = cache.get("k")
+    return (q.shape[1] == 1 and k is not None and k.ndim == 5
+            and "block_tables" not in cache and not quantized(cache)
+            and k.dtype == q.dtype == jnp.bfloat16
+            and k.shape[-1] % LANE == 0
+            and not any(n in cache for n in ("slot", "gate", "draft_valid")))
+
+
 def attend(q: jnp.ndarray, cache: dict, lengths: jnp.ndarray,
            scale: float, layer, impl: str = "dense") -> jnp.ndarray:
     """Masked attention of S fresh queries against ``layer`` of the
@@ -374,30 +391,45 @@ def attend(q: jnp.ndarray, cache: dict, lengths: jnp.ndarray,
 
     ``impl`` picks the kernel (config ``inference.attend_impl``):
 
-    - "dense" (default): ``decode_attention`` over the whole cache window,
-      int8 storage first dequantized to a whole-block fp32 copy (the
-      bit-pinned reference path);
-    - "flash": the Pallas flash-decode kernel
-      (ops/pallas/decode_attention.py) — KV blocks are read only up to
-      each slot's live length with DOUBLE-BUFFERED DMA (block j+1's copy
-      commits while block j's dots run), int8 bytes + per-row scales
-      travel to the kernel as stored and dequantize in registers: no
-      whole-cache fp32 materialization ever exists on this path. Wide
-      chunked-prefill query windows split over a q-block grid axis
-      (flash_attention's causal block-skip bounds each tile's walk).
-      Runs in interpret mode off TPU; allclose-pinned against dense
-      (tests/test_decode_kernel.py). The kernel takes one layer's block,
-      so this path hands it the sliced layer.
+    - "auto" (the shipped default): on a TPU the plain decode shape
+      (``plain_decode``) runs the stacked flash-decode kernel; every other
+      call (prefill chunks, verify, the mixed lane, int8 and paged caches)
+      and EVERY call off a TPU runs "dense". The choice is made at trace
+      time from the call's shapes and the backend: no knob to set;
+    - "dense": ``decode_attention`` over the whole cache window, int8
+      storage first dequantized to a whole-block fp32 copy (the bit-pinned
+      reference path);
+    - "flash": the Pallas flash-decode kernels everywhere
+      (ops/pallas/decode_attention.py; interpret mode off a TPU: the
+      parity surface of tests/test_decode_kernel.py, ``chip_smoke.py`` and
+      the described-chip compiles). The plain decode shape takes
+      ``flash_decode_stacked``, as under "auto": K and V are read out of
+      the stacked leaf where they lie, packed rows whole, one pass, live
+      rows only. The other shapes take ``flash_decode_attention`` on the
+      sliced layer, a head a row: KV blocks read only up to each slot's
+      live length, int8 bytes + per-row scales travel to the kernel as
+      stored and dequantize in registers, wide chunked-prefill query
+      windows split over a q-block grid axis.
 
     Paged caches (the dict carries ``block_tables``) route to the
     page-indirect attends (inference/paged_kv.py): dense gathers the
     slots' pages into a contiguous window and runs the same masked
     einsum; flash walks the block table page by page in the kernel.
     """
+    plain = plain_decode(q, cache)
+    if impl == "auto":
+        impl = "flash" if plain and on_tpu() else "dense"
     if "block_tables" in cache:
         from picotron_tpu.inference import paged_kv
 
         return paged_kv.attend(q, cache, lengths, scale, layer, impl)
+    if impl == "flash" and plain:
+        from picotron_tpu.ops.pallas.decode_attention import (
+            flash_decode_stacked,
+        )
+
+        return flash_decode_stacked(q, cache["k"], cache["v"], lengths,
+                                    scale, layer, interpret=not on_tpu())
     k, v, k_scale, v_scale = (layer_block(cache, n, layer)
                               for n in ("k", "v", "k_scale", "v_scale"))
     D = q.shape[-1]
@@ -407,14 +439,13 @@ def attend(q: jnp.ndarray, cache: dict, lengths: jnp.ndarray,
         from picotron_tpu.ops.pallas.decode_attention import (
             flash_decode_attention,
         )
-        from picotron_tpu.utils import on_tpu
 
         return flash_decode_attention(
             q, k, v, lengths, scale, k_scale=k_scale, v_scale=v_scale,
             interpret=not on_tpu())
     if impl != "dense":
         # a typo'd impl must not silently measure the wrong kernel
-        raise ValueError(f"unknown attend impl {impl!r} (dense|flash)")
+        raise ValueError(f"unknown attend impl {impl!r} (auto|dense|flash)")
     if quantized(cache):
         # a scale a head: dequantize a head a row, hand the rows on packed
         k, v = (pack_heads(dequantize_kv(unpack_heads(x, D), s, jnp.float32),

@@ -459,7 +459,7 @@ def attention_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
                 {n: c for n, c in cache.items()
                  if n not in ("live", "active")}, k, v, pos, row)
             a = kv_cache.attend(q, out, pos + S, m.attention_multiplier,
-                                row, impl="dense")
+                                row, impl=cfg.inference.attend_impl)
         a = a.reshape(B, S, -1) @ lp["wo"]
     h = h + jnp.asarray(m.residual_multiplier, h.dtype) * a
     zero = jnp.zeros((), jnp.int32)

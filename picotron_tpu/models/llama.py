@@ -455,9 +455,9 @@ def _attention(q, k, v, cfg: Config, cache=None, pos=None, layer=None):
     [L, B, max_len, n_kv_local, ...] with compact GQA heads, never
     repeated), ``layer`` the index of the layer to read and ``pos`` [B] the
     first index just written per sequence; the ``k``/``v`` positional args
-    are ignored. The decode kernel is a masked dot product over the
-    layer's block, read where it lies (inference/kv_cache.py) — flash
-    brings nothing at query length 1.
+    are ignored. ``kv_cache.attend`` reads the layer where it lies: a
+    masked dot product over its block, or on a TPU the flash-decode kernel
+    over the stacked leaf for the plain decode step.
     """
     scale = 1.0 / math.sqrt(cfg.model.head_dim)
     if cache is not None:
@@ -465,11 +465,13 @@ def _attention(q, k, v, cfg: Config, cache=None, pos=None, layer=None):
 
         # S queries starting at per-sequence write index ``pos``: the valid
         # key count is pos + S (S == 1 decode, S > 1 chunked prefill or
-        # speculative verify). ``inference.attend_impl`` picks the kernel —
-        # the dense whole-window reference or the length-aware Pallas flash
-        # decode (which reads int8 blocks as stored; the dense path
-        # dequantizes whole blocks on the fly). The impl string is a Python
-        # value, so each choice traces its own program under jit.
+        # speculative verify). ``inference.attend_impl`` picks the kernel:
+        # "auto" (on a TPU the flash-decode kernel for the plain decode
+        # step, dense elsewhere), the dense whole-window reference, or the
+        # length-aware Pallas kernels everywhere (which read int8 blocks as
+        # stored; the dense path dequantizes whole blocks on the fly). The
+        # impl string is a Python value, so each choice traces its own
+        # program under jit.
         return attend(q, cache, pos + q.shape[1], scale,
                       impl=cfg.inference.attend_impl, layer=layer)
     impl = cfg.model.attention_impl

@@ -59,8 +59,18 @@ compiled against the dense oracle).
 Block fetches are the Pallas pipeline's own double-buffered DMA: there is
 no hand-written copy or semaphore in this file. On CPU the kernel runs in
 Pallas interpret mode (``interpret=True``), which is how the parity suite
-and the tier-1 gate exercise it. Dense remains the serving default
-(``inference.attend_impl``) until the kernel is A/B'd on a chip.
+and the tier-1 gate exercise it.
+
+``flash_decode_stacked`` (PR 36, at the end of the file) is the kernel the
+shipped default ``inference.attend_impl: auto`` runs on a TPU for the plain
+decode step (``S == 1``, a contiguous bfloat16 cache of whole-lane rows): it
+takes the STACKED leaves and a layer index, so nothing is sliced or unpacked
+in front of it, contracts a block's (token, cache row) pairs against every
+query head in one matmul, and walks live rows only. A/B'd on the chip in
+``smollm-1.7b.serve-batch``: the decode step 9.5 -> 6.2 ms (PERF.md, PR 36).
+``flash_decode_attention`` keeps every other shape under ``attend_impl:
+flash`` (verify, prefill chunks, int8, paged) and takes one layer's sliced
+block, a head a row; ``dense`` serves those under ``auto``.
 
 ``block_tables`` switches to the PAGED layout (one block per pool page,
 the page id read from the block table in the index map);
@@ -91,7 +101,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from picotron_tpu.ops.attention import NEG_INF
 from picotron_tpu.ops.pallas.flash_attention import (
+    _dot_nt,
     _pick_block,
+    _scale_folds,
     causal_kv_blocks,
 )
 
@@ -412,3 +424,170 @@ def flash_decode_attention(q, k, v, lengths, scale, *,
     return (out[:, :, :sg]
             .reshape(B, nkv, S, g, D).swapaxes(1, 2)
             .reshape(B, S, nh, D))
+
+
+# --------------------------------------------------------------------------- #
+# the plain decode step: K and V read out of the stacked leaf, where they lie
+# --------------------------------------------------------------------------- #
+
+# one K or V block of the stacked kernel (x2 operands x2 pipeline buffers:
+# a quarter of the 16 MB scoped VMEM, beside a [heads, block] fp32 score tile)
+_STACKED_KV_BLOCK = 1024 * 1024
+_BF16_ROWS = 16  # rows of a bf16 register tile: what the query rows pad to
+
+
+def _stacked_blocks(L, block_t, max_nb):
+    """KV blocks a slot with ``L`` live tokens walks at ``S == 1``
+    (``_visible_blocks`` for one query row at position ``L - 1``)."""
+    return _visible_blocks(L, 0, S=1, g=1, rq=1, block_t=block_t,
+                           max_nb=max_nb)
+
+
+def _stacked_kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
+                    acc_ref, m_ref, l_ref, own_ref, *, scale, block_t, rows,
+                    pg, max_nb):
+    """One (slot, kv-block) grid step of ``flash_decode_stacked``. The K
+    block is ``block_t`` tokens of one layer and slot as a plain matrix:
+    ``[block_t * rows, lanes]``, a row of it one (token, cache row) pair,
+    every cache row of a token one after the other as the leaf holds them.
+    ALL query heads meet ALL of those pairs in one NT matmul
+    (``[heads, lanes] . [block_t * rows, lanes]^T``: K and V are the MXU's
+    resident operand exactly as they arrive, no slice, no relayout), and a
+    query head keeps the pairs of its own cache row (``own_ref``: the
+    column's token offset where the rows match, past every bound where
+    they do not). The other rows' products are exact discards: masked to
+    NEG_INF before the softmax, an exact zero in the value matmul."""
+    del layer_ref  # consumed by the index maps
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    L = len_ref[b]
+    nb = _stacked_blocks(L, block_t, max_nb)
+    nq, cols = own_ref.shape
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        head_row = lax.broadcasted_iota(jnp.int32, (nq, cols), 0) // pg
+        col = lax.broadcasted_iota(jnp.int32, (nq, cols), 1)
+        own_ref[...] = jnp.where(col % rows == head_row, col // rows,
+                                 jnp.iinfo(jnp.int32).max)
+
+    @pl.when(j < nb)
+    def _():
+        s = _dot_nt(q_ref[...], k_ref[...])
+        if scale is not None:
+            s = s * scale
+        # token j * block_t + own is visible iff it is below the length
+        s = jnp.where(own_ref[...] < L - j * block_t, s, NEG_INF)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        # every live block holds a visible key for every head (token
+        # j * block_t of its own row), so m_new is finite and a masked
+        # score's exp underflows to an exact zero
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_new
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[...],
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == max_nb - 1)
+    def _():
+        l = l_ref[...]
+        out = acc_ref[...] / jnp.where(l > 0, l, 1.0)
+        o_ref[...] = jnp.where(l > 0, out, 0.0).astype(o_ref.dtype)
+
+
+def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
+                         block_t: int | None = None,
+                         interpret: bool = False):
+    """The ``S == 1`` decode attend of a contiguous, unquantized cache,
+    reading layer ``layer`` of the STACKED leaves in place: one pass over
+    K and V, live rows only.
+
+    q: [B, 1, n_heads, D]; k/v: the carried leaves ``[L, B, T, rows,
+    p * D]`` as ``kv_cache`` lays them out (``p`` kv heads side by side in
+    a row of whole lanes; ``p == 1``: a head a row); lengths: [B] int32
+    valid-key counts (the query sits at ``lengths - 1``); ``layer`` a
+    traced or static index. Returns [B, 1, n_heads, D] in q.dtype,
+    allclose to ``kv_cache.decode_attention`` on the layer's block; a slot
+    with ``lengths == 0`` returns ZEROS (module docstring).
+
+    ``layer`` and ``lengths`` are scalar-prefetch operands and the K/V
+    index maps return ``(layer, b, walk(j), 0)`` into the leaf viewed as
+    ``[L, B, T * rows, p * D]`` (the same bytes: tokens and rows merge
+    into one major axis, which the compiled program shows as a bitcast):
+    no layer is sliced out, no head unpacked, no copy stands in front of
+    the custom call. A slot walks ``ceil(lengths[b] / block_t)`` blocks;
+    the steps past its walk repeat the last block index (no DMA) and skip
+    their compute; a free slot costs its block 0.
+
+    Packed rows are contracted whole, as ``kv_cache.decode_attention``
+    does it: the ``p * g`` query heads of a cache row each sit in their
+    own head's lanes, exact zeros in the others, and of the ``p * D``
+    lanes the value matmul returns each keeps its own (``_own_lanes``).
+    The kernel itself sees ``rows`` heads of ``p * D`` lanes with ``p * g``
+    query rows each and has no notion of packing. Scores, softmax
+    statistics and the accumulator are fp32; K meets q in the leaf's dtype
+    and the probabilities are rounded to it for the value matmul (what the
+    chip's default matmul precision makes of the dense path's fp32
+    probabilities)."""
+    from picotron_tpu.inference.kv_cache import _own_lanes, _own_lanes_only
+
+    B, S, nh, D = q.shape
+    if S != 1:
+        raise ValueError(f"flash_decode_stacked is the S == 1 step, got {S}")
+    nl, _, T, rows, lanes = k.shape
+    pack = lanes // D
+    if nh % rows or lanes != pack * D:
+        raise ValueError(
+            f"{nh} query heads of {D} against rows {rows} x {lanes} lanes")
+    if q.dtype != k.dtype:
+        raise ValueError(f"q is {q.dtype}, the cache leaves {k.dtype}")
+    pg = nh // rows  # query heads a cache row: p * (heads a kv head)
+    qg = q.reshape(B, rows, pg, D)
+    if pack > 1:
+        qg = _own_lanes_only(qg, pack)
+    qm = qg.reshape(B, nh, lanes)
+    if _scale_folds(scale):
+        qm, scale = qm * jnp.asarray(scale, qm.dtype), None
+    nq = -(-nh // _BF16_ROWS) * _BF16_ROWS
+    if nq != nh:  # pad rows own no cache row: all masked, sliced off below
+        qm = jnp.pad(qm, ((0, 0), (0, nq - nh), (0, 0)))
+    bt = _pick_block(T, block_t or max(
+        _SUBLANE, _STACKED_KV_BLOCK // (rows * lanes * k.dtype.itemsize)))
+    cols, max_nb = bt * rows, T // bt
+    merged = (nl, B, T * rows, lanes)
+
+    def kv_index(b, j, len_ref, layer_ref):
+        nb = _stacked_blocks(len_ref[b], bt, max_nb)
+        return (layer_ref[0], b, jnp.maximum(jnp.minimum(j, nb - 1), 0), 0)
+
+    q_spec = pl.BlockSpec((None, nq, lanes), lambda b, j, *_: (b, 0, 0))
+    kv_spec = pl.BlockSpec((None, None, cols, lanes), kv_index)
+    out = pl.pallas_call(
+        functools.partial(_stacked_kernel, scale=scale, block_t=bt,
+                          rows=rows, pg=pg, max_nb=max_nb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, max_nb),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((nq, lanes), jnp.float32),
+                            pltpu.VMEM((nq, 1), jnp.float32),
+                            pltpu.VMEM((nq, 1), jnp.float32),
+                            pltpu.VMEM((nq, cols), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((B, nq, lanes), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="flash_decode_attention",
+    )(lengths.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      qm, k.reshape(merged), v.reshape(merged))
+    out = out[:, :nh].reshape(B, 1, rows, pg, lanes)
+    if pack > 1:
+        out = _own_lanes(out, pack)
+    return out.reshape(B, 1, nh, D)

@@ -28,7 +28,10 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from picotron_tpu.ops.pallas import quant_matmul as qm
-from picotron_tpu.ops.pallas.decode_attention import flash_decode_attention
+from picotron_tpu.ops.pallas.decode_attention import (
+    flash_decode_attention,
+    flash_decode_stacked,
+)
 from picotron_tpu.ops.pallas.flash_attention import (
     DEFAULT_BLOCK_K,
     DEFAULT_BLOCK_Q,
@@ -167,6 +170,22 @@ def _decode(layout, b, s):
          tables, tables, lens]
 
 
+# the carried K/V leaves of the two Llama serving cells (BENCHMARK.json):
+# (layers, slots, window, rows, lanes), query heads, head size
+STACKED_LEAVES = {"smollm": ((24, 4, 2048, 16, 128), 32, 64),
+                  "mistral": ((16, 8, 2048, 8, 128), 32, 128)}
+
+
+def _decode_stacked(cell):
+    """flash_decode_stacked, the ``S == 1`` step's kernel, on a cell's own
+    stacked leaf with a traced layer index."""
+    leaf, heads, d = STACKED_LEAVES[cell]
+    return (lambda q, k, v, n, layer: flash_decode_stacked(
+        q, k, v, n, d ** -0.5, layer)), \
+        [((leaf[1], 1, heads, d), BF16), (leaf, BF16), (leaf, BF16),
+         ((leaf[1],), I32), ((), I32)]
+
+
 def _quant(m, k, n):
     return (lambda x, q, s: qm.quant_matmul_pallas(x, q, s)), \
         [((m, k), BF16), ((k, n), I8), ((n,), F32)]
@@ -188,6 +207,8 @@ CASES = {
        (lambda layout=layout, b=b, s=s: _decode(layout, b, s))
        for layout in ("contiguous", "int8", "paged", "hot_bf16")
        for name, (b, s) in DECODE_SHAPES.items()},
+    **{f"decode_stacked_{cell}": (lambda cell=cell: _decode_stacked(cell))
+       for cell in STACKED_LEAVES},
     "quant_matmul_up": lambda: _quant(8, HID, FFN),
     "quant_matmul_down": lambda: _quant(8, FFN, HID),
     "quant_matmul_head": lambda: _quant(8, HID, VOCAB),
@@ -332,19 +353,28 @@ GEOMETRY = {"smollm": (HID, HEADS, HEADS, FFN, VOCAB),
 def _serving_program(topo, prog, layout, geometry="smollm"):
     """(lowered-and-compiled ``prog`` of a SERVE_LAYERS-layer engine at
     one of ``GEOMETRY``'s head geometries on one described chip, the
-    abstract cache it was compiled for)."""
+    abstract cache it was compiled for). Layout "kernel" is the contiguous
+    cache under ``attend_impl: flash`` (the caller steers ``on_tpu``, so
+    the kernels are compiled and not their interpreter), with a head of
+    1,024 rows: sampling over the whole vocabulary is four fifths of a
+    decode block's compile time and no part of what is read here."""
     from picotron_tpu.config import Config
     from picotron_tpu.inference.engine import InferenceEngine
     from picotron_tpu.models import llama
     from picotron_tpu.topology import build_topology, named_shardings
 
     hid, heads, kv_heads, ffn, vocab = GEOMETRY[geometry]
+    if layout == "kernel":
+        vocab = 1024
     cfg = Config.from_dict({
         "model": dict(hidden_size=hid, intermediate_size=ffn,
                       num_attention_heads=heads, num_key_value_heads=kv_heads,
                       vocab_size=vocab, num_hidden_layers=SERVE_LAYERS,
                       max_position_embeddings=SEQ, dtype="bfloat16"),
-        "inference": {"kv_layout": layout, "kv_page_len": PAGE}})
+        "inference": {"kv_layout": "paged" if layout == "paged"
+                      else "contiguous", "kv_page_len": PAGE,
+                      "attend_impl": "flash" if layout == "kernel"
+                      else "dense"}})
     mesh = build_topology(1, 1, 1, 1, devices=topo.devices)
     eng = InferenceEngine(cfg, mesh, slots=SERVE_SLOTS, max_seq_len=SEQ)
 
@@ -377,18 +407,30 @@ def _serving_program(topo, prog, layout, geometry="smollm"):
     ("decode_block", "contiguous", "smollm"),
     ("prefill_chunk", "contiguous", "smollm"),
     ("decode_block", "contiguous", "mistral"),
+    ("decode_block", "kernel", "smollm"), ("decode_block", "kernel", "mistral"),
     ("decode_block", "paged", "smollm"), ("prefill_chunk", "paged", "smollm")])
 def test_serving_program_leaves_cache_in_place(prog, layout, geometry, topo,
-                                               one_chip):
+                                               one_chip, monkeypatch):
     """No loop of the program moves a layer of K or V through HBM, and the
     temporaries stay small. The contiguous cache besides lies row-major,
     whole lanes a row, and no instruction copies a leaf of it: heads of 64
     two to a row (with a head a row the resident leaf was laid out tokens
     minor-most and converted on the program's entry and exit: ``copy.18`` /
     ``.19`` / ``.25`` / ``.26`` of PR 30's trace, outside every loop), heads
-    of 128 one (``pack_factor`` 1: the leaf and the path they always had)."""
+    of 128 one (``pack_factor`` 1: the leaf and the path they always had).
+    With the kernel forced ("kernel": what ``attend_impl: auto`` runs on a
+    TPU) the decode step hands the stacked leaves to ``flash_decode_stacked``
+    as they lie: one custom call a layer, and nothing in front of it that
+    slices a layer out or unpacks a row."""
+    if layout == "kernel":
+        from picotron_tpu.inference import kv_cache
+
+        monkeypatch.setattr(kv_cache, "on_tpu", lambda: True)
     compiled, cache = _serving_program(topo, prog, layout, geometry)
     text, kv = compiled.as_text(), cache["k"].shape
+    if layout == "kernel":
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == 1, \
+            "the decode block's layer loop holds one flash-decode kernel"
     movers = _cache_movers(text, kv)
     assert not movers, (
         f"{prog}/{layout}: a loop of the compiled program moves a whole "

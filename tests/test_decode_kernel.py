@@ -34,6 +34,7 @@ from picotron_tpu.models import llama
 from picotron_tpu.ops.pallas.decode_attention import (
     _pick_block_t,
     flash_decode_attention,
+    flash_decode_stacked,
 )
 
 MAX_LEN = 96
@@ -476,3 +477,163 @@ def test_attend_impl_validated(tiny_model_kwargs):
     with pytest.raises(ValueError, match="attend impl"):
         kv_cache.attend(q, cache, jnp.ones(1, jnp.int32), 0.5, 0,
                         impl="Flash")
+
+
+# --------------------------------------------------------------------------- #
+# the plain decode step on the stacked, packed leaf (flash_decode_stacked)
+# --------------------------------------------------------------------------- #
+
+ST_LAYERS, ST_T, ST_ROWS, ST_BLOCK = 3, 32, 2, 8
+# a free slot, one token, a block's edge on either side, the whole window
+ST_LENGTHS = [0, 1, ST_BLOCK, ST_BLOCK + 1, ST_T]
+
+
+def _stacked(rng, p, g):
+    """(q [B, 1, heads, D], the K and V leaves [L, B, T, rows, 128]) in
+    bfloat16: ``p`` kv heads of ``128 / p`` side by side in a row, ``g``
+    query heads a kv head."""
+    D, B = 128 // p, len(ST_LENGTHS)
+    leaf = (ST_LAYERS, B, ST_T, ST_ROWS, 128)
+    q = rng.normal(size=(B, 1, ST_ROWS * p * g, D))
+    k, v = rng.normal(size=leaf), rng.normal(size=leaf)
+    return tuple(jnp.asarray(x, jnp.bfloat16) for x in (q, k, v))
+
+
+def _stacked_jit(scale):
+    """One compile a (shape, scale): the layer index is traced."""
+    return jax.jit(lambda q, k, v, n, layer: flash_decode_stacked(
+        q, k, v, n, scale, layer, block_t=ST_BLOCK, interpret=True))
+
+
+# SmolLM's rows (two heads of 64, MHA), Mistral's (a head of 128, four query
+# heads each), the two mixed, and Granite's: Mistral's rows under a scale
+# that is not ``D ** -0.5`` (a power of two: folded into q)
+@pytest.mark.parametrize("p,g,scale", [
+    (2, 1, None), (1, 4, None), (1, 1, None), (2, 4, None),
+    (1, 4, 1.0 / 128)])
+def test_stacked_kernel_matches_dense(p, g, scale):
+    """The new entry point against ``decode_attention`` on the layer's
+    block: packed and plain rows, MHA and GQA, the first and the last layer
+    of the leaf through a TRACED index, lengths from a free slot to the
+    whole window over a walk of four blocks."""
+    q, k, v = _stacked(np.random.default_rng(10 * p + g), p, g)
+    lengths = jnp.asarray(ST_LENGTHS, jnp.int32)
+    scale = scale or q.shape[-1] ** -0.5
+    kernel = _stacked_jit(scale)
+    dense = jax.jit(lambda q, k, v, n, layer: decode_attention(
+        q, k[layer], v[layer], n, scale))
+    for layer in (0, ST_LAYERS - 1):
+        got = kernel(q, k, v, lengths, jnp.int32(layer))
+        want = dense(q, k, v, lengths, jnp.int32(layer))
+        assert got.shape == want.shape and got.dtype == want.dtype
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        np.testing.assert_allclose(got[1:], want[1:], rtol=2e-2, atol=2e-2)
+        # the free slot: zeros, where dense emits an average nothing reads
+        assert np.all(got[0] == 0.0) and np.any(want[0] != 0.0)
+    # the layers differ, so a kernel that read the wrong one would show
+    assert np.abs(got[1:] - np.asarray(
+        kernel(q, k, v, lengths, jnp.int32(0)), np.float32)[1:]).max() > 0.1
+
+
+def test_stacked_kernel_ignores_stale_rows_and_other_layers():
+    """Rows at and past a slot's length, and every other layer of the
+    leaf, may hold anything: the output does not move."""
+    q, k, v = _stacked(np.random.default_rng(21), 2, 1)
+    lengths = jnp.asarray([0, 3, 8, 9, 20], jnp.int32)
+    run = lambda k, v: np.asarray(_stacked_jit(0.125)(
+        q, k, v, lengths, jnp.int32(1)), np.float32)
+    stale = jnp.arange(ST_T)[None, :, None, None] >= lengths[:, None, None,
+                                                            None]
+    junk = lambda x: jnp.where(stale[None], 1e4, x).at[0].set(-1e4).at[
+        2].set(1e4).astype(x.dtype)
+    np.testing.assert_array_equal(run(k, v), run(junk(k), junk(v)))
+
+
+def _routes(monkeypatch, on_tpu):
+    """Stand-ins for the three places ``attend`` can send a call, each
+    returning its own name, with ``on_tpu`` as given."""
+    import picotron_tpu.ops.pallas.decode_attention as da
+    from picotron_tpu.inference import paged_kv
+
+    monkeypatch.setattr(da, "flash_decode_stacked",
+                        lambda *a, **kw: "stacked")
+    monkeypatch.setattr(da, "flash_decode_attention",
+                        lambda *a, **kw: "sliced")
+    monkeypatch.setattr(kv_cache, "decode_attention", lambda *a: "dense")
+    monkeypatch.setattr(paged_kv, "attend", lambda *a: "paged " + a[-1])
+    monkeypatch.setattr(kv_cache, "on_tpu", lambda: on_tpu)
+
+
+def _plain_call():
+    q, k, v = _stacked(np.random.default_rng(7), 2, 1)
+    return q, {"k": k, "v": v}, jnp.asarray(ST_LENGTHS, jnp.int32)
+
+
+@pytest.mark.parametrize("impl,on_tpu,route", [
+    ("auto", False, "dense"), ("auto", True, "stacked"),
+    ("dense", True, "dense"), ("flash", False, "stacked"),
+    ("flash", True, "stacked")])
+def test_attend_routes_the_plain_decode_shape(impl, on_tpu, route,
+                                              monkeypatch):
+    """``auto``: dense off a TPU (no tier-1 test that serves on the CPU
+    starts interpreting Pallas), the stacked kernel on one; ``flash`` takes
+    the same entry point wherever it runs; ``dense`` is dense."""
+    q, cache, lengths = _plain_call()
+    assert kv_cache.plain_decode(q, cache)
+    _routes(monkeypatch, on_tpu)
+    assert kv_cache.attend(q, cache, lengths, 0.125, 1, impl=impl) == route
+
+
+def _excluded(name, q, cache, lengths):
+    """The plain call made into one of the shapes ``auto`` leaves to
+    dense."""
+    k = cache["k"]
+    if name == "S>1":
+        return jnp.concatenate([q, q], axis=1), cache, lengths + 1
+    if name == "slot":
+        return q[:1], {**cache, "slot": jnp.int32(1)}, lengths[1:2]
+    if name == "gate":
+        return q, {**cache, "gate": jnp.bool_(True)}, lengths
+    if name == "draft_valid":
+        return q, {**cache, "draft_valid": lengths}, lengths
+    if name == "int8":
+        scale = jnp.ones(k.shape[:3] + (k.shape[3] * 2,), jnp.float32)
+        return q, {"k": k.astype(jnp.int8), "v": k.astype(jnp.int8),
+                   "k_scale": scale, "v_scale": scale}, lengths
+    if name == "float32":
+        return q.astype(jnp.float32), {
+            n: x.astype(jnp.float32) for n, x in cache.items()}, lengths
+    assert name == "paged"
+    pool = k[0].reshape(-1, 8, ST_ROWS * 2, 64)  # pages of 8 rows
+    tables = jnp.arange(pool.shape[0], dtype=jnp.int32).reshape(
+        k.shape[1], -1)
+    return q, {"k": pool[None], "v": pool[None], "block_tables": tables}, \
+        lengths
+
+
+@pytest.mark.parametrize("shape", ["S>1", "slot", "gate", "draft_valid",
+                                   "int8", "float32", "paged"])
+def test_auto_leaves_every_other_shape_to_dense(shape, monkeypatch):
+    """Prefill chunks and verify (S > 1), one slot's block (``slot``,
+    ``gate``), a ragged verify (``draft_valid``), int8 and float32 leaves
+    and the paged layout are not the plain decode shape: on a TPU too,
+    ``auto`` goes where ``dense`` goes, and ``flash`` to the kernel that
+    takes the sliced layer (paged: the page walk) as before."""
+    q, cache, lengths = _excluded(shape, *_plain_call())
+    assert not kv_cache.plain_decode(q, cache)
+    _routes(monkeypatch, on_tpu=True)
+    dense = "paged dense" if shape == "paged" else "dense"
+    flash = "paged flash" if shape == "paged" else "sliced"
+    attend = lambda impl: kv_cache.attend(q, cache, lengths, 0.125, 0,
+                                          impl=impl)
+    assert [attend("auto"), attend("dense"), attend("flash")] \
+        == [dense, dense, flash]
+
+
+def test_engine_auto_is_the_default_and_dense_off_tpu(tiny_model_kwargs):
+    """The shipped default is ``auto``; an engine built on it off a TPU
+    has nothing to fall back from."""
+    cfg, eng = _engine(tiny_model_kwargs, None)
+    assert cfg.inference.attend_impl == eng.attend_impl == "auto"
+    assert not eng._flash_fallback(RuntimeError("x"))
+    assert eng.attend_impl == "auto"

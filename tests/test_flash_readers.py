@@ -69,3 +69,72 @@ def test_flash_bwd_roofline_is_listed_for_the_training_cells():
     entry = by_name["kernels.flash_bwd_roofline"]
     fwd = by_name["kernels.flash_fwd_roofline"]
     assert entry == {**fwd, "name": "kernels.flash_bwd_roofline"}
+
+
+# ---- the flash-decode kernel's share of the HBM roofline (PR 36) -----------
+
+
+def _serve_run(ops, config="smollm-1.7b"):
+    """A traced tail of 1 s over one stream that holds 1,000 prompt tokens
+    and the first of its own."""
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           config + ".json")) as f:
+        config = json.load(f)
+    requests = [{"prompt_len": 1000, "token_times": [0.5, 9.0], "done": 9.0},
+                {"prompt_len": 500, "token_times": [], "done": 0.2}]
+    return {"trace": {"ops": ops, "t_start": 1.0, "t_stop": 2.0},
+            "load": {"requests": requests}, "config": config,
+            "peaks": {"hbm_bytes_per_s": 819e9}}
+
+
+# K and V of 1,001 live tokens, one of SmolLM's 24 layers: 2 x 32 heads x 64
+# x 2 bytes a token = 8,192 bytes: 8.2 MB, 10.01 us at 819 GB/s
+DECODE_LEAST_US = 1001 * 8192 / 819e9 * 1e6
+
+
+@pytest.mark.parametrize("name", ["kernels.flash_decode_roofline",
+                                  "kernels.flash_decode_roofline.chat"])
+@pytest.mark.parametrize("ops,us", [
+    ({"flash_decode_attention.6": [192, 192 * 25e-6],
+      "fusion.198": [192, 0.3]}, 25.0),
+    # two numberings of the one kernel; an op that merely holds its name
+    # (a fusion of its operands) is not the kernel
+    ({"flash_decode_attention.6": [96, 96 * 20e-6],
+      "flash_decode_attention": [96, 96 * 30e-6],
+      "flash_decode_attention_operands_fusion.2": [192, 0.5]}, 25.0),
+])
+def test_flash_decode_roofline_reads_a_layers_attend(name, ops, us):
+    got = load_reader("layer_metrics", name)(_serve_run(ops))
+    assert got == pytest.approx(100 * DECODE_LEAST_US / us, rel=1e-6)
+    assert 0 < got < 100
+
+
+@pytest.mark.parametrize("run", [
+    {}, {"trace": None},
+    # a program that attends densely: the parent, or a fall-back
+    _serve_run({"dynamic-slice_bitcast_fusion.4": [192, 0.28],
+                "fusion.198": [192, 0.3]}),
+])
+def test_flash_decode_roofline_finds_nothing(run):
+    for name in ("kernels.flash_decode_roofline",
+                 "kernels.flash_decode_roofline.chat"):
+        assert load_reader("layer_metrics", name)(run) is None
+
+
+def test_flash_decode_roofline_is_listed_for_the_llama_serving_cells():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    base = {"unit": "%", "better": "higher", "source": "device_trace",
+            "layer": "kernels"}
+    assert by_name["kernels.flash_decode_roofline"] == {
+        **base, "name": "kernels.flash_decode_roofline",
+        "moves": "serve_out_tokens_per_s",
+        "workloads": ["smollm-1.7b.serve-batch"]}
+    assert by_name["kernels.flash_decode_roofline.chat"] == {
+        **base, "name": "kernels.flash_decode_roofline.chat",
+        "moves": "serve_tpot_mean_ms",
+        "workloads": ["mistral-7b-v0.3-l16.serve-chat",
+                      "mistral-7b-v0.3-l16.serve-longdoc"]}
+    assert [m["name"] for m in manifest["per_layer"]][-2:] == [
+        "kernels.flash_decode_roofline", "kernels.flash_decode_roofline.chat"]

@@ -637,6 +637,15 @@ def test_validate_refuses_by_name(sections, match):
         make_config(**json.loads(json.dumps(sections)))
 
 
+@pytest.mark.parametrize("impl", ["auto", "dense"])
+def test_validate_accepts_attend_impl(impl):
+    """``auto`` (the shipped default) and ``dense`` pass; an explicit
+    ``flash`` is refused above."""
+    assert make_config().inference.attend_impl == "auto"
+    cfg = make_config(inference={"attend_impl": impl})
+    assert cfg.inference.attend_impl == impl
+
+
 def test_training_is_refused_by_name():
     from picotron_tpu import train_step as ts
     from picotron_tpu.topology import topology_from_config
