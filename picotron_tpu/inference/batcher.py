@@ -1834,8 +1834,7 @@ class ContinuousBatcher:
                 self.decode_dispatches += 1
                 self._phases.to("step/sync")
                 t_sync = self._clock()
-                self._synthetic_wait(t0)
-                out = np.asarray(res.tokens), np.asarray(res.counts), None
+                out = self._sync_outputs(t0, res.tokens, res.counts)
                 self._merge_hidden(res.hidden, out[1])
                 t1 = self._clock()
                 self._phases.to("step/deliver")
@@ -1845,8 +1844,7 @@ class ContinuousBatcher:
                     self._host_sync_s = dt_sync
                 self._step_sync_wait += dt_sync
                 self._note_sync_end(t0, t1)
-                self.engine.observe_dispatch("decode", t1 - t0,
-                                             host_sync_s=dt_sync)
+                self.engine.observe_dispatch("decode", t1 - t0)
                 self.obs.tracer.record(
                     "dispatch/decode", t0, t1,
                     slots=int(np.count_nonzero(np.asarray(b) > 0)),
@@ -1920,6 +1918,22 @@ class ContinuousBatcher:
             wait = t_issue + self._synthetic_sync_s - self._clock()
             if wait > 0:
                 time.sleep(wait)
+
+    def _sync_outputs(self, t_issue: float, tokens, counts,
+                      accepted=None) -> tuple:
+        """A round's outputs as host arrays, (tokens, counts, accepted or
+        None), in the two parts of ``step/sync``: ``sync/wait``, blocked
+        until the device has them (the launch and the program's run; the
+        bench's synthetic device window pads it), and ``sync/fetch``, the
+        copies to the host. What a sync site does after them (the learned
+        drafter's hidden rows merged, the deferred page-table advance) is
+        the phase's own time."""
+        with self.obs.part("sync/wait"):
+            jax.block_until_ready(tokens)
+            self._synthetic_wait(t_issue)
+        with self.obs.part("sync/fetch"):
+            return (np.asarray(tokens), np.asarray(counts),
+                    None if accepted is None else np.asarray(accepted))
 
     def _note_sync_end(self, t_issue: float, t_end: float) -> None:
         self._t_last_sync_end = t_end
@@ -2074,10 +2088,8 @@ class ContinuousBatcher:
             self.decode_dispatches += 1
             self._phases.to("step/sync")
             t_sync = self._clock()
-            self._synthetic_wait(t0)
-            outs = (np.asarray(res.tokens), np.asarray(res.counts),
-                    None if res.accepted is None
-                    else np.asarray(res.accepted))
+            outs = self._sync_outputs(t0, res.tokens, res.counts,
+                                      res.accepted)
             # deferred page-table advance (engine.defer_advance): lands
             # here per successful dispatch, so isolation re-dispatches
             # compose exactly like the legacy per-dispatch advance
@@ -2090,8 +2102,7 @@ class ContinuousBatcher:
                 self._host_sync_s = dt_sync
             self._step_sync_wait += dt_sync
             self._note_sync_end(t0, t1)
-            self.engine.observe_dispatch(kind, t1 - t0,
-                                         host_sync_s=dt_sync)
+            self.engine.observe_dispatch(kind, t1 - t0)
             args = dict(slots=int(np.count_nonzero(np.asarray(b) > 0)),
                         host_sync_s=round(dt_sync, 6))
             if kind == "verify":
@@ -2145,10 +2156,8 @@ class ContinuousBatcher:
         self._phases.to("step/sync")
         t_sync = self._clock()
         try:
-            toks = np.asarray(rec["toks"])
-            counts = np.asarray(rec["counts"])
-            accepted = (None if rec["accepted"] is None
-                        else np.asarray(rec["accepted"]))
+            toks, counts, accepted = self._sync_outputs(
+                rec["t0"], rec["toks"], rec["counts"], rec["accepted"])
         except Exception as e:  # noqa: BLE001 - device-side round failure
             _log_dispatch_failure("sync", "in-flight round", e)
             if not self._cache_ok():
@@ -2169,7 +2178,6 @@ class ContinuousBatcher:
                         and rec["epochs"][i] == self._epoch[i]):
                     self._finish(i, "error")
             return
-        self._synthetic_wait(rec["t0"])
         t1 = self._clock()
         self._phases.to("step/deliver")
         dt_sync = t1 - t_sync
@@ -2183,8 +2191,7 @@ class ContinuousBatcher:
         mbud = np.where(live, rec["budget"], 0)
         self.engine.apply_advance(counts)
         self._merge_hidden(rec["hid"], counts)
-        self.engine.observe_dispatch(kind, t1 - rec["t0"],
-                                     host_sync_s=dt_sync)
+        self.engine.observe_dispatch(kind, t1 - rec["t0"])
         args = dict(round=rec["seq"],
                     slots=int(np.count_nonzero(
                         np.asarray(rec["budget"]) > 0)),
@@ -2471,9 +2478,8 @@ class ContinuousBatcher:
             self.decode_dispatches += 1
             self._phases.to("step/sync")
             t_sync = self._clock()
-            self._synthetic_wait(t0)
-            out = (np.asarray(res.tokens), np.asarray(res.counts),
-                   np.asarray(res.accepted))
+            out = self._sync_outputs(t0, res.tokens, res.counts,
+                                     res.accepted)
             self._merge_hidden(res.hidden, out[1])
             t1 = self._clock()
             self._phases.to("step/deliver")
@@ -2482,8 +2488,7 @@ class ContinuousBatcher:
                 self._host_sync_s = dt_sync
             self._step_sync_wait += dt_sync
             self._note_sync_end(t0, t1)
-            self.engine.observe_dispatch("verify", t1 - t0,
-                                         host_sync_s=dt_sync)
+            self.engine.observe_dispatch("verify", t1 - t0)
             self.obs.tracer.record(
                 "dispatch/verify", t0, t1,
                 slots=int(np.count_nonzero(np.asarray(b) > 0)),

@@ -714,21 +714,16 @@ class InferenceEngine:
             if dead is not None:
                 self.monitor._exit(*dead)
 
-    def observe_dispatch(self, kind: str, seconds: float,
-                         host_sync_s: Optional[float] = None) -> None:
+    def observe_dispatch(self, kind: str, seconds: float) -> None:
         """Record one dispatch's end-to-end wall time (submit through the
         caller's host sync) into the registry. Callers that pay the sync
         — the batcher's round closures, the benches — report here; the
         engine itself never blocks on its own async dispatches just to
         time them."""
-        reg = self.obs.registry
-        reg.histogram("picotron_dispatch_seconds",
-                      "dispatch wall time incl. host sync, by kind",
-                      kind=kind).observe(seconds)
-        if host_sync_s is not None:
-            reg.histogram("picotron_host_sync_seconds",
-                          "host blocked on device results, by kind",
-                          kind=kind).observe(host_sync_s)
+        self.obs.registry.histogram(
+            "picotron_dispatch_seconds",
+            "dispatch wall time incl. host sync, by kind",
+            kind=kind).observe(seconds)
 
     def _poison(self, kind: str) -> bool:
         return self.hooks is not None and self.hooks.poison_logits(kind)
@@ -2114,7 +2109,7 @@ class InferenceEngine:
         # a device tokens array must NOT round-trip through np.asarray —
         # that sync is exactly what the overlap pipeline exists to avoid
         if not isinstance(tokens, jax.Array):
-            tokens = jnp.asarray(np.asarray(tokens, np.int32))
+            tokens = np.asarray(tokens, np.int32)
         return self._round("decode_block", params, cache, (tokens,), keys,
                            eos_id, budget, temperature, top_k, top_p,
                            self.decode_block_len, budget, adapter_ids,
@@ -2173,11 +2168,25 @@ class InferenceEngine:
         # parked slot whatever its budget; ensuring them all exclusive
         # BEFORE the dispatch is what makes the rollback free — rejected
         # rows strand in pages only this slot holds, never in a shared one
-        return self._round("verify", params, cache,
-                           (jnp.asarray(tokens), jnp.asarray(valid)), key,
+        return self._round("verify", params, cache, (tokens, valid), key,
                            eos_id, budget, temperature, top_k, top_p,
                            self.spec_len + 1, None, adapter_ids, lead,
                            lanes)
+
+    def _round_operands(self, rows, keys, eos_id, budget, temperature,
+                        top_k, top_p, lanes) -> tuple:
+        """A round program's operands after ``params`` and ``cache``, on
+        the device (``issue/operands``): ``rows`` and the five [slots]
+        rows copied from the host, a row that is a device array already
+        (the overlap pipeline's tokens, the keys) as it is, then the
+        fused lane's operands."""
+        return (*(jnp.asarray(r) for r in rows), keys,
+                jnp.asarray(np.asarray(eos_id, np.int32)),
+                jnp.asarray(np.asarray(budget, np.int32)),
+                jnp.asarray(np.asarray(temperature, np.float32)),
+                jnp.asarray(np.asarray(top_k, np.int32)),
+                jnp.asarray(np.asarray(top_p, np.float32)),
+                *(self._lane_args(lanes) if self.mixed else ()))
 
     def _round(self, kind: str, params, cache, rows, keys, eos_id, budget,
                temperature, top_k, top_p, nwrite: int, reach,
@@ -2185,9 +2194,11 @@ class InferenceEngine:
         """The host half ``decode_block`` and ``verify`` share (the
         batcher's ``step/issue``): checks, hooks, adapter binding, the
         paged pre-write of up to ``nwrite`` rows a slot (``reach``: see
-        ``_pre_write``'s ``budget``), the dispatch of ``_program(kind)``
-        on ``rows`` (the program's leading device operands) and the
-        result by name."""
+        ``_pre_write``'s ``budget``), the operands put on the device
+        (``issue/operands``; ``rows`` are the program's leading ones, host
+        or device), the call of ``_program(kind)`` (``issue/enqueue``) and
+        the result by name. The two parts are timed where they happen
+        (``obs.part``); the rest is ``step/issue``'s own time."""
         if lanes is not None and not self.mixed:
             raise ValueError(
                 "lanes requires a mixed_dispatch engine (construct with "
@@ -2208,17 +2219,15 @@ class InferenceEngine:
         if self.paged is not None:
             cache = self._lane_ensure(cache, lanes)
             cache = self._pre_write(cache, nwrite, budget=reach, lead=lead)
-        lane_args = self._lane_args(lanes) if self.mixed else ()
+        with self.obs.part("issue/operands"):
+            operands = self._round_operands(rows, keys, eos_id, budget,
+                                            temperature, top_k, top_p, lanes)
         # the program is resolved INSIDE the lambda so the flash->dense
         # fallback's rebuilt table is what a re-dispatch reads
-        out = dict(zip(self._round_fields(kind), self._dispatch(
-            lambda: self._program(kind, poison)(
-                params, cache, *rows, keys,
-                jnp.asarray(np.asarray(eos_id, np.int32)),
-                jnp.asarray(np.asarray(budget, np.int32)),
-                jnp.asarray(np.asarray(temperature, np.float32)),
-                jnp.asarray(np.asarray(top_k, np.int32)),
-                jnp.asarray(np.asarray(top_p, np.float32)), *lane_args))))
+        with self.obs.part("issue/enqueue"):
+            out = dict(zip(self._round_fields(kind), self._dispatch(
+                lambda: self._program(kind, poison)(
+                    params, cache, *operands))))
         if "stats" in out:
             # kept, still on the device, for ``take_stats``
             self._stats_pending.append(out.pop("stats"))

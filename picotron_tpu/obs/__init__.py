@@ -54,6 +54,10 @@ _NULL_TRACER = NullTracer()
 # The serving dispatch loop's phases (docs/OBSERVABILITY.md "Dispatch-loop
 # phases"): one histogram family, one label value per phase.
 PHASE_HISTOGRAM = "picotron_round_phase_seconds"
+# What a phase holds inside ("The parts of a round", same document): a
+# family beside the phases, because a part lies INSIDE its phase and a
+# nested label would count twice for whoever sums over ``phase``.
+PART_HISTOGRAM = "picotron_round_part_seconds"
 
 
 class _Timed:
@@ -144,6 +148,15 @@ class Obs:
     def phase(self, name: str) -> _Timed:
         """``timed`` into ``picotron_round_phase_seconds{phase=name}``."""
         return self.timed(name, self.phase_histogram(name))
+
+    def part(self, name: str) -> _Timed:
+        """``timed`` into ``picotron_round_part_seconds{part=name}``: one
+        observation every time the part ends (a re-dispatch runs its
+        parts again), where a phase is observed once a round."""
+        return self.timed(name, self.registry.histogram(
+            PART_HISTOGRAM,
+            "host time of a round's parts inside step/issue and step/sync, "
+            "one observation a dispatch", part=name))
 
     @classmethod
     def from_config(cls, ocfg) -> "Obs":

@@ -579,7 +579,11 @@ def test_rehearsal_of_the_cell_computes_its_readers():
             "ssm.prefill_scan_tokens_per_s",
             "engine.prefill_tflops.granite",
             "batcher.dispatch_gap_ms.tput",
-            "batcher.deliver_ms.tput"} == set(out["computed"])
+            "batcher.deliver_ms.tput",
+            # the parts of a round (ISSUE 37), from the window's scrapes
+            "engine.issue_operands_ms.tput", "engine.issue_enqueue_ms.tput",
+            "engine.sync_wait_ms.tput",
+            "engine.sync_fetch_ms.tput"} == set(out["computed"])
 
 
 def test_a_program_without_the_block_fails_the_cell_at_once(tmp_path):

@@ -398,6 +398,12 @@ def test_speculative_round_spans_carry_accept_counts():
     prom = parse_prometheus(engine.obs.registry.prometheus())
     assert prom["picotron_draft_proposed_total"] == b.draft_proposed > 0
     assert prom["picotron_draft_accepted_total"] == b.draft_accepted
+    # a verify is a round like a decode block: its four parts, once each
+    assert {k: v for k, v in prom.items()
+            if k.startswith("picotron_round_part_seconds_count")} == {
+        f'picotron_round_part_seconds_count{{part="{p}"}}':
+        b.decode_dispatches for p in ("issue/operands", "issue/enqueue",
+                                      "sync/wait", "sync/fetch")}
     verifies = [s for s in GLOBAL_TRACER.spans() if s.name == "verify"]
     assert verifies and all("accepted" in s.args and
                             s.args["draft_len"] == 3 for s in verifies)
@@ -471,6 +477,17 @@ def test_serve_metrics_tracez_profilez(tmp_path):
         assert prom['picotron_requests_total{state="completed"}'] == \
             stats["completed"]
         assert prom['picotron_rejections_total{reason="queue_full"}'] == 0
+        # the round's phases under the labels they had, its parts (ISSUE
+        # 37) in a family of their own, and no second reading of the sync
+        labels = lambda family: {k.split('"')[1] for k in prom
+                                 if k.startswith(family + "_count{")}
+        served = {"step/plan", "step/admit", "step/issue", "step/sync",
+                  "step/deliver", "loop/lock_wait", "loop/results"}
+        assert served <= labels("picotron_round_phase_seconds") \
+            <= served | {"loop/idle"}
+        assert labels("picotron_round_part_seconds") == {
+            "issue/operands", "issue/enqueue", "sync/wait", "sync/fetch"}
+        assert "picotron_host_sync_seconds" not in mtext
         # the model-memory gauge (ISSUE 13): /statz and /metrics agree on
         # resident weight bytes — what the router's scrape reads to see
         # per-replica model memory (int8 replicas report ~half bf16)
@@ -819,14 +836,13 @@ class _ManualClock:
         return self.t
 
 
-def _phase_reads(registry):
+def _phase_reads(registry, family="picotron_round_phase_seconds"):
+    """{label value: {"sum", "count"}} of one labelled histogram family."""
     snap = parse_prometheus(registry.prometheus())
     out = {}
     for key, v in snap.items():
-        if key.startswith("picotron_round_phase_seconds_") \
-                and "_bucket" not in key:
-            kind, _, label = key[len("picotron_round_phase_seconds_"):] \
-                .partition("{")
+        if key.startswith(family + "_") and "_bucket" not in key:
+            kind, _, label = key[len(family) + 1:].partition("{")
             out.setdefault(label.split('"')[1], {})[kind] = v
     return out
 
