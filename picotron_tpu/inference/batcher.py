@@ -1834,7 +1834,7 @@ class ContinuousBatcher:
                 self.decode_dispatches += 1
                 self._phases.to("step/sync")
                 t_sync = self._clock()
-                out = self._sync_outputs(t0, res.tokens, res.counts)
+                out = self._sync_outputs(t0, res)
                 self._merge_hidden(res.hidden, out[1])
                 t1 = self._clock()
                 self._phases.to("step/deliver")
@@ -1919,21 +1919,21 @@ class ContinuousBatcher:
             if wait > 0:
                 time.sleep(wait)
 
-    def _sync_outputs(self, t_issue: float, tokens, counts,
-                      accepted=None) -> tuple:
+    def _sync_outputs(self, t_issue: float, res) -> tuple:
         """A round's outputs as host arrays, (tokens, counts, accepted or
         None), in the two parts of ``step/sync``: ``sync/wait``, blocked
         until the device has them (the launch and the program's run; the
         bench's synthetic device window pads it), and ``sync/fetch``, the
-        copies to the host. What a sync site does after them (the learned
-        drafter's hidden rows merged, the deferred page-table advance) is
-        the phase's own time."""
+        read of ``res.packed``'s ONE copy to the host, which the engine
+        asked for at issue, so it left with the program's end. What a sync
+        site does after them (the learned drafter's hidden rows merged,
+        the deferred page-table advance) is the phase's own time."""
         with self.obs.part("sync/wait"):
-            jax.block_until_ready(tokens)
+            jax.block_until_ready(res.packed)
             self._synthetic_wait(t_issue)
         with self.obs.part("sync/fetch"):
-            return (np.asarray(tokens), np.asarray(counts),
-                    None if accepted is None else np.asarray(accepted))
+            self.engine.count_copies("d2h")
+            return res.host()
 
     def _note_sync_end(self, t_issue: float, t_end: float) -> None:
         self._t_last_sync_end = t_end
@@ -2021,6 +2021,7 @@ class ContinuousBatcher:
             # host state; column 0 is overridden by the device token row
             tokens = self._draft(spec_lens, spec_kinds)
             drafts = jnp.asarray(tokens[:, 1:])
+            self.engine.count_copies("h2d")  # the round's second copy up
 
             def issue(b, toks_in):
                 dev_tokens = jnp.concatenate(
@@ -2047,9 +2048,7 @@ class ContinuousBatcher:
         self._round_seq += 1
         self._phases.to("step/plan")  # until the drain's sync claims it
         return dict(kind=kind, t_round=t_round, t0=t0,
-                    budget=budget, epochs=epochs, toks=out.tokens,
-                    counts=out.counts, accepted=out.accepted,
-                    hid=out.hidden,
+                    budget=budget, epochs=epochs, res=out, hid=out.hidden,
                     spec_lens=spec_lens, spec_kinds=spec_kinds,
                     # lane futures + feed records: the sync stage lands
                     # them after the round's outputs materialize
@@ -2088,8 +2087,7 @@ class ContinuousBatcher:
             self.decode_dispatches += 1
             self._phases.to("step/sync")
             t_sync = self._clock()
-            outs = self._sync_outputs(t0, res.tokens, res.counts,
-                                      res.accepted)
+            outs = self._sync_outputs(t0, res)
             # deferred page-table advance (engine.defer_advance): lands
             # here per successful dispatch, so isolation re-dispatches
             # compose exactly like the legacy per-dispatch advance
@@ -2156,8 +2154,8 @@ class ContinuousBatcher:
         self._phases.to("step/sync")
         t_sync = self._clock()
         try:
-            toks, counts, accepted = self._sync_outputs(
-                rec["t0"], rec["toks"], rec["counts"], rec["accepted"])
+            toks, counts, accepted = self._sync_outputs(rec["t0"],
+                                                        rec["res"])
         except Exception as e:  # noqa: BLE001 - device-side round failure
             _log_dispatch_failure("sync", "in-flight round", e)
             if not self._cache_ok():
@@ -2478,8 +2476,7 @@ class ContinuousBatcher:
             self.decode_dispatches += 1
             self._phases.to("step/sync")
             t_sync = self._clock()
-            out = self._sync_outputs(t0, res.tokens, res.counts,
-                                     res.accepted)
+            out = self._sync_outputs(t0, res)
             self._merge_hidden(res.hidden, out[1])
             t1 = self._clock()
             self._phases.to("step/deliver")

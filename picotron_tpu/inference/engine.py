@@ -86,6 +86,12 @@ def _runs_flash(impl: str) -> bool:
     return impl == "flash" or (impl == "auto" and on_tpu())
 
 
+# a round's [slots] host rows in the order they close its packed operand
+# (``_round_operands``), after the tokens and a verify's ``valid``; the two
+# float32 rows ride as their bits
+_PACK_ROWS = ("eos_id", "budget", "top_k", "temperature", "top_p")
+
+
 def _key_chain(key, block: int):
     """``block`` links of the batcher's key chain in one trace: each link
     is the eager ``key, sub = jax.random.split(key)``, so the carried key
@@ -97,13 +103,17 @@ def _key_chain(key, block: int):
 
 
 class RoundResult(NamedTuple):
-    """What one round dispatch (``decode_block`` | ``verify``) returns,
-    every field a device array (or pytree) still in flight; a field is None
-    where the engine's options produce nothing."""
+    """What one round dispatch (``decode_block`` | ``verify``) returns.
+    ``cache``, ``next_tok``, ``hidden`` and ``lane`` are device arrays (or
+    pytrees) still in flight, None where the engine's options produce
+    nothing. What the host reads every round comes down as ONE int32 array,
+    ``packed`` [slots, n + 1 (+ 1)], whose copy to the host was started at
+    issue: ``tokens``, ``counts`` and ``accepted`` are host views of that
+    copy, and reading one waits for it."""
     cache: dict
-    tokens: jax.Array    # [slots, decode_block_len | spec_len + 1]
-    counts: jax.Array    # [slots]: leading entries of each tokens row
-    accepted: Optional[jax.Array] = None  # verify: drafts accepted [slots]
+    # [slots, n tokens | counts | accepted (a verify's)], int32
+    packed: jax.Array
+    verify: bool = False
     # key_schedule "slot": each slot's post-round last token [slots]
     next_tok: Optional[jax.Array] = None
     # return_hidden: [slots, H] at each slot's last emitted position
@@ -111,6 +121,29 @@ class RoundResult(NamedTuple):
     # mixed_dispatch: (the lane's sampled token [dp] or logits [dp, V],
     # the lane's hidden [dp, H] or None)
     lane: Optional[tuple] = None
+
+    def host(self) -> tuple:
+        """(tokens [slots, n], counts [slots], accepted [slots] or None)
+        on the host: views of the one packed copy (waited for here)."""
+        out = np.asarray(self.packed)
+        if self.verify:
+            return out[:, :-2], out[:, -2], out[:, -1]
+        return out[:, :-1], out[:, -1], None
+
+    @property
+    def tokens(self) -> np.ndarray:
+        """[slots, decode_block_len | spec_len + 1]"""
+        return self.host()[0]
+
+    @property
+    def counts(self) -> np.ndarray:
+        """[slots]: leading entries of each tokens row"""
+        return self.host()[1]
+
+    @property
+    def accepted(self) -> Optional[np.ndarray]:
+        """verify: drafts accepted [slots]"""
+        return self.host()[2]
 
 
 def inference_config(cfg: Config) -> Config:
@@ -636,8 +669,9 @@ class InferenceEngine:
         builder derives ``out_specs`` from them, and the host reads them
         back into a ``RoundResult`` by name."""
         rh = self.return_hidden
-        return (("cache", "tokens", "counts")
-                + (("accepted",) if kind == "verify" else ())
+        # "packed": tokens, counts (and a verify's accepted), what the host
+        # reads every round, as ONE int32 array (``_round_outputs``)
+        return (("cache", "packed")
                 # the post-round last token the overlap pipeline carries
                 + (("next_tok",) if self.key_schedule == "slot" else ())
                 + (("hidden",) if rh else ())
@@ -647,18 +681,25 @@ class InferenceEngine:
                 + (("stats",) if self._n_stats and kind == "decode_block"
                    else ()))
 
-    def _program(self, kind: str, poison: bool = False):
+    def _program(self, kind: str, poison: bool = False,
+                 dev_tokens: bool = False):
         """The round program to run: ``kind`` is ``"decode_block"`` or
         ``"verify"``, the one body of each under every key schedule, with
-        the fused prefill lane on a mixed engine. Built on first use and
-        kept; ``poison`` (chaos only) is a trace-time build of the same
-        body, compiled only when a hook asks for it."""
-        prog = self._programs.get((kind, poison))
+        the fused prefill lane on a mixed engine. Its operands after
+        ``params`` and ``cache`` are what ``_round_operands`` makes: the
+        round's host rows as ONE packed int32 array [rows, slots], split
+        on its slot axis; ``tokens`` as an operand of its own only where
+        the caller holds it on the device (``dev_tokens``: a program's
+        arity is fixed, so the table's key says which); the keys; the
+        lane's operands. Built on first use and kept; ``poison`` (chaos
+        only) is a trace-time build of the same body, compiled only when a
+        hook asks for it."""
+        prog = self._programs.get((kind, poison, dev_tokens))
         if prog is None:
             dpP = P("dp") if self.dp_size > 1 else P()
-            # tokens[, valid], then the keys: [B, 2] bases shard with
-            # their slots, the round's key (rows) is replicated
-            rows = (dpP, dpP) if kind == "verify" else (dpP,)
+            pack = P(None, "dp") if self.dp_size > 1 else P()
+            # [B, 2] bases shard with their slots, the round's key (rows)
+            # is replicated
             keys = dpP if self.key_schedule == "slot" else P()
             # the prefill lane's operands (``_lane_args``), every one a
             # per-shard [dp, ...] row set: tokens, slot, start, valid[,
@@ -668,14 +709,17 @@ class InferenceEngine:
                              if self.mixed else 0)
             impl = (self._verify_impl if kind == "verify"
                     else self._decode_block_impl)
-            prog = self._programs[kind, poison] = jax.jit(shard_map(
-                partial(impl, poison=poison), self.topo.mesh,
-                in_specs=(self._decode_dispatch_pspecs, self._cspecs)
-                + rows + (keys,) + (dpP,) * 5 + lane,
-                out_specs=tuple(
-                    self._cspecs if n == "cache"
-                    else P() if n == "stats" else dpP
-                    for n in self._round_fields(kind))),
+            prog = self._programs[kind, poison, dev_tokens] = jax.jit(
+                shard_map(
+                    partial(impl, poison=poison, dev_tokens=dev_tokens),
+                    self.topo.mesh,
+                    in_specs=(self._decode_dispatch_pspecs, self._cspecs,
+                              pack)
+                    + (dpP,) * dev_tokens + (keys,) + lane,
+                    out_specs=tuple(
+                        self._cspecs if n == "cache"
+                        else P() if n == "stats" else dpP
+                        for n in self._round_fields(kind))),
                 donate_argnums=(1,))
         return prog
 
@@ -1046,12 +1090,12 @@ class InferenceEngine:
         out = out + (h,) if self.return_hidden else out
         return out if stats is None else out + (stats,)
 
-    def _decode_block_impl(self, params, cache, tokens, keys, eos_id,
-                           budget, temperature, top_k, top_p, *lane,
-                           poison=False):
+    def _decode_block_impl(self, params, cache, pack, *rest, poison=False,
+                           dev_tokens=False):
         """``decode_block_len`` autoregressive steps in one program, under
         every key schedule.
 
+        ``pack`` and ``rest`` are ``_round_operands``'s (``_unpack_rows``):
         tokens [B] (each slot's current last token), eos_id [B] int32
         (−1 = none), budget [B] int32 remaining tokens. ``keys`` is the
         engine's ``key_schedule`` (a constant of the trace): ``"round"``
@@ -1069,11 +1113,12 @@ class InferenceEngine:
         land beyond the length mask — invisible, exactly like the free
         slots that already ride through the single-step program.
 
-        Returns ``_round_fields("decode_block")``: cache, tokens
-        [B, block_len], counts [B] (``counts[b]`` leading entries of row b
-        are the tokens slot b actually produced); under the slot schedule
-        next_tok [B], the final carry token (each slot's post-block last
-        token, the input token where a slot never ran), which the
+        Returns ``_round_fields("decode_block")``: cache, packed (tokens
+        [B, block_len] and counts [B] side by side: ``counts[b]`` leading
+        entries of row b are the tokens slot b actually produced); under
+        the slot schedule next_tok [B], the final carry token (each slot's
+        post-block last token, the input token where a slot never ran),
+        which the
         lookahead dispatch consumes without a host sync; on a
         ``return_hidden`` engine hidden [B, H], each slot's
         pre-final-norm hidden state at its LAST active step — the position
@@ -1088,6 +1133,8 @@ class InferenceEngine:
         with NaN — the build that proves the sampler's non-finite gate
         keeps emitting defined tokens, the exact counterpart of
         train_step's ``poison_nonfinite``."""
+        (tokens, eos_id, budget, temperature, top_k, top_p), (keys, *lane) \
+            = self._unpack_rows("decode_block", pack, rest, dev_tokens)
         rh = self.return_hidden
         by_slot = self.key_schedule == "slot"
         hid0 = jnp.zeros((tokens.shape[0], self.cfg.model.hidden_size),
@@ -1130,12 +1177,13 @@ class InferenceEngine:
             out["stats"] = jnp.sum(counted[0], axis=0)
         return self._round_outputs("decode_block", params, out, lane)
 
-    def _verify_impl(self, params, cache, tokens, valid, key, eos_id,
-                     budget, temperature, top_k, top_p, *lane,
-                     poison=False):
-        """The speculative verify pass: tokens [B, S] (S = spec_len + 1 —
-        each slot's current last token followed by its spec_len drafted
-        continuation tokens), scored in ONE model dispatch. ``valid`` [B]
+    def _verify_impl(self, params, cache, pack, *rest, poison=False,
+                     dev_tokens=False):
+        """The speculative verify pass (``pack`` and ``rest`` are
+        ``_round_operands``'s: ``_unpack_rows``): tokens [B, S] (S =
+        spec_len + 1 — each slot's current last token followed by its
+        spec_len drafted continuation tokens), scored in ONE model
+        dispatch. ``valid`` [B]
         int32 is each slot's count of REAL fed tokens (its draft length
         + 1) — the RAGGED hook: the compiled shape stays [B, spec_len+1]
         while each slot speculates at its own controller-chosen length
@@ -1171,9 +1219,10 @@ class InferenceEngine:
         decode_block's budget. Free slots (length 0) ride along inactive:
         they emit count 0 and their length stays 0.
 
-        Returns ``_round_fields("verify")``: cache, tokens (the emitted
-        run) [B, S], counts [B], accepted [B] — the number of DRAFT tokens
-        that made it into the emitted stream (the accept-rate numerator);
+        Returns ``_round_fields("verify")``: cache, packed (side by
+        side: tokens, the emitted run, [B, S]; counts [B]; accepted [B] —
+        the number of DRAFT tokens that made it into the emitted stream,
+        the accept-rate numerator);
         under the slot schedule next_tok [B], the last emitted token where
         the row ran, else the fed last token; on a ``return_hidden``
         engine hidden [B, H], each slot's pre-final-norm hidden state at
@@ -1181,6 +1230,9 @@ class InferenceEngine:
         ``counts - 1``) — the learned drafter's next input; on a mixed
         engine the lane's outputs, as ``_decode_block_impl`` appends them.
         """
+        (tokens, valid, eos_id, budget, temperature, top_k, top_p), \
+            (key, *lane) = self._unpack_rows("verify", pack, rest,
+                                             dev_tokens)
         B, S = tokens.shape
         pos0 = cache["lengths"]
         rows = pos0[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
@@ -1233,10 +1285,40 @@ class InferenceEngine:
                 h, last[:, None, None], axis=1)[:, 0]
         return self._round_outputs("verify", params, out, lane)
 
+    def _unpack_rows(self, kind: str, pack, rest, dev_tokens: bool):
+        """A round body's first lines: ``_round_operands``'s packed int32
+        rows [rows, B] back under their names, ``(tokens[, valid], eos_id,
+        budget, temperature, top_k, top_p)``, and the operands left over
+        (the keys, then the lane's). The float rows rode as their bits and
+        are bit-cast back, so a sampled token is what separate float32
+        operands gave. ``tokens`` is the leading row (a verify's leading
+        S, one a fed position) unless the caller held it on the device:
+        then it is the first of ``rest``, as it was handed in."""
+        n = len(_PACK_ROWS)
+        lead, (eos_id, budget, top_k, temperature, top_p) = \
+            pack[:-n], pack[-n:]
+        temperature, top_p = (lax.bitcast_convert_type(r, jnp.float32)
+                              for r in (temperature, top_p))
+        valid = ()
+        if kind == "verify":
+            lead, valid = lead[:-1], (lead[-1],)
+        if dev_tokens:
+            tokens, *rest = rest
+        else:
+            tokens = lead.T if kind == "verify" else lead[0]
+        return (tokens, *valid, eos_id, budget, temperature, top_k,
+                top_p), rest
+
     def _round_outputs(self, kind: str, params, out: dict, lane) -> tuple:
         """A round program's tail: on a mixed engine the fused prefill
-        lane, run on the cache the round just updated; then what the
-        program returns, in ``_round_fields``'s order."""
+        lane, run on the cache the round just updated; what the host
+        reads every round (``RoundResult.host``) side by side in one int32
+        array; then what the program returns, in ``_round_fields``'s
+        order."""
+        out["packed"] = jnp.concatenate(
+            [out["tokens"], out["counts"][:, None]]
+            + ([out["accepted"][:, None]] if kind == "verify" else []),
+            axis=1).astype(jnp.int32)
         if self.mixed:
             out["cache"], out["lane_out"], out["lane_hidden"] = \
                 self._lane_chunk(params, out["cache"], *lane)
@@ -2175,18 +2257,36 @@ class InferenceEngine:
 
     def _round_operands(self, rows, keys, eos_id, budget, temperature,
                         top_k, top_p, lanes) -> tuple:
-        """A round program's operands after ``params`` and ``cache``, on
-        the device (``issue/operands``): ``rows`` and the five [slots]
-        rows copied from the host, a row that is a device array already
-        (the overlap pipeline's tokens, the keys) as it is, then the
-        fused lane's operands."""
-        return (*(jnp.asarray(r) for r in rows), keys,
-                jnp.asarray(np.asarray(eos_id, np.int32)),
-                jnp.asarray(np.asarray(budget, np.int32)),
-                jnp.asarray(np.asarray(temperature, np.float32)),
-                jnp.asarray(np.asarray(top_k, np.int32)),
-                jnp.asarray(np.asarray(top_p, np.float32)),
-                *(self._lane_args(lanes) if self.mixed else ()))
+        """A round program's operands after ``params`` and ``cache``
+        (``issue/operands``): every [slots] row the host holds in ONE int32
+        array [rows, slots] (``_unpack_rows`` is its reader: ``rows``,
+        which is ``tokens`` and a verify's ``valid``, then ``_PACK_ROWS``,
+        float32 rows as their bits), handed to the program's call as the
+        host array it is, so the call makes the round's ONE copy up itself
+        and no ``device_put`` of its own stands in front of it (0.3 ms a
+        round on the chip, PERF.md section 6, PR 38); ``tokens`` as it is
+        where it is a device array already (the overlap pipeline's); the
+        keys, device arrays too; then the fused lane's operands."""
+        tokens, *valid = rows
+        dev = isinstance(tokens, jax.Array)
+        bits = lambda row: np.asarray(row, np.float32).view(np.int32)
+        # a verify's tokens [slots, S] ride as S rows, one a fed position
+        host = [*(() if dev else tokens.reshape(self.slots, -1).T), *valid,
+                eos_id, budget, top_k, bits(temperature), bits(top_p)]
+        pack = np.array(host, np.int32)
+        lane = self._lane_args(lanes) if self.mixed else ()
+        self.count_copies("h2d", 1 + len(lane))
+        return (pack, *((tokens,) if dev else ()), keys, *lane)
+
+    def count_copies(self, direction: str, n: int = 1) -> None:
+        """``n`` more copies between host and device (``"h2d"`` |
+        ``"d2h"``) that a round made for its rows: the packed operand (and
+        a mixed engine's lane operands) up, the packed outputs down. A
+        serial round counts 1 and 1."""
+        self.obs.registry.counter(
+            "picotron_round_copies_total",
+            "host-device copies of a round's rows and outputs, by "
+            "direction", direction=direction).inc(n)
 
     def _round(self, kind: str, params, cache, rows, keys, eos_id, budget,
                temperature, top_k, top_p, nwrite: int, reach,
@@ -2194,10 +2294,12 @@ class InferenceEngine:
         """The host half ``decode_block`` and ``verify`` share (the
         batcher's ``step/issue``): checks, hooks, adapter binding, the
         paged pre-write of up to ``nwrite`` rows a slot (``reach``: see
-        ``_pre_write``'s ``budget``), the operands put on the device
-        (``issue/operands``; ``rows`` are the program's leading ones, host
-        or device), the call of ``_program(kind)`` (``issue/enqueue``) and
-        the result by name. The two parts are timed where they happen
+        ``_pre_write``'s ``budget``), the operands made
+        (``issue/operands``: the host rows packed; ``rows`` is ``tokens``,
+        host or device, and a verify's ``valid``), the call of
+        ``_program(kind)``, which takes the pack up with it, and the copy
+        down of its packed outputs asked for at once (``issue/enqueue``),
+        and the result by name. The two parts are timed where they happen
         (``obs.part``); the rest is ``step/issue``'s own time."""
         if lanes is not None and not self.mixed:
             raise ValueError(
@@ -2224,21 +2326,25 @@ class InferenceEngine:
                                             temperature, top_k, top_p, lanes)
         # the program is resolved INSIDE the lambda so the flash->dense
         # fallback's rebuilt table is what a re-dispatch reads
+        dev = isinstance(rows[0], jax.Array)
         with self.obs.part("issue/enqueue"):
             out = dict(zip(self._round_fields(kind), self._dispatch(
-                lambda: self._program(kind, poison)(
+                lambda: self._program(kind, poison, dev)(
                     params, cache, *operands))))
+            # the copy down starts when the program ends, not when the
+            # host has waited for it: the sync reads bytes that are there
+            out["packed"].copy_to_host_async()
         if "stats" in out:
             # kept, still on the device, for ``take_stats``
             self._stats_pending.append(out.pop("stats"))
         lane = ((out.pop("lane_out"), out.pop("lane_hidden", None))
                 if self.mixed else None)
-        res = RoundResult(lane=lane, **out)
+        res = RoundResult(verify=kind == "verify", lane=lane, **out)
         if self.paged is not None and not self.defer_advance:
             # mirror device length advancement (counts per slot; a verify
             # advanced by the ACCEPTED counts — the length pointer is the
             # rollback). The host sync this forces is the round's ONE
-            # sync, just moved ahead of the batcher's own np.asarray on
-            # the same buffers.
-            self.paged.advance(np.asarray(res.counts, np.int64))
+            # sync, just moved ahead of the batcher's own read of the
+            # same packed copy.
+            self.paged.advance(res.counts.astype(np.int64))
         return res

@@ -344,6 +344,40 @@ def _cache_movers(text: str, kv_shape: tuple) -> list:
             if moves(c, name, shape, op, rest)]
 
 
+def _assert_one_pack_each_way(text: str, slots: int, block_len: int):
+    """A decode block crosses the host-device boundary once each way (ISSUE
+    38): after the weights and the cache its parameters are the round's
+    host rows as ONE packed ``s32[6, slots]`` (where it took six [slots]
+    rows, two of them float32) and the keys; what the host reads leaves as
+    ONE ``s32[slots, block_len + 1]`` (tokens and counts side by side),
+    beside the cache; and the unpack is small fusions over the pack alone:
+    none of them reads or writes anything a leaf's size."""
+    lines = text[text.index("\nENTRY "):].splitlines()
+    own = [l.strip() for l in lines if " parameter(" in l
+           and not re.search(r"%(?:params|cache)__", l)]
+    assert len(own) == 2, own
+    pack = [l for l in own if re.search(rf"= s32\[6,{slots}\]", l)]
+    assert len(pack) == 1 and "u32[" in "".join(set(own) - set(pack)), own
+    assert not [l for l in lines if " parameter(" in l
+                and re.search(rf"= f32\[{slots}\]", l)]
+    outs = next(l for l in lines
+                if l.strip().startswith("ROOT")).split(" tuple(")[0]
+    assert len(re.findall(rf"s32\[{slots},{block_len + 1}\]", outs)) == 1, \
+        outs
+    # the one [slots] row that still leaves is the cache's lengths
+    assert len(re.findall(rf"s32\[{slots}\]", outs)) == 1, outs
+    name = re.match(r"%([\w.\-]+) =", pack[0]).group(1)
+    users = [l.strip().split(" = ", 1)[1] for l in lines
+             if re.search(rf"[(,] ?%{re.escape(name)}[,)]", l)]
+    assert users
+    for l in users:
+        result, operands = l.split("(%", 1)
+        assert result.endswith(" fusion") \
+            and operands.startswith(name + ")"), l[:200]
+        assert all(math.prod(int(d) for d in dims.split(",") if d)
+                   <= 6 * slots for dims in _SHAPE.findall(result)), l[:200]
+
+
 # (hidden, heads, kv heads, FFN, vocab): SmolLM-1.7B's 32 heads of 64, two
 # to a lane row of the cache, and Mistral-7B-v0.3's 8 kv heads of 128, one
 GEOMETRY = {"smollm": (HID, HEADS, HEADS, FFN, VOCAB),
@@ -391,9 +425,7 @@ def _serving_program(topo, prog, layout, geometry="smollm"):
     B = SERVE_SLOTS
     if prog == "decode_block":
         jitted = eng._program("decode_block")
-        args = (arg((B,), I32), arg((eng.decode_block_len, 2), jnp.uint32),
-                arg((B,), I32), arg((B,), I32), arg((B,), F32),
-                arg((B,), I32), arg((B,), F32))
+        args = (arg((6, B), I32), arg((eng.decode_block_len, 2), jnp.uint32))
     else:
         jitted = eng._prefill_chunk_jit
         args = (arg((1, eng.prefill_chunk), I32),) + (arg((), I32),) * 3
@@ -431,6 +463,8 @@ def test_serving_program_leaves_cache_in_place(prog, layout, geometry, topo,
     if layout == "kernel":
         assert text.count("custom_call_target=\"tpu_custom_call\"") == 1, \
             "the decode block's layer loop holds one flash-decode kernel"
+    if prog == "decode_block":
+        _assert_one_pack_each_way(text, SERVE_SLOTS, 8)
     movers = _cache_movers(text, kv)
     assert not movers, (
         f"{prog}/{layout}: a loop of the compiled program moves a whole "
@@ -502,9 +536,7 @@ def _latent_program(topo, prog):
     arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep)
     if prog == "decode_block":
         jitted = eng._program("decode_block")
-        args = (arg((8,), I32), arg((eng.decode_block_len, 2), jnp.uint32),
-                arg((8,), I32), arg((8,), I32), arg((8,), F32),
-                arg((8,), I32), arg((8,), F32))
+        args = (arg((6, 8), I32), arg((eng.decode_block_len, 2), jnp.uint32))
     else:
         jitted = eng._prefill_chunk_jit
         args = (arg((1, eng.prefill_chunk), I32),) + (arg((), I32),) * 3
@@ -577,10 +609,8 @@ def _cell_program(topo, prog, name):
     arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep)
     if prog == "decode_block":
         jitted = eng._program("decode_block")
-        args = (arg((slots,), I32),
-                arg((eng.decode_block_len, 2), jnp.uint32),
-                arg((slots,), I32), arg((slots,), I32), arg((slots,), F32),
-                arg((slots,), I32), arg((slots,), F32))
+        args = (arg((6, slots), I32),
+                arg((eng.decode_block_len, 2), jnp.uint32))
     else:
         jitted = eng._prefill_chunk_jit
         args = (arg((1, eng.prefill_chunk), I32),) + (arg((), I32),) * 3
@@ -629,6 +659,8 @@ def test_sala_cache_leaves_are_never_copied_whole(prog, topo, one_chip):
     and each stays row-major as it is resident."""
     compiled = _cell_program(topo, prog, "minicpm-sala-l12")
     lines = compiled.as_text().splitlines()
+    if prog == "decode_block":  # the cell's 8 slots, 8 tokens a block
+        _assert_one_pack_each_way(compiled.as_text(), 8, 8)
     leaves = {"k": r"bf16\[3,8,2,65536,128\]", "v": r"bf16\[3,8,2,65536,128\]",
               "kc": r"bf16\[3,8,2,4096,128\]",
               "state": r"f32\[9,8,32,128,128\]"}

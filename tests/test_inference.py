@@ -462,7 +462,8 @@ def test_round_program_table_name_and_record(tiny_model_kwargs, monkeypatch,
     seen = {}
     build = eng._program
 
-    def program(k, poison=False):
+    def program(k, poison=False, dev_tokens=False):
+        assert not dev_tokens  # host tokens ride in the packed operand
         def run(*args):  # the cache is donated: keep the shapes
             seen[k, poison] = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
@@ -490,7 +491,7 @@ def test_round_program_table_name_and_record(tiny_model_kwargs, monkeypatch,
 
     r = one_round(eng.init_cache())
     # (a) built by the first round that asked, and nothing else
-    assert set(eng._programs) == {(kind, False)}
+    assert set(eng._programs) == {(kind, False, False)}
     if kind == "decode_block":
         with pytest.raises(ValueError, match="spec_len"):
             eng.verify(params, r.cache, np.zeros((n, 1), np.int32), keys,
@@ -519,7 +520,7 @@ def test_round_program_table_name_and_record(tiny_model_kwargs, monkeypatch,
     # defined (the sampler's non-finite gate)
     hooks.on = True
     p = one_round(eng.init_cache())
-    assert set(eng._programs) == {(kind, False), (kind, True)}
+    assert set(eng._programs) == {(kind, False, False), (kind, True, False)}
     assert (kind, True) in seen
     toks = np.asarray(p.tokens)
     assert ((toks >= 0) & (toks < cfg.model.vocab_size)).all()

@@ -775,9 +775,7 @@ def test_the_decode_program_keeps_the_name_the_readers_find(toy):
     keys = jnp.zeros((engine.decode_block_len, 2), jnp.uint32)
     n = engine.slots
     text = engine._program("decode_block").lower(
-        params, engine.init_cache(), jnp.zeros(n, jnp.int32), keys,
-        -jnp.ones(n, jnp.int32), jnp.ones(n, jnp.int32),
-        jnp.zeros(n, F32), jnp.zeros(n, jnp.int32), jnp.ones(n, F32)
+        params, engine.init_cache(), jnp.zeros((6, n), jnp.int32), keys
     ).as_text().split("\n", 1)[0]
     assert "module @jit__decode_block_impl " in text
     assert any(p in "jit__decode_block_impl" for p in stats.DECODE_PROGRAMS)

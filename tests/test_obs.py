@@ -487,6 +487,13 @@ def test_serve_metrics_tracez_profilez(tmp_path):
             <= served | {"loop/idle"}
         assert labels("picotron_round_part_seconds") == {
             "issue/operands", "issue/enqueue", "sync/wait", "sync/fetch"}
+        # a round's host-device copies (ISSUE 38): one up and one down a
+        # dispatch, a family of two labels
+        assert {k: v for k, v in prom.items()
+                if k.startswith("picotron_round_copies_total")} == {
+            f'picotron_round_copies_total{{direction="{d}"}}':
+            prom['picotron_dispatch_seconds_count{kind="decode"}']
+            for d in ("h2d", "d2h")}
         assert "picotron_host_sync_seconds" not in mtext
         # the model-memory gauge (ISSUE 13): /statz and /metrics agree on
         # resident weight bytes — what the router's scrape reads to see

@@ -22,6 +22,7 @@ import jax
 from picotron_tpu import obs as obs_mod
 from picotron_tpu.inference import ContinuousBatcher, Request
 from picotron_tpu.obs import MetricsRegistry, Obs, SpanTracer, tracing
+from picotron_tpu.obs.metrics import parse_prometheus
 from test_obs import _ManualClock, _engine, _phase_reads
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -147,6 +148,12 @@ def test_a_redispatch_observes_its_parts_again_and_the_phase_once(
     parts, ph = _part_reads(engine.obs.registry), \
         _phase_reads(engine.obs.registry)
     assert all(parts[p]["count"] == 2 for p in PARTS)
+    # each dispatch packed and fetched for itself (ISSUE 38): the copies
+    # follow the dispatches, not the round, and a failed attempt made none
+    prom = parse_prometheus(engine.obs.registry.prometheus())
+    assert {k.split('"')[1]: v for k, v in prom.items()
+            if k.startswith("picotron_round_copies_total")} == {
+        "h2d": 2, "d2h": 2}
     assert ph["step/issue"]["count"] == ph["step/sync"]["count"] == 1
     assert parts["sync/wait"]["sum"] == pytest.approx(2 * 5e-3)
     assert ph["step/sync"]["sum"] == pytest.approx(2 * 5e-3)
