@@ -163,7 +163,10 @@ class ModelConfig:
     # only; its fields follow DeepSeek's) or "minicpm_sala" (lightning
     # linear-attention and block-sparse NoPE attention layers by
     # ``mixer_types``, a SwiGLU behind each — models/minicpm_sala.py,
-    # serving path only; its fields are at the end). The fields below are the
+    # serving path only; its fields are at the end) or "afmoe" (gated
+    # attention over a window or over everything by ``layer_types``, a
+    # SwiGLU or routed and shared experts behind it — models/afmoe.py,
+    # serving path only; its fields are the last). The fields below are the
     # published ``config.json`` keys of the DeepSeek block, by their own
     # names, and are read by no other block (but ``num_experts_per_tok``,
     # ``ep_size`` and ``ep_rank``, which both expert blocks read).
@@ -245,10 +248,42 @@ class ModelConfig:
     sparse_config: Optional[dict] = None
     first_layer: int = 0
     total_layers: int = 0
+    # "afmoe" (Trinity): the published keys of that block, beside
+    # ``layer_types`` ("sliding_attention" | "full_attention", one entry a
+    # layer held), ``num_experts_per_tok``, ``moe_intermediate_size``,
+    # ``n_group``/``topk_group`` (1: no group limit), ``ep_size``/``ep_rank``.
+    # ``num_experts`` counts the experts HELD here, those from ``ep_rank *
+    # num_experts`` on of a router ``num_experts * ep_size`` wide;
+    # ``num_dense_layers`` the leading layers held whose MLP is a SwiGLU.
+    # The expert layers held are published layers ``first_layer`` onward of
+    # a model ``total_layers`` deep (0: unplaced), which
+    # ``global_attn_every_n_layers`` holds ``layer_types`` to (a full layer
+    # where ``(index + 1) % n == 0``; 0: unchecked). No forward reads them.
+    sliding_window: int = 0
+    global_attn_every_n_layers: int = 0
+    num_dense_layers: int = 0
+    num_experts: int = 0
+    num_shared_experts: int = 0
+    route_norm: bool = True
+    route_scale: float = 1.0
+    score_func: str = "sigmoid"
+    mup_enabled: bool = False
+    # the published ``head_dim`` where it is not hidden_size / heads (afmoe:
+    # 128 of 3072 / 48); 0: derived, and it follows ``hidden_size``
+    head_dim: int = 0
 
-    @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
+
+def _get_head_dim(self) -> int:
+    return self._head_dim or self.hidden_size // self.num_attention_heads
+
+
+def _set_head_dim(self, value) -> None:
+    self._head_dim = int(value or 0)
+
+
+# a field for ``__init__`` and ``dataclasses.fields`` (a configuration may
+# list it), a property to every reader: derived unless it was given
+ModelConfig.head_dim = property(_get_head_dim, _set_head_dim)
 
 
 @dataclass
@@ -1097,16 +1132,18 @@ class Config:
                     f"dp_size ({d.dp_size}) — every layer param shards on an "
                     f"H-sized axis")
         if m.model_type not in ("llama", "deepseek_v32", "granitemoehybrid",
-                                "minicpm_sala"):
+                                "minicpm_sala", "afmoe"):
             raise ValueError(
                 f"unknown model_type {m.model_type!r} "
-                "(llama|deepseek_v32|granitemoehybrid|minicpm_sala)")
+                "(llama|deepseek_v32|granitemoehybrid|minicpm_sala|afmoe)")
         if m.model_type == "deepseek_v32":
             self._validate_deepseek_v32(for_training)
         if m.model_type == "granitemoehybrid":
             self._validate_granite_hybrid(for_training)
         if m.model_type == "minicpm_sala":
             self._validate_minicpm_sala(for_training)
+        if m.model_type == "afmoe":
+            self._validate_afmoe(for_training)
         if m.attention_impl not in ("auto", "sdpa", "flash"):
             raise ValueError(
                 f"unknown attention_impl {m.attention_impl!r} (auto|sdpa|flash)")
@@ -1730,10 +1767,145 @@ class Config:
                 f"be a multiple of sparse_config.kernel_stride ({st}): a "
                 "chunk writes whole rows of compressed keys")
 
+    def _validate_afmoe(self, for_training: bool) -> None:
+        """What ``models/afmoe.py`` needs of its keys, and what it cannot do
+        yet, each refused by name."""
+        d, m, inf = self.distributed, self.model, self.inference
+        who = "model_type 'afmoe'"
+        if for_training:
+            raise ValueError(
+                f"{who} is served, not trained: training is not implemented "
+                "for this block (no backward through the expert share; "
+                "train_step builds the Llama block only)")
+        if d.tp_size > 1:
+            raise ValueError(
+                f"{who} does not support tp_size > 1 (got {d.tp_size}): the "
+                "block holds no tp collectives and its rings are not "
+                "sharded; its share of a layer is ep_size/ep_rank")
+        if inf.dp_size > 1:
+            raise ValueError(
+                f"{who} does not support inference.dp_size > 1 (got "
+                f"{inf.dp_size}): the rings have no slot axis over 'dp'")
+        if inf.kv_layout == "paged":
+            raise ValueError(
+                f"{who} does not support inference.kv_layout 'paged' (nor "
+                "the prefix reuse that rests on it): one pool and one block "
+                "table cannot yet tell the layers that keep a sequence's "
+                "history from those that keep a window; set kv_layout: "
+                "'contiguous'")
+        if inf.kv_cache_dtype == "int8":
+            raise ValueError(
+                f"{who} does not support inference.kv_cache_dtype 'int8': "
+                "both kinds of K/V are stored in the model's dtype")
+        if inf.weight_dtype == "int8":
+            raise ValueError(
+                f"{who} does not support inference.weight_dtype 'int8': its "
+                "matmuls take dense weights only")
+        if inf.tenancy.tenants or inf.tenancy.manifest:
+            raise ValueError(
+                f"{who} does not support LoRA adapters (inference.tenancy): "
+                "the adapter pack is shaped for the Llama block's seven "
+                "projections")
+        if inf.spec_len > 0:
+            raise ValueError(
+                f"{who} does not support speculation (inference.spec_len "
+                f"{inf.spec_len}): a rejected draft's rows have already "
+                "overwritten the ring's oldest, and rewinding a length does "
+                "not bring them back")
+        if inf.attend_impl == "flash":
+            raise ValueError(
+                f"{who} does not support inference.attend_impl "
+                f"{inf.attend_impl!r}: the sliced flash-decode kernel reads "
+                "a prefix, not a ring ('auto' runs the stacked kernel for "
+                "the decode step)")
+        if inf.overlap:
+            raise ValueError(
+                f"{who} does not support inference.overlap: the lookahead "
+                "dispatch is not implemented for this block")
+        if inf.mixed_dispatch:
+            raise ValueError(
+                f"{who} does not support inference.mixed_dispatch: the "
+                "fused prefill lane embeds and heads through the Llama "
+                "block")
+        if inf.key_schedule == "slot":
+            raise ValueError(
+                f"{who} does not support inference.key_schedule 'slot': it "
+                "serves through the round-keyed programs only")
+        for name in ("sliding_window", "num_experts", "num_shared_experts",
+                     "num_experts_per_tok", "moe_intermediate_size",
+                     "ep_size"):
+            if getattr(m, name) < 1:
+                raise ValueError(f"{who} needs model.{name} >= 1")
+        kinds = ("sliding_attention", "full_attention")
+        lt = m.layer_types
+        if not lt or len(lt) != m.num_hidden_layers \
+                or any(t not in kinds for t in lt):
+            raise ValueError(
+                f"{who} needs model.layer_types: one of 'sliding_attention' "
+                f"| 'full_attention' for each of the {m.num_hidden_layers} "
+                f"layers (got {lt!r})")
+        if len(set(lt)) < 2:
+            raise ValueError(
+                f"{who} needs at least one 'sliding_attention' and one "
+                "'full_attention' layer in model.layer_types: the cache "
+                "holds a leaf of each kind")
+        if not 0 <= m.num_dense_layers < m.num_hidden_layers:
+            raise ValueError(
+                f"{who}: num_dense_layers {m.num_dense_layers} outside [0, "
+                f"num_hidden_layers {m.num_hidden_layers})")
+        every = m.global_attn_every_n_layers
+        if every:
+            n_moe = m.num_hidden_layers - m.num_dense_layers
+            if m.total_layers and (
+                    m.first_layer < m.num_dense_layers
+                    or m.first_layer + n_moe > m.total_layers):
+                raise ValueError(
+                    f"{who}: expert layers first_layer {m.first_layer} .. + "
+                    f"{n_moe} lie outside total_layers {m.total_layers} "
+                    f"behind num_dense_layers {m.num_dense_layers}")
+            first = m.first_layer if m.total_layers else m.num_dense_layers
+            for i, t in enumerate(lt):
+                pub = i if i < m.num_dense_layers \
+                    else first + i - m.num_dense_layers
+                if (t == kinds[1]) != ((pub + 1) % every == 0):
+                    raise ValueError(
+                        f"{who}: layer_types[{i}] {t!r} is published layer "
+                        f"{pub}, which global_attn_every_n_layers {every} "
+                        f"makes {kinds[(pub + 1) % every == 0]!r}")
+        if m.head_dim % 2:
+            raise ValueError(
+                f"{who}: head_dim {m.head_dim} must be even (RoPE rotates "
+                "halves)")
+        if m.num_attention_heads % m.num_key_value_heads:
+            raise ValueError(
+                f"{who}: num_attention_heads {m.num_attention_heads} must "
+                f"be a multiple of num_key_value_heads "
+                f"{m.num_key_value_heads}")
+        if not 0 <= m.ep_rank < m.ep_size:
+            raise ValueError(
+                f"{who}: ep_rank {m.ep_rank} outside [0, ep_size "
+                f"{m.ep_size})")
+        if m.num_experts_per_tok > m.num_experts * m.ep_size:
+            raise ValueError(
+                f"{who}: num_experts_per_tok {m.num_experts_per_tok} passes "
+                f"the router's width {m.num_experts * m.ep_size} "
+                "(num_experts x ep_size)")
+        for name, want in (("score_func", "sigmoid"), ("route_norm", True),
+                           ("n_group", 1), ("topk_group", 1),
+                           ("tie_word_embeddings", False),
+                           ("rope_scaling", None)):
+            if getattr(m, name) != want:
+                raise ValueError(
+                    f"{who} implements model.{name} = {want!r} only (got "
+                    f"{getattr(m, name)!r})")
+
     # ---- JSON round-trip (reference: train.py:62-63 consumes one JSON file) ----
 
     def to_dict(self) -> dict:
-        return dataclasses.asdict(self)
+        out = dataclasses.asdict(self)
+        # as it was given (0: derived), not as it reads
+        out["model"]["head_dim"] = self.model._head_dim
+        return out
 
     def to_json(self, path: str) -> None:
         with open(path, "w") as f:

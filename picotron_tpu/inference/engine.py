@@ -579,10 +579,13 @@ class InferenceEngine:
             self._insert_jit = jax.jit(kv_cache.insert_prefill,
                                        donate_argnums=(0,),
                                        out_shardings=cache_sh)
+            # a block that keeps rings sizes them by the chunk they must fit
+            ring = ({"prefill_chunk": self.prefill_chunk}
+                    if getattr(self.model, "RING_CACHE", False) else {})
             self._init_cache_jit = jax.jit(
                 partial(self.model.init_cache, m, self.slots,
                         self.max_seq_len, dtype=self.cache_dtype,
-                        quantized=self.quantized, tp=topo.tp_size),
+                        quantized=self.quantized, tp=topo.tp_size, **ring),
                 out_shardings=named_shardings(topo, self._cspecs))
         # what the cache is, read off its own shapes: the heads a row of
         # K and V holds (kv_cache.pack_factor; None without such leaves)

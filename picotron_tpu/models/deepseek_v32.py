@@ -437,27 +437,6 @@ def attention(lp, x, cos, sin, m: ModelConfig, cache, pos, layer, live):
 # --------------------------------------------------------------------------- #
 
 
-def route(scores, bias, m: ModelConfig) -> tuple:
-    """(experts [N, num_experts_per_tok] int32, weights [N, the same]
-    float32) from the sigmoid scores [N, width]: the choice is made on
-    ``scores + bias`` (groups by the sum of their two best, the best
-    ``topk_group`` groups, the best experts among them; ties to the lower
-    index), the weights are the unbiased scores of the chosen, normalised
-    to sum 1, times ``routed_scaling_factor``."""
-    N, W = scores.shape
-    choice = scores + bias
-    groups = choice.reshape(N, m.n_group, W // m.n_group)
-    group_score = jnp.sum(lax.top_k(groups, 2)[0], axis=-1)  # [N, n_group]
-    _, kept = lax.top_k(group_score, m.topk_group)
-    keep = jnp.zeros((N, m.n_group), bool).at[
-        jnp.arange(N)[:, None], kept].set(True)
-    choice = jnp.where(keep[:, :, None], groups, -jnp.inf).reshape(N, W)
-    _, experts = lax.top_k(choice, m.num_experts_per_tok)
-    w = jnp.take_along_axis(scores, experts, axis=-1)
-    w = w / jnp.sum(w, axis=-1, keepdims=True) * m.routed_scaling_factor
-    return experts.astype(jnp.int32), w
-
-
 def held_weights(experts, weights, m: ModelConfig):
     """[N, n_routed_experts] float32: each token's weight on each expert
     held here (``ep_rank * n_routed_experts`` onward), 0 where the token
@@ -478,8 +457,10 @@ def expert_mlp(lp, x, m: ModelConfig, live) -> tuple:
         logits = jnp.dot(x2.astype(jnp.float32),
                          lp["router"].astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
-        experts, weights = route(jax.nn.sigmoid(logits), lp["router_bias"],
-                                 m)
+        experts, weights = expert_share.route(
+            jax.nn.sigmoid(logits), lp["router_bias"],
+            k=m.num_experts_per_tok, n_group=m.n_group,
+            topk_group=m.topk_group, scale=m.routed_scaling_factor)
         w_held = held_weights(experts, weights, m) \
             * live.reshape(B * S, 1).astype(jnp.float32)
     y, assigned, hit = expert_share.share(lp, x2, w_held)

@@ -1,8 +1,8 @@
 """This chip's share of an expert layer: which of a router's experts are
-held here, their part of the routed sum, and what of it is counted. Both
-expert blocks (``deepseek_v32``, ``granite_hybrid``) route in their own way
-over the router's whole width and hand the choice here; the tree's leaves
-are named alike in both: ``w1``/``w3``/``w2`` ``[held, ...]`` a layer (the
+held here, their part of the routed sum, and what of it is counted. The
+expert blocks (``deepseek_v32``, ``granite_hybrid``, ``afmoe``) route over the
+router's whole width (the sigmoid router of two of them is ``route``) and
+hand the choice here; the tree's leaves are named alike in all: ``w1``/``w3``/``w2`` ``[held, ...]`` a layer (the
 group's ``UNSLICED`` stacks), ``ws_gate``/``ws_up``/``ws_down`` the shared
 expert."""
 
@@ -15,6 +15,29 @@ from jax import lax
 # a group's leaves the layer scan does not slice a layer at a time: the
 # layer function is handed the whole stack and its row in it (``lp["row"]``)
 UNSLICED = ("w1", "w3", "w2")
+
+
+def route(scores, bias, *, k: int, n_group: int = 1, topk_group: int = 1,
+          scale: float = 1.0, eps: float = 0.0) -> tuple:
+    """(experts [N, k] int32, weights [N, k] float32) from the sigmoid
+    scores [N, width]: the choice is made on ``scores + bias`` (with
+    ``n_group`` > 1: groups by the sum of their two best, the best
+    ``topk_group`` groups, the best experts among them; ties to the lower
+    index), the weights are the unbiased scores of the chosen, normalised to
+    sum 1 (``+ eps``), times ``scale``."""
+    N, W = scores.shape
+    choice = scores + bias
+    if n_group > 1:
+        groups = choice.reshape(N, n_group, W // n_group)
+        group_score = jnp.sum(lax.top_k(groups, 2)[0], axis=-1)
+        _, kept = lax.top_k(group_score, topk_group)
+        keep = jnp.zeros((N, n_group), bool).at[
+            jnp.arange(N)[:, None], kept].set(True)
+        choice = jnp.where(keep[:, :, None], groups, -jnp.inf).reshape(N, W)
+    _, experts = lax.top_k(choice, k)
+    w = jnp.take_along_axis(scores, experts, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps) * scale
+    return experts.astype(jnp.int32), w
 
 
 def held_weights(experts, weights, first: int, count: int):

@@ -443,9 +443,8 @@ def _stacked_blocks(L, block_t, max_nb):
                            max_nb=max_nb)
 
 
-def _stacked_kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
-                    acc_ref, m_ref, l_ref, own_ref, *, scale, block_t, rows,
-                    pg, max_nb):
+def _stacked_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg,
+                    max_nb, window=None, ring=0):
     """One (slot, kv-block) grid step of ``flash_decode_stacked``. The K
     block is ``block_t`` tokens of one layer and slot as a plain matrix:
     ``[block_t * rows, lanes]``, a row of it one (token, cache row) pair,
@@ -456,8 +455,15 @@ def _stacked_kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
     query head keeps the pairs of its own cache row (``own_ref``: the
     column's token offset where the rows match, past every bound where
     they do not). The other rows' products are exact discards: masked to
-    NEG_INF before the softmax, an exact zero in the value matmul."""
+    NEG_INF before the softmax, an exact zero in the value matmul.
+
+    With a ``window`` the slot's strip is a ring of ``ring`` rows and a
+    third scalar operand names the row the query's own key lies in: a row
+    is seen if it is live and fewer than ``window`` rows behind that one,
+    the ring's end joined to its start."""
     del layer_ref  # consumed by the index maps
+    last_ref, refs = (None, refs) if window is None else (refs[0], refs[1:])
+    q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, own_ref = refs
     b = pl.program_id(0)
     j = pl.program_id(1)
     L = len_ref[b]
@@ -480,12 +486,19 @@ def _stacked_kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
         if scale is not None:
             s = s * scale
         # token j * block_t + own is visible iff it is below the length
-        s = jnp.where(own_ref[...] < L - j * block_t, s, NEG_INF)
+        seen = own_ref[...] < L - j * block_t
+        if window is not None:
+            # and, of a ring, fewer than ``window`` rows behind the query's
+            age = last_ref[b] - j * block_t - own_ref[...]
+            seen &= jnp.where(age < 0, age + ring, age) < window
+        s = jnp.where(seen, s, NEG_INF)
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         # every live block holds a visible key for every head (token
         # j * block_t of its own row), so m_new is finite and a masked
-        # score's exp underflows to an exact zero
+        # score's exp underflows to an exact zero. (Of a ring one block may
+        # hold none: as the first it adds a finite sum that the next
+        # block's alpha = exp(NEG_INF - m_new) = 0 wipes; later, zeros.)
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
@@ -503,7 +516,8 @@ def _stacked_kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
 
 def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
                          block_t: int | None = None,
-                         interpret: bool = False):
+                         interpret: bool = False,
+                         window: int | None = None):
     """The ``S == 1`` decode attend of a contiguous, unquantized cache,
     reading layer ``layer`` of the STACKED leaves in place: one pass over
     K and V, live rows only.
@@ -561,8 +575,13 @@ def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
         _SUBLANE, _STACKED_KV_BLOCK // (rows * lanes * k.dtype.itemsize)))
     cols, max_nb = bt * rows, T // bt
     merged = (nl, B, T * rows, lanes)
+    lengths = lengths.astype(jnp.int32)
+    prefetch = (lengths, jnp.asarray(layer, jnp.int32).reshape(1))
+    if window is not None:
+        # rows written, and the row of the query's own key
+        prefetch = (jnp.minimum(lengths, T), prefetch[1], (lengths - 1) % T)
 
-    def kv_index(b, j, len_ref, layer_ref):
+    def kv_index(b, j, len_ref, layer_ref, *_):
         nb = _stacked_blocks(len_ref[b], bt, max_nb)
         return (layer_ref[0], b, jnp.maximum(jnp.minimum(j, nb - 1), 0), 0)
 
@@ -570,9 +589,10 @@ def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
     kv_spec = pl.BlockSpec((None, None, cols, lanes), kv_index)
     out = pl.pallas_call(
         functools.partial(_stacked_kernel, scale=scale, block_t=bt,
-                          rows=rows, pg=pg, max_nb=max_nb),
+                          rows=rows, pg=pg, max_nb=max_nb, window=window,
+                          ring=T),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(prefetch),
             grid=(B, max_nb),
             in_specs=[q_spec, kv_spec, kv_spec],
             out_specs=q_spec,
@@ -584,9 +604,9 @@ def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        name="flash_decode_attention",
-    )(lengths.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
-      qm, k.reshape(merged), v.reshape(merged))
+        name="flash_decode_attention" if window is None
+        else "flash_decode_ring",
+    )(*prefetch, qm, k.reshape(merged), v.reshape(merged))
     out = out[:, :nh].reshape(B, 1, rows, pg, lanes)
     if pack > 1:
         out = _own_lanes(out, pack)

@@ -17,6 +17,20 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
+# The run's persistent compilation cache (``utils.enable_compile_cache``:
+# one fixed directory under the checkout, or JAX_COMPILATION_CACHE_DIR).
+# ``_clear_jax_caches`` below drops every compiled program after every test
+# and six workers build the same toy engines over and over, so most of the
+# suite's time was compiling what had been compiled before (a file of 52
+# engine tests: 287 s without, 226 s from an empty directory; PR 39, when
+# the whole run took 1,438 s of the 1,470 it is given). The programs are
+# the same bytes either way; tests/test_chip_compile.py turns the cache off
+# around its described-chip compiles, which cannot be read back.
+from picotron_tpu.utils import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+
 import pytest  # noqa: E402
 
 from picotron_tpu.config import Config  # noqa: E402

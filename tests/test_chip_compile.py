@@ -186,6 +186,20 @@ def _decode_stacked(cell):
          ((leaf[1],), I32), ((), I32)]
 
 
+# the sliding layers' rings of the Trinity cell: 16 slots x (4096 + 512)
+RING_LEAF, RING_WINDOW = (7, 16, 4608, 8, 128), 4096
+
+
+def _decode_ring():
+    """flash_decode_stacked in its ring form (``window=``: a third scalar
+    operand, a second bound in the mask), on the Trinity cell's own ring
+    leaf with a traced layer index."""
+    return (lambda q, k, v, n, layer: flash_decode_stacked(
+        q, k, v, n, 128 ** -0.5, layer, window=RING_WINDOW)), \
+        [((RING_LEAF[1], 1, 48, 128), BF16), (RING_LEAF, BF16),
+         (RING_LEAF, BF16), ((RING_LEAF[1],), I32), ((), I32)]
+
+
 def _quant(m, k, n):
     return (lambda x, q, s: qm.quant_matmul_pallas(x, q, s)), \
         [((m, k), BF16), ((k, n), I8), ((n,), F32)]
@@ -209,6 +223,7 @@ CASES = {
        for name, (b, s) in DECODE_SHAPES.items()},
     **{f"decode_stacked_{cell}": (lambda cell=cell: _decode_stacked(cell))
        for cell in STACKED_LEAVES},
+    "decode_ring_trinity": _decode_ring,
     "quant_matmul_up": lambda: _quant(8, HID, FFN),
     "quant_matmul_down": lambda: _quant(8, FFN, HID),
     "quant_matmul_head": lambda: _quant(8, HID, VOCAB),

@@ -15,10 +15,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from engine_memo import memoized
 
 from picotron_tpu.config import Config
 from picotron_tpu.inference import InferenceEngine
 from picotron_tpu.models import deepseek_v32 as dsv
+from picotron_tpu.models import experts
 from picotron_tpu.ops import rope
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -65,6 +67,7 @@ def ref_config(model: dict) -> dict:
     return dict(model, torch_dtype=model["dtype"])
 
 
+@memoized
 def make_engine(model=None, **kw):
     cfg = make_config(model)
     engine = InferenceEngine(cfg, slots=2, max_seq_len=256,
@@ -198,6 +201,14 @@ def test_the_shares_add_up_to_the_uncut_expert_layer():
 
 
 # ---- (c) the router by hand -------------------------------------------------
+# (``experts.route`` since PR 39: the afmoe block calls it too)
+
+
+def route(scores, bias, m):
+    """The router as ``deepseek_v32.expert_mlp`` calls it."""
+    return experts.route(scores, bias, k=m.num_experts_per_tok,
+                         n_group=m.n_group, topk_group=m.topk_group,
+                         scale=m.routed_scaling_factor)
 
 
 def test_router_by_hand():
@@ -208,7 +219,7 @@ def test_router_by_hand():
                            0.9375, 0.125, 0.125,    # group 1: 1.0625
                            0.5625, 0.5625, 0.5625,  # group 2: 1.125
                            0.6875, 0.625, 0.0]])    # group 3: 1.3125, kept
-    experts, w = dsv.route(scores, jnp.zeros(12), m)
+    experts, w = route(scores, jnp.zeros(12), m)
     # the group limit: expert 3 has the best score and is not chosen
     assert experts.tolist() == [[0, 1, 9]]
     np.testing.assert_allclose(
@@ -217,7 +228,7 @@ def test_router_by_hand():
     # the bias moves the choice: +0.5 on expert 5 lifts group 1 (1.5625)
     # over group 3, and expert 3 comes in; the weights stay the unbiased
     # scores of the chosen
-    experts, w = dsv.route(scores, jnp.zeros(12).at[5].set(0.5), m)
+    experts, w = route(scores, jnp.zeros(12).at[5].set(0.5), m)
     assert experts.tolist() == [[3, 0, 1]]
     np.testing.assert_allclose(
         w[0], np.array([0.9375, 0.875, 0.75]) / 2.5625 * 2.5, rtol=1e-6)
@@ -225,7 +236,7 @@ def test_router_by_hand():
     # goes to the lower index; expert 4 is chosen third and weighs its
     # unbiased 0.125
     bias = jnp.zeros(12).at[4].set(0.75)
-    experts, w = dsv.route(scores, bias, m)
+    experts, w = route(scores, bias, m)
     assert experts.tolist() == [[3, 0, 4]]
     np.testing.assert_allclose(
         w[0], np.array([0.9375, 0.875, 0.125]) / 1.9375 * 2.5, rtol=1e-6)
@@ -240,6 +251,29 @@ def test_router_by_hand():
         TOY, n_routed_experts=3, ep_size=4, ep_rank=1, **shape)).model)
     np.testing.assert_allclose(
         held[0], np.array([0.9375, 0.125, 0.0]) / 1.9375 * 2.5, rtol=1e-6)
+
+
+def test_router_without_groups_takes_the_best_of_the_whole_width():
+    """``n_group = topk_group = 1`` (the afmoe block's router): no group
+    limit, the same bias-in-the-choice-only rule, ties to the lower index;
+    ``eps`` joins the normalising sum."""
+    scores = jnp.asarray([[0.875, 0.75, 0.125, 0.9375, 0.125, 0.125,
+                           0.5625, 0.5625, 0.5625, 0.6875, 0.625, 0.0]])
+    chosen, w = experts.route(scores, jnp.zeros(12), k=3, scale=2.5)
+    # expert 3, which the group limit above kept out, leads
+    assert chosen.tolist() == [[3, 0, 1]]
+    np.testing.assert_allclose(
+        w[0], np.array([0.9375, 0.875, 0.75]) / 2.5625 * 2.5, rtol=1e-6)
+    grouped, wg = experts.route(scores, jnp.zeros(12), k=3, n_group=1,
+                                topk_group=1, scale=2.5, eps=1e-20)
+    assert grouped.tolist() == chosen.tolist()
+    np.testing.assert_array_equal(np.asarray(wg), np.asarray(w))
+    # a bias of 0.3125 on expert 6 ties it with expert 0 behind expert 3:
+    # the lower index first, and it weighs its unbiased 0.5625
+    chosen, w = experts.route(scores, jnp.zeros(12).at[6].set(0.3125), k=3)
+    assert chosen.tolist() == [[3, 0, 6]]
+    np.testing.assert_allclose(
+        w[0], np.array([0.9375, 0.875, 0.5625]) / 2.375, rtol=1e-6)
 
 
 # ---- (d) the selection ------------------------------------------------------

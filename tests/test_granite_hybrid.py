@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from engine_memo import memoized
 
 from picotron_tpu.config import Config
 from picotron_tpu.inference import InferenceEngine
@@ -60,6 +61,7 @@ def make_config(model=None, **sections) -> Config:
         **sections})
 
 
+@memoized
 def make_engine(model=None, **kw):
     cfg = make_config(model)
     engine = InferenceEngine(cfg, slots=2, max_seq_len=128,
@@ -303,7 +305,7 @@ def test_the_state_of_a_bfloat16_model_is_float32_all_the_way(monkeypatch,
                 ssm_out, exponent_bits=8, mantissa_bits=7)
 
         monkeypatch.setattr(gh, "mamba_mixer", rounding)
-    _, engine, params = make_engine({"dtype": "bfloat16"})
+    _, engine, params = make_engine({"dtype": "bfloat16"}, fresh=True)
     _, _, cache = program_logits(engine, params, PROMPT)  # 3 chunks, 4 steps
     state = cache["ssm"][:, 0]
     assert state.dtype == jnp.float32 and cache["conv"].dtype == jnp.bfloat16
@@ -510,7 +512,7 @@ def test_stats_leave_the_programs_a_row_a_layer():
 def test_the_batcher_puts_the_counters_on_metrics():
     from picotron_tpu.inference import ContinuousBatcher, Request
 
-    _, engine, params = make_engine()
+    _, engine, params = make_engine(fresh=True)
     batcher = ContinuousBatcher(engine, params, seed=0)
     reqs = [Request(uid=f"r{i}", prompt=p, max_new_tokens=5)
             for i, p in enumerate((PROMPT, OTHER[:9], OTHER[:20]))]

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from engine_memo import memoized
 from jax import lax
 
 from picotron_tpu.config import Config
@@ -66,6 +67,7 @@ def make_config(model=None, **sections) -> Config:
         **sections})
 
 
+@memoized
 def make_engine(model=None, **kw):
     cfg = make_config(model)
     engine = InferenceEngine(cfg, **{"slots": 2, "max_seq_len": 256,
@@ -243,7 +245,7 @@ def test_a_stale_compressed_key_across_a_seam_would_show(toy, monkeypatch):
     monkeypatch.setattr(
         sala, "compress_block",
         lambda k, prev, st: block(k, jnp.zeros_like(prev), st))
-    _, engine, params = make_engine()
+    _, engine, params = make_engine(fresh=True)
     seq, got, _ = program_logits(engine, params, LONG[:230], steps=2)
     assert worst_rel_err(got, reference_rows(params, seq, 230)) > 1e-3
 
@@ -532,7 +534,7 @@ def test_the_state_of_a_bfloat16_model_is_float32_all_the_way(monkeypatch,
                                              mantissa_bits=7)
 
         monkeypatch.setattr(sala, "lightning_mixer", rounding)
-    _, engine, params = make_engine({"dtype": "bfloat16"})
+    _, engine, params = make_engine({"dtype": "bfloat16"}, fresh=True)
     _, _, cache = program_logits(engine, params, LONG[:75])  # 3 chunks + 4
     state = cache["state"][:, 0]
     assert state.dtype == jnp.float32 and cache["kc"].dtype == jnp.bfloat16
@@ -743,7 +745,7 @@ def test_stats_leave_the_programs_a_row_a_layer(toy):
 def test_the_batcher_puts_the_counters_on_metrics():
     from picotron_tpu.inference import ContinuousBatcher, Request
 
-    _, engine, params = make_engine()
+    _, engine, params = make_engine(fresh=True)
     batcher = ContinuousBatcher(engine, params, seed=0)
     reqs = [Request(uid=f"r{i}", prompt=p, max_new_tokens=5)
             for i, p in enumerate((LONG[:90], OTHER[:9], OTHER[:40]))]
@@ -803,9 +805,15 @@ def test_the_front_end_admits_a_full_house_of_long_prompts():
 
 
 def test_rehearsal_of_the_cell_computes_its_readers():
+    """Under six test workers a first token came later than the mix's
+    rehearsal lead-in of 1 s and the 2 s window that followed held a prefill
+    and no decode round (the driver's run before PR 39: the three counter
+    readers found nothing to divide by). A lead-in and a window of 4 s each
+    hold decode rounds whatever the first prefill waited for."""
     p = subprocess.run(
         [sys.executable, "benchmarks/run.py", "--workload", CELL, "--seed",
-         "3000000001", "--seconds", "2", "--trace", "2", "--rehearse"],
+         "3000000001", "--seconds", "4", "--trace", "2", "--rehearse",
+         "--set", "lead_in_seconds=4"],
         cwd=ROOT, capture_output=True, text=True, timeout=900)
     assert p.returncode == 0, p.stderr[-3000:]
     out = json.loads(p.stdout.strip().splitlines()[-1])

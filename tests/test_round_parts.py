@@ -306,7 +306,10 @@ def test_the_part_readers_are_listed_for_the_serving_cells():
     cells = {w["name"] for w in manifest["workloads"]}
     moved = {m["name"]: set(m.get("workloads", cells))
              for m in manifest["end_to_end"]}
-    entries = manifest["per_layer"][-len(NEW):]
+    # appended in this order by PR 37; later PRs append behind them
+    names = [m["name"] for m in manifest["per_layer"]]
+    at = names.index(NEW[0])
+    entries = manifest["per_layer"][at:at + len(NEW)]
     assert [m["name"] for m in entries] == NEW
     served = set()
     for m in entries:
