@@ -1223,11 +1223,11 @@ def run_dp(dp: int) -> dict:
         b = ContinuousBatcher(engine, params)
         skew = [0]
 
-        def on_token(uid, tok):
+        def on_tokens(uid, toks):
             occ = b.shard_occupancy()
             skew[0] = max(skew[0], max(occ) - min(occ))
 
-        b.on_token = on_token
+        b.on_tokens = on_tokens
         reqs = [Request("l0", [1, 2, 3, 4, 5], max_new_tokens=28),
                 Request("l1", [9, 8, 7, 6], max_new_tokens=28),
                 Request("s0", [11, 12], max_new_tokens=4),
@@ -1288,7 +1288,7 @@ def run_overlap(synthetic_s: float) -> dict:
     same seed, same per-slot key schedule. The tiny CPU model produces
     no hideable device time of its own, so the batcher's synthetic-sync
     knob pads every round's device window to ``synthetic_s`` and an
-    ``on_token`` sleeper injects per-token host delivery work sized so
+    ``on_tokens`` sleeper injects per-token host delivery work sized so
     per-round host work matches it — the "host work and device time
     comparable" regime the pipeline exists for. Off mode pays
     device + host serially per round; on mode hides the host walk of
@@ -1343,7 +1343,7 @@ def run_overlap(synthetic_s: float) -> dict:
         b.run([Request(f"warm{i}", [3, 1, 4, 1, 5],
                        max_new_tokens=block) for i in range(slots)])
         b._synthetic_sync_s = synthetic_s
-        b.on_token = lambda uid, tok: time.sleep(host_tok_s)
+        b.on_tokens = lambda uid, toks: time.sleep(host_tok_s * len(toks))
         reqs = [Request(f"r{i}", [(7 * i + j) % 199 + 1 for j in range(5)],
                         max_new_tokens=new_toks) for i in range(slots)]
         t0 = time.perf_counter()
@@ -1476,21 +1476,22 @@ def run_mixed() -> dict:
             fired: set = set()
             d0 = f"d{rep}.0"
 
-            def on_token(uid, tok, t_tok=t_tok, sub_t=sub_t,
-                         fired=fired, d0=d0, rep=rep):
-                t_tok.setdefault(uid, []).append(time.perf_counter())
-                k = (arrive_at_tok.get(len(t_tok[uid]))
-                     if uid == d0 else None)
-                if with_prefill and k is not None and k not in fired:
-                    fired.add(k)
-                    r = Request(f"L{rep}.{k}",
-                                [(5 * k + 3 * j) % 199 + 1
-                                 for j in range(long_prompt)],
-                                max_new_tokens=4)
-                    sub_t[r.uid] = time.perf_counter()
-                    b.submit(r)
+            def on_tokens(uid, toks, t_tok=t_tok, sub_t=sub_t,
+                          fired=fired, d0=d0, rep=rep):
+                ts = t_tok.setdefault(uid, [])
+                for _ in toks:
+                    ts.append(time.perf_counter())
+                    k = arrive_at_tok.get(len(ts)) if uid == d0 else None
+                    if with_prefill and k is not None and k not in fired:
+                        fired.add(k)
+                        r = Request(f"L{rep}.{k}",
+                                    [(5 * k + 3 * j) % 199 + 1
+                                     for j in range(long_prompt)],
+                                    max_new_tokens=4)
+                        sub_t[r.uid] = time.perf_counter()
+                        b.submit(r)
 
-            b.on_token = on_token
+            b.on_tokens = on_tokens
             # the decoders: short (sub-chunk) prompts, long streams,
             # and a TPOT SLO so the off leg's admissions run through
             # the ARMED prefill gate — serial+gate, not bare serial

@@ -204,7 +204,7 @@ class ContinuousBatcher:
     """
 
     def __init__(self, engine, params, seed: int = 0, clock=time.monotonic,
-                 drafter=None, on_token: Optional[Callable] = None,
+                 drafter=None, on_tokens: Optional[Callable] = None,
                  obs=None):
         self.engine = engine
         self.params = params
@@ -218,10 +218,11 @@ class ContinuousBatcher:
         # each see ONE input sharding, the first call and every later one
         self._key = jax.device_put(jax.random.PRNGKey(seed),
                                    engine.key_sharding)
-        # streaming hook: called as on_token(uid, token) for every token a
-        # request emits, from inside step()/run() — the serve front end
-        # pushes these straight into the response stream
-        self.on_token = on_token
+        # streaming hook: called as on_tokens(uid, tokens) once a slot and
+        # round with the tokens the request emitted in it (a list of one at
+        # admission), from inside step()/run() — the serve front end pushes
+        # each list into the response stream as one event
+        self.on_tokens = on_tokens
         # speculative engines get a drafter (selected by
         # inference.drafter — the prompt-lookup n-gram default or the
         # EAGLE-style learned head — or injected, e.g. a scripted drafter
@@ -315,6 +316,10 @@ class ContinuousBatcher:
             "picotron_ttft_seconds", "submit -> first token")
         self._tokens_total = reg.counter(
             "picotron_generated_tokens_total", "tokens emitted to streams")
+        self._stream_events_total = reg.counter(
+            "picotron_stream_events_total",
+            "hand-offs of tokens to streams, one a slot and round "
+            "(generated tokens / this = tokens an event)")
         self._draft_proposed_total = reg.counter(
             "picotron_draft_proposed_total",
             "draft tokens proposed (speculative engines)")
@@ -339,6 +344,7 @@ class ContinuousBatcher:
         # families — one instrument set, two renderings, like the global
         # counters above
         self._tenant_stats: dict = {}
+        self._tenant_tokens_total: dict = {}  # tenant -> its token counter
         # prefill tokens admitted THIS scheduler round (the SLO-aware
         # chunked-prefill interleaving budget — see _prefill_gate)
         self._round_prefill_tokens = 0
@@ -448,7 +454,7 @@ class ContinuousBatcher:
             raise ValueError(f"request {req.uid!r}: empty prompt")
         if req.max_new_tokens < 1:
             # a zero-budget request would occupy a slot forever: _remaining()
-            # is 0 from admission on, so _token_done() never fires to retire it
+            # is 0 from admission on, so _tokens_done() never fires to retire it
             raise ValueError(
                 f"request {req.uid!r}: max_new_tokens must be >= 1 "
                 f"(got {req.max_new_tokens})")
@@ -558,6 +564,17 @@ class ContinuousBatcher:
                   "prefill_deferred": 0, "prefill_preempts": 0}
             self._tenant_stats[name] = st
         return st
+
+    def _tenant_tokens(self, req: Request):
+        """The tenant's ``picotron_tenant_tokens_total`` child, resolved in
+        the registry once a tenant: delivery bumps it every slot and round."""
+        name = self._tname(req)
+        c = self._tenant_tokens_total.get(name)
+        if c is None:
+            c = self._tenant_tokens_total[name] = self.obs.registry.counter(
+                "picotron_tenant_tokens_total",
+                "tokens emitted to streams, by tenant", tenant=name)
+        return c
 
     def _tenant_count(self, req: Request, state: str) -> None:
         self._tstat(req)[state] += 1
@@ -918,37 +935,53 @@ class ContinuousBatcher:
                   self.engine.max_seq_len - len(r.prompt))
         return max(cap - len(s.generated), 0)
 
-    def _token_done(self, i: int, tok: int) -> None:
-        """Record one generated token for slot i; retire on EOS/budget."""
+    def _tokens_done(self, i: int, toks: list) -> None:
+        """Record the tokens slot i produced this round (a list of one at
+        admission), in order; retire on EOS/budget. The stop is found once:
+        the request's EOS if the round drew it, else the budget / window
+        cut; rows behind it are dropped. One accounting pass and one
+        ``on_tokens`` call, whatever the count."""
         s = self._slots[i]
-        s.generated.append(tok)
-        self.generated_tokens += 1
-        self._tokens_total.inc()
-        self._tstat(s.req)["tokens"] += 1
-        self.obs.registry.counter(
-            "picotron_tenant_tokens_total",
-            "tokens emitted to streams, by tenant",
-            tenant=self._tname(s.req)).inc()
+        r = s.req
+        # submit() holds every request to room for one token at least
+        room = max(self._remaining(i), 1)
+        toks = toks[:room]
+        reason = "length" if len(toks) == room else None
+        if r.eos_id is not None and r.eos_id in toks:
+            toks = toks[: toks.index(r.eos_id) + 1]
+            reason = "eos"
+        n = len(toks)
+        s.generated.extend(toks)
+        self.generated_tokens += n
+        self._tokens_total.inc(n)
+        self._stream_events_total.inc()
+        self._tstat(r)["tokens"] += n
+        self._tenant_tokens(r).inc(n)
         if s.ttft_s is None and s.submit_t is not None:
             s.ttft_s = self._clock() - s.submit_t
             self._ttft_hist.observe(s.ttft_s)
             self.obs.registry.histogram(
                 "picotron_tenant_ttft_seconds",
                 "submit -> first token, by tenant",
-                tenant=self._tname(s.req)).observe(s.ttft_s)
-            if s.req.ttft_slo_ms is not None:
-                self._tenant_slo(s.req, "ttft",
-                                 s.ttft_s * 1000.0 <= s.req.ttft_slo_ms)
-        if self.on_token is not None:
-            self.on_token(s.req.uid, tok)
-        r = s.req
-        if r.eos_id is not None and tok == r.eos_id:
-            self._finish(i, "eos")
-        elif (len(s.generated) >= r.max_new_tokens
-              or len(r.prompt) + len(s.generated) >= self.engine.max_seq_len):
-            self._finish(i, "length")
+                tenant=self._tname(r)).observe(s.ttft_s)
+            if r.ttft_slo_ms is not None:
+                self._tenant_slo(r, "ttft",
+                                 s.ttft_s * 1000.0 <= r.ttft_slo_ms)
+        if self.on_tokens is not None:
+            self.on_tokens(r.uid, toks)
+        if reason is not None:
+            self._finish(i, reason)
         else:
-            self._last_tok[i] = tok
+            self._last_tok[i] = toks[-1]
+
+    def _deliver_round(self, toks, counts) -> None:
+        """Hand every live slot the prefix it produced this round
+        (``toks[i, :counts[i]]``). The device already stopped each row at
+        EOS/budget; ``_tokens_done`` applies the same rules host-side."""
+        rows, counts = toks.tolist(), counts.tolist()
+        for i, n in enumerate(counts):
+            if n > 0 and self._slots[i] is not None:
+                self._tokens_done(i, rows[i][:n])
 
     def _prefill_into(self, req: Request, i: int, key=None):
         """Prefill ``req`` into slot ``i`` (one-shot or chunked) and return
@@ -1377,7 +1410,7 @@ class ContinuousBatcher:
                 # (round N+1's input): an in-flight round only reads it
                 # through its snapshotted operand, so this patch is safe
                 self._dev_last = self._dev_tok().at[i].set(first)
-            self._token_done(i, first)
+            self._tokens_done(i, [first])
 
     # ---- mixed prefill–decode dispatch (the fused lane) -------------------
 
@@ -1388,7 +1421,7 @@ class ContinuousBatcher:
         solo prefill dispatch ever issued. Admission accounting (counters,
         queue-wait, epoch bump, sampling rows, controller/drafter resets)
         mirrors the serial seat; the first token — and with it TTFT and
-        ``_token_done`` — arrives when the final chunk lands."""
+        ``_tokens_done`` — arrives when the final chunk lands."""
         sh = i // self.engine.slots_per_shard
         submit_t = self._submit_t.pop(req.uid, None)
         root = self._req_spans.get(req.uid)
@@ -1528,7 +1561,7 @@ class ContinuousBatcher:
         """Deliver one round's lane results: confirm each fed chunk
         (paged host length, ``lane`` span, dispatch accounting) and, on
         a prompt's FINAL chunk, draw/record the first token — the
-        ``_token_done`` seat flip that turns the prefilling occupant
+        ``_tokens_done`` seat flip that turns the prefilling occupant
         into a decoder next round. ``_lane_scratch`` holds the round's
         (lane_out, lane_hid); a round that never delivered (all-failed
         isolation) rewinds ``fed_end`` so the chunk re-feeds — its
@@ -1595,7 +1628,7 @@ class ContinuousBatcher:
                 # seed the device-carried last-token row (round N+1's
                 # input) exactly like a serial admission's seat patch
                 self._dev_last = self._dev_tok().at[i].set(first)
-            self._token_done(i, first)
+            self._tokens_done(i, [first])
 
     # dp rebalance discipline (the fleet controller's hysteresis/cooloff
     # shape, applied to slot placement): act only past a real skew, then
@@ -1863,16 +1896,7 @@ class ContinuousBatcher:
         for i in failed:
             if self._slots[i] is not None:
                 self._finish(i, "error")
-        for i in range(len(self._slots)):
-            if self._slots[i] is None:
-                continue
-            # the device already stopped this row at EOS/budget; walking the
-            # produced prefix through _token_done applies the same rules
-            # host-side (appending the tokens and retiring the slot)
-            for t in toks[i, : counts[i]]:
-                if self._slots[i] is None:  # device/host rule mismatch guard
-                    break
-                self._token_done(i, int(t))
+        self._deliver_round(toks, counts)
         self._lane_land(feeds)
         self._host_work_hist.observe(
             max(0.0, self._clock() - t_step0 - self._step_sync_wait))
@@ -2128,13 +2152,7 @@ class ContinuousBatcher:
         for i in failed:
             if self._slots[i] is not None:
                 self._finish(i, "error")
-        for i in range(len(self._slots)):
-            if self._slots[i] is None:
-                continue
-            for t in toks[i, : counts[i]]:
-                if self._slots[i] is None:
-                    break
-                self._token_done(i, int(t))
+        self._deliver_round(toks, counts)
         self._lane_land(feeds)
 
     def _sync_inflight(self, next_t0=None) -> None:
@@ -2218,13 +2236,7 @@ class ContinuousBatcher:
                 s.dispatches += 1
                 if self.controller is not None:
                     self.controller.after_round(i)
-        for i in range(len(self._slots)):
-            if self._slots[i] is None or counts[i] <= 0:
-                continue
-            for t in toks[i, : counts[i]]:
-                if self._slots[i] is None:
-                    break
-                self._token_done(i, int(t))
+        self._deliver_round(toks, counts)
         if rec.get("feeds"):
             # the round's lane chunk lands with its outputs: confirmed
             # host lengths, lane span, and — on the final chunk — the
@@ -2454,7 +2466,7 @@ class ContinuousBatcher:
         lifetime totals, the per-slot and per-drafter registry counter
         families the controller and the bench read, and the controller's
         obs-off shadow; the shared step() tail walks the emitted prefixes
-        through ``_token_done`` exactly like a decode block's."""
+        through ``_tokens_done`` exactly like a decode block's."""
         g = self.engine.spec_len
         t_round = self._clock()
         tokens = self._draft(lens, kinds)

@@ -1462,13 +1462,20 @@ class InferenceEngine:
 
     def _strip_stats(self, out: tuple) -> tuple:
         """Take a counting block's stats (the dispatch's last output,
-        [layers, counters] int32) off ``out`` and keep it, still on the device, for
+        [layers, counters] int32) off ``out`` and keep it for
         ``take_stats``; every caller sees the tuple the Llama block
         returns."""
         if not self._n_stats:
             return out
-        self._stats_pending.append(out[-1])
+        self._keep_stats(out[-1])
         return out[:-1]
+
+    def _keep_stats(self, stats) -> None:
+        """Keep a dispatch's stats for ``take_stats``. The copy to the host
+        is asked for here, so it leaves with the program's end and the read
+        at delivery (``step/deliver``) waits for nothing."""
+        stats.copy_to_host_async()
+        self._stats_pending.append(stats)
 
     def take_stats(self):
         """What the block counted in the dispatches since the last call,
@@ -2338,8 +2345,7 @@ class InferenceEngine:
             # host has waited for it: the sync reads bytes that are there
             out["packed"].copy_to_host_async()
         if "stats" in out:
-            # kept, still on the device, for ``take_stats``
-            self._stats_pending.append(out.pop("stats"))
+            self._keep_stats(out.pop("stats"))  # for ``take_stats``
         lane = ((out.pop("lane_out"), out.pop("lane_hidden", None))
                 if self.mixed else None)
         res = RoundResult(verify=kind == "verify", lane=lane, **out)

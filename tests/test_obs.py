@@ -880,8 +880,8 @@ def test_round_phases_tile_the_step(overlap, monkeypatch):
     costing(b, "_admit", 2e-3)              # step/admit
     costing(engine, "decode_block", 3e-3)   # step/issue
     costing(b, "_synthetic_wait", 5e-3)     # step/sync
-    costing(b, "_token_done", 0.5e-3)       # step/deliver (and admit's
-    #                                         first token of a request)
+    costing(b, "_tokens_done", 0.5e-3)      # step/deliver, once a slot
+    #                          and round (and admit's first token, alone)
     for i in range(3):
         b.submit(Request(f"p{i}", [3 + i, 5, 7], max_new_tokens=9))
     rounds, wall = 0, 0.0
@@ -902,9 +902,11 @@ def test_round_phases_tile_the_step(overlap, monkeypatch):
     assert ph["step/plan"]["sum"] == pytest.approx(rounds * 1e-3)
     assert ph["step/issue"]["sum"] == pytest.approx(n * 3e-3)
     assert ph["step/sync"]["sum"] == pytest.approx(n * 5e-3)
-    tokens = 3 * 9
+    # 3 first tokens at admission, then blocks of 2: 9 = 1 + 4 x 2
+    events = b._stream_events_total.value
+    assert events == 3 * 5 and b.generated_tokens == 3 * 9
     assert ph["step/admit"]["sum"] + ph["step/deliver"]["sum"] \
-        == pytest.approx(rounds * 2e-3 + tokens * 0.5e-3)
+        == pytest.approx(rounds * 2e-3 + events * 0.5e-3)
     assert sum(v["sum"] for v in ph.values()) == pytest.approx(wall)
     # the ring holds the same tiles: in time order they abut
     tiles = sorted((s for s in engine.obs.tracer.spans()

@@ -536,7 +536,7 @@ def test_waiter_maps_only_mutated_under_mu():
             if kind == "done":
                 res = payload
             else:
-                toks.append(payload)
+                toks.extend(payload)
         assert res.finish_reason == "length" and res.tokens == toks
         assert len(res.tokens) == 4
         assert not front.dead
