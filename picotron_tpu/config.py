@@ -166,7 +166,11 @@ class ModelConfig:
     # serving path only; its fields are at the end) or "afmoe" (gated
     # attention over a window or over everything by ``layer_types``, a
     # SwiGLU or routed and shared experts behind it — models/afmoe.py,
-    # serving path only; its fields are the last). The fields below are the
+    # serving path only) or "mimo_v2" (full and sliding attention by
+    # ``hybrid_layer_pattern`` with K/V heads counted by kind, keys wider
+    # than values, a learned sink in the sliding softmax, a SwiGLU or routed
+    # experts behind it — models/mimo_v2.py, serving path only; its fields
+    # are the last). The fields below are the
     # published ``config.json`` keys of the DeepSeek block, by their own
     # names, and are read by no other block (but ``num_experts_per_tok``,
     # ``ep_size`` and ``ep_rank``, which both expert blocks read).
@@ -193,7 +197,9 @@ class ModelConfig:
     scoring_func: str = "sigmoid"
     topk_method: str = "noaux_tc"
     norm_topk_prob: bool = True
-    moe_layer_freq: int = 1
+    # an int for ``deepseek_v32`` (1: every layer behind the dense ones); a
+    # list for ``mimo_v2``, one entry a layer held (0: SwiGLU, 1: experts)
+    moe_layer_freq: Any = 1
     first_k_dense_replace: int = 0
     num_nextn_predict_layers: int = 0
     rope_scaling: Optional[dict] = None  # the published YaRN group, whole
@@ -268,6 +274,30 @@ class ModelConfig:
     route_scale: float = 1.0
     score_func: str = "sigmoid"
     mup_enabled: bool = False
+    # "mimo_v2" (MiMo-V2.5's language model): the published keys of that
+    # block, beside ``head_dim``/``v_head_dim`` (keys wider than values),
+    # ``sliding_window``, ``n_routed_experts`` (the experts HELD here of a
+    # router ``n_routed_experts * ep_size`` wide), ``num_experts_per_tok``,
+    # ``moe_intermediate_size``, ``moe_layer_freq`` (a list), ``scoring_func``,
+    # ``topk_method``, ``norm_topk_prob``, ``n_group``/``topk_group``,
+    # ``rope_scaling`` (type "default": none), ``ep_size``/``ep_rank``,
+    # ``first_layer``/``total_layers``. ``hybrid_layer_pattern`` names each
+    # held layer's attention (0 full, 1 sliding); the ``swa_*`` keys are the
+    # sliding layers' heads, widths and RoPE base, ``num_key_value_heads``,
+    # ``head_dim``, ``v_head_dim`` and ``rope_theta`` the full layers';
+    # ``partial_rotary_factor`` is the share of a head RoPE rotates;
+    # ``layernorm_epsilon`` repeats ``rms_norm_eps`` (0: not given).
+    hybrid_layer_pattern: Optional[list] = None
+    swa_num_attention_heads: int = 0
+    swa_num_key_value_heads: int = 0
+    swa_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 10000.0
+    partial_rotary_factor: float = 1.0
+    attention_value_scale: float = 1.0
+    add_swa_attention_sink_bias: bool = False
+    add_full_attention_sink_bias: bool = False
+    layernorm_epsilon: float = 0.0
     # the published ``head_dim`` where it is not hidden_size / heads (afmoe:
     # 128 of 3072 / 48); 0: derived, and it follows ``hidden_size``
     head_dim: int = 0
@@ -1132,10 +1162,10 @@ class Config:
                     f"dp_size ({d.dp_size}) — every layer param shards on an "
                     f"H-sized axis")
         if m.model_type not in ("llama", "deepseek_v32", "granitemoehybrid",
-                                "minicpm_sala", "afmoe"):
+                                "minicpm_sala", "afmoe", "mimo_v2"):
             raise ValueError(
-                f"unknown model_type {m.model_type!r} "
-                "(llama|deepseek_v32|granitemoehybrid|minicpm_sala|afmoe)")
+                f"unknown model_type {m.model_type!r} (llama|deepseek_v32|"
+                "granitemoehybrid|minicpm_sala|afmoe|mimo_v2)")
         if m.model_type == "deepseek_v32":
             self._validate_deepseek_v32(for_training)
         if m.model_type == "granitemoehybrid":
@@ -1144,6 +1174,8 @@ class Config:
             self._validate_minicpm_sala(for_training)
         if m.model_type == "afmoe":
             self._validate_afmoe(for_training)
+        if m.model_type == "mimo_v2":
+            self._validate_mimo_v2(for_training)
         if m.attention_impl not in ("auto", "sdpa", "flash"):
             raise ValueError(
                 f"unknown attention_impl {m.attention_impl!r} (auto|sdpa|flash)")
@@ -1767,11 +1799,11 @@ class Config:
                 f"be a multiple of sparse_config.kernel_stride ({st}): a "
                 "chunk writes whole rows of compressed keys")
 
-    def _validate_afmoe(self, for_training: bool) -> None:
-        """What ``models/afmoe.py`` needs of its keys, and what it cannot do
-        yet, each refused by name."""
-        d, m, inf = self.distributed, self.model, self.inference
-        who = "model_type 'afmoe'"
+    def _refuse_beyond_ring_serving(self, who: str,
+                                    for_training: bool) -> None:
+        """What no block that keeps rings beside full-length K/V (``afmoe``,
+        ``mimo_v2``) can do yet, each refused by name."""
+        d, inf = self.distributed, self.inference
         if for_training:
             raise ValueError(
                 f"{who} is served, not trained: training is not implemented "
@@ -1831,6 +1863,13 @@ class Config:
             raise ValueError(
                 f"{who} does not support inference.key_schedule 'slot': it "
                 "serves through the round-keyed programs only")
+
+    def _validate_afmoe(self, for_training: bool) -> None:
+        """What ``models/afmoe.py`` needs of its keys, and what it cannot do
+        yet, each refused by name."""
+        m = self.model
+        who = "model_type 'afmoe'"
+        self._refuse_beyond_ring_serving(who, for_training)
         for name in ("sliding_window", "num_experts", "num_shared_experts",
                      "num_experts_per_tok", "moe_intermediate_size",
                      "ep_size"):
@@ -1894,6 +1933,90 @@ class Config:
                            ("n_group", 1), ("topk_group", 1),
                            ("tie_word_embeddings", False),
                            ("rope_scaling", None)):
+            if getattr(m, name) != want:
+                raise ValueError(
+                    f"{who} implements model.{name} = {want!r} only (got "
+                    f"{getattr(m, name)!r})")
+
+    def _validate_mimo_v2(self, for_training: bool) -> None:
+        """What ``models/mimo_v2.py`` needs of its keys, and what it cannot
+        do yet, each refused by name."""
+        m = self.model
+        who = "model_type 'mimo_v2'"
+        self._refuse_beyond_ring_serving(who, for_training)
+        for name in ("sliding_window", "n_routed_experts",
+                     "num_experts_per_tok", "moe_intermediate_size",
+                     "ep_size", "v_head_dim", "swa_num_attention_heads",
+                     "swa_num_key_value_heads", "swa_head_dim",
+                     "swa_v_head_dim"):
+            if getattr(m, name) < 1:
+                raise ValueError(f"{who} needs model.{name} >= 1")
+        n = m.num_hidden_layers
+        for name in ("hybrid_layer_pattern", "moe_layer_freq"):
+            got = getattr(m, name)
+            if not isinstance(got, list) or len(got) != n \
+                    or any(v not in (0, 1) for v in got):
+                raise ValueError(
+                    f"{who} needs model.{name}: 0 or 1 for each of the {n} "
+                    f"layers (got {got!r})")
+        if len(set(m.hybrid_layer_pattern)) < 2:
+            raise ValueError(
+                f"{who} needs at least one full (0) and one sliding (1) "
+                "layer in model.hybrid_layer_pattern: the cache holds "
+                "leaves of each kind")
+        if m.total_layers and (m.first_layer < 1 or m.first_layer + n - 1
+                               > m.total_layers):
+            raise ValueError(
+                f"{who}: layers first_layer {m.first_layer} .. + {n - 1} "
+                f"lie outside total_layers {m.total_layers} behind the "
+                "leading layer")
+        for heads, kv, hd, kind in (
+                (m.num_attention_heads, m.num_key_value_heads, m.head_dim,
+                 "full"),
+                (m.swa_num_attention_heads, m.swa_num_key_value_heads,
+                 m.swa_head_dim, "sliding")):
+            if heads % kv:
+                raise ValueError(
+                    f"{who}: the {kind} layers' {heads} query heads must be "
+                    f"a multiple of their {kv} K/V heads")
+            rot = int(hd * m.partial_rotary_factor)
+            if rot < 2 or rot % 2 or rot > hd:
+                raise ValueError(
+                    f"{who}: partial_rotary_factor {m.partial_rotary_factor}"
+                    f" of the {kind} layers' head_dim {hd} rotates {rot} "
+                    "dimensions: an even count in [2, head_dim] is needed "
+                    "(RoPE rotates halves)")
+        if m.num_attention_heads * m.v_head_dim \
+                != m.swa_num_attention_heads * m.swa_v_head_dim \
+                or int(m.head_dim * m.partial_rotary_factor) \
+                != int(m.swa_head_dim * m.partial_rotary_factor):
+            raise ValueError(
+                f"{who}: both kinds of layer must rotate as many dimensions "
+                "(one table holds both bases) and hand W_o as many columns")
+        if not 0 <= m.ep_rank < m.ep_size:
+            raise ValueError(
+                f"{who}: ep_rank {m.ep_rank} outside [0, ep_size "
+                f"{m.ep_size})")
+        if m.num_experts_per_tok > m.n_routed_experts * m.ep_size:
+            raise ValueError(
+                f"{who}: num_experts_per_tok {m.num_experts_per_tok} passes "
+                f"the router's width {m.n_routed_experts * m.ep_size} "
+                "(n_routed_experts x ep_size)")
+        if m.layernorm_epsilon and m.layernorm_epsilon != m.rms_norm_eps:
+            raise ValueError(
+                f"{who}: layernorm_epsilon {m.layernorm_epsilon} is not "
+                f"rms_norm_eps {m.rms_norm_eps} (the norms read the latter)")
+        if (m.rope_scaling or {}).get("rope_type", "default") != "default":
+            raise ValueError(
+                f"{who} implements model.rope_scaling of type 'default' "
+                f"(none) only (got {m.rope_scaling!r})")
+        for name, want in (("scoring_func", "sigmoid"),
+                           ("topk_method", "noaux_tc"),
+                           ("norm_topk_prob", True), ("n_group", 1),
+                           ("topk_group", 1), ("n_shared_experts", 0),
+                           ("add_swa_attention_sink_bias", True),
+                           ("add_full_attention_sink_bias", False),
+                           ("tie_word_embeddings", False)):
             if getattr(m, name) != want:
                 raise ValueError(
                     f"{who} implements model.{name} = {want!r} only (got "

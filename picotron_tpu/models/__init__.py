@@ -14,8 +14,8 @@ whatever the block:
   function, how many layers)]``, scanned one after the other over one cache;
   every layer function keeps ``llama.decoder_layer``'s contract and is
   handed its global index (a block whose cache leaves run over different
-  layers, ``granite_hybrid``, ``minicpm_sala`` or ``afmoe``, finds its own
-  row from it); in a decode
+  layers, ``granite_hybrid``, ``minicpm_sala``, ``afmoe`` or ``mimo_v2``,
+  finds its own row from it); in a decode
   block its cache dict also holds ``"active"`` [B] (parked and in budget),
   which a block that keeps K/V alone need not read;
 - ``UNSLICED``: names of a group's leaves the scan hands its layers
@@ -26,7 +26,9 @@ whatever the block:
   (K/V heads for the Llama block, as many to a row as fill its lanes on a
   'tp' axis that wide; latent rows for ``deepseek_v32``; K/V, compressed
   keys and a float32 state side by side for ``minicpm_sala``; full-length
-  K/V and rings of a window's rows side by side for ``afmoe``);
+  K/V and rings of a window's rows side by side for ``afmoe``; the same
+  with keys wider than values and K/V heads counted by kind, four leaves of
+  four shapes, for ``mimo_v2``);
 - ``RING_CACHE`` (absent: false): some leaves are rings a prefill chunk's
   writes must fit; ``init_cache`` then also takes ``prefill_chunk``;
 - ``CARRIES_STATE`` (absent: false): the cache holds a state with no token
@@ -46,8 +48,8 @@ STATS = "stats"
 
 
 # What the blocks whose layers alternate between kinds of mixer
-# (``granite_hybrid``, ``minicpm_sala``, ``afmoe``) share: the runs of the per-layer
-# pattern, a layer's row of its own kind's cache leaves, the rows that count.
+# (``granite_hybrid``, ``minicpm_sala``, ``afmoe``, ``mimo_v2``) share: the
+# runs of the per-layer pattern, a layer's row of its own kind's cache leaves, the rows that count.
 
 
 def runs(kinds) -> list:
@@ -103,6 +105,10 @@ def model_module(m):
         from picotron_tpu.models import afmoe
 
         return afmoe
+    if m.model_type == "mimo_v2":
+        from picotron_tpu.models import mimo_v2
+
+        return mimo_v2
     if m.model_type == "llama":
         return llama
     raise ValueError(f"unknown model_type {m.model_type!r}")

@@ -51,6 +51,12 @@ finds its row of its own kind's cache leaves from the scan's global index
 
 Every layer function returns, beside the updated cache leaves, what it
 counted (``STATS``, in the order of ``STAT_NAMES``; docs/OBSERVABILITY.md).
+
+The rings and the attends over them (``ring_rows`` to ``ring_write``,
+``visible``, ``masked_attention``, ``chunk_attention``) also serve
+``models/mimo_v2.py``, which imports them: there a row's heads are merged
+(``kv_heads``), V's heads are narrower than K's, and the sliding softmax has
+a ``sink``; this block calls them with none of the three.
 """
 
 from __future__ import annotations
@@ -314,15 +320,30 @@ def _scores(q, k, scale: float):
                       preferred_element_type=F32) * scale
 
 
-def masked_attention(q, k, v, seen, scale: float):
-    """Softmax attention of q [B, S, heads, D] over k/v [B, T, kv heads, D]
-    under ``seen`` [B, S, T], float32 softmax (``kv_cache.decode_attention``
-    with the mask handed in)."""
+def sink_rows(sink, nkv: int):
+    """A sink's [heads] float32 as [1, kv heads, group, 1, 1]: one scalar a
+    query head, beside the scores ``_scores`` lays out."""
+    return sink.astype(F32).reshape(1, nkv, -1, 1, 1)
+
+
+def masked_attention(q, k, v, seen, scale: float, sink=None):
+    """Softmax attention of q [B, S, heads, D] over k [B, T, kv heads, D]
+    and v [B, T, kv heads, Dv] under ``seen`` [B, S, T], float32 softmax
+    (``kv_cache.decode_attention`` with the mask handed in). A ``sink``
+    [heads] joins each head's maximum and denominator and has no value: the
+    rows then sum to less than 1 by its share."""
     s = jnp.where(seen[:, None, None], _scores(q, k, scale), NEG_INF)
-    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
-    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    if sink is not None:
+        sink = sink_rows(sink, k.shape[2])
+        m = jnp.maximum(m, sink)
+    p = jnp.exp(s - m)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    if sink is not None:
+        l = l + jnp.exp(sink - m)
+    p = p / l
     out = jnp.einsum("bkgst,btkd->bskgd", p, v.astype(F32))
-    return out.reshape(q.shape).astype(q.dtype)
+    return out.reshape(q.shape[:3] + v.shape[3:]).astype(q.dtype)
 
 
 def key_block(T: int) -> int:
@@ -331,26 +352,34 @@ def key_block(T: int) -> int:
 
 
 def chunk_attention(q, k_leaf, v_leaf, row, slot, pos_q, window: int,
-                    scale: float):
+                    scale: float, sink=None, kv_heads: int = 0):
     """A prefill chunk's q [1, S, heads, D] (at positions ``pos_q`` [1, S],
     its own K/V already written) over ``slot``'s strip of layer ``row`` of
-    the stacked leaves [layers, slots, T, kv heads, D], where it lies: the
-    strip's live key blocks one after the other under a running softmax, so
-    that the float32 scores never pass [heads, S, ``KEY_BLOCK``]. With a
-    ``window`` the strip is a ring (``ring_positions``), else a prefix."""
+    the stacked leaves [layers, slots, T, kv heads, D] (or, with
+    ``kv_heads``, the heads merged: [layers, slots, T, kv heads x D]; V's
+    heads may be narrower than K's), where it lies: the strip's live key
+    blocks one after the other under a running softmax, so that the float32
+    scores never pass [heads, S, ``KEY_BLOCK``]. With a ``window`` the strip
+    is a ring (``ring_positions``), else a prefix. A ``sink`` [heads] is
+    where the running softmax starts: its maximum, a denominator of 1,
+    nothing accumulated."""
     T = k_leaf.shape[2]
     Tb = key_block(T)
     end = pos_q[0, -1]
     blocks = (jnp.minimum(end + 1, T) + Tb - 1) // Tb
-    _, S, nh, D = q.shape
-    nkv = k_leaf.shape[3]
+    _, S, nh, _ = q.shape
+    nkv = kv_heads or k_leaf.shape[3]
+    Dv = math.prod(v_leaf.shape[3:]) // nkv
     zero = jnp.zeros((), jnp.int32)
+
+    def block(leaf, j):
+        at = (row, slot, j * Tb) + (zero,) * (leaf.ndim - 3)
+        got = lax.dynamic_slice(leaf, at, (1, 1, Tb) + leaf.shape[3:])[0]
+        return got.reshape(1, Tb, nkv, -1)
 
     def body(j, carry):
         m, l, acc = carry
-        at = (row, slot, j * Tb, zero, zero)
-        kb = lax.dynamic_slice(k_leaf, at, (1, 1, Tb, nkv, D))[0]
-        vb = lax.dynamic_slice(v_leaf, at, (1, 1, Tb, nkv, D))[0]
+        kb, vb = block(k_leaf, j), block(v_leaf, j)
         rows = j * Tb + jnp.arange(Tb, dtype=jnp.int32)
         pos_k = ring_positions(end, T, rows) if window else rows
         seen = visible(pos_q, pos_k[None], window)[:, None, None]
@@ -365,12 +394,16 @@ def chunk_attention(q, k_leaf, v_leaf, row, slot, pos_q, window: int,
         return m_new, l, acc
 
     lead = (1, nkv, nh // nkv, S)
-    m, l, acc = lax.fori_loop(
-        0, blocks, body,
-        (jnp.full(lead + (1,), NEG_INF, F32), jnp.zeros(lead + (1,), F32),
-         jnp.zeros(lead + (D,), F32)))
+    if sink is None:
+        start = (jnp.full(lead + (1,), NEG_INF, F32),
+                 jnp.zeros(lead + (1,), F32))
+    else:
+        start = (jnp.broadcast_to(sink_rows(sink, nkv), lead + (1,)),
+                 jnp.ones(lead + (1,), F32))
+    m, l, acc = lax.fori_loop(0, blocks, body,
+                              start + (jnp.zeros(lead + (Dv,), F32),))
     out = acc / jnp.where(l > 0, l, 1.0)
-    return jnp.moveaxis(out, 3, 1).reshape(q.shape).astype(q.dtype)
+    return jnp.moveaxis(out, 3, 1).reshape(1, S, nh, Dv).astype(q.dtype)
 
 
 def ring_write(leaf, new, pos, row, slot=None):

@@ -1,10 +1,11 @@
 """This chip's share of an expert layer: which of a router's experts are
 held here, their part of the routed sum, and what of it is counted. The
-expert blocks (``deepseek_v32``, ``granite_hybrid``, ``afmoe``) route over the
-router's whole width (the sigmoid router of two of them is ``route``) and
-hand the choice here; the tree's leaves are named alike in all: ``w1``/``w3``/``w2`` ``[held, ...]`` a layer (the
-group's ``UNSLICED`` stacks), ``ws_gate``/``ws_up``/``ws_down`` the shared
-expert."""
+expert blocks (``deepseek_v32``, ``granite_hybrid``, ``afmoe``, ``mimo_v2``)
+route over the router's whole width (the sigmoid router of three of them is
+``route``) and hand the choice here; the tree's leaves are named alike in
+all: ``w1``/``w3``/``w2`` ``[held, ...]`` a layer (the group's ``UNSLICED``
+stacks), ``ws_gate``/``ws_up``/``ws_down`` the shared expert, where the
+block has one."""
 
 from __future__ import annotations
 
@@ -85,12 +86,16 @@ def routed_experts(x, w_held, lp):
 def share(lp, x2, w_held) -> tuple:
     """(the held experts' part of the routed sum + the shared expert
     [N, H], held assignments, held experts hit) for tokens ``x2`` [N, H]
-    weighted ``w_held`` [N, held] (rows that are not live: all 0)."""
+    weighted ``w_held`` [N, held] (rows that are not live: all 0). A layer
+    whose leaves hold no ``ws_gate`` has no shared expert (``mimo_v2``)."""
     with jax.named_scope("moe_experts"):
         y = routed_experts(x2, w_held, lp)
-    with jax.named_scope("shared_expert"):
-        y = y.astype(x2.dtype) + swiglu(x2, lp["ws_gate"], lp["ws_up"],
-                                        lp["ws_down"])
+    if "ws_gate" in lp:
+        with jax.named_scope("shared_expert"):
+            y = y.astype(x2.dtype) + swiglu(x2, lp["ws_gate"], lp["ws_up"],
+                                            lp["ws_down"])
+    else:
+        y = y.astype(x2.dtype)
     assigned = jnp.sum(w_held > 0, dtype=jnp.int32)
     hit = jnp.sum(jnp.any(w_held > 0, axis=0), dtype=jnp.int32)
     return y, assigned, hit

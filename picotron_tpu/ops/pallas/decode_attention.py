@@ -436,6 +436,21 @@ _STACKED_KV_BLOCK = 1024 * 1024
 _BF16_ROWS = 16  # rows of a bf16 register tile: what the query rows pad to
 
 
+def _stacked_block_rows(T: int, row_bytes: int) -> int:
+    """Tokens a K block of the stacked kernel holds by default: as many as
+    stay under ``_STACKED_KV_BLOCK`` where that count divides the strip's
+    ``T`` rows (every row of whole powers of two: the Llama cells', the
+    Trinity cell's), else the largest divisor of ``T`` under it in whole
+    bfloat16 tiles of 16 (a row of 768 or 1,536 lanes gives 682 and 341,
+    which ``_pick_block`` would halve down to 2 and 5), else what
+    ``_pick_block`` finds."""
+    cap = min(T, max(_SUBLANE, _STACKED_KV_BLOCK // row_bytes))
+    if T % cap == 0:
+        return cap
+    fits = [b for b in range(_BF16_ROWS, cap + 1, _BF16_ROWS) if T % b == 0]
+    return max(fits) if fits else _pick_block(T, cap)
+
+
 def _stacked_blocks(L, block_t, max_nb):
     """KV blocks a slot with ``L`` live tokens walks at ``S == 1``
     (``_visible_blocks`` for one query row at position ``L - 1``)."""
@@ -444,7 +459,7 @@ def _stacked_blocks(L, block_t, max_nb):
 
 
 def _stacked_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg,
-                    max_nb, window=None, ring=0):
+                    max_nb, window=None, ring=0, sink=False):
     """One (slot, kv-block) grid step of ``flash_decode_stacked``. The K
     block is ``block_t`` tokens of one layer and slot as a plain matrix:
     ``[block_t * rows, lanes]``, a row of it one (token, cache row) pair,
@@ -460,10 +475,18 @@ def _stacked_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg,
     With a ``window`` the slot's strip is a ring of ``ring`` rows and a
     third scalar operand names the row the query's own key lies in: a row
     is seen if it is live and fewer than ``window`` rows behind that one,
-    the ring's end joined to its start."""
+    the ring's end joined to its start.
+
+    With a ``sink`` a fourth operand holds one float32 a query row: the
+    running softmax starts from it (its maximum, a denominator of exp(0),
+    nothing accumulated), so it takes its share of every row's mass and has
+    no value. V's lanes need not be K's: the accumulator and the output are
+    as wide as V."""
     del layer_ref  # consumed by the index maps
     last_ref, refs = (None, refs) if window is None else (refs[0], refs[1:])
-    q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, own_ref = refs
+    q_ref, refs = refs[0], refs[1:]
+    sink_ref, refs = (refs[0], refs[1:]) if sink else (None, refs)
+    k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, own_ref = refs
     b = pl.program_id(0)
     j = pl.program_id(1)
     L = len_ref[b]
@@ -473,8 +496,12 @@ def _stacked_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg,
     @pl.when(j == 0)
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        if sink:
+            m_ref[...] = sink_ref[...]
+            l_ref[...] = jnp.ones_like(l_ref)
+        else:
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
         head_row = lax.broadcasted_iota(jnp.int32, (nq, cols), 0) // pg
         col = lax.broadcasted_iota(jnp.int32, (nq, cols), 1)
         own_ref[...] = jnp.where(col % rows == head_row, col // rows,
@@ -517,7 +544,7 @@ def _stacked_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg,
 def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
                          block_t: int | None = None,
                          interpret: bool = False,
-                         window: int | None = None):
+                         window: int | None = None, sink=None):
     """The ``S == 1`` decode attend of a contiguous, unquantized cache,
     reading layer ``layer`` of the STACKED leaves in place: one pass over
     K and V, live rows only.
@@ -528,7 +555,14 @@ def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
     valid-key counts (the query sits at ``lengths - 1``); ``layer`` a
     traced or static index. Returns [B, 1, n_heads, D] in q.dtype,
     allclose to ``kv_cache.decode_attention`` on the layer's block; a slot
-    with ``lengths == 0`` returns ZEROS (module docstring).
+    with ``lengths == 0`` returns ZEROS (module docstring). V's heads may
+    be narrower than K's (``v``: ``[L, B, T, rows, p * Dv]``; the output
+    is then [B, 1, n_heads, Dv]), and ``sink`` [n_heads] float32 is a
+    learned logit a query head that joins its softmax and has no value
+    (``models/mimo_v2.py``); the forms without either are the programs they
+    were, and the compiled kernel's name tells the forms apart
+    (``flash_decode_attention`` | ``flash_decode_ring``, ``_sink`` behind
+    either).
 
     ``layer`` and ``lengths`` are scalar-prefetch operands and the K/V
     index maps return ``(layer, b, walk(j), 0)`` into the leaf viewed as
@@ -555,10 +589,12 @@ def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
     if S != 1:
         raise ValueError(f"flash_decode_stacked is the S == 1 step, got {S}")
     nl, _, T, rows, lanes = k.shape
-    pack = lanes // D
-    if nh % rows or lanes != pack * D:
+    pack, lanes_v = lanes // D, v.shape[-1]
+    if nh % rows or lanes != pack * D or v.shape[:-1] != k.shape[:-1] \
+            or lanes_v % pack:
         raise ValueError(
-            f"{nh} query heads of {D} against rows {rows} x {lanes} lanes")
+            f"{nh} query heads of {D} against rows {rows} x {lanes} lanes "
+            f"of K, {v.shape[-2]} x {lanes_v} of V")
     if q.dtype != k.dtype:
         raise ValueError(f"q is {q.dtype}, the cache leaves {k.dtype}")
     pg = nh // rows  # query heads a cache row: p * (heads a kv head)
@@ -571,10 +607,9 @@ def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
     nq = -(-nh // _BF16_ROWS) * _BF16_ROWS
     if nq != nh:  # pad rows own no cache row: all masked, sliced off below
         qm = jnp.pad(qm, ((0, 0), (0, nq - nh), (0, 0)))
-    bt = _pick_block(T, block_t or max(
-        _SUBLANE, _STACKED_KV_BLOCK // (rows * lanes * k.dtype.itemsize)))
+    bt = _pick_block(T, block_t) if block_t else _stacked_block_rows(
+        T, rows * lanes * k.dtype.itemsize)
     cols, max_nb = bt * rows, T // bt
-    merged = (nl, B, T * rows, lanes)
     lengths = lengths.astype(jnp.int32)
     prefetch = (lengths, jnp.asarray(layer, jnp.int32).reshape(1))
     if window is not None:
@@ -585,29 +620,40 @@ def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
         nb = _stacked_blocks(len_ref[b], bt, max_nb)
         return (layer_ref[0], b, jnp.maximum(jnp.minimum(j, nb - 1), 0), 0)
 
-    q_spec = pl.BlockSpec((None, nq, lanes), lambda b, j, *_: (b, 0, 0))
-    kv_spec = pl.BlockSpec((None, None, cols, lanes), kv_index)
+    def row_spec(width):  # a slot's query rows, or what they come to
+        return pl.BlockSpec((None, nq, width), lambda b, j, *_: (b, 0, 0))
+
+    def kv_spec(width):
+        return pl.BlockSpec((None, None, cols, width), kv_index)
+
+    operands, in_specs = [qm], [row_spec(lanes)]
+    if sink is not None:
+        # a float32 a query row; the pad rows' is 0 (sliced off below)
+        operands.append(jnp.pad(sink.astype(jnp.float32),
+                                (0, nq - nh)).reshape(nq, 1))
+        in_specs.append(pl.BlockSpec((nq, 1), lambda b, j, *_: (0, 0)))
     out = pl.pallas_call(
         functools.partial(_stacked_kernel, scale=scale, block_t=bt,
                           rows=rows, pg=pg, max_nb=max_nb, window=window,
-                          ring=T),
+                          ring=T, sink=sink is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(B, max_nb),
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=q_spec,
-            scratch_shapes=[pltpu.VMEM((nq, lanes), jnp.float32),
+            in_specs=in_specs + [kv_spec(lanes), kv_spec(lanes_v)],
+            out_specs=row_spec(lanes_v),
+            scratch_shapes=[pltpu.VMEM((nq, lanes_v), jnp.float32),
                             pltpu.VMEM((nq, 1), jnp.float32),
                             pltpu.VMEM((nq, 1), jnp.float32),
                             pltpu.VMEM((nq, cols), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct((B, nq, lanes), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, nq, lanes_v), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        name="flash_decode_attention" if window is None
-        else "flash_decode_ring",
-    )(*prefetch, qm, k.reshape(merged), v.reshape(merged))
-    out = out[:, :nh].reshape(B, 1, rows, pg, lanes)
+        name=("flash_decode_attention" if window is None
+              else "flash_decode_ring") + ("" if sink is None else "_sink"),
+    )(*prefetch, *operands, k.reshape(nl, B, T * rows, lanes),
+      v.reshape(nl, B, T * rows, lanes_v))
+    out = out[:, :nh].reshape(B, 1, rows, pg, lanes_v)
     if pack > 1:
         out = _own_lanes(out, pack)
-    return out.reshape(B, 1, nh, D)
+    return out.reshape(B, 1, nh, lanes_v // pack)

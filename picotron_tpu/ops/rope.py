@@ -52,6 +52,18 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarra
     return x * c + rotated * s
 
 
+def apply_rope_leading(x: jnp.ndarray, cos: jnp.ndarray,
+                       sin: jnp.ndarray) -> jnp.ndarray:
+    """``apply_rope`` on the leading ``cos.shape[-1]`` dimensions of every
+    head (halves of that many paired), the others untouched: a partial
+    rotation (models/mimo_v2.py: 64 of a head's 192)."""
+    rot = cos.shape[-1]
+    if rot == x.shape[-1]:
+        return apply_rope(x, cos, sin)
+    return jnp.concatenate(
+        [apply_rope(x[..., :rot], cos, sin), x[..., rot:]], axis=-1)
+
+
 def rope_at_positions(cos: jnp.ndarray, sin: jnp.ndarray,
                       pos: jnp.ndarray) -> tuple:
     """Gather per-sequence angle rows for decode-at-offset: ``pos`` is [B]

@@ -1658,11 +1658,18 @@ def _smoke() -> int:
     names = [f"127.0.0.1:{p}" for p in ports]
     by_name = dict(zip(names, servers))
     chaos = RouterChaos()
+    # Room for a loaded machine (six test workers beside this one): with a
+    # probe held to 0.4 s and a scrape stale after 1 s, three slow probes
+    # of a HEALTHY replica opened its breaker, and the drill then counted a
+    # replay, a breaker that was not "closed" or a request too many
+    # (12 copies at once: every one failed, on six different checks). The
+    # drills that need a dead probe make one (a kill, a flap, a stall of
+    # twice the timeout), whatever the timeout is.
     cfg = RouterConfig(
-        probe_interval_s=0.05, probe_timeout_s=0.4,
+        probe_interval_s=0.05, probe_timeout_s=2.0,
         breaker_failures=3, breaker_backoff_s=0.05,
         breaker_backoff_max_s=0.4, breaker_probe_attempts=4,
-        scrape_stale_s=1.0, stream_idle_timeout_s=60.0,
+        scrape_stale_s=3.0, stream_idle_timeout_s=60.0,
         connect_timeout_s=5.0)
     rs = RouterServer(names, cfg, chaos=chaos, log=lambda *a, **k: None)
     rs.start()

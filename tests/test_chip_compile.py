@@ -200,6 +200,35 @@ def _decode_ring():
          (RING_LEAF, BF16), ((RING_LEAF[1],), I32), ((), I32)]
 
 
+# the MiMo cell's four cache leaves (32 slots x 16,384; rings of 128 + 512),
+# a row's heads merged: (K leaf, V leaf, window), 64 query heads of 192
+MIMO_LEAVES = {
+    "full": ((3, 32, 16384, 768), (3, 32, 16384, 512), 0),
+    "ring": ((10, 32, 640, 1536), (10, 32, 640, 1024), 128),
+}
+
+
+def _decode_mimo(kind):
+    """``mimo_v2``'s decode attend as the block calls it: K rows wider than
+    V rows, and for the rings a window and a sink a query head."""
+    from picotron_tpu.ops.pallas import decode_attention as da
+
+    k_leaf, v_leaf, window = MIMO_LEAVES[kind]
+    slots = k_leaf[1]
+
+    def attend(q, k, v, pos, layer, sink):
+        # blocks of 512 and 320 tokens: the largest divisors under 1 MiB of K
+        assert da._stacked_block_rows(k.shape[2], 2 * k.shape[3]) \
+            == {"full": 512, "ring": 320}[kind]
+        return flash_decode_stacked(
+            q, k[:, :, :, None], v[:, :, :, None], pos + 1, 192 ** -0.5,
+            layer, window=window or None, sink=sink if window else None)
+
+    return attend, [((slots, 1, 64, 192), BF16), (k_leaf, BF16),
+                    (v_leaf, BF16), ((slots,), I32), ((), I32),
+                    ((64,), F32)]
+
+
 def _quant(m, k, n):
     return (lambda x, q, s: qm.quant_matmul_pallas(x, q, s)), \
         [((m, k), BF16), ((k, n), I8), ((n,), F32)]
@@ -224,6 +253,8 @@ CASES = {
     **{f"decode_stacked_{cell}": (lambda cell=cell: _decode_stacked(cell))
        for cell in STACKED_LEAVES},
     "decode_ring_trinity": _decode_ring,
+    "decode_full_mimo": lambda: _decode_mimo("full"),
+    "decode_ring_mimo": lambda: _decode_mimo("ring"),
     "quant_matmul_up": lambda: _quant(8, HID, FFN),
     "quant_matmul_down": lambda: _quant(8, FFN, HID),
     "quant_matmul_head": lambda: _quant(8, HID, VOCAB),
@@ -692,3 +723,39 @@ def test_sala_cache_leaves_are_never_copied_whole(prog, topo, one_chip):
     # temporaries read 0.92 GB (the lightning layers' q, k, v weights
     # re-laid once a block), a chunk's 0.01
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+
+
+# ---- the four leaves of the MiMo-V2 block (PR 42) ---------------------------
+
+
+@pytest.mark.parametrize("prog", ["decode_block", "prefill_chunk"])
+def test_mimo_cache_leaves_are_never_copied_whole(prog, topo, one_chip,
+                                                  monkeypatch):
+    """Four leaves of four shapes, a row's heads merged so that every row is
+    whole lanes (a key head of 192 held a head a row would be padded to 256):
+    each stays row-major as it is resident, no instruction copies one, the
+    decode block holds both forms of the stacked kernel (the full layers'
+    and the rings' with its sink), and the programs' temporaries leave the
+    13.07 GB resident room on the chip."""
+    from picotron_tpu.models import mimo_v2
+
+    monkeypatch.setattr(mimo_v2, "on_tpu", lambda: True)
+    compiled = _cell_program(topo, prog, "mimo-v2.5-ep32-l13")
+    text = compiled.as_text()
+    lines = text.splitlines()
+    leaves = {"k": r"bf16\[3,32,16384,768\]", "v": r"bf16\[3,32,16384,512\]",
+              "kw": r"bf16\[10,32,640,1536\]",
+              "vw": r"bf16\[10,32,640,1024\]"}
+    shapes = "|".join(leaves.values())
+    copies = [l.strip()[:160] for l in lines
+              if re.search(rf"= (?:{shapes})\S* copy\(", l)]
+    assert not copies, "\n".join(copies)
+    for name, shape in leaves.items():
+        params = [l for l in lines
+                  if re.search(rf"cache__{name}__\S* = {shape}", l)
+                  and " parameter(" in l]
+        assert len(params) == 1 and "{3,2,1,0" in params[0], (name, params)
+    if prog == "decode_block":
+        assert "flash_decode_ring_sink" in text \
+            and "flash_decode_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9

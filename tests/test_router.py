@@ -559,12 +559,23 @@ def test_remove_replica_joins_prober_and_readd_starts_breaker_fresh():
         with rep._mu:
             rep.inflight -= 1
         assert rep.snapshot(r._clock())["inflight"] == 1
-        # same address re-registered: nothing carried over
+        # same address re-registered: nothing carried over. Read as
+        # add_replica built it, before its prober runs: against a fake
+        # address a probe fails at once, and three of them (30 ms) open the
+        # fresh breaker, which a loaded machine let happen ahead of the
+        # asserts
+        fresh, spawn = {}, r._spawn_prober
+
+        def spawn_after_reading(new):
+            with new._mu:
+                fresh.update(breaker=new.breaker, fails=new.fails,
+                             inflight=new.inflight)
+            spawn(new)
+
+        r._spawn_prober = spawn_after_reading
         rep2 = r.add_replica(f"{rep.host}:{rep.port}")
         assert rep2 is not rep
-        with rep2._mu:
-            assert rep2.breaker == "closed"
-            assert rep2.fails == 0 and rep2.inflight == 0
+        assert fresh == {"breaker": "closed", "fails": 0, "inflight": 0}
         assert rep2._prober is not None and rep2._prober.is_alive()
         with pytest.raises(router_mod.DuplicateReplica):
             r.add_replica(f"{rep.host}:{rep.port}")
