@@ -80,11 +80,11 @@ from picotron_tpu.ops.rope import apply_rope, precompute_rope
 from picotron_tpu.utils import on_tpu
 
 # what a layer counts, in the order of the vector (under ``STATS``): the
-# expert share's three (as ``deepseek_v32``); keys the sliding layers'
+# expert share's (``experts.STAT_NAMES``); keys the sliding layers'
 # live queries attended, keys they would have with no window, sliding
 # layers decode steps ran
-STAT_NAMES = ("moe_assignments", "moe_experts_hit", "moe_layer_steps",
-              "swa_rows_attended", "swa_rows_context", "swa_layer_steps")
+STAT_NAMES = expert_share.STAT_NAMES + (
+    "swa_rows_attended", "swa_rows_context", "swa_layer_steps")
 
 UNSLICED = expert_share.UNSLICED
 # the sliding layers' leaves are rings a prefill chunk's writes must fit:
@@ -517,9 +517,9 @@ def attention(lp, x, cos, sin, cfg: Config, cache, pos, row, live,
 
 def expert_mlp(lp, x, m: ModelConfig, live) -> tuple:
     """The expert half of a layer on the normed stream ``x`` [B, S, H]:
-    (this chip's part of the routed sum + the shared expert, held
-    assignments, held experts hit; ``models/experts.py``). Rows that are
-    not ``live`` are routed nowhere."""
+    (this chip's part of the routed sum + the shared expert, what
+    ``models/experts.py::share`` counted). Rows that are not ``live`` are
+    routed nowhere."""
     B, S, H = x.shape
     x2 = x.reshape(B * S, H)
     with jax.named_scope("afmoe/router"):
@@ -531,8 +531,8 @@ def expert_mlp(lp, x, m: ModelConfig, live) -> tuple:
         w_held = expert_share.held_weights(
             experts, weights, m.ep_rank * m.num_experts, m.num_experts) \
             * live.reshape(B * S, 1).astype(F32)
-    y, assigned, hit = expert_share.share(lp, x2, w_held)
-    return y.reshape(B, S, H), assigned, hit
+    y, counted = expert_share.share(lp, x2, w_held)
+    return y.reshape(B, S, H), counted
 
 
 # --------------------------------------------------------------------------- #
@@ -561,10 +561,9 @@ def _layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
     zero = jnp.zeros((), jnp.int32)
     if dense:
         y = expert_share.swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
-        moe = (zero, zero, zero)
+        moe = (zero,) * len(expert_share.STAT_NAMES)
     else:
-        y, assigned, hit = expert_mlp(lp, x, m, live)
-        moe = (assigned, hit, zero + 1)
+        y, moe = expert_mlp(lp, x, m, live)
     h = h + rms_norm(y, lp["post_mlp_norm"], eps)
     decode = cache is not None and "slot" not in cache
     swa = ((attended, context, zero + int(decode)) if window

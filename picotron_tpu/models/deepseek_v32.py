@@ -75,8 +75,8 @@ from picotron_tpu.ops.rope import (
 )
 
 # what a layer counts, in the order of the vector (under ``STATS``)
-STAT_NAMES = ("moe_assignments", "moe_experts_hit", "moe_layer_steps",
-              "dsa_keys_selected", "dsa_keys_scored")
+STAT_NAMES = expert_share.STAT_NAMES + (
+    "dsa_keys_selected", "dsa_keys_scored")
 
 # keys scored by the indexer, and queries attended, at a time: bounds the
 # [B, S, index heads, keys] products and the [B, S, selected, rank] rows
@@ -448,9 +448,9 @@ def held_weights(experts, weights, m: ModelConfig):
 
 def expert_mlp(lp, x, m: ModelConfig, live) -> tuple:
     """The expert half of a layer on the normed stream ``x`` [B, S, H]:
-    (this chip's part of the routed sum + the shared expert, held
-    assignments, held experts hit; ``models/experts.py``). Rows that are
-    not ``live`` are routed nowhere."""
+    (this chip's part of the routed sum + the shared expert, what
+    ``models/experts.py::share`` counted). Rows that are not ``live`` are
+    routed nowhere."""
     B, S, H = x.shape
     x2 = x.reshape(B * S, H)
     with jax.named_scope("moe_route"):
@@ -463,8 +463,8 @@ def expert_mlp(lp, x, m: ModelConfig, live) -> tuple:
             topk_group=m.topk_group, scale=m.routed_scaling_factor)
         w_held = held_weights(experts, weights, m) \
             * live.reshape(B * S, 1).astype(jnp.float32)
-    y, assigned, hit = expert_share.share(lp, x2, w_held)
-    return y.reshape(B, S, H), assigned, hit
+    y, counted = expert_share.share(lp, x2, w_held)
+    return y.reshape(B, S, H), counted
 
 
 # --------------------------------------------------------------------------- #
@@ -489,11 +489,10 @@ def _layer(lp, h, cos, sin, cfg: Config, cache, pos, return_kv, layer,
     zero = jnp.zeros((), jnp.int32)
     if dense:
         h = h + _swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
-        moe = (zero, zero, zero)
+        moe = (zero,) * len(expert_share.STAT_NAMES)
     else:
-        y, assigned, hit = expert_mlp(lp, x, m, live)
+        y, moe = expert_mlp(lp, x, m, live)
         h = h + y
-        moe = (assigned, hit, zero + 1)
     stats = jnp.stack(moe + (selected, scored))
     if return_kv:
         # the sequence's rows, [1, S, width] each: a prefill's blocks

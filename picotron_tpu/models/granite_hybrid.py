@@ -65,11 +65,11 @@ from picotron_tpu.ops.rmsnorm import rms_norm
 from picotron_tpu.ops.ssm import ssm_scan, ssm_step
 
 # what a layer counts, in the order of the vector (under ``STATS``):
-# the expert share's three (as ``deepseek_v32``), live slot-layers a decode
+# the expert share's (``experts.STAT_NAMES``), live slot-layers a decode
 # step advanced, Mamba layers decode steps ran, live tokens through a
 # prefill scan (a layer)
-STAT_NAMES = ("moe_assignments", "moe_experts_hit", "moe_layer_steps",
-              "ssm_state_updates", "ssm_layer_steps", "ssm_tokens_scanned")
+STAT_NAMES = expert_share.STAT_NAMES + (
+    "ssm_state_updates", "ssm_layer_steps", "ssm_tokens_scanned")
 
 UNSLICED = expert_share.UNSLICED
 # the state has no token axis and cannot be fed a token twice: the engine
@@ -339,8 +339,9 @@ def route(logits, k: int) -> tuple:
 
 def expert_mlp(lp, x, m: ModelConfig, live) -> tuple:
     """The expert half of a layer on the normed stream ``x`` [B, S, H]:
-    (this chip's part of the routed sum + the shared MLP, held assignments,
-    held experts hit). Rows that are not ``live`` are routed nowhere."""
+    (this chip's part of the routed sum + the shared MLP, what
+    ``models/experts.py::share`` counted). Rows that are not ``live`` are
+    routed nowhere."""
     B, S, H = x.shape
     x2 = x.reshape(B * S, H)
     with jax.named_scope("moe_route"):
@@ -350,8 +351,8 @@ def expert_mlp(lp, x, m: ModelConfig, live) -> tuple:
         w_held = expert_share.held_weights(
             experts, weights, m.ep_rank * m.num_local_experts,
             m.num_local_experts) * live.reshape(B * S, 1).astype(F32)
-    y, assigned, hit = expert_share.share(lp, x2, w_held)
-    return y.reshape(B, S, H), assigned, hit
+    y, counted = expert_share.share(lp, x2, w_held)
+    return y.reshape(B, S, H), counted
 
 
 # --------------------------------------------------------------------------- #
@@ -361,11 +362,10 @@ def expert_mlp(lp, x, m: ModelConfig, live) -> tuple:
 
 def _finish(lp, h, m: ModelConfig, live, out: dict, ssm_stats: tuple):
     """The expert half, and the layer's counters beside its cache leaves."""
-    y, assigned, hit = expert_mlp(
+    y, moe = expert_mlp(
         lp, rms_norm(h, lp["mlp_norm"], m.rms_norm_eps), m, live)
     h = h + jnp.asarray(m.residual_multiplier, h.dtype) * y
-    one = jnp.ones((), jnp.int32)
-    out[STATS] = jnp.stack((assigned, hit, one) + ssm_stats)
+    out[STATS] = jnp.stack(moe + ssm_stats)
     return h, out
 
 

@@ -388,8 +388,8 @@ def attention(lp, x, cos, sin, cfg: Config, cache, pos, row, live,
 
 def expert_mlp(lp, x, m: ModelConfig, live) -> tuple:
     """The expert half of a layer on the normed stream ``x`` [B, S, H]:
-    (this chip's part of the routed sum, held assignments, held experts hit;
-    ``models/experts.py``). Rows that are not ``live`` are routed nowhere."""
+    (this chip's part of the routed sum, what ``models/experts.py::share``
+    counted). Rows that are not ``live`` are routed nowhere."""
     B, S, H = x.shape
     x2 = x.reshape(B * S, H)
     with jax.named_scope("mimo/router"):
@@ -402,8 +402,8 @@ def expert_mlp(lp, x, m: ModelConfig, live) -> tuple:
         w_held = expert_share.held_weights(
             experts, weights, m.ep_rank * m.n_routed_experts,
             m.n_routed_experts) * live.reshape(B * S, 1).astype(F32)
-    y, assigned, hit = expert_share.share(lp, x2, w_held)
-    return y.reshape(B, S, H), assigned, hit
+    y, counted = expert_share.share(lp, x2, w_held)
+    return y.reshape(B, S, H), counted
 
 
 # --------------------------------------------------------------------------- #
@@ -432,10 +432,9 @@ def _layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
     zero = jnp.zeros((), jnp.int32)
     if dense:
         y = expert_share.swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
-        moe = (zero, zero, zero)
+        moe = (zero,) * len(expert_share.STAT_NAMES)
     else:
-        y, assigned, hit = expert_mlp(lp, x, m, live)
-        moe = (assigned, hit, zero + 1)
+        y, moe = expert_mlp(lp, x, m, live)
     h = h + y
     decode = cache is not None and "slot" not in cache
     swa = ((attended, context, zero + int(decode)) if window

@@ -164,13 +164,14 @@ def test_the_whole_forward_matches_the_reference_at_every_position(toy):
     want = ref.forward_logits(params, np.asarray(tokens), dict(TOY))[0]
     assert float(np.max(np.abs(np.asarray(logits[0]) - want))
                  / np.max(np.abs(want))) < 1e-4
-    rows = np.asarray(stats)
+    rows = dict(zip(afmoe.STAT_NAMES, np.asarray(stats).T))
     # sliding layers 0, 1 and 3: 1 + 2 + .. of the first 16 queries, then
     # 16 each; the full one counts nothing
     attended = sum(min(t + 1, 16) for t in range(50))
-    assert list(rows[:, 3]) == [attended, attended, 0, attended]
-    assert list(rows[:, 4]) == [1275, 1275, 0, 1275]  # 50 x 51 / 2
-    assert list(rows[:, 2]) == [0, 1, 1, 1] and not rows[:, 5].any()
+    assert list(rows["swa_rows_attended"]) == [attended, attended, 0, attended]
+    assert list(rows["swa_rows_context"]) == [1275, 1275, 0, 1275]  # 50 x 51 / 2
+    assert list(rows["moe_layer_steps"]) == [0, 1, 1, 1]
+    assert not rows["swa_layer_steps"].any()
 
 
 def test_a_slot_used_twice_forgets_its_first_occupant(toy):
@@ -322,7 +323,7 @@ def test_the_shares_add_up_to_the_uncut_expert_layer():
         return jax.jit(lambda lp, x, live: afmoe.expert_mlp(lp, x, m, live))(
             lp, x, live)
 
-    whole, assigned, hit = mlp(lp, x, uncut, live)
+    whole, (assigned, hit, *_) = mlp(lp, x, uncut, live)
     assert int(assigned) == 2 * 12 * 2 and int(hit) <= 8
     shared = experts.swiglu(x, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
     total, held = shared, 0
@@ -330,7 +331,7 @@ def test_the_shares_add_up_to_the_uncut_expert_layer():
         m = make_config(dict(ep_rank=rank)).model
         part = {**lp, **{n: lp[n][2 * rank:2 * rank + 2]
                          for n in ("w1", "w3", "w2")}}
-        y, n, _ = mlp(part, x, m, live)
+        y, (n, *_) = mlp(part, x, m, live)
         total, held = total + (y - shared), held + int(n)
     np.testing.assert_allclose(total, whole, atol=2e-5)
     assert held == 2 * 12 * 2  # every token's experts are held by some rank
@@ -338,7 +339,7 @@ def test_the_shares_add_up_to_the_uncut_expert_layer():
                                                    ep_size=1))
     np.testing.assert_allclose(whole.reshape(24, 64), want, atol=2e-5)
     # rows that are not live are routed nowhere: the shared expert alone
-    y, n, _ = mlp(lp, x, uncut, jnp.zeros((2, 12), bool))
+    y, (n, *_) = mlp(lp, x, uncut, jnp.zeros((2, 12), bool))
     np.testing.assert_allclose(y, shared, atol=1e-6)
     assert int(n) == 0
 

@@ -542,6 +542,22 @@ def test_serving_program_leaves_cache_in_place(prog, layout, geometry, topo,
     assert all("{4,3,2,1,0" in l for l in params), params
 
 
+@pytest.fixture
+def experts_on_chip(monkeypatch):
+    """A chunk's rows past the rule (``experts.takes_grouped``) go through
+    the Mosaic kernel, as on a TPU; off one they run Pallas' interpreter."""
+    from picotron_tpu.models import experts
+
+    monkeypatch.setattr(experts, "on_tpu", lambda: True)
+
+
+def _assert_grouped_in_the_chunk_alone(text: str, prog: str):
+    """ISSUE 43: the 512-row chunk runs each held expert over its own rows
+    (the kernel reads the stacks where they lie: the callers' ``sliced``
+    lists hold it to that), the decode block's rows keep the loop."""
+    assert ("grouped_experts" in text) == (prog == "prefill_chunk")
+
+
 # ---- the latent cache of the DeepSeek-V3.2 block (PR 28) -------------------
 
 
@@ -590,14 +606,16 @@ def _latent_program(topo, prog):
 
 
 @pytest.mark.parametrize("prog", ["decode_block", "prefill_chunk"])
-def test_latent_cache_is_row_major_and_never_copied(prog, topo, one_chip):
+def test_latent_cache_is_row_major_and_never_copied(prog, topo, one_chip,
+                                                    experts_on_chip):
     """The TPU lays a cache leaf whose rows are not whole lanes (64 wide, or
     576) out with the tokens minor-most and copies it whole, in the layer
     loop or at the program's entry and exit (PR 28 read both here): the
     lane-padded ``[c_kv | k_r]`` row stays row-major, and no instruction
     copies a whole leaf; nor is a layer's slice of the experts' stacks
-    copied out before the loop over experts."""
+    copied out before the loop over experts or the grouped kernel."""
     text = _latent_program(topo, prog).as_text()
+    _assert_grouped_in_the_chunk_alone(text, prog)
     leaf = r"bf16\[2,8,24576,(?:640|128)\]"
     lines = text.splitlines()
     copies = [l.strip()[:160] for l in lines
@@ -664,15 +682,19 @@ def _cell_program(topo, prog, name):
 
 
 @pytest.mark.parametrize("prog", ["decode_block", "prefill_chunk"])
-def test_recurrent_state_is_row_major_and_never_copied(prog, topo, one_chip):
+def test_recurrent_state_is_row_major_and_never_copied(prog, topo, one_chip,
+                                                       experts_on_chip):
     """Left free, a prefill chunk's contractions pulled the whole float32
     state leaf (2.4 GB) into their own order on entry and pushed it back on
     exit (PR 32 read both copies here, 2.46 GB of temporaries, 15.5 GB in
     all; ``kv_cache.row_major``): the leaf stays row-major, no instruction copies it, and
     K and V of the attention layer stay in place beside it; nor is a layer's
-    slice of the experts' stacks copied out before the loop over experts."""
+    slice of the experts' stacks (680 MB) copied out before the loop over
+    experts or, in the chunk, the grouped kernel (ISSUE 43), whose VMEM the
+    compiler grants beside what it stages there itself."""
     compiled = _cell_program(topo, prog, "granite-4.0-h-small-ep2-l10")
     text = compiled.as_text()
+    _assert_grouped_in_the_chunk_alone(text, prog)
     lines = text.splitlines()
     state = r"f32\[9,64,128,64,128\]"
     kv = r"bf16\[1,64,4096,8,128\]"
@@ -730,7 +752,8 @@ def test_sala_cache_leaves_are_never_copied_whole(prog, topo, one_chip):
 
 @pytest.mark.parametrize("prog", ["decode_block", "prefill_chunk"])
 def test_mimo_cache_leaves_are_never_copied_whole(prog, topo, one_chip,
-                                                  monkeypatch):
+                                                  monkeypatch,
+                                                  experts_on_chip):
     """Four leaves of four shapes, a row's heads merged so that every row is
     whole lanes (a key head of 192 held a head a row would be padded to 256):
     each stays row-major as it is resident, no instruction copies one, the
@@ -742,6 +765,7 @@ def test_mimo_cache_leaves_are_never_copied_whole(prog, topo, one_chip,
     monkeypatch.setattr(mimo_v2, "on_tpu", lambda: True)
     compiled = _cell_program(topo, prog, "mimo-v2.5-ep32-l13")
     text = compiled.as_text()
+    _assert_grouped_in_the_chunk_alone(text, prog)
     lines = text.splitlines()
     leaves = {"k": r"bf16\[3,32,16384,768\]", "v": r"bf16\[3,32,16384,512\]",
               "kw": r"bf16\[10,32,640,1536\]",

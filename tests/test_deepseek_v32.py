@@ -133,10 +133,10 @@ def test_prefill_and_decode_match_the_reference(n_prompt, chunk, select):
     assert worst_rel_err(got, want) < 1e-3
     # what the programs counted: every query selects min(topk, t + 1) of
     # the t + 1 keys it scored, in each of the three layers
-    stats = engine.take_stats()
+    stats = dict(zip(dsv.STAT_NAMES, engine.take_stats()))
     t = np.arange(n_prompt + 4)
-    assert stats[3] == 3 * np.minimum(16, t + 1).sum()
-    assert stats[4] == 3 * (t + 1).sum()
+    assert stats["dsa_keys_selected"] == 3 * np.minimum(16, t + 1).sum()
+    assert stats["dsa_keys_scored"] == 3 * (t + 1).sum()
 
 
 def test_selection_matters_past_index_topk():
@@ -166,11 +166,13 @@ def test_decode_block_counts_and_matches_single_steps():
     cache, out, counts = r.cache, r.tokens, r.counts
     assert list(np.asarray(counts)) == [2, 0]
     assert list(np.asarray(out)[0, :2]) == seq[len(PROMPT) + 1:]
-    stats = engine.take_stats()
-    # 8 steps x 2 expert layers; the parked slot is scored at every step
-    # (the free one never), and selects index_topk of its keys
-    assert stats[2] == 16
-    assert stats[3] == 8 * 3 * 16
+    stats = dict(zip(dsv.STAT_NAMES, engine.take_stats()))
+    # 8 steps x 2 expert layers (the loop: both slots' rows through both
+    # held experts); the parked slot is scored at every step (the free one
+    # never), and selects index_topk of its keys
+    assert stats["moe_layer_steps"] == 16
+    assert stats["moe_expert_rows"] == 16 * 2 * 2
+    assert stats["dsa_keys_selected"] == 8 * 3 * 16
 
 
 # ---- (b) the share adds up to the uncut layer ------------------------------
@@ -193,7 +195,7 @@ def test_the_shares_add_up_to_the_uncut_expert_layer():
         m = make_config(dict(TOY, ep_rank=rank)).model
         part = {**lp, **{n: lp[n][2 * rank:2 * rank + 2]
                          for n in ("w1", "w3", "w2")}}
-        y, assigned, _ = dsv.expert_mlp(part, x, m, live)
+        y, (assigned, *_) = dsv.expert_mlp(part, x, m, live)
         total += np.asarray(y[0]) - shared
         held += int(assigned)
     np.testing.assert_allclose(total, want, atol=2e-5)
@@ -327,10 +329,13 @@ def test_a_chunk_boundary_changes_nothing():
     _, b = program_logits(e_whole, params, PROMPT)
     for x, y in zip(a, b):
         np.testing.assert_allclose(x, y, atol=2e-5)
-    sa, sb = e_chunks.take_stats(), e_whole.take_stats()
+    sa, sb = (dict(zip(dsv.STAT_NAMES, e.take_stats()))
+              for e in (e_chunks, e_whole))
     # the same keys selected and scored, the same held assignments,
     # whatever the number of programs (and so of expert-layer steps)
-    assert sa[3:].tolist() == sb[3:].tolist() and sa[0] == sb[0]
+    for name in ("moe_layer_steps", "moe_experts_hit", "moe_expert_rows"):
+        sa.pop(name), sb.pop(name)
+    assert sa == sb
 
 
 # ---- (e) YaRN ---------------------------------------------------------------
@@ -525,11 +530,14 @@ def test_stats_leave_the_programs_a_row_a_layer():
     pending, = engine._stats_pending
     assert pending.shape == (3, len(dsv.STAT_NAMES))
     assert pending.dtype == jnp.int32
-    rows = np.asarray(pending)
-    assert (rows[:, 4] == (np.arange(len(PROMPT)) + 1).sum()).all()
-    assert list(rows[:, 2]) == [0, 1, 1]  # the dense layer routes nothing
+    rows = dict(zip(dsv.STAT_NAMES, np.asarray(pending).T))
+    assert (rows["dsa_keys_scored"]
+            == (np.arange(len(PROMPT)) + 1).sum()).all()
+    # the dense layer routes nothing
+    assert list(rows["moe_layer_steps"]) == [0, 1, 1]
     total = engine.take_stats()
-    assert total.dtype == np.int64 and (total == rows.sum(axis=0)).all()
+    assert total.dtype == np.int64
+    assert (total == np.asarray(pending).sum(axis=0)).all()
 
 
 # ---- (h) the cell's rehearsal -------------------------------------------------

@@ -180,8 +180,13 @@ def test_a_chunk_boundary_changes_nothing():
     for name in ("ssm", "conv"):
         np.testing.assert_allclose(ca[name][:, 0], cb[name][:, 0], atol=1e-5,
                                    rtol=1e-5)
-    sa, sb = e_chunks.take_stats(), e_whole.take_stats()
-    assert sa[0] == sb[0] and sa[3:].tolist() == sb[3:].tolist()
+    sa, sb = (dict(zip(gh.STAT_NAMES, e.take_stats()))
+              for e in (e_chunks, e_whole))
+    # the same assignments and scans whatever the number of programs (and so
+    # of expert-layer steps, and of padded rows through the experts' loop)
+    for name in ("moe_layer_steps", "moe_experts_hit", "moe_expert_rows"):
+        sa.pop(name), sb.pop(name)
+    assert sa == sb
 
 
 # ---- (b) the chunked scan against the recurrence ---------------------------
@@ -347,7 +352,7 @@ def test_the_shares_add_up_to_the_uncut_expert_layer():
         m = make_config(dict(TOY, ep_rank=rank)).model
         part = {**lp, **{n: lp[n][3 * rank:3 * rank + 3]
                          for n in ("w1", "w3", "w2")}}
-        y, assigned, _ = gh.expert_mlp(part, x, m, live)
+        y, (assigned, *_) = gh.expert_mlp(part, x, m, live)
         total += np.asarray(y[0]) - shared
         held += int(assigned)
     np.testing.assert_allclose(total, want, atol=2e-5)
@@ -503,10 +508,15 @@ def test_stats_leave_the_programs_a_row_a_layer():
     pending, = engine._stats_pending
     assert pending.shape == (5, len(gh.STAT_NAMES))
     assert pending.dtype == jnp.int32
-    rows = np.asarray(pending)
-    assert list(rows[:, 2]) == [1] * 5  # experts behind every layer
-    assert list(rows[:, 5]) == [44, 44, 0, 44, 44]  # the attention layer
-    assert not rows[:, 3:5].any()  # a prefill is no decode step
+    rows = dict(zip(gh.STAT_NAMES, np.asarray(pending).T))
+    assert list(rows["moe_layer_steps"]) == [1] * 5  # behind every layer
+    # the attention layer scans nothing
+    assert list(rows["ssm_tokens_scanned"]) == [44, 44, 0, 44, 44]
+    # a prefill is no decode step
+    assert not rows["ssm_state_updates"].any()
+    assert not rows["ssm_layer_steps"].any()
+    # the bucket's 64 rows (44 live) through the three held experts' loop
+    assert list(rows["moe_expert_rows"]) == [64 * 3] * 5
 
 
 def test_the_batcher_puts_the_counters_on_metrics():

@@ -146,13 +146,14 @@ def test_the_whole_forward_matches_the_reference_at_every_position(toy):
     want = ref.forward_logits(params, np.asarray(tokens), dict(TOY))[0]
     assert float(np.max(np.abs(np.asarray(logits[0]) - want))
                  / np.max(np.abs(want))) < 1e-4
-    rows = np.asarray(stats)
+    rows = dict(zip(mimo_v2.STAT_NAMES, np.asarray(stats).T))
     # sliding layers 1, 2 and 4: 1 + 2 + .. of the first 6 queries, then 6
     # each; the full ones count nothing
     attended = sum(min(t + 1, 6) for t in range(50))
-    assert list(rows[:, 3]) == [0, attended, attended, 0, attended]
-    assert list(rows[:, 4]) == [0, 1275, 1275, 0, 1275]  # 50 x 51 / 2
-    assert list(rows[:, 2]) == [0, 1, 1, 1, 1] and not rows[:, 5].any()
+    assert list(rows["swa_rows_attended"]) == [0, attended, attended, 0, attended]
+    assert list(rows["swa_rows_context"]) == [0, 1275, 1275, 0, 1275]  # 50 x 51 / 2
+    assert list(rows["moe_layer_steps"]) == [0, 1, 1, 1, 1]
+    assert not rows["swa_layer_steps"].any()
 
 
 def test_a_slot_used_twice_forgets_its_first_occupant(toy):
@@ -463,14 +464,14 @@ def test_the_shares_add_up_to_the_uncut_expert_layer():
         return jax.jit(lambda lp, x, live: mimo_v2.expert_mlp(
             lp, x, m, live))(lp, x, live)
 
-    whole, assigned, hit = mlp(lp, x, uncut, live)
+    whole, (assigned, hit, *_) = mlp(lp, x, uncut, live)
     assert int(assigned) == 2 * 12 * 2 and int(hit) <= 8
     total, held = jnp.zeros_like(whole), 0
     for rank in range(4):
         m = make_config(dict(ep_rank=rank)).model
         part = {**lp, **{n: lp[n][2 * rank:2 * rank + 2]
                          for n in ("w1", "w3", "w2")}}
-        y, n, _ = mlp(part, x, m, live)
+        y, (n, *_) = mlp(part, x, m, live)
         total, held = total + y, held + int(n)
     np.testing.assert_allclose(total, whole, atol=2e-5)
     assert held == 2 * 12 * 2  # every token's experts are held by some rank
@@ -478,7 +479,7 @@ def test_the_shares_add_up_to_the_uncut_expert_layer():
                        dict(TOY, n_routed_experts=8, ep_size=1))
     np.testing.assert_allclose(whole.reshape(24, 64), want, atol=2e-5)
     # rows that are not live are routed nowhere: nothing at all
-    y, n, _ = mlp(lp, x, uncut, jnp.zeros((2, 12), bool))
+    y, (n, *_) = mlp(lp, x, uncut, jnp.zeros((2, 12), bool))
     assert not np.asarray(y).any() and int(n) == 0
 
 
@@ -492,19 +493,20 @@ def test_share_with_a_shared_expert_is_bit_for_bit_what_it_was():
     x = f(6, 16)
     w_held = jnp.asarray(rng.uniform(0, 1, (6, 2)) * (rng.uniform(
         0, 1, (6, 2)) > 0.4), jnp.float32)
-    routed = experts.routed_experts(x, w_held, lp)
-    y, assigned, hit = experts.share(lp, x, w_held)
+    routed, run = experts.routed_experts(x, w_held, lp)
+    y, (assigned, hit, steps, rows) = experts.share(lp, x, w_held)
     assert y.dtype == x.dtype
     np.testing.assert_array_equal(np.asarray(y, np.float32),
                                   np.asarray(routed.astype(x.dtype),
                                              np.float32))
-    ys, assigned_s, hit_s = experts.share({**lp, **shared}, x, w_held)
+    ys, (assigned_s, hit_s, *_) = experts.share({**lp, **shared}, x, w_held)
     before = routed.astype(x.dtype) + experts.swiglu(
         x, shared["ws_gate"], shared["ws_up"], shared["ws_down"])
     np.testing.assert_array_equal(np.asarray(ys, np.float32),
                                   np.asarray(before, np.float32))
     assert int(assigned) == int(assigned_s) == int((w_held > 0).sum())
-    assert int(hit) == int(hit_s)
+    assert int(hit) == int(hit_s) and int(steps) == 1
+    assert int(rows) == int(run) == 6 * 2  # the loop: every row, both experts
 
 
 def test_the_router_is_experts_route_with_eight_of_256():
