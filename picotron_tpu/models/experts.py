@@ -1,7 +1,8 @@
 """This chip's share of an expert layer: which of a router's experts are
-held here, their part of the routed sum (one expert after the other over
-every row, or from ``takes_grouped`` rows up each over its own rows only),
-and what of it is counted. The expert blocks (``deepseek_v32``, ``granite_hybrid``, ``afmoe``, ``mimo_v2``)
+held here, their part of the routed sum (every held expert over every row,
+as a loop or, where ``takes_pipelined``, as one pipelined pass; from
+``takes_grouped`` rows up each expert over its own rows only), and what of
+it is counted. The expert blocks (``deepseek_v32``, ``granite_hybrid``, ``afmoe``, ``mimo_v2``)
 route over the router's whole width (the sigmoid router of three of them is
 ``route``) and hand the choice here; the tree's leaves are named alike in
 all: ``w1``/``w3``/``w2`` ``[held, ...]`` a layer (the group's ``UNSLICED``
@@ -23,9 +24,10 @@ UNSLICED = ("w1", "w3", "w2")
 # what ``share`` counts of an expert layer's step, the head of every expert
 # block's ``STAT_NAMES``: assignments that landed on an expert held here,
 # held experts a row chose, the step itself, expert-rows pushed through the
-# held experts (padding and rows that chose another expert included)
+# held experts (padding and rows that chose another expert included), the
+# step again where its rows took the pipelined pass (``takes_pipelined``)
 STAT_NAMES = ("moe_assignments", "moe_experts_hit", "moe_layer_steps",
-              "moe_expert_rows")
+              "moe_expert_rows", "moe_pipelined_steps")
 # rows an expert at which its matmuls take as long as its bfloat16 weights'
 # read on a v5e: 197 TFLOP/s / 819 GB/s
 RIDGE_ROWS = 240
@@ -81,10 +83,10 @@ def takes_grouped(rows: int) -> bool:
     alone 0.83; at 256 rows 1.66 and 1.03: PERF.md section 6, PR 43). From
     the ridge up the rows go grouped: the 256-row bucket and the 512-row
     chunk, not the 128-row bucket, a decode block's or a verify's rows
-    (8-64), whose every held expert is read at every step as before (what
-    the kernel would win there, 1.38 -> 1.03 ms, is its pipeline's and not
-    the grouping's: PERF.md section 7). Static, and of the shape alone: no
-    caller and no option chooses."""
+    (8-64), whose every held expert is read at every step as before, by
+    the loop or by the pipelined pass (``takes_pipelined``: what a kernel
+    wins there is its pipeline's, not the grouping's). Static, and of the
+    shape alone: no caller and no option chooses."""
     return rows >= RIDGE_ROWS
 
 
@@ -110,6 +112,15 @@ def group_rows(w_held, tile: int) -> tuple:
     return rank, expert, base, ends[-1]
 
 
+def _stacks(lp) -> tuple:
+    """(this layer's row in them, int32 [1]; ``w1``, ``w3``, ``w2`` as
+    stacks [layers, held, ...]) of a layer's leaves: the group's own stacks
+    beside ``lp["row"]``, or the layer's matrices as a stack of one."""
+    row = lp.get("row")
+    stacks = [lp[n] if row is not None else lp[n][None] for n in UNSLICED]
+    return jnp.asarray(0 if row is None else row, jnp.int32)[None], stacks
+
+
 def _grouped(x, w_held, lp) -> tuple:
     """``routed_experts`` for the rows ``takes_grouped`` sends here: (the
     float32 sum, expert-rows run). The rows go ``GROUP_ROWS`` at a time
@@ -117,8 +128,7 @@ def _grouped(x, w_held, lp) -> tuple:
     grouped by itself and reading every held expert it has a row for
     once."""
     N, H = x.shape
-    row = lp.get("row")
-    stacks = [lp[n] if row is not None else lp[n][None] for n in UNSLICED]
+    row, stacks = _stacks(lp)
     tile = grouped.TILE
     rows = min(GROUP_ROWS, -(-N // tile) * tile)
     pad = -N % rows
@@ -128,8 +138,7 @@ def _grouped(x, w_held, lp) -> tuple:
         rank, expert, base, tiles = jax.vmap(
             lambda w: group_rows(w, tile))(
                 w_held.reshape(-1, rows, w_held.shape[1]))
-        meta = jnp.concatenate(
-            (jnp.asarray(0 if row is None else row, jnp.int32)[None], tiles))
+        meta = jnp.concatenate((row, tiles))
         y = grouped.grouped_swiglu(
             x, w_held, rank.reshape(w_held.shape), expert.reshape(-1),
             base.reshape(-1), meta, *stacks, rows=rows, tile=tile,
@@ -137,10 +146,46 @@ def _grouped(x, w_held, lp) -> tuple:
     return y[:N], jnp.sum(tiles) * tile
 
 
+def takes_pipelined(rows: int, H: int, I: int, itemsize: int) -> bool:
+    """Whether ``rows`` rows below the ridge go through the held experts as
+    one pipelined pass and not the loop: the same work in the same order,
+    every held expert read at every step, so the choice is of speed alone.
+    The loop is three fusions an expert, each of which starts with an empty
+    pipeline: 4-6 us lost a fusion whatever its matrix's size, a third of
+    the time where the matrix is Granite's 6.3 MB (7.7 us at HBM speed), a
+    seventh where it is DeepSeek's 29.4 MB; the pass fetches the next
+    expert's matrices under this one's matmuls. It takes the shapes whose
+    three matrices go whole through the kernel's weight budget
+    (``grouped.fits``: one grid step an expert, every DMA one contiguous
+    matrix), which of the four served blocks is Granite's: a layer's routed
+    sum 1.286 -> 0.926 ms at 64 rows on the chip (8 rows 1.144 -> 0.923, 200
+    rows 1.430 -> 0.944) and the cell +9 to +14 % (PERF.md section 6, PR
+    44). A wider expert would go a block of its width at a time: read that
+    way Trinity's cell gained 2-3 %, MiMo's LOST 1 % (its loop's fusions sit
+    closer together in the cell than alone) and DeepSeek's pass was behind
+    its loop already alone, so they keep the loop. Static, and of the shapes
+    alone: no caller, no option and no model's name chooses."""
+    return not takes_grouped(rows) and grouped.fits(H, I, itemsize)
+
+
+def _pipelined(x, w_held, lp):
+    """``routed_experts`` for the rows ``takes_pipelined`` sends here: the
+    float32 sum (the expert-rows are the loop's, rows x held)."""
+    N = x.shape[0]
+    # whole sublane tiles of the dtype; the rows added weigh 0
+    pad = ((0, -N % (32 // x.dtype.itemsize)), (0, 0))
+    row, stacks = _stacks(lp)
+    with jax.named_scope("pipelined"):
+        y = grouped.pipelined_swiglu(jnp.pad(x, pad), jnp.pad(w_held, pad),
+                                     row, *stacks, interpret=not on_tpu())
+    return y[:N]
+
+
 def routed_experts(x, w_held, lp) -> tuple:
     """(``sum_e w_held[:, e] * E_e(x)`` over the experts held here, float32
-    [N, H]; the expert-rows it ran). Two orders of the same work, chosen by
-    ``takes_grouped(N)``:
+    [N, H]; the expert-rows it ran; whether the pipelined pass ran them).
+    Three orders of the same work, chosen by ``takes_grouped(N)`` and, below
+    it, ``takes_pipelined``:
 
     - the loop, one expert after the other, each over every row (``N x
       held`` expert-rows). Every held expert runs at every step, chosen or
@@ -150,8 +195,15 @@ def routed_experts(x, w_held, lp) -> tuple:
       1.5-2.2 of DeepSeek's 8; measured 450-467 tokens/s over seeds), which
       is a property of the cut, not of the model. That is about which
       experts' weights a step reads, and the rows below the ridge (a decode
-      block, a verify, the small buckets) keep it as it was;
-    - grouped (``ops/pallas/grouped_experts.py``): each held expert over
+      block, a verify, the small buckets) keep it on both of their orders;
+    - the pipelined pass (``ops/pallas/grouped_experts.py::
+      pipelined_swiglu``): the loop's work in the loop's order, every held
+      expert over every row, expert 0 first, as ONE kernel whose grid is the
+      held experts, so that the next expert's matrices are fetched under
+      this one's matmuls (the loop is three fusions an expert, each of which
+      starts with an empty pipeline and ends draining it), where an
+      expert's three matrices go whole through the kernel;
+    - grouped (``grouped_swiglu`` there): each held expert over
       its own rows only, in tiles of 128 (the assignments, whatever their
       skew, plus at most a tile of padding a group), the same matmuls in
       the same precisions and the same float32 sum of a row's experts, in
@@ -161,10 +213,14 @@ def routed_experts(x, w_held, lp) -> tuple:
 
     With a ``row`` entry, ``lp``'s expert leaves are the group's whole
     stacks (``UNSLICED``) and this layer is that row of them: each expert's
-    matrices are then read in place on both paths (a layer's slice of the
-    stack, handed to either, is a copy of all of them)."""
-    if takes_grouped(x.shape[0]):
-        return _grouped(x, w_held, lp)
+    matrices are then read in place on every path (a layer's slice of the
+    stack, handed to any, is a copy of all of them)."""
+    N, n = w_held.shape
+    if takes_grouped(N):
+        return _grouped(x, w_held, lp) + (jnp.zeros((), jnp.int32),)
+    run = jnp.asarray(N * n, jnp.int32)
+    if takes_pipelined(*x.shape, lp["w1"].shape[-1], lp["w1"].dtype.itemsize):
+        return _pipelined(x, w_held, lp), run, jnp.ones((), jnp.int32)
     row = lp.get("row")
 
     def weights(name, e):
@@ -176,10 +232,9 @@ def routed_experts(x, w_held, lp) -> tuple:
         y = swiglu(x, weights("w1", e), weights("w3", e), weights("w2", e))
         return acc + y.astype(jnp.float32) * w[:, None], None
 
-    n = w_held.shape[1]
     acc, _ = lax.scan(one, jnp.zeros(x.shape, jnp.float32),
                       (w_held.T, jnp.arange(n, dtype=jnp.int32)))
-    return acc, jnp.asarray(x.shape[0] * n, jnp.int32)
+    return acc, run, jnp.zeros((), jnp.int32)
 
 
 def share(lp, x2, w_held) -> tuple:
@@ -189,7 +244,7 @@ def share(lp, x2, w_held) -> tuple:
     live: all 0). A layer whose leaves hold no ``ws_gate`` has no shared
     expert (``mimo_v2``)."""
     with jax.named_scope("moe_experts"):
-        y, run = routed_experts(x2, w_held, lp)
+        y, run, pipelined = routed_experts(x2, w_held, lp)
     if "ws_gate" in lp:
         with jax.named_scope("shared_expert"):
             y = y.astype(x2.dtype) + swiglu(x2, lp["ws_gate"], lp["ws_up"],
@@ -198,4 +253,4 @@ def share(lp, x2, w_held) -> tuple:
         y = y.astype(x2.dtype)
     assigned = jnp.sum(w_held > 0, dtype=jnp.int32)
     hit = jnp.sum(jnp.any(w_held > 0, axis=0), dtype=jnp.int32)
-    return y, (assigned, hit, jnp.ones((), jnp.int32), run)
+    return y, (assigned, hit, jnp.ones((), jnp.int32), run, pipelined)

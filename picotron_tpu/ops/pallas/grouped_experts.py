@@ -1,4 +1,7 @@
-"""Grouped expert SwiGLU: each held expert over its own rows only.
+"""The held experts' SwiGLU as Pallas kernels: grouped, each held expert over
+its own rows only (``grouped_swiglu``, below), and pipelined, every held
+expert over every row in one pass (``pipelined_swiglu``, at the end: the
+loop's work in the loop's order, for the rows below the ridge).
 
 ``sum_e w[:, e] * E_e(x)`` for rows ``x`` [N, H] and the experts a chip
 holds (``w1``/``w3`` [L, E, H, I], ``w2`` [L, E, I, H]: the stacks of every
@@ -41,14 +44,34 @@ TILE = 128  # rows a tile: the MXU's own height (fewer rows cost it the same)
 WEIGHT_VMEM = 40 << 20
 
 
+def fits(H: int, ti: int, itemsize: int) -> bool:
+    """Whether a step's three weight blocks, ``ti`` of the expert's width
+    each, stay inside ``WEIGHT_VMEM`` twice over (the blocks in use, the
+    blocks being fetched)."""
+    return 3 * H * ti * itemsize * 2 <= WEIGHT_VMEM
+
+
 def _block_i(H: int, I: int, itemsize: int) -> int:
     """The widest block of the expert's width that divides it in whole lane
-    tiles and keeps the step's weight blocks, twice, inside ``WEIGHT_VMEM``
-    (Granite's 768 whole; 256 of DeepSeek's 2048)."""
+    tiles and ``fits`` (Granite's 768 whole; 256 of DeepSeek's 2048)."""
     ti = I
-    while 3 * H * ti * itemsize * 2 > WEIGHT_VMEM and ti % 256 == 0:
+    while not fits(H, ti, itemsize) and ti % 256 == 0:
         ti //= 2
     return ti
+
+
+def _swiglu(x, w1_ref, w3_ref, w2_ref):
+    """float32 ``(silu(x w1) * (x w3)) w2`` over the blocks in VMEM: operands
+    of the model's dtype, float32 accumulation, each product rounded to the
+    dtype where the loop's ``swiglu`` rounds it."""
+    dtype = x.dtype
+    gate = jnp.dot(x, w1_ref[...],
+                   preferred_element_type=jnp.float32).astype(dtype)
+    up = jnp.dot(x, w3_ref[...],
+                 preferred_element_type=jnp.float32).astype(dtype)
+    h = (jax.nn.silu(gate.astype(jnp.float32))
+         * up.astype(jnp.float32)).astype(dtype)
+    return jnp.dot(h, w2_ref[...], preferred_element_type=jnp.float32)
 
 
 def _kernel(te_ref, tb_ref, meta_ref, rank_ref, rank_t_ref, w_ref, x_ref,
@@ -78,15 +101,7 @@ def _kernel(te_ref, tb_ref, meta_ref, rank_ref, rank_t_ref, w_ref, x_ref,
                                   ).astype(dtype)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        xt = xt_ref[...]
-        gate = jnp.dot(xt, w1_ref[...],
-                       preferred_element_type=jnp.float32).astype(dtype)
-        up = jnp.dot(xt, w3_ref[...],
-                     preferred_element_type=jnp.float32).astype(dtype)
-        h = (jax.nn.silu(gate.astype(jnp.float32))
-             * up.astype(jnp.float32)).astype(dtype)
-        acc_ref[...] += jnp.dot(h, w2_ref[...],
-                                preferred_element_type=jnp.float32)
+        acc_ref[...] += _swiglu(xt_ref[...], w1_ref, w3_ref, w2_ref)
 
         @pl.when(j == pl.num_programs(2) - 1)
         def _():
@@ -162,3 +177,51 @@ def grouped_swiglu(x, w_held, rank, tile_expert, tile_base, meta, w1, w3, w2,
         interpret=interpret,
         name="grouped_experts",
     )(tile_expert, tile_base, meta, rank, rank.T, w_held, x, w1, w3, w2)
+
+
+def _pipelined_kernel(row_ref, w_ref, x_ref, w1_ref, w3_ref, w2_ref, o_ref):
+    e = pl.program_id(0)
+
+    @pl.when(e == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    y = _swiglu(x_ref[...], w1_ref, w3_ref, w2_ref).astype(x_ref.dtype)
+    mine = lax.broadcasted_iota(jnp.int32, w_ref.shape, 1) == e
+    w = jnp.sum(jnp.where(mine, w_ref[...], 0.0), axis=1, keepdims=True)
+    o_ref[...] += y.astype(jnp.float32) * w
+
+
+def pipelined_swiglu(x, w_held, row, w1, w3, w2, *, interpret: bool = False):
+    """float32 [N, H]: ``sum_e w_held[:, e] * swiglu_e(x)``, every held
+    expert over every row, expert 0 first, as the loop of ``models/experts.py
+    ::routed_experts`` runs it: the grid is the held experts, ALL of them
+    whatever ``w_held`` holds, one step each with the expert's three
+    matrices whole (the caller sees that they ``fits``: every DMA is one
+    contiguous matrix). The rows and their float32 sum stay in VMEM for the
+    whole call, and step ``e``'s matmuls run while step ``e + 1``'s matrices
+    are fetched out of the stacks in place (layer ``row`` of ``w1``/``w3``
+    [L, E, H, I], ``w2`` [L, E, I, H]), where the loop's three fusions an
+    expert each start with an empty pipeline. ``x`` [N, H], N whole sublane
+    tiles of its dtype; ``w_held`` [N, E] float32."""
+    N, H = x.shape
+    E, I = w1.shape[1], w1.shape[3]
+    held = lambda e, row: (row[0], e, 0, 0)
+    rows = lambda width: pl.BlockSpec((N, width), lambda e, row: (0, 0))
+    up = pl.BlockSpec((None, None, H, I), held)
+    vmem = (2 * N * H * (x.dtype.itemsize + 4)  # x, the sum: two buffers each
+            + 2 * 3 * H * I * w1.dtype.itemsize + (16 << 20))
+    return pl.pallas_call(
+        _pipelined_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(E,),
+            in_specs=[rows(E), rows(H), up, up,
+                      pl.BlockSpec((None, None, I, H), held)],
+            out_specs=rows(H)),
+        out_shape=jax.ShapeDtypeStruct((N, H), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem),
+        interpret=interpret,
+        name="pipelined_experts",
+    )(row, w_held, x, w1, w3, w2)

@@ -551,11 +551,25 @@ def experts_on_chip(monkeypatch):
     monkeypatch.setattr(experts, "on_tpu", lambda: True)
 
 
-def _assert_grouped_in_the_chunk_alone(text: str, prog: str):
+def _assert_expert_orders(text: str, prog: str, pipelined: bool):
     """ISSUE 43: the 512-row chunk runs each held expert over its own rows
     (the kernel reads the stacks where they lie: the callers' ``sliced``
-    lists hold it to that), the decode block's rows keep the loop."""
-    assert ("grouped_experts" in text) == (prog == "prefill_chunk")
+    lists hold it to that). ISSUE 44: the decode block's rows take the
+    pipelined pass where ``experts.takes_pipelined`` says so (Granite's
+    shape: ``pipelined``), one Pallas call a layer and no scan over the
+    held experts beside it, and keep that scan where it does not
+    (DeepSeek's, Trinity's, MiMo's)."""
+    chunk = prog == "prefill_chunk"
+    assert ("moe_experts/grouped/grouped_experts" in text) == chunk
+    assert ("moe_experts/pipelined/pipelined_experts" in text) == (
+        pipelined and not chunk)
+    # the loop's scan carries the held experts' weights a row ([held, rows]
+    # float32) and their indices, then the stacks [layers, held, ...]
+    loops = [l.strip()[:160] for l in text.splitlines()
+             if " while(" in l and re.search(
+                 r"f32\[(\d+),\d+\]\{[^}]*\}, s32\[\1\]\{[^}]*\}, "
+                 r"(?:/\*index=\d+\*/)?bf16\[\d+,\1,\d+,\d+\]", l)]
+    assert bool(loops) == (not pipelined and not chunk), "\n".join(loops)
 
 
 # ---- the latent cache of the DeepSeek-V3.2 block (PR 28) -------------------
@@ -615,7 +629,7 @@ def test_latent_cache_is_row_major_and_never_copied(prog, topo, one_chip,
     copies a whole leaf; nor is a layer's slice of the experts' stacks
     copied out before the loop over experts or the grouped kernel."""
     text = _latent_program(topo, prog).as_text()
-    _assert_grouped_in_the_chunk_alone(text, prog)
+    _assert_expert_orders(text, prog, pipelined=False)
     leaf = r"bf16\[2,8,24576,(?:640|128)\]"
     lines = text.splitlines()
     copies = [l.strip()[:160] for l in lines
@@ -689,12 +703,13 @@ def test_recurrent_state_is_row_major_and_never_copied(prog, topo, one_chip,
     exit (PR 32 read both copies here, 2.46 GB of temporaries, 15.5 GB in
     all; ``kv_cache.row_major``): the leaf stays row-major, no instruction copies it, and
     K and V of the attention layer stay in place beside it; nor is a layer's
-    slice of the experts' stacks (680 MB) copied out before the loop over
-    experts or, in the chunk, the grouped kernel (ISSUE 43), whose VMEM the
-    compiler grants beside what it stages there itself."""
+    slice of the experts' stacks (680 MB) copied out before the pipelined
+    pass over the experts (ISSUE 44) or, in the chunk, the grouped kernel
+    (ISSUE 43), whose VMEM the compiler grants beside what it stages there
+    itself."""
     compiled = _cell_program(topo, prog, "granite-4.0-h-small-ep2-l10")
     text = compiled.as_text()
-    _assert_grouped_in_the_chunk_alone(text, prog)
+    _assert_expert_orders(text, prog, pipelined=True)
     lines = text.splitlines()
     state = r"f32\[9,64,128,64,128\]"
     kv = r"bf16\[1,64,4096,8,128\]"
@@ -709,6 +724,26 @@ def test_recurrent_state_is_row_major_and_never_copied(prog, topo, one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 500e6
     sliced = [l.strip()[:160] for l in lines
               if re.search(r"= bf16\[(?:1,)?36,(?:4096,768|768,4096)\]", l)
+              and " parameter(" not in l and "get-tuple-element" not in l]
+    assert not sliced, "\n".join(sliced)
+
+
+def test_trinity_decode_block_keeps_the_loop(topo, one_chip, monkeypatch,
+                                             experts_on_chip):
+    """The Trinity cell's decode block (16 rows over eight held experts of
+    three 18.9 MB matrices each: 113 MB with the pipeline's two buffers,
+    past the pipelined pass's whole-matrix budget): the scan over the held
+    experts, no Pallas call for them, the stacks read where they lie, the
+    rings' kernel beside it."""
+    from picotron_tpu.models import afmoe
+
+    monkeypatch.setattr(afmoe, "on_tpu", lambda: True)
+    compiled = _cell_program(topo, "decode_block", "trinity-large-ep32-l9")
+    text = compiled.as_text()
+    _assert_expert_orders(text, "decode_block", pipelined=False)
+    assert "flash_decode_ring" in text
+    sliced = [l.strip()[:160] for l in text.splitlines()
+              if re.search(r"= bf16\[(?:1,)?8,3072,3072\]", l)
               and " parameter(" not in l and "get-tuple-element" not in l]
     assert not sliced, "\n".join(sliced)
 
@@ -765,7 +800,7 @@ def test_mimo_cache_leaves_are_never_copied_whole(prog, topo, one_chip,
     monkeypatch.setattr(mimo_v2, "on_tpu", lambda: True)
     compiled = _cell_program(topo, prog, "mimo-v2.5-ep32-l13")
     text = compiled.as_text()
-    _assert_grouped_in_the_chunk_alone(text, prog)
+    _assert_expert_orders(text, prog, pipelined=False)
     lines = text.splitlines()
     leaves = {"k": r"bf16\[3,32,16384,768\]", "v": r"bf16\[3,32,16384,512\]",
               "kw": r"bf16\[10,32,640,1536\]",

@@ -333,7 +333,8 @@ def test_a_chunk_boundary_changes_nothing():
               for e in (e_chunks, e_whole))
     # the same keys selected and scored, the same held assignments,
     # whatever the number of programs (and so of expert-layer steps)
-    for name in ("moe_layer_steps", "moe_experts_hit", "moe_expert_rows"):
+    for name in ("moe_layer_steps", "moe_experts_hit", "moe_expert_rows",
+                 "moe_pipelined_steps"):
         sa.pop(name), sb.pop(name)
     assert sa == sb
 

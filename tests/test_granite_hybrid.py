@@ -184,7 +184,8 @@ def test_a_chunk_boundary_changes_nothing():
               for e in (e_chunks, e_whole))
     # the same assignments and scans whatever the number of programs (and so
     # of expert-layer steps, and of padded rows through the experts' loop)
-    for name in ("moe_layer_steps", "moe_experts_hit", "moe_expert_rows"):
+    for name in ("moe_layer_steps", "moe_experts_hit", "moe_expert_rows",
+                 "moe_pipelined_steps"):
         sa.pop(name), sb.pop(name)
     assert sa == sb
 

@@ -493,8 +493,8 @@ def test_share_with_a_shared_expert_is_bit_for_bit_what_it_was():
     x = f(6, 16)
     w_held = jnp.asarray(rng.uniform(0, 1, (6, 2)) * (rng.uniform(
         0, 1, (6, 2)) > 0.4), jnp.float32)
-    routed, run = experts.routed_experts(x, w_held, lp)
-    y, (assigned, hit, steps, rows) = experts.share(lp, x, w_held)
+    routed, run, pipelined = experts.routed_experts(x, w_held, lp)
+    y, (assigned, hit, steps, rows, passed) = experts.share(lp, x, w_held)
     assert y.dtype == x.dtype
     np.testing.assert_array_equal(np.asarray(y, np.float32),
                                   np.asarray(routed.astype(x.dtype),
@@ -506,7 +506,9 @@ def test_share_with_a_shared_expert_is_bit_for_bit_what_it_was():
                                   np.asarray(before, np.float32))
     assert int(assigned) == int(assigned_s) == int((w_held > 0).sum())
     assert int(hit) == int(hit_s) and int(steps) == 1
-    assert int(rows) == int(run) == 6 * 2  # the loop: every row, both experts
+    # below the ridge, the pass or the loop: every row, both experts
+    assert int(rows) == int(run) == 6 * 2
+    assert int(passed) == int(pipelined) == 1
 
 
 def test_the_router_is_experts_route_with_eight_of_256():
