@@ -2,7 +2,7 @@
 NATIVE_SO := picotron_tpu/native/_build/libpicotron_data.so
 NATIVE_SRC := picotron_tpu/native/dataloader.cc
 
-.PHONY: native test test-all test-isolated bench lint decode-smoke spec-smoke kernel-smoke quant-smoke paged-smoke chaos-smoke chaos-pod-smoke serve-smoke serve-chaos-smoke router-chaos-smoke disagg-smoke dp-smoke tenant-smoke fleet-chaos-smoke fleet-bench obs-smoke overlap-smoke mixed-smoke clean
+.PHONY: native test test-all test-isolated lint decode-smoke spec-smoke kernel-smoke quant-smoke paged-smoke chaos-smoke chaos-pod-smoke serve-smoke serve-chaos-smoke router-chaos-smoke tenant-smoke fleet-chaos-smoke obs-smoke clean
 
 native: $(NATIVE_SO)
 
@@ -22,12 +22,8 @@ test-all: native lint
 	$(MAKE) obs-smoke
 	$(MAKE) quant-smoke
 	$(MAKE) router-chaos-smoke
-	$(MAKE) disagg-smoke
-	$(MAKE) dp-smoke
 	$(MAKE) tenant-smoke
 	$(MAKE) fleet-chaos-smoke
-	$(MAKE) overlap-smoke
-	$(MAKE) mixed-smoke
 
 # picolint static analysis (picotron_tpu/analysis/, docs/ANALYSIS.md):
 # JAX hot-path rules (host syncs on traced values, trace-time
@@ -48,36 +44,22 @@ test-isolated: native
 	  python -m pytest "$$f" -q || fail=1; \
 	done; exit $$fail
 
-# On the chip only (no accelerator: it refuses to measure). The quickest
-# on-chip proof is `python chip_smoke.py`.
-bench: native
-	python bench.py
-
 # Serving-path smoke: tiny-model CPU generate through the full
 # prefill/KV-cache/batcher/CLI stack (picotron_tpu/inference) — seconds,
 # no checkpoint or network needed. Runs the blocked decode fast path
-# (on-device stop state, one host sync per block) and the int8 KV cache,
-# then the blocked-decode bench so dispatches-per-token shows up in logs.
+# (on-device stop state, one host sync per block) and the int8 KV cache.
 decode-smoke:
 	JAX_PLATFORMS=cpu python -m picotron_tpu.tools.generate --smoke
 	JAX_PLATFORMS=cpu python -m picotron_tpu.tools.generate --smoke \
 	  --kv-cache-dtype int8 --decode-block-len 4
-	JAX_PLATFORMS=cpu python bench_decode.py --block-len 8
 
 # Speculative-decoding smoke: draft-verify generation (prompt-lookup
-# drafter, one verify dispatch per accepted run) through the CLI, then
-# the spec bench on repetitive prompts — dispatches-per-token under the
-# spec-off baseline of 1 with a nonzero accept rate in the JSON line —
-# and the CONTROLLER run: a mixed repetitive/random-prompt workload
-# through the real batcher with inference.spec_controller enabled, so
-# spec_len_effective / accept_rate_by_drafter / controller-decision
-# counts land in the JSON trajectory (docs/INFERENCE.md "Self-tuning
-# speculation").
+# drafter, one verify dispatch per accepted run) through the CLI. The
+# accept rate, dispatches per token under 1 and the controller's
+# convergence are pinned in tests/test_speculative.py.
 spec-smoke:
 	JAX_PLATFORMS=cpu python -m picotron_tpu.tools.generate --smoke \
 	  --spec-len 4
-	JAX_PLATFORMS=cpu python bench_decode.py --spec-len 4
-	JAX_PLATFORMS=cpu python bench_decode.py --spec-len 4 --spec-auto
 
 # Flash-decode kernel parity (ops/pallas/decode_attention.py) in Pallas
 # interpret mode on CPU: flash vs dense allclose across S=1 decode,
@@ -85,20 +67,10 @@ spec-smoke:
 # lengths, stale rows, GQA down to nkv=1, non-dividing KV blocks;
 # double-buffered DMA pinned bitwise against the serial fetch — plus the
 # engine-level wiring proof for inference.attend_impl and the on-device
-# sampling epilogue's seeded host-equivalence. Closes with the
-# mixed-rung bench: every PR-11 ladder rung ON in one run (pipelined
-# flash DMA over paged pages, hot_bf16 per-page policy, fused sampling
-# epilogue), so the JSON line carries the full A/B field set
-# (kv_bytes_per_token, logits_bytes_to_host_per_token,
-# dispatch_latency_s) the TPU A/B matrix diffs. The serving default
-# stays dense, so decode-smoke/spec-smoke GENERATION output is
-# unchanged.
+# sampling epilogue's seeded host-equivalence.
 kernel-smoke:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_decode_kernel.py \
 	  tests/test_sampling_epilogue.py -q
-	JAX_PLATFORMS=cpu python bench_decode.py --attend-impl flash \
-	  --kv-layout paged --kv-page-policy hot_bf16 --sample-on-device \
-	  --block-len 8
 
 # Quantized-weights smoke (ops/pallas/quant_matmul.py, docs/INFERENCE.md
 # "Quantized weights"): per-channel int8 weights through the full
@@ -106,9 +78,7 @@ kernel-smoke:
 # IDENTICAL to a bf16 engine fed the fake-quant reference (the
 # quantization error is in both; any difference is the fused dequant
 # pipeline itself), on tp=1 here and tp=1/2 in tier-1
-# (tests/test_quant_weights.py). Closes with the int8 bench so
-# weight_bytes_total/weight_bytes_per_token land in the JSON trajectory
-# next to the bf16 default's. The serving default stays bf16, so
+# (tests/test_quant_weights.py). The serving default stays bf16, so
 # decode/spec/paged-smoke output is unchanged.
 quant-smoke:
 	JAX_PLATFORMS=cpu python -m picotron_tpu.tools.generate --smoke \
@@ -116,15 +86,12 @@ quant-smoke:
 	JAX_PLATFORMS=cpu python -m picotron_tpu.tools.generate --smoke \
 	  --weight-dtype int8 --check-weight-parity --kv-cache-dtype int8 \
 	  --decode-block-len 4
-	JAX_PLATFORMS=cpu python bench_decode.py --weight-dtype int8 \
-	  --block-len 8
 
 # Paged-KV smoke (inference/paged_kv.py): a shared-prefix batch through
 # the page-pool layout (block-table indirection, radix prefix sharing,
 # copy-on-write) with --check-layout-parity asserting every request's
-# tokens are IDENTICAL to the contiguous layout — fp32 and int8 caches —
-# then the paged bench so kv_pages_*/pool utilization land in the JSON
-# trajectory. tests/test_paged_kv.py is the full tier-1 matrix.
+# tokens are IDENTICAL to the contiguous layout — fp32 and int8 caches.
+# tests/test_paged_kv.py is the full tier-1 matrix.
 paged-smoke:
 	JAX_PLATFORMS=cpu python -m picotron_tpu.tools.generate --smoke \
 	  --kv-layout paged --check-layout-parity \
@@ -136,7 +103,6 @@ paged-smoke:
 	  --decode-block-len 4 \
 	  --prompt-ids "1,2,3,4,5,6,7,8,9,10,11,12,13,14,15,16,17,18" \
 	  --prompt-ids "1,2,3,4,5,6,7,8,9,10,11,12,13,14,21,22"
-	JAX_PLATFORMS=cpu python bench_decode.py --kv-layout paged --block-len 8
 
 # Fault-injection suite on a CPU mesh (picotron_tpu/resilience/): chaos
 # SIGTERM/crash/NaN/truncation at fixed steps, kill->resume bit-for-bit
@@ -182,31 +148,6 @@ obs-smoke:
 	  $(OBS_SMOKE_DIR)-overlap/trace.json \
 	  --require-request-chain --require-overlap-chain
 
-# Zero-bubble overlapped-scheduling smoke (inference.overlap,
-# docs/INFERENCE.md "Overlapped scheduling"): the bench_decode
-# --overlap ab protocol — the SAME batcher workload with the pipeline
-# off then on, synthetic device windows + injected per-token host work.
-# Gates bit-identical token streams, overlap-on dispatch-gap p50
-# <= 0.5x overlap-off, and tokens/s >= 1.3x with host work and device
-# time comparable. Runs inside `make test-all`; the serving default
-# stays overlap OFF, so decode/spec-smoke output is unchanged.
-overlap-smoke:
-	JAX_PLATFORMS=cpu python bench_decode.py --overlap ab
-
-# Mixed prefill-decode dispatch smoke (inference.mixed_dispatch,
-# docs/INFERENCE.md "Mixed prefill-decode dispatch"): the bench_decode
-# --mixed ab protocol — long prompts arriving mid-decode with the fused
-# lane off then on, plus a decoders-only TPOT floor leg. Gates
-# bit-identical token streams, decode TPOT p95 under concurrent prefill
-# <= 3x the no-prefill floor, TTFT p95 <= 3x the serial+gate baseline
-# (a CPU-proxy allowance: a solo B=1 chunk dispatch here is ~3x cheaper
-# than a fused round), and prompt tokens actually moved through the lane
-# (picotron_prefill_lane_tokens_total). Runs inside `make test-all`;
-# the serving default stays mixed_dispatch OFF, so every other smoke's
-# output is unchanged.
-mixed-smoke:
-	JAX_PLATFORMS=cpu python bench_decode.py --mixed ab
-
 # Multi-replica router chaos drill (tools/router.py, docs/SERVING.md
 # "Multi-replica fabric"): 3 in-process serve.py replicas behind the
 # prefix-affinity router; kill one mid-stream (the spliced client stream
@@ -220,49 +161,21 @@ mixed-smoke:
 router-chaos-smoke:
 	JAX_PLATFORMS=cpu python -m picotron_tpu.tools.router --smoke
 
-# Prefill/decode disaggregation interference bench (ISSUE 15,
-# docs/SERVING.md "Disaggregated prefill/decode"): decode-stream TPOT
-# with long shared-prefix prompts arriving mid-stream, measured three
-# ways — no interference (baseline), colocated (the long prefills run
-# inside the decode batcher's own loop and stall every stream), and a
-# disaggregated prefill+decode two-role fleet behind the router (the
-# prefills land on the prefill worker, finished KV pages stream to the
-# decode worker, its batcher never spends a dispatch on them). Greedy
-# streams asserted bit-identical across all three phases; the JSON
-# records tpot_p95_{baseline,colocated,disagg}, handoff bytes/latency,
-# and the cluster-wide prefix hit rate. Exit nonzero unless the
-# colocated configuration measurably degrades past the disaggregated
-# one. CPU proxy (subprocess replicas = one interpreter per role).
-disagg-smoke:
-	JAX_PLATFORMS=cpu python bench_decode.py --disagg
-
-# dp-sharded continuous batching smoke (ISSUE 18, inference/engine.py,
-# docs/INFERENCE.md "dp-sharded batching"): a REAL dp=2 batcher on the
-# forced multi-device CPU mesh vs the dp=1 baseline — gates bit-identical
-# greedy streams, slots_total = dp x slots_per_shard, a comm_trace-verified
-# collective-free decode hot path, and at least one cross-shard slot
-# migration driven by the occupancy-rebalance planner.
-dp-smoke:
-	JAX_PLATFORMS=cpu python bench_decode.py --dp 2
-
 # Multi-tenant serving smoke (ISSUE 16, inference/tenancy.py,
 # docs/SERVING.md "Multi-tenant serving"): the adapter-parity gate —
 # greedy generations through the segmented multi-LoRA matmul must be
 # IDENTICAL to an adapter-less engine fed the merged-weight (W + BA)
 # reference — on the int8 base (the fake-quant error is in both; any
-# difference is the segmented adapter path itself), then the
-# mixed-tenant bench: 3 adapters + base-only rows in ONE continuous
-# batch, per-tenant tokens/dpt/TTFT and adapter_bytes_per_token in the
-# JSON trajectory. The serving default stays adapter-less, so every
-# other smoke's output is unchanged.
+# difference is the segmented adapter path itself), then on the paged
+# layout under speculation. Several tenants in ONE continuous batch are
+# tier-1's (tests/test_tenancy.py). The serving default stays
+# adapter-less, so every other smoke's output is unchanged.
 tenant-smoke:
 	JAX_PLATFORMS=cpu python -m picotron_tpu.tools.generate --smoke \
 	  --weight-dtype int8 --adapter 4 --check-adapter-parity
 	JAX_PLATFORMS=cpu python -m picotron_tpu.tools.generate --smoke \
 	  --adapter 4:7:0.5 --check-adapter-parity --kv-layout paged \
 	  --spec-len 3
-	JAX_PLATFORMS=cpu python bench_decode.py --tenants 3 --adapter-rank 4 \
-	  --weight-dtype int8
 
 # Elastic fleet chaos drill (ISSUE 17, tools/fleet.py, docs/SERVING.md
 # "Elastic fleet"): the controller bootstraps a 3-worker fleet against
@@ -279,15 +192,6 @@ tenant-smoke:
 # malfunction.
 fleet-chaos-smoke:
 	JAX_PLATFORMS=cpu python -m picotron_tpu.tools.fleet --smoke
-
-# Elasticity latency bench (ISSUE 17): a real 3-worker SUBPROCESS fleet
-# (serve.py under supervise --serve; a SIGKILL is a real process-group
-# death) behind the router under the controller — the JSON records
-# scale_up_latency_s, replace_latency_s, ttft_p95_during_spike vs
-# ttft_p95_steady. Minutes on CPU (three cold jax startups are part of
-# what it measures), so it rides outside test-all.
-fleet-bench:
-	JAX_PLATFORMS=cpu python bench_decode.py --fleet
 
 # Serving chaos suite (tests/test_serving.py): dispatch-exception,
 # latency-spike, and poisoned-logits faults through the engine hooks —

@@ -41,8 +41,8 @@ per-token loop at ``decode_block_len == 1``).
 
 ``decode_dispatches`` / ``prefill_dispatches`` / ``generated_tokens``
 count engine calls and output tokens across the batcher's lifetime —
-``decode_dispatches / generated_tokens`` is the dispatches-per-token
-metric bench_decode.py tracks (1 for the per-token loop, ~1/block_len
+``decode_dispatches / generated_tokens`` is dispatches per token, the
+count of host syncs a token costs (1 for the per-token loop, ~1/block_len
 when every slot stays busy). Speculative runs add ``draft_proposed`` /
 ``draft_accepted`` (``accept_rate`` = their ratio): an accept rate of r
 means the average verify dispatch emitted ~1 + r*spec_len tokens.
@@ -390,7 +390,6 @@ class ContinuousBatcher:
         self._ov_device_s = 0.0       # summed issue -> sync-end windows
         self._ov_t0 = None            # first issue (efficiency wall start)
         self._ov_t1 = None            # last sync end (efficiency wall end)
-        self._synthetic_sync_s = 0.0  # bench knob: padded device window
         self._gap_hist = reg.histogram(
             "picotron_dispatch_gap_seconds",
             "issue-to-issue scheduling gap net of device time")
@@ -826,7 +825,7 @@ class ContinuousBatcher:
         with self._scratch_mu:
             d["last_host_sync_s"] = self._host_sync_s
             d["last_prefill"] = dict(self._last_prefill)
-        # the overlap A/B payload (bench_decode --overlap, obs-smoke):
+        # what /statz shows of the scheduling gap (obs-smoke reads it):
         # issue-to-issue gap and per-round host work percentiles from the
         # histograms' retained samples, plus the device-busy fraction
         ov = dict(enabled=self._overlap,
@@ -1867,7 +1866,7 @@ class ContinuousBatcher:
                 self.decode_dispatches += 1
                 self._phases.to("step/sync")
                 t_sync = self._clock()
-                out = self._sync_outputs(t0, res)
+                out = self._sync_outputs(res)
                 self._merge_hidden(res.hidden, out[1])
                 t1 = self._clock()
                 self._phases.to("step/deliver")
@@ -1931,30 +1930,17 @@ class ContinuousBatcher:
             gap = max(0.0, t0 - self._t_last_sync_end)
         self._gap_hist.observe(gap)
 
-    def _synthetic_wait(self, t_issue: float) -> None:
-        """Bench knob: pad the round's device window to at least
-        ``_synthetic_sync_s`` by sleeping the RESIDUAL at the sync point.
-        Models hideable device time on hosts whose model is too small to
-        produce any (chaos latency fires host-side at issue, so it can
-        never be overlapped; this can — bench_decode's --overlap A/B and
-        make overlap-smoke drive it). 0.0 (the default) is a no-op."""
-        if self._synthetic_sync_s > 0.0:
-            wait = t_issue + self._synthetic_sync_s - self._clock()
-            if wait > 0:
-                time.sleep(wait)
-
-    def _sync_outputs(self, t_issue: float, res) -> tuple:
+    def _sync_outputs(self, res) -> tuple:
         """A round's outputs as host arrays, (tokens, counts, accepted or
         None), in the two parts of ``step/sync``: ``sync/wait``, blocked
-        until the device has them (the launch and the program's run; the
-        bench's synthetic device window pads it), and ``sync/fetch``, the
-        read of ``res.packed``'s ONE copy to the host, which the engine
-        asked for at issue, so it left with the program's end. What a sync
-        site does after them (the learned drafter's hidden rows merged,
-        the deferred page-table advance) is the phase's own time."""
+        until the device has them (the launch and the program's run), and
+        ``sync/fetch``, the read of ``res.packed``'s ONE copy to the host,
+        which the engine asked for at issue, so it left with the program's
+        end. What a sync site does after them (the learned drafter's hidden
+        rows merged, the deferred page-table advance) is the phase's own
+        time."""
         with self.obs.part("sync/wait"):
             jax.block_until_ready(res.packed)
-            self._synthetic_wait(t_issue)
         with self.obs.part("sync/fetch"):
             self.engine.count_copies("d2h")
             return res.host()
@@ -2111,7 +2097,7 @@ class ContinuousBatcher:
             self.decode_dispatches += 1
             self._phases.to("step/sync")
             t_sync = self._clock()
-            outs = self._sync_outputs(t0, res)
+            outs = self._sync_outputs(res)
             # deferred page-table advance (engine.defer_advance): lands
             # here per successful dispatch, so isolation re-dispatches
             # compose exactly like the legacy per-dispatch advance
@@ -2172,8 +2158,7 @@ class ContinuousBatcher:
         self._phases.to("step/sync")
         t_sync = self._clock()
         try:
-            toks, counts, accepted = self._sync_outputs(rec["t0"],
-                                                        rec["res"])
+            toks, counts, accepted = self._sync_outputs(rec["res"])
         except Exception as e:  # noqa: BLE001 - device-side round failure
             _log_dispatch_failure("sync", "in-flight round", e)
             if not self._cache_ok():
@@ -2488,7 +2473,7 @@ class ContinuousBatcher:
             self.decode_dispatches += 1
             self._phases.to("step/sync")
             t_sync = self._clock()
-            out = self._sync_outputs(t0, res)
+            out = self._sync_outputs(res)
             self._merge_hidden(res.hidden, out[1])
             t1 = self._clock()
             self._phases.to("step/deliver")

@@ -1047,9 +1047,8 @@ class PagedKV:
             "prefix_cached_tokens": self.cached_tokens,
             "cow_copies": self.cow_copies,
             "radix_evictions": self.radix.evictions,
-            # hot_bf16 policy mix over LIVE pages (cold = read as int8);
-            # consumers that know the row byte widths (bench_decode's
-            # kv_bytes_per_token) weight their accounting with this
+            # hot_bf16 policy mix over LIVE pages (cold = read as int8):
+            # with the two row byte widths, the bytes a cache walk reads
             "kv_pages_quant": int(np.sum(self.pool.refs[1:] == 1)),
         }
 

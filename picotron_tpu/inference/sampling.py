@@ -151,12 +151,11 @@ def sample(logits: jnp.ndarray, key, temperature: jnp.ndarray,
 # The host-side (eager-call) entry for ``sample``. Called eagerly, the
 # ``lax.cond`` above traces and XLA-compiles a FRESH program on every
 # invocation — its branch closures are new objects each call, so nothing
-# caches and every admit-time first-token draw pays ~quarter-second of
-# compile (measured on the CPU backend; bench_decode --overlap surfaced
-# it as a fixed per-request cost swamping the pipeline A/B). Under jit
-# the cond traces once per argument shape and the executable is cached,
-# so admissions after the first are microseconds. Same computation,
-# same key discipline — jit only changes where the compile cache lives.
+# caches and every admit-time first-token draw pays a compile (a fixed
+# cost on every request). Under jit the cond traces once per argument
+# shape and the executable is cached, so admissions after the first are
+# microseconds. Same computation, same key discipline — jit only changes
+# where the compile cache lives.
 sample_jit = jax.jit(sample)
 
 

@@ -9,7 +9,7 @@ drafter proposes ``gamma`` tokens, one jitted verify dispatch
 positions per slot) scores them all, and the distribution-preserving
 acceptance rule (sampling.speculative_accept) keeps the matching prefix
 plus one fresh token. Every dispatch emits between 1 and gamma+1 tokens,
-so dispatches-per-token — the host-sync metric bench_decode.py tracks —
+so dispatches-per-token — the host syncs a token costs —
 drops below 1 whenever anything accepts, and the output distribution is
 untouched (bit-identical for greedy, distributionally identical for
 sampled; both test-pinned).

@@ -662,9 +662,8 @@ def layers_forward(stacked, h, cos, sin, cfg: Config):
       ~bytes/FLOP of the model: ≈ (12H + 6I) bytes per token-layer
       against 2(4H^2 + 3HI) FLOPs — a crossover around H ~ 14k at an
       assumed 16 GB/s link, inversely proportional to the measured
-      bandwidth (tools/measure_offload_bw) — docs/BENCH_7B.md has the
-      arithmetic. The mode exists for the big-model pod regime; the
-      single-chip bench ladder does not use it."""
+      bandwidth (tools/measure_offload_bw). The mode exists for the
+      big-model pod regime; no benchmark cell uses it."""
     valid = layer_valid_mask(stacked, cfg)
 
     if valid is None:

@@ -5,8 +5,8 @@ The remat="offload" mode (models/llama.py:layers_forward) parks the
 decoder layer's tagged residuals in pinned host memory instead of
 recomputing them — a win exactly when the host link sustains the model's
 bytes-per-FLOP: ≈ (12H + 6I) bytes per token-layer against
-2(4H^2 + 3HI) FLOPs (docs/BENCH_7B.md derives the crossover: H ~ 14k at
-an assumed ~16 GB/s PCIe, inversely proportional to the real bandwidth).
+2(4H^2 + 3HI) FLOPs (the sides meet at H ~ 14k under an assumed ~16 GB/s
+PCIe against 197 TF, inversely proportional to the real bandwidth).
 This tool replaces the assumption with measurements:
 
   1. d2h / h2d bandwidth — timed ``jax.device_put`` of a ~1 GB buffer

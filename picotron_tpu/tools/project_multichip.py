@@ -24,8 +24,8 @@ Conservatism policy (each choice biases MFU_proj DOWN):
   these sizes, so the ring is charged its first hop only).
 - PP p2p boundary activations are tiny but charged fully exposed.
 
-Anchors (single-chip, measured on the v5e, docs/BENCH_7B.md; re-anchor when
-any round's bench capture lands — still pending as of r05, see
+Anchors (single-chip, an earlier builder's v5e readings on older code that no
+ledger line bears out; re-anchor when a benchmark cell lands one, see
 docs/PROJECTION.md status note):
 - SmolLM-1.7B @ seq 2048: 55.3% MFU
 - Llama-2-7B-geometry proxy @ seq 4096: 66.5% MFU
@@ -51,7 +51,7 @@ BYTES_ACT = 2               # bf16 activations
 # this to 2 B in project() — bf16 accumulators are synced as bf16.
 BYTES_GRAD = 4
 
-# measured single-chip compute efficiency anchors (docs/BENCH_7B.md)
+# single-chip compute efficiency anchors (docs/PROJECTION.md status note)
 EFF_SMOLLM = 0.553
 EFF_7B = 0.665
 

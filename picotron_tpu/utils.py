@@ -93,25 +93,11 @@ def on_tpu() -> bool:
 
 
 def device_record() -> dict:
-    """The device a result was taken on, as JAX reports it — the keys every
-    bench record and ``chip_smoke.py``'s last line carry."""
+    """The device a result was taken on, as JAX reports it — the keys
+    ``chip_smoke.py``'s last line carries."""
     d = jax.devices()[0]
     return {"platform": d.platform, "kind": d.device_kind,
             "count": len(jax.devices())}
-
-
-def require_accelerator(what: str) -> str:
-    """The platform a measurement runs on: an accelerator, or the CPU only
-    when the caller pinned it (``JAX_PLATFORMS=cpu``, the explicit opt-in
-    of the tests and the ``make *-smoke`` targets). Finding no chip is a
-    failure where it is found, never a quiet CPU run."""
-    platform = jax.devices()[0].platform
-    pinned = os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip()
-    if platform == "cpu" and pinned.lower() != "cpu":
-        raise SystemExit(
-            f"{what}: JAX found no accelerator (platform 'cpu'); refusing "
-            f"to measure. Set JAX_PLATFORMS=cpu to ask for a CPU run.")
-    return platform
 
 
 def peak_flops_per_chip(device=None) -> float | None:

@@ -1,7 +1,6 @@
 """Guards around the chip path: ``chip_smoke.py``'s contract, the compile
-cache helper, the peaks table and the bench scripts' refusal to measure
-without a chip. Nothing here compiles or starts a process: the two slow
-end-to-end rehearsals live in tests/test_train_cli.py.
+cache helper and the peaks table. Nothing here compiles or starts a
+process: the two slow end-to-end rehearsals live in tests/test_train_cli.py.
 """
 
 import ast
@@ -278,52 +277,6 @@ def test_peak_flops_cpu_is_none():
 def test_peak_flops_unknown_accelerator_raises(platform, kind):
     with pytest.raises(ValueError, match="no peak"):
         utils.peak_flops_per_chip(_dev(platform, kind))
-
-
-# --------------------------------------------------------------------------- #
-# bench scripts: no chip, no measurement (unless JAX_PLATFORMS=cpu is given)
-# --------------------------------------------------------------------------- #
-
-
-def test_require_accelerator_honors_only_an_explicit_cpu_pin(monkeypatch):
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    with pytest.raises(SystemExit, match="refusing to measure"):
-        utils.require_accelerator("x")
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    assert utils.require_accelerator("x") == "cpu"
-
-
-@pytest.mark.parametrize("script,argv", [
-    ("bench", None), ("bench_7b", None), ("bench_decode", []),
-    ("bench_decode", ["--overlap", "ab"]), ("bench_decode", ["--mixed", "ab"]),
-    ("bench_decode", ["--dp", "2"]), ("bench_decode", ["--disagg"]),
-    ("bench_decode", ["--fleet"]),
-])
-def test_bench_scripts_refuse_to_measure_without_a_chip(monkeypatch, script,
-                                                        argv):
-    """The sandbox has no accelerator and the caller did not pin the CPU:
-    every bench entry point must stop before it measures anything."""
-    import importlib
-
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    mod = importlib.import_module(script)
-    for name in ("run", "run_overlap", "run_mixed", "run_dp", "run_disagg",
-                 "run_fleet", "run_descending"):
-        if hasattr(mod, name):
-            monkeypatch.setattr(mod, name,
-                                lambda *a, **k: pytest.fail("measured"))
-    with pytest.raises(SystemExit, match="refusing to measure"):
-        mod.main() if argv is None else mod.main(argv)
-
-
-def test_bench_has_no_child_process_and_no_stand_in_model():
-    import bench
-    import bench_7b
-
-    for mod in (bench, bench_7b):
-        src = inspect.getsource(mod)
-        assert "subprocess" not in src and "--inner" not in src
-        assert "hidden_size=256" not in src  # the old CPU stand-in model
 
 
 # --------------------------------------------------------------------------- #

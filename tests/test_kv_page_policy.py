@@ -13,7 +13,7 @@ tails) read as int8. This file pins the contract:
 - **hot pages really are hot**: under a shared prefix, the shared pages'
   flags read full-precision while exclusive tail pages read int8;
 - **allclose vs uniform** at int8-level tolerance with strictly fewer
-  accounted cache bytes per attend walk;
+  cache bytes read per attend walk over the live pages;
 - **validation** rejects the policy off the paged layout (and over a
   uniformly int8 cache) with the fix named, at both the config and the
   engine-kwarg layer.
@@ -72,20 +72,37 @@ def test_policy_dense_equals_flash(tiny_model_kwargs):
     assert dense == flash
 
 
+def _read_bytes_a_row(eng):
+    """What an attend walk reads for one live row of one layer, from the
+    leaves the engine keeps and the flags it ships: both K and V at the
+    cache's width where the page reads full precision, the int8 values
+    and their scales where it reads cold."""
+    leaves = jax.eval_shape(eng._init_cache_jit)  # shapes alone, no reset
+
+    def row(*names):  # leaves are [layers, pages, page_len, ...]
+        return sum(int(np.prod(leaves[n].shape[3:])) * leaves[n].dtype.itemsize
+                   for n in names)
+
+    full = row("k", "v")
+    if not eng.page_policy:
+        return float(full)
+    cold = row("k_q", "v_q", "k_scale", "v_scale")
+    assert cold < full  # the int8 row is the narrower one by construction
+    live = np.flatnonzero(eng.paged.pool.refs[1:] > 0) + 1
+    q = float(np.mean(eng.paged.quant_flags()[live]))
+    return q * cold + (1.0 - q) * full
+
+
 def test_policy_allclose_uniform_with_fewer_bytes(tiny_model_kwargs):
     """hot_bf16 generations stay within int8 tolerance of the uniform
     full-precision cache (here: token-identical on the tiny model), and
-    the accounted bytes per attend walk strictly shrink."""
-    from bench_decode import kv_bytes_per_token
-
+    the bytes an attend walk reads over the live pages strictly shrink."""
     uni, ue = _generate(tiny_model_kwargs, kv_page_policy="uniform",
                         attend_impl="flash")
     hot, he = _generate(tiny_model_kwargs, kv_page_policy="hot_bf16",
                         attend_impl="flash")
     assert uni == hot  # int8 tails don't move the tiny model's argmax
-    lengths = np.full(2, 32)
-    assert (kv_bytes_per_token(he, lengths)
-            < kv_bytes_per_token(ue, lengths))
+    assert _read_bytes_a_row(he) < _read_bytes_a_row(ue)
     stats = he.paged.stats()
     assert stats["kv_pages_quant"] >= 1  # cold tails exist and are int8
 

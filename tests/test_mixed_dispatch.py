@@ -24,9 +24,8 @@ it into the decode dispatch cannot move a single bit. Around it:
 - mixed_dispatch=False (default) leaves the serial path byte-identical
   — no lane state, no fused programs, lanes= rejected at the engine.
 
-`make mixed-smoke` (bench_decode --mixed ab) is the throughput half:
-decode TPOT p95 under concurrent long prefills <= 3x the no-prefill
-floor with TTFT p95 not regressing vs the serial+gate baseline.
+What the lane does to TPOT and TTFT has not been measured on the chip: no
+benchmark cell turns ``mixed_dispatch`` on.
 """
 
 from __future__ import annotations
@@ -106,8 +105,7 @@ def _lane_tokens(b):
 # The full matrix is the gate; ONE canonical leg stays un-marked as the
 # tier-1 core (the single-core tier-1 budget is tight — ~25s per leg)
 # and the rest ride the `slow` lane (same budget discipline as the
-# overlap and speculative matrices; `make test-all` and `make
-# mixed-smoke` run the full set).
+# overlap and speculative matrices; `make test-all` runs the full set).
 _slow = pytest.mark.slow
 @pytest.mark.parametrize(
     "program,layout,attend,quant,tp,dp,temp,overlap", [

@@ -879,7 +879,7 @@ def test_round_phases_tile_the_step(overlap, monkeypatch):
     costing(b, "_expire_deadlines", 1e-3)   # step/plan, before admit
     costing(b, "_admit", 2e-3)              # step/admit
     costing(engine, "decode_block", 3e-3)   # step/issue
-    costing(b, "_synthetic_wait", 5e-3)     # step/sync
+    costing(jax, "block_until_ready", 5e-3)  # step/sync: its one wait
     costing(b, "_tokens_done", 0.5e-3)      # step/deliver, once a slot
     #                          and round (and admit's first token, alone)
     for i in range(3):

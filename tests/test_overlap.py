@@ -19,8 +19,9 @@ dense/flash x contiguous/paged x int8 x tp x dp). Around it:
 - drain: `busy` covers the in-flight lookahead round, so a drain loop
   flushes it instead of stranding its tokens.
 
-`make overlap-smoke` (bench_decode --overlap ab) is the throughput half:
-gap p50 <= 0.5x serial and tokens/s >= 1.3x with host work ~= device.
+What the pipeline does to tokens/s has not been measured on the chip: no
+benchmark cell turns ``overlap`` on. What the dispatch gap counts in either
+mode is pinned below on a manual clock.
 """
 
 from __future__ import annotations
@@ -38,6 +39,7 @@ from picotron_tpu.inference import (
     Request,
 )
 from picotron_tpu.models import llama
+from picotron_tpu.obs import MetricsRegistry, Obs
 from picotron_tpu.resilience.chaos import ServingChaos
 
 MAX_LEN = 96
@@ -340,6 +342,36 @@ def test_drain_flushes_inflight_lookahead_round(tiny_model_kwargs):
     res = b.take_results()
     assert {u: len(r.tokens) for u, r in res.items()} == \
         {"a": 19, "b": 13, "c": 4}
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+def test_dispatch_gap_is_host_time_from_sync_end_to_issue(
+        tiny_model_kwargs, overlap, monkeypatch):
+    """What ``picotron_dispatch_gap_seconds`` counts, on a clock where only
+    the hand-over of a slot's tokens costs time (1 ms): the serial step
+    pays it between one round's sync end and the next issue, so every gap
+    is at least a millisecond; the pipelined step issues with a round in
+    flight, so every gap is 0.0."""
+    cfg, eng = _engine(tiny_model_kwargs, overlap)
+    eng.obs = Obs(enabled=True, registry=MetricsRegistry())
+    now = [100.0]
+    b = ContinuousBatcher(eng, _params(cfg, eng), seed=7,
+                          clock=lambda: now[0])
+    inner = b._tokens_done
+
+    def costing(*a, **kw):
+        now[0] += 1e-3
+        return inner(*a, **kw)
+
+    monkeypatch.setattr(b, "_tokens_done", costing)
+    b.run(_reqs("block"))
+    gaps = b._gap_hist.read()
+    assert gaps["count"] == b.decode_dispatches - 1 >= 3  # none at the first
+    p50 = b.stats()["overlap"]["dispatch_gap_s"]["p50"]
+    if overlap:
+        assert gaps["sum"] == 0.0 and p50 == 0.0
+    else:
+        assert gaps["sum"] >= gaps["count"] * 1e-3 and p50 >= 1e-3
 
 
 def test_stats_overlap_payload_and_threaded_scrape(tiny_model_kwargs):

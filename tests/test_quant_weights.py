@@ -166,8 +166,8 @@ def test_quantize_params_tree_and_bytes(tiny_model_kwargs):
                                   is_leaf=lambda x: not isinstance(x, dict)))
     # the quantized-leaf bytes come in at <= 55% of their bf16 form (the
     # tiny model's full-tree ratio is dominated by the deliberately
-    # full-precision embedding; at the 7B geometry — checked below via
-    # bench_7b's arithmetic — the whole tree lands at ~51%)
+    # full-precision embedding; at the 7B geometry — checked below over
+    # the trees' shapes — the whole tree lands at ~51%)
     def mat_bytes(tree):
         leaves = [tree["layers"][k] for k in llama.QUANT_WEIGHT_LEAVES]
         leaves.append(tree["lm_head"])
@@ -178,10 +178,18 @@ def test_quantize_params_tree_and_bytes(tiny_model_kwargs):
     assert ratio <= 0.55, ratio
     assert llama.param_bytes(qp) < llama.param_bytes(params)
 
-    from bench_7b import LLAMA2_7B_GEOM, weight_bytes
-
-    geom = dict(LLAMA2_7B_GEOM, num_hidden_layers=32)
-    assert weight_bytes(geom, "int8") <= 0.55 * weight_bytes(geom, "bf16")
+    # Llama-2-7B's geometry, shapes alone: the two trees as the program
+    # would build them
+    m7b = make_config(dict(
+        num_hidden_layers=32, num_attention_heads=32,
+        num_key_value_heads=32, hidden_size=4096, intermediate_size=11008,
+        vocab_size=32000, max_position_embeddings=4096, dtype="bfloat16",
+        attention_impl="sdpa")).model
+    key = jax.random.PRNGKey(0)
+    dense7b = jax.eval_shape(lambda k: llama.init_params(k, m7b), key)
+    int8_7b = jax.eval_shape(
+        lambda k: llama.quantize_params(llama.init_params(k, m7b)), key)
+    assert llama.param_bytes(int8_7b) <= 0.55 * llama.param_bytes(dense7b)
     # fake-quant round trip restores the dense structure and dtype
     fq = llama.dequantize_params(qp, jnp.bfloat16)
     assert jax.tree.structure(fq) == jax.tree.structure(params)

@@ -58,7 +58,7 @@ def _clocked(engine, params, monkeypatch, costs):
     b = ContinuousBatcher(engine, params, clock=clock)
     b._retry = dict(b._retry, backoff=0.0)
     for (who, name), seconds in costs.items():
-        obj = {"engine": engine, "batcher": b}[who]
+        obj = {"engine": engine, "batcher": b, "jax": jax}[who]
         inner = getattr(obj, name)
 
         def wrapped(*a, _inner=inner, _s=seconds, **kw):
@@ -73,7 +73,8 @@ COSTS = {("engine", "_hook"): 0.25e-3,            # step/issue's own time
          ("engine", "_round_operands"): 1e-3,     # issue/operands
          ("engine", "_dispatch"): 2e-3,           # issue/enqueue (prefill's
          #                                          enqueues: step/admit)
-         ("batcher", "_synthetic_wait"): 5e-3,    # sync/wait
+         ("jax", "block_until_ready"): 5e-3,      # sync/wait: the one
+         #                              wait of the inference package
          ("batcher", "_note_sync_end"): 0.5e-3}   # step/deliver
 
 

@@ -132,6 +132,28 @@ def test_dp2_greedy_matches_dp1(tiny_model_kwargs, program, attend,
     assert st["slots_total"] == 2 * b2.engine.slots_per_shard
 
 
+@pytest.mark.parametrize("program,dp_ops", [
+    ("block", set()), ("verify", set()),
+    ("chunked", {"prefill_owner_reduce"}),
+])
+def test_dp2_traces_no_dp_collective_but_the_owner_reduce(
+        tiny_model_kwargs, program, dp_ops, monkeypatch, capfd):
+    """Every program a dp=2 batcher traces over a whole paged run (the
+    rebalance migration included) is shard-local: the comm_trace channel
+    logs each collective once as it is traced, and the only line on the
+    dp axis is the chunked prefill's owner reduce, which the third case
+    shows the channel does see."""
+    monkeypatch.setenv("PICOTRON_VERBOSE", "1")
+    _, b = _run(tiny_model_kwargs, 2, program, kv_layout="paged",
+                kv_page_len=8)
+    assert b.decode_dispatches > 0
+    if program != "verify":  # the watermark tripped: a migration traced too
+        assert b.stats()["rebalance_count"] >= 1
+    lines = [ln for ln in capfd.readouterr().err.splitlines()
+             if ln.startswith("[comm]") and "axis=dp" in ln]
+    assert {ln.split()[1] for ln in lines} == dp_ops, lines
+
+
 def test_dp2_round_keys_replicated_and_sampled_pinned(tiny_model_kwargs):
     """The round schedule under dp=2: the key program's outputs lie
     replicated over the dp mesh (the round program takes keys as P()), and
@@ -389,9 +411,9 @@ def test_migration_dead_peer_exits_77_without_page_leak(
 
 
 def test_batcher_rebalance_fires_and_streams_stay_exact(tiny_model_kwargs):
-    """The end-to-end planner path ``make dp-smoke`` gates, pinned in
-    tier-1: long streams land on shard 0, shard 1's short streams retire,
-    the watermark trips, ONE slot migrates cross-shard mid-run — and
+    """The end-to-end planner path: long streams land on shard 0, shard
+    1's short streams retire, the watermark trips, ONE slot migrates
+    cross-shard mid-run — and
     every stream still equals the dp=1 baseline. The migration counters
     and per-shard occupancy gauges land in stats()/the registry."""
     base, _ = _run(tiny_model_kwargs, 1, "block", kv_layout="paged",

@@ -408,8 +408,7 @@ def test_measure_cond_gating_small(capsys):
 
 @pytest.mark.slow
 def test_measure_offload_bw_small(capsys):
-    """The offload-economics probe (remat='offload' bandwidth math,
-    docs/BENCH_7B.md) runs end-to-end on CPU and reports link bandwidth +
+    """The offload-economics probe (remat='offload' bandwidth math) runs end-to-end on CPU and reports link bandwidth +
     both step timings; the decisive PCIe numbers need the chip."""
     from picotron_tpu.tools import measure_offload_bw as mob
 
