@@ -1,0 +1,22 @@
+"""Token-expert assignments that landed on an expert held here, per program
+step (a decode step, or a prefill bucket or chunk), layer and held expert:
+delta ``picotron_moe_assignments_total`` / delta
+``picotron_moe_layer_steps_total`` between the window's two scrapes, over
+``num_experts``. A decode step of 8 live slots with 8 experts a token and 16
+of 128 held gives 8 x 8 / 128 = 0.5, where the deployment's eight chips'
+slots would give 4. A router that drops tokens, or stops sending any here,
+moves it. A program without the block's counter
+(``picotron_dsa_rows_attended_total``) reads as nothing."""
+
+from benchmarks import phases
+
+
+def read(run):
+    if "metrics_after" not in run:
+        return None
+    layer_steps = phases.delta(run, "picotron_moe_layer_steps_total")
+    if layer_steps <= 0 \
+            or phases.delta(run, "picotron_dsa_rows_attended_total") <= 0:
+        return None
+    return (phases.delta(run, "picotron_moe_assignments_total") / layer_steps
+            / run["config"]["num_experts"])

@@ -169,8 +169,11 @@ class ModelConfig:
     # serving path only) or "mimo_v2" (full and sliding attention by
     # ``hybrid_layer_pattern`` with K/V heads counted by kind, keys wider
     # than values, a learned sink in the sliding softmax, a SwiGLU or routed
-    # experts behind it — models/mimo_v2.py, serving path only; its fields
-    # are the last). The fields below are the
+    # experts behind it — models/mimo_v2.py, serving path only) or "KeyeVL2"
+    # (Keye-VL-2.0's language model: GQA with q/k norm a head, M-RoPE, a
+    # learned top-k selection whose chosen K/V rows a decode step gathers,
+    # softmax-routed experts in every layer — models/keye_vl2.py, serving
+    # path only; its fields are the last). The fields below are the
     # published ``config.json`` keys of the DeepSeek block, by their own
     # names, and are read by no other block (but ``num_experts_per_tok``,
     # ``ep_size`` and ``ep_rank``, which both expert blocks read).
@@ -298,6 +301,22 @@ class ModelConfig:
     add_swa_attention_sink_bias: bool = False
     add_full_attention_sink_bias: bool = False
     layernorm_epsilon: float = 0.0
+    # "KeyeVL2" (Keye-VL-2.0's language model): the published keys of that
+    # block, beside ``head_dim``, ``num_experts`` (the experts HELD here of a
+    # router ``num_experts * ep_size`` wide), ``num_local_experts`` (as
+    # published it repeats the router's width: 0, or ``num_experts *
+    # ep_size``), ``num_experts_per_tok``, ``moe_intermediate_size``,
+    # ``norm_topk_prob``, ``rope_scaling`` (type "default" with
+    # ``mrope_section``, the pairs of a head each position stream rotates),
+    # ``ep_size``/``ep_rank``, ``first_layer``/``total_layers``.
+    # ``sa_config`` is the sparse attention's group (``indexer_num_heads``,
+    # ``indexer_head_dim``, ``indexer_num_kv_heads``, ``topk``; its
+    # ``q_chunk_size``/``kv_chunk_size`` are read by no forward);
+    # ``decoder_sparse_step`` 1 and ``mlp_only_layers`` [] say that every
+    # layer's MLP is the routed experts.
+    sa_config: Optional[dict] = None
+    decoder_sparse_step: int = 1
+    mlp_only_layers: Optional[list] = None
     # the published ``head_dim`` where it is not hidden_size / heads (afmoe:
     # 128 of 3072 / 48); 0: derived, and it follows ``hidden_size``
     head_dim: int = 0
@@ -1162,10 +1181,11 @@ class Config:
                     f"dp_size ({d.dp_size}) — every layer param shards on an "
                     f"H-sized axis")
         if m.model_type not in ("llama", "deepseek_v32", "granitemoehybrid",
-                                "minicpm_sala", "afmoe", "mimo_v2"):
+                                "minicpm_sala", "afmoe", "mimo_v2",
+                                "KeyeVL2"):
             raise ValueError(
                 f"unknown model_type {m.model_type!r} (llama|deepseek_v32|"
-                "granitemoehybrid|minicpm_sala|afmoe|mimo_v2)")
+                "granitemoehybrid|minicpm_sala|afmoe|mimo_v2|KeyeVL2)")
         if m.model_type == "deepseek_v32":
             self._validate_deepseek_v32(for_training)
         if m.model_type == "granitemoehybrid":
@@ -1176,6 +1196,8 @@ class Config:
             self._validate_afmoe(for_training)
         if m.model_type == "mimo_v2":
             self._validate_mimo_v2(for_training)
+        if m.model_type == "KeyeVL2":
+            self._validate_keye_vl2(for_training)
         if m.attention_impl not in ("auto", "sdpa", "flash"):
             raise ValueError(
                 f"unknown attention_impl {m.attention_impl!r} (auto|sdpa|flash)")
@@ -2021,6 +2043,119 @@ class Config:
                 raise ValueError(
                     f"{who} implements model.{name} = {want!r} only (got "
                     f"{getattr(m, name)!r})")
+
+    def _validate_keye_vl2(self, for_training: bool) -> None:
+        """What ``models/keye_vl2.py`` needs of its keys, and what it cannot
+        do yet, each refused by name."""
+        d, m, inf = self.distributed, self.model, self.inference
+        who = "model_type 'KeyeVL2'"
+        refused = (
+            (for_training, "is served, not trained: training is not "
+             "implemented for this block (no backward through the top-k "
+             "selection, whose indexer would need a training loss of its "
+             "own, nor through the expert share; train_step builds the "
+             "Llama block only)"),
+            (d.tp_size > 1, f"does not support tp_size > 1 (got "
+             f"{d.tp_size}): the block holds no tp collectives and the "
+             "indexer's one key head cannot be sharded; its share of a "
+             "layer is ep_size/ep_rank"),
+            (inf.dp_size > 1, f"does not support inference.dp_size > 1 (got "
+             f"{inf.dp_size}): the indexer's keys have no slot axis over "
+             "'dp'"),
+            (inf.kv_layout == "paged", "does not support inference.kv_layout "
+             "'paged': paged_kv.py pages K/V heads, not the indexer's keys, "
+             "and its attends do not gather chosen rows; set kv_layout: "
+             "'contiguous'"),
+            (inf.kv_cache_dtype == "int8", "does not support "
+             "inference.kv_cache_dtype 'int8': K, V and the indexer's keys "
+             "are stored in the model's dtype"),
+            (inf.weight_dtype == "int8", "does not support "
+             "inference.weight_dtype 'int8': its matmuls take dense weights "
+             "only"),
+            (bool(inf.tenancy.tenants or inf.tenancy.manifest), "does not "
+             "support LoRA adapters (inference.tenancy): the adapter pack "
+             "is shaped for the Llama block's seven projections"),
+            (inf.spec_len > 0, f"does not support speculation "
+             f"(inference.spec_len {inf.spec_len}): a verify block's "
+             "queries would each gather rows of their own, and there is no "
+             "such program"),
+            (inf.attend_impl == "flash", f"does not support "
+             f"inference.attend_impl {inf.attend_impl!r}: the flash-decode "
+             "kernels read a prefix, not chosen rows"),
+            (inf.overlap, "does not support inference.overlap: the "
+             "lookahead dispatch is not implemented for this block"),
+            (inf.mixed_dispatch, "does not support inference.mixed_dispatch:"
+             " the fused prefill lane embeds and heads through the Llama "
+             "block"),
+            (inf.key_schedule == "slot", "does not support "
+             "inference.key_schedule 'slot': it serves through the "
+             "round-keyed programs only"),
+        )
+        for bad, why in refused:
+            if bad:
+                raise ValueError(f"{who} {why}")
+        for name in ("num_experts", "num_experts_per_tok",
+                     "moe_intermediate_size", "ep_size"):
+            if getattr(m, name) < 1:
+                raise ValueError(f"{who} needs model.{name} >= 1")
+        sa = m.sa_config or {}
+        need = ("indexer_num_heads", "indexer_head_dim", "topk")
+        if any(int(sa.get(n, 0)) < 1 for n in need) \
+                or int(sa.get("indexer_num_kv_heads", 1)) != 1:
+            raise ValueError(
+                f"{who} needs model.sa_config with {', '.join(need)} each "
+                f">= 1 and indexer_num_kv_heads 1 (got {m.sa_config!r})")
+        if m.head_dim % 2 or int(sa["indexer_head_dim"]) % 2:
+            raise ValueError(
+                f"{who}: head_dim {m.head_dim} and sa_config.indexer_head_dim"
+                f" {sa['indexer_head_dim']} must be even (RoPE rotates "
+                "halves)")
+        rs = m.rope_scaling or {}
+        section = rs.get("mrope_section")
+        if rs.get("rope_type", rs.get("type", "default")) != "default" \
+                or not isinstance(section, list) or len(section) != 3 \
+                or sum(section) != m.head_dim // 2:
+            raise ValueError(
+                f"{who} needs model.rope_scaling of type 'default' with an "
+                f"mrope_section of three counts that sum to head_dim / 2 = "
+                f"{m.head_dim // 2} (got {m.rope_scaling!r})")
+        if m.num_attention_heads % m.num_key_value_heads:
+            raise ValueError(
+                f"{who}: num_attention_heads {m.num_attention_heads} must "
+                f"be a multiple of num_key_value_heads "
+                f"{m.num_key_value_heads}")
+        if not 0 <= m.ep_rank < m.ep_size:
+            raise ValueError(
+                f"{who}: ep_rank {m.ep_rank} outside [0, ep_size "
+                f"{m.ep_size})")
+        width = m.num_experts * m.ep_size
+        if m.num_experts_per_tok > width:
+            raise ValueError(
+                f"{who}: num_experts_per_tok {m.num_experts_per_tok} passes "
+                f"the router's width {width} (num_experts x ep_size)")
+        if m.num_local_experts not in (0, width):
+            raise ValueError(
+                f"{who}: num_local_experts {m.num_local_experts} is not the "
+                f"router's width {width} (num_experts x ep_size), which it "
+                "repeats as published")
+        if m.total_layers and m.first_layer + m.num_hidden_layers \
+                > m.total_layers:
+            raise ValueError(
+                f"{who}: layers first_layer {m.first_layer} .. + "
+                f"{m.num_hidden_layers} lie outside total_layers "
+                f"{m.total_layers}")
+        for name, want in (("norm_topk_prob", True),
+                           ("decoder_sparse_step", 1),
+                           ("tie_word_embeddings", False)):
+            if getattr(m, name) != want:
+                raise ValueError(
+                    f"{who} implements model.{name} = {want!r} only (got "
+                    f"{getattr(m, name)!r})")
+        if m.mlp_only_layers:
+            raise ValueError(
+                f"{who} implements model.mlp_only_layers = [] only (got "
+                f"{m.mlp_only_layers!r}): every layer's MLP is the routed "
+                "experts")
 
     # ---- JSON round-trip (reference: train.py:62-63 consumes one JSON file) ----
 

@@ -28,7 +28,8 @@ whatever the block:
   keys and a float32 state side by side for ``minicpm_sala``; full-length
   K/V and rings of a window's rows side by side for ``afmoe``; the same
   with keys wider than values and K/V heads counted by kind, four leaves of
-  four shapes, for ``mimo_v2``);
+  four shapes, for ``mimo_v2``; a token's K and V heads in one row and an
+  indexer's keys, two to a row, for ``keye_vl2``);
 - ``RING_CACHE`` (absent: false): some leaves are rings a prefill chunk's
   writes must fit; ``init_cache`` then also takes ``prefill_chunk``;
 - ``CARRIES_STATE`` (absent: false): the cache holds a state with no token
@@ -109,6 +110,10 @@ def model_module(m):
         from picotron_tpu.models import mimo_v2
 
         return mimo_v2
+    if m.model_type == "KeyeVL2":
+        from picotron_tpu.models import keye_vl2
+
+        return keye_vl2
     if m.model_type == "llama":
         return llama
     raise ValueError(f"unknown model_type {m.model_type!r}")

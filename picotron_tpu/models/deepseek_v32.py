@@ -66,7 +66,15 @@ from picotron_tpu.models.llama import (  # noqa: F401 - the seam's shared parts
 )
 from picotron_tpu.ops.attention import NEG_INF
 from picotron_tpu.ops.rmsnorm import rms_norm
-from picotron_tpu.ops.select import select_keys
+from picotron_tpu.ops.select import (
+    KEY_BLOCK,  # keys a block of the indexer's and the attend's walks
+    index_scores,
+    key_block as _key_block,
+    key_blocks as _key_blocks,
+    layer_norm as _layer_norm,
+    live_blocks as _live_blocks,
+    select_keys,
+)
 from picotron_tpu.ops.rope import (
     apply_rope,
     apply_rope_interleaved,
@@ -78,12 +86,9 @@ from picotron_tpu.ops.rope import (
 STAT_NAMES = expert_share.STAT_NAMES + (
     "dsa_keys_selected", "dsa_keys_scored")
 
-# keys scored by the indexer, and queries attended, at a time: bounds the
-# [B, S, index heads, keys] products and the [B, S, selected, rank] rows
-KEY_BLOCK = 2048
+# queries attended at a time: bounds the [B, S, heads, keys] scores of a key
+# block (``ops/select.py::KEY_BLOCK`` keys, the indexer's blocks)
 QUERY_BLOCK = 128
-
-LAYER_NORM_EPS = 1e-6  # the indexer's LayerNorm
 
 
 # --------------------------------------------------------------------------- #
@@ -254,54 +259,6 @@ def init_cache(m: ModelConfig, slots: int, max_seq_len: int, dtype=None,
 # --------------------------------------------------------------------------- #
 
 
-def _key_block(src: dict, name: str, layer, t0, n: int):
-    """Keys ``t0 .. t0 + n`` of stacked leaf ``name`` at ``layer``, read
-    where they lie: [B, n, width] ([1, n, width] of the one slot a ``slot``
-    entry names)."""
-    leaf = src[name]
-    B = 1 if "slot" in src else leaf.shape[1]
-    at = (jnp.asarray(layer, jnp.int32),
-          jnp.asarray(src.get("slot", 0), jnp.int32),
-          jnp.asarray(t0, jnp.int32), jnp.zeros((), jnp.int32))
-    return lax.dynamic_slice(leaf, at, (1, B, n, leaf.shape[3]))[0]
-
-
-def _key_blocks(T: int) -> int:
-    """Keys handled at a time in a window of ``T``: ``KEY_BLOCK``, or what
-    of it divides the window."""
-    return T if T <= KEY_BLOCK else math.gcd(T, KEY_BLOCK)
-
-
-def _live_blocks(pos_q, T: int, Tb: int):
-    """How many leading blocks of ``Tb`` keys some query at ``pos_q`` can
-    see: the rest of the window is never read."""
-    return jnp.minimum(jnp.max(pos_q), T - 1) // Tb + 1
-
-
-def index_scores(qi, wi, src: dict, layer, pos_q):
-    """The indexer's scores [B, S, T] float32 of every key of the window for
-    queries at ``pos_q`` [B, S]: ``sum_h w[h] * ReLU(q^I[h] . k^I[s])`` for
-    ``s <= pos_q``, -inf past it. Keys are scored ``KEY_BLOCK`` at a time,
-    and only the blocks a query can see are read."""
-    B, S = pos_q.shape
-    T = src["ki"].shape[2]
-    Tb = _key_blocks(T)
-
-    def body(j, buf):
-        kb = _key_block(src, "ki", layer, j * Tb, Tb)  # [B, Tb, D]
-        s = jnp.einsum("bshd,btd->bsht", qi, kb,
-                       preferred_element_type=jnp.float32)
-        s = jnp.sum(jax.nn.relu(s) * wi[..., None], axis=2)  # [B, S, Tb]
-        t = j * Tb + jnp.arange(Tb, dtype=jnp.int32)
-        s = jnp.where(t[None, None, :] <= pos_q[..., None], s, -jnp.inf)
-        return lax.dynamic_update_slice(buf, s, (0, 0, j * Tb))
-
-    buf = jnp.full((B, S, T), -jnp.inf, jnp.float32)
-    if T == Tb:
-        return body(0, buf)
-    return lax.fori_loop(0, _live_blocks(pos_q, T, Tb), body, buf)
-
-
 def _attend_selected(q, chosen, src: dict, layer, scale: float, rank: int,
                      pos_q):
     """Softmax attention of each query ``q`` [B, S, heads, row width] (its
@@ -311,7 +268,7 @@ def _attend_selected(q, chosen, src: dict, layer, scale: float, rank: int,
     kept running over them (max, sum, weighted rows)."""
     B, S, nh, _ = q.shape
     T = src["ckv"].shape[2]
-    Tb = _key_blocks(T)
+    Tb = _key_blocks(T, KEY_BLOCK)
 
     def body(j, carry):
         m, l, acc = carry
@@ -337,14 +294,6 @@ def _attend_selected(q, chosen, src: dict, layer, scale: float, rank: int,
     else:
         _, l, acc = lax.fori_loop(0, _live_blocks(pos_q, T, Tb), body, carry)
     return acc / l[..., None]
-
-
-def _layer_norm(x, w, b):
-    x32 = x.astype(jnp.float32)
-    mu = jnp.mean(x32, axis=-1, keepdims=True)
-    var = jnp.mean((x32 - mu) ** 2, axis=-1, keepdims=True)
-    return ((x32 - mu) * lax.rsqrt(var + LAYER_NORM_EPS)).astype(x.dtype) \
-        * w + b
 
 
 def attention(lp, x, cos, sin, m: ModelConfig, cache, pos, layer, live):
@@ -403,7 +352,7 @@ def attention(lp, x, cos, sin, m: ModelConfig, cache, pos, layer, live):
             src[n] = kv_cache.write_rows(cache, n, r, pos, layer)
 
     with jax.named_scope("dsa_index"):
-        scores = index_scores(qi, wi, src, layer, pos_q)
+        scores = index_scores(qi, wi, src, layer, pos_q, KEY_BLOCK)
     with jax.named_scope("dsa_select"):
         chosen = select_keys(scores, m.index_topk)
     with jax.named_scope("mla_attend"):

@@ -2,9 +2,10 @@
 held here, their part of the routed sum (every held expert over every row,
 as a loop or, where ``takes_pipelined``, as one pipelined pass; from
 ``takes_grouped`` rows up each expert over its own rows only), and what of
-it is counted. The expert blocks (``deepseek_v32``, ``granite_hybrid``, ``afmoe``, ``mimo_v2``)
-route over the router's whole width (the sigmoid router of three of them is
-``route``) and hand the choice here; the tree's leaves are named alike in
+it is counted. The expert blocks (``deepseek_v32``, ``granite_hybrid``, ``afmoe``, ``mimo_v2``,
+``keye_vl2``) route over the router's whole width (``route`` is the router
+of four of them: sigmoid scores and a correction bias for three, softmax
+scores and no bias for ``keye_vl2``) and hand the choice here; the tree's leaves are named alike in
 all: ``w1``/``w3``/``w2`` ``[held, ...]`` a layer (the group's ``UNSLICED``
 stacks), ``ws_gate``/``ws_up``/``ws_down`` the shared expert, where the
 block has one."""
@@ -37,8 +38,9 @@ GROUP_ROWS = 512
 
 def route(scores, bias, *, k: int, n_group: int = 1, topk_group: int = 1,
           scale: float = 1.0, eps: float = 0.0) -> tuple:
-    """(experts [N, k] int32, weights [N, k] float32) from the sigmoid
-    scores [N, width]: the choice is made on ``scores + bias`` (with
+    """(experts [N, k] int32, weights [N, k] float32) from the router's
+    scores [N, width], a sigmoid's or a softmax's (``keye_vl2``: with a zero
+    ``bias``): the choice is made on ``scores + bias`` (with
     ``n_group`` > 1: groups by the sum of their two best, the best
     ``topk_group`` groups, the best experts among them; ties to the lower
     index), the weights are the unbiased scores of the chosen, normalised to
@@ -242,7 +244,7 @@ def share(lp, x2, w_held) -> tuple:
     [N, H], the layer step's counts in the order of ``STAT_NAMES``) for
     tokens ``x2`` [N, H] weighted ``w_held`` [N, held] (rows that are not
     live: all 0). A layer whose leaves hold no ``ws_gate`` has no shared
-    expert (``mimo_v2``)."""
+    expert (``mimo_v2``, ``keye_vl2``)."""
     with jax.named_scope("moe_experts"):
         y, run, pipelined = routed_experts(x2, w_held, lp)
     if "ws_gate" in lp:

@@ -77,6 +77,44 @@ def rope_at_positions(cos: jnp.ndarray, sin: jnp.ndarray,
 
 
 # --------------------------------------------------------------------------- #
+# M-RoPE (models/keye_vl2.py): three position streams, a section of pairs each
+# --------------------------------------------------------------------------- #
+
+
+def mrope_rows(cos: jnp.ndarray, sin: jnp.ndarray, section) -> tuple:
+    """M-RoPE's angle rows [B, S, D] from each position stream's own rows
+    ``cos``/``sin`` [3, B, S, D] (temporal, height, width; the plain table's
+    rows at that stream's positions, halves tiled): pair ``i`` of a head's
+    ``D / 2`` takes the stream that owns its section, ``section`` the pairs
+    each stream owns in that order (Qwen2-VL's ``mrope_section``, which sums
+    to ``D / 2``). With equal streams the rows are the plain table's, bit
+    for bit."""
+    half = cos.shape[-1] // 2
+    if sum(section) != half or len(section) != 3:
+        raise ValueError(f"mrope_section {list(section)} must give the "
+                         f"{half} pairs of a head to three streams")
+    owner = np.tile(np.repeat(np.arange(3), section), 2)  # a column's stream
+
+    def pick(rows):
+        return jnp.where(owner == 0, rows[0],
+                         jnp.where(owner == 1, rows[1], rows[2]))
+
+    return pick(cos), pick(sin)
+
+
+def mrope_at_positions(cos: jnp.ndarray, sin: jnp.ndarray, pos: jnp.ndarray,
+                       section) -> tuple:
+    """M-RoPE's angle rows [B, S, D] for ``pos`` [3, B, S] (a token's
+    temporal, height and width position; a text token's three are equal)
+    out of the plain tables ``cos``/``sin`` [T, D]: each stream's rows
+    (``rope_at_positions``), then ``mrope_rows``."""
+    n, B, S = pos.shape
+    c, s = rope_at_positions(cos, sin, pos.reshape(n * B, S))
+    return mrope_rows(c.reshape(n, B, S, -1), s.reshape(n, B, S, -1),
+                      section)
+
+
+# --------------------------------------------------------------------------- #
 # YaRN (models/deepseek_v32.py): a frequency blend and the softmax's mscale
 # --------------------------------------------------------------------------- #
 

@@ -818,3 +818,41 @@ def test_mimo_cache_leaves_are_never_copied_whole(prog, topo, one_chip,
         assert "flash_decode_ring_sink" in text \
             and "flash_decode_attention" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+# ---- the two leaves of the Keye-VL-2.0 block (PR 46) -------------------------
+
+
+@pytest.mark.parametrize("prog", ["decode_block", "prefill_chunk"])
+def test_keye_cache_leaves_are_never_copied_whole(prog, topo, one_chip,
+                                                  experts_on_chip):
+    """A token's K and V heads in one 2 KB row and the indexer's keys two to
+    a row: each leaf stays row-major as it is resident and no instruction
+    copies one (a chunk's masked walk holds the blocks it reads to the rows'
+    own order: left free, the compiler re-laid the K/V rows a head's keys
+    side by side, a copy of the whole leaf, and the chunk did not fit); the
+    decode block gathers the chosen rows (ONE gather of 16,384 rows a
+    layer) and runs the sixteen held experts as the pipelined pass, the
+    chunk as the grouped kernel; the programs' temporaries leave the
+    12.75 GB resident room on the chip."""
+    compiled = _cell_program(topo, prog, "keye-vl-2.0-ep8-l12")
+    text = compiled.as_text()
+    _assert_expert_orders(text, prog, pipelined=True)
+    lines = text.splitlines()
+    leaves = {"kv": (r"bf16\[12,8,49152,8,128\]", "{4,3,2,1,0"),
+              "ki": (r"bf16\[12,8,24576,128\]", "{3,2,1,0")}
+    shapes = "|".join(shape for shape, _ in leaves.values())
+    copies = [l.strip()[:160] for l in lines
+              if re.search(rf"= (?:{shapes})\S* copy\(", l)]
+    assert not copies, "\n".join(copies)
+    for name, (shape, order) in leaves.items():
+        params = [l for l in lines
+                  if re.search(rf"cache__{name}__\S* = {shape}", l)
+                  and " parameter(" in l]
+        assert len(params) == 1 and order in params[0], (name, params)
+    gathers = [l for l in lines
+               if re.search(r"= bf16\[8,2048,8,128\]\S* gather\(", l)]
+    assert (len(gathers) == 1) == (prog == "decode_block"), gathers
+    mem = compiled.memory_analysis()
+    assert round(mem.argument_size_in_bytes / 1e9, 2) == 12.75
+    assert mem.temp_size_in_bytes < 0.8e9  # 0.23 a block, 0.61 a chunk
