@@ -139,10 +139,14 @@ class ModelConfig:
     # ops/pallas/flash_attention.py). Tuning knobs for other chips/shapes.
     flash_block_q: Optional[int] = None
     flash_block_k: Optional[int] = None
-    # Kernel data layout: "folded" reshapes [B,S,H,D] -> [B*H,S,D] around
-    # every kernel call (battle-tested default); "bshd" runs the kernels on
-    # the model layout directly, skipping the host-side transpose copies
-    # (opt-in until A/B'd on hardware; interpret-mode-verified identical).
+    # Kernel data layout: "folded" (default) reshapes [B,S,H,D] ->
+    # [B*H,S,D] around a kernel call, a relayout copy of every operand; at
+    # heads of 64 the training layer stack takes the default's paired form
+    # instead, two heads to a 128-lane row of [B,S,H*D] and no copy
+    # (llama.flash_heads_per_row says when; a prefill and context
+    # parallelism keep the fold). "merged" is that idea for head_dim % 128
+    # == 0. "bshd" squeezes the head out of [B,S,H,D] blocks: interpret
+    # mode only, Mosaic refuses it on a chip (ops/pallas/flash_attention.py).
     flash_layout: str = "folded"
     use_pallas_rmsnorm: Optional[bool] = None  # None = auto (TPU only)
     # gather logits over tp before the loss (reference tensor_parallel.py:48-50

@@ -448,7 +448,36 @@ def embed_lookup(w, tokens, sp: bool = False, cfg=None):
     return sp_scatter(e) if sp else tp_reduce(e)
 
 
-def _attention(q, k, v, cfg: Config, cache=None, pos=None, layer=None):
+def _attention_impl(cfg: Config) -> str:
+    """``model.attention_impl`` with "auto" resolved: the flash kernels on a
+    TPU, ``sdpa`` elsewhere."""
+    impl = cfg.model.attention_impl
+    if impl == "auto":
+        impl = "flash" if on_tpu() else "sdpa"
+    return impl
+
+
+def flash_heads_per_row(cfg: Config) -> int:
+    """Heads the training layer stack's flash kernels take to a 128-lane
+    row: 2 where heads of 64 go through them two by two, as the projections
+    and RoPE leave them (the ``paired`` form of
+    ops/pallas/flash_attention.py: no relayout copy around a call), 1 where
+    a call folds its operands to a head a row. Of the configuration alone:
+    half-lane heads, an even number of them on a tp rank, no context
+    parallelism, ``flash_layout`` left at its default, the flash kernels in
+    use. A prefill keeps a head a row whatever this says (``_attention``):
+    it builds one program a bucket and runs each a handful of times."""
+    from picotron_tpu.ops.pallas.flash_attention import LANE
+
+    m, d = cfg.model, cfg.distributed
+    paired = (_attention_impl(cfg) == "flash" and 2 * m.head_dim == LANE
+              and (m.num_attention_heads // d.tp_size) % 2 == 0
+              and d.cp_size == 1 and m.flash_layout == "folded")
+    return 2 if paired else 1
+
+
+def _attention(q, k, v, cfg: Config, cache=None, pos=None, layer=None,
+               paired: bool = False):
     """Full-sequence attention (training / prefill), or — when ``cache`` is
     given — the incremental decode path: ``cache`` is the UPDATED stacked
     cache dict (``{"k","v"[, "k_scale","v_scale"]}``, each
@@ -457,7 +486,8 @@ def _attention(q, k, v, cfg: Config, cache=None, pos=None, layer=None):
     first index just written per sequence; the ``k``/``v`` positional args
     are ignored. ``kv_cache.attend`` reads the layer where it lies: a
     masked dot product over its block, or on a TPU the flash-decode kernel
-    over the stacked leaf for the plain decode step.
+    over the stacked leaf for the plain decode step. ``paired``: the
+    training stack's call where ``flash_heads_per_row`` says 2.
     """
     scale = 1.0 / math.sqrt(cfg.model.head_dim)
     if cache is not None:
@@ -474,9 +504,7 @@ def _attention(q, k, v, cfg: Config, cache=None, pos=None, layer=None):
         # program under jit.
         return attend(q, cache, pos + q.shape[1], scale,
                       impl=cfg.inference.attend_impl, layer=layer)
-    impl = cfg.model.attention_impl
-    if impl == "auto":
-        impl = "flash" if on_tpu() else "sdpa"
+    impl = _attention_impl(cfg)
     if cfg.distributed.cp_size > 1:
         if cfg.distributed.cp_impl == "ulysses":
             # all-to-all seq<->head reshard around one full-sequence kernel
@@ -499,7 +527,8 @@ def _attention(q, k, v, cfg: Config, cache=None, pos=None, layer=None):
         return flash_attention(q, k, v, scale, causal=True,
                                block_q=cfg.model.flash_block_q,
                                block_k=cfg.model.flash_block_k,
-                               layout=cfg.model.flash_layout)
+                               layout="paired" if paired
+                               else cfg.model.flash_layout)
     return sdpa(q, k, v, scale, causal=True)
 
 
@@ -569,11 +598,25 @@ def decoder_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
     # tagged residual in pinned host memory — layers_forward docstring)
     x = _ckpt_name(enter(_norm(h, lp["attn_norm"], cfg)), "attn_in")
     B, S, _ = x.shape
-    q = matmul(x, lp["wq"]).reshape(B, S, nh, D)
-    k = matmul(x, lp["wk"]).reshape(B, S, nkv, D)
+    # the training stack's call at heads of 64: its flash kernels take the
+    # rows as the projections write them, two heads to 128 lanes, so RoPE
+    # stays in rows too. A prefill (one program a bucket, each run a handful
+    # of times) and every cache path keep a head a row, and the program they
+    # lowered to before.
+    paired = (cache is None and not return_kv and nkv == nh
+              and flash_heads_per_row(cfg) == 2)
+    if paired:
+        from picotron_tpu.ops.pallas.rope import rope_rows
+
+        q, k = (r.reshape(B, S, nh, D) for r in rope_rows(
+            matmul(x, lp["wq"]), matmul(x, lp["wk"]), cos, sin))
+    else:
+        q = matmul(x, lp["wq"]).reshape(B, S, nh, D)
+        k = matmul(x, lp["wk"]).reshape(B, S, nkv, D)
     v = _ckpt_name(matmul(x, lp["wv"]).reshape(B, S, nkv, D), "v_proj")
-    q = _ckpt_name(apply_rope(q, cos, sin), "q_rope")
-    k = _ckpt_name(apply_rope(k, cos, sin), "k_rope")
+    if not paired:
+        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    q, k = _ckpt_name(q, "q_rope"), _ckpt_name(k, "k_rope")
 
     new_cache = None
     if cache is not None:
@@ -599,7 +642,7 @@ def decoder_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
         if nkv != nh and not compact_cp:
             k = jnp.repeat(k, nh // nkv, axis=2)
             v = jnp.repeat(v, nh // nkv, axis=2)
-        o = _attention(q, k, v, cfg)
+        o = _attention(q, k, v, cfg, paired=paired)
     o = o.reshape(B, S, nh * D)
     h = h + leave(matmul(o, lp["wo"]))
 

@@ -6,11 +6,13 @@ Same asymptotics as FlashAttention-2: O(S) memory (never materializes the
 [S, S] score matrix in HBM), online softmax in fp32, log-sum-exp saved for
 the backward, which re-derives P per block.
 
-Three data layouts share the same kernel bodies (``model.flash_layout``):
+Three data layouts, and the paired form of the default at heads of 64, share
+the same kernel bodies (``model.flash_layout``):
 
 - "folded" (default, battle-tested): the model's [B, S, H, D] is folded to
   [B*H, S, D] around the pallas_call; the grid walks (batch*head, q-block).
-  The fold is a host-side transpose+reshape copy of every operand per call.
+  The fold is a transpose+reshape copy of every operand per call, in HBM,
+  outside the kernels.
 - "bshd" (interpret-mode only — REJECTED on hardware): the kernels consume
   [B, S, H, D] directly — grid (batch, head, q-block), the head dimension
   squeezed out by a size-None BlockSpec entry — avoiding the fold's
@@ -26,11 +28,36 @@ Three data layouts share the same kernel bodies (``model.flash_layout``):
   dims merge — and the head grid axis selects a D-wide LANE-aligned slice
   of the last dim, which Mosaic accepts. Same zero-transpose-copy win the
   bshd experiment wanted, within the tiling rules.
+- "paired" (heads of 64, an even number of them; not a ``flash_layout``
+  value: the training layer stack takes it where ``model.flash_layout`` is
+  the default, ``llama.flash_heads_per_row``): merged's idea below 128
+  lanes. A block is the 128 lanes of heads 2p and 2p+1; the grid walks
+  (batch, head pair, tile, head of the pair), the pair's two programs one
+  after the other on the same blocks. A program zeroes the other head's
+  lanes of ONE operand of each contraction over D (q forward; k and v
+  backward), so the matmul over 128 lanes is its own head's, at the MXU
+  depth a head of 64 padded to the array had; what comes out 128 lanes wide
+  (p @ v, dV, dK) is right in its own lanes and is merged into the pair's
+  block; dQ gathers both heads in one [S, 128] float32 scratch (the masked k
+  adds zeros to the other head's lanes). Same tiles, mask, scale fold and
+  five-matmul backward, bit for bit the folded result. The forward's lse
+  leaves compact, [B, H, S] rows along lanes (1/128 of the folded layout's).
+
+What a call costs around the kernels at [3, 2048, 32, 64] (v5e, PERF.md
+PR 48): folded, twelve relayout copies of a q-sized array a layer (q, k, v
+in, the output back, again for the ``remat: full`` recompute, dO in, dQ, dK,
+dV back: 0.07-0.14 ms each), the lse's [96, 2048, 128] float32 written
+twice and sliced once (0.22 ms), RoPE through the [.., H, D] view
+(sequence-minor, 0.39 ms), delta 0.05; 1.64 ms a layer in all. Paired: none
+of the copies, a [3, 32, 2048] lse, RoPE by ``ops/pallas/rope.py`` on the rows
+as the projections wrote them (0.40 ms), a forward kernel 0.06 ms slower a
+call: the cell's step is 1.2 ms a layer shorter.
 
 K/V for one head live whole in VMEM (S*D*2B ~ 1 MB at S=8192, D=64)
 while scores exist only as a [block_q, block_k] VMEM tile. The forward's
 per-row LSE is materialized with a broadcast 128-lane minor dim
-([BH, S, 128] / [B, S, H, 128]) — Mosaic requires the last two block dims be
+([BH, S, 128] / [B, S, H, 128]; the paired form's is compact) — Mosaic
+requires the last two block dims be
 (8k, 128m), so a lane-less layout can't be tiled per-q-block (the in-tree TPU
 flash kernel uses the same trick); the residual and the backward's operands
 are compact. GQA repetition happens in the model before the call (as the
@@ -147,16 +174,35 @@ def _dot_tn(a, b):
 # --------------------------------------------------------------------------- #
 
 
+PAIR = 2  # heads of LANE / 2 to a lane row in the paired form
+
+
+def _own_lanes(blk_axis):
+    """(this program's head of a paired row, [1, LANE] mask of its lanes):
+    the grid axis after the tile axis runs the pair's heads."""
+    sub = pl.program_id(blk_axis + 1)
+    half = lax.broadcasted_iota(jnp.int32, (1, LANE), 1) // (LANE // PAIR)
+    return sub, half == sub
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_q,
-                block_k, causal, blk_axis=1):
+                block_k, causal, blk_axis=1, paired=False):
     # Matmul inputs stay in their native dtype (bf16 in training) with fp32
     # accumulation via preferred_element_type — fp32 MXU issue rate is 1/8
     # of bf16 on TPU, so casting q/k/v up would throttle the whole kernel.
     # Softmax state (m, l, acc) is fp32. blk_axis: which grid axis walks the
     # q-blocks (1 = folded (BH, nq) grid, 2 = bshd (B, H, nq) grid).
+    # paired: the tiles are 128 lanes of two heads of 64 and this program
+    # runs one of them. Its q loses the other head's lanes, so q @ k.T
+    # contracts over its own (the MXU pass is as deep as a head of 64
+    # padded to the array was); p @ v comes out right in its own lanes, and
+    # only those are stored.
     qi = pl.program_id(blk_axis)
     fold = _scale_folds(scale)
     q = q_ref[0]  # [bq, D]
+    if paired:
+        sub, own = _own_lanes(blk_axis)
+        q = jnp.where(own, q, jnp.zeros_like(q))
     if fold:
         q = q * scale  # exact; once a query tile, not once a score
     nk = k_ref.shape[1] // block_k
@@ -197,14 +243,31 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_q,
     l0 = jnp.zeros((bq, LANE if cols else 1), jnp.float32)
     acc, m, l = lax.fori_loop(0, nk, body, (acc0, m0, l0))
     l = jnp.sum(l, axis=1, keepdims=True)
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), (bq, LANE))
+    out = (acc / l).astype(o_ref.dtype)
+    lse = jnp.broadcast_to(m + jnp.log(l), (bq, LANE))
+    if not paired:
+        o_ref[0] = out
+        lse_ref[0] = lse
+        return
+    # the pair's two programs share the out block: each its own lanes
+    o_ref[0] = jnp.where(own, out, o_ref[0])
+    # The lse leaves compact, query rows along lanes (what the backward
+    # reads): at 128 lanes a head a token it is four times q's bytes, written
+    # by every call and read back through a slice. Row r of a stretch of 128
+    # moves to lane r: a select on the diagonal and a sum down the sublanes.
+    g = min(bq, LANE)
+    diag = (lax.broadcasted_iota(jnp.int32, (g, LANE), 0)
+            == lax.broadcasted_iota(jnp.int32, (g, LANE), 1))
+    rows = jnp.where(diag, lse.reshape(bq // g, g, LANE), 0.0)
+    lse_ref[0, sub, qi] = jnp.sum(rows, axis=1)[:, :g]
 
 
 def _fwd(q, k, v, scale, causal, block_q, block_k, layout="folded"):
     """folded: q [BH,Sq,D] -> (out [BH,Sq,D], lse [BH,Sq,LANE]).
-    bshd/merged: q [B,Sq,H,D] -> (out [B,Sq,H,D], lse [B,Sq,H,LANE]).
-    LSE is the broadcast-lane fp32 layout. Sq and Sk may differ
+    bshd/merged: q [B,Sq,H,D] -> (out [B,Sq,H,D], lse [B,Sq,H,LANE]);
+    paired: the same with lse [B,Sq,H,1].
+    LSE is the broadcast-lane fp32 layout (paired: compact). Sq and Sk may
+    differ
     (ring-attention half blocks); causal requires Sq == Sk (aligned
     positions)."""
     sq, sk = q.shape[1], k.shape[1]
@@ -213,24 +276,28 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, layout="folded"):
     bq = _pick_block(sq, block_q)
     bk = _pick_block(sk, block_k)
     post = lambda out, lse: (out, lse)
-    if layout == "merged":
+    if layout in ("merged", "paired"):
         # [B, S, H, D] viewed as [B, S, H*D] (free: minor dims merge), the
-        # head index a grid axis selecting a D-wide lane slice — needs
-        # D % 128 == 0 to satisfy Mosaic's lane tiling, and in exchange the
-        # kernels consume the model layout with ZERO transpose copies.
+        # head index a grid axis selecting a LANE-aligned slice: D wide
+        # where D % 128 == 0 (merged), and at D 64 the 128 lanes of heads
+        # 2p and 2p+1 (paired), which a last grid axis of 2 runs one after
+        # the other on the same blocks. The kernels consume the model
+        # layout with ZERO transpose copies.
         b, h = q.shape[0], q.shape[2]
+        per = PAIR if layout == "paired" else 1  # heads a block
+        w = d * per
         q, k, v = (x.reshape(x.shape[0], x.shape[1], h * d)
                    for x in (q, k, v))
-        grid = (b, h, sq // bq)
+        grid = (b, h // per, sq // bq) + ((per,) if per > 1 else ())
         blk_axis = 2
         in_specs = [
-            pl.BlockSpec((1, bq, d), lambda b_, hh, i: (b_, i, hh)),
-            pl.BlockSpec((1, sk, d), lambda b_, hh, i: (b_, 0, hh)),
-            pl.BlockSpec((1, sk, d), lambda b_, hh, i: (b_, 0, hh)),
+            pl.BlockSpec((1, bq, w), lambda b_, hh, i, *_: (b_, i, hh)),
+            pl.BlockSpec((1, sk, w), lambda b_, hh, i, *_: (b_, 0, hh)),
+            pl.BlockSpec((1, sk, w), lambda b_, hh, i, *_: (b_, 0, hh)),
         ]
         out_specs = [
-            pl.BlockSpec((1, bq, d), lambda b_, hh, i: (b_, i, hh)),
-            pl.BlockSpec((1, bq, LANE), lambda b_, hh, i: (b_, i, hh)),
+            pl.BlockSpec((1, bq, w), lambda b_, hh, i, *_: (b_, i, hh)),
+            pl.BlockSpec((1, bq, LANE), lambda b_, hh, i, *_: (b_, i, hh)),
         ]
         out_shape = [
             jax.ShapeDtypeStruct((b, sq, h * d), q.dtype),
@@ -238,6 +305,17 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, layout="folded"):
         ]
         post = lambda out, lse: (out.reshape(b, sq, h, d),
                                  lse.reshape(b, sq, h, LANE))
+        if per > 1:
+            # the lse compact, [B, H, nq, bq / 128, 128]: a pair's block
+            # stays in VMEM over its query tiles, each program writes its own
+            g = min(bq, LANE)
+            tile = (sq // bq, bq // g, g)
+            out_specs[1] = pl.BlockSpec(
+                (1, per) + tile, lambda b_, hh, *_: (b_, hh, 0, 0, 0))
+            out_shape[1] = jax.ShapeDtypeStruct((b, h) + tile, jnp.float32)
+            post = lambda out, lse: (
+                out.reshape(b, sq, h, d),
+                lse.reshape(b, h, sq).transpose(0, 2, 1)[..., None])
     elif layout == "folded":
         bh = q.shape[0]
         grid = (bh, sq // bq)
@@ -274,7 +352,8 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, layout="folded"):
         ]
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, block_q=bq, block_k=bk,
-                          causal=causal, blk_axis=blk_axis),
+                          causal=causal, blk_axis=blk_axis,
+                          paired=layout == "paired"),
         grid=grid, in_specs=in_specs, out_specs=out_specs,
         name="flash_fwd",
         out_shape=out_shape,
@@ -305,17 +384,27 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, layout="folded"):
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dq_ref, dk_ref, dv_ref, dq_acc, *, scale, block_q, block_k,
-                causal, blk_axis=1):
+                causal, blk_axis=1, paired=False):
     kj = pl.program_id(blk_axis)
     fold = _scale_folds(scale)
     k = k_ref[0]  # [bk, D]
     v = v_ref[0]
+    first, last = kj == 0, kj == pl.num_programs(blk_axis) - 1
+    if paired:
+        # this program's head of the pair (``_fwd_kernel``): k and v lose
+        # the other head's lanes, so k @ q.T and v @ dO.T contract over its
+        # own and dst.T @ k adds zeros to the other head's lanes of dq_acc;
+        # dK and dV come out right in its own lanes, which are stored.
+        sub, own = _own_lanes(blk_axis)
+        k = jnp.where(own, k, jnp.zeros_like(k))
+        v = jnp.where(own, v, jnp.zeros_like(v))
+        first, last = first & (sub == 0), last & (sub == PAIR - 1)
     ks = k * scale if fold else k  # exact; once a key tile
     nq = q_ref.shape[1] // block_q
     # first q block that can see this k block
     i0 = (kj * block_k) // block_q if causal else 0
 
-    @pl.when(kj == 0)
+    @pl.when(first)
     def _():
         dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
 
@@ -340,10 +429,15 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     z = jnp.zeros(k.shape, jnp.float32)
     dk, dv = lax.fori_loop(i0, nq, body, (z, z))
-    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    dk = (dk * scale).astype(dk_ref.dtype)
+    dv = dv.astype(dv_ref.dtype)
+    if paired:
+        dk = jnp.where(own, dk, dk_ref[0])
+        dv = jnp.where(own, dv, dv_ref[0])
+    dk_ref[0] = dk
+    dv_ref[0] = dv
 
-    @pl.when(kj == pl.num_programs(blk_axis) - 1)
+    @pl.when(last)
     def _():
         dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
@@ -363,6 +457,7 @@ def _bwd(scale, causal, block_q, block_k, layout, res, dout):
                     axis=-1)
     # q_spec: a head's whole q / dO / dQ; k_spec: one key tile of k / v /
     # dK / dV. Operand order is layout-independent; only these vary.
+    per = PAIR if layout == "paired" else 1  # heads a block
     if layout == "folded":
         bh = q.shape[0]
         grid, blk_axis = (bh, sk // bk), 1
@@ -373,16 +468,24 @@ def _bwd(scale, causal, block_q, block_k, layout, res, dout):
         shape = lambda s: (bh, s, d)
     else:
         b, h = q.shape[0], q.shape[2]
-        grid, blk_axis = (b, h, sk // bk), 2
+        grid, blk_axis = (b, h // per, sk // bk), 2
         rows = lambda x: x.transpose(0, 2, 1).reshape(b, h, nq, bq)
         row_spec = pl.BlockSpec((1, None, nq, bq),
                                 lambda b_, hh, j: (b_, hh, 0, 0))
-        if layout == "merged":
+        if layout in ("merged", "paired"):
+            w = d * per
             q, dout = (x.reshape(b, sq, h * d) for x in (q, dout))
             k, v = (x.reshape(b, sk, h * d) for x in (k, v))
-            q_spec = pl.BlockSpec((1, sq, d), lambda b_, hh, j: (b_, 0, hh))
-            k_spec = pl.BlockSpec((1, bk, d), lambda b_, hh, j: (b_, j, hh))
+            q_spec = pl.BlockSpec((1, sq, w),
+                                  lambda b_, hh, j, *_: (b_, 0, hh))
+            k_spec = pl.BlockSpec((1, bk, w),
+                                  lambda b_, hh, j, *_: (b_, j, hh))
             shape = lambda s: (b, s, h * d)
+            if per > 1:  # the pair's heads one after the other, innermost
+                grid += (per,)
+                row_spec = pl.BlockSpec(
+                    (1, None, nq, bq),
+                    lambda b_, hh, j, sub: (b_, hh * per + sub, 0, 0))
         else:
             q_spec = pl.BlockSpec((1, sq, None, d),
                                   lambda b_, hh, j: (b_, 0, hh, 0))
@@ -392,7 +495,8 @@ def _bwd(scale, causal, block_q, block_k, layout, res, dout):
 
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, scale=scale, block_q=bq, block_k=bk,
-                          causal=causal, blk_axis=blk_axis),
+                          causal=causal, blk_axis=blk_axis,
+                          paired=layout == "paired"),
         grid=grid,
         in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
         out_specs=[q_spec, k_spec, k_spec],
@@ -400,13 +504,15 @@ def _bwd(scale, causal, block_q, block_k, layout, res, dout):
         out_shape=[jax.ShapeDtypeStruct(shape(sq), q.dtype),
                    jax.ShapeDtypeStruct(shape(sk), k.dtype),
                    jax.ShapeDtypeStruct(shape(sk), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((sq, d), jnp.float32)],
-        # dq_acc gathers over a head's key tiles: that axis runs in order,
-        # innermost, whatever a later compiler does with the others
+        scratch_shapes=[pltpu.VMEM((sq, d * per), jnp.float32)],
+        # dq_acc gathers over a head's key tiles (a pair's, with its two
+        # heads): those axes run in order, innermost, whatever a later
+        # compiler does with the others
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",) * blk_axis + ("arbitrary",)),
+            dimension_semantics=("parallel",) * blk_axis
+            + ("arbitrary",) * (len(grid) - blk_axis)),
     )(q, k, v, dout, rows(lse_c), rows(delta))
-    if layout == "merged":  # back to the [B, S, H, D] primal shape (free)
+    if layout in ("merged", "paired"):  # back to [B, S, H, D] (free)
         dq = dq.reshape(b, sq, h, d)
         dk = dk.reshape(b, sk, h, d)
         dv = dv.reshape(b, sk, h, d)
@@ -418,14 +524,19 @@ def _bwd(scale, causal, block_q, block_k, layout, res, dout):
 # --------------------------------------------------------------------------- #
 
 
-def _check_layout(layout: str, d: int | None = None) -> None:
-    if layout not in ("folded", "bshd", "merged"):
+def _check_layout(layout: str, d: int | None = None,
+                  h: int | None = None) -> None:
+    if layout not in ("folded", "bshd", "merged", "paired"):
         raise ValueError(
-            f"unknown flash layout {layout!r} (folded|bshd|merged)")
+            f"unknown flash layout {layout!r} (folded|bshd|merged|paired)")
     if layout == "merged" and d is not None and d % LANE:
         raise ValueError(
             f"flash layout 'merged' needs head_dim % {LANE} == 0 (the head "
             f"slice must be a whole lane tile); got head_dim={d}")
+    if layout == "paired" and (2 * d != LANE or h % 2):
+        raise ValueError(
+            f"flash layout 'paired' needs heads of {LANE // 2}, an even "
+            f"number of them (two to a lane row); got {h} of {d}")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -457,12 +568,12 @@ def flash_attention(q, k, v, scale: float | None = None, causal: bool = True,
     the model layout directly with no fold copies; "folded" is the
     always-available default."""
     b, s, h, d = q.shape
-    _check_layout(layout, d)
+    _check_layout(layout, d, h)
     block_q = block_q or DEFAULT_BLOCK_Q
     block_k = block_k or DEFAULT_BLOCK_K
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    if layout in ("bshd", "merged"):
+    if layout != "folded":
         return _flash_core(q, k, v, float(scale), causal, block_q, block_k,
                            layout)
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
@@ -483,10 +594,10 @@ def flash_block_grads(q, k, v, out, lse, dout, scale: float,
     k/v are [B, Sk, H, D] (Sq != Sk allowed for ring half-blocks, non-causal
     only); lse is [B, Sq, H] fp32. Returns (dq, dk, dv)."""
     b, sq, h, d = q.shape
-    _check_layout(layout, d)
+    _check_layout(layout, d, h)
     block_q = block_q or DEFAULT_BLOCK_Q
     block_k = block_k or DEFAULT_BLOCK_K
-    if layout in ("bshd", "merged"):
+    if layout != "folded":
         return _bwd(scale, causal, block_q, block_k, layout,
                     (q, k, v, out, lse), dout)
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
@@ -507,12 +618,12 @@ def flash_attention_with_lse(q, k, v, scale: float | None = None,
     building block for ring attention's LSE merge. Sq != Sk allowed
     (non-causal only)."""
     b, s, h, d = q.shape
-    _check_layout(layout, d)
+    _check_layout(layout, d, h)
     block_q = block_q or DEFAULT_BLOCK_Q
     block_k = block_k or DEFAULT_BLOCK_K
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    if layout in ("bshd", "merged"):
+    if layout != "folded":
         out, lse = _fwd(q, k, v, float(scale), causal, block_q, block_k,
                         layout)
         return out, lse[..., 0]

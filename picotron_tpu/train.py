@@ -201,6 +201,12 @@ def train(cfg, max_steps_override: Optional[int] = None,
     adoptions_ctr = obs.registry.counter(
         "picotron_consensus_adoptions_total",
         "peer preemption verdicts adopted via consensus")
+    # what the layer stack's attention is, of the configuration alone (the
+    # training side of the engine's picotron_kv_pack_factor)
+    obs.registry.gauge(
+        "picotron_flash_heads_per_row",
+        "heads the training flash kernels take to one 128-lane row").set(
+            llama.flash_heads_per_row(cfg))
 
     # state the finally below may touch — defined before anything can raise
     manager = None

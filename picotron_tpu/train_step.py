@@ -22,6 +22,7 @@ are psum'd over 'pp' — only the owning stage produces nonzero contributions.
 
 from __future__ import annotations
 
+import sys
 from functools import partial
 from typing import Any
 
@@ -45,7 +46,11 @@ from picotron_tpu.parallel.tp import (
     reduce_scatter_dim,
 )
 from picotron_tpu.topology import Topology, batch_pspec, named_shardings
-from picotron_tpu.utils import shard_map as shard_map_compat, typeof_vma
+from picotron_tpu.utils import (
+    log0,
+    shard_map as shard_map_compat,
+    typeof_vma,
+)
 
 
 def lr_schedule(t):
@@ -302,6 +307,8 @@ def build_train_step(cfg: Config, topo: Topology, multi_step: int = 1,
     fault-injection suite to drive the non-finite gate below; never enabled
     in production programs."""
     _llama_only(cfg)
+    log0(f"[train_step] flash heads a lane row: "
+         f"{llama.flash_heads_per_row(cfg)}", file=sys.stderr)
     mesh = topo.mesh
     pp = cfg.distributed.pp_size
     engine = cfg.distributed.pp_engine

@@ -103,13 +103,18 @@ def _flash_bwd():
 CELL_SHAPES = {
     "train2k": ((3, 2048, 32, 64), dict(tiles=10, scale_folded=True)),
     "pp2tp2": ((1, 4096, 16, 128), dict(tiles=36, scale_folded=False)),
+    # what train-2k's layer stack calls since PR 48: heads of 64 two to a
+    # 128-lane block (Mosaic accepts the head-pair block), the same walk
+    "train2k_paired": ((3, 2048, 32, 64), dict(tiles=10, scale_folded=True)),
 }
+CELL_LAYOUT = {"train2k_paired": "paired"}  # the others fold
 
 
 def _flash_cell(cell, backward):
     shape, _ = CELL_SHAPES[cell]
     q = (shape, BF16)
-    attend = lambda q, k, v: flash_attention(q, k, v, shape[-1] ** -0.5)
+    attend = lambda q, k, v: flash_attention(
+        q, k, v, shape[-1] ** -0.5, layout=CELL_LAYOUT.get(cell, "folded"))
     if not backward:
         return attend, [q, q, q]
     return jax.grad(lambda q, k, v: _sum32(attend(q, k, v)),
@@ -430,7 +435,7 @@ GEOMETRY = {"smollm": (HID, HEADS, HEADS, FFN, VOCAB),
             "mistral": (4096, 32, 8, 14336, 32768)}
 
 
-def _serving_program(topo, prog, layout, geometry="smollm"):
+def _serving_program(topo, prog, layout, geometry="smollm", bucket=None):
     """(lowered-and-compiled ``prog`` of a SERVE_LAYERS-layer engine at
     one of ``GEOMETRY``'s head geometries on one described chip, the
     abstract cache it was compiled for). Layout "kernel" is the contiguous
@@ -469,6 +474,12 @@ def _serving_program(topo, prog, layout, geometry="smollm"):
     rep = named_shardings(mesh, jax.sharding.PartitionSpec())
     arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep)
     B = SERVE_SLOTS
+    if prog == "prefill":  # the one-shot prefill of one power-of-two bucket
+        args = (arg((1, bucket), I32), arg((1,), I32))
+        if eng.sample_on_device:
+            args += (arg((2,), jnp.uint32), arg((1,), F32), arg((1,), I32),
+                     arg((1,), F32))
+        return eng._prefill_jit.lower(params, *args).compile(), cache
     if prog == "decode_block":
         jitted = eng._program("decode_block")
         args = (arg((6, B), I32), arg((eng.decode_block_len, 2), jnp.uint32))
@@ -540,6 +551,103 @@ def test_serving_program_leaves_cache_in_place(prog, layout, geometry, topo,
               and " parameter(" in l]
     assert len(params) == 2, params
     assert all("{4,3,2,1,0" in l for l in params), params
+
+
+# --------------------------------------------------------------------------- #
+# train-2k's layer: q, k, v and their gradients are never copied whole
+# --------------------------------------------------------------------------- #
+
+_QKV_SIZED = re.compile(
+    r"= bf16\[(?:3,2048,32,64|3,32,2048,64|96,2048,64|3,2048,2048)\]\S* "
+    r"(copy|transpose)\((?!%gather|%reshape)")
+
+
+def _train_layer_text(topo, monkeypatch, heads_per_row=None):
+    """Compiled text of the train-2k cell's own layer, forward and gradient
+    under ``remat: full`` (micro-batch 3 of SmolLM-1.7B at 2048), on one
+    described chip; ``heads_per_row`` overrides the rule's answer."""
+    from jax.sharding import PartitionSpec as P
+
+    from picotron_tpu.config import Config
+    from picotron_tpu.models import llama
+    from picotron_tpu.topology import build_topology, named_shardings
+    from picotron_tpu.utils import shard_map
+
+    monkeypatch.setattr(llama, "on_tpu", lambda: True)
+    if heads_per_row is not None:
+        monkeypatch.setattr(llama, "flash_heads_per_row",
+                            lambda cfg: heads_per_row)
+    hid, heads, kv_heads, ffn, vocab = GEOMETRY["smollm"]
+    cfg = Config.from_dict({
+        "model": dict(hidden_size=hid, intermediate_size=ffn,
+                      num_attention_heads=heads, num_key_value_heads=kv_heads,
+                      vocab_size=vocab, num_hidden_layers=1,
+                      max_position_embeddings=SEQ, dtype="bfloat16"),
+        "training": {"seq_length": SEQ, "micro_batch_size": 3,
+                     "remat": "full"},
+        "dataset": {"name": "synthetic"}})
+    mesh = build_topology(1, 1, 1, 1, devices=topo.devices)
+    specs = llama.param_pspecs(cfg.model)["layers"]
+    cos, sin = llama.rope_tables(cfg)
+
+    def loss(params, h):
+        return _sum32(llama.layers_forward(params, h, cos, sin, cfg))
+
+    def abstract(tree, specs):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            tree, named_shardings(mesh, specs))
+
+    params = abstract(jax.eval_shape(lambda: llama.init_params(
+        jax.random.key(0), cfg.model))["layers"], specs)
+    h = abstract(jax.ShapeDtypeStruct((3, SEQ, hid), BF16), P())
+    fn = shard_map(jax.grad(loss, argnums=(0, 1)), mesh.mesh,
+                   in_specs=(specs, P()), out_specs=(specs, P()))
+    return jax.jit(fn).lower(params, h).compile().as_text()
+
+
+def test_train_layer_never_copies_qkv(topo, one_chip, monkeypatch):
+    """ISSUE 48: at heads of 64 the layer's flash kernels take q, k, v and
+    dO as rows [3, 2048, 32 * 64], where the projections and ``rope_rows``
+    leave them, and hand back the output and the gradients the same way: no
+    ``copy`` or ``transpose`` of a q-sized array in the compiled layer,
+    where the folded call's fold and unfold are a dozen of them. The
+    kernels keep their names and their count: the forward (here the
+    ``remat: full`` recompute alone: a gradient needs no first pass) and
+    ONE backward, ``rope_rows`` in front of the one and behind the other."""
+    text = _train_layer_text(topo, monkeypatch)
+    moved = [l.strip()[:140] for l in text.splitlines()
+             if _QKV_SIZED.search(l)]
+    assert not moved, "\n".join(moved)
+    calls = re.findall(r"%(\w+?)(?:\.\d+)? = [^=]*custom-call\([^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert [calls.count(k) for k in
+            ("flash_fwd", "flash_bwd_dkv", "rope_rows")] == [1, 1, 2], calls
+    assert "bf16[96,2048,64]" not in text
+
+
+def test_folded_train_layer_copies_qkv(topo, one_chip, monkeypatch):
+    """The same layer a head a row (what it compiled to before PR 48, and
+    what heads of 128 still do): the copies the test above would see."""
+    text = _train_layer_text(topo, monkeypatch, heads_per_row=1)
+    moved = [l for l in text.splitlines() if _QKV_SIZED.search(l)]
+    assert len(moved) >= 8, "\n".join(l.strip()[:140] for l in moved)
+    assert "rope_rows" not in text and "bf16[96,2048,64]" in text
+
+
+def test_smollm_prefill_keeps_the_folded_call(topo, one_chip, monkeypatch):
+    """The serving engine's one-shot prefill (``return_kv=True``) at bucket
+    256 of SmolLM still holds ``flash_fwd`` over [32, 256, 64] and no
+    ``rope_rows``: its programs are the parent's, and so is the serving
+    cell's set-up (five such programs a process; PERF.md, PR 47)."""
+    from picotron_tpu.models import llama
+
+    monkeypatch.setattr(llama, "on_tpu", lambda: True)
+    text = _serving_program(topo, "prefill", "contiguous",
+                            bucket=256)[0].as_text()
+    kernel = [l for l in text.splitlines() if re.search(r"%flash_fwd\S* = ", l)]
+    assert len(kernel) == 1 and "bf16[32,256,64]" in kernel[0], kernel
+    assert "rope_rows" not in text
 
 
 @pytest.fixture

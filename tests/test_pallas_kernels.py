@@ -42,8 +42,9 @@ def _qkv(b=2, s=256, h=2, d=64, seed=0):
     return tuple(jax.random.normal(k, shape, jnp.float32) for k in ks)
 
 
-# merged requires head_dim % 128 == 0, so its cases run at d=128
-LAYOUT_D = [("folded", 64), ("bshd", 64), ("merged", 128)]
+# merged requires head_dim % 128 == 0, so its cases run at d=128; paired (the
+# training stack's form of the default at heads of 64) takes two heads of 64
+LAYOUT_D = [("folded", 64), ("bshd", 64), ("merged", 128), ("paired", 64)]
 
 
 @pytest.mark.parametrize("layout,d", LAYOUT_D)
@@ -113,7 +114,8 @@ def _tiles_walked(seq, bq, bk):
 # training dtype: against the float32 oracle on the same rounded inputs,
 # relative to its largest entry).
 TILINGS = [(128, 128), (256, 128), (128, 256)]
-TILED_LAYOUT_D = [("folded", 64), ("folded", 128), ("merged", 128)]
+TILED_LAYOUT_D = [("folded", 64), ("folded", 128), ("merged", 128),
+                  ("paired", 64)]
 
 
 def _close(got, want, dtype, f32_tol, name=""):
@@ -186,6 +188,49 @@ def test_flash_rect_block_matches_einsum(sq, sk, layout, d):
     _close(lse, want_lse, jnp.float32, 2e-5, "lse")
     for g, w, name in zip(grads, want, "qkv"):
         _close(g, w, jnp.float32, 5e-5, f"d{name}")
+
+
+@pytest.mark.parametrize("b,s,h", [(1, 256, 2), (2, 512, 4), (3, 1024, 6)])
+def test_flash_paired_matches_folded(b, s, h):
+    """Heads of 64 two to a lane row against a head a row, same inputs,
+    causal, the kernels' own tiles: forward, lse, dQ, dK, dV."""
+    q, k, v = _qkv(b=b, s=s, h=h, d=64, seed=b)
+    do = jax.random.normal(jax.random.PRNGKey(9), q.shape)
+    got = {}
+    with pltpu.force_tpu_interpret_mode():
+        for layout in ("folded", "paired"):
+            out, lse = flash_attention_with_lse(q, k, v, 0.125, layout=layout)
+            grads = jax.vjp(lambda q, k, v: flash_attention(
+                q, k, v, 0.125, layout=layout), q, k, v)[1](do)
+            got[layout] = (out, lse, *grads)
+    for a, w, name in zip(got["paired"], got["folded"],
+                          ("out", "lse", "dq", "dk", "dv")):
+        _close(a, w, jnp.float32, 5e-5 if name[0] == "d" else 2e-5, name)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_rope_rows_matches_apply_rope(dtype):
+    """RoPE on [B, S, H * D] rows (q and k in one call) against
+    ``apply_rope`` through the [.., H, D] view: values and gradients."""
+    from picotron_tpu.ops.pallas.rope import rope_rows
+    from picotron_tpu.ops.rope import apply_rope, precompute_rope
+
+    B, S, H, D = 2, 64, 6, 64  # 384 lanes a row: three blocks of 128
+    cos, sin = precompute_rope(S, D, 10000.0, dtype)
+    q, k, _ = (x.astype(dtype) for x in _qkv(b=B, s=S, h=H, d=D, seed=4))
+    flat = lambda x: x.reshape(B, S, H * D)
+    mix = lambda a, b: jnp.sum(jnp.sin(a.astype(jnp.float32))
+                               + jnp.cos(b.astype(jnp.float32)))
+    with pltpu.force_tpu_interpret_mode():
+        got = rope_rows(flat(q), flat(k), cos, sin)
+        g_got = jax.grad(lambda q, k: mix(*rope_rows(q, k, cos, sin)),
+                         argnums=(0, 1))(flat(q), flat(k))
+    want = (apply_rope(q, cos, sin), apply_rope(k, cos, sin))
+    g_want = jax.grad(lambda q, k: mix(apply_rope(q, cos, sin),
+                                       apply_rope(k, cos, sin)),
+                      argnums=(0, 1))(q, k)
+    for a, w, name in zip(got + g_got, want + g_want, ("q", "k", "dq", "dk")):
+        _close(a, flat(w), dtype, 2e-6, name)
 
 
 @pytest.mark.parametrize("seq,d,bq,bk,tiles,folded", [
@@ -293,3 +338,160 @@ def test_flash_blocks_configurable_through_model(tiny_model_kwargs):
                                rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(bshd), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# --------------------------------------------------------------------------- #
+# the training stack's rule: heads of 64 two to a lane row
+# --------------------------------------------------------------------------- #
+
+SMOL = dict(num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+            hidden_size=256, intermediate_size=512, vocab_size=256,
+            max_position_embeddings=128, rope_theta=10000.0, dtype="float32",
+            attention_impl="flash")
+
+
+def _cfg(model=None, remat="none", **distributed):
+    from picotron_tpu.config import Config
+
+    return Config.from_dict({
+        "distributed": {"use_cpu": True, **distributed},
+        "model": {**SMOL, **(model or {})},
+        "training": {"seq_length": 128, "remat": remat},
+        "dataset": {"name": "synthetic"}})
+
+
+@pytest.fixture
+def layouts_called(monkeypatch):
+    """The ``layout`` of every ``flash_attention`` call the model makes."""
+    from picotron_tpu.ops.pallas import flash_attention as fa
+
+    seen, real = [], fa.flash_attention
+
+    def spy(*a, **kw):
+        seen.append(kw.get("layout", "folded"))
+        return real(*a, **kw)
+
+    monkeypatch.setattr(fa, "flash_attention", spy)
+    return seen
+
+
+@pytest.fixture
+def plain_interpreter(monkeypatch):
+    """The kernels through Pallas' plain interpreter: the TPU one simulates
+    memory with ordered callbacks, which ``jax.checkpoint`` refuses."""
+    from functools import partial
+
+    from jax.experimental import pallas as pl
+
+    monkeypatch.setattr(pl, "pallas_call",
+                        partial(pl.pallas_call, interpret=True))
+
+
+def _stack(cfg, h, return_kv=False):
+    """(loss, gradients) of the cfg's layer stack over ``h``, or one
+    layer's prefill outputs, on one device."""
+    from picotron_tpu.models import llama
+    from picotron_tpu.topology import build_topology
+    from picotron_tpu.utils import shard_map
+
+    from jax.sharding import PartitionSpec as P
+
+    params = llama.init_params(jax.random.PRNGKey(0), cfg.model)["layers"]
+    specs = llama.param_pspecs(cfg.model)["layers"]
+    cos, sin = llama.rope_tables(cfg)
+    cos, sin = cos[:h.shape[1]], sin[:h.shape[1]]
+    mesh = build_topology(1, 1, 1, 1, devices=jax.devices()[:1]).mesh
+
+    def loss(params, h):
+        out = llama.layers_forward(params, h, cos, sin, cfg)
+        return jnp.sum(out * jnp.cos(out))
+
+    def prefill(params, h):
+        lp = jax.tree.map(lambda a: a[0], params)
+        out, (k, v) = llama.decoder_layer(lp, h, cos, sin, cfg,
+                                          return_kv=True)
+        return out, k, v
+
+    fn = prefill if return_kv else jax.value_and_grad(loss, argnums=(0, 1))
+    out_specs = (P(),) * 3 if return_kv else (P(), (specs, P()))
+    return jax.jit(shard_map(fn, mesh, in_specs=(specs, P()),
+                             out_specs=out_specs))(params, h)
+
+
+@pytest.mark.parametrize("remat", ["full", "save_attn"])
+def test_paired_stack_matches_folded_stack(remat, layouts_called,
+                                           plain_interpreter, monkeypatch):
+    """The layer stack through the paired kernels and RoPE on rows against
+    the same stack a head a row: loss and every gradient."""
+    from picotron_tpu.models import llama
+
+    cfg = _cfg(remat=remat)
+    h = jax.random.normal(jax.random.PRNGKey(2), (2, 128, 256))
+    assert llama.flash_heads_per_row(cfg) == 2
+    got = _stack(cfg, h)
+    assert set(layouts_called) == {"paired"}
+    del layouts_called[:]
+    monkeypatch.setattr(llama, "flash_heads_per_row", lambda cfg: 1)
+    want = _stack(cfg, h)
+    assert set(layouts_called) == {"folded"}
+    for a, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        _close(a, w, jnp.float32, 5e-5)
+
+
+# every way out of the rule: (model overrides, distributed, the gauge's value)
+WAYS_OUT = {
+    "odd_heads": (dict(num_attention_heads=3, num_key_value_heads=3,
+                       hidden_size=192), {}),
+    "heads_of_128": (dict(num_attention_heads=2, num_key_value_heads=2), {}),
+    "odd_heads_a_rank": (dict(num_attention_heads=6, num_key_value_heads=6,
+                              hidden_size=384), dict(tp_size=2)),
+    "context_parallel": ({}, dict(cp_size=2)),
+    "layout_by_the_user": (dict(flash_layout="bshd"), {}),
+    "sdpa": (dict(attention_impl="sdpa"), {}),
+}
+
+
+@pytest.mark.parametrize("way", sorted(WAYS_OUT))
+def test_paired_rule_ways_out(way, layouts_called, plain_interpreter):
+    """Outside the rule the gauge reads 1 and the layer takes today's path
+    (one device can run the stack wherever the mesh is one device)."""
+    from picotron_tpu.models import llama
+
+    model, distributed = WAYS_OUT[way]
+    cfg = _cfg(model=model, **distributed)
+    assert llama.flash_heads_per_row(cfg) == 1
+    if distributed or way == "sdpa":
+        return
+    h = jax.random.normal(jax.random.PRNGKey(2),
+                          (1, 128, cfg.model.hidden_size))
+    _stack(cfg, h)
+    assert layouts_called and set(layouts_called) == {
+        cfg.model.flash_layout}
+
+
+def test_prefill_keeps_a_head_a_row(layouts_called, plain_interpreter):
+    """``return_kv=True`` (the engine's one-shot prefill) keeps the folded
+    call whatever the gauge reads, and returns the K/V it always did."""
+    from picotron_tpu.models import llama
+
+    cfg = _cfg()
+    assert llama.flash_heads_per_row(cfg) == 2
+    h = jax.random.normal(jax.random.PRNGKey(2), (1, 128, 256))
+    out, k, v = _stack(cfg, h, return_kv=True)
+    assert layouts_called == ["folded"]
+    assert k.shape == v.shape == (1, 128, 4, 64)
+
+
+@pytest.mark.parametrize("shape,want", [
+    # SmolLM-1.7B: 32 heads of 64; Mistral-7B-v0.3: 32 heads of 128
+    (dict(hidden_size=2048, num_attention_heads=32, num_key_value_heads=32,
+          intermediate_size=8192), 2),
+    (dict(hidden_size=4096, num_attention_heads=32, num_key_value_heads=8,
+          intermediate_size=14336), 1),
+])
+def test_flash_heads_per_row_gauge(shape, want):
+    """``picotron_flash_heads_per_row`` as ``train.py`` sets it, from the
+    one function the call site's rule uses."""
+    from picotron_tpu.models import llama
+
+    assert llama.flash_heads_per_row(_cfg(model=shape)) == want
