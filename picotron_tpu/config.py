@@ -628,8 +628,14 @@ class InferenceConfig:
     # copy-on-write — HBM tracks LIVE tokens instead of slots x window,
     # and identical prompt prefixes are stored (and prefilled) once.
     # Generations are pinned identical to contiguous
-    # (tests/test_paged_kv.py); contiguous stays the default until the
-    # paged path is A/B'd on hardware.
+    # (tests/test_paged_kv.py). Contiguous stays the default: the decode
+    # attend over a strip is the kernel every Llama cell's numbers rest
+    # on, and since PR 49 the layout reuses prompts too. A Llama-block
+    # engine keeps finished prompts' whole pages in a side store
+    # (kv_store_pages below) and COPIES the retained prefix of a later
+    # prompt into its strip instead of prefilling it again; "paged" shares
+    # such pages in place and also packs live tokens, at the price of its
+    # attends (PERF.md, PR 26).
     kv_layout: str = "contiguous"
     # Rows per KV page (paged layout only). Small pages waste less
     # capacity per sequence and fork prefixes at finer grain; large pages
@@ -641,6 +647,16 @@ class InferenceConfig:
     # parity with the contiguous layout; raise it to oversubscribe slots
     # against short typical sequences, shrink it to cap HBM.
     kv_num_pages: int = 0
+    # Pages of the contiguous layout's prefix store (docs/SERVING.md
+    # "Prompt reuse on the contiguous layout"; kv_page_len rows each, the
+    # NULL page among them). 0 = auto: twice the strips' rows (2 x slots x
+    # ceil(max_seq_len / kv_page_len) pages), fewer or none where the
+    # device could not hold them beside the weights and the strips; a
+    # positive value is the pool as given; -1 = no store. The store exists
+    # only where engine._store_pages says (a Llama block, contiguous
+    # layout, cache in the model's dtype, serial admission): elsewhere the
+    # field is not read.
+    kv_store_pages: int = 0
     # Radix prefix cache (paged layout only): prompt pages are kept in a
     # token-keyed trie after prefill and new requests reuse (refcount,
     # skip prefilling) their longest cached prefix, copy-on-write at the
@@ -1332,6 +1348,10 @@ class Config:
         if inf.kv_num_pages < 0:
             raise ValueError(
                 "inference.kv_num_pages must be >= 0 (0 = auto-size)")
+        if inf.kv_store_pages < -1:
+            raise ValueError(
+                "inference.kv_store_pages must be >= -1 (0 = auto-size, "
+                "-1 = no prefix store)")
         if inf.kv_page_policy not in ("uniform", "hot_bf16"):
             raise ValueError(
                 f"unknown inference.kv_page_policy {inf.kv_page_policy!r} "

@@ -807,6 +807,11 @@ class ContinuousBatcher:
             # handoff (zero prefill dispatches) + remote prefix imports
             d["handoff_seated"] = self.handoff_seated
             d["prefix_remote_hits"] = int(self._remote_hits_total.value)
+        if self.engine.store is not None:
+            # the contiguous layout's prefix store: occupancy, and the hit
+            # rate under the paged layout's keys
+            d.update(self.engine.store.stats())
+            d["prefix_store_bytes"] = self.engine.store_bytes
         if self._tenant_stats:
             # the /statz rendering of the picotron_tenant_* families
             d["tenants"] = {name: dict(st)
@@ -991,7 +996,9 @@ class ContinuousBatcher:
         cross to the host. Mutates the cache/dispatch counters. On the
         paged layout the engine's prefix-sharing admission runs instead:
         the longest radix-cached prefix is shared (no dispatches) and only
-        the suffix prefills."""
+        the suffix prefills; a contiguous engine with a prefix store
+        (``engine.store``) copies the retained prefix into the slot and
+        prefills the suffix the same way."""
         sample = None
         rh = self.engine.return_hidden
         hidden = None
@@ -1007,11 +1014,17 @@ class ContinuousBatcher:
                 return seated  # ("handoff", first_token)
             # payload landed in the radix as a prefix hint; the normal
             # paged admission below radix-hits it
-        if self.paged is not None:
-            self.paged.priced[i] = self.page_commitment(req)
-            out = self.engine.prefill_paged(
-                self.params, self._cache, req.prompt, i, sample=sample,
-                adapter_id=adapter, cache_salt=req.tenant)
+        if self.paged is not None or self.engine.store is not None:
+            # either layout's prefix reuse: the paged pool shares the
+            # cached pages in place, the contiguous strips' side store
+            # copies them in; only the rest of the prompt prefills
+            if self.paged is not None:
+                self.paged.priced[i] = self.page_commitment(req)
+            admit = (self.engine.prefill_paged if self.paged is not None
+                     else self.engine.prefill_stored)
+            out = admit(self.params, self._cache, req.prompt, i,
+                        sample=sample, adapter_id=adapter,
+                        cache_salt=req.tenant)
             self._cache, logits, n, cached = out[:4]
             hidden = out[4] if rh else None
             self.prefill_dispatches += n

@@ -191,6 +191,7 @@ class InferenceEngine:
                  kv_page_len: Optional[int] = None,
                  kv_num_pages: Optional[int] = None,
                  kv_page_policy: Optional[str] = None,
+                 kv_store_pages: Optional[int] = None,
                  sample_on_device: Optional[bool] = None,
                  weight_dtype: Optional[str] = None,
                  drafter: Optional[str] = None,
@@ -604,6 +605,108 @@ class InferenceEngine:
             "picotron_kv_cache_bytes",
             "bytes of the resident KV cache leaves").set(
                 self.kv_cache_bytes)
+        # The prefix store beside the strips (docs/SERVING.md "Prompt
+        # reuse on the contiguous layout"): finished prompts' whole pages
+        # kept in a side pool under a radix trie, copied back into the slot
+        # of a later prompt that begins with them (``prefill_stored``).
+        # Host half ``self.store``; device half ``self._store_pool``,
+        # allocated with the first cache (``init_cache``) and kept across
+        # caches; None where ``_store_pages`` finds no place for it.
+        if kv_store_pages is not None:
+            inf.kv_store_pages = int(kv_store_pages)
+        self.store: Optional[paged_kv.PrefixStore] = None
+        self._store_pool = None
+        # retentions owed, (prompt ids, slot, salt): copied in the shadow
+        # of the next round (``_store_flush``), not inside admission
+        self._store_pending: list = []
+        page_len = int(kv_page_len or inf.kv_page_len)
+        pages = self._store_pages(shapes, page_len)
+        if pages:
+            self.store = paged_kv.PrefixStore(
+                page_len, -(-self.max_seq_len // page_len), pages)
+            full = named_shardings(topo, self._cspecs)
+            pool_sh = {n: full[n] for n in kv_cache.store_leaves(shapes)}
+            self._init_store_jit = jax.jit(
+                partial(kv_cache.init_store, shapes, pages, page_len),
+                out_shardings=pool_sh)
+            # both take their arrays under the cache's own shardings
+            # whoever made them (a fresh cache, a round, a release), so
+            # the one compile of ``init_cache`` serves every admission
+            self._retain_jit = jax.jit(
+                kv_cache.retain_rows, donate_argnums=(0,),
+                in_shardings=(pool_sh, full, None, None),
+                out_shardings=pool_sh)
+            self._seat_jit = jax.jit(
+                kv_cache.seat_rows, donate_argnums=(0,),
+                in_shardings=(full, pool_sh, None, None, None),
+                out_shardings=full)
+            self.store_bytes = kv_cache.cache_bytes(
+                jax.eval_shape(self._init_store_jit))
+            reg = self.obs.registry
+            reg.gauge("picotron_prefix_store_bytes",
+                      "bytes of the prefix store's page pool").set(
+                          self.store_bytes)
+            self._store_counters = {
+                what: reg.counter(f"picotron_prefix_store_{what}_total", doc)
+                for what, doc in (
+                    ("hits", "admissions that found a retained prefix"),
+                    ("tokens_copied", "prompt tokens whose K/V rows were "
+                     "copied into the slot and not prefilled"),
+                    ("pages_retained", "pages of finished prompts the "
+                     "store took"),
+                    ("pages_evicted", "retained pages freed for newer "
+                     "ones, least recently used first"))}
+
+    def _store_pages(self, shapes: dict, page_len: int) -> int:
+        """Pages of the prefix store this engine keeps beside its strips,
+        the NULL page among them; 0: no store. THE one rule, read off the
+        configuration: a Llama block on the contiguous layout with its
+        cache in the model's dtype and admission on the serial path.
+        Absent for every other block (their caches are latents, states and
+        rings), for ``kv_layout: paged`` (it shares pages in place), and
+        for an int8 cache, ``dp_size > 1``, ``mixed_dispatch``,
+        ``overlap``, speculative decoding and adapter packs, none of which
+        is wired to it. ``inference.kv_store_pages`` sizes it: a negative
+        value turns it off, a positive one is the pool's pages as given,
+        0 (auto) holds twice the strips' rows (a document asked again
+        later has to outlive the requests in between), fewer where the
+        device could not hold them beside the weights, the strips and an
+        eighth of its memory left to the programs, none where not a page
+        fits: such a configuration starts as it did without a store."""
+        inf, m = self.cfg.inference, self.cfg.model
+        if (inf.kv_store_pages < 0 or m.model_type != "llama"
+                or self.paged is not None or self.quantized
+                or self.dp_size > 1 or self.mixed or self.overlap
+                or self.spec_len > 0 or self.adapters is not None):
+            return 0
+        if inf.kv_store_pages:
+            return max(int(inf.kv_store_pages), 2)
+        auto = 2 * self.slots * -(-self.max_seq_len // page_len)
+        limit = self._device_limit()
+        if limit is None:  # a backend that does not say (the CPU)
+            return 1 + auto
+        tp = self.topo.tp_size
+        like = jax.eval_shape(partial(llama.init_params, m=m),
+                              jax.random.key(0))
+        if self.quant_weights:
+            like = jax.eval_shape(llama.quantize_params, like)
+        # a device's share: the matrices, the strips and the pool all
+        # split their heads over 'tp'
+        page = kv_cache.cache_bytes(jax.eval_shape(
+            partial(kv_cache.init_store, shapes, 1, page_len)))
+        room = limit - limit // 8 - (llama.param_bytes(like)
+                                     + self.kv_cache_bytes) // tp
+        fit = min(auto, max(room, 0) // (page // tp))
+        return 1 + fit if fit else 0
+
+    def _device_limit(self) -> Optional[int]:
+        """Bytes one device of the mesh may hold, where the backend says
+        (the CPU does not, nor does a device described and not attached)."""
+        try:
+            stats = self.topo.mesh.local_devices[0].memory_stats()
+        except (jax.errors.JaxRuntimeError, IndexError):
+            return None
+        return (stats or {}).get("bytes_limit")
 
     def _build_programs(self) -> None:
         """(Re)build the compiled model programs. Runs at construction and
@@ -1543,7 +1646,20 @@ class InferenceEngine:
         the batcher's cache-lost rebuild gets a coherent empty pool."""
         if self.paged is not None:
             self.paged.reset()
-        return self._init_cache_jit()
+        cache = self._init_cache_jit()
+        if self.store is not None:
+            # a new cache forgets what was retained, as the paged pool does
+            self.store.reset()
+            del self._store_pending[:]
+            if self._store_pool is None:
+                # the pool, and its two copy programs compiled with it on
+                # pages nobody reads: the first hit finds them built
+                self._store_pool = self._init_store_jit()
+                null = np.zeros(self.store.max_pages, np.int32)
+                self._store_pool = self._retain_jit(self._store_pool, cache,
+                                                    0, null)
+                cache = self._seat_jit(cache, self._store_pool, 0, null, 0)
+        return cache
 
     def make_draft_program(self, with_head: bool = False):
         """Build the learned drafter's jitted dispatch (EAGLE-style —
@@ -1963,6 +2079,116 @@ class InferenceEngine:
         base = (cache, logits, n, cached)
         return base + (hidden,) if rh else base
 
+    def prefill_stored(self, params, cache, prompt_ids, slot: int,
+                       sample=None, adapter_id=None,
+                       cache_salt: str = "") -> tuple:
+        """Contiguous admission through the prefix store (consumes
+        ``cache``): what ``prefill_paged`` returns, (cache, last_logits
+        [1, V] fp32 — or the sampled token on a ``sample_on_device`` engine
+        — n_dispatches, cached_tokens[, hidden]).
+
+        The store resolves the longest retained prefix of the prompt in
+        whole pages (``PrefixStore.lookup``, within ``cache_salt``'s
+        domain). A hit COPIES those pages into rows ``[0, cached)`` of the
+        slot's strip (``_store_seat``: one program, the slot's length set
+        with it) and the rest runs through the chunk program,
+        ``prefill_chunked(start=cached)``, whatever its length: no shape
+        the warm-up has not compiled. The hit is taken where it saves a
+        dispatch (``_prefill_dispatches``): a resumed suffix pays whole
+        ``prefill_chunk``-row chunks however short it is, so a prompt the
+        one-shot program takes in one smaller bucket, or one whose suffix
+        still needs as many chunks as the whole of it, is prefilled as
+        before (``smollm-1.7b.serve-batch`` asks its eight prompts over and
+        over, most of them under a chunk: PERF.md section 6, PR 49). A miss
+        takes exactly the dispatches the store-less engine takes.
+
+        Either way the prompt's own whole pages the trie does not hold yet
+        are owed to the store (``_store_pending``) and copied strip -> pool,
+        for the next prompt that begins with them, once the next round is
+        on the device (``_store_flush``, from ``_round``): the bookkeeping
+        and the copy's enqueue then run while the host would wait for the
+        round, and the copy itself in the gap the device has between two
+        rounds, where inside admission every running stream would wait for
+        both."""
+        ids = [int(t) for t in np.asarray(prompt_ids, np.int32).reshape(-1)]
+        if not ids:
+            raise ValueError("empty prompt")
+        # a prompt parked in this slot and still owed (no round ran since)
+        # is copied now, from rows this one is about to overwrite
+        self._store_flush(cache, slot)
+        cache, cached = self._store_seat(cache, ids, slot, cache_salt)
+        n = self._prefill_dispatches(len(ids), cached)
+        if cached > 0 or len(ids) > self.prefill_chunk:
+            out = self.prefill_chunked(params, cache, ids, slot,
+                                       start=cached, sample=sample,
+                                       adapter_id=adapter_id)
+            cache = out[0]
+        else:
+            out = self.prefill(params, ids, sample=sample,
+                               adapter_id=adapter_id)
+            cache = self.insert(cache, out[0], slot, len(ids))
+        self._store_pending.append((ids, slot, cache_salt))
+        return (cache, out[1], n, cached) + tuple(out[2:])
+
+    def _store_flush(self, cache, slot: Optional[int] = None) -> None:
+        """Retain what is owed (of ``slot`` alone, if given) from ``cache``,
+        which is only read: the rows of a parked prompt stay as its prefill
+        wrote them whatever its slot decodes behind them."""
+        owed = [e for e in self._store_pending
+                if slot is None or e[1] == slot]
+        for entry in owed:
+            self._store_pending.remove(entry)
+            self._store_retain(cache, *entry)
+
+    def _prefill_dispatches(self, prompt_len: int, cached: int = 0) -> int:
+        """Prefill programs a prompt runs past ``cached`` parked tokens:
+        chunks of ``prefill_chunk`` rows, or the one one-shot bucket of a
+        prompt at or under a chunk with nothing parked."""
+        return max(-(-(prompt_len - cached) // self.prefill_chunk), 1)
+
+    def _store_seat(self, cache, ids, slot: int, salt: str = "") -> tuple:
+        """The hit: (cache, cached) with the longest retained prefix of
+        ``ids``, ``cached`` tokens in whole pages, copied into ``slot``'s
+        strip and its length set; the cache as it came and 0 on a miss,
+        and where the copy would save no prefill dispatch."""
+        whole = self._prefill_dispatches(len(ids))
+        row, cached = self.store.lookup(
+            ids, salt=salt, worth=lambda c: self._prefill_dispatches(
+                len(ids), c) < whole)
+        if cached:
+            cache = self._seat_jit(cache, self._store_pool, slot, row,
+                                   cached)
+            self._store_counters["hits"].inc()
+            self._store_counters["tokens_copied"].inc(cached)
+        return cache, cached
+
+    def _store_retain(self, cache, ids, slot: int, salt: str = "") -> None:
+        """Retention: the whole pages of the prompt ``ids``, parked in
+        ``slot``, that the trie does not hold yet are copied into pool
+        pages (freed from the least recently used leaves where the pool is
+        full) and the trie takes them. Keeps what fits, and never raises:
+        a round or an admission does not fail for a page not kept."""
+        evicted = self.store.radix.evictions
+        plan = self.store.plan_retain(ids, salt=salt)
+        self._store_counters["pages_evicted"].inc(
+            self.store.radix.evictions - evicted)
+        if plan is None:
+            return
+        row, chunk_pids = plan
+        try:
+            self._store_pool = self._retain_jit(self._store_pool, cache,
+                                                slot, row)
+        except Exception as e:  # noqa: BLE001 - the store starts over
+            # the pool was donated to the dispatch that failed and may be
+            # gone with it: forget what was retained and hold a fresh one
+            log0(f"prefix store: retention failed ({type(e).__name__}: "
+                 f"{e}); the store starts over empty", flush=True)
+            self.store.reset()
+            self._store_pool = self._init_store_jit()
+            return
+        self._store_counters["pages_retained"].inc(
+            self.store.commit(ids, chunk_pids, salt=salt))
+
     # ---- page transport (prefill/decode disaggregation) -------------------
 
     def transport_spec(self) -> dict:
@@ -2344,6 +2570,9 @@ class InferenceEngine:
             # the copy down starts when the program ends, not when the
             # host has waited for it: the sync reads bytes that are there
             out["packed"].copy_to_host_async()
+        if self._store_pending:
+            # this round's admissions, retained behind it on the device
+            self._store_flush(out["cache"])
         if "stats" in out:
             self._keep_stats(out.pop("stats"))  # for ``take_stats``
         lane = ((out.pop("lane_out"), out.pop("lane_hidden", None))

@@ -553,6 +553,92 @@ def release(cache: dict, slot) -> dict:
     return {**cache, "lengths": cache["lengths"].at[slot].set(0)}
 
 
+# --------------------------------------------------------------------------- #
+# the prefix store beside the strips (host half: paged_kv.PrefixStore)
+# --------------------------------------------------------------------------- #
+#
+# A pool of pages for every storage leaf of the cache, ``[L, pages,
+# page_len, ...]`` with the leaf's own row behind (a packed row stays packed:
+# a page is ``page_len`` rows of a strip, byte for byte), page 0 the NULL
+# page: what a copy reads from it nobody reads, what a copy writes to it
+# nobody keeps. Both copies index the pool as ONE axis of ``L * pages``
+# pages (a free reshape), a page of a layer an index: the form the chip's
+# compiler gathers and scatters in place, with no layer sliced out and
+# nothing re-laid (``paged_kv.gather_window``; tests/test_chip_compile.py
+# reads both programs' compiled text).
+
+
+def store_leaves(cache: dict) -> list:
+    """The leaves a prefix store keeps pages of: every stacked leaf."""
+    return [n for n in cache if n != "lengths"]
+
+
+def init_store(shapes: dict, num_pages: int, page_len: int) -> dict:
+    """Zeroed page pools shaped after the cache's leaves (``shapes``: the
+    cache's own arrays or their abstract shapes)."""
+    return {n: jnp.zeros(shapes[n].shape[:1] + (num_pages, page_len)
+                         + shapes[n].shape[3:], shapes[n].dtype)
+            for n in store_leaves(shapes)}
+
+
+def _flat_pages(pool: jnp.ndarray, pids: jnp.ndarray) -> tuple:
+    """(the pool as [L * pages, page_len, ...], the flat index [L, n] of
+    page ``pids[i]`` in every layer)."""
+    L, pages = pool.shape[:2]
+    at = (jnp.arange(L, dtype=jnp.int32)[:, None] * pages
+          + jnp.asarray(pids, jnp.int32)[None, :])
+    return pool.reshape((L * pages,) + pool.shape[2:]), at
+
+
+def retain_rows(store: dict, cache: dict, slot, pids) -> dict:
+    """Retention's copy, strip -> pool: page ``i`` of ``slot``'s strip
+    (rows ``[i * page_len, (i + 1) * page_len)`` of every leaf and layer)
+    lands in pool page ``pids[i]``; ``pids`` [ceil(T / page_len)] int32
+    names the NULL page wherever nothing is to be kept. ``store`` is
+    donated and updated in place; the cache is only read."""
+    slot = jnp.asarray(slot, jnp.int32)
+    out = {}
+    for name, pool in store.items():
+        leaf = cache[name]
+        n, page_len = pids.shape[0], pool.shape[2]
+        at0 = (jnp.zeros((), jnp.int32),) * (leaf.ndim - 2)
+        strip = lax.dynamic_slice(
+            leaf, (at0[0], slot) + at0,
+            leaf.shape[:1] + (1,) + leaf.shape[2:])[:, 0]  # [L, T, ...]
+        short = n * page_len - strip.shape[1]
+        if short:  # a window that ends inside its last page
+            strip = jnp.pad(strip, ((0, 0), (0, short))
+                            + ((0, 0),) * (strip.ndim - 2))
+        flat, at = _flat_pages(pool, pids)
+        pages = strip.reshape((-1, page_len) + strip.shape[2:])
+        out[name] = flat.at[at.reshape(-1)].set(pages).reshape(pool.shape)
+    return out
+
+
+def seat_rows(cache: dict, store: dict, slot, pids, length) -> dict:
+    """The hit's copy, pool -> strip: pool page ``pids[i]`` becomes page
+    ``i`` of ``slot``'s strip in every leaf and layer, and the slot's
+    length is set to ``length`` (the retained prefix, whole pages). The
+    whole strip is written, rows past ``length`` with whatever the NULL
+    page holds: they lie behind the length mask and the prompt's own chunks
+    and decode steps overwrite them. ``cache`` is donated and updated in
+    place; the store is only read."""
+    slot = jnp.asarray(slot, jnp.int32)
+    out = dict(cache)
+    for name, pool in store.items():
+        leaf = cache[name]
+        flat, at = _flat_pages(pool, pids)
+        rows = flat[at]  # [L, n, page_len, ...]
+        rows = rows.reshape(rows.shape[:1] + (-1,) + rows.shape[3:])
+        rows = rows[:, None, : leaf.shape[2]]
+        at0 = (jnp.zeros((), jnp.int32),) * (leaf.ndim - 2)
+        out[name] = lax.dynamic_update_slice(leaf, rows,
+                                             (at0[0], slot) + at0)
+    out["lengths"] = cache["lengths"].at[slot].set(
+        jnp.asarray(length, jnp.int32))
+    return out
+
+
 def live_tokens(cache: dict) -> jax.Array:
     """Total tokens currently parked across slots (occupancy metric for
     the batcher/bench)."""

@@ -52,6 +52,7 @@ generations against contiguous across every dispatch family.
 
 from __future__ import annotations
 
+import heapq
 from collections import deque
 
 import jax.numpy as jnp
@@ -742,21 +743,33 @@ class RadixCache:
         return sum(count(c)[0] for root in self._roots.values()
                    for c in root.children.values())
 
+    def evict(self, n: int) -> int:
+        """Free up to ``n`` pages, each time the least-recently-used
+        refcount-1 leaf's; a freed leaf exposes its parent as a candidate.
+        ONE walk of the trie however many pages go (a store that is full
+        frees a prompt's worth of pages at every admission). Returns the
+        pages freed: fewer than ``n`` when nothing more is evictable
+        (every cached page left is also held by a live slot)."""
+        heap = [(leaf.last_use, id(leaf), leaf) for leaf in self._leaves()
+                if self.pool.refs[leaf.page_id] == 1]
+        heapq.heapify(heap)
+        freed = 0
+        while heap and freed < n:
+            _, _, node = heapq.heappop(heap)
+            parent = node.parent
+            self.pool.unref(node.page_id)
+            del parent.children[node.tokens]
+            self.evictions += 1
+            freed += 1
+            if (parent.parent is not None and not parent.children
+                    and self.pool.refs[parent.page_id] == 1):
+                heapq.heappush(heap, (parent.last_use, id(parent), parent))
+        return freed
+
     def evict_one(self) -> bool:
         """Free the least-recently-used refcount-1 leaf's page. Returns
-        False when nothing is evictable (every cached page is also held
-        by a live slot)."""
-        best = None
-        for n in self._leaves():
-            if self.pool.refs[n.page_id] == 1 and (
-                    best is None or n.last_use < best.last_use):
-                best = n
-        if best is None:
-            return False
-        self.pool.unref(best.page_id)
-        del best.parent.children[best.tokens]
-        self.evictions += 1
-        return True
+        False when nothing is evictable."""
+        return self.evict(1) == 1
 
     def clear(self) -> None:
         """Drop every cache reference, across all salts (pool reset
@@ -771,7 +784,207 @@ class RadixCache:
             root.children = {}
 
 
-class PagedKV:
+class PageStore:
+    """A page pool and the radix trie over it, with the calls that pin
+    cached pages and land pages in the trie from outside a slot: what the
+    paged layout's manager (``PagedKV``) and the contiguous layout's prefix
+    store (``PrefixStore``) share."""
+
+    def __init__(self, page_len: int, num_pages: int,
+                 prefix_cache: bool = True):
+        self.page_len = int(page_len)
+        self.num_pages = int(num_pages)
+        self.prefix_cache = bool(prefix_cache)
+
+    def reset(self) -> None:
+        """Fresh pool and trie: every retained byte is forgotten."""
+        self.pool = PagePool(self.num_pages)
+        self.radix = RadixCache(self.page_len, self.pool)
+
+    def pages_for(self, tokens: int) -> int:
+        """Worst-case pages ``tokens`` rows can occupy."""
+        return -(-max(int(tokens), 0) // self.page_len)
+
+    def _alloc(self) -> int:
+        pid = self.pool.alloc()
+        while pid is None:
+            if not self.radix.evict_one():
+                raise PagePoolExhausted(
+                    f"page pool exhausted ({self.pool.usable_pages} pages, "
+                    f"none free or evictable)")
+            pid = self.pool.alloc()
+        return pid
+
+    def acquire_prefix(self, ids, salt: str = "") -> tuple:
+        """Pin: radix-match ``ids`` (within ``salt``'s domain) and
+        take a TRANSIENT reference on every matched page so eviction (and
+        any COW planning) cannot touch them while the holder works (the
+        transport serializes their bytes; the prefix store allocates
+        beside them). Returns (page_ids, matched_tokens); the
+        caller MUST ``release_pages`` the returned pages when done — the
+        pin is a holder like any other."""
+        if not self.prefix_cache:
+            return [], 0
+        pages, matched = self.radix.match(ids, salt=salt)
+        npages = self.pages_for(matched)
+        held = []
+        for i in range(npages):
+            self.pool.ref(pages[i])
+            held.append(int(pages[i]))
+        return held, matched
+
+    def release_pages(self, pids) -> None:
+        """Drop the transient references ``acquire_prefix`` (or a failed
+        import) holds. Double drops raise — the pool's own discipline."""
+        for pid in pids:
+            self.pool.unref(int(pid))
+
+    def alloc_import(self, n: int) -> list:
+        """Allocate ``n`` pages for a transport import or a retention
+        (refcount 1 held by the importer), the least recently used
+        evictable pages freed first where the free list is short (one walk
+        of the trie, ``RadixCache.evict``). All-or-nothing: on exhaustion
+        every page of this batch is released before the raise, so a failed
+        import can never leak pool capacity."""
+        if n > self.pool.free_count:
+            self.radix.evict(n - self.pool.free_count)
+        pids = []
+        try:
+            for _ in range(n):
+                pids.append(self._alloc())
+        except PagePoolExhausted:
+            self.release_pages(pids)
+            raise
+        return pids
+
+    def finish_import(self, ids, chunk_pids: dict, salt: str = "") -> int:
+        """Graft written import pages into ``salt``'s radix domain and
+        drop the importer's references: created nodes end held by the
+        cache alone (refcount 1, evictable — exactly a registered
+        prompt's state); duplicate chunks' pages free immediately.
+        Returns nodes created."""
+        created, _ = self.radix.adopt(ids, chunk_pids, salt=salt)
+        self.release_pages(chunk_pids.values())
+        return created
+
+
+class PrefixStore(PageStore):
+    """Host half of the CONTIGUOUS layout's prefix store: the whole pages
+    of finished prompts, kept beside the slots' strips under their token
+    paths, so that a prompt whose leading pages were prefilled before has
+    them copied into its strip and not computed again. The device half is a
+    pool of ``num_pages`` pages laid out as the strips' rows are
+    (``kv_cache.init_store``) and the two copy programs
+    (``kv_cache.retain_rows`` strip -> pool, ``kv_cache.seat_rows`` pool ->
+    strip), which take a slot's ``max_pages`` page ids in one fixed-shape
+    row, NULL where nothing moves.
+
+    A slot never points at a page here: a hit copies, so outside
+    ``plan_retain`` .. ``commit`` every retained page is held by the trie
+    alone and goes, least recently used leaf first, when the pool runs
+    dry. Whole pages only: a prompt's tail past its last page boundary is
+    prefilled again (under a page of tokens). The same correctness contract
+    as the paged layout's sharing (this module's docstring): rows at
+    position ``p`` depend on tokens ``0..p`` alone."""
+
+    def __init__(self, page_len: int, max_pages: int, num_pages: int):
+        super().__init__(page_len, num_pages)
+        self.max_pages = int(max_pages)
+        self.reset()
+
+    def reset(self) -> None:
+        super().reset()
+        self.prefix_queries = 0
+        self.prefix_hits = 0
+        self.prompt_tokens = 0
+        self.cached_tokens = 0
+        self.pages_retained = 0
+
+    def _row(self, page_at: dict) -> np.ndarray:
+        """The copy programs' operand: page ``page_at[i]`` at the slot's
+        logical page ``i``, the NULL page elsewhere."""
+        row = np.full(self.max_pages, NULL_PAGE, np.int32)
+        for i, pid in page_at.items():
+            row[i] = pid
+        return row
+
+    def _whole(self, ids) -> list:
+        """``ids`` cut to its whole pages (inside the slot's window)."""
+        n = min(len(ids) // self.page_len, self.max_pages)
+        return list(ids[: n * self.page_len])
+
+    def lookup(self, ids, salt: str = "", worth=None) -> tuple:
+        """The hit: (page-id row, cached) for the longest retained prefix
+        of ``ids`` in whole pages within ``salt``'s domain, capped so that
+        at least one prompt token is left to prefill (its logits seed the
+        first sampled token). ``worth(cached) -> bool`` is the caller's
+        say on whether copying that prefix in beats prefilling it (the
+        engine's: fewer prefill dispatches); a prefix not worth it counts
+        as no hit. The matched path is touched (LRU) either way."""
+        self.prefix_queries += 1
+        self.prompt_tokens += len(ids)
+        pages, matched = self.radix.match(ids, salt=salt)
+        n = min(min(matched, len(ids) - 1) // self.page_len, self.max_pages)
+        if n and worth is not None and not worth(n * self.page_len):
+            n = 0
+        if n:
+            self.prefix_hits += 1
+            self.cached_tokens += n * self.page_len
+        return self._row(dict(enumerate(pages[:n]))), n * self.page_len
+
+    def plan_retain(self, ids, salt: str = ""):
+        """Retention, first half: pages for the whole pages of the prompt
+        ``ids`` that the trie does not hold yet, as (page-id row for the
+        copy strip -> pool, ``{chunk: page}`` for ``commit``); None when
+        there is nothing to retain or no page to be had. The pages the
+        trie already holds of this prompt are pinned while the least
+        recently used leaves make room, so eviction never cuts into the
+        path being extended; where free and evictable pages together fall
+        short, the LEADING missing pages are kept (a prefix is what a later
+        prompt can use) and the rest is not retained: the request never
+        fails for it."""
+        whole = self._whole(ids)
+        missing = self.radix.plan_adopt(whole, salt=salt)
+        if not missing:
+            return None
+        held, _ = self.acquire_prefix(whole, salt=salt)
+        try:
+            if len(missing) > self.pool.free_count:
+                self.radix.evict(len(missing) - self.pool.free_count)
+            take = missing[:self.pool.free_count]
+            fresh = self.alloc_import(len(take))
+        finally:
+            self.release_pages(held)
+        if not take:
+            return None
+        chunk_pids = dict(zip(take, fresh))
+        return self._row(chunk_pids), chunk_pids
+
+    def commit(self, ids, chunk_pids: dict, salt: str = "") -> int:
+        """Retention, second half (the copy is enqueued): the trie takes
+        the pages under ``salt``. Returns the pages it took."""
+        created = self.finish_import(self._whole(ids), chunk_pids, salt=salt)
+        self.pages_retained += created
+        return created
+
+    def stats(self) -> dict:
+        """Occupancy and effectiveness (merged into ``batcher.stats()`` ->
+        ``/statz``; the hit keys are the paged layout's)."""
+        return {
+            "prefix_store_pages_total": self.pool.usable_pages,
+            "prefix_store_pages_live": self.pool.live_count,
+            "prefix_store_pages_retained": self.pages_retained,
+            "prefix_queries": self.prefix_queries,
+            "prefix_hits": self.prefix_hits,
+            "prefix_hit_rate": (
+                round(self.cached_tokens / self.prompt_tokens, 4)
+                if self.prompt_tokens else None),
+            "prefix_cached_tokens": self.cached_tokens,
+            "radix_evictions": self.radix.evictions,
+        }
+
+
+class PagedKV(PageStore):
     """Host-side page manager for one engine: per-slot block tables +
     lengths, the pool, the radix cache, and admission pricing.
 
@@ -785,19 +998,16 @@ class PagedKV:
 
     def __init__(self, slots: int, page_len: int, max_pages: int,
                  num_pages: int, prefix_cache: bool = True):
+        super().__init__(page_len, num_pages, prefix_cache)
         self.slots = int(slots)
-        self.page_len = int(page_len)
         self.max_pages = int(max_pages)
-        self.num_pages = int(num_pages)
-        self.prefix_cache = bool(prefix_cache)
         self.reset()
 
     def reset(self) -> None:
         """Fresh pool/trie/tables — pairs with a fresh zeroed device
         cache (engine.init_cache), including the batcher's cache-lost
         rebuild."""
-        self.pool = PagePool(self.num_pages)
-        self.radix = RadixCache(self.page_len, self.pool)
+        super().reset()
         self.tables = np.full((self.slots, self.max_pages), NULL_PAGE,
                               np.int32)
         self.host_len = np.zeros(self.slots, np.int64)
@@ -810,10 +1020,6 @@ class PagedKV:
         self.cow_copies = 0
 
     # ---- pricing / admission ---------------------------------------------
-
-    def pages_for(self, tokens: int) -> int:
-        """Worst-case pages ``tokens`` rows can occupy."""
-        return -(-max(int(tokens), 0) // self.page_len)
 
     @property
     def usable_pages(self) -> int:
@@ -848,16 +1054,6 @@ class PagedKV:
         return need <= self.available_pages()
 
     # ---- slot lifecycle ---------------------------------------------------
-
-    def _alloc(self) -> int:
-        pid = self.pool.alloc()
-        while pid is None:
-            if not self.radix.evict_one():
-                raise PagePoolExhausted(
-                    f"page pool exhausted ({self.pool.usable_pages} pages, "
-                    f"none free or evictable)")
-            pid = self.pool.alloc()
-        return pid
 
     def match_prefix(self, slot: int, ids, cap_last: bool = True,
                      salt: str = "") -> int:
@@ -935,55 +1131,6 @@ class PagedKV:
                 self.pool.unref(pid)
                 self.cow_copies += 1
         return cows
-
-    # ---- page transport (prefill/decode disaggregation) -------------------
-
-    def acquire_prefix(self, ids, salt: str = "") -> tuple:
-        """Export pin: radix-match ``ids`` (within ``salt``'s domain) and
-        take a TRANSIENT reference on every matched page so eviction (and
-        any COW planning) cannot touch them while the transport
-        serializes their bytes. Returns (page_ids, matched_tokens); the
-        caller MUST ``release_pages`` the returned pages when done — the
-        pin is a holder like any other."""
-        if not self.prefix_cache:
-            return [], 0
-        pages, matched = self.radix.match(ids, salt=salt)
-        npages = self.pages_for(matched)
-        held = []
-        for i in range(npages):
-            self.pool.ref(pages[i])
-            held.append(int(pages[i]))
-        return held, matched
-
-    def release_pages(self, pids) -> None:
-        """Drop the transient references ``acquire_prefix`` (or a failed
-        import) holds. Double drops raise — the pool's own discipline."""
-        for pid in pids:
-            self.pool.unref(int(pid))
-
-    def alloc_import(self, n: int) -> list:
-        """Allocate ``n`` pages for a transport import (refcount 1 held
-        by the importer). All-or-nothing: on exhaustion every page of
-        this batch is released before the raise, so a failed import can
-        never leak pool capacity."""
-        pids = []
-        try:
-            for _ in range(n):
-                pids.append(self._alloc())
-        except PagePoolExhausted:
-            self.release_pages(pids)
-            raise
-        return pids
-
-    def finish_import(self, ids, chunk_pids: dict, salt: str = "") -> int:
-        """Graft written import pages into ``salt``'s radix domain and
-        drop the importer's references: created nodes end held by the
-        cache alone (refcount 1, evictable — exactly a registered
-        prompt's state); duplicate chunks' pages free immediately.
-        Returns nodes created."""
-        created, _ = self.radix.adopt(ids, chunk_pids, salt=salt)
-        self.release_pages(chunk_pids.values())
-        return created
 
     def register_prompt(self, slot: int, ids, salt: str = "") -> None:
         """Insert a freshly prefilled prompt's pages into ``salt``'s

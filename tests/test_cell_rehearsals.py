@@ -88,3 +88,63 @@ def test_rehearsal_of_the_cell_computes_its_readers(cell):
     want = of_cell(MANIFEST["end_to_end"], cell) \
         | READERS.get(cell, counter_readers(cell))
     assert want <= set(out["computed"]), want - set(out["computed"])
+
+
+def _toy_reuse_pct(cell: str) -> float:
+    """What ``engine.prompt_reuse_pct.chat`` computes, at toy size in this
+    process: the mix's rehearsal schedule (the load generator's own plan:
+    ``loadgen.build_schedule``) through a batcher over the configuration's
+    rehearsal engine, as many requests in flight as it has slots; 100 x (1
+    - ``picotron_prefill_tokens_total`` / the prompt tokens asked). A
+    rehearsal prints no value, so the share is held here."""
+    import jax
+
+    from benchmarks import common, loadgen
+    from benchmarks.run import merged
+    from picotron_tpu.config import Config
+    from picotron_tpu.inference import (ContinuousBatcher, InferenceEngine,
+                                        Request)
+    from picotron_tpu.models import llama
+
+    entry = next(w for w in MANIFEST["workloads"] if w["name"] == cell)
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           entry["config"] + ".json")) as f:
+        config = json.load(f)
+    with open(os.path.join(ROOT, "benchmarks", "traffic",
+                           entry["traffic"] + ".json")) as f:
+        traffic = json.load(f)
+    config = merged(config, config["rehearsal"])
+    traffic = merged(traffic, traffic["rehearsal"])
+    cfg = Config.from_dict({
+        "distributed": {"use_cpu": True},
+        "model": common.model_section(config),
+        "training": {"seq_length": config["serve"]["max_seq_len"]},
+        "dataset": {"name": "synthetic"}})
+    # chunks of 32 stand to the toy prompts (44-90) as chunks of 512 to
+    # the cell's (1,040-1,900): two or three a prompt, one a resumed suffix
+    engine = InferenceEngine(cfg, prefill_chunk=32, **config["serve"])
+    params = engine.shard_params(jax.jit(
+        lambda k: llama.init_params(k, cfg.model))(jax.random.PRNGKey(0)))
+    plan = loadgen.build_schedule(traffic, 3000000001, 2.0,
+                                  cfg.model.vocab_size)[:24]
+    b = ContinuousBatcher(engine, params, seed=0)
+    res = b.run([Request(uid=f"r{i}", prompt=r["prompt"],
+                         max_new_tokens=r["max_new_tokens"])
+                 for i, r in enumerate(plan)])
+    assert all(len(res[f"r{i}"].tokens) == r["max_new_tokens"]
+               for i, r in enumerate(plan))
+    ran = b.obs.registry.counter("picotron_prefill_tokens_total").value
+    return 100.0 * (1.0 - ran / sum(len(r["prompt"]) for r in plan))
+
+
+def test_documents_asked_again_are_not_prefilled_again():
+    """``serve-longdoc``'s plan at toy size (documents of 40-80 tokens
+    asked three times, pages of 16): asks two and three find the
+    document's whole pages retained, so a change that silently stops
+    retaining fails here and not in a chip run."""
+    assert _toy_reuse_pct("mistral-7b-v0.3-l16.serve-longdoc") > 25.0
+
+
+def test_prompts_asked_once_are_prefilled_whole():
+    """``serve-chat`` sends every prompt once: the store finds nothing."""
+    assert _toy_reuse_pct("mistral-7b-v0.3-l16.serve-chat") == 0.0

@@ -554,6 +554,119 @@ def test_serving_program_leaves_cache_in_place(prog, layout, geometry, topo,
 
 
 # --------------------------------------------------------------------------- #
+# the prefix store's two copy programs (ISSUE 49)
+# --------------------------------------------------------------------------- #
+
+
+def _store_engine(topo, geometry, store_pages=0):
+    """(engine on one described chip at one of ``GEOMETRY``'s head
+    geometries, its abstract cache, its abstract page pool or None)."""
+    from picotron_tpu.config import Config
+    from picotron_tpu.inference.engine import InferenceEngine
+    from picotron_tpu.topology import build_topology, named_shardings
+
+    hid, heads, kv_heads, ffn, vocab = GEOMETRY[geometry]
+    cfg = Config.from_dict({
+        "model": dict(hidden_size=hid, intermediate_size=ffn,
+                      num_attention_heads=heads, num_key_value_heads=kv_heads,
+                      vocab_size=1024, num_hidden_layers=SERVE_LAYERS,
+                      max_position_embeddings=SEQ, dtype="bfloat16"),
+        "inference": {"attend_impl": "dense"}})
+    mesh = build_topology(1, 1, 1, 1, devices=topo.devices)
+    eng = InferenceEngine(cfg, mesh, slots=SERVE_SLOTS, max_seq_len=SEQ,
+                          kv_store_pages=store_pages)
+    full = named_shardings(mesh, eng._cspecs)
+    abstract = lambda tree: {
+        n: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=full[n])
+        for n, x in tree.items()}
+    cache = abstract(jax.eval_shape(eng._init_cache_jit))
+    pool = (abstract(jax.eval_shape(eng._init_store_jit))
+            if eng.store is not None else None)
+    return eng, cache, pool
+
+
+@pytest.mark.parametrize("geometry", sorted(GEOMETRY))
+@pytest.mark.parametrize("prog", ["retain", "seat"])
+def test_store_copy_moves_a_strip_and_nothing_else(prog, geometry, topo,
+                                                   one_chip):
+    """Retention (strip -> pool) and the hit (pool -> strip) compile for
+    the chip as one gather or scatter of pages over the pool seen as ONE
+    axis of layer x page, in place: the donated side is aliased to the
+    result, nothing is transposed, no array of a strip's size or more is
+    copied, and the program's temporaries stay under one strip (the flat
+    index; the rows themselves are staged on the chip). SmolLM's packed
+    rows (two heads of 64 a lane row) are pages of the same rows."""
+    eng, cache, pool = _store_engine(topo, geometry)
+    rep = jax.sharding.NamedSharding(eng.topo.mesh,
+                                     jax.sharding.PartitionSpec())
+    arg = lambda shape: jax.ShapeDtypeStruct(shape, I32, sharding=rep)
+    pids = arg((eng.store.max_pages,))
+    assert pool["k"].shape == (SERVE_LAYERS, 1 + 2 * SERVE_SLOTS * MAXP * (
+        PAGE // eng.store.page_len), eng.store.page_len) + cache["k"].shape[3:]
+    if prog == "retain":
+        compiled = eng._retain_jit.lower(pool, cache, arg(()), pids).compile()
+        moved, kept = pool, "pool"
+    else:
+        compiled = eng._seat_jit.lower(cache, pool, arg(()), pids,
+                                       arg(())).compile()
+        moved, kept = cache, "cache"
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    strip = 2 * math.prod(cache["k"].shape) // SERVE_SLOTS * 2  # K, V, bf16
+    donated = 2 * math.prod(moved["k"].shape) * 2
+    assert mem.alias_size_in_bytes >= donated, (
+        f"{prog}: the {kept} is not updated in place")
+    assert mem.temp_size_in_bytes < strip, (
+        f"{prog}: {mem.temp_size_in_bytes / 1e6:.0f} MB of temporaries "
+        f"against a strip of {strip / 1e6:.0f} MB")
+    turned = [l.strip()[:160] for l in text.splitlines()
+              if (m := re.search(r" transpose\(.*dimensions=\{([\d,]*)\}", l))
+              and m.group(1) != ",".join(
+                  map(str, range(m.group(1).count(",") + 1)))]
+    assert not turned, "\n".join(turned)
+    copies = [l.strip()[:160] for l in text.splitlines()
+              if (m := _INSTR.match(l)) and m.group(3) == "copy"
+              and max((math.prod(int(d) for d in dims.split(",") if d)
+                       for dims in _SHAPE.findall(m.group(2))), default=0)
+              >= strip // 4]
+    assert not copies, "\n".join(copies)
+    assert len(re.findall(r" (?:gather|scatter)\(", text)) == 2, (
+        "one gather or scatter a leaf")
+
+
+@pytest.mark.parametrize("prog", ["decode_block", "prefill_chunk"])
+def test_store_leaves_the_serving_programs_as_they_were(prog, topo,
+                                                        one_chip):
+    """The decode and chunk programs of an engine that keeps a store lower
+    to the text of one that keeps none: the store is two programs beside
+    them and an operand of neither."""
+    from picotron_tpu.models import llama
+    from picotron_tpu.topology import named_shardings
+
+    texts = []
+    for store_pages in (0, -1):
+        eng, cache, _ = _store_engine(topo, "mistral", store_pages)
+        assert (eng.store is None) == (store_pages < 0)
+        mesh = eng.topo
+        params = jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            jax.eval_shape(lambda: llama.init_params(jax.random.key(0),
+                                                     eng.cfg.model)),
+            named_shardings(mesh, eng._pspecs))
+        rep = named_shardings(mesh, jax.sharding.PartitionSpec())
+        arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep)
+        if prog == "decode_block":
+            jitted = eng._program("decode_block")
+            args = (arg((6, SERVE_SLOTS), I32),
+                    arg((eng.decode_block_len, 2), jnp.uint32))
+        else:
+            jitted = eng._prefill_chunk_jit
+            args = (arg((1, eng.prefill_chunk), I32),) + (arg((), I32),) * 3
+        texts.append(jitted.lower(params, cache, *args).as_text())
+    assert texts[0] == texts[1]
+
+
+# --------------------------------------------------------------------------- #
 # train-2k's layer: q, k, v and their gradients are never copied whole
 # --------------------------------------------------------------------------- #
 

@@ -186,10 +186,12 @@ def _cfg(tp=1, **inf):
 
 
 def _engines(tp=1, slots=3, **kw):
-    """(contiguous engine, paged engine) over one tiny config."""
+    """(contiguous engine, paged engine) over one tiny config. The
+    contiguous one is the reference: it keeps no prefix store
+    (tests/test_prefix_store.py) and prefills every prompt whole."""
     cfg = _cfg(tp=tp)
     ec = InferenceEngine(cfg, slots=slots, max_seq_len=MAX_LEN,
-                         kv_layout="contiguous", **kw)
+                         kv_layout="contiguous", kv_store_pages=-1, **kw)
     ep = InferenceEngine(cfg, slots=slots, max_seq_len=MAX_LEN,
                          kv_layout="paged", kv_page_len=PAGE, **kw)
     params = ec.shard_params(jax.jit(
