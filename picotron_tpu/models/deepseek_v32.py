@@ -36,6 +36,14 @@ its keys are kept in the model's dtype, not FP8.
 RoPE pairing as published: adjacent pairs in the attention
 (``apply_rope_interleaved``), halves in the indexer (``apply_rope``).
 
+A prefill (a chunk of one slot, or a whole sequence without a cache) selects
+by mask and attends masked under a running softmax over the live key blocks
+(``_attend_selected``: 2,048 different keys for each of its queries is no row
+gather). A decode step (``S == 1`` over the slots' cache) takes the chosen
+keys' row indices (``select_rows``), GATHERS those rows of ``ckv`` and
+attends over them alone (``_attend_rows``): ``min(context, index_topk)`` rows
+of 1,280 B a slot and layer where the walk reads the context.
+
 Every layer function returns, beside the updated cache leaves, what it
 counted (``STATS``: an int32 vector in the order of ``STAT_NAMES``; the
 programs return a row a layer, summed over a block's steps, the engine adds
@@ -68,12 +76,14 @@ from picotron_tpu.ops.attention import NEG_INF
 from picotron_tpu.ops.rmsnorm import rms_norm
 from picotron_tpu.ops.select import (
     KEY_BLOCK,  # keys a block of the indexer's and the attend's walks
+    gather_rows,
     index_scores,
     key_block as _key_block,
     key_blocks as _key_blocks,
     layer_norm as _layer_norm,
     live_blocks as _live_blocks,
     select_keys,
+    select_rows,
 )
 from picotron_tpu.ops.rope import (
     apply_rope,
@@ -82,9 +92,11 @@ from picotron_tpu.ops.rope import (
     yarn_mscale,
 )
 
-# what a layer counts, in the order of the vector (under ``STATS``)
+# what a layer counts, in the order of the vector (under ``STATS``); the
+# last is the latent rows a query's attend read (``min(context, index_topk)``
+# through a decode step's gather, the context through a masked walk)
 STAT_NAMES = expert_share.STAT_NAMES + (
-    "dsa_keys_selected", "dsa_keys_scored")
+    "dsa_keys_selected", "dsa_keys_scored", "dsa_rows_attended")
 
 # queries attended at a time: bounds the [B, S, heads, keys] scores of a key
 # block (``ops/select.py::KEY_BLOCK`` keys, the indexer's blocks)
@@ -296,12 +308,43 @@ def _attend_selected(q, chosen, src: dict, layer, scale: float, rank: int,
     return acc / l[..., None]
 
 
+def _attend_selected_blocks(q, chosen, src: dict, layer, scale: float,
+                            rank: int, pos_q):
+    """``_attend_selected``, ``QUERY_BLOCK`` queries at a time: bounds the
+    [queries, heads, keys of a block] float32 scores of a chunk."""
+    B, S, nh, _ = q.shape
+    Sb = S if S <= QUERY_BLOCK else math.gcd(S, QUERY_BLOCK)
+    if Sb == S:
+        return _attend_selected(q, chosen, src, layer, scale, rank, pos_q)
+
+    def blocks(a):  # [B, S, ...] -> [S / Sb, B, Sb, ...]
+        return jnp.moveaxis(a.reshape(B, S // Sb, Sb, *a.shape[2:]), 1, 0)
+
+    o_lat = lax.map(
+        lambda xs: _attend_selected(xs[0], xs[1], src, layer, scale, rank,
+                                    xs[2]),
+        tuple(blocks(a) for a in (q, chosen, pos_q)))
+    return jnp.moveaxis(o_lat, 0, 1).reshape(B, S, nh, rank)
+
+
+def _attend_rows(q, src: dict, layer, rows, count, scale: float, rank: int):
+    """A decode step's ``q`` [B, 1, heads, row width] over the rows ``rows``
+    [B, n] of its slot's ``ckv`` (the first ``count`` [B] of them are chosen
+    keys; the rest weigh nothing): the rows gathered, then attended densely
+    as one K/V head, float32 softmax: [B, 1, heads, rank]. A row is its own
+    value: of the weighted rows the latent part is kept."""
+    with jax.named_scope("dsa_gather"):
+        got = gather_rows(src["ckv"], layer, rows)[:, :, None]  # [B, n, 1, w]
+    return kv_cache.decode_attention(q, got, got, count, scale)[..., :rank]
+
+
 def attention(lp, x, cos, sin, m: ModelConfig, cache, pos, layer, live):
     """The attention half of a layer on the normed stream ``x`` [B, S, H]:
     (output [B, S, H], the cache dict with this layer's rows written (or,
     without a cache, the rows themselves as a one-layer stack), keys
-    selected, keys scored). ``pos`` [B] is each sequence's first position;
-    ``live`` [B, S] marks the queries that are counted."""
+    selected, keys scored, latent rows attended). ``pos`` [B] is each
+    sequence's first position; ``live`` [B, S] marks the queries that are
+    counted."""
     B, S, _ = x.shape
     nh, dn, dr = m.num_attention_heads, m.qk_nope_head_dim, m.qk_rope_head_dim
     dv, R = m.v_head_dim, m.kv_lora_rank
@@ -342,6 +385,7 @@ def attention(lp, x, cos, sin, m: ModelConfig, cache, pos, layer, live):
             * (m.index_n_heads ** -0.5 * m.index_head_dim ** -0.5)
 
     rows = {"ckv": jnp.concatenate([ckv, kr, lanes], axis=-1), "ki": ki}
+    decode = S == 1 and cache is not None and "slot" not in cache
     if cache is None:
         # a whole sequence at once: its own rows are the keys
         src = {n: r[None] for n, r in rows.items()}
@@ -353,32 +397,31 @@ def attention(lp, x, cos, sin, m: ModelConfig, cache, pos, layer, live):
 
     with jax.named_scope("dsa_index"):
         scores = index_scores(qi, wi, src, layer, pos_q, KEY_BLOCK)
-    with jax.named_scope("dsa_select"):
-        chosen = select_keys(scores, m.index_topk)
+    scale = softmax_scale(m)
+    if decode:
+        with jax.named_scope("dsa_select"):
+            # [1, slots, T]: the slots beside the keys fill a register
+            picked, count = select_rows(scores.swapaxes(0, 1), m.index_topk)
+        with jax.named_scope("mla_attend"):
+            o_lat = _attend_rows(q_cat, src, layer, picked[0], count[0],
+                                 scale, R)
+    else:
+        with jax.named_scope("dsa_select"):
+            chosen = select_keys(scores, m.index_topk)
+        with jax.named_scope("mla_attend"):
+            o_lat = _attend_selected_blocks(q_cat, chosen, src, layer, scale,
+                                            R, pos_q)
     with jax.named_scope("mla_attend"):
-        scale = softmax_scale(m)
-        Sb = S if S <= QUERY_BLOCK else math.gcd(S, QUERY_BLOCK)
-        if Sb == S:
-            o_lat = _attend_selected(q_cat, chosen, src, layer, scale, R,
-                                     pos_q)
-        else:
-            def blocks(a):  # [B, S, ...] -> [S / Sb, B, Sb, ...]
-                return jnp.moveaxis(
-                    a.reshape(B, S // Sb, Sb, *a.shape[2:]), 1, 0)
-
-            o_lat = lax.map(
-                lambda xs: _attend_selected(xs[0], xs[1], src, layer, scale,
-                                            R, xs[2]),
-                tuple(blocks(a) for a in (q_cat, chosen, pos_q)))
-            o_lat = jnp.moveaxis(o_lat, 0, 1).reshape(B, S, nh, R)
         o = jnp.einsum("bshc,chd->bshd", o_lat.astype(x.dtype),
                        wkv_b[..., dn:])
         out = o.reshape(B, S, nh * dv) @ lp["wo"]
 
-    selected = jnp.sum(jnp.where(live[..., None], chosen, False),
-                       dtype=jnp.int32)
+    kept = jnp.where(live[:, 0], count[0], 0) if decode \
+        else jnp.where(live[..., None], chosen, False)
+    selected = jnp.sum(kept, dtype=jnp.int32)
     scored = jnp.sum(jnp.where(live, pos_q + 1, 0), dtype=jnp.int32)
-    return out, src, selected, scored
+    # a decode step reads the rows it chose, a walk every live key block
+    return out, src, selected, scored, selected if decode else scored
 
 
 # --------------------------------------------------------------------------- #
@@ -430,7 +473,7 @@ def _layer(lp, h, cos, sin, cfg: Config, cache, pos, return_kv, layer,
         live = jnp.ones(h.shape[:2], bool)
     attn_cache = None if cache is None else {
         n: v for n, v in cache.items() if n != "live"}
-    a, src, selected, scored = attention(
+    a, src, *counted = attention(
         lp, rms_norm(h, lp["attn_norm"], m.rms_norm_eps), cos, sin, m,
         attn_cache, pos, layer, live)
     h = h + a
@@ -442,7 +485,7 @@ def _layer(lp, h, cos, sin, cfg: Config, cache, pos, return_kv, layer,
     else:
         y, moe = expert_mlp(lp, x, m, live)
         h = h + y
-    stats = jnp.stack(moe + (selected, scored))
+    stats = jnp.stack(moe + tuple(counted))
     if return_kv:
         # the sequence's rows, [1, S, width] each: a prefill's blocks
         out = {n: src[n][0] for n in kv_cache.LATENT_LEAVES}

@@ -51,11 +51,12 @@ A prefill chunk (a ``slot`` entry) writes its rows, scores every live key a
 block at a time, selects by mask and attends masked under a running softmax
 over the slot's live key blocks (``_attend_chosen``; exact). A decode step
 (``S == 1``) scores the slots' live ``ki``, takes the chosen keys' row
-indices (``select_rows``), GATHERS those rows out of ``kv`` and attends over
-them alone (``_attend_rows``): ``min(context, topk)`` rows of 2 KB a slot and
-layer where the masked walk reads the context. Every layer
-function returns, beside the updated cache leaves, what it counted
-(``STATS``, in the order of ``STAT_NAMES``; docs/OBSERVABILITY.md).
+indices (``select_rows``), GATHERS those rows out of ``kv``
+(``select.gather_rows``) and attends over them alone (``_attend_rows``):
+``min(context, topk)`` rows of 2 KB a slot and layer where the masked walk
+reads the context. Every layer function returns, beside the updated cache
+leaves, what it counted (``STATS``, in the order of ``STAT_NAMES``;
+docs/OBSERVABILITY.md).
 """
 
 from __future__ import annotations
@@ -76,6 +77,9 @@ from picotron_tpu.ops import select
 from picotron_tpu.ops.attention import NEG_INF
 from picotron_tpu.ops.rmsnorm import rms_norm
 from picotron_tpu.ops.rope import apply_rope, mrope_rows, precompute_rope
+# under this module's name too: ``_attend_rows`` calls it by that, and the
+# controls wrap it there (benchmarks/tests/control_keye.py)
+from picotron_tpu.ops.select import gather_rows
 
 # what a layer counts: the expert share's, the selection's under the names
 # the DeepSeek block counts them, and the K/V rows a query's attend read
@@ -369,15 +373,6 @@ def _attend_chosen_blocks(q, chosen, src: dict, layer, scale: float, pos_q):
         lambda xs: _attend_chosen(xs[0], xs[1], src, layer, scale, xs[2]),
         tuple(blocks(a) for a in (q, chosen, pos_q)))
     return jnp.moveaxis(out, 0, 1).reshape(B, S, nh, D)
-
-
-def gather_rows(leaf, layer, rows):
-    """Rows ``rows`` [B, n] of each slot's strip of ``layer`` of a stacked
-    leaf [layers, slots, T, ...], fetched where they lie: [B, n, ...]. A
-    row gather along the token axis (an embedding's lookup, a row 2 KB):
-    nothing else of the layer is read."""
-    return leaf[jnp.asarray(layer, jnp.int32),
-                jnp.arange(leaf.shape[1])[:, None], rows]
 
 
 def _attend_rows(q, src: dict, layer, rows, count, scale: float):

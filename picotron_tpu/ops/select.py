@@ -1,10 +1,11 @@
 """Exact top-k of a row of scores without a sort, as a mask or as row
 indices: what a sparse selection chooses its keys with
 (``models/deepseek_v32.py``, ``models/keye_vl2.py``: keys by the indexer's
-scores; ``models/minicpm_sala.py``: key blocks by their pooled scores). And
-the learned indexer's scoring of a window's cached keys, which the two blocks
-that have one share (``index_scores``, its walk over the live key blocks, its
-LayerNorm)."""
+scores; ``models/minicpm_sala.py``: key blocks by their pooled scores), and
+the gather of the rows so chosen (``gather_rows``: a decode step of either
+block). And the learned indexer's scoring of a window's cached keys, which
+the two blocks that have one share (``index_scores``, its walk over the live
+key blocks, its LayerNorm)."""
 
 from __future__ import annotations
 
@@ -151,6 +152,16 @@ def select_rows(scores, k: int) -> tuple:
     return chosen_rows((above | (ties & first)) & valid, k)
 
 
+def gather_rows(leaf, layer, rows):
+    """Rows ``rows`` [B, n] of each slot's strip of ``layer`` of a stacked
+    leaf [layers, slots, T, ...], fetched where they lie: [B, n, ...]. A
+    row gather along the token axis (an embedding's lookup; a row is 2 KB of
+    ``keye_vl2``'s K/V, 1,280 B of ``deepseek_v32``'s latent): nothing else
+    of the layer is read."""
+    return leaf[jnp.asarray(layer, jnp.int32),
+                jnp.arange(leaf.shape[1])[:, None], rows]
+
+
 # --------------------------------------------------------------------------- #
 # the indexer: its LayerNorm, its scores of a window's cached keys
 # --------------------------------------------------------------------------- #
@@ -203,7 +214,6 @@ def index_scores(qi, wi, src: dict, layer, pos_q, block: int = KEY_BLOCK):
     lanes of a row that is zero elsewhere, once for each of the ``p`` places
     (an exact zero times a finite key adds an exact zero), and the ``p``
     score rows are laid one key after the other."""
-    B, S = pos_q.shape
     D = qi.shape[-1]
     p = src["ki"].shape[3] // D
     T = src["ki"].shape[2] * p
@@ -211,44 +221,32 @@ def index_scores(qi, wi, src: dict, layer, pos_q, block: int = KEY_BLOCK):
     if p > 1:
         return _index_scores_packed(qi, wi, src, layer, pos_q, Tb, p)
 
-    def body(j, buf):
+    def scored(j):
         kb = key_block(src, "ki", layer, j * Tb, Tb)  # [B, Tb, D]
         s = jnp.einsum("bshd,btd->bsht", qi, kb,
                        preferred_element_type=jnp.float32)
-        s = jnp.sum(jax.nn.relu(s) * wi[..., None], axis=2)  # [B, S, Tb]
-        t = j * Tb + jnp.arange(Tb, dtype=jnp.int32)
-        s = jnp.where(t[None, None, :] <= pos_q[..., None], s, -jnp.inf)
-        return lax.dynamic_update_slice(buf, s, (0, 0, j * Tb))
+        return jnp.sum(jax.nn.relu(s) * wi[..., None], axis=2)  # [B, S, Tb]
 
-    buf = jnp.full((B, S, T), -jnp.inf, jnp.float32)
-    if T == Tb:
-        return body(0, buf)
-    return lax.fori_loop(0, live_blocks(pos_q, T, Tb), body, buf)
+    return _walk(scored, pos_q, T, Tb)
 
 
-def _index_scores_packed(qi, wi, src: dict, layer, pos_q, Tb: int, p: int):
-    """``index_scores`` over a ``ki`` leaf of ``p`` keys a row. A decode
-    step's scores (S == 1) are gathered queries-major, [S, B, T], and handed
-    back [B, S, T]: with the slots beside the keys the two minor axes fill a
-    register, where [slots, 1, T] lies a row of 128 a register, an eighth
-    full, and its block writes and masks cost three times the keys' read (a
-    caller that swaps the axes back, ``keye_vl2.attention``, moves
-    nothing). A chunk's (B == 1) are gathered as they are returned."""
-    B, S, h, D = qi.shape
-    T = src["ki"].shape[2] * p
-    # [B, S, p, heads, p x D]: place i holds the head in lanes i x D ..
-    place = jnp.eye(p, dtype=bool)[:, None, :, None]  # [p, 1, p, 1]
-    qi = jnp.where(place, qi[:, :, None, :, None, :], 0).reshape(
-        B, S, p, h, p * D)
+def _walk(scored, pos_q, T: int, Tb: int):
+    """[B, S, T] float32: ``scored(j)`` [B, S, Tb], the scores of the
+    ``j``-th block of ``Tb`` keys, for every block a query at ``pos_q`` can
+    see; -inf past a query's own position. A decode step's scores (S == 1)
+    are gathered queries-major, [S, B, T], and handed back [B, S, T]: with
+    the slots beside the keys the two minor axes fill a register, where
+    [slots, 1, T] lies a row of 128 a register, an eighth full, and its
+    block writes and masks cost three times the keys' read (a caller that
+    swaps the axes back, a decode step of ``keye_vl2.attention`` or
+    ``deepseek_v32.attention``, moves nothing). A chunk's (B == 1) are
+    gathered as they are returned."""
+    B, S = pos_q.shape
     swap = S == 1
     pos = pos_q.T if swap else pos_q
 
     def body(j, buf):
-        kb = key_block(src, "ki", layer, j * (Tb // p), Tb // p)
-        s = jnp.einsum("bsphd,btd->bstph", qi, kb,
-                       preferred_element_type=jnp.float32)
-        s = jnp.sum(jax.nn.relu(s) * wi[:, :, None, None, :], axis=-1)
-        s = s.reshape(B, S, Tb)  # key = row x p + place
+        s = scored(j)
         s = s.swapaxes(0, 1) if swap else s
         t = j * Tb + jnp.arange(Tb, dtype=jnp.int32)
         s = jnp.where(t[None, None, :] <= pos[..., None], s, -jnp.inf)
@@ -260,3 +258,22 @@ def _index_scores_packed(qi, wi, src: dict, layer, pos_q, Tb: int, p: int):
     else:
         buf = lax.fori_loop(0, live_blocks(pos_q, T, Tb), body, buf)
     return buf.swapaxes(0, 1) if swap else buf
+
+
+def _index_scores_packed(qi, wi, src: dict, layer, pos_q, Tb: int, p: int):
+    """``index_scores`` over a ``ki`` leaf of ``p`` keys a row."""
+    B, S, h, D = qi.shape
+    T = src["ki"].shape[2] * p
+    # [B, S, p, heads, p x D]: place i holds the head in lanes i x D ..
+    place = jnp.eye(p, dtype=bool)[:, None, :, None]  # [p, 1, p, 1]
+    qi = jnp.where(place, qi[:, :, None, :, None, :], 0).reshape(
+        B, S, p, h, p * D)
+
+    def scored(j):
+        kb = key_block(src, "ki", layer, j * (Tb // p), Tb // p)
+        s = jnp.einsum("bsphd,btd->bstph", qi, kb,
+                       preferred_element_type=jnp.float32)
+        s = jnp.sum(jax.nn.relu(s) * wi[:, :, None, None, :], axis=-1)
+        return s.reshape(B, S, Tb)  # key = row x p + place
+
+    return _walk(scored, pos_q, T, Tb)

@@ -864,6 +864,15 @@ def test_latent_cache_is_row_major_and_never_copied(prog, topo, one_chip,
               if re.search(r"= bf16\[(?:1,)?8,(?:7168,2048|2048,7168)\]", l)
               and " parameter(" not in l and "get-tuple-element" not in l]
     assert not sliced, "\n".join(sliced)
+    # a decode step GATHERS the rows it chose (one gather of 16,384 latent
+    # rows a layer) and never slices a key block of latent rows out of the
+    # leaf; a chunk walks the live key blocks under its mask and never gathers
+    gathers = [l for l in lines
+               if re.search(r"= bf16\[8,2048,(?:1,)?640\]\S* gather\(", l)]
+    assert (len(gathers) == 2) == (prog == "decode_block"), gathers
+    walked = [l for l in lines if re.search(
+        r"%\S*dynamic-slice\S* = bf16\[(?:1,)?[18],2048,640\]", l)]
+    assert bool(walked) == (prog == "prefill_chunk"), walked[:4]
 
 
 # ---- the recurrent state of the Granite-4.0-H block (PR 32) -----------------

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from engine_memo import memoized
+from engine_memo import admit, memoized
 
 from picotron_tpu.config import Config
 from picotron_tpu.inference import InferenceEngine
@@ -132,11 +132,15 @@ def test_prefill_and_decode_match_the_reference(n_prompt, chunk, select):
     want = reference_rows(params, seq, n_prompt, TOY, select=select)
     assert worst_rel_err(got, want) < 1e-3
     # what the programs counted: every query selects min(topk, t + 1) of
-    # the t + 1 keys it scored, in each of the three layers
+    # the t + 1 keys it scored, in each of the three layers; a prefill's
+    # queries attend under a mask over their context's rows, a decode step
+    # reads the rows it chose and no other (none takes the walk)
     stats = dict(zip(dsv.STAT_NAMES, engine.take_stats()))
     t = np.arange(n_prompt + 4)
     assert stats["dsa_keys_selected"] == 3 * np.minimum(16, t + 1).sum()
     assert stats["dsa_keys_scored"] == 3 * (t + 1).sum()
+    assert stats["dsa_rows_attended"] == 3 * (
+        (t[:n_prompt] + 1).sum() + np.minimum(16, t[n_prompt:] + 1).sum())
 
 
 def test_selection_matters_past_index_topk():
@@ -172,7 +176,104 @@ def test_decode_block_counts_and_matches_single_steps():
     # never), and selects index_topk of its keys
     assert stats["moe_layer_steps"] == 16
     assert stats["moe_expert_rows"] == 16 * 2 * 2
-    assert stats["dsa_keys_selected"] == 8 * 3 * 16
+    assert stats["dsa_rows_attended"] == stats["dsa_keys_selected"] \
+        == 8 * 3 * 16
+
+
+def test_a_decode_step_with_contexts_on_both_sides_of_index_topk():
+    """One slot at 12 tokens (under ``index_topk`` 16: every key kept, the
+    gathered rows past its count are row 0 again and must weigh nothing),
+    one at 70 (16 of its keys), decoded side by side."""
+    _, engine, params = make_engine()
+    short, long = PROMPT[:12], PROMPT[5:] + PROMPT[:5]
+    cache, last_s = admit(engine, params, engine.init_cache(), short, slot=0)
+    cache, last_l = admit(engine, params, cache, long, slot=1)
+    seqs = [short + [int(np.argmax(last_s))], long + [int(np.argmax(last_l))]]
+    got = [[], []]
+    for _ in range(3):
+        toks = np.asarray([seq[-1] for seq in seqs], np.int32)
+        cache, _, logits = engine.decode_step(
+            params, cache, toks, jax.random.PRNGKey(0),
+            np.zeros(2, np.float32), np.zeros(2, np.int32),
+            np.ones(2, np.float32))
+        for b, row in enumerate(np.asarray(logits, np.float32)):
+            got[b].append(row)
+            seqs[b].append(int(np.argmax(row)))
+    for b, n in enumerate((12, 70)):
+        want = reference_rows(params, seqs[b][:-1], n + 1, TOY)
+        assert worst_rel_err(got[b], want) < 1e-3, b
+    engine.take_stats()
+
+
+def _one_layer_cache(S=40, B=2):
+    """(layer parameters, a one-layer latent cache of ``B`` slots holding
+    ``S`` rows each, written by the block's own chunk path, the tables)."""
+    m = make_config().model
+    params = jax.jit(lambda k: dsv.init_params(k, m))(jax.random.PRNGKey(2))
+    lp = jax.tree.map(lambda v: v[0], params["layers"])
+    cache = dsv.init_cache(m, B, 64)
+    cos, sin = dsv.serving_rope_tables(m, 64, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(4), (B, S, m.hidden_size))
+    leaves = {n: cache[n][:1] for n in ("ckv", "ki")}
+    for b in range(B):
+        leaves = {n: v for n, v in dsv.attention(
+            lp, x[b:b + 1], cos[:S], sin[:S], m,
+            {**leaves, "slot": jnp.int32(b)}, jnp.zeros((1,), jnp.int32), 0,
+            jnp.ones((1, S), bool))[1].items() if n != "slot"}
+    return m, lp, leaves, (cos, sin)
+
+
+def test_a_chunk_walks_and_a_decode_step_gathers(monkeypatch):
+    """The rule is the call's shapes: a prefill (a chunk of one slot, or a
+    whole sequence without a cache) never calls the gather, a decode step
+    never calls ``_attend_selected``."""
+    calls = []
+
+    def spy(name):
+        real = getattr(dsv, name)
+
+        def spying(*args):
+            calls.append(name)
+            return real(*args)
+
+        monkeypatch.setattr(dsv, name, spying)
+
+    spy("gather_rows")
+    spy("_attend_selected")
+    m, lp, leaves, (cos, sin) = _one_layer_cache()  # two chunks
+    assert calls == ["_attend_selected"] * 2
+    del calls[:]
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 1, m.hidden_size))
+    out, _, selected, scored, attended = dsv.attention(
+        lp, x, cos[40][None, None], sin[40][None, None], m, dict(leaves),
+        jnp.asarray([40, 40], jnp.int32), 0, jnp.ones((2, 1), bool))
+    assert calls == ["gather_rows"]
+    assert (int(selected), int(scored), int(attended)) == (32, 82, 32)
+    del calls[:]
+    *_, attended = dsv.attention(
+        lp, x[:1].repeat(40, axis=1), cos[:40], sin[:40], m, None, None,
+        None, jnp.ones((1, 40), bool))
+    assert calls == ["_attend_selected"]
+    assert int(attended) == 40 * 41 // 2
+
+
+def test_a_gather_off_by_one_row_fails_the_float32_check(monkeypatch):
+    """``correct``'s kind of check can see a wrong gather: with every row
+    index of the decode steps shifted by one, the logits of the four decode
+    steps leave the float32 reference (the prefill's do not: a chunk never
+    gathers)."""
+    _, sound, params = make_engine()
+    seq, got = program_logits(sound, params, PROMPT)
+    want = reference_rows(params, seq, len(PROMPT), TOY)
+    assert worst_rel_err(got, want) < 1e-3
+    real = dsv.gather_rows
+    monkeypatch.setattr(dsv, "gather_rows",
+                        lambda leaf, layer, rows: real(leaf, layer, rows + 1))
+    _, engine, _ = make_engine(fresh=True)  # traced under the fault
+    _, faulty = program_logits(engine, params, PROMPT,
+                               follow=seq[len(PROMPT):])
+    assert worst_rel_err(faulty[:1], want[:1]) < 1e-3
+    assert worst_rel_err(faulty[1:], want[1:]) > 1e-2
 
 
 # ---- (b) the share adds up to the uncut layer ------------------------------
@@ -556,6 +657,31 @@ def test_rehearsal_of_the_cell_computes_its_readers():
     assert out["device"]["platform"] == "cpu"
     assert {"serve_out_tokens_per_s", "serve_itl_p99_ms", "setup_s",
             "moe.held_assignments_per_step", "dsa.selected_pct",
+            "dsa.attended_rows_pct.dsv32",
             "batcher.dispatch_gap_ms", "batcher.plan_ms",
             "batcher.deliver_ms", "front.loop_lock_wait_ms",
             "front.results_ms"} <= set(out["computed"])
+    # Keye's readers take the counter this block now shares as the mark of
+    # Keye's block: ``run.py`` confines each to its own cells
+    assert not [n for n in out["computed"] if n.endswith(".keye")]
+
+
+def test_the_reader_gives_nothing_for_a_program_without_the_counter():
+    """The parent's program: the block's two older counters, and no
+    ``picotron_dsa_rows_attended_total``."""
+    sys.path.insert(0, ROOT)
+    from benchmarks.run import load_reader
+
+    read = load_reader("layer_metrics", "dsa.attended_rows_pct.dsv32")
+    scrape = ("picotron_dsa_keys_scored_total {}\n"
+              "picotron_dsa_keys_selected_total {}\n")
+    run = {"metrics_before": scrape.format(1000, 500),
+           "metrics_after": scrape.format(9000, 1500)}
+    assert read(run) is None
+    assert read({}) is None
+    attended = "picotron_dsa_rows_attended_total {}\n"
+    run = {"metrics_before": run["metrics_before"] + attended.format(500),
+           "metrics_after": run["metrics_after"] + attended.format(1500)}
+    assert read(run) == pytest.approx(12.5)
+    run["metrics_after"] = run["metrics_before"]  # no step in the window
+    assert read(run) is None
