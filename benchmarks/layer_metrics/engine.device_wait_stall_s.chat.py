@@ -1,0 +1,8 @@
+"""``engine.device_wait_stall_s`` for the cells that report
+``serve_tpot_mean_ms``."""
+
+from benchmarks import stalls
+
+
+def read(run):
+    return stalls.stall_s(run, stalls.DEVICE_WAIT)

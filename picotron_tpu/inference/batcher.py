@@ -92,9 +92,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from picotron_tpu.inference import sampling
-from picotron_tpu.obs import RoundPhases
+from picotron_tpu.obs import RoundPhases, admit_key
 from picotron_tpu.resilience.retry import retry
 from picotron_tpu.utils import log0
+
+
+def _judged_as(kind: str, lane) -> str:
+    """The stall judge's key for a round's ``step/issue`` and ``step/sync``:
+    the kind of round, and whether a fused prefill lane rode it."""
+    return kind + "+lane" if lane else kind
 
 
 def _sid(span) -> Optional[int]:
@@ -381,24 +387,23 @@ class ContinuousBatcher:
         self._epoch = np.zeros(n, np.int64)
         self._inflight = None   # issued-not-yet-synced round record
         self._dev_last = None   # device-resident [slots] last-token row
-        self._round_seq = 0     # issued rounds (span labels)
+        self._round_seq = 0     # issues so far (span labels, /statz stalls)
         # scheduling-gap instrumentation (BOTH modes): host time between
         # one round's sync end and the next issue — what overlap exists
         # to hide. 0.0 whenever a round is still in flight at issue.
         self._t_last_sync_end = None
-        self._step_sync_wait = 0.0    # per-step blocked-on-device time
         self._ov_device_s = 0.0       # summed issue -> sync-end windows
         self._ov_t0 = None            # first issue (efficiency wall start)
         self._ov_t1 = None            # last sync end (efficiency wall end)
         self._gap_hist = reg.histogram(
             "picotron_dispatch_gap_seconds",
             "issue-to-issue scheduling gap net of device time")
-        self._host_work_hist = reg.histogram(
-            "picotron_host_work_seconds",
-            "per-round host scheduling work (step wall minus sync wait)")
         # the round's wall time tiled into step/plan, step/admit,
-        # step/issue, step/sync, step/deliver (docs/OBSERVABILITY.md)
+        # step/issue, step/sync, step/deliver (docs/OBSERVABILITY.md), each
+        # judged against its own recent past ("Stalls", same document)
         self._phases = RoundPhases(self.obs)
+        self.obs.stalls.register("step/plan", "step/admit", "step/issue",
+                                 "step/sync", "step/deliver")
         # what the block's layers count (``STAT_NAMES``; none of the Llama
         # block), added where a round is delivered
         self._model_counters = [
@@ -831,17 +836,19 @@ class ContinuousBatcher:
             d["last_host_sync_s"] = self._host_sync_s
             d["last_prefill"] = dict(self._last_prefill)
         # what /statz shows of the scheduling gap (obs-smoke reads it):
-        # issue-to-issue gap and per-round host work percentiles from the
-        # histograms' retained samples, plus the device-busy fraction
+        # issue-to-issue gap percentiles from the histogram's retained
+        # samples, plus the device-busy fraction
         ov = dict(enabled=self._overlap,
-                  dispatch_gap_s=self._gap_hist.percentiles(),
-                  host_work_s=self._host_work_hist.percentiles())
+                  dispatch_gap_s=self._gap_hist.percentiles())
         if self._ov_t0 is not None and self._ov_t1 is not None:
             wall = max(self._ov_t1 - self._ov_t0, 1e-9)
             ov["device_busy_s"] = self._ov_device_s
             ov["wall_s"] = wall
             ov["overlap_efficiency"] = min(1.0, self._ov_device_s / wall)
         d["overlap"] = ov
+        # the stall judge's totals a phase and its slowest rounds' records
+        # (docs/OBSERVABILITY.md "Stalls")
+        d["stalls"] = self.obs.stalls.stats()
         # mixed prefill–decode dispatch: whether the fused lane family is
         # compiled in, and how many shard lanes are mid-prompt right now
         d["mixed"] = dict(
@@ -1817,16 +1824,27 @@ class ContinuousBatcher:
             else:
                 self._step_serial()
         finally:
-            self._phases.close()
+            self._phases.close(self._round_seq, self._round_facts)
+
+    def _round_facts(self) -> dict:
+        """What a slow round's record says of the batcher (asked for only
+        when the stall judge keeps one)."""
+        return {"live_slots": sum(s is not None for s in self._slots),
+                "prompt_tokens": self._round_prefill_tokens}
 
     def _admit_phase(self) -> None:
+        """``_admit`` as its phase, judged under what it dispatched: the
+        prefill programs and the prompt rows they ran (``admit_key``)."""
         self._phases.to("step/admit")
+        n0 = self.prefill_dispatches
+        rows0 = self._prefill_tokens_total.value
         self._admit()
+        n = self.prefill_dispatches - n0
+        self._phases.key("step/admit", admit_key(
+            n, self._prefill_tokens_total.value - rows0 if n else 0))
         self._phases.to("step/plan")
 
     def _step_serial(self) -> None:
-        t_step0 = self._clock()
-        self._step_sync_wait = 0.0
         self._expire_deadlines()
         self._rebalance()
         self._admit_phase()
@@ -1859,9 +1877,10 @@ class ContinuousBatcher:
                 # one program advances the chain by the block's links and
                 # the keys stay on the device: nothing here waits for it
                 self._key, keys = self.engine.round_keys(self._key)
+            kind = _judged_as("decode", feeds)
 
             def dispatch(b):
-                self._phases.to("step/issue")
+                self._phases.to("step/issue", kind)
                 t0 = self._clock()
                 self._note_issue(t0)
                 res = self.engine.decode_block(
@@ -1877,7 +1896,7 @@ class ContinuousBatcher:
                 # (_last_tok, updated by the walk, stays authoritative)
                 self._cache, self._lane_scratch = res.cache, res.lane
                 self.decode_dispatches += 1
-                self._phases.to("step/sync")
+                self._phases.to("step/sync", kind)
                 t_sync = self._clock()
                 out = self._sync_outputs(res)
                 self._merge_hidden(res.hidden, out[1])
@@ -1887,7 +1906,6 @@ class ContinuousBatcher:
                 dt_sync = t1 - t_sync
                 with self._scratch_mu:
                     self._host_sync_s = dt_sync
-                self._step_sync_wait += dt_sync
                 self._note_sync_end(t0, t1)
                 self.engine.observe_dispatch("decode", t1 - t0)
                 self.obs.tracer.record(
@@ -1910,8 +1928,6 @@ class ContinuousBatcher:
                 self._finish(i, "error")
         self._deliver_round(toks, counts)
         self._lane_land(feeds)
-        self._host_work_hist.observe(
-            max(0.0, self._clock() - t_step0 - self._step_sync_wait))
 
     # ---- overlapped (zero-bubble) scheduling ------------------------------
 
@@ -1932,7 +1948,9 @@ class ContinuousBatcher:
         the previous round's sync end and this issue — the bubble overlap
         exists to close. While a round is still in flight at issue the
         pipeline is gapless by construction (0.0). Feeds the
-        picotron_dispatch_gap_seconds histogram and /statz ``overlap``."""
+        picotron_dispatch_gap_seconds histogram and /statz ``overlap``.
+        Every issue, serial or pipelined, takes the next ``_round_seq``."""
+        self._round_seq += 1
         if self._ov_t0 is None:
             self._ov_t0 = t0
         if self._inflight is not None:
@@ -1983,8 +2001,6 @@ class ContinuousBatcher:
         always used. With no occupied slots the in-flight round drains
         and the pipeline empties (serve.py's shutdown loop relies on
         ``busy`` covering the in-flight record)."""
-        t_step0 = self._clock()
-        self._step_sync_wait = 0.0
         self._expire_deadlines()
         self._rebalance_overlap()
         self._admit_phase()
@@ -2002,8 +2018,6 @@ class ContinuousBatcher:
         rec = self._issue_round(budget)
         self._sync_inflight(next_t0=None if rec is None else rec["t0"])
         self._inflight = rec
-        self._host_work_hist.observe(
-            max(0.0, self._clock() - t_step0 - self._step_sync_wait))
 
     def _issue_round(self, budget):
         """Build and ISSUE one decode/verify dispatch without touching its
@@ -2054,7 +2068,8 @@ class ContinuousBatcher:
                     self._eos, b, self._temp, self._top_k, self._top_p,
                     draft_len=spec_lens, adapter_ids=adapter, lead=lead,
                     lanes=lanes)
-        self._phases.to("step/issue")
+        judged = _judged_as(kind, feeds)
+        self._phases.to("step/issue", judged)
         t0 = self._clock()
         self._note_issue(t0)
         epochs = self._epoch.copy()
@@ -2068,9 +2083,8 @@ class ContinuousBatcher:
             return None
         self._cache, self._dev_last = out.cache, out.next_tok
         self.decode_dispatches += 1
-        self._round_seq += 1
         self._phases.to("step/plan")  # until the drain's sync claims it
-        return dict(kind=kind, t_round=t_round, t0=t0,
+        return dict(kind=kind, judged=judged, t_round=t_round, t0=t0,
                     budget=budget, epochs=epochs, res=out, hid=out.hidden,
                     spec_lens=spec_lens, spec_kinds=spec_kinds,
                     # lane futures + feed records: the sync stage lands
@@ -2100,15 +2114,17 @@ class ContinuousBatcher:
         g = self.engine.spec_len
         self._lane_scratch = None
 
+        judged = _judged_as(kind, feeds)
+
         def dispatch(b):
-            self._phases.to("step/issue")
+            self._phases.to("step/issue", judged)
             t0 = self._clock()
             self._note_issue(t0)
             res = issue(b, self._dev_tok())
             self._cache, self._lane_scratch = res.cache, res.lane
             self._dev_last = res.next_tok
             self.decode_dispatches += 1
-            self._phases.to("step/sync")
+            self._phases.to("step/sync", judged)
             t_sync = self._clock()
             outs = self._sync_outputs(res)
             # deferred page-table advance (engine.defer_advance): lands
@@ -2121,7 +2137,6 @@ class ContinuousBatcher:
             dt_sync = t1 - t_sync
             with self._scratch_mu:
                 self._host_sync_s = dt_sync
-            self._step_sync_wait += dt_sync
             self._note_sync_end(t0, t1)
             self.engine.observe_dispatch(kind, t1 - t0)
             args = dict(slots=int(np.count_nonzero(np.asarray(b) > 0)),
@@ -2168,7 +2183,7 @@ class ContinuousBatcher:
         if rec is None:
             return
         kind = rec["kind"]
-        self._phases.to("step/sync")
+        self._phases.to("step/sync", rec["judged"])
         t_sync = self._clock()
         try:
             toks, counts, accepted = self._sync_outputs(rec["res"])
@@ -2197,7 +2212,6 @@ class ContinuousBatcher:
         dt_sync = t1 - t_sync
         with self._scratch_mu:
             self._host_sync_s = dt_sync
-        self._step_sync_wait += dt_sync
         self._note_sync_end(rec["t0"], t1)
         live = ((rec["epochs"] == self._epoch)
                 & np.array([s is not None for s in self._slots]))
@@ -2471,8 +2485,10 @@ class ContinuousBatcher:
         key = (self._base_keys if self._sched == "slot"
                else self._split())
 
+        judged = _judged_as("verify", lanes is not None)
+
         def dispatch(b):
-            self._phases.to("step/issue")
+            self._phases.to("step/issue", judged)
             t0 = self._clock()
             self._note_issue(t0)
             res = self.engine.verify(
@@ -2484,7 +2500,7 @@ class ContinuousBatcher:
             # overlap feed) is ignored here — see step()'s closure
             self._cache, self._lane_scratch = res.cache, res.lane
             self.decode_dispatches += 1
-            self._phases.to("step/sync")
+            self._phases.to("step/sync", judged)
             t_sync = self._clock()
             out = self._sync_outputs(res)
             self._merge_hidden(res.hidden, out[1])
@@ -2493,7 +2509,6 @@ class ContinuousBatcher:
             dt_sync = t1 - t_sync
             with self._scratch_mu:
                 self._host_sync_s = dt_sync
-            self._step_sync_wait += dt_sync
             self._note_sync_end(t0, t1)
             self.engine.observe_dispatch("verify", t1 - t0)
             self.obs.tracer.record(

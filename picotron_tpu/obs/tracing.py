@@ -25,6 +25,11 @@ Three record styles:
 ``instant()`` records zero-duration marks (trace-time collective logs
 from ``comm_trace``).
 
+``pin(t0, t1, **label)`` copies the ring's spans that ended in a window to
+a bounded list beside the ring (the stall judge's slow rounds,
+``obs/stalls.py``): ``chrome_trace()`` renders them with the ring's own
+after the ring has turned over.
+
 While a ``jax.profiler`` capture is open (``obs.profiler.ProfileCapture``
 sets the module flag below) a SCOPED span is written twice: into the ring
 as always, and as a ``jax.profiler.TraceAnnotation`` into the capture's
@@ -55,6 +60,9 @@ from collections import deque
 from typing import Optional
 
 DEFAULT_RING = 4096
+# Slow rounds whose spans are kept beside the ring (``SpanTracer.pin``): as
+# many as the stall judge keeps records of (``obs/stalls.py::TABLE``).
+PINNED_ROUNDS = 16
 
 LOOP_PREFIX = "pt:"      # scoped spans of a claimed loop thread
 REQ_PREFIX = "pt.req:"   # scoped spans of any other thread
@@ -119,6 +127,9 @@ class SpanTracer:
         self._clock = clock
         self._next_id = 1
         self._ring: deque = deque(maxlen=max(1, int(ring)))
+        # (label, spans) of the windows pinned: copies out of the ring that
+        # outlive its turning over
+        self._pinned: deque = deque(maxlen=PINNED_ROUNDS)
         self._loop_threads: set = set()  # idents that claimed "pt:"
 
     @property
@@ -190,6 +201,21 @@ class SpanTracer:
         t = self._clock()
         return self.record(name, t, t, **args)
 
+    def pin(self, t0: float, t1: float, **label) -> int:
+        """Keep the ring's spans that ENDED in ``[t0, t1]`` (a slow round's
+        phases and parts, its ``dispatch/*`` with the request chains'
+        children of it; an overlapped dispatch began a round earlier)
+        beside the ring, the last ``PINNED_ROUNDS`` windows of them:
+        ``chrome_trace()`` renders them with the ring's own, each with
+        ``label`` among its args, after the ring has dropped them. Returns
+        how many it kept."""
+        with self._mu:
+            ring = list(self._ring)
+        spans = [s for s in ring if t0 <= s.t1 <= t1]
+        with self._mu:
+            self._pinned.append((label, spans))
+        return len(spans)
+
     def adopt(self, parent: Span, child: Span) -> None:
         """Make ``child``, a span that ended before ``parent`` could exist
         (a wait that came before the request was known), the first link of
@@ -235,21 +261,39 @@ class SpanTracer:
         with self._mu:
             return list(self._ring)
 
+    def pinned(self) -> list:
+        """[(label, spans)] of the windows ``pin`` kept, oldest first."""
+        with self._mu:
+            return list(self._pinned)
+
     def clear(self) -> None:
         with self._mu:
             self._ring.clear()
+            self._pinned.clear()
 
     def chrome_trace(self) -> dict:
         """Chrome-trace JSON ("traceEvents" array format): one complete
         ("X") event per span — instants (t0 == t1) render as "i" — with
-        ``args.id``/``args.parent`` carrying the chain links."""
+        ``args.id``/``args.parent`` carrying the chain links. A pinned
+        span (``pin``) is rendered once, in the ring or out of it, with its
+        window's label among its args."""
         pid = os.getpid()
         events = []
-        for s in self.spans():
+        ring = self.spans()
+        seen = {s.span_id for s in ring}
+        labels, gone = {}, []  # pinned spans' labels; those the ring lost
+        for label, spans in self.pinned():
+            for s in spans:
+                labels[s.span_id] = label
+                if s.span_id not in seen:
+                    seen.add(s.span_id)
+                    gone.append(s)
+        for s in gone + ring:
             args = {"id": s.span_id}
             if s.parent_id:
                 args["parent"] = s.parent_id
             args.update(s.args)
+            args.update(labels.get(s.span_id, ()))
             ev = {"name": s.name, "cat": "picotron", "pid": pid,
                   "tid": s.tid, "ts": round(s.t0 * 1e6, 3), "args": args}
             if s.t1 is not None and s.t1 > s.t0:
@@ -290,6 +334,9 @@ class NullTracer(SpanTracer):
 
     def adopt(self, parent, child) -> None:
         pass
+
+    def pin(self, t0, t1, **label) -> int:
+        return 0
 
     def claim_loop_thread(self) -> None:
         pass

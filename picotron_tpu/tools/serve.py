@@ -47,7 +47,9 @@ API (all bodies JSON):
   ``inference.spec_controller`` is on).
 - ``GET /tracez`` — the process span ring as Chrome-trace JSON: each
   request's queue-wait -> prefill -> per-dispatch -> delivery chain,
-  parented. Validate/query with ``tools/trace_dump.py``.
+  parented, and the spans of the rounds the stall judge found slow
+  (docs/OBSERVABILITY.md "Stalls"; ``/statz`` ``stalls`` has their
+  records). Validate/query with ``tools/trace_dump.py``.
 - ``POST /profilez`` — start one timed ``jax.profiler`` capture
   (``{"seconds", "dir"}`` optional; defaults from ``obs.profile_dir`` /
   ``obs.profile_seconds``); 409 while one is running. The CLI wires
@@ -261,6 +263,11 @@ class FrontEnd:
         self._submit_wait_hist = self.obs.registry.histogram(
             "picotron_submit_lock_wait_seconds",
             "handler thread's wait for the batcher lock in submit()")
+        # the loop's own two phases join the batcher's five under the stall
+        # judge (loop/idle does not: waiting for work is no stall), and the
+        # watchdog's sleeps say whether the process was alive meanwhile
+        self.obs.stalls.register("loop/lock_wait", "loop/results")
+        self.obs.stalls.register_watchdog()
         self._uid_seq = 0
         self._start_t = time.monotonic()
         self._progress_t = time.monotonic()
@@ -799,6 +806,10 @@ class FrontEnd:
                     self._progress_t = time.monotonic()
                     for uid, res in results.items():
                         self._deliver(uid, res)
+                # a slow interval speaks when it happened, not at the
+                # watchdog's 60 s; outside the lock, as every log line
+                for rec in self.obs.stalls.take_slow():
+                    self._event("slow_interval", **rec)
                 if self.draining and not busy:
                     self._event("drain_done")
                     return
@@ -875,7 +886,7 @@ class FrontEnd:
         if self.stall_timeout_s <= 0:
             return
         while not self.stopped.is_set():
-            time.sleep(self.watchdog_poll_s)
+            self._nap()
             busy = self._batcher.busy  # racy read: a threshold, not a ledger
             age = time.monotonic() - self._progress_t
             if busy and age > self.stall_timeout_s:
@@ -887,6 +898,16 @@ class FrontEnd:
             elif self.stalled:
                 self.stalled = False
                 self._event("stall_recovered")
+
+    def _nap(self, sleep=time.sleep, clock=time.monotonic) -> None:
+        """One sleep of the watchdog, and how far it overran counted
+        (``picotron_watchdog_oversleep_seconds_total``). This thread does
+        none of the loop's work and holds none of its locks, so a sleep
+        that overran by seconds is the whole process standing still, not
+        the loop waiting for a device program."""
+        t0 = clock()
+        sleep(self.watchdog_poll_s)
+        self.obs.stalls.oversleep(clock() - t0 - self.watchdog_poll_s)
 
     # ---- observability ----------------------------------------------------
 
@@ -916,7 +937,8 @@ class FrontEnd:
         return self.obs.registry.prometheus() + GLOBAL_REGISTRY.prometheus()
 
     def trace_json(self) -> dict:
-        """The process span ring as Chrome-trace JSON."""
+        """The process span ring, and the slow rounds' spans pinned beside
+        it, as Chrome-trace JSON."""
         return self.obs.tracer.chrome_trace()
 
     def healthy(self) -> bool:
@@ -936,6 +958,11 @@ class FrontEnd:
                 self._mu.release()
         else:
             d = {"snapshot": "partial (dispatch in progress)"}
+        # the stall judge answers without the lock: what an operator asks
+        # for DURING a stall. The watchdog's own count of episodes past
+        # stall_timeout_s (once /statz ``stalls`` itself) rides inside.
+        d["stalls"] = {**self.obs.stalls.stats(),
+                       "watchdog_episodes": self.stalls}
         with self._rej_mu:
             d["rejected"] = dict(self.rejections)
         d["weight_bytes"] = self.weight_bytes
@@ -946,7 +973,6 @@ class FrontEnd:
         d["draining"] = self.draining
         d["dead"] = self.dead
         d["stalled"] = self.stalled
-        d["stalls"] = self.stalls
         d["uptime_s"] = round(time.monotonic() - self._start_t, 3)
         return d
 
@@ -1489,7 +1515,7 @@ def _smoke(server: Server, obs_dump: str = "") -> int:
     terminal = stats["completed"] + stats["expired"] + stats["errored"]
     check("accounting", terminal == stats["admitted"] == 3
           and stats["queued"] == 0 and stats["active_slots"] == 0)
-    check("no_stalls", stats["stalls"] == 0)
+    check("no_stalls", stats["stalls"]["watchdog_episodes"] == 0)
     return 1 if fail else 0
 
 

@@ -12,6 +12,10 @@
     # `make obs-smoke` gate
     python -m picotron_tpu.tools.trace_dump trace.json --require-request-chain
 
+    # the spans of a round the stall judge found slow (pinned beside the
+    # ring, so they are there after it has turned over)
+    python -m picotron_tpu.tools.trace_dump trace.json --stall-round 1234
+
 The file format is the Chrome trace-event "traceEvents" array
 (chrome://tracing, https://ui.perfetto.dev both load it directly);
 ``picotron_tpu.obs.tracing.SpanTracer.chrome_trace`` emits it with
@@ -126,6 +130,23 @@ def request_chains(trace: dict) -> dict:
         c["complete"] = bool(c["prefill"] and c["dispatches"]
                              and c["delivery"])
     return chains
+
+
+def stall_rounds(trace: dict) -> dict:
+    """{round: events} of the slow rounds whose spans the stall judge
+    pinned (``SpanTracer.pin``: each carries ``args.stall_round`` and
+    ``args.stall_where``, docs/OBSERVABILITY.md "Stalls"), each round's
+    events in time order."""
+    out: dict = {}
+    for ev in trace.get("traceEvents", ()):
+        if not isinstance(ev, dict):
+            continue
+        args = ev.get("args") or {}
+        if "stall_round" in args:
+            out.setdefault(args["stall_round"], []).append(ev)
+    for events in out.values():
+        events.sort(key=lambda e: e.get("ts", 0))
+    return out
 
 
 def overlap_chain(trace: dict) -> dict:
@@ -266,6 +287,10 @@ def main(argv=None) -> int:
                     help="fail unless >= 1 'lane' span links to a request "
                          "root with chunks tiling the prompt (the "
                          "inference.mixed_dispatch obs gate)")
+    ap.add_argument("--stall-round", type=int, default=None, metavar="SEQ",
+                    help="list the pinned spans of slow round SEQ (a "
+                         "slow_interval event's ``round``); fail if the "
+                         "trace holds none")
     args = ap.parse_args(argv)
     if not args.path and not args.url:
         ap.error("pass a trace file path or --url")
@@ -289,8 +314,20 @@ def main(argv=None) -> int:
               f"prefill={c['prefill']} dispatches={c['dispatches']} "
               f"delivery={c['delivery']} "
               f"{'COMPLETE' if c['complete'] else 'partial'}")
+    stalls = stall_rounds(trace)
+    for seq, events in sorted(stalls.items()):
+        print(f"  slow round {seq} ({events[0]['args']['stall_where']}): "
+              f"{len(events)} pinned spans")
     if errors:
         return 1
+    if args.stall_round is not None:
+        for ev in stalls.get(args.stall_round, ()):
+            print(f"    {ev['ts'] / 1e6:.6f} s +{ev.get('dur', 0) / 1e3:.3f} "
+                  f"ms {ev['name']}")
+        if args.stall_round not in stalls:
+            print(f"FAILED: no pinned spans of round {args.stall_round}",
+                  file=sys.stderr)
+            return 1
     want = args.require_request_chain
     if want is not None:
         ok = bool(complete) if want == "any" \

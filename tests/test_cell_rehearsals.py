@@ -35,7 +35,10 @@ CELLS = [w["name"] for w in MANIFEST["workloads"]
 ROUND = {"batcher.plan_ms", "batcher.deliver_ms", "engine.issue_operands_ms",
          "engine.issue_enqueue_ms", "engine.sync_wait_ms",
          "engine.sync_fetch_ms", "front.loop_lock_wait_ms",
-         "front.results_ms"}
+         "front.results_ms",
+         # the stall judge's counters print at 0 from the server's start
+         "batcher.stall_s", "engine.device_wait_stall_s",
+         "front.oversleep_s"}
 CHAT = {r + ".chat" for r in ROUND} | {
     "batcher.admit_ms.chat", "batcher.itl_p99_ms.chat",
     "engine.prefill_tokens_per_s.chat", "engine.prompt_reuse_pct.chat"}

@@ -596,7 +596,10 @@ def test_rehearsal_of_the_cell_computes_its_readers():
             # the parts of a round (ISSUE 37), from the window's scrapes
             "engine.issue_operands_ms.tput", "engine.issue_enqueue_ms.tput",
             "engine.sync_wait_ms.tput",
-            "engine.sync_fetch_ms.tput"} == set(out["computed"])
+            "engine.sync_fetch_ms.tput",
+            # the stall judge's counters (ISSUE 53): each prints at 0
+            "batcher.stall_s", "engine.device_wait_stall_s",
+            "front.oversleep_s"} == set(out["computed"])
 
 
 def test_a_program_without_the_block_fails_the_cell_at_once(tmp_path):

@@ -37,6 +37,8 @@ from picotron_tpu.obs import (
     Obs,
     SpanTracer,
 )
+from picotron_tpu.obs import stalls as stalls_mod
+from picotron_tpu.obs import tracing as tracing_mod
 from picotron_tpu.obs.metrics import (
     CounterDict,
     NullRegistry,
@@ -422,6 +424,12 @@ def test_obs_disabled_batcher_runs_and_records_nothing():
     assert engine.obs.registry.prometheus() == ""
     s = b.stats()
     assert s["queue_wait_s"] is None and s["ttft_s"] is None
+    # the stall judge is its null form: no history, no counters, no record
+    watch = engine.obs.stalls
+    assert not watch.enabled and s["stalls"] == {}
+    assert watch.judge("step/sync", "decode", 9.0) is None
+    assert watch.take_slow() == [] and "picotron_stall" not in \
+        engine.obs.registry.prometheus()
 
 
 def test_obs_disabled_output_identical():
@@ -1082,3 +1090,362 @@ def test_train_window_and_loop_spans_go_through_the_capture(tmp_path):
             "pt:host_sync"} <= set(ev)
     assert len(ev["pt:train/dispatch"]) == 1  # steps [2, 3): one dispatch
     assert not any(k.startswith("pt.req:") for k in ev)
+
+
+# --------------------------------------------------------------------------- #
+# stalls: the judge, its counters, the slow round's record and spans
+# --------------------------------------------------------------------------- #
+
+
+def _watch(*wheres):
+    from picotron_tpu.obs import StallWatch
+
+    watch = StallWatch(MetricsRegistry(), SpanTracer(ring=64))
+    watch.register(*wheres)
+    return watch
+
+
+def test_judge_gives_no_verdict_before_eight_predecessors():
+    watch = _watch("step/sync")
+    for _ in range(stalls_mod.MIN_HISTORY - 1):
+        assert watch.judge("step/sync", "decode", 0.05) is None
+    assert watch.judge("step/sync", "decode", 5.0) is None  # the eighth
+    # ... and is now a predecessor itself: the median of eight holds
+    assert watch.judge("step/sync", "decode", 5.0) == pytest.approx(
+        (0.05, 4.95))
+
+
+@pytest.mark.parametrize("reference,seconds,slow", [
+    (0.001, 0.09, False),   # 90 x the reference, under 0.1 s over it
+    (0.2, 0.5, False),      # 0.3 s over it, under 3 x
+    (0.2, 0.601, True),     # past 3 x and 0.4 s over
+    (0.001, 0.102, True),   # past both by a hair
+])
+def test_judge_needs_the_ratio_and_the_excess(reference, seconds, slow):
+    watch = _watch("step/plan")
+    for _ in range(stalls_mod.MIN_HISTORY):
+        watch.judge("step/plan", "", reference)
+    assert (watch.judge("step/plan", "", seconds) is not None) == slow
+
+
+def test_judge_excess_is_duration_less_the_median():
+    watch = _watch("step/deliver")
+    for s in (0.01, 0.02, 0.03, 0.04, 0.05, 0.06, 0.07, 0.08):
+        assert watch.judge("step/deliver", "", s) is None
+    ref, excess = watch.judge("step/deliver", "", 9.0)
+    assert ref == pytest.approx(0.045) and excess == pytest.approx(8.955)
+    # the median of the nine predecessors is 0.05 whatever the 9.0 among them
+    ref, excess = watch.judge("step/deliver", "", 1.0)
+    assert ref == pytest.approx(0.05) and excess == pytest.approx(0.95)
+
+
+def test_judge_two_keys_do_not_share_a_reference():
+    watch = _watch("step/admit")
+    for _ in range(10):
+        watch.judge("step/admit", "none", 0.0001)
+        watch.judge("step/admit", "8x4096", 0.9)
+    # a long admission among long admissions is no stall; among none it is
+    assert watch.judge("step/admit", "8x4096", 1.1) is None
+    assert watch.judge("step/admit", "none", 1.1) is not None
+    assert stalls_mod.admit_key(0, 0) == "none"
+    assert stalls_mod.admit_key(1, 3) == "1x16"
+    assert stalls_mod.admit_key(3, 584) == "4x1024"
+    assert stalls_mod.admit_key(16, 8192) == "16x8192"
+
+
+def test_judge_null_form_records_nothing():
+    off = Obs(enabled=False)
+    off.stalls.register("step/sync")
+    off.stalls.register_watchdog()
+    off.stalls.oversleep(3.0)
+    for _ in range(12):
+        assert off.stalls.judge("step/sync", "decode", 0.01) is None
+    assert off.stalls.judge("step/sync", "decode", 9.0) is None
+    with off.phase("loop/results"), off.part("sync/wait"):
+        pass
+    assert off.stalls.stats() == {} and off.stalls.take_slow() == []
+    assert off.registry.prometheus() == ""
+
+
+def _scripted_batcher(monkeypatch, sync_costs, slots=2, **inf):
+    """A batcher on a manual clock whose n-th wait for the device costs
+    ``sync_costs(n)`` seconds and whose other phases cost a little."""
+    cfg, engine, params = _engine(slots=slots, decode_block_len=2, **inf)
+    clock = _ManualClock()
+    engine.obs = Obs(enabled=True, registry=MetricsRegistry(),
+                     tracer=SpanTracer(ring=4096, clock=clock))
+    b = ContinuousBatcher(engine, params, clock=clock)
+    waits = [0]
+    ready = jax.block_until_ready
+
+    def waiting(x):
+        waits[0] += 1
+        clock.t += sync_costs(waits[0])
+        return ready(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", waiting)
+    inner = engine.decode_block
+
+    def issuing(*a, **kw):
+        clock.t += 3e-3
+        return inner(*a, **kw)
+
+    monkeypatch.setattr(engine, "decode_block", issuing)
+    return engine, b, clock
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+def test_one_slow_sync_is_counted_recorded_and_pinned(overlap, monkeypatch):
+    """Round 12's wait for the device takes 2 s where the others take 5 ms:
+    the excess over the median lands in the two counters under
+    ``step/sync``, nowhere else, and ``stats()['stalls']`` holds the round
+    with its phases, its parts and the host's evidence."""
+    engine, b, clock = _scripted_batcher(
+        monkeypatch, lambda n: 2.0 if n == 12 else 5e-3, overlap=overlap)
+    b.submit(Request("p", [3, 5, 7], max_new_tokens=41))
+    b.run()
+    snap = parse_prometheus(engine.obs.registry.prometheus())
+    assert snap['picotron_stall_seconds_total{where="step/sync"}'] \
+        == pytest.approx(2.0 - 5e-3)
+    assert snap['picotron_stalls_total{where="step/sync"}'] == 1
+    for where in ("step/plan", "step/admit", "step/issue", "step/deliver"):
+        assert snap[f'picotron_stall_seconds_total{{where="{where}"}}'] == 0
+        assert snap[f'picotron_stalls_total{{where="{where}"}}'] == 0
+    st = b.stats()["stalls"]
+    assert st["count"]["step/sync"] == 1
+    assert st["seconds"]["step/sync"] == pytest.approx(1.995)
+    (rec,) = st["slowest"]
+    assert (rec["where"], rec["key"]) == ("step/sync", "decode")
+    assert rec["round"] == (13 if overlap else 12)  # drained a round later
+    assert rec["duration_s"] == pytest.approx(2.0)
+    assert rec["reference_s"] == pytest.approx(5e-3)
+    assert rec["excess_s"] == pytest.approx(1.995)
+    # the part that holds the slow sync names the device wait
+    assert rec["parts"]["sync/wait"] == pytest.approx(2.0)
+    assert rec["parts"]["sync/fetch"] == 0.0
+    assert rec["phases"]["step/sync"] == pytest.approx(2.0)
+    assert rec["phases"]["step/issue"] == pytest.approx(3e-3)
+    assert rec["live_slots"] == 1 and rec["prompt_tokens"] == 0
+    assert rec["t0"] <= clock.t and rec["unix_t0"] > 1e9
+    assert set(rec["host"]) == {
+        "wall_s", "thread_cpu_s", "process_cpu_s", "involuntary_switches",
+        "major_faults", "gc_s", "oversleep_s"}
+    assert rec["host"]["oversleep_s"] == 0.0
+    # the record waits for whoever logs it, once
+    assert engine.obs.stalls.take_slow() == [rec]
+    assert engine.obs.stalls.take_slow() == []
+    # the round's spans are pinned under its number
+    rounds = trace_dump.stall_rounds(engine.obs.tracer.chrome_trace())
+    names = {e["name"] for e in rounds[rec["round"]]}
+    assert {"step/sync", "sync/wait", "sync/fetch", "step/deliver",
+            "dispatch/decode", "decode"} <= names
+
+
+def test_admissions_of_mixed_prompt_lengths_are_no_stall(monkeypatch):
+    """A prefill costs 20 ms a row of its padded bucket here, so a round
+    that admits 60 tokens takes 1.28 s in ``step/admit`` and one that
+    admits 4 takes 0.32: four times and a second apart, and no stall,
+    because each is held against admissions of its own size."""
+    engine, b, clock = _scripted_batcher(monkeypatch, lambda n: 5e-3,
+                                         slots=1)
+    inner = b._prefill_into
+
+    def prefilling(req, i, key=None):
+        clock.t += 0.02 * engine.prefill_bucket(len(req.prompt))
+        return inner(req, i, key)
+
+    monkeypatch.setattr(b, "_prefill_into", prefilling)
+    lengths = [4, 60, 20, 12, 40, 30]
+    for i in range(60):
+        n = lengths[i % len(lengths)]
+        b.submit(Request(f"m{i}", list(range(3, 3 + n)), max_new_tokens=3))
+    b.run()
+    snap = parse_prometheus(engine.obs.registry.prometheus())
+    assert snap['picotron_stall_seconds_total{where="step/admit"}'] == 0
+    assert b.stats()["stalls"]["slowest"] == []
+    # not for want of a verdict: the keys filled, and apart
+    history = {k[1]: list(v) for k, v in engine.obs.stalls._history.items()
+               if k[0] == "step/admit"}
+    assert {"1x16", "1x32", "1x64"} <= set(history)
+    assert all(len(history[k]) > stalls_mod.MIN_HISTORY
+               for k in ("1x16", "1x32", "1x64"))
+    assert min(history["1x64"]) > 3 * max(history["1x16"])
+    # ... and one key for them all would have cried stall
+    flat = _watch("step/admit")
+    assert any(flat.judge("step/admit", "", s)
+               for s in history["1x16"][:8] + history["1x64"][:1])
+
+
+def test_pinned_spans_survive_the_ring_turning_over(tmp_path):
+    clock = _ManualClock()
+    tr = SpanTracer(ring=8, clock=clock)
+    root = tr.begin("request", uid="u")
+    for name in ("step/issue", "step/sync", "step/deliver"):
+        with tr.span(name):
+            clock.t += 0.5
+    tr.record("dispatch/decode", 100.0, 101.0)
+    tr.record("decode", 100.0, 101.0, parent=root)
+    assert tr.pin(100.0, clock.t, stall_round=7, stall_where="step/sync") == 5
+    for i in range(20):  # the ring of 8 turns over twice and more
+        with tr.span(f"later{i}"):
+            clock.t += 0.01
+    assert not any(s.name == "step/sync" for s in tr.spans())
+    trace = tr.chrome_trace()
+    assert trace_dump.validate(trace) == []
+    assert len(trace["traceEvents"]) == 8 + 5  # each span once
+    (events,) = trace_dump.stall_rounds(trace).values()
+    assert [e["name"] for e in events if e["name"].startswith("step/")] \
+        == ["step/issue", "step/sync", "step/deliver"]
+    assert all(e["args"]["stall_where"] == "step/sync" for e in events)
+    # a span pinned and still in the ring is rendered once, with its label
+    tr.pin(clock.t - 0.005, clock.t, stall_round=8, stall_where="step/plan")
+    trace = tr.chrome_trace()
+    assert len(trace["traceEvents"]) == 8 + 5
+    assert len(trace_dump.stall_rounds(trace)[8]) == 1
+    # the tool finds the round in a dump
+    path = tmp_path / "trace.json"
+    tr.dump_chrome(str(path))
+    assert trace_dump.main([str(path), "--stall-round", "7"]) == 0
+    assert trace_dump.main([str(path), "--stall-round", "9"]) == 1
+    # no more than PINNED_ROUNDS windows are kept
+    for i in range(40):
+        tr.pin(0.0, 0.0, stall_round=100 + i, stall_where="x")
+    assert len(tr.pinned()) == tracing_mod.PINNED_ROUNDS
+
+
+def _front(**kw):
+    from picotron_tpu.tools import serve
+
+    cfg, engine, params = _engine(slots=2)
+    kw.setdefault("log", lambda *a, **k: None)
+    return engine, serve.FrontEnd(engine, params, **kw)
+
+
+def test_watchdog_counts_how_far_its_sleep_overran():
+    engine, front = _front(watchdog_poll_s=0.25)
+    clock = _ManualClock()
+
+    def sleep(overrun):
+        def sleeping(s):
+            clock.t += s + overrun
+        return sleeping
+
+    front._nap(sleep=sleep(0.0), clock=clock)
+    front._nap(sleep=sleep(2.8), clock=clock)
+    front._nap(sleep=sleep(0.002), clock=clock)  # the scheduler's: no freeze
+    front._nap(sleep=sleep(0.011), clock=clock)
+    snap = parse_prometheus(front.metrics_text())
+    assert snap["picotron_watchdog_oversleep_seconds_total"] \
+        == pytest.approx(2.811)
+    assert front.stats()["stalls"]["oversleep_s"] == pytest.approx(2.811)
+    assert front.stats()["stalls"]["watchdog_episodes"] == 0
+
+
+def test_every_stall_family_prints_at_zero_before_any_round():
+    engine, front = _front()
+    snap = parse_prometheus(front.metrics_text())
+    for where in ("loop/lock_wait", "step/plan", "step/admit", "step/issue",
+                  "step/sync", "step/deliver", "loop/results"):
+        assert snap[f'picotron_stall_seconds_total{{where="{where}"}}'] == 0
+        assert snap[f'picotron_stalls_total{{where="{where}"}}'] == 0
+    assert not any("loop/idle" in k for k in snap if "stall" in k)
+    assert snap["picotron_watchdog_oversleep_seconds_total"] == 0
+    for g in "012":  # the process's registry; a collection may have run
+        assert snap[
+            f'picotron_gc_pause_seconds_total{{generation="{g}"}}'] >= 0
+    assert front.stats()["stalls"]["slowest"] == []
+
+
+def test_gc_pauses_are_counted_by_generation():
+    import gc
+
+    c = GLOBAL_REGISTRY.counter("picotron_gc_pause_seconds_total",
+                                generation="2")
+    obs_mod.install_gc_pause_counter(GLOBAL_REGISTRY)  # again: once only
+    before, callbacks = c.value, len(gc.callbacks)
+    gc.collect()
+    assert c.value > before and len(gc.callbacks) == callbacks
+
+
+def test_a_slow_results_phase_of_the_loop_is_logged_when_it_happens(
+        monkeypatch):
+    """The front end's own phases are judged too: a ``loop/results`` that
+    takes 1.5 s (a log that blocked) is a ``slow_interval`` event in the
+    log at once, and ``/statz`` has it."""
+    from picotron_tpu.tools import serve
+
+    cfg, engine, params = _engine(slots=2, decode_block_len=2)
+    clock = _ManualClock()
+    engine.obs = Obs(enabled=True, registry=MetricsRegistry(),
+                     tracer=SpanTracer(ring=4096, clock=clock))
+    lines = []
+    srv = serve.Server(engine, params, port=0,
+                       log=lambda m, **k: lines.append(json.loads(m)))
+    takes = [0]
+    inner = srv.front._batcher.take_results
+
+    def taking():
+        takes[0] += 1
+        clock.t += 1.5 if takes[0] == 12 else 1e-3
+        return inner()
+
+    monkeypatch.setattr(srv.front._batcher, "take_results", taking)
+    srv.start()
+    try:
+        st, body = serve._post(srv.port, {"prompt": [1, 2, 3],
+                                          "max_new_tokens": 41})
+        assert st == 200 and len(body["tokens"]) == 41
+        deadline = time.monotonic() + 30
+        while not any(e["evt"] == "slow_interval" for e in lines) \
+                and time.monotonic() < deadline:
+            time.sleep(0.01)
+        (evt,) = [e for e in lines if e["evt"] == "slow_interval"]
+        assert evt["where"] == "loop/results" and evt["round"] == 11
+        assert evt["excess_s"] == pytest.approx(1.499)
+        assert evt["phases"] == {"loop/results": 1.5}
+        st, stats = serve._get(srv.port, "/statz")
+        assert stats["stalls"]["slowest"][0]["where"] == "loop/results"
+        assert stats["stalls"]["seconds"]["loop/results"] \
+            == pytest.approx(1.499)
+        st, trace = serve._get(srv.port, "/tracez")
+        assert 11 in {int(k) for k in trace_dump.stall_rounds(trace)}
+    finally:
+        srv.drain_and_join(timeout=30)
+
+
+_READERS = {
+    "batcher.stall_s": 2.25, "batcher.stall_s.chat": 2.25,
+    "engine.device_wait_stall_s": 2.0,
+    "engine.device_wait_stall_s.chat": 2.0,
+    "front.oversleep_s": 1.5, "front.oversleep_s.chat": 1.5,
+}
+
+
+@pytest.mark.parametrize("name", sorted(_READERS))
+def test_stall_readers_none_without_the_family_zero_when_quiet(name):
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    try:
+        from benchmarks import common
+        read = common.load_file("layer_metrics", name).read
+    finally:
+        sys.path.remove(root)
+    _, front = _front()
+    quiet = front.metrics_text()
+    # the parent's scrape: every other family, none of the judge's
+    parent = "\n".join(line for line in quiet.splitlines()
+                       if "picotron_stall" not in line
+                       and "oversleep" not in line)
+    assert read({"metrics_before": parent, "metrics_after": parent}) is None
+    assert read({}) is None
+    assert read({"metrics_before": quiet, "metrics_after": quiet}) == 0.0
+    reg = front.obs.registry
+    reg.counter("picotron_stall_seconds_total", where="step/sync").inc(1.25)
+    reg.counter("picotron_stall_seconds_total", where="step/admit").inc(0.75)
+    reg.counter("picotron_stall_seconds_total", where="step/plan").inc(0.25)
+    front.obs.stalls.oversleep(1.5)
+    got = read({"metrics_before": quiet,
+                "metrics_after": front.metrics_text()})
+    assert got == pytest.approx(_READERS[name])
