@@ -433,8 +433,9 @@ def ring_attend(q, kw, vw, pos, row, window: int, scale: float,
     rows read once, those older than the window masked. Under ``impl``
     "auto" (``kv_cache.attend``'s rule) on a TPU, bfloat16 rows of whole
     lanes go through the stacked flash-decode kernel in its ring form,
-    where they lie; else ("dense", or what the engine fell back to) a masked
-    contraction of the layer's block."""
+    where they lie (it fetches the blocks the window's rows lie in); else
+    ("dense", or what the engine fell back to) a masked contraction of the
+    layer's block."""
     if impl == "auto" and on_tpu() and kv_cache.plain_decode(q, {"k": kw}):
         from picotron_tpu.ops.pallas.decode_attention import (
             flash_decode_stacked,

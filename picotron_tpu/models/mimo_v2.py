@@ -42,7 +42,8 @@ rings are ``models/afmoe.py``'s (``ring_rows``, ``ring_positions``,
 ``ring_write``, ``visible``, the chunk's walk): a sliding layer writes
 position ``p`` at row ``p mod ring``, K cached rotated. A decode step reads
 a layer's rows once (on a TPU ``flash_decode_stacked`` over the merged rows,
-in its ring form with the sink for the sliding layers); a prefill chunk
+in its ring form with the sink for the sliding layers: of a ring's five
+blocks of 128 rows it fetches those the window's rows lie in); a prefill chunk
 walks its slot's live key blocks under a running softmax that starts from
 the sink.
 

@@ -445,10 +445,24 @@ def _stacked_block_rows(T: int, row_bytes: int) -> int:
     which ``_pick_block`` would halve down to 2 and 5), else what
     ``_pick_block`` finds."""
     cap = min(T, max(_SUBLANE, _STACKED_KV_BLOCK // row_bytes))
-    if T % cap == 0:
-        return cap
+    return cap if T % cap == 0 else _tile_divisor(T, cap)
+
+
+def _tile_divisor(T: int, cap: int) -> int:
+    """The largest divisor of ``T`` up to ``cap`` in whole bfloat16 tiles of
+    16 rows, else what ``_pick_block`` finds."""
     fits = [b for b in range(_BF16_ROWS, cap + 1, _BF16_ROWS) if T % b == 0]
     return max(fits) if fits else _pick_block(T, cap)
+
+
+def _ring_block_rows(T: int, row_bytes: int, window: int) -> int:
+    """Tokens a K block of the ring form holds by default: the largest
+    divisor of the ring's ``T`` rows in whole bfloat16 tiles that is at most
+    the window and at most ``_stacked_block_rows``' (a block larger than the
+    window crosses the HBM with rows no query sees, whatever the walk skips:
+    a ring of 640 rows under a window of 128 goes in blocks of 128, one of
+    4,608 under 4,096 in the 512 it had)."""
+    return _tile_divisor(T, min(_stacked_block_rows(T, row_bytes), window))
 
 
 def _stacked_blocks(L, block_t, max_nb):
@@ -458,8 +472,44 @@ def _stacked_blocks(L, block_t, max_nb):
                            max_nb=max_nb)
 
 
+def _ring_walk(window: int, block_t: int, max_nb: int) -> int:
+    """The most blocks of ``block_t`` rows, of a ring's ``max_nb``, that a
+    window's rows touch wherever they start: the grid's second extent in the
+    ring form."""
+    seen = min(window, max_nb * block_t)
+    return min(max_nb, -(-(seen - 1) // block_t) + 1)
+
+
+def _ring_blocks(written, last, *, window, block_t, max_nb):
+    """``(first, count)``: the blocks of a ring that hold a row its query
+    sees, as the one holding the oldest such row and how many there are from
+    it on, the ring's end joined to its start (step ``j`` of the walk is
+    block ``_ring_block(first, j, max_nb)``). ``written`` rows of the ring
+    are live and the query's own key lies in row ``last``, so it sees the
+    ``min(written, window)`` rows that end there. Shared by the index maps
+    and the kernel body, as ``_stacked_blocks`` is: the block a step fetches
+    is the block it masks. A free slot (``written == 0``) walks none and
+    costs the block of ``last``."""
+    n = jnp.minimum(written, window)
+    oldest = last - jnp.maximum(n, 1) + 1
+    oldest = jnp.where(oldest < 0, oldest + max_nb * block_t, oldest)
+    # nothing here is negative: lax.div and lax.rem spare the scalar core
+    # the sign fix of ``//`` and ``%`` in every index map of every step
+    count = lax.div(lax.rem(oldest, block_t) + n + (block_t - 1), block_t)
+    count = jnp.where(n > 0, jnp.minimum(count, max_nb), 0)
+    return lax.div(oldest, block_t), count
+
+
+def _ring_block(first, j, max_nb):
+    """Block number of step ``j < max_nb`` of a walk from block ``first``
+    (a compare and a subtract where ``%`` is a division by no power of two
+    on the scalar core, in every index map of every grid step)."""
+    blk = first + j
+    return jnp.where(blk >= max_nb, blk - max_nb, blk)
+
+
 def _stacked_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg,
-                    max_nb, window=None, ring=0, sink=False):
+                    max_nb, steps, window=None, sink=False):
     """One (slot, kv-block) grid step of ``flash_decode_stacked``. The K
     block is ``block_t`` tokens of one layer and slot as a plain matrix:
     ``[block_t * rows, lanes]``, a row of it one (token, cache row) pair,
@@ -472,10 +522,15 @@ def _stacked_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg,
     they do not). The other rows' products are exact discards: masked to
     NEG_INF before the softmax, an exact zero in the value matmul.
 
-    With a ``window`` the slot's strip is a ring of ``ring`` rows and a
+    With a ``window`` the slot's strip is a ring of ``max_nb`` blocks and a
     third scalar operand names the row the query's own key lies in: a row
     is seen if it is live and fewer than ``window`` rows behind that one,
-    the ring's end joined to its start.
+    the ring's end joined to its start. The grid's second axis is then the
+    walk over the blocks that hold such a row (``_ring_blocks``: step ``j``
+    of the grid's ``steps`` is block ``first + j`` round the ring), not over
+    the blocks that are written: a block wholly out of the window is never
+    fetched. The order the blocks come in is
+    nothing to a running softmax.
 
     With a ``sink`` a fourth operand holds one float32 a query row: the
     running softmax starts from it (its maximum, a denominator of exp(0),
@@ -490,7 +545,12 @@ def _stacked_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg,
     b = pl.program_id(0)
     j = pl.program_id(1)
     L = len_ref[b]
-    nb = _stacked_blocks(L, block_t, max_nb)
+    if window is None:
+        nb, blk = _stacked_blocks(L, block_t, max_nb), j
+    else:
+        first, nb = _ring_blocks(L, last_ref[b], window=window,
+                                 block_t=block_t, max_nb=max_nb)
+        blk = _ring_block(first, j, max_nb)
     nq, cols = own_ref.shape
 
     @pl.when(j == 0)
@@ -512,20 +572,19 @@ def _stacked_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg,
         s = _dot_nt(q_ref[...], k_ref[...])
         if scale is not None:
             s = s * scale
-        # token j * block_t + own is visible iff it is below the length
-        seen = own_ref[...] < L - j * block_t
+        # token blk * block_t + own is visible iff it is below the length
+        seen = own_ref[...] < L - blk * block_t
         if window is not None:
             # and, of a ring, fewer than ``window`` rows behind the query's
-            age = last_ref[b] - j * block_t - own_ref[...]
-            seen &= jnp.where(age < 0, age + ring, age) < window
+            age = last_ref[b] - blk * block_t - own_ref[...]
+            seen &= jnp.where(age < 0, age + max_nb * block_t, age) < window
         s = jnp.where(seen, s, NEG_INF)
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        # every live block holds a visible key for every head (token
-        # j * block_t of its own row), so m_new is finite and a masked
-        # score's exp underflows to an exact zero. (Of a ring one block may
-        # hold none: as the first it adds a finite sum that the next
-        # block's alpha = exp(NEG_INF - m_new) = 0 wipes; later, zeros.)
+        # every walked block holds a visible key for every head (of a
+        # prefix token blk * block_t of its own row, of a ring a row of the
+        # window), so m_new is finite and a masked score's exp underflows
+        # to an exact zero
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
@@ -534,7 +593,7 @@ def _stacked_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg,
             p.astype(v_ref.dtype), v_ref[...],
             preferred_element_type=jnp.float32)
 
-    @pl.when(j == max_nb - 1)
+    @pl.when(j == steps - 1)
     def _():
         l = l_ref[...]
         out = acc_ref[...] / jnp.where(l > 0, l, 1.0)
@@ -573,6 +632,18 @@ def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
     the steps past its walk repeat the last block index (no DMA) and skip
     their compute; a free slot costs its block 0.
 
+    With a ``window`` a slot's strip is a ring (``models/afmoe.py``: position
+    ``p`` lies in row ``p mod T``) and the walk is over the blocks that hold
+    a row the query SEES, live and fewer than ``window`` behind its own, the
+    ring's end joined to its start (``_ring_blocks``), not over the blocks
+    that are written: the grid's second extent is the most blocks a window
+    can touch (``_ring_walk``), the index map returns the ``j``-th seen
+    block, and past the walk the last one again, as above. The default block
+    is no larger than the window (``_ring_block_rows``), since whatever a
+    block holds beside the window's rows crosses the HBM to be masked: a ring
+    of 640 rows under a window of 128 is read as one or two blocks of 128
+    where blocks of 320 read it whole. ``block_t`` overrides either default.
+
     Packed rows are contracted whole, as ``kv_cache.decode_attention``
     does it: the ``p * g`` query heads of a cache row each sit in their
     own head's lanes, exact zeros in the others, and of the ``p * D``
@@ -607,18 +678,31 @@ def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
     nq = -(-nh // _BF16_ROWS) * _BF16_ROWS
     if nq != nh:  # pad rows own no cache row: all masked, sliced off below
         qm = jnp.pad(qm, ((0, 0), (0, nq - nh), (0, 0)))
-    bt = _pick_block(T, block_t) if block_t else _stacked_block_rows(
-        T, rows * lanes * k.dtype.itemsize)
+    row_bytes = rows * lanes * k.dtype.itemsize
+    if block_t:
+        bt = _pick_block(T, block_t)
+    elif window is None:
+        bt = _stacked_block_rows(T, row_bytes)
+    else:
+        bt = _ring_block_rows(T, row_bytes, window)
     cols, max_nb = bt * rows, T // bt
+    steps = max_nb if window is None else _ring_walk(window, bt, max_nb)
     lengths = lengths.astype(jnp.int32)
     prefetch = (lengths, jnp.asarray(layer, jnp.int32).reshape(1))
     if window is not None:
         # rows written, and the row of the query's own key
         prefetch = (jnp.minimum(lengths, T), prefetch[1], (lengths - 1) % T)
 
-    def kv_index(b, j, len_ref, layer_ref, *_):
-        nb = _stacked_blocks(len_ref[b], bt, max_nb)
-        return (layer_ref[0], b, jnp.maximum(jnp.minimum(j, nb - 1), 0), 0)
+    def kv_index(b, j, len_ref, layer_ref, *last_ref):
+        if window is None:
+            nb = _stacked_blocks(len_ref[b], bt, max_nb)
+        else:
+            first, nb = _ring_blocks(len_ref[b], last_ref[0][b],
+                                     window=window, block_t=bt, max_nb=max_nb)
+        jj = jnp.maximum(jnp.minimum(j, nb - 1), 0)  # past the walk: no DMA
+        if window is not None:
+            jj = _ring_block(first, jj, max_nb)
+        return (layer_ref[0], b, jj, 0)
 
     def row_spec(width):  # a slot's query rows, or what they come to
         return pl.BlockSpec((None, nq, width), lambda b, j, *_: (b, 0, 0))
@@ -634,11 +718,11 @@ def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
         in_specs.append(pl.BlockSpec((nq, 1), lambda b, j, *_: (0, 0)))
     out = pl.pallas_call(
         functools.partial(_stacked_kernel, scale=scale, block_t=bt,
-                          rows=rows, pg=pg, max_nb=max_nb, window=window,
-                          ring=T, sink=sink is not None),
+                          rows=rows, pg=pg, max_nb=max_nb, steps=steps,
+                          window=window, sink=sink is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
-            grid=(B, max_nb),
+            grid=(B, steps),
             in_specs=in_specs + [kv_spec(lanes), kv_spec(lanes_v)],
             out_specs=row_spec(lanes_v),
             scratch_shapes=[pltpu.VMEM((nq, lanes_v), jnp.float32),

@@ -261,27 +261,38 @@ def test_ring_positions_by_hand():
     assert afmoe.key_block(4608) == 1536 and afmoe.key_block(32768) == 2048
 
 
-@pytest.mark.parametrize("block_t", [8, 16, None])
-def test_the_ring_form_of_the_stacked_decode_kernel(block_t):
+@pytest.mark.parametrize("T,W,block_t", [
+    (48, 32, 8), (48, 32, 16), (48, 32, None), (80, 16, 16), (80, 16, None),
+    (80, 16, 8)])
+def test_the_ring_form_of_the_stacked_decode_kernel(T, W, block_t):
     """``flash_decode_stacked(window=)`` in interpret mode against the
     masked contraction of the layer's block: a ring not come round, one
     come round once and twice, a dead range that is block 0 whole (pos 88:
-    row 40 is the query's, rows 0-7 are 33-40 behind it)."""
+    row 40 is the query's, rows 0-7 are 33-40 behind it); and a ring of
+    five blocks under a window of one (T 80, W 16): a window not full, the
+    query's row a block's last (47, 79) and first (64), a window that wraps
+    from the last block to block 0 (85), come round twice (197)."""
     from picotron_tpu.ops.pallas.decode_attention import flash_decode_stacked
 
     rng = np.random.default_rng(0)
-    L, B, T, nkv, D, nh, W = 3, 6, 48, 2, 128, 8, 32
+    pos = jnp.asarray([0, 7, 47, 63, 88, 127] if T == 48 else
+                      [0, 7, 40, 47, 64, 79, 85, 95, 160, 197], jnp.int32)
+    L, B, nkv, D, nh = 3, len(pos), 2, 128, 8
     k, v = (jnp.asarray(rng.standard_normal((L, B, T, nkv, D)), jnp.bfloat16)
             for _ in range(2))
     q = jnp.asarray(rng.standard_normal((B, 1, nh, D)), jnp.bfloat16)
-    pos = jnp.asarray([0, 7, 47, 63, 88, 127], jnp.int32)
     got = flash_decode_stacked(q, k, v, pos + 1, D ** -0.5, 1,
                                block_t=block_t, interpret=True, window=W)
     seen = afmoe.visible(pos[:, None], afmoe.ring_positions(pos, T), W)
-    assert seen.sum(-1).ravel().tolist() == [1, 8, 32, 32, 32, 32]
+    assert seen.sum(-1).ravel().tolist() == [min(int(p) + 1, W) for p in pos]
     want = afmoe.masked_attention(q, k[1], v[1], seen, D ** -0.5)
     assert float(jnp.max(jnp.abs(got.astype(jnp.float32)
                                  - want.astype(jnp.float32)))) < 2e-2
+    # a free slot walks no block and comes out zeros, as in the plain form
+    free = flash_decode_stacked(q[:2], k[:, :2], v[:, :2],
+                                jnp.asarray([0, 8], jnp.int32), D ** -0.5, 1,
+                                block_t=block_t, interpret=True, window=W)
+    assert not free[0].any() and free[1].any()
 
 
 def test_on_a_tpu_the_window_step_takes_the_kernel(monkeypatch):

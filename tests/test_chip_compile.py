@@ -222,9 +222,11 @@ def _decode_mimo(kind):
     slots = k_leaf[1]
 
     def attend(q, k, v, pos, layer, sink):
-        # blocks of 512 and 320 tokens: the largest divisors under 1 MiB of K
-        assert da._stacked_block_rows(k.shape[2], 2 * k.shape[3]) \
-            == {"full": 512, "ring": 320}[kind]
+        # blocks of 512 tokens, the largest divisor under 1 MiB of K, and
+        # of a ring 128: the largest that is no more than the window
+        T, row_bytes = k.shape[2], 2 * k.shape[3]
+        assert {"full": da._stacked_block_rows(T, row_bytes) == 512,
+                "ring": da._ring_block_rows(T, row_bytes, 128) == 128}[kind]
         return flash_decode_stacked(
             q, k[:, :, :, None], v[:, :, :, None], pos + 1, 192 ** -0.5,
             layer, window=window or None, sink=sink if window else None)

@@ -365,25 +365,38 @@ def dense_answer(q, k, v, pos, nkv, window, sink):
         q.shape[-1] ** -0.5, sink)
 
 
-@pytest.mark.parametrize("form,block_t", [
-    ("full", 16), ("full", None), ("ring_sink", 16), ("ring_sink", 48),
-    ("ring", 16), ("full_sink", 16)])
-def test_the_stacked_kernel_with_keys_wider_than_values(form, block_t):
+# a ring of five blocks under a window of one (T 80, W 16, blocks of 16): a
+# window not full (0, 7), a ring not come round (40), the query's row a
+# block's last (47, 79) and first (64: the window ends in the next block's
+# row 0), a window that wraps from the last block to block 0 (85), one that
+# is block 0 whole (95), come round twice (160: row 0; 197)
+SMALL_WINDOW_POS = [0, 7, 40, 47, 64, 79, 85, 95, 160, 197]
+
+
+@pytest.mark.parametrize("form,block_t,T,W", [
+    ("full", 16, 48, 32), ("full", None, 48, 32), ("ring_sink", 16, 48, 32),
+    ("ring_sink", 48, 48, 32), ("ring", 16, 48, 32),
+    ("full_sink", 16, 48, 32), ("ring_sink", 16, 80, 16),
+    ("ring_sink", None, 80, 16), ("ring", 16, 80, 16),
+    ("ring", None, 80, 16), ("ring", None, 48, 32)])
+def test_the_stacked_kernel_with_keys_wider_than_values(form, block_t, T, W):
     """``flash_decode_stacked`` in interpret mode, a row's heads merged (K
     rows of 4 x 192, V rows of 4 x 128; 16 query heads), against the dense
     answer: a prefix, and a ring (not come round, come round once and
-    twice, a dead block) with and without a sink; a sink of -inf gives the
+    twice, a dead block; and a ring of five blocks under a window of one:
+    ``SMALL_WINDOW_POS``) with and without a sink; a sink of -inf gives the
     plain form's answer."""
     from picotron_tpu.ops.pallas.decode_attention import flash_decode_stacked
 
     rng = np.random.default_rng(0)
-    L, B, T, nkv, D, Dv, nh, W = 3, 6, 48, 4, 192, 128, 16, 32
+    window = W if form.startswith("ring") else 0
+    pos = jnp.asarray(([0, 7, 47, 63, 88, 127] if T == 48
+                       else SMALL_WINDOW_POS) if window
+                      else [0, 7, 15, 16, 33, 47], jnp.int32)
+    L, B, nkv, D, Dv, nh = 3, len(pos), 4, 192, 128, 16
     k = jnp.asarray(rng.standard_normal((L, B, T, nkv * D)), jnp.bfloat16)
     v = jnp.asarray(rng.standard_normal((L, B, T, nkv * Dv)), jnp.bfloat16)
     q = jnp.asarray(rng.standard_normal((B, 1, nh, D)), jnp.bfloat16)
-    window = W if form.startswith("ring") else 0
-    pos = jnp.asarray([0, 7, 47, 63, 88, 127] if window
-                      else [0, 7, 15, 16, 33, 47], jnp.int32)
     sink = jnp.asarray(2.0 + rng.standard_normal(nh), jnp.float32) \
         if form.endswith("sink") else None
 
@@ -438,12 +451,48 @@ def test_on_a_tpu_the_decode_step_takes_the_kernel(monkeypatch):
     mimo_v2.decode_attend(q, kw, vw, pos, 1, 128, 0.1, sink, 8, impl="dense")
     assert len(calls) == 2
     # the kernel's own choice of block: the cells' rows as they were, a
-    # row of 768 or 1,536 lanes in the largest divisor under 1 MiB of K
+    # row of 768 lanes in the largest divisor under 1 MiB of K; a ring's
+    # no larger than its window (this cell's 640 rows in five blocks of
+    # 128, Trinity's 4,608 under a window of 4,096 in the nine of 512 it had)
     assert da._stacked_block_rows(16384, 2 * 768) == 512
-    assert da._stacked_block_rows(640, 2 * 1536) == 320
+    assert da._ring_block_rows(640, 2 * 1536, 128) == 128
+    assert da._ring_block_rows(4608, 2 * 8 * 128, 4096) == 512
+    assert [da._ring_walk(W, b, T // b) for W, b, T in (
+        (128, 128, 640), (4096, 512, 4608), (16, 16, 80), (32, 16, 48),
+        (4096, 512, 2048), (1, 16, 48))] == [2, 9, 2, 3, 4, 1]
     assert [da._stacked_block_rows(T, 2 * rows * 128) for T, rows in (
         (2048, 16), (2048, 8), (32768, 8), (4608, 8), (4096, 8), (48, 2))] \
         == [256, 512, 512, 512, 512, 48]
+
+
+@pytest.mark.parametrize("T,W,block_t", [
+    (80, 16, 16), (48, 32, 16), (48, 32, 8), (640, 128, 128), (640, 128, 64),
+    (4608, 4096, 512), (2048, 4096, 512), (96, 96, 32), (64, 1, 16)])
+def test_the_ring_walk_names_the_blocks_the_query_sees(T, W, block_t):
+    """``_ring_blocks`` alone: for every length from 1 to three times round
+    the ring, the blocks it names are exactly those that hold a row
+    ``rings.visible`` marks, each once and never more than the static bound
+    the grid is built to; a free slot walks none. (A ring shorter than its
+    window, which a short ``max_seq_len`` makes, never comes round.)"""
+    from picotron_tpu.ops.pallas import decode_attention as da
+
+    nb = T // block_t
+    steps = da._ring_walk(W, block_t, nb)
+    assert steps <= nb
+    L = jnp.arange(0, (3 * T if W <= T else T) + 1, dtype=jnp.int32)
+    first, count = da._ring_blocks(jnp.minimum(L, T), (L - 1) % T, window=W,
+                                   block_t=block_t, max_nb=nb)
+    first, count = np.asarray(first), np.asarray(count)
+    assert count[0] == 0 and 0 <= first.min() and first.max() < nb
+    assert count[1:].min() >= 1 and count.max() == steps
+    walked = (first[:, None] + np.arange(nb)) % nb  # [lengths, steps]
+    named = np.zeros((len(L), nb), int)
+    np.add.at(named, (np.arange(len(L))[:, None], walked),
+              np.arange(nb) < count[:, None])
+    pos = L[1:] - 1
+    seen = afmoe.visible(pos[:, None], afmoe.ring_positions(pos, T), W)[:, 0]
+    holds = np.asarray(seen).reshape(len(pos), nb, block_t).any(-1)
+    np.testing.assert_array_equal(named[1:], holds.astype(int))
 
 
 # ---- (d) the share and the router -------------------------------------------
