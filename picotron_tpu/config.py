@@ -177,7 +177,11 @@ class ModelConfig:
     # (Keye-VL-2.0's language model: GQA with q/k norm a head, M-RoPE, a
     # learned top-k selection whose chosen K/V rows a decode step gathers,
     # softmax-routed experts in every layer — models/keye_vl2.py, serving
-    # path only; its fields are the last). The fields below are the
+    # path only) or "nemotron_h" (Nemotron 3's hybrid: a layer is ONE
+    # sublayer, Mamba-2 with B and C a group of heads, NoPE attention, or
+    # LatentMoE, two-matrix relu^2 experts routed in a latent, by
+    # ``hybrid_override_pattern`` — models/nemotron_h.py, serving path only;
+    # its fields are the last). The fields below are the
     # published ``config.json`` keys of the DeepSeek block, by their own
     # names, and are read by no other block (but ``num_experts_per_tok``,
     # ``ep_size`` and ``ep_rank``, which both expert blocks read).
@@ -321,6 +325,31 @@ class ModelConfig:
     sa_config: Optional[dict] = None
     decoder_sparse_step: int = 1
     mlp_only_layers: Optional[list] = None
+    # "nemotron_h" (Nemotron 3 Super): the published keys of that block,
+    # beside ``head_dim``, ``n_routed_experts`` (the experts HELD here of a
+    # router ``n_routed_experts * ep_size`` wide), ``num_experts_per_tok``,
+    # ``moe_intermediate_size``, ``n_shared_experts``, ``norm_topk_prob``,
+    # ``routed_scaling_factor``, ``n_group``/``topk_group``,
+    # ``mamba_proj_bias``, ``num_nextn_predict_layers`` (0: the
+    # multi-token-prediction layer is not held), ``tie_word_embeddings``,
+    # ``ep_size``/``ep_rank``. ``hybrid_override_pattern`` is one letter a
+    # layer held: ``M`` Mamba-2, ``E`` experts, ``*`` attention; a layer is
+    # that one sublayer. ``n_groups`` B/C groups share the ``mamba_num_heads``
+    # heads of ``mamba_head_dim``; ``moe_latent_size`` is the width the routed
+    # experts work in, ``moe_shared_expert_intermediate_size`` the shared
+    # expert's on the stream; the norms' eps is ``rms_norm_eps`` (the
+    # published ``layer_norm_epsilon``).
+    hybrid_override_pattern: str = ""
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    conv_kernel: int = 4
+    n_groups: int = 1
+    chunk_size: int = 128
+    use_conv_bias: bool = True
+    mlp_hidden_act: str = "silu"
+    moe_latent_size: int = 0
+    moe_shared_expert_intermediate_size: int = 0
     # the published ``head_dim`` where it is not hidden_size / heads (afmoe:
     # 128 of 3072 / 48); 0: derived, and it follows ``hidden_size``
     head_dim: int = 0
@@ -1202,10 +1231,11 @@ class Config:
                     f"H-sized axis")
         if m.model_type not in ("llama", "deepseek_v32", "granitemoehybrid",
                                 "minicpm_sala", "afmoe", "mimo_v2",
-                                "KeyeVL2"):
+                                "KeyeVL2", "nemotron_h"):
             raise ValueError(
                 f"unknown model_type {m.model_type!r} (llama|deepseek_v32|"
-                "granitemoehybrid|minicpm_sala|afmoe|mimo_v2|KeyeVL2)")
+                "granitemoehybrid|minicpm_sala|afmoe|mimo_v2|KeyeVL2|"
+                "nemotron_h)")
         if m.model_type == "deepseek_v32":
             self._validate_deepseek_v32(for_training)
         if m.model_type == "granitemoehybrid":
@@ -1218,6 +1248,8 @@ class Config:
             self._validate_mimo_v2(for_training)
         if m.model_type == "KeyeVL2":
             self._validate_keye_vl2(for_training)
+        if m.model_type == "nemotron_h":
+            self._validate_nemotron_h(for_training)
         if m.attention_impl not in ("auto", "sdpa", "flash"):
             raise ValueError(
                 f"unknown attention_impl {m.attention_impl!r} (auto|sdpa|flash)")
@@ -2180,6 +2212,102 @@ class Config:
                 f"{who} implements model.mlp_only_layers = [] only (got "
                 f"{m.mlp_only_layers!r}): every layer's MLP is the routed "
                 "experts")
+
+    def _validate_nemotron_h(self, for_training: bool) -> None:
+        """What ``models/nemotron_h.py`` needs of its keys, and what it
+        cannot do yet, each refused by name."""
+        d, m, inf = self.distributed, self.model, self.inference
+        who = "model_type 'nemotron_h'"
+        pattern = m.hybrid_override_pattern
+        width = m.n_routed_experts * m.ep_size
+        refused = (
+            (for_training, "is served, not trained: training is not "
+             "implemented for this block (no backward through the chunked "
+             "scan and the expert share; train_step builds the Llama block "
+             "only)"),
+            (d.tp_size > 1, f"does not support tp_size > 1 (got "
+             f"{d.tp_size}): the recurrent state has no tp sharding and the "
+             "block holds no tp collectives; its share of a layer is "
+             "ep_size/ep_rank"),
+            (inf.kv_layout == "paged", "does not support inference.kv_layout "
+             "'paged' (nor the prefix reuse that rests on it): a recurrent "
+             "state has no token axis to page and no snapshot to resume a "
+             "shared prefix from; set kv_layout: 'contiguous'"),
+            (inf.kv_cache_dtype == "int8", "does not support "
+             "inference.kv_cache_dtype 'int8': the state is float32 and K/V "
+             "are stored in the model's dtype"),
+            (inf.weight_dtype == "int8", "does not support "
+             "inference.weight_dtype 'int8': its matmuls take dense weights "
+             "only"),
+            (bool(inf.tenancy.tenants or inf.tenancy.manifest), "does not "
+             "support LoRA adapters (inference.tenancy): the adapter pack "
+             "is shaped for the Llama block's seven projections"),
+            (inf.spec_len > 0 or m.num_nextn_predict_layers > 0, "does not "
+             f"support speculation (inference.spec_len {inf.spec_len}, "
+             f"model.num_nextn_predict_layers {m.num_nextn_predict_layers}): "
+             "a rejected draft cannot be rolled back out of a recurrent "
+             "state by rewinding a length, so the multi-token-prediction "
+             "layer is not held"),
+            (inf.attend_impl == "flash", f"does not support "
+             f"inference.attend_impl {inf.attend_impl!r}: the recurrent "
+             "state has no kernel, and forcing one for the attention "
+             "layer's prefill chunks is untested ('auto' runs it for the "
+             "decode step)"),
+            (inf.overlap or inf.mixed_dispatch or inf.key_schedule == "slot"
+             or inf.dp_size > 1, "serves through the serial round-keyed "
+             "programs only: inference.overlap, mixed_dispatch, key_schedule "
+             "'slot' and dp_size > 1 are not implemented for it"),
+        )
+        for bad, why in refused:
+            if bad:
+                raise ValueError(f"{who} {why}")
+        for name in ("mamba_num_heads", "mamba_head_dim", "ssm_state_size",
+                     "conv_kernel", "n_groups", "chunk_size",
+                     "n_routed_experts", "num_experts_per_tok",
+                     "moe_intermediate_size", "moe_latent_size",
+                     "moe_shared_expert_intermediate_size", "ep_size"):
+            if getattr(m, name) < 1:
+                raise ValueError(f"{who} needs model.{name} >= 1")
+        if len(pattern) != m.num_hidden_layers or set(pattern) - set("ME*"):
+            raise ValueError(
+                f"{who} needs model.hybrid_override_pattern: one of 'M' | "
+                f"'E' | '*' for each of the {m.num_hidden_layers} layers "
+                f"(got {pattern!r}; '-', a dense MLP layer, is not "
+                "implemented)")
+        if "M" not in pattern or "*" not in pattern:
+            raise ValueError(
+                f"{who} needs at least one 'M' and one '*' layer in "
+                "model.hybrid_override_pattern: the cache holds a leaf of "
+                "each kind")
+        checks = (
+            (m.mamba_num_heads % m.n_groups, f"mamba_num_heads "
+             f"{m.mamba_num_heads} must be a multiple of n_groups "
+             f"{m.n_groups}"),
+            (m.num_attention_heads % m.num_key_value_heads,
+             f"num_attention_heads {m.num_attention_heads} must be a "
+             f"multiple of num_key_value_heads {m.num_key_value_heads}"),
+            (not 0 <= m.ep_rank < m.ep_size, f"ep_rank {m.ep_rank} outside "
+             f"[0, ep_size {m.ep_size})"),
+            (m.num_experts_per_tok > width, f"num_experts_per_tok "
+             f"{m.num_experts_per_tok} passes the router's width {width} "
+             "(n_routed_experts x ep_size)"),
+            (width % m.n_group or not 1 <= m.topk_group <= m.n_group,
+             f"n_group {m.n_group} must divide the router's width {width} "
+             f"and hold topk_group {m.topk_group}"),
+        )
+        for bad, why in checks:
+            if bad:
+                raise ValueError(f"{who}: {why}")
+        for name, want in (("use_conv_bias", True),
+                           ("mamba_proj_bias", False),
+                           ("mlp_hidden_act", "relu2"),
+                           ("n_shared_experts", 1),
+                           ("norm_topk_prob", True),
+                           ("tie_word_embeddings", False)):
+            if getattr(m, name) != want:
+                raise ValueError(
+                    f"{who} implements model.{name} = {want!r} only (got "
+                    f"{getattr(m, name)!r})")
 
     # ---- JSON round-trip (reference: train.py:62-63 consumes one JSON file) ----
 

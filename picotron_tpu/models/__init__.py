@@ -15,7 +15,9 @@ whatever the block:
   every layer function keeps ``llama.decoder_layer``'s contract and is
   handed its global index (a block whose cache leaves run over different
   layers, ``granite_hybrid``, ``minicpm_sala``, ``afmoe`` or ``mimo_v2``,
-  finds its own row from it); in a decode
+  finds its own row from it; what ``nemotron_h`` hands the scan as a layer
+  is a unit of its pattern, one sublayer of each kind at most,
+  ``nemotron_h.stacking``); in a decode
   block its cache dict also holds ``"active"`` [B] (parked and in budget),
   which a block that keeps K/V alone need not read;
 - ``UNSLICED``: names of a group's leaves the scan hands its layers
@@ -29,7 +31,8 @@ whatever the block:
   K/V and rings of a window's rows side by side for ``afmoe``; the same
   with keys wider than values and K/V heads counted by kind, four leaves of
   four shapes, for ``mimo_v2``; a token's K and V heads in one row and an
-  indexer's keys, two to a row, for ``keye_vl2``);
+  indexer's keys, two to a row, for ``keye_vl2``; two K/V heads of 128 a
+  token beside a float32 state for ``nemotron_h``);
 - ``RING_CACHE`` (absent: false): some leaves are rings a prefill chunk's
   writes must fit; ``init_cache`` then also takes ``prefill_chunk``;
 - ``CARRIES_STATE`` (absent: false): the cache holds a state with no token
@@ -39,6 +42,8 @@ whatever the block:
   ``"stats"`` in its dict (``()``: none, and the programs have no such
   output).
 """
+
+import importlib
 
 from picotron_tpu.models import llama  # noqa: F401
 
@@ -50,7 +55,9 @@ STATS = "stats"
 
 # What the blocks whose layers alternate between kinds of mixer
 # (``granite_hybrid``, ``minicpm_sala``, ``afmoe``, ``mimo_v2``) share: the
-# runs of the per-layer pattern, a layer's row of its own kind's cache leaves, the rows that count.
+# runs of the per-layer pattern, a layer's row of its own kind's cache leaves, the rows that count
+# (``nemotron_h``, whose runs are all one layer long, stacks by units of its
+# own and takes the last two).
 
 
 def runs(kinds) -> list:
@@ -86,34 +93,24 @@ def live_rows(cache, live, h):
     return live
 
 
+# ``model_type`` -> the module of ``picotron_tpu.models`` that builds it
+BLOCKS = {
+    "llama": "llama",
+    "deepseek_v32": "deepseek_v32",
+    "granitemoehybrid": "granite_hybrid",
+    "minicpm_sala": "minicpm_sala",
+    "afmoe": "afmoe",
+    "mimo_v2": "mimo_v2",
+    "KeyeVL2": "keye_vl2",
+    "nemotron_h": "nemotron_h",
+}
+
+
 def model_module(m):
     """The module that builds ``m.model_type``'s block (a ``ModelConfig``).
     Imported on demand: ``deepseek_v32`` needs the inference package, which
     needs this one."""
-    if m.model_type == "deepseek_v32":
-        from picotron_tpu.models import deepseek_v32
-
-        return deepseek_v32
-    if m.model_type == "granitemoehybrid":
-        from picotron_tpu.models import granite_hybrid
-
-        return granite_hybrid
-    if m.model_type == "minicpm_sala":
-        from picotron_tpu.models import minicpm_sala
-
-        return minicpm_sala
-    if m.model_type == "afmoe":
-        from picotron_tpu.models import afmoe
-
-        return afmoe
-    if m.model_type == "mimo_v2":
-        from picotron_tpu.models import mimo_v2
-
-        return mimo_v2
-    if m.model_type == "KeyeVL2":
-        from picotron_tpu.models import keye_vl2
-
-        return keye_vl2
-    if m.model_type == "llama":
-        return llama
-    raise ValueError(f"unknown model_type {m.model_type!r}")
+    if m.model_type not in BLOCKS:
+        raise ValueError(f"unknown model_type {m.model_type!r}")
+    return importlib.import_module(
+        "picotron_tpu.models." + BLOCKS[m.model_type])

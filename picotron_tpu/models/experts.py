@@ -3,12 +3,20 @@ held here, their part of the routed sum (every held expert over every row,
 as a loop or, where ``takes_pipelined``, as one pipelined pass; from
 ``takes_grouped`` rows up each expert over its own rows only), and what of
 it is counted. The expert blocks (``deepseek_v32``, ``granite_hybrid``, ``afmoe``, ``mimo_v2``,
-``keye_vl2``) route over the router's whole width (``route`` is the router
-of four of them: sigmoid scores and a correction bias for three, softmax
+``keye_vl2``, ``nemotron_h``) route over the router's whole width (``route`` is the router
+of five of them: sigmoid scores and a correction bias for four, softmax
 scores and no bias for ``keye_vl2``) and hand the choice here; the tree's leaves are named alike in
 all: ``w1``/``w3``/``w2`` ``[held, ...]`` a layer (the group's ``UNSLICED``
 stacks), ``ws_gate``/``ws_up``/``ws_down`` the shared expert, where the
-block has one."""
+block has one.
+
+An expert has one of two forms, and the tree says which: with a ``w3`` leaf
+(and a ``ws_gate``) the gated SwiGLU of three matrices, ``(silu(x w1) * (x
+w3)) w2``; without, two matrices and ``relu(x w1)^2 w2`` (``nemotron_h``).
+All three orders run either (``expert``; the kernels' ``_expert``), and
+nothing but those two functions knows the form. ``nemotron_h``'s routed
+experts also see another input than its shared expert (a latent the block
+projects into and out of): ``share``'s ``routed_in``/``routed_out``."""
 
 from __future__ import annotations
 
@@ -20,7 +28,8 @@ from picotron_tpu.ops.pallas import grouped_experts as grouped
 from picotron_tpu.utils import on_tpu
 
 # a group's leaves the layer scan does not slice a layer at a time: the
-# layer function is handed the whole stack and its row in it (``lp["row"]``)
+# layer function is handed the whole stack and its row in it (``lp["row"]``);
+# a tree of two-matrix experts has no ``w3``
 UNSLICED = ("w1", "w3", "w2")
 # what ``share`` counts of an expert layer's step, the head of every expert
 # block's ``STAT_NAMES``: assignments that landed on an expert held here,
@@ -73,6 +82,20 @@ def swiglu(x, w_gate, w_up, w_down):
     return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
+def relu2(x, w_up, w_down):
+    return jnp.square(jax.nn.relu(x @ w_up)) @ w_down
+
+
+def expert(x, *ws):
+    """One expert over ``x``, its form by the count of its matrices."""
+    return swiglu(x, *ws) if len(ws) == 3 else relu2(x, *ws)
+
+
+def expert_leaves(lp) -> list:
+    """The names of a layer's expert matrices, in ``expert``'s order."""
+    return [n for n in UNSLICED if n in lp]
+
+
 def takes_grouped(rows: int) -> bool:
     """Whether ``rows`` rows go through the held experts grouped. The loop
     runs every held expert over every row: ``6 H I`` FLOPs an expert-row
@@ -115,11 +138,12 @@ def group_rows(w_held, tile: int) -> tuple:
 
 
 def _stacks(lp) -> tuple:
-    """(this layer's row in them, int32 [1]; ``w1``, ``w3``, ``w2`` as
+    """(this layer's row in them, int32 [1]; ``w1``[, ``w3``], ``w2`` as
     stacks [layers, held, ...]) of a layer's leaves: the group's own stacks
     beside ``lp["row"]``, or the layer's matrices as a stack of one."""
     row = lp.get("row")
-    stacks = [lp[n] if row is not None else lp[n][None] for n in UNSLICED]
+    stacks = [lp[n] if row is not None else lp[n][None]
+              for n in expert_leaves(lp)]
     return jnp.asarray(0 if row is None else row, jnp.int32)[None], stacks
 
 
@@ -229,9 +253,11 @@ def routed_experts(x, w_held, lp) -> tuple:
         w = lp[name]
         return w[e] if row is None else w[row, e]
 
+    names = expert_leaves(lp)
+
     def one(acc, xs):
         w, e = xs
-        y = swiglu(x, weights("w1", e), weights("w3", e), weights("w2", e))
+        y = expert(x, *(weights(n, e) for n in names))
         return acc + y.astype(jnp.float32) * w[:, None], None
 
     acc, _ = lax.scan(one, jnp.zeros(x.shape, jnp.float32),
@@ -239,20 +265,27 @@ def routed_experts(x, w_held, lp) -> tuple:
     return acc, run, jnp.zeros((), jnp.int32)
 
 
-def share(lp, x2, w_held) -> tuple:
+def share(lp, x2, w_held, routed_in=None, routed_out=None) -> tuple:
     """(the held experts' part of the routed sum + the shared expert
     [N, H], the layer step's counts in the order of ``STAT_NAMES``) for
     tokens ``x2`` [N, H] weighted ``w_held`` [N, held] (rows that are not
-    live: all 0). A layer whose leaves hold no ``ws_gate`` has no shared
-    expert (``mimo_v2``, ``keye_vl2``)."""
+    live: all 0). A layer whose leaves hold no ``ws_up`` has no shared
+    expert (``mimo_v2``, ``keye_vl2``); one with ``ws_gate`` a gated one,
+    one without a ``relu2``. Where the routed experts do not see what the
+    shared expert sees (``nemotron_h``: a latent), ``routed_in`` [N, width]
+    is their rows and ``routed_out`` takes their sum back to [N, H] before
+    the shared expert's is added (linear, so the chips' shares still add
+    up)."""
     with jax.named_scope("moe_experts"):
-        y, run, pipelined = routed_experts(x2, w_held, lp)
-    if "ws_gate" in lp:
+        y, run, pipelined = routed_experts(
+            x2 if routed_in is None else routed_in, w_held, lp)
+    y = y.astype(x2.dtype)
+    if routed_out is not None:
+        y = routed_out(y)
+    if "ws_up" in lp:
         with jax.named_scope("shared_expert"):
-            y = y.astype(x2.dtype) + swiglu(x2, lp["ws_gate"], lp["ws_up"],
-                                            lp["ws_down"])
-    else:
-        y = y.astype(x2.dtype)
+            y = y + expert(x2, *(lp[n] for n in ("ws_gate", "ws_up",
+                                                 "ws_down") if n in lp))
     assigned = jnp.sum(w_held > 0, dtype=jnp.int32)
     hit = jnp.sum(jnp.any(w_held > 0, axis=0), dtype=jnp.int32)
     return y, (assigned, hit, jnp.ones((), jnp.int32), run, pipelined)

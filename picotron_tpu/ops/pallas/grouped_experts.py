@@ -1,7 +1,11 @@
-"""The held experts' SwiGLU as Pallas kernels: grouped, each held expert over
+"""The held experts as Pallas kernels: grouped, each held expert over
 its own rows only (``grouped_swiglu``, below), and pipelined, every held
 expert over every row in one pass (``pipelined_swiglu``, at the end: the
-loop's work in the loop's order, for the rows below the ridge).
+loop's work in the loop's order, for the rows below the ridge). An expert
+has one of two forms, told by the count of its matrices (``_expert``): three,
+the gated SwiGLU ``(silu(x w1) * (x w3)) w2`` the kernels are named for; two,
+``relu(x w1)^2 w2`` (``models/nemotron_h.py``). Nothing else of either
+kernel knows the form.
 
 ``sum_e w[:, e] * E_e(x)`` for rows ``x`` [N, H] and the experts a chip
 holds (``w1``/``w3`` [L, E, H, I], ``w2`` [L, E, I, H]: the stacks of every
@@ -47,7 +51,8 @@ WEIGHT_VMEM = 40 << 20
 def fits(H: int, ti: int, itemsize: int) -> bool:
     """Whether a step's three weight blocks, ``ti`` of the expert's width
     each, stay inside ``WEIGHT_VMEM`` twice over (the blocks in use, the
-    blocks being fetched)."""
+    blocks being fetched). An expert of two matrices is held to the same
+    rule: it has room to spare."""
     return 3 * H * ti * itemsize * 2 <= WEIGHT_VMEM
 
 
@@ -60,22 +65,27 @@ def _block_i(H: int, I: int, itemsize: int) -> int:
     return ti
 
 
-def _swiglu(x, w1_ref, w3_ref, w2_ref):
-    """float32 ``(silu(x w1) * (x w3)) w2`` over the blocks in VMEM: operands
-    of the model's dtype, float32 accumulation, each product rounded to the
-    dtype where the loop's ``swiglu`` rounds it."""
+def _expert(x, w_refs):
+    """float32 ``E(x)`` over an expert's blocks in VMEM, ``(w1, w3, w2)``:
+    ``(silu(x w1) * (x w3)) w2``, or ``(w1, w2)``: ``relu(x w1)^2 w2``.
+    Operands of the model's dtype, float32 accumulation, each product
+    rounded to the dtype where the loop's ``swiglu`` / ``relu2`` rounds
+    it."""
     dtype = x.dtype
-    gate = jnp.dot(x, w1_ref[...],
-                   preferred_element_type=jnp.float32).astype(dtype)
-    up = jnp.dot(x, w3_ref[...],
-                 preferred_element_type=jnp.float32).astype(dtype)
-    h = (jax.nn.silu(gate.astype(jnp.float32))
-         * up.astype(jnp.float32)).astype(dtype)
-    return jnp.dot(h, w2_ref[...], preferred_element_type=jnp.float32)
+    *ups, w2_ref = w_refs
+    ups = [jnp.dot(x, ref[...], preferred_element_type=jnp.float32
+                   ).astype(dtype).astype(jnp.float32) for ref in ups]
+    if len(ups) == 2:
+        h = jax.nn.silu(ups[0]) * ups[1]
+    else:
+        h = jnp.square(jax.nn.relu(ups[0]))
+    return jnp.dot(h.astype(dtype), w2_ref[...],
+                   preferred_element_type=jnp.float32)
 
 
 def _kernel(te_ref, tb_ref, meta_ref, rank_ref, rank_t_ref, w_ref, x_ref,
-            w1_ref, w3_ref, w2_ref, o_ref, xt_ref, acc_ref, *, tm, hc):
+            *refs, tm, hc):
+    *w_refs, o_ref, xt_ref, acc_ref = refs
     c, t, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     N, H = x_ref.shape
     E = w_ref.shape[1]
@@ -101,7 +111,7 @@ def _kernel(te_ref, tb_ref, meta_ref, rank_ref, rank_t_ref, w_ref, x_ref,
                                   ).astype(dtype)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        acc_ref[...] += _swiglu(xt_ref[...], w1_ref, w3_ref, w2_ref)
+        acc_ref[...] += _expert(xt_ref[...], w_refs)
 
         @pl.when(j == pl.num_programs(2) - 1)
         def _():
@@ -119,9 +129,9 @@ def _kernel(te_ref, tb_ref, meta_ref, rank_ref, rank_t_ref, w_ref, x_ref,
                     preferred_element_type=jnp.float32)
 
 
-def grouped_swiglu(x, w_held, rank, tile_expert, tile_base, meta, w1, w3, w2,
-                   *, rows: int, tile: int, interpret: bool = False):
-    """float32 [N, H]: ``sum_e w_held[:, e] * swiglu_e(x)`` over the
+def grouped_swiglu(x, w_held, rank, tile_expert, tile_base, meta, *ws,
+                   rows: int, tile: int, interpret: bool = False):
+    """float32 [N, H]: ``sum_e w_held[:, e] * E_e(x)`` over the
     assignments ``rank`` names, ``rows`` rows of ``x`` at a time (N a whole
     number of them, ``rows`` of whole tiles): each stretch's rows and their
     float32 sum stay in VMEM while its tiles run, and reads every expert it
@@ -131,9 +141,10 @@ def grouped_swiglu(x, w_held, rank, tile_expert, tile_base, meta, w1, w3, w2,
     [stretches * tiles] int32, each tile's expert and the rank of its first
     slot (the tiles of a stretch that run nothing repeat its last that
     does); ``meta`` int32 [1 + stretches]: the layer's row in the stacks,
-    then each stretch's tiles to run; ``w1``/``w3`` [L, E, H, I], ``w2``
-    [L, E, I, H]."""
+    then each stretch's tiles to run; ``ws`` the expert stacks, ``w1``[,
+    ``w3``] [L, E, H, I] and, last, ``w2`` [L, E, I, H]."""
     N, H = x.shape
+    w1 = ws[0]
     E, I = w1.shape[1], w1.shape[3]
     ti = _block_i(H, I, w1.dtype.itemsize)
     steps, stretches = I // ti, N // rows
@@ -155,7 +166,8 @@ def grouped_swiglu(x, w_held, rank, tile_expert, tile_base, meta, w1, w3, w2,
                                          lambda c, t, j, *_: (c, 0))
     itemsize = x.dtype.itemsize
     vmem = (2 * rows * H * (itemsize + 4)  # x, the sum: two buffers each
-            + 2 * 3 * H * ti * w1.dtype.itemsize + tile * H * (itemsize + 4)
+            + 2 * len(ws) * H * ti * w1.dtype.itemsize
+            + tile * H * (itemsize + 4)
             + (16 << 20))  # ranks, weights, one-hots, a column block's sum
     return pl.pallas_call(
         functools.partial(_kernel, tm=tile, hc=_pick_block(H, 1024)),
@@ -165,8 +177,8 @@ def grouped_swiglu(x, w_held, rank, tile_expert, tile_base, meta, w1, w3, w2,
             in_specs=[stretch(E),
                       pl.BlockSpec((E, rows), lambda c, t, j, *_: (0, c)),
                       stretch(E), stretch(H),
-                      pl.BlockSpec((None, None, H, ti), up_map),
-                      pl.BlockSpec((None, None, H, ti), up_map),
+                      *[pl.BlockSpec((None, None, H, ti), up_map)] *
+                      (len(ws) - 1),
                       pl.BlockSpec((None, None, ti, H), down_map)],
             out_specs=stretch(H),
             scratch_shapes=[pltpu.VMEM((tile, H), x.dtype),
@@ -176,28 +188,29 @@ def grouped_swiglu(x, w_held, rank, tile_expert, tile_base, meta, w1, w3, w2,
             dimension_semantics=("arbitrary",) * 3, vmem_limit_bytes=vmem),
         interpret=interpret,
         name="grouped_experts",
-    )(tile_expert, tile_base, meta, rank, rank.T, w_held, x, w1, w3, w2)
+    )(tile_expert, tile_base, meta, rank, rank.T, w_held, x, *ws)
 
 
-def _pipelined_kernel(row_ref, w_ref, x_ref, w1_ref, w3_ref, w2_ref, o_ref):
+def _pipelined_kernel(row_ref, w_ref, x_ref, *refs):
+    *w_refs, o_ref = refs
     e = pl.program_id(0)
 
     @pl.when(e == 0)
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    y = _swiglu(x_ref[...], w1_ref, w3_ref, w2_ref).astype(x_ref.dtype)
+    y = _expert(x_ref[...], w_refs).astype(x_ref.dtype)
     mine = lax.broadcasted_iota(jnp.int32, w_ref.shape, 1) == e
     w = jnp.sum(jnp.where(mine, w_ref[...], 0.0), axis=1, keepdims=True)
     o_ref[...] += y.astype(jnp.float32) * w
 
 
-def pipelined_swiglu(x, w_held, row, w1, w3, w2, *, interpret: bool = False):
-    """float32 [N, H]: ``sum_e w_held[:, e] * swiglu_e(x)``, every held
+def pipelined_swiglu(x, w_held, row, *ws, interpret: bool = False):
+    """float32 [N, H]: ``sum_e w_held[:, e] * E_e(x)``, every held
     expert over every row, expert 0 first, as the loop of ``models/experts.py
     ::routed_experts`` runs it: the grid is the held experts, ALL of them
-    whatever ``w_held`` holds, one step each with the expert's three
-    matrices whole (the caller sees that they ``fits``: every DMA is one
+    whatever ``w_held`` holds, one step each with the expert's
+    matrices (``ws``: three or two, ``_expert``) whole (the caller sees that they ``fits``: every DMA is one
     contiguous matrix). The rows and their float32 sum stay in VMEM for the
     whole call, and step ``e``'s matmuls run while step ``e + 1``'s matrices
     are fetched out of the stacks in place (layer ``row`` of ``w1``/``w3``
@@ -205,18 +218,19 @@ def pipelined_swiglu(x, w_held, row, w1, w3, w2, *, interpret: bool = False):
     expert each start with an empty pipeline. ``x`` [N, H], N whole sublane
     tiles of its dtype; ``w_held`` [N, E] float32."""
     N, H = x.shape
+    w1 = ws[0]
     E, I = w1.shape[1], w1.shape[3]
     held = lambda e, row: (row[0], e, 0, 0)
     rows = lambda width: pl.BlockSpec((N, width), lambda e, row: (0, 0))
     up = pl.BlockSpec((None, None, H, I), held)
     vmem = (2 * N * H * (x.dtype.itemsize + 4)  # x, the sum: two buffers each
-            + 2 * 3 * H * I * w1.dtype.itemsize + (16 << 20))
+            + 2 * len(ws) * H * I * w1.dtype.itemsize + (16 << 20))
     return pl.pallas_call(
         _pipelined_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(E,),
-            in_specs=[rows(E), rows(H), up, up,
+            in_specs=[rows(E), rows(H), *[up] * (len(ws) - 1),
                       pl.BlockSpec((None, None, I, H), held)],
             out_specs=rows(H)),
         out_shape=jax.ShapeDtypeStruct((N, H), jnp.float32),
@@ -224,4 +238,4 @@ def pipelined_swiglu(x, w_held, row, w1, w3, w2, *, interpret: bool = False):
             dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem),
         interpret=interpret,
         name="pipelined_experts",
-    )(row, w_held, x, w1, w3, w2)
+    )(row, w_held, x, *ws)

@@ -6,13 +6,19 @@ the input, one ``B``/``C`` for all heads) and ``models/minicpm_sala.py``
 = q``, each per head) prefill with the chunked scan in matmul form
 (``ssm_scan``) and decode with the one-row step (``ssm_step``).
 
-``Bm``/``Cm`` are [B, S, d_state] (shared by the heads) or [B, S, heads,
-d_state] (a head's own); the rank picks the contraction, nothing else
-differs. ``dt = 0`` leaves the state exactly as it is (``exp(0) S + 0``):
+``Bm``/``Cm`` are [B, S, d_state] (shared by the heads), [B, S, heads,
+d_state] (a head's own) or [B, S, groups, d_state] with fewer groups than
+heads (``models/nemotron_h.py``: eight groups of sixteen heads; head ``h``
+reads group ``h // (heads / groups)``); the rank picks the contraction, and a
+group's heads are the shared form, a group at a time (``_by_group``: B and C
+are never written out a head). ``dt = 0`` leaves the state exactly as it is (``exp(0) S + 0``):
 how a pad row or a parked slot is kept out of it."""
 
 from __future__ import annotations
 
+from functools import partial
+
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -20,16 +26,36 @@ F32 = jnp.float32
 HIGHEST = lax.Precision.HIGHEST
 
 
+def _by_group(fn, xs, dt, A, Bm, Cm, S_in) -> tuple:
+    """``fn`` (``ssm_scan`` or ``ssm_step``) with ``Bm``/``Cm`` [B, S,
+    groups, d_state], a group of neighbouring heads to each: the shared form
+    over a group's heads, mapped over the groups."""
+    B, S, nh, hd = xs.shape
+    G = Bm.shape[2]
+    R = nh // G
+    y, state = jax.vmap(fn, in_axes=(2, 2, 0, 2, 2, 1), out_axes=(2, 1))(
+        xs.reshape(B, S, G, R, hd), dt.reshape(B, S, G, R), A.reshape(G, R),
+        Bm, Cm, S_in.reshape(B, G, R, *S_in.shape[2:]))
+    return y.reshape(B, -1, nh, hd), state.reshape(S_in.shape)
+
+
+def _grouped(xs, Bm) -> bool:
+    return Bm.ndim == 4 and Bm.shape[2] != xs.shape[2]
+
+
 def ssm_scan(xs, dt, A, Bm, Cm, S_in, chunk: int) -> tuple:
     """The recurrence over a whole block of rows, ``chunk`` at a time in
     matmul form: (y [B, S, heads, d_head] float32 without the ``D`` skip,
     the state after the last row). ``xs`` [B, S, heads, d_head], ``dt`` [B,
     S, heads] float32 (0: the row leaves the state as it is), ``A`` [heads],
-    ``Bm``/``Cm`` [B, S, d_state] or [B, S, heads, d_state], ``S_in`` [B,
-    heads, d_head, d_state] float32. Within a chunk, with ``L = cumsum(dt
+    ``Bm``/``Cm`` [B, S, d_state], [B, S, heads, d_state] or [B, S, groups,
+    d_state], ``S_in`` [B, heads, d_head, d_state] float32. Within a chunk, with ``L = cumsum(dt
     A)``: ``Y = ((C B^T) * exp(L_t - L_s) * [s <= t]) (dt x) + exp(L_t) C
     S_in`` and ``S_out = exp(L_Q) S_in + sum_s exp(L_Q - L_s) dt_s x_s (x)
     B_s``."""
+    if _grouped(xs, Bm):
+        return _by_group(partial(ssm_scan, chunk=chunk), xs, dt, A, Bm, Cm,
+                         S_in)
     B, S, nh, hd = xs.shape
     per_head = Bm.ndim == 4
     Q = min(chunk, S)
@@ -79,6 +105,8 @@ def ssm_step(xs, dt, A, Bm, Cm, S_in) -> tuple:
     """One row a sequence, the recurrence as it is written: (y [B, 1,
     heads, d_head] float32 without the skip, the new state). One pass over
     the state: elementwise in float32, the read-out a sum over d_state."""
+    if _grouped(xs, Bm):
+        return _by_group(ssm_step, xs, dt, A, Bm, Cm, S_in)
 
     def over_heads(a):  # -> broadcasts against [B, heads, d_head, d_state]
         a = a[:, 0].astype(F32)

@@ -1244,6 +1244,14 @@ class _Handler(BaseHTTPRequestHandler):
                 return
 
 
+class _HTTPServer(ThreadingHTTPServer):
+    # a closed loop's clients all connect at once. Past the listen backlog
+    # (socketserver's default: 5) the kernel drops a SYN, and the client
+    # sends it again after 1, 3, 7, 15, 31, 63 s: of 128 clients one reached
+    # the server 63 s behind the others in two runs of six (PERF.md, PR 56)
+    request_queue_size = 1024
+
+
 class Server:
     """FrontEnd + ThreadingHTTPServer, both on background threads. The
     embedding entry point for the CLI, the smoke drive, and the tests."""
@@ -1251,7 +1259,7 @@ class Server:
     def __init__(self, engine, params, *, host: str = "127.0.0.1",
                  port: int = 0, **front_kw):
         self.front = FrontEnd(engine, params, **front_kw)
-        self.httpd = ThreadingHTTPServer((host, port), _Handler)
+        self.httpd = _HTTPServer((host, port), _Handler)
         self.httpd.front = self.front
         self.port = self.httpd.server_address[1]
         self._http_thread: Optional[threading.Thread] = None

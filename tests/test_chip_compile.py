@@ -960,6 +960,42 @@ def test_recurrent_state_is_row_major_and_never_copied(prog, topo, one_chip,
     assert not sliced, "\n".join(sliced)
 
 
+@pytest.mark.parametrize("prog", ["decode_block", "prefill_chunk"])
+def test_nemotron_state_and_packed_kv_stay_in_place(prog, topo, one_chip,
+                                                    experts_on_chip):
+    """The Nemotron-H cell's programs at its own size (PR 56: 128 slots x
+    8192 beside 9.30 GB of weights): the float32 state leaf (2.68 GB) and
+    the K/V leaves, two heads of 128 a token under the compiler's own (2,
+    128) tile, stay row-major and no instruction copies them (the two heads
+    side by side in one 256-lane row were padded to two rows and copied
+    whole, three times a program); a decode block runs the 128 held two-matrix
+    experts as the pipelined pass, a chunk as the grouped kernel, neither
+    behind a copy of a layer's slice of the stacks (1.41 GB); and the
+    programs' temporaries (0.60 and 0.54 GB) leave the resident 13.09 GB
+    its room."""
+    compiled = _cell_program(topo, prog, "nemotron-3-super-ep4-l11")
+    text = compiled.as_text()
+    _assert_expert_orders(text, prog, pipelined=True)
+    lines = text.splitlines()
+    state = r"f32\[5,128,128,64,128\]"
+    kv = r"bf16\[1,128,8192,2,128\]"
+    copies = [l.strip()[:160] for l in lines
+              if re.search(rf"= (?:{state}|{kv})\S* copy\(", l)]
+    assert not copies, "\n".join(copies)
+    for leaf, shape in (("ssm", state), ("k", kv), ("v", kv)):
+        params = [l for l in lines
+                  if re.search(rf"cache__{leaf}__\S* = {shape}", l)
+                  and " parameter(" in l]
+        assert len(params) == 1 and "{4,3,2,1,0" in params[0], params
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.8e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14.0e9
+    sliced = [l.strip()[:160] for l in lines
+              if re.search(r"= bf16\[(?:1,)?128,(?:1024,2688|2688,1024)\]", l)
+              and " parameter(" not in l and "get-tuple-element" not in l]
+    assert not sliced, "\n".join(sliced)
+
+
 def test_trinity_decode_block_keeps_the_loop(topo, one_chip, monkeypatch,
                                              experts_on_chip):
     """The Trinity cell's decode block (16 rows over eight held experts of

@@ -312,3 +312,28 @@ def test_metrics_show_tokens_an_event(tapped_server):
     assert prom["picotron_generated_tokens_total"] == 2 * n
     assert not [w for w in writes if b'"uid": "m1"' in w
                 and b'"event"' in w]
+
+
+def test_a_burst_of_connections_is_held_not_dropped():
+    """A closed loop's clients all connect at once (128 in the Nemotron
+    cell). The listening socket holds them until the accept loop takes
+    them: past a backlog of 5, socketserver's default, the kernel drops the
+    SYN and the client's next one comes 1, 3, 7, ... 63 s later (ISSUE 56:
+    one of 128 streams began 63 s behind the rest). Here nobody accepts at
+    all, and 96 connections still complete at once."""
+    import socket
+
+    from picotron_tpu.tools import serve
+
+    engine, params = _engine()
+    srv = serve.Server(engine, params, port=0, log=lambda *a, **k: None)
+    socks = []
+    try:
+        assert srv.httpd.request_queue_size >= 1024
+        for _ in range(96):
+            socks.append(socket.create_connection(("127.0.0.1", srv.port),
+                                                  timeout=0.5))
+    finally:
+        for s in socks:
+            s.close()
+        srv.httpd.server_close()
