@@ -281,12 +281,14 @@ def init_cache(m: ModelConfig, slots: int, max_seq_len: int, dtype=None,
 
 
 def mamba_mixer(lp, x, conv_in, ssm_in, live, m: ModelConfig,
-                one_step: bool) -> tuple:
+                one_step: tuple) -> tuple:
     """The mixer on the normed stream ``x`` [B, S, H] from the conv's last
     inputs ``conv_in`` [B, d_conv - 1, width] and the state ``ssm_in``:
     (output [B, S, H], the conv's last inputs and the state behind the last
     ``live`` row). ``live`` [B, S] marks the real rows, a leading run of
-    each sequence."""
+    each sequence. ``one_step`` is empty, or on a decode step ``(row,)``:
+    ``ssm_in`` is then the whole stacked leaf and so is the state returned,
+    that row of it advanced where it lies (``ops/ssm.py::ssm_step``)."""
     B, S, _ = x.shape
     nh, hd, N, K = (m.mamba_n_heads, m.mamba_d_head, m.mamba_d_state,
                     m.mamba_d_conv)
@@ -312,7 +314,7 @@ def mamba_mixer(lp, x, conv_in, ssm_in, live, m: ModelConfig,
     A = -jnp.exp(lp["A_log"])
     if one_step:
         with jax.named_scope("ssm_step"):
-            y, ssm_out = ssm_step(xs, dt, A, Bm, Cm, ssm_in)
+            y, ssm_out = ssm_step(xs, dt, A, Bm, Cm, ssm_in, *one_step)
     else:
         with jax.named_scope("ssm_scan"):
             y, ssm_out = ssm_scan(xs, dt, A, Bm, Cm, ssm_in,
@@ -384,6 +386,7 @@ def mamba_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
     n_live = jnp.sum(live, dtype=jnp.int32)
     zero = jnp.zeros((), jnp.int32)
     decode = cache is not None and "slot" not in cache
+    step = ()
     if cache is None:
         conv_in = jnp.zeros((B, m.mamba_d_conv - 1, conv_width(m)), h.dtype)
         ssm_in = jnp.zeros((B, m.mamba_n_heads, m.mamba_d_head,
@@ -397,7 +400,11 @@ def mamba_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
         # ``kv_cache.cache_write`` holds a packed K/V leaf)
         pin = (lambda x: x) if decode else kv_cache.row_major
         conv_in = lax.dynamic_index_in_dim(pin(cache["conv"]), row, 0, False)
-        ssm_in = lax.dynamic_index_in_dim(pin(cache["ssm"]), row, 0, False)
+        # a decode step hands the mixer the state leaf whole and the row
+        if decode and h.shape[1] == 1:
+            step = (row,)
+        ssm_in = cache["ssm"] if step else lax.dynamic_index_in_dim(
+            pin(cache["ssm"]), row, 0, False)
         if not decode:
             slot = jnp.asarray(cache["slot"], jnp.int32)
             conv_in = lax.dynamic_slice_in_dim(conv_in, slot, 1, axis=0)
@@ -409,7 +416,7 @@ def mamba_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
             ssm_in = jnp.where(fresh, jnp.zeros_like(ssm_in), ssm_in)
     y, conv_out, ssm_out = mamba_mixer(
         lp, rms_norm(h, lp["mixer_norm"], m.rms_norm_eps), conv_in, ssm_in,
-        live, m, one_step=decode and h.shape[1] == 1)
+        live, m, one_step=step)
     h = h + jnp.asarray(m.residual_multiplier, h.dtype) * y
     if cache is None:
         out = {"ssm": ssm_out, "conv": conv_out} if return_kv else {}
@@ -418,7 +425,9 @@ def mamba_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
         for name, new, old in (("conv", conv_out, conv_in),
                                ("ssm", ssm_out, ssm_in)):
             new = new.astype(cache[name].dtype)
-            if decode:
+            if step and name == "ssm":  # the leaf itself, its row advanced
+                out[name] = new
+            elif decode:
                 out[name] = lax.dynamic_update_index_in_dim(
                     cache[name], new, row, 0)
             else:

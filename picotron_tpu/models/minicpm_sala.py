@@ -318,12 +318,15 @@ def _finish(lp, h, m: ModelConfig, out: dict, stats: tuple):
 
 
 def lightning_mixer(lp, x, cos, sin, state_in, live, m: ModelConfig,
-                    one_step: bool) -> tuple:
+                    one_step: tuple) -> tuple:
     """The mixer on the normed stream ``x`` [B, S, H] from the state
     ``state_in`` [B, heads, d, d]: (output [B, S, H], the state behind the
     last ``live`` row). The recurrence is ``ops/ssm.py``'s with ``dt = 1``
     on live rows and 0 elsewhere, ``A = -slope``, ``x = v``, ``B = k``, ``C =
-    q``, each head its own."""
+    q``, each head its own. ``one_step`` is empty, or on a decode step
+    ``(row,)``: ``state_in`` is then the whole stacked leaf and so is the
+    state returned, that row of it advanced where it lies
+    (``ops/ssm.py::ssm_step``)."""
     B, S, _ = x.shape
     nh, hd = m.lightning_nh, m.lightning_head_dim
     eps = m.rms_norm_eps
@@ -335,7 +338,7 @@ def lightning_mixer(lp, x, cos, sin, state_in, live, m: ModelConfig,
     A = -lp["slope"].astype(F32)
     if one_step:
         with jax.named_scope("sala/lightning_step"):
-            y, state = ssm_step(v, dt, A, k, q, state_in)
+            y, state = ssm_step(v, dt, A, k, q, state_in, *one_step)
     else:
         with jax.named_scope("sala/lightning_scan"):
             y, state = ssm_scan(v, dt, A, k, q, state_in, SCAN_CHUNK)
@@ -360,16 +363,20 @@ def lightning_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
     n_live = jnp.sum(live, dtype=jnp.int32)
     zero = jnp.zeros((), jnp.int32)
     decode = cache is not None and "slot" not in cache
+    step = ()
     if cache is None:
         state_in = jnp.zeros((B, m.lightning_nh, m.lightning_head_dim,
                               m.lightning_head_dim), F32)
     else:
         row = leaf_row(layer, first, kind_first)
+        # a decode step hands the mixer the state leaf whole and the row
+        if decode and h.shape[1] == 1:
+            step = (row,)
         # a chunk's contractions are held to the leaf's own layout, as
         # ``granite_hybrid.mamba_layer`` holds its state
         pin = (lambda a: a) if decode else kv_cache.row_major
-        state_in = lax.dynamic_index_in_dim(pin(cache["state"]), row, 0,
-                                            False)
+        state_in = cache["state"] if step else lax.dynamic_index_in_dim(
+            pin(cache["state"]), row, 0, False)
         if not decode:
             slot = jnp.asarray(cache["slot"], jnp.int32)
             state_in = lax.dynamic_slice_in_dim(state_in, slot, 1, axis=0)
@@ -378,13 +385,15 @@ def lightning_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
                                  state_in)
     y, state = lightning_mixer(
         lp, rms_norm(h, lp["mixer_norm"], m.rms_norm_eps), cos, sin,
-        state_in, live, m, one_step=decode and h.shape[1] == 1)
+        state_in, live, m, one_step=step)
     h = h + jnp.asarray(residual_scale(m), h.dtype) * y
     if cache is None:
         out = {"state": state} if return_kv else {}
     else:
         out = _leaves(cache)
-        if decode:
+        if step:  # the leaf itself, its row advanced
+            out["state"] = state
+        elif decode:
             out["state"] = lax.dynamic_update_index_in_dim(
                 cache["state"], state, row, 0)
         else:

@@ -333,12 +333,14 @@ def init_cache(m: ModelConfig, slots: int, max_seq_len: int, dtype=None,
 
 
 def mamba_mixer(lp, x, conv_in, ssm_in, live, m: ModelConfig,
-                one_step: bool) -> tuple:
+                one_step: tuple) -> tuple:
     """The mixer on the normed stream ``x`` [B, S, H] from the conv's last
     inputs ``conv_in`` [B, conv_kernel - 1, width] and the state ``ssm_in``:
     (output [B, S, H], the conv's last inputs and the state behind the last
     ``live`` row). ``live`` [B, S] marks the real rows, a leading run of
-    each sequence."""
+    each sequence. ``one_step`` is empty, or on a decode step ``(row,)``:
+    ``ssm_in`` is then the whole stacked leaf and so is the state returned,
+    that row of it advanced where it lies (``ops/ssm.py::ssm_step``)."""
     B, S, _ = x.shape
     nh, hd, N, K, G = (m.mamba_num_heads, m.mamba_head_dim, m.ssm_state_size,
                        m.conv_kernel, m.n_groups)
@@ -365,7 +367,7 @@ def mamba_mixer(lp, x, conv_in, ssm_in, live, m: ModelConfig,
     A = -jnp.exp(lp["A_log"])
     if one_step:
         with jax.named_scope("nemotron/ssm_step"):
-            y, ssm_out = ssm_step(xs, dt, A, Bm, Cm, ssm_in)
+            y, ssm_out = ssm_step(xs, dt, A, Bm, Cm, ssm_in, *one_step)
     else:
         with jax.named_scope("nemotron/ssm_scan"):
             y, ssm_out = ssm_scan(xs, dt, A, Bm, Cm, ssm_in, m.chunk_size)
@@ -391,6 +393,8 @@ def mamba_sublayer(lp, h, cfg: Config, cache, out: dict, pos, return_kv,
     n_live = jnp.sum(live, dtype=jnp.int32)
     zero = jnp.zeros((), jnp.int32)
     decode = cache is not None and "slot" not in cache
+    # a decode step hands the mixer the state leaf whole and the row
+    step = (row,) if decode and h.shape[1] == 1 else ()
     if cache is None:
         conv_in = jnp.zeros((B, m.conv_kernel - 1, conv_width(m)), h.dtype)
         ssm_in = jnp.zeros((B, m.mamba_num_heads, m.mamba_head_dim,
@@ -402,7 +406,8 @@ def mamba_sublayer(lp, h, cfg: Config, cache, out: dict, pos, return_kv,
         # and push it back on exit)
         pin = (lambda x: x) if decode else kv_cache.row_major
         conv_in = lax.dynamic_index_in_dim(pin(out["conv"]), row, 0, False)
-        ssm_in = lax.dynamic_index_in_dim(pin(out["ssm"]), row, 0, False)
+        ssm_in = out["ssm"] if step else lax.dynamic_index_in_dim(
+            pin(out["ssm"]), row, 0, False)
         if not decode:
             slot = jnp.asarray(cache["slot"], jnp.int32)
             conv_in = lax.dynamic_slice_in_dim(conv_in, slot, 1, axis=0)
@@ -414,7 +419,7 @@ def mamba_sublayer(lp, h, cfg: Config, cache, out: dict, pos, return_kv,
             ssm_in = jnp.where(fresh, jnp.zeros_like(ssm_in), ssm_in)
     y, conv_out, ssm_out = mamba_mixer(
         lp, rms_norm(h, lp["m_norm"], m.rms_norm_eps), conv_in, ssm_in,
-        live, m, one_step=decode and h.shape[1] == 1)
+        live, m, one_step=step)
     if cache is None:
         if return_kv:
             out.update(ssm=ssm_out, conv=conv_out)
@@ -422,7 +427,9 @@ def mamba_sublayer(lp, h, cfg: Config, cache, out: dict, pos, return_kv,
         for name, new, old in (("conv", conv_out, conv_in),
                                ("ssm", ssm_out, ssm_in)):
             new = new.astype(out[name].dtype)
-            if decode:
+            if step and name == "ssm":  # the leaf itself, its row advanced
+                out[name] = new
+            elif decode:
                 out[name] = lax.dynamic_update_index_in_dim(
                     out[name], new, row, 0)
             else:

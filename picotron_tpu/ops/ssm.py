@@ -4,7 +4,14 @@ B_t``, ``y_t = S_t C_t``. ``models/granite_hybrid.py`` (Mamba-2: ``dt`` from
 the input, one ``B``/``C`` for all heads) and ``models/minicpm_sala.py``
 (lightning attention: ``dt = 1``, ``A = -slope``, ``x = v``, ``B = k``, ``C
 = q``, each per head) prefill with the chunked scan in matmul form
-(``ssm_scan``) and decode with the one-row step (``ssm_step``).
+(``ssm_scan``) and decode with the one-row step (``ssm_step``), taken on the
+layer's row of the stacked state leaf (``row=``): on a TPU one Pallas kernel
+that passes over that row once, in place
+(``ops/pallas/ssm_step.py::ssm_step_stacked``: read, decay, add, write back,
+read out), and off one (every CPU test and drive) the elementwise step
+between a slice of the row and an update back. The platform and the
+operands' shapes choose, as ``quant_matmul`` chooses its form: no option
+does.
 
 ``Bm``/``Cm`` are [B, S, d_state] (shared by the heads), [B, S, heads,
 d_state] (a head's own) or [B, S, groups, d_state] with fewer groups than
@@ -21,6 +28,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from picotron_tpu.utils import on_tpu
 
 F32 = jnp.float32
 HIGHEST = lax.Precision.HIGHEST
@@ -101,10 +110,25 @@ def ssm_scan(xs, dt, A, Bm, Cm, S_in, chunk: int) -> tuple:
     return y[:, :S], state
 
 
-def ssm_step(xs, dt, A, Bm, Cm, S_in) -> tuple:
+def ssm_step(xs, dt, A, Bm, Cm, S_in, row=None) -> tuple:
     """One row a sequence, the recurrence as it is written: (y [B, 1,
     heads, d_head] float32 without the skip, the new state). One pass over
-    the state: elementwise in float32, the read-out a sum over d_state."""
+    the state: elementwise in float32, the read-out a sum over d_state.
+
+    With a ``row`` (a decode step) ``S_in`` is the STACKED leaf [layers of
+    this kind, B, heads, d_head, d_state] and the step is taken on that row
+    of it: (y, the leaf with the row advanced). On a TPU that is the Pallas
+    kernel, which takes the leaf and the row and writes where it read (a
+    slice handed to it would be a copy of the layer in front of it and
+    another behind); elsewhere the step below on the row."""
+    if row is not None:
+        if on_tpu():
+            from picotron_tpu.ops.pallas.ssm_step import ssm_step_stacked
+
+            return ssm_step_stacked(xs, dt, A, Bm, Cm, S_in, row)
+        y, state = ssm_step(xs, dt, A, Bm, Cm,
+                            lax.dynamic_index_in_dim(S_in, row, 0, False))
+        return y, lax.dynamic_update_index_in_dim(S_in, state, row, 0)
     if _grouped(xs, Bm):
         return _by_group(ssm_step, xs, dt, A, Bm, Cm, S_in)
 

@@ -43,6 +43,7 @@ from picotron_tpu.ops.pallas.flash_attention import (
     flash_block_grads,
 )
 from picotron_tpu.ops.pallas.rmsnorm import rms_norm_pallas
+from picotron_tpu.ops.pallas.ssm_step import ssm_step_stacked
 
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 HID, HEADS, D, FFN, VOCAB, SEQ = 2048, 32, 64, 8192, 49152, 2048
@@ -241,6 +242,25 @@ def _quant(m, k, n):
         [((m, k), BF16), ((k, n), I8), ((n,), F32)]
 
 
+# the three recurrent cells' stacked float32 state leaves and one slot's B/C
+# rows: Nemotron's eight groups of sixteen heads, Granite's one row for all
+# heads, SALA's row a head (k and q)
+SSM_LEAVES = {"nemotron": ((5, 128, 128, 64, 128), (8, 128)),
+              "granite": ((9, 64, 128, 64, 128), (128,)),
+              "sala": ((9, 8, 32, 128, 128), (32, 128))}
+
+
+def _ssm_step(cell):
+    """ssm_step_stacked, the recurrent layers' decode step, on a cell's own
+    stacked state leaf with a traced row."""
+    leaf, bc = SSM_LEAVES[cell]
+    _, slots, heads, hd, _ = leaf
+    return ssm_step_stacked, \
+        [((slots, 1, heads, hd), BF16), ((slots, 1, heads), F32),
+         ((heads,), F32), ((slots, 1) + bc, BF16), ((slots, 1) + bc, BF16),
+         (leaf, F32), ((), I32)]
+
+
 DECODE_SHAPES = {"decode": (SLOTS, 1), "verify": (SLOTS, 5),
                  "chunk": (1, 256)}
 CASES = {
@@ -265,6 +285,8 @@ CASES = {
     "quant_matmul_up": lambda: _quant(8, HID, FFN),
     "quant_matmul_down": lambda: _quant(8, FFN, HID),
     "quant_matmul_head": lambda: _quant(8, HID, VOCAB),
+    **{f"ssm_step_{cell}": (lambda cell=cell: _ssm_step(cell))
+       for cell in SSM_LEAVES},
 }
 
 
@@ -774,6 +796,35 @@ def experts_on_chip(monkeypatch):
     monkeypatch.setattr(experts, "on_tpu", lambda: True)
 
 
+@pytest.fixture
+def ssm_on_chip(monkeypatch):
+    """A recurrent layer's decode step takes the Pallas kernel on its row of
+    the stacked state leaf, as on a TPU; off one it is the elementwise step
+    between a slice and an update."""
+    from picotron_tpu.ops import ssm
+
+    monkeypatch.setattr(ssm, "on_tpu", lambda: True)
+
+
+def _assert_state_steps_in_place(text: str, prog: str, state: str):
+    """ISSUE 57: a decode block holds the ``ssm_step`` kernel, the state
+    leaf (``state``: its shape as the text writes it) its operand and,
+    aliased, its result, so neither a slice of a layer's row in front of it
+    nor an update behind it; a chunk scans and holds none."""
+    calls = [l for l in text.splitlines()
+             if "tpu_custom_call" in l and "/ssm_step/pallas_call" in l]
+    assert bool(calls) == (prog == "decode_block"), len(calls)
+    for call in calls:
+        assert re.search(rf"= \({state}\S*, f32\[", call), call[:200]
+        assert "output_to_operand_aliasing={{0}: (5, {})}" in call, call[:400]
+    if calls:
+        rows = state.replace(r"f32\[", "").split(",", 1)[1]
+        moved = [l.strip()[:160] for l in text.splitlines()
+                 if re.search(rf"= f32\[(?:1,)?{rows}\S* "
+                              r"(?:fusion|copy|dynamic-slice)\(", l)]
+        assert not moved, "\n".join(moved)
+
+
 def _assert_expert_orders(text: str, prog: str, pipelined: bool):
     """ISSUE 43: the 512-row chunk runs each held expert over its own rows
     (the kernel reads the stacks where they lie: the callers' ``sliced``
@@ -929,7 +980,8 @@ def _cell_program(topo, prog, name):
 
 @pytest.mark.parametrize("prog", ["decode_block", "prefill_chunk"])
 def test_recurrent_state_is_row_major_and_never_copied(prog, topo, one_chip,
-                                                       experts_on_chip):
+                                                       experts_on_chip,
+                                                       ssm_on_chip):
     """Left free, a prefill chunk's contractions pulled the whole float32
     state leaf (2.4 GB) into their own order on entry and pushed it back on
     exit (PR 32 read both copies here, 2.46 GB of temporaries, 15.5 GB in
@@ -945,6 +997,7 @@ def test_recurrent_state_is_row_major_and_never_copied(prog, topo, one_chip,
     lines = text.splitlines()
     state = r"f32\[9,64,128,64,128\]"
     kv = r"bf16\[1,64,4096,8,128\]"
+    _assert_state_steps_in_place(text, prog, state)
     copies = [l.strip()[:160] for l in lines
               if re.search(rf"= (?:{state}|{kv})\S* copy\(", l)]
     assert not copies, "\n".join(copies)
@@ -962,7 +1015,8 @@ def test_recurrent_state_is_row_major_and_never_copied(prog, topo, one_chip,
 
 @pytest.mark.parametrize("prog", ["decode_block", "prefill_chunk"])
 def test_nemotron_state_and_packed_kv_stay_in_place(prog, topo, one_chip,
-                                                    experts_on_chip):
+                                                    experts_on_chip,
+                                                    ssm_on_chip):
     """The Nemotron-H cell's programs at its own size (PR 56: 128 slots x
     8192 beside 9.30 GB of weights): the float32 state leaf (2.68 GB) and
     the K/V leaves, two heads of 128 a token under the compiler's own (2,
@@ -979,6 +1033,7 @@ def test_nemotron_state_and_packed_kv_stay_in_place(prog, topo, one_chip,
     lines = text.splitlines()
     state = r"f32\[5,128,128,64,128\]"
     kv = r"bf16\[1,128,8192,2,128\]"
+    _assert_state_steps_in_place(text, prog, state)
     copies = [l.strip()[:160] for l in lines
               if re.search(rf"= (?:{state}|{kv})\S* copy\(", l)]
     assert not copies, "\n".join(copies)
@@ -1020,7 +1075,8 @@ def test_trinity_decode_block_keeps_the_loop(topo, one_chip, monkeypatch,
 
 
 @pytest.mark.parametrize("prog", ["decode_block", "prefill_chunk"])
-def test_sala_cache_leaves_are_never_copied_whole(prog, topo, one_chip):
+def test_sala_cache_leaves_are_never_copied_whole(prog, topo, one_chip,
+                                                  ssm_on_chip):
     """K and V a kv head's keys one after the other ([layers, slots, kv
     heads, T, d]): tokens-major, the decode block re-laid both leaves inside
     its step loop (four copies of 0.8 GB a step) and a prefill chunk on
@@ -1035,6 +1091,7 @@ def test_sala_cache_leaves_are_never_copied_whole(prog, topo, one_chip):
     leaves = {"k": r"bf16\[3,8,2,65536,128\]", "v": r"bf16\[3,8,2,65536,128\]",
               "kc": r"bf16\[3,8,2,4096,128\]",
               "state": r"f32\[9,8,32,128,128\]"}
+    _assert_state_steps_in_place(compiled.as_text(), prog, leaves["state"])
     shapes = "|".join(sorted(set(leaves.values())))
     copies = [l.strip()[:160] for l in lines
               if re.search(rf"= (?:{shapes})\S* copy\(", l)]
