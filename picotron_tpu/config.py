@@ -180,7 +180,11 @@ class ModelConfig:
     # path only) or "nemotron_h" (Nemotron 3's hybrid: a layer is ONE
     # sublayer, Mamba-2 with B and C a group of heads, NoPE attention, or
     # LatentMoE, two-matrix relu^2 experts routed in a latent, by
-    # ``hybrid_override_pattern`` — models/nemotron_h.py, serving path only;
+    # ``hybrid_override_pattern`` — models/nemotron_h.py, serving path only)
+    # or "solar_open2" (Solar Open 2: Kimi-delta-attention layers, a gated
+    # delta rule with a decay for every key channel, ``gqa_interval`` of
+    # them between two gated NoPE GQA layers by ``gqa_layers``, routed and
+    # shared experts behind each — models/solar_open2.py, serving path only;
     # its fields are the last). The fields below are the
     # published ``config.json`` keys of the DeepSeek block, by their own
     # names, and are read by no other block (but ``num_experts_per_tok``,
@@ -350,6 +354,29 @@ class ModelConfig:
     mlp_hidden_act: str = "silu"
     moe_latent_size: int = 0
     moe_shared_expert_intermediate_size: int = 0
+    # "solar_open2" (Solar Open 2): the published keys of that block, beside
+    # ``head_dim``, ``n_routed_experts`` (the experts HELD here of a router
+    # ``n_routed_experts * ep_size`` wide), ``num_experts_per_tok``,
+    # ``moe_intermediate_size``, ``n_shared_experts``, ``norm_topk_prob``,
+    # ``routed_scaling_factor``, ``first_k_dense_replace`` (0: every layer
+    # has experts), ``tie_word_embeddings``, ``ep_size``/``ep_rank``.
+    # ``gqa_layers`` lists the held layers whose mixer is the gated NoPE GQA
+    # (``gqa_interval`` Kimi-delta-attention layers between two of them; 0:
+    # unchecked); ``linear_attn_config`` is the KDA layers' group
+    # (``num_heads``, ``head_dim`` for keys and values alike,
+    # ``short_conv_kernel_size``, ``num_kv_heads`` null: as many as heads);
+    # ``use_rope`` false: the GQA layers rotate nothing; ``use_gqa_gate``: a
+    # sigmoid gate on their output; ``kda_use_full_proj``: the decay's and
+    # the gate's projections as one matrix each, not through a rank of
+    # ``head_dim``; ``kda_allow_neg_eigval``: the write strength doubled,
+    # into (0, 2).
+    linear_attn_config: Optional[dict] = None
+    gqa_layers: Optional[list] = None
+    gqa_interval: int = 0
+    use_gqa_gate: bool = False
+    use_rope: bool = True
+    kda_use_full_proj: bool = False
+    kda_allow_neg_eigval: bool = False
     # the published ``head_dim`` where it is not hidden_size / heads (afmoe:
     # 128 of 3072 / 48); 0: derived, and it follows ``hidden_size``
     head_dim: int = 0
@@ -1231,11 +1258,11 @@ class Config:
                     f"H-sized axis")
         if m.model_type not in ("llama", "deepseek_v32", "granitemoehybrid",
                                 "minicpm_sala", "afmoe", "mimo_v2",
-                                "KeyeVL2", "nemotron_h"):
+                                "KeyeVL2", "nemotron_h", "solar_open2"):
             raise ValueError(
                 f"unknown model_type {m.model_type!r} (llama|deepseek_v32|"
                 "granitemoehybrid|minicpm_sala|afmoe|mimo_v2|KeyeVL2|"
-                "nemotron_h)")
+                "nemotron_h|solar_open2)")
         if m.model_type == "deepseek_v32":
             self._validate_deepseek_v32(for_training)
         if m.model_type == "granitemoehybrid":
@@ -1250,6 +1277,8 @@ class Config:
             self._validate_keye_vl2(for_training)
         if m.model_type == "nemotron_h":
             self._validate_nemotron_h(for_training)
+        if m.model_type == "solar_open2":
+            self._validate_solar_open2(for_training)
         if m.attention_impl not in ("auto", "sdpa", "flash"):
             raise ValueError(
                 f"unknown attention_impl {m.attention_impl!r} (auto|sdpa|flash)")
@@ -2302,6 +2331,100 @@ class Config:
                            ("mamba_proj_bias", False),
                            ("mlp_hidden_act", "relu2"),
                            ("n_shared_experts", 1),
+                           ("norm_topk_prob", True),
+                           ("tie_word_embeddings", False)):
+            if getattr(m, name) != want:
+                raise ValueError(
+                    f"{who} implements model.{name} = {want!r} only (got "
+                    f"{getattr(m, name)!r})")
+
+    def _validate_solar_open2(self, for_training: bool) -> None:
+        """What ``models/solar_open2.py`` needs of its keys, and what it
+        cannot do yet, each refused by name."""
+        d, m, inf = self.distributed, self.model, self.inference
+        who = "model_type 'solar_open2'"
+        la = m.linear_attn_config or {}
+        gqa = m.gqa_layers or []
+        width = m.n_routed_experts * m.ep_size
+        refused = (
+            (for_training, "is served, not trained: training is not "
+             "implemented for this block (no backward through the chunked "
+             "delta rule and the expert share; train_step builds the Llama "
+             "block only)"),
+            (d.tp_size > 1, f"does not support tp_size > 1 (got "
+             f"{d.tp_size}): the recurrent state has no tp sharding and the "
+             "block holds no tp collectives; its share of a layer is "
+             "ep_size/ep_rank"),
+            (inf.kv_layout == "paged", "does not support inference.kv_layout "
+             "'paged' (nor the prefix reuse that rests on it): a recurrent "
+             "state has no token axis to page and no snapshot to resume a "
+             "shared prefix from; set kv_layout: 'contiguous'"),
+            (inf.kv_cache_dtype == "int8", "does not support "
+             "inference.kv_cache_dtype 'int8': the state is float32 and K/V "
+             "are stored in the model's dtype"),
+            (inf.weight_dtype == "int8", "does not support "
+             "inference.weight_dtype 'int8': its matmuls take dense weights "
+             "only"),
+            (bool(inf.tenancy.tenants or inf.tenancy.manifest), "does not "
+             "support LoRA adapters (inference.tenancy): the adapter pack "
+             "is shaped for the Llama block's seven projections"),
+            (inf.spec_len > 0, f"does not support speculation "
+             f"(inference.spec_len {inf.spec_len}): a rejected draft cannot "
+             "be rolled back out of a recurrent state by rewinding a length"),
+            (inf.attend_impl == "flash", f"does not support "
+             f"inference.attend_impl {inf.attend_impl!r}: the recurrent "
+             "state has no kernel, and forcing one for the GQA layers' "
+             "prefill chunks is untested ('auto' runs it for the decode "
+             "step)"),
+            (inf.overlap or inf.mixed_dispatch or inf.key_schedule == "slot"
+             or inf.dp_size > 1, "serves through the serial round-keyed "
+             "programs only: inference.overlap, mixed_dispatch, key_schedule "
+             "'slot' and dp_size > 1 are not implemented for it"),
+        )
+        for bad, why in refused:
+            if bad:
+                raise ValueError(f"{who} {why}")
+        for name in ("n_routed_experts", "n_shared_experts",
+                     "num_experts_per_tok", "moe_intermediate_size",
+                     "ep_size"):
+            if getattr(m, name) < 1:
+                raise ValueError(f"{who} needs model.{name} >= 1")
+        need = ("num_heads", "head_dim", "short_conv_kernel_size")
+        if any(int(la.get(n) or 0) < 1 for n in need) \
+                or la.get("num_kv_heads") not in (None, la.get("num_heads")):
+            raise ValueError(
+                f"{who} needs model.linear_attn_config with "
+                f"{', '.join(need)} each >= 1 and num_kv_heads null or "
+                f"num_heads (k and v a head each; got "
+                f"{m.linear_attn_config!r})")
+        kda = [i for i in range(m.num_hidden_layers) if i not in gqa]
+        if not gqa or not kda or sorted(set(gqa)) != list(gqa) \
+                or not 0 <= gqa[0] <= gqa[-1] < m.num_hidden_layers:
+            raise ValueError(
+                f"{who} needs model.gqa_layers: rising indices among the "
+                f"{m.num_hidden_layers} layers held, with at least one GQA "
+                f"and one KDA layer (the cache holds a leaf of each kind; "
+                f"got {m.gqa_layers!r})")
+        checks = (
+            (m.gqa_interval and any(
+                b - a != m.gqa_interval + 1 for a, b in zip(gqa, gqa[1:])),
+             f"gqa_layers {gqa} do not lie gqa_interval {m.gqa_interval} "
+             "KDA layers apart"),
+            (m.num_attention_heads % m.num_key_value_heads,
+             f"num_attention_heads {m.num_attention_heads} must be a "
+             f"multiple of num_key_value_heads {m.num_key_value_heads}"),
+            (not 0 <= m.ep_rank < m.ep_size, f"ep_rank {m.ep_rank} outside "
+             f"[0, ep_size {m.ep_size})"),
+            (m.num_experts_per_tok > width, f"num_experts_per_tok "
+             f"{m.num_experts_per_tok} passes the router's width {width} "
+             "(n_routed_experts x ep_size)"),
+        )
+        for bad, why in checks:
+            if bad:
+                raise ValueError(f"{who}: {why}")
+        for name, want in (("use_rope", False),
+                           ("first_k_dense_replace", 0),
+                           ("scoring_func", "sigmoid"),
                            ("norm_topk_prob", True),
                            ("tie_word_embeddings", False)):
             if getattr(m, name) != want:

@@ -17,7 +17,8 @@ whatever the block:
   layers, ``granite_hybrid``, ``minicpm_sala``, ``afmoe`` or ``mimo_v2``,
   finds its own row from it; what ``nemotron_h`` hands the scan as a layer
   is a unit of its pattern, one sublayer of each kind at most,
-  ``nemotron_h.stacking``); in a decode
+  ``nemotron_h.stacking``; ``solar_open2``'s layers alternate as
+  ``granite_hybrid``'s do, by ``gqa_layers``); in a decode
   block its cache dict also holds ``"active"`` [B] (parked and in budget),
   which a block that keeps K/V alone need not read;
 - ``UNSLICED``: names of a group's leaves the scan hands its layers
@@ -32,7 +33,9 @@ whatever the block:
   with keys wider than values and K/V heads counted by kind, four leaves of
   four shapes, for ``mimo_v2``; a token's K and V heads in one row and an
   indexer's keys, two to a row, for ``keye_vl2``; two K/V heads of 128 a
-  token beside a float32 state for ``nemotron_h``);
+  token beside a float32 state for ``nemotron_h``; K/V of the GQA layers
+  beside the float32 delta-rule state ``kda`` and the conv tail of the KDA
+  layers for ``solar_open2``);
 - ``RING_CACHE`` (absent: false): some leaves are rings a prefill chunk's
   writes must fit; ``init_cache`` then also takes ``prefill_chunk``;
 - ``CARRIES_STATE`` (absent: false): the cache holds a state with no token
@@ -48,13 +51,15 @@ import importlib
 from picotron_tpu.models import llama  # noqa: F401
 
 import jax.numpy as jnp
+from jax import lax
 
 # the key under which a layer that counts returns its counters
 STATS = "stats"
 
 
 # What the blocks whose layers alternate between kinds of mixer
-# (``granite_hybrid``, ``minicpm_sala``, ``afmoe``, ``mimo_v2``) share: the
+# (``granite_hybrid``, ``minicpm_sala``, ``afmoe``, ``mimo_v2``,
+# ``solar_open2``) share: the
 # runs of the per-layer pattern, a layer's row of its own kind's cache leaves, the rows that count
 # (``nemotron_h``, whose runs are all one layer long, stacks by units of its
 # own and takes the last two).
@@ -93,6 +98,80 @@ def live_rows(cache, live, h):
     return live
 
 
+# What the blocks that keep a recurrent state beside K/V (``granite_hybrid``,
+# ``nemotron_h``, ``minicpm_sala``, ``solar_open2``) share: the way from the
+# state leaves to a mixer and back, and the three counters of such a layer.
+
+
+def carry_state(cache, leaves, names, like, row, pos, h, mixer) -> tuple:
+    """Run a recurrent ``mixer`` from a layer's part of its state leaves and
+    put back what it leaves behind: ``mixer(*ins, one_step) -> (y, *outs)``,
+    an in and an out for each of ``names``, leaves of ``leaves`` shaped
+    [layers of the kind, slots, ...] of which ``row`` is this layer's (the
+    last is the state proper; before it, what a conv keeps of its last
+    inputs). Three shapes of call, by ``cache``: None (a whole sequence from
+    zeros, ``like`` = a slot's (shape, dtype) for each name: what is behind
+    the last live row comes back as a one-slot block); a ``slot`` entry (a
+    prefill chunk carries that slot's part on, from zeros where ``pos`` is
+    0: admission, whatever the slot's last occupant left; a ``gate`` entry
+    chooses between what the chunk leaves and what was there); neither (a
+    decode step advances every slot, and where ``h`` is one row a slot the
+    mixer is handed the state leaf whole and ``one_step = (row,)`` and
+    returns the leaf, that row of it advanced). Returns ``(y, {name: the
+    leaf written, or the block}, decode)``."""
+    # on demand: the inference package needs this one
+    from picotron_tpu.inference import kv_cache
+
+    if cache is None:
+        y, *news = mixer(*(jnp.zeros((h.shape[0],) + shape, dt)
+                           for shape, dt in like), ())
+        return y, dict(zip(names, news)), False
+    decode = "slot" not in cache
+    step = (row,) if decode and h.shape[1] == 1 else ()
+    # a decode step's elementwise pass takes the leaves as they lie. A
+    # chunk's contractions must be held to that: left free they pull the
+    # whole state leaf into their own order on entry and push it back on
+    # exit (two copies of 2.4 GB a chunk in Granite's cell; as
+    # ``kv_cache.cache_write`` holds a packed K/V leaf)
+    pin = (lambda x: x) if decode else kv_cache.row_major
+    zero = jnp.zeros((), jnp.int32)
+    slot = None if decode else jnp.asarray(cache["slot"], jnp.int32)
+    ins = []
+    for name in names:
+        if step and name == names[-1]:
+            ins.append(leaves[name])
+            continue
+        x = lax.dynamic_index_in_dim(pin(leaves[name]), row, 0, False)
+        if not decode:
+            x = lax.dynamic_slice_in_dim(x, slot, 1, axis=0)
+            x = jnp.where(pos[0] == 0, jnp.zeros_like(x), x)
+        ins.append(x)
+    y, *news = mixer(*ins, step)
+    out = {}
+    for name, new, old in zip(names, news, ins):
+        new = new.astype(leaves[name].dtype)
+        if step and name == names[-1]:  # the leaf itself, its row advanced
+            out[name] = new
+        elif decode:
+            out[name] = lax.dynamic_update_index_in_dim(
+                leaves[name], new, row, 0)
+        else:
+            if cache.get("gate") is not None:
+                new = jnp.where(cache["gate"], new, old)
+            at = (row, slot) + (zero,) * (new.ndim - 1)
+            out[name] = pin(lax.dynamic_update_slice(
+                leaves[name], pin(new)[None], at))
+    return y, out, decode
+
+
+def state_counts(live, decode: bool) -> tuple:
+    """A recurrent layer's three counters for one call: (slots a decode step
+    advanced, decode steps of the layer, tokens a prefill scanned)."""
+    n_live = jnp.sum(live, dtype=jnp.int32)
+    zero = jnp.zeros((), jnp.int32)
+    return (n_live, zero + 1, zero) if decode else (zero, zero, n_live)
+
+
 # ``model_type`` -> the module of ``picotron_tpu.models`` that builds it
 BLOCKS = {
     "llama": "llama",
@@ -103,6 +182,7 @@ BLOCKS = {
     "mimo_v2": "mimo_v2",
     "KeyeVL2": "keye_vl2",
     "nemotron_h": "nemotron_h",
+    "solar_open2": "solar_open2",
 }
 
 

@@ -58,7 +58,8 @@ from jax.sharding import PartitionSpec as P
 
 from picotron_tpu.config import Config, ModelConfig
 from picotron_tpu.inference import kv_cache
-from picotron_tpu.models import STATS, leaf_row, live_rows, llama, runs
+from picotron_tpu.models import (STATS, carry_state, leaf_row, live_rows,
+                                 llama, runs, state_counts)
 from picotron_tpu.models import experts as expert_share
 from picotron_tpu.models.llama import param_bytes  # noqa: F401 - the seam
 from picotron_tpu.ops.rmsnorm import rms_norm
@@ -381,64 +382,22 @@ def mamba_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
     prefill chunk carries that slot's state on, from zeros where ``pos`` is
     0), neither (a decode step advances every live slot)."""
     m = cfg.model
-    B = h.shape[0]
     live = live_rows(cache, live, h)
-    n_live = jnp.sum(live, dtype=jnp.int32)
-    zero = jnp.zeros((), jnp.int32)
-    decode = cache is not None and "slot" not in cache
-    step = ()
-    if cache is None:
-        conv_in = jnp.zeros((B, m.mamba_d_conv - 1, conv_width(m)), h.dtype)
-        ssm_in = jnp.zeros((B, m.mamba_n_heads, m.mamba_d_head,
-                            m.mamba_d_state), F32)
-    else:
-        row = leaf_row(layer, first, kind_first)
-        # a decode step's elementwise pass takes the leaves as they lie. A
-        # chunk's contractions must be held to that: left free they pull
-        # the whole state leaf into their own order on entry and push it
-        # back on exit (two copies of 2.4 GB a chunk; as
-        # ``kv_cache.cache_write`` holds a packed K/V leaf)
-        pin = (lambda x: x) if decode else kv_cache.row_major
-        conv_in = lax.dynamic_index_in_dim(pin(cache["conv"]), row, 0, False)
-        # a decode step hands the mixer the state leaf whole and the row
-        if decode and h.shape[1] == 1:
-            step = (row,)
-        ssm_in = cache["ssm"] if step else lax.dynamic_index_in_dim(
-            pin(cache["ssm"]), row, 0, False)
-        if not decode:
-            slot = jnp.asarray(cache["slot"], jnp.int32)
-            conv_in = lax.dynamic_slice_in_dim(conv_in, slot, 1, axis=0)
-            ssm_in = lax.dynamic_slice_in_dim(ssm_in, slot, 1, axis=0)
-            # admission: a prompt's first chunk starts from zeros, whatever
-            # the slot's last occupant left
-            fresh = pos[0] == 0
-            conv_in = jnp.where(fresh, jnp.zeros_like(conv_in), conv_in)
-            ssm_in = jnp.where(fresh, jnp.zeros_like(ssm_in), ssm_in)
-    y, conv_out, ssm_out = mamba_mixer(
-        lp, rms_norm(h, lp["mixer_norm"], m.rms_norm_eps), conv_in, ssm_in,
-        live, m, one_step=step)
+    x = rms_norm(h, lp["mixer_norm"], m.rms_norm_eps)
+    y, new, decode = carry_state(
+        cache, cache, ("conv", "ssm"),
+        (((m.mamba_d_conv - 1, conv_width(m)), h.dtype),
+         ((m.mamba_n_heads, m.mamba_d_head, m.mamba_d_state), F32)),
+        None if cache is None else leaf_row(layer, first, kind_first), pos,
+        h, lambda conv_in, ssm_in, step: mamba_mixer(
+            lp, x, conv_in, ssm_in, live, m, one_step=step))
     h = h + jnp.asarray(m.residual_multiplier, h.dtype) * y
     if cache is None:
-        out = {"ssm": ssm_out, "conv": conv_out} if return_kv else {}
+        out = new if return_kv else {}
     else:
         out = {n: v for n, v in cache.items() if n not in ("live", "active")}
-        for name, new, old in (("conv", conv_out, conv_in),
-                               ("ssm", ssm_out, ssm_in)):
-            new = new.astype(cache[name].dtype)
-            if step and name == "ssm":  # the leaf itself, its row advanced
-                out[name] = new
-            elif decode:
-                out[name] = lax.dynamic_update_index_in_dim(
-                    cache[name], new, row, 0)
-            else:
-                if cache.get("gate") is not None:
-                    new = jnp.where(cache["gate"], new, old)
-                at = (row, slot) + (zero,) * (new.ndim - 1)
-                out[name] = pin(lax.dynamic_update_slice(
-                    cache[name], pin(new)[None], at))
-    ssm_stats = ((n_live, zero + 1, zero) if decode
-                 else (zero, zero, n_live))
-    return _finish(lp, h, m, live, out, ssm_stats)
+        out.update(new)
+    return _finish(lp, h, m, live, out, state_counts(live, decode))
 
 
 def attention_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,

@@ -72,7 +72,8 @@ from jax.sharding import PartitionSpec as P
 
 from picotron_tpu.config import Config, ModelConfig
 from picotron_tpu.inference import kv_cache
-from picotron_tpu.models import STATS, leaf_row, live_rows, llama, runs
+from picotron_tpu.models import (STATS, carry_state, leaf_row, live_rows,
+                                 llama, runs, state_counts)
 from picotron_tpu.models.experts import swiglu
 from picotron_tpu.models.llama import param_bytes  # noqa: F401 - the seam
 from picotron_tpu.ops.attention import NEG_INF
@@ -358,50 +359,22 @@ def lightning_layer(lp, h, cos, sin, cfg: Config, cache=None, pos=None,
     carries that slot's state on, from zeros where ``pos`` is 0), neither (a
     decode step advances every live slot)."""
     m = cfg.model
-    B = h.shape[0]
     live = live_rows(cache, live, h)
-    n_live = jnp.sum(live, dtype=jnp.int32)
-    zero = jnp.zeros((), jnp.int32)
-    decode = cache is not None and "slot" not in cache
-    step = ()
-    if cache is None:
-        state_in = jnp.zeros((B, m.lightning_nh, m.lightning_head_dim,
-                              m.lightning_head_dim), F32)
-    else:
-        row = leaf_row(layer, first, kind_first)
-        # a decode step hands the mixer the state leaf whole and the row
-        if decode and h.shape[1] == 1:
-            step = (row,)
-        # a chunk's contractions are held to the leaf's own layout, as
-        # ``granite_hybrid.mamba_layer`` holds its state
-        pin = (lambda a: a) if decode else kv_cache.row_major
-        state_in = cache["state"] if step else lax.dynamic_index_in_dim(
-            pin(cache["state"]), row, 0, False)
-        if not decode:
-            slot = jnp.asarray(cache["slot"], jnp.int32)
-            state_in = lax.dynamic_slice_in_dim(state_in, slot, 1, axis=0)
-            # admission: a prompt's first chunk starts from zeros
-            state_in = jnp.where(pos[0] == 0, jnp.zeros_like(state_in),
-                                 state_in)
-    y, state = lightning_mixer(
-        lp, rms_norm(h, lp["mixer_norm"], m.rms_norm_eps), cos, sin,
-        state_in, live, m, one_step=step)
+    x = rms_norm(h, lp["mixer_norm"], m.rms_norm_eps)
+    y, new, decode = carry_state(
+        cache, cache, ("state",),
+        (((m.lightning_nh, m.lightning_head_dim, m.lightning_head_dim),
+          F32),),
+        None if cache is None else leaf_row(layer, first, kind_first), pos,
+        h, lambda state_in, step: lightning_mixer(
+            lp, x, cos, sin, state_in, live, m, one_step=step))
     h = h + jnp.asarray(residual_scale(m), h.dtype) * y
     if cache is None:
-        out = {"state": state} if return_kv else {}
+        out = new if return_kv else {}
     else:
-        out = _leaves(cache)
-        if step:  # the leaf itself, its row advanced
-            out["state"] = state
-        elif decode:
-            out["state"] = lax.dynamic_update_index_in_dim(
-                cache["state"], state, row, 0)
-        else:
-            at = (row, slot) + (zero,) * (state.ndim - 1)
-            out["state"] = pin(lax.dynamic_update_slice(
-                cache["state"], pin(state)[None], at))
-    counted = ((n_live, zero + 1, zero) if decode else (zero, zero, n_live))
-    return _finish(lp, h, m, out, (zero,) * 4 + counted)
+        out = {**_leaves(cache), **new}
+    zero = jnp.zeros((), jnp.int32)
+    return _finish(lp, h, m, out, (zero,) * 4 + state_counts(live, decode))
 
 
 # --------------------------------------------------------------------------- #

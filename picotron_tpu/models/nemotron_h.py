@@ -69,7 +69,8 @@ from jax.sharding import PartitionSpec as P
 
 from picotron_tpu.config import Config, ModelConfig
 from picotron_tpu.inference import kv_cache
-from picotron_tpu.models import STATS, leaf_row, live_rows, llama
+from picotron_tpu.models import (STATS, carry_state, leaf_row, live_rows,
+                                 llama, state_counts)
 from picotron_tpu.models import experts as expert_share
 from picotron_tpu.models.llama import param_bytes  # noqa: F401 - the seam
 from picotron_tpu.ops.rmsnorm import rms_norm
@@ -389,57 +390,17 @@ def mamba_sublayer(lp, h, cfg: Config, cache, out: dict, pos, return_kv,
     from zeros where ``pos`` is 0), neither (a decode step advances every
     live slot)."""
     m = cfg.model
-    B = h.shape[0]
-    n_live = jnp.sum(live, dtype=jnp.int32)
+    x = rms_norm(h, lp["m_norm"], m.rms_norm_eps)
+    y, new, decode = carry_state(
+        cache, out, ("conv", "ssm"),
+        (((m.conv_kernel - 1, conv_width(m)), h.dtype),
+         ((m.mamba_num_heads, m.mamba_head_dim, m.ssm_state_size), F32)),
+        row, pos, h, lambda conv_in, ssm_in, step: mamba_mixer(
+            lp, x, conv_in, ssm_in, live, m, one_step=step))
+    if cache is not None or return_kv:
+        out.update(new)
     zero = jnp.zeros((), jnp.int32)
-    decode = cache is not None and "slot" not in cache
-    # a decode step hands the mixer the state leaf whole and the row
-    step = (row,) if decode and h.shape[1] == 1 else ()
-    if cache is None:
-        conv_in = jnp.zeros((B, m.conv_kernel - 1, conv_width(m)), h.dtype)
-        ssm_in = jnp.zeros((B, m.mamba_num_heads, m.mamba_head_dim,
-                            m.ssm_state_size), F32)
-    else:
-        # a decode step's elementwise pass takes the leaves as they lie; a
-        # chunk's contractions are held to that (``granite_hybrid``: left
-        # free they pull the whole state leaf into their own order on entry
-        # and push it back on exit)
-        pin = (lambda x: x) if decode else kv_cache.row_major
-        conv_in = lax.dynamic_index_in_dim(pin(out["conv"]), row, 0, False)
-        ssm_in = out["ssm"] if step else lax.dynamic_index_in_dim(
-            pin(out["ssm"]), row, 0, False)
-        if not decode:
-            slot = jnp.asarray(cache["slot"], jnp.int32)
-            conv_in = lax.dynamic_slice_in_dim(conv_in, slot, 1, axis=0)
-            ssm_in = lax.dynamic_slice_in_dim(ssm_in, slot, 1, axis=0)
-            # admission: a prompt's first chunk starts from zeros, whatever
-            # the slot's last occupant left
-            fresh = pos[0] == 0
-            conv_in = jnp.where(fresh, jnp.zeros_like(conv_in), conv_in)
-            ssm_in = jnp.where(fresh, jnp.zeros_like(ssm_in), ssm_in)
-    y, conv_out, ssm_out = mamba_mixer(
-        lp, rms_norm(h, lp["m_norm"], m.rms_norm_eps), conv_in, ssm_in,
-        live, m, one_step=step)
-    if cache is None:
-        if return_kv:
-            out.update(ssm=ssm_out, conv=conv_out)
-    else:
-        for name, new, old in (("conv", conv_out, conv_in),
-                               ("ssm", ssm_out, ssm_in)):
-            new = new.astype(out[name].dtype)
-            if step and name == "ssm":  # the leaf itself, its row advanced
-                out[name] = new
-            elif decode:
-                out[name] = lax.dynamic_update_index_in_dim(
-                    out[name], new, row, 0)
-            else:
-                if cache.get("gate") is not None:
-                    new = jnp.where(cache["gate"], new, old)
-                at = (row, slot) + (zero,) * (new.ndim - 1)
-                out[name] = pin(lax.dynamic_update_slice(
-                    out[name], pin(new)[None], at))
-    stats = (n_live, zero + 1, zero) if decode else (zero, zero, n_live)
-    return y, out, (zero,) * N_MOE + stats
+    return y, out, (zero,) * N_MOE + state_counts(live, decode)
 
 
 def attention_sublayer(lp, h, cfg: Config, cache, out: dict, pos, return_kv,

@@ -1,7 +1,11 @@
-"""The diagonal-decay recurrence both recurrent mixers run over a float32
+"""The diagonal-decay recurrence three recurrent mixers run over a float32
 state ``S[h]`` [d_head, d_state]: ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x)
-B_t``, ``y_t = S_t C_t``. ``models/granite_hybrid.py`` (Mamba-2: ``dt`` from
-the input, one ``B``/``C`` for all heads) and ``models/minicpm_sala.py``
+B_t``, ``y_t = S_t C_t``: a scalar decay a head and a plain rank-one add. (The
+repo's second recurrence is ``ops/kda.py``, ``models/solar_open2.py``'s gated
+delta rule: a decay for every key channel, and a write that first READS the
+state along the key; no operands given to the functions here compute it.)
+``models/granite_hybrid.py`` (Mamba-2: ``dt`` from the input, one
+``B``/``C`` for all heads) and ``models/minicpm_sala.py``
 (lightning attention: ``dt = 1``, ``A = -slope``, ``x = v``, ``B = k``, ``C
 = q``, each per head) prefill with the chunked scan in matmul form
 (``ssm_scan``) and decode with the one-row step (``ssm_step``), taken on the

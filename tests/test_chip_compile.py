@@ -1051,6 +1051,60 @@ def test_nemotron_state_and_packed_kv_stay_in_place(prog, topo, one_chip,
     assert not sliced, "\n".join(sliced)
 
 
+@pytest.mark.parametrize("prog", ["decode_block", "prefill_chunk"])
+def test_solar_state_and_kv_stay_in_place(prog, topo, one_chip,
+                                          experts_on_chip):
+    """The Solar Open 2 cell's programs at its own size (PR 58: 64 slots x
+    8192 beside 7.80 GB of weights): the float32 delta-rule state leaf (1.61
+    GB) and the K/V leaves of eight heads of 128 stay row-major and no
+    instruction copies them; a decode block's KDA layer walks its row of the
+    state in two fusions (one reads it along ``k`` and ``q``, one writes it
+    where it lies) and never lands a layer's state (268 MB) in a buffer of
+    its own; the 20 held experts of 1,280 at 4,096 (31.5 MB each, past the
+    pass's weight budget) take the loop in the block and the grouped kernel
+    in the chunk, neither behind a copy of a layer's slice of the stacks
+    (0.63 GB); and the programs' temporaries (1.43 and 1.24 GB) leave the
+    resident 13.76 GB its room in the chip's 15.75."""
+    compiled = _cell_program(topo, prog, "solar-open2-ep16-l8")
+    text = compiled.as_text()
+    _assert_expert_orders(text, prog, pipelined=False)
+    lines = text.splitlines()
+    state = r"f32\[6,64,64,128,128\]"
+    kv = r"bf16\[2,64,8192,8,128\]"
+    copies = [l.strip()[:160] for l in lines
+              if re.search(rf"= (?:{state}|{kv})\S* copy\(", l)]
+    assert not copies, "\n".join(copies)
+    for leaf, shape in (("kda", state), ("k", kv), ("v", kv)):
+        params = [l for l in lines
+                  if re.search(rf"cache__{leaf}__\S* = {shape}", l)
+                  and " parameter(" in l]
+        assert len(params) == 1 and "{4,3,2,1,0" in params[0], params
+    if prog == "decode_block":
+        comps = _computations(text)
+        own = [f"{c}: %{n} = {sh[:40]} {op}" for c, ins in comps.items()
+               if not c.startswith("fused_computation")
+               for n, sh, op, _ in ins
+               if re.match(r"f32\[(?:1,)?64,64,128,128\]", sh)]
+        assert not own, "\n".join(own)
+        # the fusions of one layer step that are handed the leaf: two in
+        # each of the two KDA groups' bodies, none anywhere else
+        walked = []
+        for c, ins in comps.items():
+            shapes = {n: sh for n, sh, _, _ in ins}
+            walks = [n for n, _, op, rest in ins if op == "fusion" and any(
+                re.match(state, shapes.get(o, ""))
+                for o in re.findall(r"%([\w.\-]+)", rest))]
+            walked += [len(walks)] * bool(walks)
+        assert walked == [2, 2], walked
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1.6e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.4e9
+    sliced = [l.strip()[:160] for l in lines
+              if re.search(r"= bf16\[(?:1,)?20,(?:4096,1280|1280,4096)\]", l)
+              and " parameter(" not in l and "get-tuple-element" not in l]
+    assert not sliced, "\n".join(sliced)
+
+
 def test_trinity_decode_block_keeps_the_loop(topo, one_chip, monkeypatch,
                                              experts_on_chip):
     """The Trinity cell's decode block (16 rows over eight held experts of
