@@ -10,7 +10,9 @@ difference from what was there, then the read-out from the new state.
 ``models/solar_open2.py`` runs it: a prefill chunk as the chunked form
 (``kda_scan``), a decode step as the recurrence written out (``kda_step``),
 taken on the layer's row of the stacked state leaf (``row=``) as
-``ops/ssm.py::ssm_step`` takes its own. ``ops/ssm.py``'s recurrence (a scalar
+``ops/ssm.py::ssm_step`` takes its own: on a TPU one Pallas pass over the
+row, in place (``ops/pallas/kda_step.py``), elsewhere the step below between
+a slice and an update. ``ops/ssm.py``'s recurrence (a scalar
 decay a head, a plain rank-one add) cannot express this one: the state is
 read before it is written, and the chunked form solves a triangular system.
 
@@ -23,6 +25,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 from jax import lax
+
+from picotron_tpu.utils import on_tpu
 
 F32 = jnp.float32
 HIGHEST = lax.Precision.HIGHEST
@@ -37,16 +41,24 @@ def kda_step(q, k, v, g, b, S_in, row=None) -> tuple:
     ``v`` [B, 1, heads, values], ``g`` [B, 1, heads, keys] float32 (<= 0),
     ``b`` [B, 1, heads] float32, ``S_in`` [B, heads, keys, values] float32.
 
-    Two passes over the state: one reads it along ``k`` and along ``q``
-    (both sums over the decayed ``S'``), one writes it. The read-out from
-    the new state is ``S_t^T q = S'^T q + (k . q) u`` with ``u = b (v - S'^T
-    k)`` the row written: the same sum, with no third walk over 4 MB a slot
-    and layer.
+    As the compiler runs it, two passes over the state: one reads it along
+    ``k`` and along ``q`` (both sums over the decayed ``S'``), one writes
+    it. The read-out from the new state is ``S_t^T q = S'^T q + (k . q) u``
+    with ``u = b (v - S'^T k)`` the row written: the same sum, with no third
+    walk over 4 MB a slot and layer.
 
     With a ``row`` (a decode step) ``S_in`` is the STACKED leaf [layers of
     this kind, B, heads, keys, values] and the step is taken on that row of
-    it: (o, the leaf with the row advanced)."""
+    it: (o, the leaf with the row advanced). On a TPU that is the Pallas
+    kernel, ONE pass over the row where it lies (it takes the leaf and the
+    row and writes where it read; the read-out comes from the new state
+    while a block is in VMEM); elsewhere the two passes below between a
+    slice of the row and an update back."""
     if row is not None:
+        if on_tpu():
+            from picotron_tpu.ops.pallas.kda_step import kda_step_stacked
+
+            return kda_step_stacked(q, k, v, g, b, S_in, row)
         o, state = kda_step(q, k, v, g, b,
                             lax.dynamic_index_in_dim(S_in, row, 0, False))
         return o, lax.dynamic_update_index_in_dim(S_in, state, row, 0)

@@ -42,6 +42,7 @@ from picotron_tpu.ops.pallas.flash_attention import (
     flash_attention_with_lse,
     flash_block_grads,
 )
+from picotron_tpu.ops.pallas.kda_step import kda_step_stacked
 from picotron_tpu.ops.pallas.rmsnorm import rms_norm_pallas
 from picotron_tpu.ops.pallas.ssm_step import ssm_step_stacked
 
@@ -261,6 +262,19 @@ def _ssm_step(cell):
          (leaf, F32), ((), I32)]
 
 
+def _kda_step():
+    """kda_step_stacked, the delta rule's decode step, on the Solar cell's
+    own stacked state leaf (6 KDA layers x 64 slots x 64 heads of 128 x
+    128) with a traced row."""
+    leaf = (6, 64, 64, 128, 128)
+    _, slots, heads, keys, values = leaf
+    qk = ((slots, 1, heads, keys), BF16)
+    return kda_step_stacked, \
+        [qk, qk, ((slots, 1, heads, values), BF16),
+         ((slots, 1, heads, keys), F32), ((slots, 1, heads), F32),
+         (leaf, F32), ((), I32)]
+
+
 DECODE_SHAPES = {"decode": (SLOTS, 1), "verify": (SLOTS, 5),
                  "chunk": (1, 256)}
 CASES = {
@@ -287,6 +301,7 @@ CASES = {
     "quant_matmul_head": lambda: _quant(8, HID, VOCAB),
     **{f"ssm_step_{cell}": (lambda cell=cell: _ssm_step(cell))
        for cell in SSM_LEAVES},
+    "kda_step_solar": _kda_step,
 }
 
 
@@ -806,17 +821,30 @@ def ssm_on_chip(monkeypatch):
     monkeypatch.setattr(ssm, "on_tpu", lambda: True)
 
 
-def _assert_state_steps_in_place(text: str, prog: str, state: str):
-    """ISSUE 57: a decode block holds the ``ssm_step`` kernel, the state
-    leaf (``state``: its shape as the text writes it) its operand and,
-    aliased, its result, so neither a slice of a layer's row in front of it
-    nor an update behind it; a chunk scans and holds none."""
+@pytest.fixture
+def kda_on_chip(monkeypatch):
+    """The delta rule's decode step takes the Pallas kernel on its row of
+    the stacked state leaf, as on a TPU; off one it is the step written out
+    between a slice and an update."""
+    from picotron_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "on_tpu", lambda: True)
+
+
+def _assert_state_steps_in_place(text: str, prog: str, state: str,
+                                 kernel: str = "ssm_step", operand: int = 5):
+    """ISSUE 57, ISSUE 59: a decode block holds the ``ssm_step`` (or
+    ``kda_step``) kernel, the state leaf (``state``: its shape as the text
+    writes it) its operand and, aliased, its result, so neither a slice of
+    a layer's row in front of it nor an update behind it; a chunk scans and
+    holds none."""
     calls = [l for l in text.splitlines()
-             if "tpu_custom_call" in l and "/ssm_step/pallas_call" in l]
+             if "tpu_custom_call" in l and f"/{kernel}/pallas_call" in l]
     assert bool(calls) == (prog == "decode_block"), len(calls)
     for call in calls:
         assert re.search(rf"= \({state}\S*, f32\[", call), call[:200]
-        assert "output_to_operand_aliasing={{0}: (5, {})}" in call, call[:400]
+        assert "output_to_operand_aliasing={{0}: (%d, {})}" % operand \
+            in call, call[:400]
     if calls:
         rows = state.replace(r"f32\[", "").split(",", 1)[1]
         moved = [l.strip()[:160] for l in text.splitlines()
@@ -1053,24 +1081,27 @@ def test_nemotron_state_and_packed_kv_stay_in_place(prog, topo, one_chip,
 
 @pytest.mark.parametrize("prog", ["decode_block", "prefill_chunk"])
 def test_solar_state_and_kv_stay_in_place(prog, topo, one_chip,
-                                          experts_on_chip):
+                                          experts_on_chip, kda_on_chip):
     """The Solar Open 2 cell's programs at its own size (PR 58: 64 slots x
     8192 beside 7.80 GB of weights): the float32 delta-rule state leaf (1.61
     GB) and the K/V leaves of eight heads of 128 stay row-major and no
-    instruction copies them; a decode block's KDA layer walks its row of the
-    state in two fusions (one reads it along ``k`` and ``q``, one writes it
-    where it lies) and never lands a layer's state (268 MB) in a buffer of
-    its own; the 20 held experts of 1,280 at 4,096 (31.5 MB each, past the
-    pass's weight budget) take the loop in the block and the grouped kernel
-    in the chunk, neither behind a copy of a layer's slice of the stacks
-    (0.63 GB); and the programs' temporaries (1.43 and 1.24 GB) leave the
-    resident 13.76 GB its room in the chip's 15.75."""
+    instruction copies them; a decode block's KDA layer steps its row of
+    the state through the ``kda_step`` kernel, the leaf its operand and
+    aliased result (ISSUE 59: the two fusions that walked the row three
+    times are gone, with the slice in front of them and the update behind),
+    and never lands a layer's state (268 MB) in a buffer of its own; the 20
+    held experts of 1,280 at 4,096 (31.5 MB each, past the pass's weight
+    budget) take the loop in the block and the grouped kernel in the chunk,
+    neither behind a copy of a layer's slice of the stacks (0.63 GB); and
+    the programs' temporaries leave the resident 13.76 GB its room in the
+    chip's 15.75."""
     compiled = _cell_program(topo, prog, "solar-open2-ep16-l8")
     text = compiled.as_text()
     _assert_expert_orders(text, prog, pipelined=False)
     lines = text.splitlines()
     state = r"f32\[6,64,64,128,128\]"
     kv = r"bf16\[2,64,8192,8,128\]"
+    _assert_state_steps_in_place(text, prog, state, "kda_step", 6)
     copies = [l.strip()[:160] for l in lines
               if re.search(rf"= (?:{state}|{kv})\S* copy\(", l)]
     assert not copies, "\n".join(copies)
@@ -1086,16 +1117,16 @@ def test_solar_state_and_kv_stay_in_place(prog, topo, one_chip,
                for n, sh, op, _ in ins
                if re.match(r"f32\[(?:1,)?64,64,128,128\]", sh)]
         assert not own, "\n".join(own)
-        # the fusions of one layer step that are handed the leaf: two in
-        # each of the two KDA groups' bodies, none anywhere else
+        # nothing but the kernel is handed the leaf: no fusion of a layer
+        # step walks it, in either of the two KDA groups' bodies
         walked = []
         for c, ins in comps.items():
             shapes = {n: sh for n, sh, _, _ in ins}
-            walks = [n for n, _, op, rest in ins if op == "fusion" and any(
-                re.match(state, shapes.get(o, ""))
-                for o in re.findall(r"%([\w.\-]+)", rest))]
-            walked += [len(walks)] * bool(walks)
-        assert walked == [2, 2], walked
+            walked += [f"{c}: %{n}" for n, _, op, rest in ins
+                       if op == "fusion" and any(
+                           re.match(state, shapes.get(o, ""))
+                           for o in re.findall(r"%([\w.\-]+)", rest))]
+        assert not walked, walked
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 1.6e9
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.4e9
