@@ -1105,8 +1105,9 @@ class LoggingConfig:
 
 
 # The flagship benchmark model (reference README.md:7 headline:
-# SmolLM-1.7B at ~50% MFU on 8xH100). Shared by bench.py and the driver
-# entry so both always measure the same model.
+# SmolLM-1.7B at ~50% MFU on 8xH100). Shared by chip_smoke.py, the driver
+# entry (__graft_entry__.py) and tests/test_tools.py so all measure the same
+# model.
 SMOLLM_1_7B = dict(
     name="HuggingFaceTB/SmolLM-1.7B", num_hidden_layers=24,
     num_attention_heads=32, num_key_value_heads=32, hidden_size=2048,
@@ -1148,7 +1149,11 @@ class Config:
     def validate(self, for_training: bool = False) -> None:
         """Divisibility constraints, surfaced as errors the way the reference
         uses asserts (train.py:85-86, model.py:94-95, tensor_parallel.py:226).
-        ``for_training`` is what the training entry points pass
+        What is a model block's own (its published keys, and what it cannot
+        do yet) is not checked here: the block of ``model_type`` is looked
+        up in ``models.BLOCKS``, an unknown one refused with that table's
+        names, and the module's ``validate(cfg, for_training)`` called where
+        it has one. ``for_training`` is what the training entry points pass
         (train_step.init_state / build_train_step): a block that only
         serves refuses there, by name."""
         d, m, t = self.distributed, self.model, self.training
@@ -1256,29 +1261,14 @@ class Config:
                     f"fsdp needs hidden_size ({m.hidden_size}) divisible by "
                     f"dp_size ({d.dp_size}) — every layer param shards on an "
                     f"H-sized axis")
-        if m.model_type not in ("llama", "deepseek_v32", "granitemoehybrid",
-                                "minicpm_sala", "afmoe", "mimo_v2",
-                                "KeyeVL2", "nemotron_h", "solar_open2"):
-            raise ValueError(
-                f"unknown model_type {m.model_type!r} (llama|deepseek_v32|"
-                "granitemoehybrid|minicpm_sala|afmoe|mimo_v2|KeyeVL2|"
-                "nemotron_h|solar_open2)")
-        if m.model_type == "deepseek_v32":
-            self._validate_deepseek_v32(for_training)
-        if m.model_type == "granitemoehybrid":
-            self._validate_granite_hybrid(for_training)
-        if m.model_type == "minicpm_sala":
-            self._validate_minicpm_sala(for_training)
-        if m.model_type == "afmoe":
-            self._validate_afmoe(for_training)
-        if m.model_type == "mimo_v2":
-            self._validate_mimo_v2(for_training)
-        if m.model_type == "KeyeVL2":
-            self._validate_keye_vl2(for_training)
-        if m.model_type == "nemotron_h":
-            self._validate_nemotron_h(for_training)
-        if m.model_type == "solar_open2":
-            self._validate_solar_open2(for_training)
+        # what the block of ``model_type`` needs of its keys and cannot do
+        # yet is the block module's to say (``models/support.py``); on
+        # demand: the blocks import this module
+        from picotron_tpu.models import model_module
+
+        block_validate = getattr(model_module(m), "validate", None)
+        if block_validate is not None:
+            block_validate(self, for_training)
         if m.attention_impl not in ("auto", "sdpa", "flash"):
             raise ValueError(
                 f"unknown attention_impl {m.attention_impl!r} (auto|sdpa|flash)")
@@ -1583,854 +1573,6 @@ class Config:
             # silently never fire — refuse instead
             raise ValueError(
                 "chaos_*_step injection requires training.steps_per_call == 1")
-
-    def _validate_deepseek_v32(self, for_training: bool) -> None:
-        """What ``models/deepseek_v32.py`` needs of its keys, and what it
-        cannot do yet, each refused by name so that nothing runs the Llama
-        block under this model's name."""
-        d, m, inf = self.distributed, self.model, self.inference
-        who = "model_type 'deepseek_v32'"
-        if for_training:
-            raise ValueError(
-                f"{who} is served, not trained: training is not implemented "
-                "for this block (no backward through the selection and the "
-                "expert share; train_step builds the Llama block only)")
-        if d.tp_size > 1:
-            raise ValueError(
-                f"{who} does not support tp_size > 1 (got {d.tp_size}): the "
-                "latent cache has no head axis to shard and the block holds "
-                "no tp collectives; its share of a layer is ep_size/ep_rank")
-        if inf.kv_layout == "paged":
-            raise ValueError(
-                f"{who} does not support inference.kv_layout 'paged': the "
-                "latent cache is contiguous only (paged_kv.py pages K/V "
-                "heads); set kv_layout: 'contiguous'")
-        if inf.kv_cache_dtype == "int8":
-            raise ValueError(
-                f"{who} does not support inference.kv_cache_dtype 'int8': "
-                "the latent cache is stored in the model's dtype")
-        if inf.weight_dtype == "int8":
-            raise ValueError(
-                f"{who} does not support inference.weight_dtype 'int8': its "
-                "matmuls take dense weights only")
-        if inf.tenancy.tenants or inf.tenancy.manifest:
-            raise ValueError(
-                f"{who} does not support LoRA adapters (inference.tenancy): "
-                "the adapter pack is shaped for the Llama block's seven "
-                "projections")
-        if inf.spec_len > 0:
-            raise ValueError(
-                f"{who} does not support speculation (inference.spec_len "
-                f"{inf.spec_len}): there is no verify program for this "
-                "block and the MTP module is cut with the depth")
-        if inf.attend_impl == "flash":
-            raise ValueError(
-                f"{who} does not support inference.attend_impl "
-                f"{inf.attend_impl!r}: the flash-decode kernel reads K/V "
-                "heads, not latent rows")
-        if inf.overlap or inf.mixed_dispatch or inf.key_schedule == "slot" \
-                or inf.dp_size > 1:
-            raise ValueError(
-                f"{who} serves through the serial round-keyed programs "
-                "only: inference.overlap, mixed_dispatch, key_schedule "
-                "'slot' and dp_size > 1 are not implemented for it")
-        for name in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
-                     "qk_rope_head_dim", "v_head_dim", "index_n_heads",
-                     "index_head_dim", "index_topk", "n_routed_experts",
-                     "n_shared_experts", "num_experts_per_tok",
-                     "moe_intermediate_size", "n_group", "topk_group",
-                     "ep_size"):
-            if getattr(m, name) < 1:
-                raise ValueError(f"{who} needs model.{name} >= 1")
-        if m.qk_rope_head_dim % 2 or m.index_head_dim < m.qk_rope_head_dim:
-            raise ValueError(
-                f"{who}: qk_rope_head_dim ({m.qk_rope_head_dim}) must be "
-                f"even and fit index_head_dim ({m.index_head_dim})")
-        if not 0 <= m.ep_rank < m.ep_size:
-            raise ValueError(
-                f"{who}: ep_rank {m.ep_rank} outside [0, ep_size "
-                f"{m.ep_size})")
-        width = m.n_routed_experts * m.ep_size
-        if width % m.n_group or m.topk_group > m.n_group \
-                or m.num_experts_per_tok > m.topk_group * (width // m.n_group) \
-                or width // m.n_group < 2:
-            raise ValueError(
-                f"{who}: the router's width {width} (n_routed_experts x "
-                f"ep_size) must split into n_group {m.n_group} groups of at "
-                f"least 2, with topk_group {m.topk_group} <= n_group and "
-                f"num_experts_per_tok {m.num_experts_per_tok} experts "
-                "inside the kept groups")
-        if not 0 <= m.first_k_dense_replace <= m.num_hidden_layers:
-            raise ValueError(
-                f"{who}: first_k_dense_replace {m.first_k_dense_replace} "
-                f"outside [0, num_hidden_layers {m.num_hidden_layers}]")
-        for name, want in (("scoring_func", "sigmoid"),
-                           ("topk_method", "noaux_tc"),
-                           ("norm_topk_prob", True), ("moe_layer_freq", 1),
-                           ("num_nextn_predict_layers", 0)):
-            if getattr(m, name) != want:
-                raise ValueError(
-                    f"{who} implements model.{name} = {want!r} only (got "
-                    f"{getattr(m, name)!r})")
-        rs = m.rope_scaling
-        if rs is not None and rs.get("type", rs.get("rope_type")) != "yarn":
-            raise ValueError(
-                f"{who} implements rope_scaling type 'yarn' only (got "
-                f"{rs!r})")
-
-    def _validate_granite_hybrid(self, for_training: bool) -> None:
-        """What ``models/granite_hybrid.py`` needs of its keys, and what it
-        cannot do yet, each refused by name."""
-        d, m, inf = self.distributed, self.model, self.inference
-        who = "model_type 'granitemoehybrid'"
-        if for_training:
-            raise ValueError(
-                f"{who} is served, not trained: training is not implemented "
-                "for this block (no backward through the chunked scan and "
-                "the expert share; train_step builds the Llama block only)")
-        if d.tp_size > 1:
-            raise ValueError(
-                f"{who} does not support tp_size > 1 (got {d.tp_size}): the "
-                "recurrent state has no tp sharding and the block holds no "
-                "tp collectives; its share of a layer is ep_size/ep_rank")
-        if inf.kv_layout == "paged":
-            raise ValueError(
-                f"{who} does not support inference.kv_layout 'paged' (nor "
-                "the prefix reuse that rests on it): a recurrent state has "
-                "no token axis to page and no snapshot to resume a shared "
-                "prefix from; set kv_layout: 'contiguous'")
-        if inf.kv_cache_dtype == "int8":
-            raise ValueError(
-                f"{who} does not support inference.kv_cache_dtype 'int8': "
-                "the state is float32 and K/V are stored in the model's "
-                "dtype")
-        if inf.weight_dtype == "int8":
-            raise ValueError(
-                f"{who} does not support inference.weight_dtype 'int8': its "
-                "matmuls take dense weights only")
-        if inf.tenancy.tenants or inf.tenancy.manifest:
-            raise ValueError(
-                f"{who} does not support LoRA adapters (inference.tenancy): "
-                "the adapter pack is shaped for the Llama block's seven "
-                "projections")
-        if inf.spec_len > 0:
-            raise ValueError(
-                f"{who} does not support speculation (inference.spec_len "
-                f"{inf.spec_len}): a rejected draft cannot be rolled back "
-                "out of a recurrent state by rewinding a length")
-        if inf.attend_impl == "flash":
-            raise ValueError(
-                f"{who} does not support inference.attend_impl "
-                f"{inf.attend_impl!r}: the recurrent state has no "
-                "kernel, and forcing one for the attention layer's prefill "
-                "chunks is untested ('auto' runs it for the decode step)")
-        if inf.overlap or inf.mixed_dispatch or inf.key_schedule == "slot" \
-                or inf.dp_size > 1:
-            raise ValueError(
-                f"{who} serves through the serial round-keyed programs "
-                "only: inference.overlap, mixed_dispatch, key_schedule "
-                "'slot' and dp_size > 1 are not implemented for it")
-        for name in ("mamba_n_heads", "mamba_d_head", "mamba_d_state",
-                     "mamba_d_conv", "mamba_chunk_size", "num_local_experts",
-                     "num_experts_per_tok", "shared_intermediate_size",
-                     "ep_size"):
-            if getattr(m, name) < 1:
-                raise ValueError(f"{who} needs model.{name} >= 1")
-        lt = m.layer_types
-        if not lt or len(lt) != m.num_hidden_layers \
-                or any(t not in ("mamba", "attention") for t in lt):
-            raise ValueError(
-                f"{who} needs model.layer_types: one of 'mamba' | "
-                f"'attention' for each of the {m.num_hidden_layers} layers "
-                f"(got {lt!r})")
-        if len(set(lt)) < 2:
-            raise ValueError(
-                f"{who} needs at least one 'mamba' and one 'attention' layer "
-                "in model.layer_types: the cache holds a leaf of each kind")
-        if m.mamba_n_heads * m.mamba_d_head != m.mamba_expand * m.hidden_size:
-            raise ValueError(
-                f"{who}: mamba_n_heads {m.mamba_n_heads} x mamba_d_head "
-                f"{m.mamba_d_head} must be mamba_expand {m.mamba_expand} x "
-                f"hidden_size {m.hidden_size}")
-        if not 0 <= m.ep_rank < m.ep_size:
-            raise ValueError(
-                f"{who}: ep_rank {m.ep_rank} outside [0, ep_size "
-                f"{m.ep_size})")
-        if m.num_experts_per_tok > m.num_local_experts * m.ep_size:
-            raise ValueError(
-                f"{who}: num_experts_per_tok {m.num_experts_per_tok} passes "
-                f"the router's width {m.num_local_experts * m.ep_size} "
-                "(num_local_experts x ep_size)")
-        for name, want in (("mamba_n_groups", 1), ("mamba_conv_bias", True),
-                           ("mamba_proj_bias", False),
-                           ("position_embedding_type", "nope"),
-                           ("tie_word_embeddings", True),
-                           ("rope_scaling", None)):
-            if getattr(m, name) != want:
-                raise ValueError(
-                    f"{who} implements model.{name} = {want!r} only (got "
-                    f"{getattr(m, name)!r})")
-
-    def _validate_minicpm_sala(self, for_training: bool) -> None:
-        """What ``models/minicpm_sala.py`` needs of its keys, and what it
-        cannot do yet, each refused by name."""
-        d, m, inf = self.distributed, self.model, self.inference
-        who = "model_type 'minicpm_sala'"
-        if for_training:
-            raise ValueError(
-                f"{who} is served, not trained: training is not implemented "
-                "for this block (no backward through the block selection "
-                "and the chunked scan; train_step builds the Llama block "
-                "only)")
-        if d.tp_size > 1:
-            raise ValueError(
-                f"{who} does not support tp_size > 1 (got {d.tp_size}): the "
-                "lightning state and the compressed keys have no tp "
-                "sharding and the block holds no tp collectives")
-        if inf.dp_size > 1:
-            raise ValueError(
-                f"{who} does not support inference.dp_size > 1 (got "
-                f"{inf.dp_size}): the state and the compressed keys have "
-                "no slot axis over 'dp'")
-        if inf.kv_layout == "paged":
-            raise ValueError(
-                f"{who} does not support inference.kv_layout 'paged' (nor "
-                "the prefix reuse that rests on it): the block selection "
-                "gathers key blocks of a contiguous leaf, and neither the "
-                "compressed keys nor the lightning state are paged; set "
-                "kv_layout: 'contiguous'")
-        if inf.kv_cache_dtype == "int8":
-            raise ValueError(
-                f"{who} does not support inference.kv_cache_dtype 'int8': "
-                "the state is float32 and K, V and the compressed keys are "
-                "stored in the model's dtype")
-        if inf.weight_dtype == "int8":
-            raise ValueError(
-                f"{who} does not support inference.weight_dtype 'int8': its "
-                "matmuls take dense weights only")
-        if inf.tenancy.tenants or inf.tenancy.manifest:
-            raise ValueError(
-                f"{who} does not support LoRA adapters (inference.tenancy): "
-                "the adapter pack is shaped for the Llama block's seven "
-                "projections")
-        if inf.spec_len > 0:
-            raise ValueError(
-                f"{who} does not support speculation (inference.spec_len "
-                f"{inf.spec_len}): a rejected draft cannot be rolled back "
-                "out of a recurrent state by rewinding a length")
-        if inf.attend_impl == "flash":
-            raise ValueError(
-                f"{who} does not support inference.attend_impl "
-                f"{inf.attend_impl!r}: the flash-decode kernel reads every "
-                "live key, not the chosen blocks")
-        if inf.overlap:
-            raise ValueError(
-                f"{who} does not support inference.overlap: the lookahead "
-                "dispatch is not implemented for a block that carries a "
-                "state")
-        if inf.mixed_dispatch:
-            raise ValueError(
-                f"{who} does not support inference.mixed_dispatch: the "
-                "fused prefill lane embeds and heads through the Llama "
-                "block")
-        if inf.key_schedule == "slot":
-            raise ValueError(
-                f"{who} does not support inference.key_schedule 'slot': it "
-                "serves through the round-keyed programs only")
-        for name in ("lightning_nh", "lightning_nkv", "lightning_head_dim",
-                     "dim_model_base"):
-            if getattr(m, name) < 1:
-                raise ValueError(f"{who} needs model.{name} >= 1")
-        mt = m.mixer_types
-        kinds = ("minicpm4", "lightning-attn")
-        if not mt or len(mt) != m.num_hidden_layers \
-                or any(t not in kinds for t in mt):
-            raise ValueError(
-                f"{who} needs model.mixer_types: one of 'minicpm4' | "
-                f"'lightning-attn' for each of the {m.num_hidden_layers} "
-                f"layers (got {mt!r})")
-        if len(set(mt)) < 2:
-            raise ValueError(
-                f"{who} needs at least one 'minicpm4' and one "
-                "'lightning-attn' layer in model.mixer_types: the cache "
-                "holds a leaf of each kind")
-        if m.lightning_nkv != m.lightning_nh:
-            raise ValueError(
-                f"{who}: lightning_nkv {m.lightning_nkv} must equal "
-                f"lightning_nh {m.lightning_nh} (a state a head)")
-        if m.lightning_head_dim % 2:
-            raise ValueError(
-                f"{who}: lightning_head_dim {m.lightning_head_dim} must be "
-                "even (RoPE rotates halves)")
-        if m.total_layers and m.first_layer + m.num_hidden_layers \
-                > m.total_layers or m.first_layer < 0:
-            raise ValueError(
-                f"{who}: layers first_layer {m.first_layer} .. + "
-                f"num_hidden_layers {m.num_hidden_layers} lie outside "
-                f"total_layers {m.total_layers}")
-        for name, want in (("lightning_scale", "1/sqrt(d)"),
-                           ("lightning_use_rope", True),
-                           ("attn_use_rope", False),
-                           ("attn_use_output_gate", True),
-                           ("qk_norm", True), ("use_output_norm", True),
-                           ("use_output_gate", True),
-                           ("tie_word_embeddings", False),
-                           ("rope_scaling", None)):
-            if getattr(m, name) != want:
-                raise ValueError(
-                    f"{who} implements model.{name} = {want!r} only (got "
-                    f"{getattr(m, name)!r})")
-        sc = m.sparse_config or {}
-        need = ("kernel_size", "kernel_stride", "block_size", "init_blocks",
-                "window_size", "topk", "dense_len")
-        if any(int(sc.get(k, 0)) < 1 for k in need):
-            raise ValueError(
-                f"{who} needs model.sparse_config with {', '.join(need)} "
-                f"each >= 1 (got {m.sparse_config!r})")
-        ks, st, bs = sc["kernel_size"], sc["kernel_stride"], sc["block_size"]
-        if ks != 2 * st or bs % st or sc["window_size"] % bs \
-                or sc["dense_len"] % bs:
-            raise ValueError(
-                f"{who}: sparse_config needs kernel_size {ks} = 2 x "
-                f"kernel_stride {st}, and block_size {bs}, window_size "
-                f"{sc['window_size']} and dense_len {sc['dense_len']} in "
-                "whole strides and blocks")
-        if sc["init_blocks"] + sc["window_size"] // bs > sc["topk"]:
-            raise ValueError(
-                f"{who}: sparse_config's forced blocks (init_blocks "
-                f"{sc['init_blocks']} + window_size / block_size "
-                f"{sc['window_size'] // bs}) pass topk {sc['topk']}")
-        if inf.prefill_chunk % st:
-            raise ValueError(
-                f"{who}: inference.prefill_chunk ({inf.prefill_chunk}) must "
-                f"be a multiple of sparse_config.kernel_stride ({st}): a "
-                "chunk writes whole rows of compressed keys")
-
-    def _refuse_beyond_ring_serving(self, who: str,
-                                    for_training: bool) -> None:
-        """What no block that keeps rings beside full-length K/V (``afmoe``,
-        ``mimo_v2``) can do yet, each refused by name."""
-        d, inf = self.distributed, self.inference
-        if for_training:
-            raise ValueError(
-                f"{who} is served, not trained: training is not implemented "
-                "for this block (no backward through the expert share; "
-                "train_step builds the Llama block only)")
-        if d.tp_size > 1:
-            raise ValueError(
-                f"{who} does not support tp_size > 1 (got {d.tp_size}): the "
-                "block holds no tp collectives and its rings are not "
-                "sharded; its share of a layer is ep_size/ep_rank")
-        if inf.dp_size > 1:
-            raise ValueError(
-                f"{who} does not support inference.dp_size > 1 (got "
-                f"{inf.dp_size}): the rings have no slot axis over 'dp'")
-        if inf.kv_layout == "paged":
-            raise ValueError(
-                f"{who} does not support inference.kv_layout 'paged' (nor "
-                "the prefix reuse that rests on it): one pool and one block "
-                "table cannot yet tell the layers that keep a sequence's "
-                "history from those that keep a window; set kv_layout: "
-                "'contiguous'")
-        if inf.kv_cache_dtype == "int8":
-            raise ValueError(
-                f"{who} does not support inference.kv_cache_dtype 'int8': "
-                "both kinds of K/V are stored in the model's dtype")
-        if inf.weight_dtype == "int8":
-            raise ValueError(
-                f"{who} does not support inference.weight_dtype 'int8': its "
-                "matmuls take dense weights only")
-        if inf.tenancy.tenants or inf.tenancy.manifest:
-            raise ValueError(
-                f"{who} does not support LoRA adapters (inference.tenancy): "
-                "the adapter pack is shaped for the Llama block's seven "
-                "projections")
-        if inf.spec_len > 0:
-            raise ValueError(
-                f"{who} does not support speculation (inference.spec_len "
-                f"{inf.spec_len}): a rejected draft's rows have already "
-                "overwritten the ring's oldest, and rewinding a length does "
-                "not bring them back")
-        if inf.attend_impl == "flash":
-            raise ValueError(
-                f"{who} does not support inference.attend_impl "
-                f"{inf.attend_impl!r}: the sliced flash-decode kernel reads "
-                "a prefix, not a ring ('auto' runs the stacked kernel for "
-                "the decode step)")
-        if inf.overlap:
-            raise ValueError(
-                f"{who} does not support inference.overlap: the lookahead "
-                "dispatch is not implemented for this block")
-        if inf.mixed_dispatch:
-            raise ValueError(
-                f"{who} does not support inference.mixed_dispatch: the "
-                "fused prefill lane embeds and heads through the Llama "
-                "block")
-        if inf.key_schedule == "slot":
-            raise ValueError(
-                f"{who} does not support inference.key_schedule 'slot': it "
-                "serves through the round-keyed programs only")
-
-    def _validate_afmoe(self, for_training: bool) -> None:
-        """What ``models/afmoe.py`` needs of its keys, and what it cannot do
-        yet, each refused by name."""
-        m = self.model
-        who = "model_type 'afmoe'"
-        self._refuse_beyond_ring_serving(who, for_training)
-        for name in ("sliding_window", "num_experts", "num_shared_experts",
-                     "num_experts_per_tok", "moe_intermediate_size",
-                     "ep_size"):
-            if getattr(m, name) < 1:
-                raise ValueError(f"{who} needs model.{name} >= 1")
-        kinds = ("sliding_attention", "full_attention")
-        lt = m.layer_types
-        if not lt or len(lt) != m.num_hidden_layers \
-                or any(t not in kinds for t in lt):
-            raise ValueError(
-                f"{who} needs model.layer_types: one of 'sliding_attention' "
-                f"| 'full_attention' for each of the {m.num_hidden_layers} "
-                f"layers (got {lt!r})")
-        if len(set(lt)) < 2:
-            raise ValueError(
-                f"{who} needs at least one 'sliding_attention' and one "
-                "'full_attention' layer in model.layer_types: the cache "
-                "holds a leaf of each kind")
-        if not 0 <= m.num_dense_layers < m.num_hidden_layers:
-            raise ValueError(
-                f"{who}: num_dense_layers {m.num_dense_layers} outside [0, "
-                f"num_hidden_layers {m.num_hidden_layers})")
-        every = m.global_attn_every_n_layers
-        if every:
-            n_moe = m.num_hidden_layers - m.num_dense_layers
-            if m.total_layers and (
-                    m.first_layer < m.num_dense_layers
-                    or m.first_layer + n_moe > m.total_layers):
-                raise ValueError(
-                    f"{who}: expert layers first_layer {m.first_layer} .. + "
-                    f"{n_moe} lie outside total_layers {m.total_layers} "
-                    f"behind num_dense_layers {m.num_dense_layers}")
-            first = m.first_layer if m.total_layers else m.num_dense_layers
-            for i, t in enumerate(lt):
-                pub = i if i < m.num_dense_layers \
-                    else first + i - m.num_dense_layers
-                if (t == kinds[1]) != ((pub + 1) % every == 0):
-                    raise ValueError(
-                        f"{who}: layer_types[{i}] {t!r} is published layer "
-                        f"{pub}, which global_attn_every_n_layers {every} "
-                        f"makes {kinds[(pub + 1) % every == 0]!r}")
-        if m.head_dim % 2:
-            raise ValueError(
-                f"{who}: head_dim {m.head_dim} must be even (RoPE rotates "
-                "halves)")
-        if m.num_attention_heads % m.num_key_value_heads:
-            raise ValueError(
-                f"{who}: num_attention_heads {m.num_attention_heads} must "
-                f"be a multiple of num_key_value_heads "
-                f"{m.num_key_value_heads}")
-        if not 0 <= m.ep_rank < m.ep_size:
-            raise ValueError(
-                f"{who}: ep_rank {m.ep_rank} outside [0, ep_size "
-                f"{m.ep_size})")
-        if m.num_experts_per_tok > m.num_experts * m.ep_size:
-            raise ValueError(
-                f"{who}: num_experts_per_tok {m.num_experts_per_tok} passes "
-                f"the router's width {m.num_experts * m.ep_size} "
-                "(num_experts x ep_size)")
-        for name, want in (("score_func", "sigmoid"), ("route_norm", True),
-                           ("n_group", 1), ("topk_group", 1),
-                           ("tie_word_embeddings", False),
-                           ("rope_scaling", None)):
-            if getattr(m, name) != want:
-                raise ValueError(
-                    f"{who} implements model.{name} = {want!r} only (got "
-                    f"{getattr(m, name)!r})")
-
-    def _validate_mimo_v2(self, for_training: bool) -> None:
-        """What ``models/mimo_v2.py`` needs of its keys, and what it cannot
-        do yet, each refused by name."""
-        m = self.model
-        who = "model_type 'mimo_v2'"
-        self._refuse_beyond_ring_serving(who, for_training)
-        for name in ("sliding_window", "n_routed_experts",
-                     "num_experts_per_tok", "moe_intermediate_size",
-                     "ep_size", "v_head_dim", "swa_num_attention_heads",
-                     "swa_num_key_value_heads", "swa_head_dim",
-                     "swa_v_head_dim"):
-            if getattr(m, name) < 1:
-                raise ValueError(f"{who} needs model.{name} >= 1")
-        n = m.num_hidden_layers
-        for name in ("hybrid_layer_pattern", "moe_layer_freq"):
-            got = getattr(m, name)
-            if not isinstance(got, list) or len(got) != n \
-                    or any(v not in (0, 1) for v in got):
-                raise ValueError(
-                    f"{who} needs model.{name}: 0 or 1 for each of the {n} "
-                    f"layers (got {got!r})")
-        if len(set(m.hybrid_layer_pattern)) < 2:
-            raise ValueError(
-                f"{who} needs at least one full (0) and one sliding (1) "
-                "layer in model.hybrid_layer_pattern: the cache holds "
-                "leaves of each kind")
-        if m.total_layers and (m.first_layer < 1 or m.first_layer + n - 1
-                               > m.total_layers):
-            raise ValueError(
-                f"{who}: layers first_layer {m.first_layer} .. + {n - 1} "
-                f"lie outside total_layers {m.total_layers} behind the "
-                "leading layer")
-        for heads, kv, hd, kind in (
-                (m.num_attention_heads, m.num_key_value_heads, m.head_dim,
-                 "full"),
-                (m.swa_num_attention_heads, m.swa_num_key_value_heads,
-                 m.swa_head_dim, "sliding")):
-            if heads % kv:
-                raise ValueError(
-                    f"{who}: the {kind} layers' {heads} query heads must be "
-                    f"a multiple of their {kv} K/V heads")
-            rot = int(hd * m.partial_rotary_factor)
-            if rot < 2 or rot % 2 or rot > hd:
-                raise ValueError(
-                    f"{who}: partial_rotary_factor {m.partial_rotary_factor}"
-                    f" of the {kind} layers' head_dim {hd} rotates {rot} "
-                    "dimensions: an even count in [2, head_dim] is needed "
-                    "(RoPE rotates halves)")
-        if m.num_attention_heads * m.v_head_dim \
-                != m.swa_num_attention_heads * m.swa_v_head_dim \
-                or int(m.head_dim * m.partial_rotary_factor) \
-                != int(m.swa_head_dim * m.partial_rotary_factor):
-            raise ValueError(
-                f"{who}: both kinds of layer must rotate as many dimensions "
-                "(one table holds both bases) and hand W_o as many columns")
-        if not 0 <= m.ep_rank < m.ep_size:
-            raise ValueError(
-                f"{who}: ep_rank {m.ep_rank} outside [0, ep_size "
-                f"{m.ep_size})")
-        if m.num_experts_per_tok > m.n_routed_experts * m.ep_size:
-            raise ValueError(
-                f"{who}: num_experts_per_tok {m.num_experts_per_tok} passes "
-                f"the router's width {m.n_routed_experts * m.ep_size} "
-                "(n_routed_experts x ep_size)")
-        if m.layernorm_epsilon and m.layernorm_epsilon != m.rms_norm_eps:
-            raise ValueError(
-                f"{who}: layernorm_epsilon {m.layernorm_epsilon} is not "
-                f"rms_norm_eps {m.rms_norm_eps} (the norms read the latter)")
-        if (m.rope_scaling or {}).get("rope_type", "default") != "default":
-            raise ValueError(
-                f"{who} implements model.rope_scaling of type 'default' "
-                f"(none) only (got {m.rope_scaling!r})")
-        for name, want in (("scoring_func", "sigmoid"),
-                           ("topk_method", "noaux_tc"),
-                           ("norm_topk_prob", True), ("n_group", 1),
-                           ("topk_group", 1), ("n_shared_experts", 0),
-                           ("add_swa_attention_sink_bias", True),
-                           ("add_full_attention_sink_bias", False),
-                           ("tie_word_embeddings", False)):
-            if getattr(m, name) != want:
-                raise ValueError(
-                    f"{who} implements model.{name} = {want!r} only (got "
-                    f"{getattr(m, name)!r})")
-
-    def _validate_keye_vl2(self, for_training: bool) -> None:
-        """What ``models/keye_vl2.py`` needs of its keys, and what it cannot
-        do yet, each refused by name."""
-        d, m, inf = self.distributed, self.model, self.inference
-        who = "model_type 'KeyeVL2'"
-        refused = (
-            (for_training, "is served, not trained: training is not "
-             "implemented for this block (no backward through the top-k "
-             "selection, whose indexer would need a training loss of its "
-             "own, nor through the expert share; train_step builds the "
-             "Llama block only)"),
-            (d.tp_size > 1, f"does not support tp_size > 1 (got "
-             f"{d.tp_size}): the block holds no tp collectives and the "
-             "indexer's one key head cannot be sharded; its share of a "
-             "layer is ep_size/ep_rank"),
-            (inf.dp_size > 1, f"does not support inference.dp_size > 1 (got "
-             f"{inf.dp_size}): the indexer's keys have no slot axis over "
-             "'dp'"),
-            (inf.kv_layout == "paged", "does not support inference.kv_layout "
-             "'paged': paged_kv.py pages K/V heads, not the indexer's keys, "
-             "and its attends do not gather chosen rows; set kv_layout: "
-             "'contiguous'"),
-            (inf.kv_cache_dtype == "int8", "does not support "
-             "inference.kv_cache_dtype 'int8': K, V and the indexer's keys "
-             "are stored in the model's dtype"),
-            (inf.weight_dtype == "int8", "does not support "
-             "inference.weight_dtype 'int8': its matmuls take dense weights "
-             "only"),
-            (bool(inf.tenancy.tenants or inf.tenancy.manifest), "does not "
-             "support LoRA adapters (inference.tenancy): the adapter pack "
-             "is shaped for the Llama block's seven projections"),
-            (inf.spec_len > 0, f"does not support speculation "
-             f"(inference.spec_len {inf.spec_len}): a verify block's "
-             "queries would each gather rows of their own, and there is no "
-             "such program"),
-            (inf.attend_impl == "flash", f"does not support "
-             f"inference.attend_impl {inf.attend_impl!r}: the flash-decode "
-             "kernels read a prefix, not chosen rows"),
-            (inf.overlap, "does not support inference.overlap: the "
-             "lookahead dispatch is not implemented for this block"),
-            (inf.mixed_dispatch, "does not support inference.mixed_dispatch:"
-             " the fused prefill lane embeds and heads through the Llama "
-             "block"),
-            (inf.key_schedule == "slot", "does not support "
-             "inference.key_schedule 'slot': it serves through the "
-             "round-keyed programs only"),
-        )
-        for bad, why in refused:
-            if bad:
-                raise ValueError(f"{who} {why}")
-        for name in ("num_experts", "num_experts_per_tok",
-                     "moe_intermediate_size", "ep_size"):
-            if getattr(m, name) < 1:
-                raise ValueError(f"{who} needs model.{name} >= 1")
-        sa = m.sa_config or {}
-        need = ("indexer_num_heads", "indexer_head_dim", "topk")
-        if any(int(sa.get(n, 0)) < 1 for n in need) \
-                or int(sa.get("indexer_num_kv_heads", 1)) != 1:
-            raise ValueError(
-                f"{who} needs model.sa_config with {', '.join(need)} each "
-                f">= 1 and indexer_num_kv_heads 1 (got {m.sa_config!r})")
-        if m.head_dim % 2 or int(sa["indexer_head_dim"]) % 2:
-            raise ValueError(
-                f"{who}: head_dim {m.head_dim} and sa_config.indexer_head_dim"
-                f" {sa['indexer_head_dim']} must be even (RoPE rotates "
-                "halves)")
-        rs = m.rope_scaling or {}
-        section = rs.get("mrope_section")
-        if rs.get("rope_type", rs.get("type", "default")) != "default" \
-                or not isinstance(section, list) or len(section) != 3 \
-                or sum(section) != m.head_dim // 2:
-            raise ValueError(
-                f"{who} needs model.rope_scaling of type 'default' with an "
-                f"mrope_section of three counts that sum to head_dim / 2 = "
-                f"{m.head_dim // 2} (got {m.rope_scaling!r})")
-        if m.num_attention_heads % m.num_key_value_heads:
-            raise ValueError(
-                f"{who}: num_attention_heads {m.num_attention_heads} must "
-                f"be a multiple of num_key_value_heads "
-                f"{m.num_key_value_heads}")
-        if not 0 <= m.ep_rank < m.ep_size:
-            raise ValueError(
-                f"{who}: ep_rank {m.ep_rank} outside [0, ep_size "
-                f"{m.ep_size})")
-        width = m.num_experts * m.ep_size
-        if m.num_experts_per_tok > width:
-            raise ValueError(
-                f"{who}: num_experts_per_tok {m.num_experts_per_tok} passes "
-                f"the router's width {width} (num_experts x ep_size)")
-        if m.num_local_experts not in (0, width):
-            raise ValueError(
-                f"{who}: num_local_experts {m.num_local_experts} is not the "
-                f"router's width {width} (num_experts x ep_size), which it "
-                "repeats as published")
-        if m.total_layers and m.first_layer + m.num_hidden_layers \
-                > m.total_layers:
-            raise ValueError(
-                f"{who}: layers first_layer {m.first_layer} .. + "
-                f"{m.num_hidden_layers} lie outside total_layers "
-                f"{m.total_layers}")
-        for name, want in (("norm_topk_prob", True),
-                           ("decoder_sparse_step", 1),
-                           ("tie_word_embeddings", False)):
-            if getattr(m, name) != want:
-                raise ValueError(
-                    f"{who} implements model.{name} = {want!r} only (got "
-                    f"{getattr(m, name)!r})")
-        if m.mlp_only_layers:
-            raise ValueError(
-                f"{who} implements model.mlp_only_layers = [] only (got "
-                f"{m.mlp_only_layers!r}): every layer's MLP is the routed "
-                "experts")
-
-    def _validate_nemotron_h(self, for_training: bool) -> None:
-        """What ``models/nemotron_h.py`` needs of its keys, and what it
-        cannot do yet, each refused by name."""
-        d, m, inf = self.distributed, self.model, self.inference
-        who = "model_type 'nemotron_h'"
-        pattern = m.hybrid_override_pattern
-        width = m.n_routed_experts * m.ep_size
-        refused = (
-            (for_training, "is served, not trained: training is not "
-             "implemented for this block (no backward through the chunked "
-             "scan and the expert share; train_step builds the Llama block "
-             "only)"),
-            (d.tp_size > 1, f"does not support tp_size > 1 (got "
-             f"{d.tp_size}): the recurrent state has no tp sharding and the "
-             "block holds no tp collectives; its share of a layer is "
-             "ep_size/ep_rank"),
-            (inf.kv_layout == "paged", "does not support inference.kv_layout "
-             "'paged' (nor the prefix reuse that rests on it): a recurrent "
-             "state has no token axis to page and no snapshot to resume a "
-             "shared prefix from; set kv_layout: 'contiguous'"),
-            (inf.kv_cache_dtype == "int8", "does not support "
-             "inference.kv_cache_dtype 'int8': the state is float32 and K/V "
-             "are stored in the model's dtype"),
-            (inf.weight_dtype == "int8", "does not support "
-             "inference.weight_dtype 'int8': its matmuls take dense weights "
-             "only"),
-            (bool(inf.tenancy.tenants or inf.tenancy.manifest), "does not "
-             "support LoRA adapters (inference.tenancy): the adapter pack "
-             "is shaped for the Llama block's seven projections"),
-            (inf.spec_len > 0 or m.num_nextn_predict_layers > 0, "does not "
-             f"support speculation (inference.spec_len {inf.spec_len}, "
-             f"model.num_nextn_predict_layers {m.num_nextn_predict_layers}): "
-             "a rejected draft cannot be rolled back out of a recurrent "
-             "state by rewinding a length, so the multi-token-prediction "
-             "layer is not held"),
-            (inf.attend_impl == "flash", f"does not support "
-             f"inference.attend_impl {inf.attend_impl!r}: the recurrent "
-             "state has no kernel, and forcing one for the attention "
-             "layer's prefill chunks is untested ('auto' runs it for the "
-             "decode step)"),
-            (inf.overlap or inf.mixed_dispatch or inf.key_schedule == "slot"
-             or inf.dp_size > 1, "serves through the serial round-keyed "
-             "programs only: inference.overlap, mixed_dispatch, key_schedule "
-             "'slot' and dp_size > 1 are not implemented for it"),
-        )
-        for bad, why in refused:
-            if bad:
-                raise ValueError(f"{who} {why}")
-        for name in ("mamba_num_heads", "mamba_head_dim", "ssm_state_size",
-                     "conv_kernel", "n_groups", "chunk_size",
-                     "n_routed_experts", "num_experts_per_tok",
-                     "moe_intermediate_size", "moe_latent_size",
-                     "moe_shared_expert_intermediate_size", "ep_size"):
-            if getattr(m, name) < 1:
-                raise ValueError(f"{who} needs model.{name} >= 1")
-        if len(pattern) != m.num_hidden_layers or set(pattern) - set("ME*"):
-            raise ValueError(
-                f"{who} needs model.hybrid_override_pattern: one of 'M' | "
-                f"'E' | '*' for each of the {m.num_hidden_layers} layers "
-                f"(got {pattern!r}; '-', a dense MLP layer, is not "
-                "implemented)")
-        if "M" not in pattern or "*" not in pattern:
-            raise ValueError(
-                f"{who} needs at least one 'M' and one '*' layer in "
-                "model.hybrid_override_pattern: the cache holds a leaf of "
-                "each kind")
-        checks = (
-            (m.mamba_num_heads % m.n_groups, f"mamba_num_heads "
-             f"{m.mamba_num_heads} must be a multiple of n_groups "
-             f"{m.n_groups}"),
-            (m.num_attention_heads % m.num_key_value_heads,
-             f"num_attention_heads {m.num_attention_heads} must be a "
-             f"multiple of num_key_value_heads {m.num_key_value_heads}"),
-            (not 0 <= m.ep_rank < m.ep_size, f"ep_rank {m.ep_rank} outside "
-             f"[0, ep_size {m.ep_size})"),
-            (m.num_experts_per_tok > width, f"num_experts_per_tok "
-             f"{m.num_experts_per_tok} passes the router's width {width} "
-             "(n_routed_experts x ep_size)"),
-            (width % m.n_group or not 1 <= m.topk_group <= m.n_group,
-             f"n_group {m.n_group} must divide the router's width {width} "
-             f"and hold topk_group {m.topk_group}"),
-        )
-        for bad, why in checks:
-            if bad:
-                raise ValueError(f"{who}: {why}")
-        for name, want in (("use_conv_bias", True),
-                           ("mamba_proj_bias", False),
-                           ("mlp_hidden_act", "relu2"),
-                           ("n_shared_experts", 1),
-                           ("norm_topk_prob", True),
-                           ("tie_word_embeddings", False)):
-            if getattr(m, name) != want:
-                raise ValueError(
-                    f"{who} implements model.{name} = {want!r} only (got "
-                    f"{getattr(m, name)!r})")
-
-    def _validate_solar_open2(self, for_training: bool) -> None:
-        """What ``models/solar_open2.py`` needs of its keys, and what it
-        cannot do yet, each refused by name."""
-        d, m, inf = self.distributed, self.model, self.inference
-        who = "model_type 'solar_open2'"
-        la = m.linear_attn_config or {}
-        gqa = m.gqa_layers or []
-        width = m.n_routed_experts * m.ep_size
-        refused = (
-            (for_training, "is served, not trained: training is not "
-             "implemented for this block (no backward through the chunked "
-             "delta rule and the expert share; train_step builds the Llama "
-             "block only)"),
-            (d.tp_size > 1, f"does not support tp_size > 1 (got "
-             f"{d.tp_size}): the recurrent state has no tp sharding and the "
-             "block holds no tp collectives; its share of a layer is "
-             "ep_size/ep_rank"),
-            (inf.kv_layout == "paged", "does not support inference.kv_layout "
-             "'paged' (nor the prefix reuse that rests on it): a recurrent "
-             "state has no token axis to page and no snapshot to resume a "
-             "shared prefix from; set kv_layout: 'contiguous'"),
-            (inf.kv_cache_dtype == "int8", "does not support "
-             "inference.kv_cache_dtype 'int8': the state is float32 and K/V "
-             "are stored in the model's dtype"),
-            (inf.weight_dtype == "int8", "does not support "
-             "inference.weight_dtype 'int8': its matmuls take dense weights "
-             "only"),
-            (bool(inf.tenancy.tenants or inf.tenancy.manifest), "does not "
-             "support LoRA adapters (inference.tenancy): the adapter pack "
-             "is shaped for the Llama block's seven projections"),
-            (inf.spec_len > 0, f"does not support speculation "
-             f"(inference.spec_len {inf.spec_len}): a rejected draft cannot "
-             "be rolled back out of a recurrent state by rewinding a length"),
-            (inf.attend_impl == "flash", f"does not support "
-             f"inference.attend_impl {inf.attend_impl!r}: the recurrent "
-             "state has no kernel, and forcing one for the GQA layers' "
-             "prefill chunks is untested ('auto' runs it for the decode "
-             "step)"),
-            (inf.overlap or inf.mixed_dispatch or inf.key_schedule == "slot"
-             or inf.dp_size > 1, "serves through the serial round-keyed "
-             "programs only: inference.overlap, mixed_dispatch, key_schedule "
-             "'slot' and dp_size > 1 are not implemented for it"),
-        )
-        for bad, why in refused:
-            if bad:
-                raise ValueError(f"{who} {why}")
-        for name in ("n_routed_experts", "n_shared_experts",
-                     "num_experts_per_tok", "moe_intermediate_size",
-                     "ep_size"):
-            if getattr(m, name) < 1:
-                raise ValueError(f"{who} needs model.{name} >= 1")
-        need = ("num_heads", "head_dim", "short_conv_kernel_size")
-        if any(int(la.get(n) or 0) < 1 for n in need) \
-                or la.get("num_kv_heads") not in (None, la.get("num_heads")):
-            raise ValueError(
-                f"{who} needs model.linear_attn_config with "
-                f"{', '.join(need)} each >= 1 and num_kv_heads null or "
-                f"num_heads (k and v a head each; got "
-                f"{m.linear_attn_config!r})")
-        kda = [i for i in range(m.num_hidden_layers) if i not in gqa]
-        if not gqa or not kda or sorted(set(gqa)) != list(gqa) \
-                or not 0 <= gqa[0] <= gqa[-1] < m.num_hidden_layers:
-            raise ValueError(
-                f"{who} needs model.gqa_layers: rising indices among the "
-                f"{m.num_hidden_layers} layers held, with at least one GQA "
-                f"and one KDA layer (the cache holds a leaf of each kind; "
-                f"got {m.gqa_layers!r})")
-        checks = (
-            (m.gqa_interval and any(
-                b - a != m.gqa_interval + 1 for a, b in zip(gqa, gqa[1:])),
-             f"gqa_layers {gqa} do not lie gqa_interval {m.gqa_interval} "
-             "KDA layers apart"),
-            (m.num_attention_heads % m.num_key_value_heads,
-             f"num_attention_heads {m.num_attention_heads} must be a "
-             f"multiple of num_key_value_heads {m.num_key_value_heads}"),
-            (not 0 <= m.ep_rank < m.ep_size, f"ep_rank {m.ep_rank} outside "
-             f"[0, ep_size {m.ep_size})"),
-            (m.num_experts_per_tok > width, f"num_experts_per_tok "
-             f"{m.num_experts_per_tok} passes the router's width {width} "
-             "(n_routed_experts x ep_size)"),
-        )
-        for bad, why in checks:
-            if bad:
-                raise ValueError(f"{who}: {why}")
-        for name, want in (("use_rope", False),
-                           ("first_k_dense_replace", 0),
-                           ("scoring_func", "sigmoid"),
-                           ("norm_topk_prob", True),
-                           ("tie_word_embeddings", False)):
-            if getattr(m, name) != want:
-                raise ValueError(
-                    f"{who} implements model.{name} = {want!r} only (got "
-                    f"{getattr(m, name)!r})")
 
     # ---- JSON round-trip (reference: train.py:62-63 consumes one JSON file) ----
 

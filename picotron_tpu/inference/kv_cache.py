@@ -183,10 +183,6 @@ def latent_widths(m: ModelConfig) -> dict:
             "ki": m.index_head_dim}
 
 
-def latent_cache_pspecs() -> dict:
-    return {**{n: P() for n in LATENT_LEAVES}, "lengths": P()}
-
-
 def init_latent_cache(m: ModelConfig, slots: int, max_seq_len: int,
                       dtype=None) -> dict:
     """Zeroed latent cache for ``slots`` concurrent sequences."""

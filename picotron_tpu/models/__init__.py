@@ -14,28 +14,16 @@ whatever the block:
   function, how many layers)]``, scanned one after the other over one cache;
   every layer function keeps ``llama.decoder_layer``'s contract and is
   handed its global index (a block whose cache leaves run over different
-  layers, ``granite_hybrid``, ``minicpm_sala``, ``afmoe`` or ``mimo_v2``,
-  finds its own row from it; what ``nemotron_h`` hands the scan as a layer
-  is a unit of its pattern, one sublayer of each kind at most,
-  ``nemotron_h.stacking``; ``solar_open2``'s layers alternate as
-  ``granite_hybrid``'s do, by ``gqa_layers``); in a decode
-  block its cache dict also holds ``"active"`` [B] (parked and in budget),
-  which a block that keeps K/V alone need not read;
+  layers finds its own row from it, ``leaf_row``); in a decode block its
+  cache dict also holds ``"active"`` [B] (parked and in budget), which a
+  block that keeps K/V alone need not read;
 - ``UNSLICED``: names of a group's leaves the scan hands its layers
   whole, with the layer's row in them under ``"row"`` (``()``: none);
 - ``serving_rope_tables(m, seq_len, dtype)``: the angle tables of the cache window;
 - ``cache_pspecs(m, quantized, dp=...)`` and ``init_cache(m, slots,
-  max_seq_len, dtype=..., quantized=..., tp=...)``: the contiguous cache
-  (K/V heads for the Llama block, as many to a row as fill its lanes on a
-  'tp' axis that wide; latent rows for ``deepseek_v32``; K/V, compressed
-  keys and a float32 state side by side for ``minicpm_sala``; full-length
-  K/V and rings of a window's rows side by side for ``afmoe``; the same
-  with keys wider than values and K/V heads counted by kind, four leaves of
-  four shapes, for ``mimo_v2``; a token's K and V heads in one row and an
-  indexer's keys, two to a row, for ``keye_vl2``; two K/V heads of 128 a
-  token beside a float32 state for ``nemotron_h``; K/V of the GQA layers
-  beside the float32 delta-rule state ``kda`` and the conv tail of the KDA
-  layers for ``solar_open2``);
+  max_seq_len, dtype=..., quantized=..., tp=...)``: the contiguous cache,
+  whose leaves beside ``"lengths"`` a block other than Llama names in
+  ``LEAVES`` (what each holds is its ``init_cache``'s to say);
 - ``RING_CACHE`` (absent: false): some leaves are rings a prefill chunk's
   writes must fit; ``init_cache`` then also takes ``prefill_chunk``;
 - ``CARRIES_STATE`` (absent: false): the cache holds a state with no token
@@ -43,15 +31,25 @@ whatever the block:
   window to whole prefill chunks;
 - ``STAT_NAMES``: the counters a layer returns, an int32 vector under
   ``"stats"`` in its dict (``()``: none, and the programs have no such
-  output).
+  output);
+- ``WHY`` and ``validate(cfg, for_training)`` (absent, as in the Llama
+  block: nothing is refused): the block's reason against each thing it
+  cannot do yet, and its checks of a configuration, the shared refusals
+  and its own keys (``models/support.py``). ``Config.validate`` calls it.
+
+A block served whole on one chip takes ``param_pspecs``, ``num_params`` and
+``cache_pspecs`` from ``served_whole`` below.
 """
 
 import importlib
+import math
 
 from picotron_tpu.models import llama  # noqa: F401
 
+import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 # the key under which a layer that counts returns its counters
 STATS = "stats"
@@ -172,6 +170,32 @@ def state_counts(live, decode: bool) -> tuple:
     return (n_live, zero + 1, zero) if decode else (zero, zero, n_live)
 
 
+def served_whole(block: str, init_params, leaves: tuple) -> tuple:
+    """``(param_pspecs, num_params, cache_pspecs)`` of a block served whole
+    on one chip, from its ``init_params`` and the names of its cache's
+    leaves: every leaf replicated (tp_size 1: the block's share of a layer
+    is ``ep_size``/``ep_rank``, a cut and not a mesh axis), the tree read
+    off the shapes ``init_params`` draws. ``block`` names it in the
+    refusal of anything else (``Config.validate`` refuses it first)."""
+
+    def shapes(m):
+        return jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
+
+    def param_pspecs(m, fsdp: bool = False, weight_dtype: str = "bf16"):
+        if fsdp or weight_dtype != "bf16":
+            raise ValueError(f"{block} serves dense weights, unsharded")
+        return jax.tree.map(lambda _: P(), shapes(m))
+
+    def num_params(m) -> int:
+        return sum(math.prod(s.shape) for s in jax.tree.leaves(shapes(m)))
+
+    def cache_pspecs(m, quantized: bool = False, dp: int = 1) -> dict:
+        assert not quantized and dp == 1
+        return {n: P() for n in leaves + ("lengths",)}
+
+    return param_pspecs, num_params, cache_pspecs
+
+
 # ``model_type`` -> the module of ``picotron_tpu.models`` that builds it
 BLOCKS = {
     "llama": "llama",
@@ -191,6 +215,7 @@ def model_module(m):
     Imported on demand: ``deepseek_v32`` needs the inference package, which
     needs this one."""
     if m.model_type not in BLOCKS:
-        raise ValueError(f"unknown model_type {m.model_type!r}")
+        raise ValueError(
+            f"unknown model_type {m.model_type!r} ({'|'.join(BLOCKS)})")
     return importlib.import_module(
         "picotron_tpu.models." + BLOCKS[m.model_type])

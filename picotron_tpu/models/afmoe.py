@@ -67,11 +67,11 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
 
 from picotron_tpu.config import Config, ModelConfig
 from picotron_tpu.inference import kv_cache
-from picotron_tpu.models import STATS, leaf_row, live_rows, llama, runs
+from picotron_tpu.models import (STATS, leaf_row, live_rows, llama, runs,
+                                 served_whole, support)
 from picotron_tpu.models import experts as expert_share
 from picotron_tpu.models.llama import param_bytes  # noqa: F401 - the seam
 from picotron_tpu.ops.attention import NEG_INF
@@ -98,6 +98,46 @@ ROTATED = (WINDOW,)  # the kinds of layer whose q and k take RoPE
 # float32 scores (48 x 512 x 2048: 201 MB)
 KEY_BLOCK = 2048
 ROUTE_EPS = 1e-20
+LEAVES = ("k", "v", "kw", "vw")  # the cache's, beside "lengths"
+WHY = support.RINGS  # what the block cannot do yet
+
+
+def validate(cfg: Config, for_training: bool) -> None:
+    """What the block cannot do yet and what it needs of its keys, each
+    refused by name (``Config.validate`` calls it)."""
+    m = cfg.model
+    support.refuse(cfg, for_training, WHY)
+    support.positive(m, "sliding_window", "num_experts", "num_shared_experts",
+                     "num_experts_per_tok", "moe_intermediate_size",
+                     "ep_size")
+    kinds = (WINDOW, FULL)
+    support.layer_kinds(m, "layer_types", kinds)
+    support.check(m, (
+        not 0 <= m.num_dense_layers < m.num_hidden_layers,
+        f"num_dense_layers {m.num_dense_layers} outside [0, "
+        f"num_hidden_layers {m.num_hidden_layers})"))
+    every = m.global_attn_every_n_layers
+    if every:
+        support.held_layers(
+            m, m.num_hidden_layers - m.num_dense_layers,
+            lead=m.num_dense_layers, what="expert layers",
+            behind=f" behind num_dense_layers {m.num_dense_layers}")
+        first = m.first_layer if m.total_layers else m.num_dense_layers
+        for i, t in enumerate(m.layer_types):
+            pub = i if i < m.num_dense_layers \
+                else first + i - m.num_dense_layers
+            support.check(m, (
+                (t == FULL) != ((pub + 1) % every == 0),
+                f"layer_types[{i}] {t!r} is published layer {pub}, which "
+                f"global_attn_every_n_layers {every} makes "
+                f"{kinds[(pub + 1) % every == 0]!r}"))
+    support.check(m, (
+        m.head_dim % 2,
+        f"head_dim {m.head_dim} must be even (RoPE rotates halves)"))
+    support.ep_share(m, "num_experts")
+    support.pinned(m, score_func="sigmoid", route_norm=True, n_group=1,
+                   topk_group=1, tie_word_embeddings=False,
+                   rope_scaling=None)
 
 
 # --------------------------------------------------------------------------- #
@@ -215,19 +255,8 @@ def init_params(key, m: ModelConfig, pp_size: int = 1,
     return params
 
 
-def param_pspecs(m: ModelConfig, fsdp: bool = False,
-                 weight_dtype: str = "bf16") -> dict:
-    """Every leaf replicated: the block is served at tp_size 1 (its share
-    of a layer is ``ep_size``/``ep_rank``, a cut and not a mesh axis)."""
-    if fsdp or weight_dtype != "bf16":
-        raise ValueError("afmoe serves dense weights, unsharded")
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
-    return jax.tree.map(lambda _: P(), shapes)
-
-
-def num_params(m: ModelConfig) -> int:
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
-    return sum(math.prod(s.shape) for s in jax.tree.leaves(shapes))
+param_pspecs, num_params, cache_pspecs = served_whole(
+    "afmoe", init_params, LEAVES)
 
 
 # --------------------------------------------------------------------------- #
@@ -258,14 +287,6 @@ def ring_rows(m: ModelConfig, max_seq_len: int, prefill_chunk: int) -> int:
     chunk more, so that a chunk's writes land only on rows older than any
     of its queries sees; never more than the cache window itself."""
     return min(m.sliding_window + prefill_chunk, max_seq_len)
-
-
-def cache_pspecs(m: ModelConfig, quantized: bool = False,
-                 dp: int = 1) -> dict:
-    """Both kinds of K/V are served whole on one chip (``Config.validate``
-    refuses the rest by name)."""
-    assert not quantized and dp == 1
-    return {n: P() for n in ("k", "v", "kw", "vw", "lengths")}
 
 
 def init_cache(m: ModelConfig, slots: int, max_seq_len: int, dtype=None,

@@ -60,11 +60,10 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
 
 from picotron_tpu.config import Config, ModelConfig
 from picotron_tpu.inference import kv_cache
-from picotron_tpu.models import STATS
+from picotron_tpu.models import STATS, served_whole, support
 from picotron_tpu.models import experts as expert_share
 from picotron_tpu.models.experts import swiglu as _swiglu
 from picotron_tpu.models.llama import (  # noqa: F401 - the seam's shared parts
@@ -101,6 +100,62 @@ STAT_NAMES = expert_share.STAT_NAMES + (
 # queries attended at a time: bounds the [B, S, heads, keys] scores of a key
 # block (``ops/select.py::KEY_BLOCK`` keys, the indexer's blocks)
 QUERY_BLOCK = 128
+LEAVES = kv_cache.LATENT_LEAVES  # the latent cache's, beside "lengths"
+
+# what the block cannot do yet, and why (``support.refuse``)
+WHY = {
+    **support.LLAMA_ONLY,
+    "training": "no backward through the selection and the expert share",
+    "tp": "the latent cache has no head axis to shard and the block holds no "
+          "tp collectives; its share of a layer is ep_size/ep_rank",
+    "dp": "the latent cache has no slot axis over 'dp'",
+    "paged": "the latent cache is contiguous only (paged_kv.py pages K/V "
+             "heads); set kv_layout: 'contiguous'",
+    "kv_int8": "the latent cache is stored in the model's dtype",
+    "speculation": "there is no verify program for this block and the MTP "
+                   "module is cut with the depth",
+    "flash": "the flash-decode kernel reads K/V heads, not latent rows",
+}
+
+
+def validate(cfg: Config, for_training: bool) -> None:
+    """What the block cannot do yet and what it needs of its keys, each
+    refused by name so that nothing runs the Llama block under this model's
+    name (``Config.validate`` calls it)."""
+    m = cfg.model
+    support.refuse(cfg, for_training, WHY)
+    support.positive(
+        m, "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+        "qk_rope_head_dim", "v_head_dim", "index_n_heads", "index_head_dim",
+        "index_topk", "n_routed_experts", "n_shared_experts",
+        "num_experts_per_tok", "moe_intermediate_size", "n_group",
+        "topk_group", "ep_size")
+    support.check(m, (
+        m.qk_rope_head_dim % 2 or m.index_head_dim < m.qk_rope_head_dim,
+        f"qk_rope_head_dim ({m.qk_rope_head_dim}) must be even and fit "
+        f"index_head_dim ({m.index_head_dim})"))
+    support.ep_share(m)
+    width = router_width(m)
+    support.check(
+        m,
+        (width % m.n_group or m.topk_group > m.n_group
+         or m.num_experts_per_tok > m.topk_group * (width // m.n_group)
+         or width // m.n_group < 2,
+         f"the router's width {width} (n_routed_experts x ep_size) must "
+         f"split into n_group {m.n_group} groups of at least 2, with "
+         f"topk_group {m.topk_group} <= n_group and num_experts_per_tok "
+         f"{m.num_experts_per_tok} experts inside the kept groups"),
+        (not 0 <= m.first_k_dense_replace <= m.num_hidden_layers,
+         f"first_k_dense_replace {m.first_k_dense_replace} outside [0, "
+         f"num_hidden_layers {m.num_hidden_layers}]"))
+    support.pinned(m, scoring_func="sigmoid", topk_method="noaux_tc",
+                   norm_topk_prob=True, moe_layer_freq=1,
+                   num_nextn_predict_layers=0)
+    rs = m.rope_scaling
+    if rs is not None and rs.get("type", rs.get("rope_type")) != "yarn":
+        raise ValueError(
+            f"{support.who(m)} implements rope_scaling type 'yarn' only (got "
+            f"{rs!r})")
 
 
 # --------------------------------------------------------------------------- #
@@ -218,19 +273,8 @@ def init_params(key, m: ModelConfig, pp_size: int = 1,
     return params
 
 
-def param_pspecs(m: ModelConfig, fsdp: bool = False,
-                 weight_dtype: str = "bf16") -> dict:
-    """Every leaf replicated: the block is served at tp_size 1 (its share
-    of a layer is ``ep_size``/``ep_rank``, a cut and not a mesh axis)."""
-    if fsdp or weight_dtype != "bf16":
-        raise ValueError("deepseek_v32 serves dense weights, unsharded")
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
-    return jax.tree.map(lambda _: P(), shapes)
-
-
-def num_params(m: ModelConfig) -> int:
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
-    return sum(math.prod(s.shape) for s in jax.tree.leaves(shapes))
+param_pspecs, num_params, cache_pspecs = served_whole(
+    "deepseek_v32", init_params, LEAVES)
 
 
 # --------------------------------------------------------------------------- #
@@ -250,14 +294,6 @@ def softmax_scale(m: ModelConfig) -> float:
     if m.rope_scaling is not None:
         scale *= yarn_mscale(m.rope_scaling) ** 2
     return scale
-
-
-def cache_pspecs(m: ModelConfig, quantized: bool = False,
-                 dp: int = 1) -> dict:
-    """The latent cache is served whole on one chip, in the model's dtype
-    (``Config.validate`` refuses the rest by name)."""
-    assert not quantized and dp == 1
-    return kv_cache.latent_cache_pspecs()
 
 
 def init_cache(m: ModelConfig, slots: int, max_seq_len: int, dtype=None,

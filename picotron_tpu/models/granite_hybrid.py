@@ -54,12 +54,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
 
 from picotron_tpu.config import Config, ModelConfig
 from picotron_tpu.inference import kv_cache
 from picotron_tpu.models import (STATS, carry_state, leaf_row, live_rows,
-                                 llama, runs, state_counts)
+                                 llama, runs, served_whole, state_counts,
+                                 support)
 from picotron_tpu.models import experts as expert_share
 from picotron_tpu.models.llama import param_bytes  # noqa: F401 - the seam
 from picotron_tpu.ops.rmsnorm import rms_norm
@@ -77,8 +77,31 @@ UNSLICED = expert_share.UNSLICED
 # holds the window to whole prefill chunks (``prefill_chunked``'s last
 # chunk slides back, and re-feeds its overlap, where it would pass the end)
 CARRIES_STATE = True
+LEAVES = ("k", "v", "ssm", "conv")  # the cache's, beside "lengths"
+WHY = support.RECURRENT_STATE  # what the block cannot do yet
 F32 = jnp.float32
 HIGHEST = lax.Precision.HIGHEST
+
+
+def validate(cfg: Config, for_training: bool) -> None:
+    """What the block cannot do yet and what it needs of its keys, each
+    refused by name (``Config.validate`` calls it)."""
+    m = cfg.model
+    support.refuse(cfg, for_training, WHY)
+    support.positive(
+        m, "mamba_n_heads", "mamba_d_head", "mamba_d_state", "mamba_d_conv",
+        "mamba_chunk_size", "num_local_experts", "num_experts_per_tok",
+        "shared_intermediate_size", "ep_size")
+    support.layer_kinds(m, "layer_types", ("mamba", "attention"))
+    support.check(m, (
+        m.mamba_n_heads * m.mamba_d_head != m.mamba_expand * m.hidden_size,
+        f"mamba_n_heads {m.mamba_n_heads} x mamba_d_head {m.mamba_d_head} "
+        f"must be mamba_expand {m.mamba_expand} x hidden_size "
+        f"{m.hidden_size}"))
+    support.ep_share(m, "num_local_experts")
+    support.pinned(m, mamba_n_groups=1, mamba_conv_bias=True,
+                   mamba_proj_bias=False, position_embedding_type="nope",
+                   tie_word_embeddings=True, rope_scaling=None)
 
 
 # --------------------------------------------------------------------------- #
@@ -205,19 +228,8 @@ def init_params(key, m: ModelConfig, pp_size: int = 1,
     return params
 
 
-def param_pspecs(m: ModelConfig, fsdp: bool = False,
-                 weight_dtype: str = "bf16") -> dict:
-    """Every leaf replicated: the block is served at tp_size 1 (its share
-    of a layer is ``ep_size``/``ep_rank``, a cut and not a mesh axis)."""
-    if fsdp or weight_dtype != "bf16":
-        raise ValueError("granitemoehybrid serves dense weights, unsharded")
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
-    return jax.tree.map(lambda _: P(), shapes)
-
-
-def num_params(m: ModelConfig) -> int:
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
-    return sum(math.prod(s.shape) for s in jax.tree.leaves(shapes))
+param_pspecs, num_params, cache_pspecs = served_whole(
+    "granitemoehybrid", init_params, LEAVES)
 
 
 # --------------------------------------------------------------------------- #
@@ -244,14 +256,6 @@ def serving_rope_tables(m: ModelConfig, seq_len: int, dtype) -> tuple:
     length (the programs slice and gather them by position)."""
     t = jnp.zeros((seq_len, 2), dtype)
     return t, t
-
-
-def cache_pspecs(m: ModelConfig, quantized: bool = False,
-                 dp: int = 1) -> dict:
-    """State and K/V are served whole on one chip (``Config.validate``
-    refuses the rest by name)."""
-    assert not quantized and dp == 1
-    return {n: P() for n in ("k", "v", "ssm", "conv", "lengths")}
 
 
 def init_cache(m: ModelConfig, slots: int, max_seq_len: int, dtype=None,

@@ -66,11 +66,11 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
 
 from picotron_tpu.config import Config, ModelConfig
 from picotron_tpu.inference import kv_cache
-from picotron_tpu.models import STATS, live_rows, llama
+from picotron_tpu.models import (STATS, live_rows, llama, served_whole,
+                                 support)
 from picotron_tpu.models import experts as expert_share
 from picotron_tpu.models.llama import param_bytes  # noqa: F401 - the seam
 from picotron_tpu.ops import select
@@ -88,7 +88,7 @@ from picotron_tpu.ops.select import gather_rows
 STAT_NAMES = expert_share.STAT_NAMES + (
     "dsa_keys_selected", "dsa_keys_scored", "dsa_rows_attended")
 UNSLICED = expert_share.UNSLICED
-LEAVES = ("kv", "ki")
+LEAVES = ("kv", "ki")  # the cache's, beside "lengths"
 F32 = jnp.float32
 HIGHEST = lax.Precision.HIGHEST
 QUERY_BLOCK = 128  # queries a masked walk attends at a time
@@ -107,6 +107,67 @@ DECODE_KEY_BLOCK = 8192
 # stays inside the check's limit.
 INIT_GAIN = {"wo": 4.0, "w2": 0.5}
 KI_BIAS = 0.1  # the indexer's LayerNorm bias, U(+-): small, and not zero
+
+# what the block cannot do yet, and why (``support.refuse``)
+WHY = {
+    **support.LLAMA_ONLY,
+    "training": "no backward through the top-k selection, whose indexer "
+                "would need a training loss of its own, nor through the "
+                "expert share",
+    "tp": "the block holds no tp collectives and the indexer's one key head "
+          "cannot be sharded; its share of a layer is ep_size/ep_rank",
+    "dp": "the indexer's keys have no slot axis over 'dp'",
+    "paged": "paged_kv.py pages K/V heads, not the indexer's keys, and its "
+             "attends do not gather chosen rows; set kv_layout: 'contiguous'",
+    "kv_int8": "K, V and the indexer's keys are stored in the model's dtype",
+    "speculation": "a verify block's queries would each gather rows of their "
+                   "own, and there is no such program",
+    "flash": "the flash-decode kernels read a prefix, not chosen rows",
+}
+
+
+def validate(cfg: Config, for_training: bool) -> None:
+    """What the block cannot do yet and what it needs of its keys, each
+    refused by name (``Config.validate`` calls it)."""
+    m = cfg.model
+    who = support.who(m)
+    support.refuse(cfg, for_training, WHY)
+    support.positive(m, "num_experts", "num_experts_per_tok",
+                     "moe_intermediate_size", "ep_size")
+    sa = m.sa_config or {}
+    need = ("indexer_num_heads", "indexer_head_dim", "topk")
+    if any(int(sa.get(n, 0)) < 1 for n in need) \
+            or int(sa.get("indexer_num_kv_heads", 1)) != 1:
+        raise ValueError(
+            f"{who} needs model.sa_config with {', '.join(need)} each "
+            f">= 1 and indexer_num_kv_heads 1 (got {m.sa_config!r})")
+    support.check(m, (
+        m.head_dim % 2 or int(sa["indexer_head_dim"]) % 2,
+        f"head_dim {m.head_dim} and sa_config.indexer_head_dim "
+        f"{sa['indexer_head_dim']} must be even (RoPE rotates halves)"))
+    rs = m.rope_scaling or {}
+    section = rs.get("mrope_section")
+    if rs.get("rope_type", rs.get("type", "default")) != "default" \
+            or not isinstance(section, list) or len(section) != 3 \
+            or sum(section) != m.head_dim // 2:
+        raise ValueError(
+            f"{who} needs model.rope_scaling of type 'default' with an "
+            f"mrope_section of three counts that sum to head_dim / 2 = "
+            f"{m.head_dim // 2} (got {m.rope_scaling!r})")
+    support.ep_share(m, "num_experts")
+    width = router_width(m)
+    support.check(m, (
+        m.num_local_experts not in (0, width),
+        f"num_local_experts {m.num_local_experts} is not the router's width "
+        f"{width} (num_experts x ep_size), which it repeats as published"))
+    support.held_layers(m, m.num_hidden_layers)
+    support.pinned(m, norm_topk_prob=True, decoder_sparse_step=1,
+                   tie_word_embeddings=False)
+    if m.mlp_only_layers:
+        raise ValueError(
+            f"{who} implements model.mlp_only_layers = [] only (got "
+            f"{m.mlp_only_layers!r}): every layer's MLP is the routed "
+            "experts")
 
 
 # --------------------------------------------------------------------------- #
@@ -181,19 +242,8 @@ def init_params(key, m: ModelConfig, pp_size: int = 1,
     }
 
 
-def param_pspecs(m: ModelConfig, fsdp: bool = False,
-                 weight_dtype: str = "bf16") -> dict:
-    """Every leaf replicated: the block is served at tp_size 1 (its share
-    of a layer is ``ep_size``/``ep_rank``, a cut and not a mesh axis)."""
-    if fsdp or weight_dtype != "bf16":
-        raise ValueError("KeyeVL2 serves dense weights, unsharded")
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
-    return jax.tree.map(lambda _: P(), shapes)
-
-
-def num_params(m: ModelConfig) -> int:
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
-    return sum(math.prod(s.shape) for s in jax.tree.leaves(shapes))
+param_pspecs, num_params, cache_pspecs = served_whole(
+    "KeyeVL2", init_params, LEAVES)
 
 
 # --------------------------------------------------------------------------- #
@@ -201,10 +251,7 @@ def num_params(m: ModelConfig) -> int:
 # --------------------------------------------------------------------------- #
 
 
-def embed_lookup(w, tokens, cfg: Config):
-    return llama.embed_lookup(w, tokens)
-
-
+embed_lookup = llama.embed_lookup  # no multiplier
 head_logits = llama.head_logits  # final norm, then the untied head
 
 
@@ -239,14 +286,6 @@ def ki_pack(m: ModelConfig) -> int:
     register row's lanes (two of 64; one where they do not fill it whole)."""
     D = indexer(m)[1]
     return kv_cache.LANE // D if kv_cache.LANE % D == 0 else 1
-
-
-def cache_pspecs(m: ModelConfig, quantized: bool = False,
-                 dp: int = 1) -> dict:
-    """Both leaves are served whole on one chip (``Config.validate``
-    refuses the rest by name)."""
-    assert not quantized and dp == 1
-    return {n: P() for n in LEAVES + ("lengths",)}
 
 
 def init_cache(m: ModelConfig, slots: int, max_seq_len: int, dtype=None,

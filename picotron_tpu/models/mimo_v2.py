@@ -63,11 +63,11 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
 
 from picotron_tpu.config import Config, ModelConfig
 from picotron_tpu.inference import kv_cache
-from picotron_tpu.models import STATS, leaf_row, live_rows, llama, runs
+from picotron_tpu.models import (STATS, leaf_row, live_rows, llama, runs,
+                                 served_whole, support)
 from picotron_tpu.models import afmoe as rings
 from picotron_tpu.models import experts as expert_share
 from picotron_tpu.models.llama import param_bytes  # noqa: F401 - the seam
@@ -82,7 +82,71 @@ RING_CACHE = True  # ``init_cache`` takes the engine's ``prefill_chunk``
 F32 = jnp.float32
 HIGHEST = lax.Precision.HIGHEST
 ROUTE_EPS = 1e-20
-LEAVES = ("k", "v", "kw", "vw")
+LEAVES = ("k", "v", "kw", "vw")  # the cache's, beside "lengths"
+WHY = support.RINGS  # what the block cannot do yet
+
+
+def validate(cfg: Config, for_training: bool) -> None:
+    """What the block cannot do yet and what it needs of its keys, each
+    refused by name (``Config.validate`` calls it)."""
+    m = cfg.model
+    who = support.who(m)
+    support.refuse(cfg, for_training, WHY)
+    support.positive(
+        m, "sliding_window", "n_routed_experts", "num_experts_per_tok",
+        "moe_intermediate_size", "ep_size", "v_head_dim",
+        "swa_num_attention_heads", "swa_num_key_value_heads", "swa_head_dim",
+        "swa_v_head_dim")
+    n = m.num_hidden_layers
+    for name in ("hybrid_layer_pattern", "moe_layer_freq"):
+        got = getattr(m, name)
+        if not isinstance(got, list) or len(got) != n \
+                or any(v not in (0, 1) for v in got):
+            raise ValueError(
+                f"{who} needs model.{name}: 0 or 1 for each of the {n} "
+                f"layers (got {got!r})")
+    if len(set(m.hybrid_layer_pattern)) < 2:
+        raise ValueError(
+            f"{who} needs at least one full (0) and one sliding (1) "
+            "layer in model.hybrid_layer_pattern: the cache holds "
+            "leaves of each kind")
+    # the leading layer is held whatever the cut
+    support.held_layers(m, n - 1, lead=1, behind=" behind the leading layer")
+    for heads, kv, hd, kind in (
+            (m.num_attention_heads, m.num_key_value_heads, m.head_dim,
+             "full"),
+            (m.swa_num_attention_heads, m.swa_num_key_value_heads,
+             m.swa_head_dim, "sliding")):
+        rot = int(hd * m.partial_rotary_factor)
+        support.check(
+            m,
+            (heads % kv, f"the {kind} layers' {heads} query heads must be a "
+             f"multiple of their {kv} K/V heads"),
+            (rot < 2 or rot % 2 or rot > hd,
+             f"partial_rotary_factor {m.partial_rotary_factor} of the {kind} "
+             f"layers' head_dim {hd} rotates {rot} dimensions: an even count "
+             "in [2, head_dim] is needed (RoPE rotates halves)"))
+    support.check(m, (
+        m.num_attention_heads * m.v_head_dim
+        != m.swa_num_attention_heads * m.swa_v_head_dim
+        or int(m.head_dim * m.partial_rotary_factor)
+        != int(m.swa_head_dim * m.partial_rotary_factor),
+        "both kinds of layer must rotate as many dimensions (one table holds "
+        "both bases) and hand W_o as many columns"))
+    support.ep_share(m, "n_routed_experts")
+    support.check(m, (
+        m.layernorm_epsilon and m.layernorm_epsilon != m.rms_norm_eps,
+        f"layernorm_epsilon {m.layernorm_epsilon} is not rms_norm_eps "
+        f"{m.rms_norm_eps} (the norms read the latter)"))
+    if (m.rope_scaling or {}).get("rope_type", "default") != "default":
+        raise ValueError(
+            f"{who} implements model.rope_scaling of type 'default' "
+            f"(none) only (got {m.rope_scaling!r})")
+    support.pinned(m, scoring_func="sigmoid", topk_method="noaux_tc",
+                   norm_topk_prob=True, n_group=1, topk_group=1,
+                   n_shared_experts=0, add_swa_attention_sink_bias=True,
+                   add_full_attention_sink_bias=False,
+                   tie_word_embeddings=False)
 
 # Seeded weights. The block has no norm behind a branch, so what a branch
 # adds to the stream is as loud as its matrices' draw makes it. With every
@@ -213,19 +277,8 @@ def init_params(key, m: ModelConfig, pp_size: int = 1,
     return params
 
 
-def param_pspecs(m: ModelConfig, fsdp: bool = False,
-                 weight_dtype: str = "bf16") -> dict:
-    """Every leaf replicated: the block is served at tp_size 1 (its share
-    of a layer is ``ep_size``/``ep_rank``, a cut and not a mesh axis)."""
-    if fsdp or weight_dtype != "bf16":
-        raise ValueError("mimo_v2 serves dense weights, unsharded")
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
-    return jax.tree.map(lambda _: P(), shapes)
-
-
-def num_params(m: ModelConfig) -> int:
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
-    return sum(math.prod(s.shape) for s in jax.tree.leaves(shapes))
+param_pspecs, num_params, cache_pspecs = served_whole(
+    "mimo_v2", init_params, LEAVES)
 
 
 # --------------------------------------------------------------------------- #
@@ -233,10 +286,7 @@ def num_params(m: ModelConfig) -> int:
 # --------------------------------------------------------------------------- #
 
 
-def embed_lookup(w, tokens, cfg: Config):
-    return llama.embed_lookup(w, tokens)
-
-
+embed_lookup = llama.embed_lookup  # no multiplier
 head_logits = llama.head_logits  # final norm, then the untied head
 
 
@@ -255,14 +305,6 @@ def _own_tables(cos, sin, window: bool) -> tuple:
     rot = cos.shape[-1] // 2
     half = slice(rot, None) if window else slice(0, rot)
     return cos[..., half], sin[..., half]
-
-
-def cache_pspecs(m: ModelConfig, quantized: bool = False,
-                 dp: int = 1) -> dict:
-    """All four leaves are served whole on one chip (``Config.validate``
-    refuses the rest by name)."""
-    assert not quantized and dp == 1
-    return {n: P() for n in LEAVES + ("lengths",)}
 
 
 def init_cache(m: ModelConfig, slots: int, max_seq_len: int, dtype=None,

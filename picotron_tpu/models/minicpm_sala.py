@@ -68,12 +68,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import PartitionSpec as P
 
 from picotron_tpu.config import Config, ModelConfig
 from picotron_tpu.inference import kv_cache
 from picotron_tpu.models import (STATS, carry_state, leaf_row, live_rows,
-                                 llama, runs, state_counts)
+                                 llama, runs, served_whole, state_counts,
+                                 support)
 from picotron_tpu.models.experts import swiglu
 from picotron_tpu.models.llama import param_bytes  # noqa: F401 - the seam
 from picotron_tpu.ops.attention import NEG_INF
@@ -105,6 +105,71 @@ KEY_BLOCK = 1024
 QUERY_BLOCK = 128
 # rows of a lightning layer's chunked scan at a time (``ops/ssm.py``)
 SCAN_CHUNK = 256
+LEAVES = ("k", "v", "kc", "state")  # the cache's, beside "lengths"
+
+# what the block cannot do yet, and why (``support.refuse``)
+WHY = {
+    **support.RECURRENT_STATE,
+    "training": "no backward through the block selection and the chunked "
+                "scan",
+    "tp": "the lightning state and the compressed keys have no tp sharding "
+          "and the block holds no tp collectives",
+    "dp": "the state and the compressed keys have no slot axis over 'dp'",
+    "paged": "the block selection gathers key blocks of a contiguous leaf, "
+             "and neither the compressed keys nor the lightning state are "
+             "paged; set kv_layout: 'contiguous'",
+    "kv_int8": "the state is float32 and K, V and the compressed keys are "
+               "stored in the model's dtype",
+    "flash": "the flash-decode kernel reads every live key, not the chosen "
+             "blocks",
+}
+
+
+def validate(cfg: Config, for_training: bool) -> None:
+    """What the block cannot do yet and what it needs of its keys, each
+    refused by name (``Config.validate`` calls it)."""
+    m = cfg.model
+    support.refuse(cfg, for_training, WHY)
+    support.positive(m, "lightning_nh", "lightning_nkv", "lightning_head_dim",
+                     "dim_model_base")
+    support.layer_kinds(m, "mixer_types", tuple(KINDS))
+    support.check(
+        m,
+        (m.lightning_nkv != m.lightning_nh,
+         f"lightning_nkv {m.lightning_nkv} must equal lightning_nh "
+         f"{m.lightning_nh} (a state a head)"),
+        (m.lightning_head_dim % 2,
+         f"lightning_head_dim {m.lightning_head_dim} must be even (RoPE "
+         "rotates halves)"))
+    support.held_layers(m, m.num_hidden_layers)
+    support.pinned(m, lightning_scale="1/sqrt(d)", lightning_use_rope=True,
+                   attn_use_rope=False, attn_use_output_gate=True,
+                   qk_norm=True, use_output_norm=True, use_output_gate=True,
+                   tie_word_embeddings=False, rope_scaling=None)
+    sc = m.sparse_config or {}
+    need = ("kernel_size", "kernel_stride", "block_size", "init_blocks",
+            "window_size", "topk", "dense_len")
+    if any(int(sc.get(k, 0)) < 1 for k in need):
+        raise ValueError(
+            f"{support.who(m)} needs model.sparse_config with "
+            f"{', '.join(need)} each >= 1 (got {m.sparse_config!r})")
+    ks, st, bs = sc["kernel_size"], sc["kernel_stride"], sc["block_size"]
+    chunk = cfg.inference.prefill_chunk
+    support.check(
+        m,
+        (ks != 2 * st or bs % st or sc["window_size"] % bs
+         or sc["dense_len"] % bs,
+         f"sparse_config needs kernel_size {ks} = 2 x kernel_stride {st}, "
+         f"and block_size {bs}, window_size {sc['window_size']} and "
+         f"dense_len {sc['dense_len']} in whole strides and blocks"),
+        (sc["init_blocks"] + sc["window_size"] // bs > sc["topk"],
+         f"sparse_config's forced blocks (init_blocks {sc['init_blocks']} + "
+         f"window_size / block_size {sc['window_size'] // bs}) pass topk "
+         f"{sc['topk']}"),
+        (chunk % st,
+         f"inference.prefill_chunk ({chunk}) must be a multiple of "
+         f"sparse_config.kernel_stride ({st}): a chunk writes whole rows of "
+         "compressed keys"))
 
 
 # --------------------------------------------------------------------------- #
@@ -219,18 +284,8 @@ def init_params(key, m: ModelConfig, pp_size: int = 1,
     return params
 
 
-def param_pspecs(m: ModelConfig, fsdp: bool = False,
-                 weight_dtype: str = "bf16") -> dict:
-    """Every leaf replicated: the block is served at tp_size 1."""
-    if fsdp or weight_dtype != "bf16":
-        raise ValueError("minicpm_sala serves dense weights, unsharded")
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
-    return jax.tree.map(lambda _: P(), shapes)
-
-
-def num_params(m: ModelConfig) -> int:
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
-    return sum(math.prod(s.shape) for s in jax.tree.leaves(shapes))
+param_pspecs, num_params, cache_pspecs = served_whole(
+    "minicpm_sala", init_params, LEAVES)
 
 
 # --------------------------------------------------------------------------- #
@@ -257,14 +312,6 @@ def serving_rope_tables(m: ModelConfig, seq_len: int, dtype) -> tuple:
     rotate; the sparse layers read neither."""
     return precompute_rope(seq_len, m.lightning_head_dim, m.rope_theta,
                            dtype)
-
-
-def cache_pspecs(m: ModelConfig, quantized: bool = False,
-                 dp: int = 1) -> dict:
-    """Every leaf is served whole on one chip (``Config.validate`` refuses
-    the rest by name)."""
-    assert not quantized and dp == 1
-    return {n: P() for n in ("k", "v", "kc", "state", "lengths")}
 
 
 def init_cache(m: ModelConfig, slots: int, max_seq_len: int, dtype=None,

@@ -65,12 +65,11 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
 
 from picotron_tpu.config import Config, ModelConfig
 from picotron_tpu.inference import kv_cache
 from picotron_tpu.models import (STATS, carry_state, leaf_row, live_rows,
-                                 llama, state_counts)
+                                 llama, served_whole, state_counts, support)
 from picotron_tpu.models import experts as expert_share
 from picotron_tpu.models.llama import param_bytes  # noqa: F401 - the seam
 from picotron_tpu.ops.rmsnorm import rms_norm
@@ -93,6 +92,42 @@ HIGHEST = lax.Precision.HIGHEST
 ROUTE_EPS = 1e-20
 KINDS = "ME*"
 TAG = {"M": "m", "E": "e", "*": "a"}  # a kind in a group's name
+LEAVES = ("k", "v", "ssm", "conv")  # the cache's, beside "lengths"
+WHY = support.RECURRENT_STATE  # what the block cannot do yet
+
+
+def validate(cfg: Config, for_training: bool) -> None:
+    """What the block cannot do yet and what it needs of its keys, each
+    refused by name (``Config.validate`` calls it)."""
+    m = cfg.model
+    support.refuse(cfg, for_training, WHY)
+    if m.num_nextn_predict_layers > 0:
+        raise ValueError(
+            f"{support.who(m)} does not support speculation "
+            f"(model.num_nextn_predict_layers {m.num_nextn_predict_layers}): "
+            f"{WHY['speculation']}, so the multi-token-prediction layer is "
+            "not held")
+    support.positive(
+        m, "mamba_num_heads", "mamba_head_dim", "ssm_state_size",
+        "conv_kernel", "n_groups", "chunk_size", "n_routed_experts",
+        "num_experts_per_tok", "moe_intermediate_size", "moe_latent_size",
+        "moe_shared_expert_intermediate_size", "ep_size")
+    support.layer_kinds(
+        m, "hybrid_override_pattern", tuple(KINDS), need=("M", "*"),
+        note="; '-', a dense MLP layer, is not implemented")
+    support.check(m, (
+        m.mamba_num_heads % m.n_groups,
+        f"mamba_num_heads {m.mamba_num_heads} must be a multiple of "
+        f"n_groups {m.n_groups}"))
+    support.ep_share(m, "n_routed_experts")
+    width = router_width(m)
+    support.check(m, (
+        width % m.n_group or not 1 <= m.topk_group <= m.n_group,
+        f"n_group {m.n_group} must divide the router's width {width} and "
+        f"hold topk_group {m.topk_group}"))
+    support.pinned(m, use_conv_bias=True, mamba_proj_bias=False,
+                   mlp_hidden_act="relu2", n_shared_experts=1,
+                   norm_topk_prob=True, tie_word_embeddings=False)
 
 
 # --------------------------------------------------------------------------- #
@@ -261,19 +296,8 @@ def init_params(key, m: ModelConfig, pp_size: int = 1,
     return params
 
 
-def param_pspecs(m: ModelConfig, fsdp: bool = False,
-                 weight_dtype: str = "bf16") -> dict:
-    """Every leaf replicated: the block is served at tp_size 1 (its share
-    of a layer is ``ep_size``/``ep_rank``, a cut and not a mesh axis)."""
-    if fsdp or weight_dtype != "bf16":
-        raise ValueError("nemotron_h serves dense weights, unsharded")
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
-    return jax.tree.map(lambda _: P(), shapes)
-
-
-def num_params(m: ModelConfig) -> int:
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
-    return sum(math.prod(s.shape) for s in jax.tree.leaves(shapes))
+param_pspecs, num_params, cache_pspecs = served_whole(
+    "nemotron_h", init_params, LEAVES)
 
 
 # --------------------------------------------------------------------------- #
@@ -281,10 +305,7 @@ def num_params(m: ModelConfig) -> int:
 # --------------------------------------------------------------------------- #
 
 
-def embed_lookup(w, tokens, cfg: Config):
-    return llama.embed_lookup(w, tokens)
-
-
+embed_lookup = llama.embed_lookup  # no multiplier
 head_logits = llama.head_logits  # final norm, then the untied head
 
 
@@ -293,14 +314,6 @@ def serving_rope_tables(m: ModelConfig, seq_len: int, dtype) -> tuple:
     length (the programs slice and gather them by position)."""
     t = jnp.zeros((seq_len, 2), dtype)
     return t, t
-
-
-def cache_pspecs(m: ModelConfig, quantized: bool = False,
-                 dp: int = 1) -> dict:
-    """State and K/V are served whole on one chip (``Config.validate``
-    refuses the rest by name)."""
-    assert not quantized and dp == 1
-    return {n: P() for n in ("k", "v", "ssm", "conv", "lengths")}
 
 
 def init_cache(m: ModelConfig, slots: int, max_seq_len: int, dtype=None,

@@ -64,12 +64,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
 
 from picotron_tpu.config import Config, ModelConfig
 from picotron_tpu.inference import kv_cache
 from picotron_tpu.models import (STATS, carry_state, leaf_row, live_rows,
-                                 llama, runs, state_counts)
+                                 llama, runs, served_whole, state_counts,
+                                 support)
 from picotron_tpu.models import experts as expert_share
 from picotron_tpu.models.afmoe import output_gate
 from picotron_tpu.models.granite_hybrid import (  # noqa: F401 - the seam
@@ -94,6 +94,49 @@ F32 = jnp.float32
 HIGHEST = lax.Precision.HIGHEST
 ROUTE_EPS = 1e-20
 L2_EPS = 1e-6  # under the root of q's and k's norms
+LEAVES = ("k", "v", "kda", "conv")  # the cache's, beside "lengths"
+# what the block cannot do yet, and why (``support.refuse``)
+WHY = {**support.RECURRENT_STATE,
+       "training": "no backward through the chunked delta rule and the "
+                   "expert share"}
+
+
+def validate(cfg: Config, for_training: bool) -> None:
+    """What the block cannot do yet and what it needs of its keys, each
+    refused by name (``Config.validate`` calls it)."""
+    m = cfg.model
+    who = support.who(m)
+    support.refuse(cfg, for_training, WHY)
+    support.positive(m, "n_routed_experts", "n_shared_experts",
+                     "num_experts_per_tok", "moe_intermediate_size",
+                     "ep_size")
+    la = m.linear_attn_config or {}
+    need = ("num_heads", "head_dim", "short_conv_kernel_size")
+    if any(int(la.get(n) or 0) < 1 for n in need) \
+            or la.get("num_kv_heads") not in (None, la.get("num_heads")):
+        raise ValueError(
+            f"{who} needs model.linear_attn_config with "
+            f"{', '.join(need)} each >= 1 and num_kv_heads null or "
+            f"num_heads (k and v a head each; got "
+            f"{m.linear_attn_config!r})")
+    gqa = m.gqa_layers or []
+    kda = [i for i in range(m.num_hidden_layers) if i not in gqa]
+    if not gqa or not kda or sorted(set(gqa)) != list(gqa) \
+            or not 0 <= gqa[0] <= gqa[-1] < m.num_hidden_layers:
+        raise ValueError(
+            f"{who} needs model.gqa_layers: rising indices among the "
+            f"{m.num_hidden_layers} layers held, with at least one GQA "
+            f"and one KDA layer (the cache holds a leaf of each kind; "
+            f"got {m.gqa_layers!r})")
+    support.check(m, (
+        m.gqa_interval and any(
+            b - a != m.gqa_interval + 1 for a, b in zip(gqa, gqa[1:])),
+        f"gqa_layers {gqa} do not lie gqa_interval {m.gqa_interval} KDA "
+        "layers apart"))
+    support.ep_share(m, "n_routed_experts")
+    support.pinned(m, use_rope=False, first_k_dense_replace=0,
+                   scoring_func="sigmoid", norm_topk_prob=True,
+                   tie_word_embeddings=False)
 
 
 # --------------------------------------------------------------------------- #
@@ -240,19 +283,8 @@ def init_params(key, m: ModelConfig, pp_size: int = 1,
     return params
 
 
-def param_pspecs(m: ModelConfig, fsdp: bool = False,
-                 weight_dtype: str = "bf16") -> dict:
-    """Every leaf replicated: the block is served at tp_size 1 (its share
-    of a layer is ``ep_size``/``ep_rank``, a cut and not a mesh axis)."""
-    if fsdp or weight_dtype != "bf16":
-        raise ValueError("solar_open2 serves dense weights, unsharded")
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
-    return jax.tree.map(lambda _: P(), shapes)
-
-
-def num_params(m: ModelConfig) -> int:
-    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), m))
-    return sum(math.prod(s.shape) for s in jax.tree.leaves(shapes))
+param_pspecs, num_params, cache_pspecs = served_whole(
+    "solar_open2", init_params, LEAVES)
 
 
 # --------------------------------------------------------------------------- #
@@ -260,19 +292,8 @@ def num_params(m: ModelConfig) -> int:
 # --------------------------------------------------------------------------- #
 
 
-def embed_lookup(w, tokens, cfg: Config):
-    return llama.embed_lookup(w, tokens)
-
-
+embed_lookup = llama.embed_lookup  # no multiplier
 head_logits = llama.head_logits  # final norm, then the untied head
-
-
-def cache_pspecs(m: ModelConfig, quantized: bool = False,
-                 dp: int = 1) -> dict:
-    """State and K/V are served whole on one chip (``Config.validate``
-    refuses the rest by name)."""
-    assert not quantized and dp == 1
-    return {n: P() for n in ("k", "v", "kda", "conv", "lengths")}
 
 
 def init_cache(m: ModelConfig, slots: int, max_seq_len: int, dtype=None,

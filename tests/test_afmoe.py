@@ -7,6 +7,7 @@ more than twice, what a sliding and a full layer each see, the expert share
 and the router, the ring form of the stacked decode kernel, and what
 ``Config.validate`` refuses."""
 
+from functools import partial
 import importlib.util
 import json
 import os
@@ -17,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import block_toys
 from engine_memo import memoized
 
 from picotron_tpu.config import Config, ModelConfig
@@ -28,15 +30,7 @@ NAME = "trinity-large-ep32-l9"
 CELL = NAME + ".serve-mixedctx-decode"
 S, F = afmoe.WINDOW, afmoe.FULL
 
-TOY = dict(
-    name="toy-afmoe", model_type="afmoe", num_hidden_layers=4,
-    layer_types=[S, S, F, S], num_dense_layers=1, hidden_size=64,
-    num_attention_heads=4, num_key_value_heads=2, head_dim=32,
-    intermediate_size=96, vocab_size=256, rms_norm_eps=1e-5,
-    rope_theta=10000.0, max_position_embeddings=256, dtype="float32",
-    sliding_window=16, num_experts=2, ep_size=4, ep_rank=0,
-    num_experts_per_tok=2, num_shared_experts=1, moe_intermediate_size=32,
-    route_scale=2.448, mup_enabled=True)
+TOY = block_toys.TOYS["afmoe"]
 
 
 def _load_reference():
@@ -51,12 +45,7 @@ def _load_reference():
 ref = _load_reference()
 
 
-def make_config(model=None, **sections) -> Config:
-    return Config.from_dict({
-        "distributed": {"use_cpu": True, **sections.pop("distributed", {})},
-        "model": dict(TOY, **(model or {})),
-        "training": {"seq_length": 128}, "dataset": {"name": "synthetic"},
-        **sections})
+make_config = partial(block_toys.make_config, "afmoe")
 
 
 @memoized
@@ -468,24 +457,6 @@ def test_seeded_draws_are_as_the_configuration_file_says(toy):
 
 
 # ---- (e) refused by name ----------------------------------------------------
-
-
-@pytest.mark.parametrize("sections,match", [
-    ({"distributed": {"tp_size": 2}}, "tp_size > 1"),
-    ({"inference": {"kv_layout": "paged"}}, "kv_layout 'paged'"),
-    ({"inference": {"kv_cache_dtype": "int8"}}, "kv_cache_dtype 'int8'"),
-    ({"inference": {"weight_dtype": "int8"}}, "weight_dtype 'int8'"),
-    ({"inference": {"spec_len": 2}}, "speculation"),
-    ({"inference": {"attend_impl": "flash"}}, "attend_impl"),
-    ({"inference": {"overlap": True, "key_schedule": "slot"}}, "overlap"),
-    ({"inference": {"mixed_dispatch": True, "kv_layout": "contiguous"}},
-     "mixed_dispatch"),
-    ({"inference": {"key_schedule": "slot"}}, "key_schedule 'slot'"),
-    ({"inference": {"dp_size": 2}}, "dp_size > 1"),
-])
-def test_validate_refuses_by_name(sections, match):
-    with pytest.raises(ValueError, match="afmoe.*" + match):
-        make_config(**sections)
 
 
 def test_training_is_refused_by_name():

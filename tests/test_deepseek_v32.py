@@ -4,6 +4,7 @@ size in float32 on the CPU with seeded weights, against the plain reference
 latent cache, the expert share, the router and the selection by hand, the
 YaRN tables, what ``Config.validate`` refuses, and the bfloat16 control."""
 
+from functools import partial
 import importlib.util
 import json
 import math
@@ -15,9 +16,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import block_toys
 from engine_memo import admit, memoized
 
-from picotron_tpu.config import Config
 from picotron_tpu.inference import InferenceEngine
 from picotron_tpu.models import deepseek_v32 as dsv
 from picotron_tpu.models import experts
@@ -26,19 +27,8 @@ from picotron_tpu.ops import rope
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CELL = "deepseek-v3.2-ep32-l7.serve-longctx-decode"
 
-YARN = {"type": "yarn", "factor": 40, "beta_fast": 32, "beta_slow": 1,
-        "mscale": 1, "mscale_all_dim": 1,
-        "original_max_position_embeddings": 64}
-TOY = dict(
-    name="toy-dsv32", model_type="deepseek_v32", num_hidden_layers=3,
-    first_k_dense_replace=1, hidden_size=128, num_attention_heads=8,
-    num_key_value_heads=8, intermediate_size=256, vocab_size=512,
-    rms_norm_eps=1e-6, rope_theta=10000.0, max_position_embeddings=512,
-    dtype="float32", q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
-    qk_rope_head_dim=8, v_head_dim=16, index_n_heads=4, index_head_dim=16,
-    index_topk=16, n_routed_experts=2, ep_size=4, ep_rank=0,
-    n_shared_experts=1, num_experts_per_tok=2, moe_intermediate_size=64,
-    n_group=4, topk_group=2, routed_scaling_factor=2.5, rope_scaling=YARN)
+YARN = block_toys.YARN
+TOY = block_toys.TOYS["deepseek_v32"]
 
 
 def _load_reference():
@@ -53,12 +43,7 @@ def _load_reference():
 ref = _load_reference()
 
 
-def make_config(model=None, **sections) -> Config:
-    return Config.from_dict({
-        "distributed": {"use_cpu": True, **sections.pop("distributed", {})},
-        "model": dict(TOY, **(model or {})),
-        "training": {"seq_length": 256}, "dataset": {"name": "synthetic"},
-        **sections})
+make_config = partial(block_toys.make_config, "deepseek_v32", seq_length=256)
 
 
 def ref_config(model: dict) -> dict:
@@ -483,21 +468,6 @@ def test_interleaved_rope_pairs_adjacent_elements():
 
 
 # ---- (f) what is refused, by name ------------------------------------------
-
-
-@pytest.mark.parametrize("sections,match", [
-    ({"inference": {"kv_layout": "paged"}}, "kv_layout 'paged'"),
-    ({"inference": {"kv_cache_dtype": "int8"}}, "kv_cache_dtype 'int8'"),
-    ({"inference": {"weight_dtype": "int8"}}, "weight_dtype 'int8'"),
-    ({"inference": {"tenancy": {"tenants": [{"name": "a"}]}}}, "LoRA"),
-    ({"inference": {"spec_len": 4}}, "speculation"),
-    ({"distributed": {"tp_size": 2}}, "tp_size > 1"),
-    ({"inference": {"attend_impl": "flash"}}, "attend_impl"),
-    ({"inference": {"mixed_dispatch": True}}, "mixed_dispatch"),
-])
-def test_validate_refuses_by_name(sections, match):
-    with pytest.raises(ValueError, match=match):
-        make_config(**json.loads(json.dumps(sections)))
 
 
 @pytest.mark.parametrize("impl", ["auto", "dense"])

@@ -6,6 +6,7 @@ of them (pad rows, a slot's second occupant, a slot that is not live, the
 state carried from chunk to chunk), the chunked scan against the recurrence,
 the expert share, and what ``Config.validate`` refuses."""
 
+from functools import partial
 import importlib.util
 import json
 import os
@@ -16,9 +17,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import block_toys
 from engine_memo import memoized
 
-from picotron_tpu.config import Config
 from picotron_tpu.inference import InferenceEngine
 from picotron_tpu.models import granite_hybrid as gh
 
@@ -27,18 +28,7 @@ CELL = "granite-4.0-h-small-ep2-l10.serve-chat-closed"
 PUBLISHED_TYPES = (["mamba"] * 5 + ["attention"] + ["mamba"] * 4
                    + (["mamba"] * 5 + ["attention"] + ["mamba"] * 4) * 3)
 
-TOY = dict(
-    name="toy-granite", model_type="granitemoehybrid", num_hidden_layers=5,
-    layer_types=["mamba", "mamba", "attention", "mamba", "mamba"],
-    hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
-    intermediate_size=32, vocab_size=256, rms_norm_eps=1e-5,
-    max_position_embeddings=256, dtype="float32", mamba_n_heads=8,
-    mamba_d_head=16, mamba_d_state=16, mamba_d_conv=4, mamba_chunk_size=8,
-    num_local_experts=3, ep_size=2, ep_rank=0, num_experts_per_tok=2,
-    shared_intermediate_size=48, embedding_multiplier=12.0,
-    residual_multiplier=0.22, attention_multiplier=0.0625,
-    logits_scaling=16.0, position_embedding_type="nope",
-    tie_word_embeddings=True)
+TOY = block_toys.TOYS["granitemoehybrid"]
 
 
 def _load_reference():
@@ -53,12 +43,7 @@ def _load_reference():
 ref = _load_reference()
 
 
-def make_config(model=None, **sections) -> Config:
-    return Config.from_dict({
-        "distributed": {"use_cpu": True, **sections.pop("distributed", {})},
-        "model": dict(TOY, **(model or {})),
-        "training": {"seq_length": 128}, "dataset": {"name": "synthetic"},
-        **sections})
+make_config = partial(block_toys.make_config, "granitemoehybrid")
 
 
 @memoized
@@ -407,24 +392,6 @@ def test_the_cache_has_three_kinds_of_leaf():
 
 
 # ---- (f) what is refused, by name ------------------------------------------
-
-
-@pytest.mark.parametrize("sections,match", [
-    ({"inference": {"kv_layout": "paged"}}, "kv_layout 'paged'"),
-    ({"inference": {"kv_cache_dtype": "int8"}}, "kv_cache_dtype 'int8'"),
-    ({"inference": {"weight_dtype": "int8"}}, "weight_dtype 'int8'"),
-    ({"inference": {"tenancy": {"tenants": [{"name": "a"}]}}}, "LoRA"),
-    ({"inference": {"spec_len": 4}}, "speculation"),
-    ({"distributed": {"tp_size": 2}}, "tp_size > 1"),
-    ({"inference": {"attend_impl": "flash"}}, "attend_impl"),
-    ({"inference": {"overlap": True}}, "overlap"),
-    ({"inference": {"mixed_dispatch": True}}, "mixed_dispatch"),
-    ({"inference": {"key_schedule": "slot"}}, "key_schedule"),
-    ({"inference": {"dp_size": 2}}, "dp_size > 1"),
-])
-def test_validate_refuses_by_name(sections, match):
-    with pytest.raises(ValueError, match=match):
-        make_config(**json.loads(json.dumps(sections)))
 
 
 @pytest.mark.parametrize("impl", ["auto", "dense"])

@@ -8,6 +8,7 @@ the masked walk, ``ops/select.py``'s row indices, M-RoPE over three streams,
 q/k norm, the softmax router, the expert share, what the programs count, and
 what ``Config.validate`` refuses."""
 
+from functools import partial
 import importlib.util
 import json
 import os
@@ -18,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import block_toys
 from engine_memo import admit, decode, memoized, program_logits, worst_rel_err
 
 from picotron_tpu.config import Config, ModelConfig
@@ -33,20 +35,7 @@ CELL = NAME + ".serve-longctx-decode"
 # what is new is kept: K/V heads fewer than query heads (2 of 8), indexer
 # keys of 64 (two to a cache row) on 4 heads, a topk (16) well under the
 # prompt (70), three M-RoPE sections, a softmax top-4 of 16 with 4 held
-TOY = dict(
-    name="toy-keye", model_type="KeyeVL2", num_hidden_layers=3,
-    hidden_size=128, num_attention_heads=8, num_key_value_heads=2,
-    head_dim=32, intermediate_size=256, vocab_size=512, rms_norm_eps=1e-6,
-    rope_theta=10000.0, max_position_embeddings=512, dtype="float32",
-    sa_config={"indexer_head_dim": 64, "indexer_num_heads": 4,
-               "indexer_num_kv_heads": 1, "kv_chunk_size": 8,
-               "q_chunk_size": 8, "topk": 16},
-    rope_scaling={"mrope_section": [4, 6, 6], "rope_type": "default",
-                  "type": "default"},
-    num_experts=4, num_local_experts=16, ep_size=4, ep_rank=0,
-    num_experts_per_tok=4, moe_intermediate_size=64, norm_topk_prob=True,
-    decoder_sparse_step=1, mlp_only_layers=[], first_layer=3,
-    total_layers=12)
+TOY = block_toys.TOYS["KeyeVL2"]
 
 
 def _load_reference():
@@ -61,12 +50,7 @@ def _load_reference():
 ref = _load_reference()
 
 
-def make_config(model=None, **sections) -> Config:
-    return Config.from_dict({
-        "distributed": {"use_cpu": True, **sections.pop("distributed", {})},
-        "model": dict(TOY, **(model or {})),
-        "training": {"seq_length": 256}, "dataset": {"name": "synthetic"},
-        **sections})
+make_config = partial(block_toys.make_config, "KeyeVL2", seq_length=256)
 
 
 @memoized
@@ -619,24 +603,6 @@ def test_seeded_draws_are_as_the_configuration_file_says(toy):
         assert (np.asarray(layers[name]) == 1).all(), name
     assert "router_bias" not in layers and "ws_gate" not in layers
     assert layers["q_norm"].shape == layers["k_norm"].shape == (3, 32)
-
-
-@pytest.mark.parametrize("sections,match", [
-    ({"distributed": {"tp_size": 2}}, "tp_size > 1"),
-    ({"inference": {"kv_layout": "paged"}}, "kv_layout 'paged'"),
-    ({"inference": {"kv_cache_dtype": "int8"}}, "kv_cache_dtype 'int8'"),
-    ({"inference": {"weight_dtype": "int8"}}, "weight_dtype 'int8'"),
-    ({"inference": {"spec_len": 2}}, "speculation"),
-    ({"inference": {"attend_impl": "flash"}}, "attend_impl"),
-    ({"inference": {"overlap": True, "key_schedule": "slot"}}, "overlap"),
-    ({"inference": {"mixed_dispatch": True, "kv_layout": "contiguous"}},
-     "mixed_dispatch"),
-    ({"inference": {"key_schedule": "slot"}}, "key_schedule 'slot'"),
-    ({"inference": {"dp_size": 2}}, "dp_size > 1"),
-])
-def test_validate_refuses_by_name(sections, match):
-    with pytest.raises(ValueError, match="KeyeVL2.*" + match):
-        make_config(**sections)
 
 
 def test_training_is_refused_by_name():

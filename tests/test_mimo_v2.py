@@ -8,6 +8,7 @@ partial rotation under two bases, what each kind of layer sees, the expert
 share with no shared expert, the new forms of the stacked decode kernel, and
 what ``Config.validate`` refuses."""
 
+from functools import partial
 import importlib.util
 import json
 import os
@@ -18,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import block_toys
 from engine_memo import (
     admit,
     decode,
@@ -38,18 +40,7 @@ CELL = NAME + ".serve-mixedctx-decode"
 # what is new is kept: K/V heads that differ by kind (2 full, 4 sliding),
 # keys wider than values (24, 16), 8 of a head's 24 dimensions rotated, two
 # bases, a window (6) smaller than the chunk (8)
-TOY = dict(
-    name="toy-mimo", model_type="mimo_v2", num_hidden_layers=5,
-    hybrid_layer_pattern=[0, 1, 1, 0, 1], moe_layer_freq=[0, 1, 1, 1, 1],
-    hidden_size=64, num_attention_heads=8, num_key_value_heads=2,
-    head_dim=24, v_head_dim=16, swa_num_attention_heads=8,
-    swa_num_key_value_heads=4, swa_head_dim=24, swa_v_head_dim=16,
-    partial_rotary_factor=0.334, rope_theta=1e7, swa_rope_theta=1e4,
-    sliding_window=6, attention_value_scale=0.707,
-    add_swa_attention_sink_bias=True, intermediate_size=96,
-    moe_intermediate_size=32, n_routed_experts=2, ep_size=4, ep_rank=0,
-    num_experts_per_tok=2, vocab_size=256, rms_norm_eps=1e-5,
-    max_position_embeddings=256, dtype="float32")
+TOY = block_toys.TOYS["mimo_v2"]
 
 
 def _load_reference():
@@ -64,12 +55,7 @@ def _load_reference():
 ref = _load_reference()
 
 
-def make_config(model=None, **sections) -> Config:
-    return Config.from_dict({
-        "distributed": {"use_cpu": True, **sections.pop("distributed", {})},
-        "model": dict(TOY, **(model or {})),
-        "training": {"seq_length": 128}, "dataset": {"name": "synthetic"},
-        **sections})
+make_config = partial(block_toys.make_config, "mimo_v2")
 
 
 @memoized
@@ -681,24 +667,6 @@ def test_seeded_draws_are_as_the_configuration_file_says(toy):
 
 
 # ---- (f) refused by name ----------------------------------------------------
-
-
-@pytest.mark.parametrize("sections,match", [
-    ({"distributed": {"tp_size": 2}}, "tp_size > 1"),
-    ({"inference": {"kv_layout": "paged"}}, "kv_layout 'paged'"),
-    ({"inference": {"kv_cache_dtype": "int8"}}, "kv_cache_dtype 'int8'"),
-    ({"inference": {"weight_dtype": "int8"}}, "weight_dtype 'int8'"),
-    ({"inference": {"spec_len": 2}}, "speculation"),
-    ({"inference": {"attend_impl": "flash"}}, "attend_impl"),
-    ({"inference": {"overlap": True, "key_schedule": "slot"}}, "overlap"),
-    ({"inference": {"mixed_dispatch": True, "kv_layout": "contiguous"}},
-     "mixed_dispatch"),
-    ({"inference": {"key_schedule": "slot"}}, "key_schedule 'slot'"),
-    ({"inference": {"dp_size": 2}}, "dp_size > 1"),
-])
-def test_validate_refuses_by_name(sections, match):
-    with pytest.raises(ValueError, match="mimo_v2.*" + match):
-        make_config(**sections)
 
 
 def test_training_is_refused_by_name():

@@ -9,6 +9,7 @@ what a state with no token axis asks of the programs, the moved
 ``select_keys`` and scan against what Granite and DeepSeek ran before, the
 counters, and what ``Config.validate`` refuses."""
 
+from functools import partial
 import importlib.util
 import json
 import os
@@ -19,10 +20,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import block_toys
 from engine_memo import memoized
 from jax import lax
 
-from picotron_tpu.config import Config
 from picotron_tpu.inference import InferenceEngine
 from picotron_tpu.models import minicpm_sala as sala
 from picotron_tpu.ops.select import select_keys
@@ -32,20 +33,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CELL = "minicpm-sala-l12.serve-longctx-decode"
 F32 = jnp.float32
 
-SPARSE = dict(kernel_size=8, kernel_stride=4, block_size=16, init_blocks=1,
-              window_size=32, topk=4, dense_len=64)
-TOY = dict(
-    name="toy-sala", model_type="minicpm_sala", num_hidden_layers=7,
-    mixer_types=["minicpm4", "lightning-attn", "lightning-attn",
-                 "lightning-attn", "minicpm4", "minicpm4", "lightning-attn"],
-    hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
-    intermediate_size=128, vocab_size=256, rms_norm_eps=1e-6,
-    rope_theta=10000.0, max_position_embeddings=512, dtype="float32",
-    lightning_nh=4, lightning_nkv=4, lightning_head_dim=16,
-    attn_use_rope=False, attn_use_output_gate=True,
-    qk_norm=True, use_output_norm=True, use_output_gate=True, scale_emb=12.0,
-    scale_depth=1.4, dim_model_base=16, mup_denominator=32,
-    sparse_config=SPARSE, first_layer=9, total_layers=32)
+SPARSE = block_toys.SPARSE
+TOY = block_toys.TOYS["minicpm_sala"]
 
 
 def _load(path, name):
@@ -59,12 +48,7 @@ def _load(path, name):
 ref = _load("benchmarks/reference/minicpm_sala.py", "reference_minicpm_sala")
 
 
-def make_config(model=None, **sections) -> Config:
-    return Config.from_dict({
-        "distributed": {"use_cpu": True, **sections.pop("distributed", {})},
-        "model": dict(TOY, **(model or {})),
-        "training": {"seq_length": 256}, "dataset": {"name": "synthetic"},
-        **sections})
+make_config = partial(block_toys.make_config, "minicpm_sala", seq_length=256)
 
 
 @memoized
@@ -618,25 +602,6 @@ def test_seeded_draws_are_as_the_configuration_file_says():
 
 
 # ---- (g) what is refused, by name -------------------------------------------
-
-
-@pytest.mark.parametrize("sections,match", [
-    ({"inference": {"kv_layout": "paged"}}, "kv_layout 'paged'"),
-    ({"inference": {"kv_cache_dtype": "int8"}}, "kv_cache_dtype 'int8'"),
-    ({"inference": {"weight_dtype": "int8"}}, "weight_dtype 'int8'"),
-    ({"inference": {"tenancy": {"tenants": [{"name": "a"}]}}}, "LoRA"),
-    ({"inference": {"spec_len": 4}}, "speculation"),
-    ({"distributed": {"tp_size": 2}}, "tp_size > 1"),
-    ({"inference": {"attend_impl": "flash"}}, "attend_impl"),
-    ({"inference": {"overlap": True}}, "overlap"),
-    ({"inference": {"mixed_dispatch": True}}, "mixed_dispatch"),
-    ({"inference": {"key_schedule": "slot"}}, "key_schedule"),
-    ({"inference": {"dp_size": 2}}, "dp_size > 1"),
-    ({"inference": {"prefill_chunk": 18}}, "kernel_stride"),
-])
-def test_validate_refuses_by_name(sections, match):
-    with pytest.raises(ValueError, match=match):
-        make_config(**json.loads(json.dumps(sections)))
 
 
 @pytest.mark.parametrize("impl", ["auto", "dense"])

@@ -8,6 +8,7 @@ through the expert share's two-matrix relu^2 form in its three orders
 what a state with no token axis asks of the programs, and what
 ``Config.validate`` refuses."""
 
+from functools import partial
 import importlib.util
 import json
 import os
@@ -16,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import block_toys
 from engine_memo import memoized
 
 from picotron_tpu.config import Config
@@ -29,17 +31,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PUBLISHED = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
              "EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
 
-TOY = dict(
-    name="toy-nemotron", model_type="nemotron_h", num_hidden_layers=7,
-    hybrid_override_pattern="MEMEM*E", hidden_size=64,
-    num_attention_heads=4, num_key_value_heads=2, head_dim=16,
-    intermediate_size=32, vocab_size=256, rms_norm_eps=1e-5,
-    layer_norm_epsilon=1e-5, max_position_embeddings=256, dtype="float32",
-    mamba_num_heads=8, mamba_head_dim=16, ssm_state_size=16, n_groups=4,
-    conv_kernel=4, chunk_size=8, n_routed_experts=3, ep_size=2, ep_rank=0,
-    num_experts_per_tok=2, moe_intermediate_size=32, moe_latent_size=32,
-    moe_shared_expert_intermediate_size=48, n_shared_experts=1,
-    routed_scaling_factor=2.5, mlp_hidden_act="relu2")
+TOY = block_toys.TOYS["nemotron_h"]
 
 
 def _load_reference():
@@ -54,12 +46,7 @@ def _load_reference():
 ref = _load_reference()
 
 
-def make_config(model=None, **sections) -> Config:
-    return Config.from_dict({
-        "distributed": {"use_cpu": True, **sections.pop("distributed", {})},
-        "model": dict(TOY, **(model or {})),
-        "training": {"seq_length": 128}, "dataset": {"name": "synthetic"},
-        **sections})
+make_config = partial(block_toys.make_config, "nemotron_h")
 
 
 @memoized
@@ -595,24 +582,6 @@ def test_the_batcher_puts_the_counters_on_metrics():
 
 
 # ---- (g) what is refused, by name ------------------------------------------
-
-
-@pytest.mark.parametrize("sections,match", [
-    ({"inference": {"kv_layout": "paged"}}, "kv_layout 'paged'"),
-    ({"inference": {"kv_cache_dtype": "int8"}}, "kv_cache_dtype 'int8'"),
-    ({"inference": {"weight_dtype": "int8"}}, "weight_dtype 'int8'"),
-    ({"inference": {"tenancy": {"tenants": [{"name": "a"}]}}}, "LoRA"),
-    ({"inference": {"spec_len": 4}}, "speculation"),
-    ({"distributed": {"tp_size": 2}}, "tp_size > 1"),
-    ({"inference": {"attend_impl": "flash"}}, "attend_impl"),
-    ({"inference": {"overlap": True}}, "overlap"),
-    ({"inference": {"mixed_dispatch": True}}, "mixed_dispatch"),
-    ({"inference": {"key_schedule": "slot"}}, "key_schedule"),
-    ({"inference": {"dp_size": 2}}, "dp_size > 1"),
-])
-def test_validate_refuses_by_name(sections, match):
-    with pytest.raises(ValueError, match=match):
-        make_config(**json.loads(json.dumps(sections)))
 
 
 @pytest.mark.parametrize("model,match", [

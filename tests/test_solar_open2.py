@@ -7,6 +7,7 @@ hard enough to overflow a careless one), gated NoPE GQA layers, the expert
 share at the block's own widths, what a state with no token axis asks of the
 programs, and what ``Config.validate`` refuses."""
 
+from functools import partial
 import importlib.util
 import json
 import os
@@ -15,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import block_toys
 from engine_memo import (admit, decode, memoized, program_logits,
                          worst_rel_err)
 
@@ -28,18 +30,7 @@ from picotron_tpu.ops.pallas import grouped_experts as grouped
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PUBLISHED_GQA = list(range(0, 48, 4))
 
-TOY = dict(
-    name="toy-solar", model_type="solar_open2", num_hidden_layers=8,
-    gqa_layers=[0, 4], gqa_interval=3, hidden_size=64,
-    num_attention_heads=4, num_key_value_heads=2, head_dim=16,
-    intermediate_size=160, vocab_size=256, rms_norm_eps=1e-5,
-    max_position_embeddings=256, dtype="float32",
-    linear_attn_config={"short_conv_kernel_size": 4, "head_dim": 16,
-                        "num_heads": 4, "num_kv_heads": None},
-    use_rope=False, use_gqa_gate=True, kda_use_full_proj=False,
-    kda_allow_neg_eigval=True, n_routed_experts=3, ep_size=2, ep_rank=0,
-    num_experts_per_tok=2, moe_intermediate_size=32, n_shared_experts=1,
-    routed_scaling_factor=1.0, norm_topk_prob=True, first_k_dense_replace=0)
+TOY = block_toys.TOYS["solar_open2"]
 N_KDA, N_GQA = 6, 2  # layers of TOY's pattern
 
 
@@ -55,12 +46,7 @@ def _load_reference():
 ref = _load_reference()
 
 
-def make_config(model=None, **sections) -> Config:
-    return Config.from_dict({
-        "distributed": {"use_cpu": True, **sections.pop("distributed", {})},
-        "model": dict(TOY, **(model or {})),
-        "training": {"seq_length": 128}, "dataset": {"name": "synthetic"},
-        **sections})
+make_config = partial(block_toys.make_config, "solar_open2")
 
 
 @memoized
@@ -515,24 +501,6 @@ def test_the_batcher_puts_the_counters_on_metrics():
 
 
 # ---- (g) what is refused, by name ------------------------------------------
-
-
-@pytest.mark.parametrize("sections,match", [
-    ({"inference": {"kv_layout": "paged"}}, "kv_layout 'paged'"),
-    ({"inference": {"kv_cache_dtype": "int8"}}, "kv_cache_dtype 'int8'"),
-    ({"inference": {"weight_dtype": "int8"}}, "weight_dtype 'int8'"),
-    ({"inference": {"tenancy": {"tenants": [{"name": "a"}]}}}, "LoRA"),
-    ({"inference": {"spec_len": 4}}, "speculation"),
-    ({"distributed": {"tp_size": 2}}, "tp_size > 1"),
-    ({"inference": {"attend_impl": "flash"}}, "attend_impl"),
-    ({"inference": {"overlap": True}}, "overlap"),
-    ({"inference": {"mixed_dispatch": True}}, "mixed_dispatch"),
-    ({"inference": {"key_schedule": "slot"}}, "key_schedule"),
-    ({"inference": {"dp_size": 2}}, "dp_size > 1"),
-])
-def test_validate_refuses_by_name(sections, match):
-    with pytest.raises(ValueError, match=match):
-        make_config(**json.loads(json.dumps(sections)))
 
 
 @pytest.mark.parametrize("model,match", [
