@@ -276,7 +276,9 @@ class ContinuousBatcher:
             self._hidden = jnp.zeros(
                 (engine.slots, engine.cfg.model.hidden_size),
                 jnp.dtype(engine.cfg.model.dtype))
-        self._cache = engine.init_cache()
+        # engine, weights and cache are in one hand here first: the store's
+        # narrow chunk program is built on the fresh cache (once a process)
+        self._cache = engine.build_narrow(params, engine.init_cache())
         self._slots: list = [None] * engine.slots
         self._pending: deque = deque()
         self._results: dict = {}
@@ -796,6 +798,13 @@ class ContinuousBatcher:
         # what the cache is (engine.__init__ reads both off its shapes):
         # resident bytes, and for K/V leaves the heads a lane row holds
         d["kv_cache_bytes"] = self.engine.kv_cache_bytes
+        # what the prefill programs ran, padding included, beside the
+        # prompt tokens they were asked for; chunk dispatches by width
+        d["prefill_tokens"] = int(self._prefill_tokens_total.value)
+        d["prefill_rows"] = int(self.engine.prefill_rows_total.value)
+        d["prefill_chunks"] = {
+            str(w): int(c.value)
+            for w, c in self.engine.prefill_chunks_total.items()}
         if self.engine.kv_pack is not None:
             d["kv_pack_factor"] = self.engine.kv_pack
         if self.draft_proposed:

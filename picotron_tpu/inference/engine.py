@@ -91,6 +91,13 @@ def _runs_flash(impl: str) -> bool:
 # float32 rows ride as their bits
 _PACK_ROWS = ("eos_id", "budget", "top_k", "temperature", "top_p")
 
+# Rows of the chunk program a short remainder runs, behind the prefix store
+# (``InferenceEngine.chunk_width``): the largest multiple of the MXU's 128-row
+# tile under the v5e's ridge (197 TFLOP/s over 819 GB/s: ~240 rows of bf16),
+# so such a chunk costs the read of the weights and no more, where a
+# ``prefill_chunk`` of 512 rows costs four times that for the same few tokens.
+NARROW_CHUNK = 128
+
 
 def _key_chain(key, block: int):
     """``block`` links of the batcher's key chain in one trace: each link
@@ -656,6 +663,21 @@ class InferenceEngine:
                      "store took"),
                     ("pages_evicted", "retained pages freed for newer "
                      "ones, least recently used first"))}
+        # the narrow chunk (``chunk_width``): with the store alone, where a
+        # resumed suffix is what an admission prefills; 0: every chunk wide
+        self.narrow_chunk = (NARROW_CHUNK if self.store is not None
+                             and NARROW_CHUNK < self.prefill_chunk else 0)
+        self._narrow_built = False  # ``build_narrow`` ran (once a process)
+        reg = self.obs.registry
+        self.prefill_rows_total = reg.counter(
+            "picotron_prefill_rows_total",
+            "rows the prefill programs ran (one-shot buckets, chunks, lane "
+            "chunks), padding included")
+        self.prefill_chunks_total = {
+            w: reg.counter("picotron_prefill_chunks_total",
+                           "chunk programs dispatched, by their width in "
+                           "rows", width=str(w))
+            for w in filter(None, (self.prefill_chunk, self.narrow_chunk))}
 
     def _store_pages(self, shapes: dict, page_len: int) -> int:
         """Pages of the prefix store this engine keeps beside its strips,
@@ -1887,6 +1909,7 @@ class InferenceEngine:
                 start[sh] = int(ln["start"])
                 toks[sh, : chunk.size] = chunk
                 valid[sh] = chunk.size
+                self.prefill_rows_total.inc(C)
                 if self.sample_on_device:
                     keyrows[sh] = np.asarray(ln["key"]).reshape(2)
                     temp[sh] = np.float32(ln.get("temperature", 1.0))
@@ -1942,6 +1965,7 @@ class InferenceEngine:
         padded = np.zeros((1, bucket), np.int32)
         padded[0, : ids.size] = ids
         self._hook("prefill")
+        self.prefill_rows_total.inc(bucket)
         # resolved inside the lambda like every hot-path program, so the
         # flash->dense fallback's rebuilt jit is what a re-dispatch runs
         return self._strip_stats(self._dispatch(lambda: self._prefill_jit(
@@ -1959,7 +1983,10 @@ class InferenceEngine:
         chain matches the host sampler's exactly) and no chunk ever ships
         logits. One compiled shape regardless of prompt length; the
         ragged final chunk pads to the chunk width with rows past the
-        final length unreachable.
+        final length unreachable. Behind the prefix store a chunk of at
+        most ``NARROW_CHUNK`` real rows pads to that width instead
+        (``chunk_width``): the same body at a second operand shape, which
+        computes the rows the wide one computes for those tokens.
 
         ``start`` > 0 resumes past an already-parked prefix (the paged
         prefix-sharing admission: rows [0, start) are cached pages the
@@ -1980,11 +2007,11 @@ class InferenceEngine:
             raise ValueError(
                 f"chunked-prefill start {start} outside prompt of "
                 f"{ids.size} tokens")
-        C = self.prefill_chunk
         logits = None
         hidden = None
-        for s0 in range(start, ids.size, C):
-            end = min(s0 + C, ids.size)
+        for s0 in range(start, ids.size, self.prefill_chunk):
+            end = min(s0 + self.prefill_chunk, ids.size)
+            C = self.chunk_width(end - s0)  # the width dispatched
             if self.paged is None:
                 # the write window is the chunk's full [w0, w0 + C) rows;
                 # past max_seq_len, dynamic_update_slice would CLAMP the
@@ -2010,12 +2037,10 @@ class InferenceEngine:
                 cache = self._ensure(cache, slot, w0, end)
                 cache = self._sync_tables(cache)
             self._hook("prefill_chunk")
-            out = self._strip_stats(self._dispatch(
-                lambda: self._prefill_chunk_jit(
-                    params, cache, jnp.asarray(padded),
-                    jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(w0, jnp.int32),
-                    jnp.asarray(chunk.size, jnp.int32), *samp)))
+            self.prefill_rows_total.inc(C)
+            self.prefill_chunks_total[C].inc()
+            out = self._chunk(params, cache, padded, slot, w0, chunk.size,
+                              samp)
             if self.return_hidden:
                 cache, logits, hidden = out
             else:
@@ -2026,6 +2051,55 @@ class InferenceEngine:
             # the FINAL chunk's last-token hidden state is the prompt's
             return cache, logits, hidden
         return cache, logits
+
+    def _chunk(self, params, cache, padded, slot: int, w0: int, valid: int,
+               samp: tuple) -> tuple:
+        """One dispatch of the chunk program, ``padded`` [1, width] wide."""
+        return self._strip_stats(self._dispatch(
+            lambda: self._prefill_chunk_jit(
+                params, cache, jnp.asarray(padded),
+                jnp.asarray(slot, jnp.int32), jnp.asarray(w0, jnp.int32),
+                jnp.asarray(valid, jnp.int32), *samp)))
+
+    def chunk_width(self, rows: int) -> int:
+        """Rows the chunk program is dispatched with for a chunk that
+        holds ``rows`` real ones: ``prefill_chunk``, or ``NARROW_CHUNK``
+        for at most that many in an engine that holds the prefix store
+        (``narrow_chunk``: a resumed suffix is a question of a few dozen
+        tokens, and ``build_narrow`` has the second shape compiled). Read
+        off the lengths; no option. Every other engine runs one width, as
+        its rings, scans and carried states are sized to."""
+        return (self.narrow_chunk if rows <= self.narrow_chunk
+                else self.prefill_chunk)
+
+    def prefill_widths(self, prompt_len: int, cached: int = 0) -> list:
+        """Rows, padding included, of each prefill program a prompt runs
+        past ``cached`` parked tokens: the one one-shot bucket of a prompt
+        at or under a chunk with nothing parked, else its chunks."""
+        if not cached and prompt_len <= self.prefill_chunk:
+            return [self.prefill_bucket(prompt_len)]
+        full, tail = divmod(prompt_len - cached, self.prefill_chunk)
+        return ([self.prefill_chunk] * full
+                + ([self.chunk_width(tail)] if tail else []))
+
+    def build_narrow(self, params, cache) -> dict:
+        """The narrow chunk program compiled and run before anything waits
+        on it (consumes ``cache``): one dispatch of one valid row into slot
+        0 of a fresh cache, the slot's length set back to 0 behind it, as
+        ``init_cache`` builds the store's two copy programs on pages nobody
+        reads. A warm-up of fresh prompts never resumes a short suffix, so
+        without this the first hit would compile inside somebody's
+        admission. Once a process, by the first batcher: a rebuilt cache
+        finds the program built. Nothing where no chunk runs narrow."""
+        if not self.narrow_chunk or self._narrow_built:
+            return cache
+        samp = (self._sample_args((jax.random.PRNGKey(0), 0.0, 0, 1.0))
+                if self.sample_on_device else ())
+        out = self._chunk(params, cache,
+                          np.zeros((1, self.narrow_chunk), np.int32), 0, 0, 1,
+                          samp)
+        self._narrow_built = True
+        return self.release(out[0], 0)
 
     def prefill_paged(self, params, cache, prompt_ids, slot: int,
                       sample=None, adapter_id=None,
@@ -2092,15 +2166,17 @@ class InferenceEngine:
         domain). A hit COPIES those pages into rows ``[0, cached)`` of the
         slot's strip (``_store_seat``: one program, the slot's length set
         with it) and the rest runs through the chunk program,
-        ``prefill_chunked(start=cached)``, whatever its length: no shape
-        the warm-up has not compiled. The hit is taken where it saves a
-        dispatch (``_prefill_dispatches``): a resumed suffix pays whole
-        ``prefill_chunk``-row chunks however short it is, so a prompt the
-        one-shot program takes in one smaller bucket, or one whose suffix
-        still needs as many chunks as the whole of it, is prefilled as
+        ``prefill_chunked(start=cached)``, whatever its length: whole
+        ``prefill_chunk``-row chunks and then, for a remainder of at most
+        ``NARROW_CHUNK`` rows, one chunk of that width (``chunk_width``),
+        both shapes compiled in set-up (``build_narrow``). The hit is taken
+        where it leaves fewer rows for the programs to run, padding
+        included (``prefill_widths``): a prompt the one-shot program takes
+        in a bucket no wider than the suffix's chunks is prefilled as
         before (``smollm-1.7b.serve-batch`` asks its eight prompts over and
-        over, most of them under a chunk: PERF.md section 6, PR 49). A miss
-        takes exactly the dispatches the store-less engine takes.
+        over, some of them in a 64- or 128-row bucket: PERF.md section 6,
+        PR 49 and PR 61). A miss takes exactly the dispatches the
+        store-less engine takes, but for a last chunk's width.
 
         Either way the prompt's own whole pages the trie does not hold yet
         are owed to the store (``_store_pending``) and copied strip -> pool,
@@ -2117,7 +2193,7 @@ class InferenceEngine:
         # is copied now, from rows this one is about to overwrite
         self._store_flush(cache, slot)
         cache, cached = self._store_seat(cache, ids, slot, cache_salt)
-        n = self._prefill_dispatches(len(ids), cached)
+        n = len(self.prefill_widths(len(ids), cached))
         if cached > 0 or len(ids) > self.prefill_chunk:
             out = self.prefill_chunked(params, cache, ids, slot,
                                        start=cached, sample=sample,
@@ -2140,21 +2216,16 @@ class InferenceEngine:
             self._store_pending.remove(entry)
             self._store_retain(cache, *entry)
 
-    def _prefill_dispatches(self, prompt_len: int, cached: int = 0) -> int:
-        """Prefill programs a prompt runs past ``cached`` parked tokens:
-        chunks of ``prefill_chunk`` rows, or the one one-shot bucket of a
-        prompt at or under a chunk with nothing parked."""
-        return max(-(-(prompt_len - cached) // self.prefill_chunk), 1)
-
     def _store_seat(self, cache, ids, slot: int, salt: str = "") -> tuple:
         """The hit: (cache, cached) with the longest retained prefix of
         ``ids``, ``cached`` tokens in whole pages, copied into ``slot``'s
         strip and its length set; the cache as it came and 0 on a miss,
-        and where the copy would save no prefill dispatch."""
-        whole = self._prefill_dispatches(len(ids))
+        and where the copy would leave the prefill programs no fewer rows
+        to run than the prompt prefilled whole."""
+        whole = sum(self.prefill_widths(len(ids)))
         row, cached = self.store.lookup(
-            ids, salt=salt, worth=lambda c: self._prefill_dispatches(
-                len(ids), c) < whole)
+            ids, salt=salt, worth=lambda c: sum(
+                self.prefill_widths(len(ids), c)) < whole)
         if cached:
             cache = self._seat_jit(cache, self._store_pool, slot, row,
                                    cached)

@@ -919,8 +919,9 @@ class PrefixStore(PageStore):
         at least one prompt token is left to prefill (its logits seed the
         first sampled token). ``worth(cached) -> bool`` is the caller's
         say on whether copying that prefix in beats prefilling it (the
-        engine's: fewer prefill dispatches); a prefix not worth it counts
-        as no hit. The matched path is touched (LRU) either way."""
+        engine's: fewer rows for the prefill programs to run); a prefix not
+        worth it counts as no hit. The matched path is touched (LRU) either
+        way."""
         self.prefix_queries += 1
         self.prompt_tokens += len(ids)
         pages, matched = self.radix.match(ids, salt=salt)

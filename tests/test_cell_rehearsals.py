@@ -41,7 +41,8 @@ ROUND = {"batcher.plan_ms", "batcher.deliver_ms", "engine.issue_operands_ms",
          "front.oversleep_s"}
 CHAT = {r + ".chat" for r in ROUND} | {
     "batcher.admit_ms.chat", "batcher.itl_p99_ms.chat",
-    "engine.prefill_tokens_per_s.chat", "engine.prompt_reuse_pct.chat"}
+    "engine.prefill_tokens_per_s.chat", "engine.prompt_reuse_pct.chat",
+    "engine.prefill_pad_rows_pct.chat"}
 READERS = {
     "smollm-1.7b.train-2k": {"train_step.step_ms"},
     "smollm-1.7b.serve-batch": ROUND | {"batcher.dispatch_gap_ms"},

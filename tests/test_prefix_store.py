@@ -14,9 +14,15 @@
   branches it took before;
 - **what the counters say**: ``picotron_prefill_tokens_total`` leaves the
   copied tokens out, the store's own counters count them, and after the
-  engine's first cache no admission compiles anything.
+  engine's first cache no admission compiles anything;
+- **the narrow chunk** (ISSUE 61; ``engine.NARROW_CHUNK``, ``chunk_width``,
+  ``prefill_widths``, ``build_narrow``): behind the store a chunk of at most
+  128 real rows is dispatched 128 wide and gives the store-less engine's
+  tokens, the hit is weighed in rows, the first batcher builds the second
+  shape, and an engine without the store runs one width.
 """
 
+import contextlib
 import json
 import os
 
@@ -28,6 +34,7 @@ import jax
 from conftest import make_config
 from picotron_tpu.config import Config
 from picotron_tpu.inference import ContinuousBatcher, InferenceEngine, Request
+from picotron_tpu.inference.engine import NARROW_CHUNK
 from picotron_tpu.inference.paged_kv import NULL_PAGE, PrefixStore
 from picotron_tpu.models import llama
 
@@ -73,6 +80,24 @@ CASES = {
     # to prefill, for the first token's logits
     "equal": (_doc(48), _doc(48), 40),
 }
+
+
+@contextlib.contextmanager
+def _compiles():
+    """The backend compiles made inside the block, one entry each."""
+    import jax.monitoring
+
+    seen = []
+
+    def on(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        yield seen
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
 
 
 def _generate(engine, params, requests, seed=0):
@@ -312,16 +337,7 @@ def test_no_admission_compiles_after_the_first_cache():
     the cache's own shardings whoever made the arrays: hits and retentions
     behind every producer of a cache (a one-shot insert, a chunk, a decode
     round, a release) compile nothing, as the benchmark's window demands."""
-    import jax.monitoring
-
-    compiles = []
-
-    def on(event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            compiles.append(event)
-
-    jax.monitoring.register_event_duration_secs_listener(on)
-    try:
+    with _compiles() as compiles:
         cfg, eng = _engine()
         params = _params(cfg, eng)
         doc = _doc(40)
@@ -339,8 +355,6 @@ def test_no_admission_compiles_after_the_first_cache():
         run(b, prompts)
         assert b.stats()["prefix_hits"] == 2
         assert len(compiles) == warm
-    finally:
-        jax.monitoring.unregister_event_duration_listener(on)
 
 
 def _cell_model(name):
@@ -419,3 +433,247 @@ def test_auto_size_holds_twice_the_strips_and_shrinks_to_the_device(
     assert fits(roomy) == 1 + 2 * 2 * pages
     assert fits((taken + 5 * page_bytes) * 8 // 7 + 8) == 1 + 5
     assert fits(taken) == 0  # strips that fill the chip: as without a store
+
+
+# ---- the narrow chunk (ISSUE 61) -------------------------------------------
+# a chunk wider than NARROW_CHUNK, which the tests above never have
+WIDE, LONG = 2 * NARROW_CHUNK, 4 * NARROW_CHUNK
+
+
+def _wide_engine(store_pages=0, cfg=None, **kw):
+    return _engine(store_pages, cfg, **{"max_seq_len": LONG,
+                                        "prefill_chunk": WIDE, **kw})
+
+
+def _widths(engine):
+    """The chunk program's dispatches from here on, by the width of their
+    operand: what ran, not what the counters say ran."""
+    seen, real = [], engine._prefill_chunk_jit
+
+    def spy(params, cache, tokens, *rest):
+        seen.append(tokens.shape[1])
+        return real(params, cache, tokens, *rest)
+
+    engine._prefill_chunk_jit = spy
+    return seen
+
+
+def _counted(engine):
+    reg = engine.obs.registry
+    return (reg.counter("picotron_prefill_rows_total").value,
+            {w: c.value for w, c in engine.prefill_chunks_total.items()})
+
+
+@pytest.mark.parametrize("suffix", [1, 17, 59, NARROW_CHUNK,
+                                    NARROW_CHUNK + 1])
+def test_a_resumed_suffix_runs_a_chunk_of_its_own_width(suffix):
+    """A suffix of at most NARROW_CHUNK rows behind a hit is dispatched that
+    wide, a longer one as wide as ever, and both give the store-less
+    engine's tokens."""
+    doc = _doc(136)  # 17 pages: whole, it would take the 256-row bucket
+    reqs = [Request(uid="first", prompt=doc + [250], max_new_tokens=6),
+            Request(uid="second", prompt=doc + [251] * suffix,
+                    max_new_tokens=6)]
+    cfg, off = _wide_engine(store_pages=-1)
+    _, on = _wide_engine(cfg=cfg)
+    assert off.narrow_chunk == 0 and on.narrow_chunk == NARROW_CHUNK
+    params = _params(cfg, on)
+    want, _ = _generate(off, params, reqs)
+    b = ContinuousBatcher(on, params, seed=0)
+    b.run(reqs[:1])  # the first ask: one-shot, a 256-row bucket
+    seen, (rows0, chunks0) = _widths(on), _counted(on)
+    got = {"first": want["first"],
+           "second": b.run(reqs[1:])["second"].tokens}
+    assert got == want
+    assert b._last_prefill == {"dispatches": 1, "cached_tokens": 136}
+    width = NARROW_CHUNK if suffix <= NARROW_CHUNK else WIDE
+    assert seen == [width]
+    rows, chunks = _counted(on)
+    assert rows - rows0 == width
+    assert chunks[width] - chunks0[width] == 1
+    s = b.stats()
+    assert s["prefill_rows"] == rows
+    assert s["prefill_chunks"] == {str(w): int(n) for w, n in chunks.items()}
+    assert s["prefill_tokens"] == 137 + suffix
+
+
+@pytest.mark.parametrize("tail", [1, 44, NARROW_CHUNK, NARROW_CHUNK + 1])
+def test_a_first_asks_short_last_chunk_runs_narrow_and_changes_nothing(tail):
+    """Nothing retained: a prompt past a chunk ends in a chunk of ``tail``
+    rows, narrow if they fit, and the tokens are the all-wide engine's."""
+    reqs = [Request(uid="r", prompt=_doc(WIDE + tail), max_new_tokens=6)]
+    cfg, off = _wide_engine(store_pages=-1)
+    _, on = _wide_engine(cfg=cfg)
+    params = _params(cfg, on)
+    wide, narrow = _widths(off), _widths(on)
+    want, _ = _generate(off, params, reqs)
+    got, b = _generate(on, params, reqs)
+    assert got == want
+    assert wide == [WIDE, WIDE]
+    # the batcher's build of the narrow program first, then the prompt's
+    assert narrow == [NARROW_CHUNK, WIDE,
+                      NARROW_CHUNK if tail <= NARROW_CHUNK else WIDE]
+    assert b.prefill_dispatches == 2 and b.stats()["prefix_hits"] == 0
+
+
+@pytest.mark.parametrize("n, cached, widths, whole", [
+    # fully retained, and still its one-shot bucket: 64 rows beat 128
+    (64, 0, [64], [64]),
+    # a 128-row bucket against a 128-row chunk: no fewer, not taken
+    (100, 0, [128], [128]),
+    # a 256-row bucket against a 128-row chunk: taken
+    (137, 136, [NARROW_CHUNK], [256]),
+    # 300 tokens: a chunk and a narrow one whole, one narrow one behind a hit
+    (300, 296, [NARROW_CHUNK], [WIDE, NARROW_CHUNK]),
+], ids=["bucket_64", "bucket_128", "bucket_256", "chunked_300"])
+def test_a_hit_is_worth_the_rows_it_saves(n, cached, widths, whole):
+    """The hit is taken where it leaves the programs fewer rows to run,
+    padding included, than the prompt prefilled whole."""
+    prompt = _doc(n)
+    reqs = [Request(uid=u, prompt=prompt, max_new_tokens=4)
+            for u in ("first", "again")]
+    cfg, off = _wide_engine(store_pages=-1)
+    _, on = _wide_engine(cfg=cfg)
+    params = _params(cfg, on)
+    assert on.prefill_widths(n) == whole
+    want, _ = _generate(off, params, reqs)
+    b = ContinuousBatcher(on, params, seed=0)
+    got = {"first": b.run(reqs[:1])["first"].tokens}
+    rows0, _ = _counted(on)
+    got["again"] = b.run(reqs[1:])["again"].tokens
+    assert got == want
+    assert b._last_prefill == {"dispatches": len(widths),
+                               "cached_tokens": cached}
+    assert on.prefill_widths(n, cached) == widths
+    assert _counted(on)[0] - rows0 == sum(widths)
+    assert b.stats()["prefix_hits"] == (1 if cached else 0)
+
+
+def test_a_narrow_chunk_near_the_strips_end_stays_inside_it():
+    """A resumed suffix ending within NARROW_CHUNK rows of ``max_seq_len``:
+    the window slides back by the width dispatched, feeds the rows before
+    the suffix again, and every row of the strip is the store-less
+    engine's."""
+    doc = _doc(LONG - 32)
+    first, second = doc + [250], doc + [251] * 20
+    cfg, off = _wide_engine(store_pages=-1)
+    _, on = _wide_engine(cfg=cfg)
+    params = _params(cfg, on)
+    cache = on.init_cache()
+    cache = on.prefill_stored(params, cache, first, 0)[0]
+    on._store_flush(cache)
+    seen = _widths(on)
+    starts, real = [], on._chunk
+
+    def spy(params, cache, padded, slot, w0, valid, samp):
+        starts.append((w0, valid))
+        return real(params, cache, padded, slot, w0, valid, samp)
+
+    on._chunk = spy
+    cache, logits, n, cached = on.prefill_stored(params, cache, second, 1)
+    assert (n, cached, seen) == (1, LONG - 32, [NARROW_CHUNK])
+    # rows [LONG - 128, LONG - 12): 96 of them fed again, 20 new
+    assert starts == [(LONG - NARROW_CHUNK, NARROW_CHUNK - 12)]
+    ref, want = off.prefill_chunked(params, off.init_cache(), second, 1)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    assert int(np.asarray(cache["lengths"])[1]) == len(second)
+    for leaf in ("k", "v"):
+        np.testing.assert_allclose(
+            np.asarray(cache[leaf])[:, 1, :len(second)],
+            np.asarray(ref[leaf])[:, 1, :len(second)],
+            rtol=1e-5, atol=1e-5, err_msg=leaf)
+
+
+def test_the_first_batcher_builds_the_narrow_program_and_no_second_does():
+    """``build_narrow`` runs with the first batcher's cache: a resumed
+    admission, the first short suffix anyone sends, compiles nothing, the
+    warm-up dispatch leaves no trace in the counters or the slot, and a
+    second batcher (a rebuilt cache) dispatches nothing."""
+    with _compiles() as compiles:
+        cfg, eng = _wide_engine()
+        params = _params(cfg, eng)
+        seen = _widths(eng)
+        b = ContinuousBatcher(eng, params, seed=0)
+        assert seen == [NARROW_CHUNK] and eng._narrow_built
+        assert _counted(eng) == (0, {WIDE: 0, NARROW_CHUNK: 0})
+        assert not np.asarray(b._cache["lengths"]).any()
+        run = lambda ps: b.run([
+            Request(uid=f"r{i}", prompt=p, max_new_tokens=5)
+            for i, p in enumerate(ps)])
+        # the benchmark's warm-up: fresh prompts, every one-shot bucket and
+        # whole chunks, so no chunk of it is short
+        run([_doc(n, 5 + i) for i, n in enumerate((9, 20, 40, 70, 130))]
+            + [_doc(2 * WIDE - 8, 4)])
+        assert set(seen) == {NARROW_CHUNK, WIDE}
+        assert b.stats()["prefix_hits"] == 0
+        warm, doc = len(compiles), _doc(200, 20)
+        for prompt in (doc + [250], doc + [251] * 30, _doc(WIDE + 40, 30)):
+            run([prompt])
+        assert b.stats()["prefix_hits"] == 1
+        assert _counted(eng)[1][NARROW_CHUNK] == 2
+        assert len(compiles) == warm
+        del seen[:]
+        ContinuousBatcher(eng, params, seed=1)
+        assert seen == [] and len(compiles) == warm
+
+
+def _other_block_engine():
+    """One other block's toy configuration (the Granite cell's rehearsal),
+    its window two chunks of 256 rows."""
+    cfg = Config.from_dict({
+        "distributed": {"use_cpu": True},
+        "model": _cell_model("granite-4.0-h-small-ep2-l10.json"),
+        "training": {"seq_length": 64}, "dataset": {"name": "synthetic"}})
+    eng = InferenceEngine(cfg, slots=2, max_seq_len=LONG,
+                          prefill_chunk=WIDE)
+    return eng, eng.shard_params(jax.jit(
+        lambda k: eng.model.init_params(k, cfg.model))(jax.random.PRNGKey(0)))
+
+
+def _llama_engine(**mode):
+    cfg, eng = _wide_engine(**mode)
+    return eng, _params(cfg, eng)
+
+
+@pytest.mark.parametrize("build", [
+    lambda: _llama_engine(store_pages=-1),
+    lambda: _llama_engine(kv_layout="paged"),
+    _other_block_engine], ids=["store_off", "paged", "granite"])
+def test_an_engine_without_the_store_never_runs_a_narrow_chunk(build):
+    eng, params = build()
+    assert eng.store is None and eng.narrow_chunk == 0
+    assert list(eng.prefill_chunks_total) == [WIDE]
+    assert eng.chunk_width(1) == WIDE
+    assert eng.prefill_widths(WIDE + 30) == [WIDE, WIDE]
+    seen = _widths(eng)
+    b = ContinuousBatcher(eng, params, seed=0)
+    assert seen == []  # nothing to build
+    vocab = eng.cfg.model.vocab_size
+    prompt = [1 + (7 * i) % (vocab - 1) for i in range(WIDE + 30)]
+    res = b.run([Request(uid="r", prompt=prompt, max_new_tokens=3)])
+    assert len(res["r"].tokens) == 3
+    assert seen == [WIDE, WIDE]
+    assert b.stats()["prefill_chunks"] == {str(WIDE): 2}
+    assert b.stats()["prefill_rows"] == 2 * WIDE
+
+
+@pytest.mark.parametrize("before, after, want", [
+    # 600 of 2,048 rows held a prompt token
+    ("picotron_prefill_tokens_total 1000\npicotron_prefill_rows_total 2048",
+     "picotron_prefill_tokens_total 1600\npicotron_prefill_rows_total 4096",
+     100.0 * (1 - 600 / 2048)),
+    # no prefill in the window
+    ("picotron_prefill_tokens_total 7\npicotron_prefill_rows_total 16",
+     "picotron_prefill_tokens_total 7\npicotron_prefill_rows_total 16", None),
+    # a program without the counter: the parent under this benchmark
+    ("picotron_prefill_tokens_total 1000",
+     "picotron_prefill_tokens_total 1600", None),
+], ids=["padded", "idle", "parent"])
+def test_the_pad_rows_reader_on_two_scrapes(before, after, want):
+    from benchmarks.run import load_reader
+
+    read = load_reader("layer_metrics", "engine.prefill_pad_rows_pct.chat")
+    got = read({"metrics_before": before, "metrics_after": after})
+    assert got == (want if want is None else pytest.approx(want))
+    assert read({}) is None
