@@ -118,6 +118,11 @@ class DistributedConfig:
     stage_gating: str = "auto"
 
 
+# the values of ``ModelConfig.remasking`` (a block that generates by diffusion
+# over blocks: ``inference/sampling.py::confidence_unmask``)
+REMASKING = ("low_confidence_static", "low_confidence_dynamic")
+
+
 @dataclass
 class ModelConfig:
     name: str = "HuggingFaceTB/SmolLM-1.7B"
@@ -377,6 +382,23 @@ class ModelConfig:
     use_rope: bool = True
     kda_use_full_proj: bool = False
     kda_allow_neg_eigval: bool = False
+    # "sdar_moe" (SDAR-30B-A3B-Chat): the published keys of that block are
+    # "KeyeVL2"'s less ``sa_config`` and ``rope_scaling`` (``num_experts``
+    # HELD of a router ``num_experts * ep_size`` wide, ``num_experts_per_tok``,
+    # ``moe_intermediate_size``, ``norm_topk_prob``, ``decoder_sparse_step``,
+    # ``mlp_only_layers``, ``ep_size``/``ep_rank``, ``first_layer``/
+    # ``total_layers``). It generates by diffusion over blocks, and the
+    # schedule is the configuration's: blocks of ``block_length`` positions
+    # (bidirectional inside, causal between), up to ``denoising_steps``
+    # forwards a block, ``remasking`` (one of ``REMASKING``) at
+    # ``confidence_threshold``
+    # (``inference/sampling.py::confidence_unmask``), ``mask_token_id`` the
+    # id a position not yet decided is fed as.
+    block_length: int = 0
+    denoising_steps: int = 0
+    remasking: str = "low_confidence_dynamic"
+    confidence_threshold: float = 0.9
+    mask_token_id: int = 0
     # the published ``head_dim`` where it is not hidden_size / heads (afmoe:
     # 128 of 3072 / 48); 0: derived, and it follows ``hidden_size``
     head_dim: int = 0

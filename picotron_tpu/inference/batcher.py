@@ -284,6 +284,15 @@ class ContinuousBatcher:
         self._results: dict = {}
         n = engine.slots
         self._last_tok = np.zeros(n, np.int32)
+        # an engine that generates by blocks (``engine.blocks``) is fed no
+        # last token: a slot's next block starts from ``_given[i,
+        # :_given_n[i]]``, the prompt's remainder behind its whole prefilled
+        # blocks in the slot's first round and nothing after it
+        self._blocks = bool(getattr(engine, "blocks", False))
+        self._given = np.zeros(
+            (n, engine.cfg.model.block_length if self._blocks else 1),
+            np.int32)
+        self._given_n = np.zeros(n, np.int32)
         self._temp = np.zeros(n, np.float32)
         self._top_k = np.zeros(n, np.int32)
         self._top_p = np.ones(n, np.float32)
@@ -406,12 +415,14 @@ class ContinuousBatcher:
         self._phases = RoundPhases(self.obs)
         self.obs.stalls.register("step/plan", "step/admit", "step/issue",
                                  "step/sync", "step/deliver")
-        # what the block's layers count (``STAT_NAMES``; none of the Llama
-        # block), added where a round is delivered
+        # what the block's layers and the engine's rounds count
+        # (``engine.stat_names``; nothing with the Llama block), added where
+        # a round is delivered
         self._model_counters = [
             reg.counter(f"picotron_{name}_total",
-                        "counted inside the programs by the model's layers")
-            for name in engine.model.STAT_NAMES]
+                        "counted inside the programs by the model's layers",
+                        **labels)
+            for name, labels in engine.stat_names]
         self._prefill_tokens_total = reg.counter(
             "picotron_prefill_tokens_total",
             "prompt tokens run through a prefill program (solo, chunked "
@@ -938,12 +949,19 @@ class ContinuousBatcher:
                 self._lane_drop(sh, reason)
         self._cache = self.engine.release(self._cache, i)
         self._last_tok[i] = 0
+        self._given_n[i] = 0
         self._temp[i] = 0.0
         self._top_k[i] = 0
         self._top_p[i] = 1.0
         self._eos[i] = -1
         self._budget[i] = 0
         self._adapter[i] = 0
+
+    def _remainder(self, prompt) -> int:
+        """Tokens of ``prompt`` behind its whole blocks, which lead the
+        slot's first block (0 for an engine that generates token by
+        token)."""
+        return len(prompt) % self._given.shape[1] if self._blocks else 0
 
     def _remaining(self, i: int) -> int:
         """Tokens slot i may still produce: its max_new_tokens budget capped
@@ -1014,10 +1032,20 @@ class ContinuousBatcher:
         the longest radix-cached prefix is shared (no dispatches) and only
         the suffix prefills; a contiguous engine with a prefix store
         (``engine.store``) copies the retained prefix into the slot and
-        prefills the suffix the same way."""
+        prefills the suffix the same way. An engine that generates by
+        blocks prefills the prompt's whole blocks alone (none: no dispatch),
+        and what comes back is read by nobody: its logits score the token
+        AT a position, and the remainder is the first block's."""
         sample = None
         rh = self.engine.return_hidden
         hidden = None
+        # a blocks engine: whole blocks only, the remainder is the first
+        # block's; a prompt shorter than a block prefills nothing
+        prompt = req.prompt[: len(req.prompt) - self._remainder(req.prompt)]
+        if not prompt:
+            with self._scratch_mu:
+                self._last_prefill = {"dispatches": 0}
+            return None
         if self.engine.sample_on_device:
             sample = (key, req.temperature, req.top_k, req.top_p)
         # the tenant's adapter rides the prefill dispatch as a single-row
@@ -1047,12 +1075,12 @@ class ContinuousBatcher:
             with self._scratch_mu:
                 self._last_prefill = {"dispatches": n,
                                       "cached_tokens": cached}
-        elif len(req.prompt) > self.engine.prefill_chunk:
+        elif len(prompt) > self.engine.prefill_chunk:
             # long prompt: fixed-width chunks straight into the slot —
             # O(1) compiled shapes in prompt length
-            n_chunks = -(-len(req.prompt) // self.engine.prefill_chunk)
+            n_chunks = -(-len(prompt) // self.engine.prefill_chunk)
             out = self.engine.prefill_chunked(
-                self.params, self._cache, req.prompt, i, sample=sample,
+                self.params, self._cache, prompt, i, sample=sample,
                 adapter_id=adapter)
             self._cache, logits = out[:2]
             hidden = out[2] if rh else None
@@ -1060,12 +1088,12 @@ class ContinuousBatcher:
             with self._scratch_mu:
                 self._last_prefill = {"dispatches": n_chunks}
         else:
-            out = self.engine.prefill(self.params, req.prompt,
+            out = self.engine.prefill(self.params, prompt,
                                       sample=sample, adapter_id=adapter)
             kv, logits = out[:2]
             hidden = out[2] if rh else None
             self._cache = self.engine.insert(
-                self._cache, kv, i, len(req.prompt))
+                self._cache, kv, i, len(prompt))
             self.prefill_dispatches += 1
             with self._scratch_mu:
                 self._last_prefill = {"dispatches": 1}
@@ -1409,6 +1437,13 @@ class ContinuousBatcher:
             self._top_k[i] = req.top_k
             self._top_p[i] = req.top_p
             self._eos[i] = req.eos_id if req.eos_id is not None else -1
+            if self._blocks:
+                # no token comes of the prefill: the prompt's remainder
+                # leads the slot's first block, whose round emits the first
+                rest = self._remainder(req.prompt)
+                self._given[i, :rest] = req.prompt[len(req.prompt) - rest:]
+                self._given_n[i] = rest
+                continue
             if isinstance(logits, tuple) and logits[:1] == ("handoff",):
                 # seated from an imported handoff: the prefill worker
                 # already sampled the first token — nothing to draw here
@@ -1893,10 +1928,12 @@ class ContinuousBatcher:
                 t0 = self._clock()
                 self._note_issue(t0)
                 res = self.engine.decode_block(
-                    self.params, self._cache, self._last_tok, keys,
+                    self.params, self._cache,
+                    self._given if self._blocks else self._last_tok, keys,
                     self._eos, b, self._temp, self._top_k, self._top_p,
                     adapter_ids=(self._adapter if self.engine.adapters
-                                 is not None else None), lanes=lanes)
+                                 is not None else None), lanes=lanes,
+                    given=self._given_n if self._blocks else None)
                 # the fused lane's outputs wait for _lane_land, after the
                 # round delivers. An isolation re-dispatch re-runs the
                 # lane chunk too: same rows, same bytes, so restashing is
@@ -1928,6 +1965,7 @@ class ContinuousBatcher:
         for i, s in enumerate(self._slots):
             if s is not None and budget[i] > 0 and i not in failed:
                 s.dispatches += 1
+                self._given_n[i] = 0  # a blocks round took the remainder
                 if self.controller is not None:
                     # policy tick AFTER this round's counters landed in
                     # the registry; idle slots advance their cooloff
@@ -1943,8 +1981,8 @@ class ContinuousBatcher:
     def _count_model_stats(self) -> None:
         """Add what the model's layers counted since the last round (this
         round's decode block, and the prefills admitted before it) to the
-        registry: ``picotron_<name>_total`` for each of the block's
-        ``STAT_NAMES`` (docs/OBSERVABILITY.md). A block that does not
+        registry: ``picotron_<name>_total`` for each of the engine's
+        ``stat_names`` (docs/OBSERVABILITY.md). A block that does not
         count costs one attribute read."""
         stats = self.engine.take_stats()
         if stats is None:

@@ -216,6 +216,14 @@ class InferenceEngine:
         # of counters a layer (``take_stats``)
         self.model = models.model_module(m)
         self._n_stats = len(self.model.STAT_NAMES)
+        # how the block generates (its ``GENERATES``): a token a step, or
+        # whole blocks of ``model.block_length`` positions a round
+        # (``_blocks_impl``; docs/INFERENCE.md "Rounds of blocks")
+        self.blocks = getattr(self.model, "GENERATES", "tokens") == "blocks"
+        # what ``take_stats`` hands over, (name, labels) each: the layers'
+        # counters and, behind them, what a round of blocks counts itself
+        self.stat_names = tuple((n, {}) for n in self.model.STAT_NAMES) \
+            + (sampling.DIFFUSION_STATS if self.blocks else ())
         self._stats_pending: list = []
         self.dp_size = int(inf.dp_size or 1)
         if self.dp_size < 1:
@@ -473,6 +481,13 @@ class InferenceEngine:
             # or by a keyword here
             inf.spec_len = self.spec_len
             inf.prefill_chunk = self.prefill_chunk
+            inf.decode_block_len = self.decode_block_len
+            if self.blocks and self.max_seq_len % max(m.block_length, 1):
+                raise ValueError(
+                    f"model_type {m.model_type!r} generates by blocks: "
+                    f"max_seq_len ({self.max_seq_len}) must be a multiple "
+                    f"of block_length ({m.block_length}), or a prompt's "
+                    "last chunk slides back off a block boundary")
             self.cfg.validate()
             if adapters is not None or self.quantized:
                 raise ValueError(
@@ -792,10 +807,10 @@ class InferenceEngine:
 
     def _round_fields(self, kind: str) -> tuple:
         """The names of what a round program (``"decode_block"`` |
-        ``"verify"``) returns, in order: THE one place that knows which
-        outputs this engine's options add. The bodies return them so, the
-        builder derives ``out_specs`` from them, and the host reads them
-        back into a ``RoundResult`` by name."""
+        ``"verify"`` | ``"blocks"``) returns, in order: THE one place that
+        knows which outputs this engine's options add. The bodies return
+        them so, the builder derives ``out_specs`` from them, and the host
+        reads them back into a ``RoundResult`` by name."""
         rh = self.return_hidden
         # "packed": tokens, counts (and a verify's accepted), what the host
         # reads every round, as ONE int32 array (``_round_outputs``)
@@ -806,13 +821,14 @@ class InferenceEngine:
                 + (("lane_out",) if self.mixed else ())
                 + (("lane_hidden",) if self.mixed and rh else ())
                 # a block that counts appends its counters, last of all
-                + (("stats",) if self._n_stats and kind == "decode_block"
-                   else ()))
+                + (("stats",) if kind == "blocks" or (
+                    self._n_stats and kind == "decode_block") else ()))
 
     def _program(self, kind: str, poison: bool = False,
                  dev_tokens: bool = False):
-        """The round program to run: ``kind`` is ``"decode_block"`` or
-        ``"verify"``, the one body of each under every key schedule, with
+        """The round program to run: ``kind`` is ``"decode_block"``,
+        ``"verify"`` or ``"blocks"``, the one body of each under every key
+        schedule, with
         the fused prefill lane on a mixed engine. Its operands after
         ``params`` and ``cache`` are what ``_round_operands`` makes: the
         round's host rows as ONE packed int32 array [rows, slots], split
@@ -835,8 +851,9 @@ class InferenceEngine:
             lane = (dpP,) * (4 + 4 * self.sample_on_device
                              + (self.adapters is not None)
                              if self.mixed else 0)
-            impl = (self._verify_impl if kind == "verify"
-                    else self._decode_block_impl)
+            impl = {"decode_block": self._decode_block_impl,
+                    "verify": self._verify_impl,
+                    "blocks": self._blocks_impl}[kind]
             prog = self._programs[kind, poison, dev_tokens] = jax.jit(
                 shard_map(
                     partial(impl, poison=poison, dev_tokens=dev_tokens),
@@ -1160,7 +1177,7 @@ class InferenceEngine:
         return {**new_leaves, **self._meta(cache), "lengths": lengths}
 
     def _model_block(self, params, cache, tokens, rows, pos,
-                     extra_meta=None):
+                     extra_meta=None, head: bool = True):
         """The shared incremental-decode model body: embed ``tokens``
         [B, S] at RoPE positions ``rows`` [B, S], scan the layer stack
         writing each slot's S new K/V rows from ``pos`` [B]
@@ -1169,16 +1186,20 @@ class InferenceEngine:
         pre-final-norm hidden states [B, S, H]). S == 1 is the decode
         step; S > 1 the speculative verify block. ``extra_meta`` rides
         into each layer's cache dict alongside the paged metadata (the
-        ragged verify's ``draft_valid`` write mask). Lengths are NOT
+        ragged verify's ``draft_valid`` write mask; a blocks round's own
+        ``live`` rows). Without ``head`` the logits are None (a commit
+        forward's are read by nobody). Lengths are NOT
         advanced here — callers apply their own activity rule."""
         cos_b, sin_b = rope_at_positions(self._cos, self._sin, rows)
         h = self._embed(params, tokens)
         meta = {**self._local_meta(cache), **(extra_meta or {})}
-        if self._n_stats:
+        if self._n_stats and "live" not in meta:
             # free slots (length 0) ride along uncounted and unrouted
             meta["live"] = jnp.broadcast_to((pos > 0)[:, None], rows.shape)
         h, new_leaves = self._scan_layers(params, cache, h,
                                           cos_b, sin_b, pos, meta)
+        if not head:
+            return new_leaves, None, h
         logits = tp_gather(self.model.head_logits(params, h, self.cfg))
         return new_leaves, logits.astype(jnp.float32), h
 
@@ -1413,6 +1434,151 @@ class InferenceEngine:
                 h, last[:, None, None], axis=1)[:, 0]
         return self._round_outputs("verify", params, out, lane)
 
+    def _block_forward(self, params, cache, x, active, head: bool = True):
+        """One forward of every slot's current block: ``x`` [B,
+        block_length] embedded at positions ``lengths .. lengths +
+        block_length``, its K/V rows written there and then attended (each
+        row sees the slot's stored prefix and the whole block), ``lengths``
+        NOT advanced: the rows are provisional, beyond the length for every
+        later reader, and the next forward of the slot overwrites them (a
+        commit is this forward of the finished block, then the advance).
+        Slots that are not ``active`` [B] ride along unrouted and uncounted.
+        Returns (cache, logits [B, block_length, V] float32 or None without
+        ``head``, the layers' stats)."""
+        pos = cache["lengths"]
+        Bd = x.shape[1]
+        rows = pos[:, None] + jnp.arange(Bd, dtype=jnp.int32)[None, :]
+        new_leaves, logits, _ = self._model_block(
+            params, cache, x, rows, pos, head=head, extra_meta={
+                "live": jnp.broadcast_to(active[:, None], rows.shape)})
+        stats = new_leaves.pop(models.STATS, jnp.zeros(
+            (self.cfg.model.num_hidden_layers, 0), jnp.int32))
+        return self._rebuild(cache, new_leaves, pos), logits, stats
+
+    def _commit(self, params, cache, x, active):
+        """The commit forward of a finished block ``x``: its K/V stay, and
+        the ``active`` slots' lengths pass them. (cache, stats)."""
+        with jax.named_scope("diffusion/commit"):
+            cache, _, stats = self._block_forward(params, cache, x, active,
+                                                  head=False)
+        pos = cache["lengths"]
+        return {**cache, "lengths": jnp.where(
+            active, pos + x.shape[1], pos)}, stats
+
+    def _blocks_impl(self, params, cache, pack, *rest, poison=False,
+                     dev_tokens=False):
+        """A round of generation by diffusion over blocks (SDAR's published
+        ``block_diffusion_generate``, a cache under it): ``decode_block_len /
+        block_length`` blocks a slot, one after the other, in one program.
+
+        ``pack`` and ``rest`` are ``_round_operands``'s: tokens [B,
+        block_length] and given [B], the leading positions of each slot's
+        FIRST block of the round that are given (a prompt's remainder
+        behind its whole prefilled blocks; 0 in every later round), eos_id,
+        budget, the sampling rows, and the round's keys [decode_block_len,
+        2] (block ``j``'s denoise step ``s`` draws with ``keys[j *
+        block_length + s]``).
+
+        A block starts as its given positions and ``mask_token_id`` behind
+        them; masked-ness is a flag a position, never an id compared. While
+        a position of a live slot is masked, for at most ``denoising_steps``
+        steps: one denoise forward of every slot's block (``_block_forward``:
+        written, attended, not counted in ``lengths``), a draw at every
+        position (``sampling.sample``: the argmax at temperature 0), and the
+        confidence rule (``sampling.confidence_unmask``) fixes some of the
+        masked positions at their draw. Then the commit forward of the
+        finished block stores its K/V and the slot's length passes it. The
+        block's new tokens (behind the given ones) are emitted up to the
+        budget and the first EOS among them, which end the slot's stream: it
+        rides the round's later blocks inactive (its provisional rows land
+        beyond its length). A slot is live while its budget lasts; a free
+        slot's is 0.
+
+        Returns ``_round_fields("blocks")``: cache, packed (tokens [B,
+        decode_block_len], a slot's emitted run left-packed, and counts [B]
+        side by side), and the stats in the order of ``stat_names``, one
+        vector: the layers' own summed over the forwards and the layers, the
+        round's ``sampling.DIFFUSION_STATS`` behind them."""
+        (tokens, given, eos_id, budget, temperature, top_k, top_p), \
+            (keys, *lane) = self._unpack_rows("blocks", pack, rest,
+                                              dev_tokens)
+        m = self.cfg.model
+        Bd, T = m.block_length, m.denoising_steps
+        B, nb = tokens.shape[0], self.decode_block_len // Bd
+        cols = jnp.arange(Bd, dtype=jnp.int32)[None, :]
+        per_row = lambda a: jnp.repeat(a, Bd)
+
+        def block(carry, keys_j):
+            cache, given, budget, stats, counts = carry
+            active = budget > 0
+            x = jnp.where(cols < given[:, None], tokens, m.mask_token_id)
+            masked = (cols >= given[:, None]) & active[:, None]
+            n_live = jnp.sum(active, dtype=jnp.int32)
+
+            def denoise(c):
+                s, cache, x, masked, stats, counts = c
+                cache, logits, counted = self._block_forward(
+                    params, cache, x, active)
+                if poison:
+                    logits = jnp.full_like(logits, jnp.nan)
+                with jax.named_scope("diffusion/unmask"):
+                    x0 = sampling.sample(
+                        logits.reshape(B * Bd, -1), keys_j[s],
+                        per_row(temperature), per_row(top_k),
+                        per_row(top_p)).reshape(B, Bd)
+                    fix, passed = sampling.confidence_unmask(
+                        logits, x0, masked,
+                        sampling.transfer_count(Bd, T, s), m.remasking,
+                        m.confidence_threshold)
+                counts = counts + jnp.stack([
+                    1, 0, 0, jnp.sum(fix, dtype=jnp.int32),
+                    jnp.sum(passed), Bd * n_live])
+                return (s + 1, cache, jnp.where(fix, x0, x), masked & ~fix,
+                        stats + counted, counts)
+
+            _, cache, x, _, stats, counts = lax.while_loop(
+                lambda c: (c[0] < T) & jnp.any(c[3]), denoise,
+                (jnp.zeros((), jnp.int32), cache, x, masked, stats, counts))
+            cache, counted = self._commit(params, cache, x, active)
+            counts = counts + jnp.stack([0, 1, n_live, 0, 0, Bd * n_live])
+            # the block's new tokens, up to the budget and the first EOS
+            n = jnp.where(active, jnp.minimum(Bd - given, budget), 0)
+            new = (cols >= given[:, None]) & (cols < (given + n)[:, None])
+            is_eos = new & (eos_id >= 0)[:, None] & (x == eos_id[:, None])
+            hit = jnp.any(is_eos, axis=1)
+            n = jnp.where(hit, jnp.argmax(is_eos, axis=1) - given + 1, n)
+            new &= cols < (given + n)[:, None]
+            budget = jnp.where(hit, 0, budget - n)
+            return (cache, jnp.zeros_like(given), budget, stats + counted,
+                    counts), (jnp.where(new, x, 0), n)
+
+        zeros = lambda *shape: jnp.zeros(shape, jnp.int32)
+        (cache, _, _, stats, counts), (toks, ns) = lax.scan(
+            block, (cache, given, budget,
+                    zeros(m.num_hidden_layers, self._n_stats),
+                    zeros(len(sampling.DIFFUSION_STATS))),
+            keys.reshape(nb, Bd, 2))
+        # a slot's run lies behind its given positions, blocks end to end
+        flat = jnp.swapaxes(toks, 0, 1).reshape(B, nb * Bd)
+        total = jnp.sum(ns, axis=0)
+        at = jnp.arange(nb * Bd, dtype=jnp.int32)[None, :]
+        run = jnp.take_along_axis(
+            flat, jnp.minimum(at + given[:, None], nb * Bd - 1), axis=1)
+        out = {"cache": cache, "counts": total,
+               "tokens": jnp.where(at < total[:, None], run, 0),
+               "stats": jnp.concatenate([jnp.sum(stats, axis=0), counts])}
+        return self._round_outputs("blocks", params, out, lane)
+
+    def _block_forward_impl(self, params, cache, tokens, active, *,
+                            commit: bool):
+        """``_block_forward`` (or ``_commit``) as a program of its own
+        (``block_forward``): a round's forwards one at a time, for the
+        checks that read every forward's logits."""
+        if commit:
+            cache, stats = self._commit(params, cache, tokens, active)
+            return cache, jnp.zeros((), jnp.float32), stats
+        return self._block_forward(params, cache, tokens, active)
+
     def _unpack_rows(self, kind: str, pack, rest, dev_tokens: bool):
         """A round body's first lines: ``_round_operands``'s packed int32
         rows [rows, B] back under their names, ``(tokens[, valid], eos_id,
@@ -1420,20 +1586,22 @@ class InferenceEngine:
         (the keys, then the lane's). The float rows rode as their bits and
         are bit-cast back, so a sampled token is what separate float32
         operands gave. ``tokens`` is the leading row (a verify's leading
-        S, one a fed position) unless the caller held it on the device:
-        then it is the first of ``rest``, as it was handed in."""
+        S, one a fed position; a blocks round's leading ``block_length``,
+        with how many of them are given as its ``valid``) unless the caller
+        held it on the device: then it is the first of ``rest``, as it was
+        handed in."""
         n = len(_PACK_ROWS)
         lead, (eos_id, budget, top_k, temperature, top_p) = \
             pack[:-n], pack[-n:]
         temperature, top_p = (lax.bitcast_convert_type(r, jnp.float32)
                               for r in (temperature, top_p))
         valid = ()
-        if kind == "verify":
+        if kind != "decode_block":
             lead, valid = lead[:-1], (lead[-1],)
         if dev_tokens:
             tokens, *rest = rest
         else:
-            tokens = lead.T if kind == "verify" else lead[0]
+            tokens = lead[0] if kind == "decode_block" else lead.T
         return (tokens, *valid, eos_id, budget, temperature, top_k,
                 top_p), rest
 
@@ -1603,16 +1771,21 @@ class InferenceEngine:
         self._stats_pending.append(stats)
 
     def take_stats(self):
-        """What the block counted in the dispatches since the last call,
-        summed over them and over the layers (int64, one number for each
-        of ``model.STAT_NAMES``; None of a block that does not count). The batcher calls it where it delivers a round, when the
-        round's results are on the host anyway."""
-        if not self._n_stats:
+        """What was counted in the dispatches since the last call, summed
+        over them and over the layers (int64, one number for each of
+        ``stat_names``; None where nothing counts). A dispatch's stats are
+        a row a layer of the block's own counters, or of a round of blocks
+        one vector already summed, the round's counters behind the block's.
+        The batcher calls it where it delivers a round, when the round's
+        results are on the host anyway."""
+        if not self.stat_names:
             return None
         pending, self._stats_pending = self._stats_pending, []
-        total = np.zeros(self._n_stats, np.int64)
+        total = np.zeros(len(self.stat_names), np.int64)
         for v in pending:
-            total += np.asarray(v, np.int64).sum(axis=0)
+            v = np.asarray(v, np.int64)
+            v = v.sum(axis=0) if v.ndim == 2 else v
+            total[:len(v)] += v
         return total
 
     def shard_params(self, params):
@@ -2469,7 +2642,7 @@ class InferenceEngine:
 
     def decode_block(self, params, cache, tokens, keys, eos_id, budget,
                      temperature, top_k, top_p, adapter_ids=None,
-                     lead=None, lanes=None) -> RoundResult:
+                     lead=None, lanes=None, given=None) -> RoundResult:
         """``decode_block_len`` tokens for every slot in one dispatch.
         ``keys`` is [decode_block_len, 2] (one PRNG key per in-block step)
         on a round-keyed engine, or the per-slot BASE keys [slots, 2] on a
@@ -2488,7 +2661,13 @@ class InferenceEngine:
         ``lanes`` (mixed_dispatch engines only — see ``_lane_args``)
         feeds each dp shard's fused prefill lane; a mixed engine ALWAYS
         runs the fused program (idle padded lanes when None), so the
-        compiled shape never changes."""
+        compiled shape never changes.
+
+        An engine that generates by blocks (``self.blocks``) runs its round
+        of blocks here (``_blocks_impl``): ``tokens`` is then [slots,
+        block_length], the positions of each slot's next block that are
+        given, and ``given`` [slots] says how many lead it (a prompt's
+        remainder in the slot's first round, 0 after)."""
         if self.key_schedule != "slot":
             keys = jnp.asarray(keys)
             if keys.shape[0] != self.decode_block_len:
@@ -2499,10 +2678,58 @@ class InferenceEngine:
         # that sync is exactly what the overlap pipeline exists to avoid
         if not isinstance(tokens, jax.Array):
             tokens = np.asarray(tokens, np.int32)
+        if self.blocks:
+            Bd = self.cfg.model.block_length
+            given = np.asarray(given, np.int32)
+            if tokens.shape != (self.slots, Bd) \
+                    or given.shape != (self.slots,) \
+                    or np.any(given < 0) or np.any(given >= Bd):
+                raise ValueError(
+                    f"a round of blocks takes tokens [slots, block_length] "
+                    f"= [{self.slots}, {Bd}] and given [slots] in [0, "
+                    f"block_length); got {tokens.shape} and "
+                    f"{given.tolist()}")
+            return self._round("blocks", params, cache, (tokens, given),
+                               keys, eos_id, budget, temperature, top_k,
+                               top_p, self.decode_block_len, budget,
+                               adapter_ids, lead, lanes)
         return self._round("decode_block", params, cache, (tokens,), keys,
                            eos_id, budget, temperature, top_k, top_p,
                            self.decode_block_len, budget, adapter_ids,
                            lead, lanes)
+
+    def block_forward(self, params, cache, tokens, active,
+                      commit: bool = False) -> tuple:
+        """One forward of a round of blocks as a dispatch of its own, for
+        the checks that hold every forward to the reference: ``tokens``
+        [slots, block_length] is every slot's block as it stands (given and
+        fixed positions, ``mask_token_id`` at the others), ``active``
+        [slots] bool the slots it counts for. A denoise forward returns
+        (cache, logits [slots, block_length, V] float32) and leaves
+        ``lengths`` where they were; with ``commit`` (cache, None), the
+        active slots' lengths past the block. Consumes ``cache``."""
+        if not self.blocks:
+            raise ValueError("block_forward is a blocks engine's")
+        def prog():
+            # looked up at the call: a flash->dense rebuild empties the table
+            key = ("block_forward", commit)
+            if key not in self._programs:
+                self._programs[key] = jax.jit(
+                    shard_map(
+                        partial(self._block_forward_impl, commit=commit),
+                        self.topo.mesh,
+                        in_specs=(self._decode_dispatch_pspecs,
+                                  self._cspecs, P(), P()),
+                        out_specs=(self._cspecs, P(), P())),
+                    donate_argnums=(1,))
+            return self._programs[key]
+
+        self._hook("block_forward")
+        cache, logits, stats = self._dispatch(lambda: prog()(
+            params, cache, jnp.asarray(np.asarray(tokens, np.int32)),
+            jnp.asarray(np.asarray(active, bool))))
+        self._keep_stats(stats)
+        return cache, (None if commit else logits)
 
     def verify(self, params, cache, tokens, key, eos_id, budget,
                temperature, top_k, top_p, draft_len=None,

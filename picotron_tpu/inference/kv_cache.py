@@ -366,14 +366,16 @@ def layer_block(cache: dict, name: str, layer):
     return leaf
 
 
-def plain_decode(q: jnp.ndarray, cache: dict) -> bool:
+def plain_decode(q: jnp.ndarray, cache: dict, block: int = 1) -> bool:
     """Whether an ``attend`` call is the plain decode shape the stacked
-    flash-decode kernel serves: one fresh query a slot against a
-    contiguous bfloat16 cache of whole-lane rows, every slot at its own
-    length, with no addressing entry spliced in (``slot`` / ``gate``: one
-    slot's block; ``draft_valid``: a ragged verify)."""
+    flash-decode kernel serves: one fresh query a slot (or, with ``block``
+    > 1, one whole aligned block of ``block`` fresh rows a slot, every row
+    of which sees every live key) against a contiguous bfloat16 cache of
+    whole-lane rows, every slot at its own length, with no addressing entry
+    spliced in (``slot`` / ``gate``: one slot's block; ``draft_valid``: a
+    ragged verify)."""
     k = cache.get("k")
-    return (q.shape[1] == 1 and k is not None and k.ndim == 5
+    return (q.shape[1] in (1, block) and k is not None and k.ndim == 5
             and "block_tables" not in cache and not quantized(cache)
             and k.dtype == q.dtype == jnp.bfloat16
             and k.shape[-1] % LANE == 0
@@ -381,9 +383,22 @@ def plain_decode(q: jnp.ndarray, cache: dict) -> bool:
 
 
 def attend(q: jnp.ndarray, cache: dict, lengths: jnp.ndarray,
-           scale: float, layer, impl: str = "dense") -> jnp.ndarray:
+           scale: float, layer, impl: str = "dense",
+           block: int = 1) -> jnp.ndarray:
     """Masked attention of S fresh queries against ``layer`` of the
     stacked cache leaves (``layer_block``: read where it lies).
+
+    ``block`` is the width of the band a fresh row sees: keys up to the
+    end of its own block of ``block`` positions, counted from the
+    sequence's start, and never past the last fresh row (``block == 1``:
+    up to itself, the causal band; ``models/sdar_moe.py``: bidirectional
+    inside a block, causal between blocks). The fresh rows start on a block
+    boundary where ``block`` > 1 (the caller's to hold: its window, chunk
+    and round are whole blocks), so ``S == block`` rows a slot are one
+    block, each sees every live key, and the call is the plain decode
+    shape with ``S`` times the query heads (``plain_decode``). The dense
+    rule and that shape hold a band; the sliced flash kernel and the paged
+    attends see the causal one alone and refuse another.
 
     ``impl`` picks the kernel (config ``inference.attend_impl``):
 
@@ -412,24 +427,31 @@ def attend(q: jnp.ndarray, cache: dict, lengths: jnp.ndarray,
     slots' pages into a contiguous window and runs the same masked
     einsum; flash walks the block table page by page in the kernel.
     """
-    plain = plain_decode(q, cache)
+    plain = plain_decode(q, cache, block)
     if impl == "auto":
         impl = "flash" if plain and on_tpu() else "dense"
     if "block_tables" in cache:
         from picotron_tpu.inference import paged_kv
 
+        if block != 1:
+            raise NotImplementedError("the paged attends see a causal band")
         return paged_kv.attend(q, cache, lengths, scale, layer, impl)
     if impl == "flash" and plain:
         from picotron_tpu.ops.pallas.decode_attention import (
             flash_decode_stacked,
         )
 
-        return flash_decode_stacked(q, cache["k"], cache["v"], lengths,
-                                    scale, layer, interpret=not on_tpu())
+        if q.shape[1] == 1:
+            return flash_decode_stacked(q, cache["k"], cache["v"], lengths,
+                                        scale, layer, interpret=not on_tpu())
+        return _attend_whole_block(q, cache, lengths, scale, layer)
     k, v, k_scale, v_scale = (layer_block(cache, n, layer)
                               for n in ("k", "v", "k_scale", "v_scale"))
     D = q.shape[-1]
     if impl == "flash":
+        if block != 1:
+            raise NotImplementedError(
+                "the sliced flash kernel sees a causal band")
         # the kernel takes a head a row
         k, v = unpack_heads(k, D), unpack_heads(v, D)
         from picotron_tpu.ops.pallas.decode_attention import (
@@ -447,11 +469,36 @@ def attend(q: jnp.ndarray, cache: dict, lengths: jnp.ndarray,
         k, v = (pack_heads(dequantize_kv(unpack_heads(x, D), s, jnp.float32),
                            x.shape[-1] // D)
                 for x, s in ((k, k_scale), (v, v_scale)))
-    return decode_attention(q, k, v, lengths, scale)
+    return decode_attention(q, k, v, lengths, scale, block)
+
+
+def _attend_whole_block(q, cache: dict, lengths, scale: float, layer):
+    """``attend``'s plain decode shape at ``S == block`` > 1: every one of a
+    slot's S fresh rows sees every live key, so the rows ride beside the
+    query heads of their cache row (``[B, S, rows, p, g, D] -> [B, 1, rows x
+    p x S x g, D]``: ``S x g`` query heads a kv head) through the stacked
+    kernel, which reads the live rows of K and V once for all of them, in
+    the decode step's own K blocks: an ``S``-th of them (the step's score
+    tile kept) made a call four times the grid steps, most of them past a
+    slot's walk, and took half as long again (0.889 against 0.614 ms a layer
+    at 5,000 tokens a slot, the step itself 0.569; PERF.md section 6, PR
+    62)."""
+    from picotron_tpu.ops.pallas.decode_attention import flash_decode_stacked
+
+    B, S, nh, D = q.shape
+    k, v = cache["k"], cache["v"]
+    rows, p = k.shape[3], k.shape[4] // D
+    g = nh // (rows * p)
+    fold = q.reshape(B, S, rows, p, g, D).transpose(0, 2, 3, 1, 4, 5)
+    out = flash_decode_stacked(fold.reshape(B, 1, S * nh, D), k, v, lengths,
+                               scale, layer, interpret=not on_tpu())
+    out = out.reshape(B, rows, p, S, g, -1).transpose(0, 3, 1, 2, 4, 5)
+    return out.reshape(B, S, nh, -1)
 
 
 def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                     lengths: jnp.ndarray, scale: float) -> jnp.ndarray:
+                     lengths: jnp.ndarray, scale: float,
+                     block: int = 1) -> jnp.ndarray:
     """Masked dot-product attention of S fresh queries against a cache block.
 
     q: [B, S, n_heads, D] — the new tokens, the LAST of which sits at global
@@ -473,7 +520,9 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     S == 1 is the autoregressive decode step; S > 1 is chunked continuation
     — prefill chunks (B == 1) or speculative verify batches (B > 1)
     attending over the already-written prefix plus themselves (each query i
-    masks keys past its own position).
+    masks keys past its own position; with ``block`` > 1, past the end of
+    its own block of ``block`` positions, and past the last fresh row:
+    ``attend``).
     """
     B, S, nh, D = q.shape
     T, nkv = k.shape[1], k.shape[2]  # nkv rows of p heads
@@ -486,6 +535,9 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         preferred_element_type=jnp.float32) * scale
     # query s has global position lengths - S + s; key t visible iff t <= it
     pos_q = lengths[:, None] - S + jnp.arange(S)[None, :]  # [B, S]
+    if block > 1:  # ... iff t lies in its block or before it
+        pos_q = jnp.minimum(pos_q // block * block + block - 1,
+                            lengths[:, None] - 1)
     mask = jnp.arange(T)[None, None, :] <= pos_q[:, :, None]  # [B, S, T]
     scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
     m = jnp.max(scores, axis=-1, keepdims=True)

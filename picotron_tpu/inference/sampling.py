@@ -33,6 +33,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from picotron_tpu.config import REMASKING
 from picotron_tpu.ops.attention import NEG_INF
 
 
@@ -366,3 +367,61 @@ def speculative_match(logits: jnp.ndarray, draft: jnp.ndarray,
     # to and including the correction/bonus column IS the target chain
     emitted = jnp.where(cols <= acc[:, None], s, 0)
     return emitted, acc + 1
+
+
+# --------------------------------------------------------------------------- #
+# generation by diffusion over blocks: which masked positions a forward fixes
+# --------------------------------------------------------------------------- #
+
+# what a round of blocks counts (``engine._blocks_impl``), as (name, labels)
+# of a ``picotron_<name>_total`` counter each: forwards of either kind, blocks
+# committed and positions unmasked summed over the live slots, masked
+# positions whose confidence passed the threshold, slot-rows forwarded by
+# live slots. The engine hands them behind the block's own
+# (``engine.stat_names``)
+DIFFUSION_STATS = (("diffusion_forwards", {"kind": "denoise"}),
+                   ("diffusion_forwards", {"kind": "commit"}),
+                   ("diffusion_blocks", {}),
+                   ("diffusion_positions_unmasked", {}),
+                   ("diffusion_threshold_passes", {}),
+                   ("diffusion_rows", {}))
+
+
+def transfer_count(block: int, steps: int, step):
+    """Positions denoise step ``step`` (traced) of ``steps`` owes a block of
+    ``block``: ``block // steps``, one more in the first ``block % steps``
+    steps (the published ``get_num_transfer_tokens``)."""
+    return block // steps + (step < block % steps).astype(jnp.int32)
+
+
+def confidence_unmask(logits: jnp.ndarray, x0: jnp.ndarray,
+                      masked: jnp.ndarray, owed, remasking: str,
+                      threshold: float) -> tuple:
+    """One step of SDAR's ``block_diffusion_generate``: which of a block's
+    masked positions take their drawn token now. ``logits`` [B, Bd, V]
+    float32, ``x0`` [B, Bd] the draw at every position, ``masked`` [B, Bd]
+    bool, ``owed`` the step's ``transfer_count``. The confidence of a masked
+    position is ``softmax(logits)[x0]``, of any other ``-inf``. ``"low_
+    confidence_static"`` takes the ``owed`` masked positions of largest
+    confidence (ties to the lower index); ``"low_confidence_dynamic"`` takes
+    every masked position whose confidence passes ``threshold`` where at
+    least ``owed`` do, else as static. A position that is not masked is
+    never taken (``min(owed, masks left)``, where the published top-k could
+    reach a given position). Returns (transfer [B, Bd] bool, masked
+    positions that passed the threshold [B] int32)."""
+    if remasking not in REMASKING:
+        raise ValueError(f"unknown remasking {remasking!r} "
+                         f"({'|'.join(REMASKING)})")
+    logits = logits.astype(jnp.float32)
+    drawn = jnp.take_along_axis(logits, x0[..., None], axis=-1)[..., 0]
+    conf = jnp.exp(drawn - jax.nn.logsumexp(logits, axis=-1))
+    conf = jnp.where(masked, conf, -jnp.inf)
+    # a position's rank by confidence, ties to the lower index
+    order = jnp.argsort(-conf, axis=-1, stable=True)
+    rank = jnp.argsort(order, axis=-1, stable=True)
+    transfer = masked & (rank < owed)
+    passed = masked & (conf > threshold)
+    n_passed = jnp.sum(passed, axis=-1, dtype=jnp.int32)
+    if remasking == "low_confidence_dynamic":
+        transfer = jnp.where((n_passed >= owed)[:, None], passed, transfer)
+    return transfer, n_passed

@@ -29,6 +29,12 @@ whatever the block:
 - ``CARRIES_STATE`` (absent: false): the cache holds a state with no token
   axis, which cannot be fed a token twice; the engine then holds the
   window to whole prefill chunks;
+- ``GENERATES`` (absent: ``"tokens"``): how the engine generates with the
+  block. ``"tokens"``: a token a slot and step, fed the token before it
+  (``engine._decode_block_impl``). ``"blocks"``: by diffusion over blocks of
+  ``model.block_length`` positions; a round denoises and commits whole
+  blocks (``engine._blocks_impl``) and counts its own forwards
+  (``engine.stat_names``), and admission prefills a prompt's whole blocks;
 - ``STAT_NAMES``: the counters a layer returns, an int32 vector under
   ``"stats"`` in its dict (``()``: none, and the programs have no such
   output);
@@ -207,6 +213,7 @@ BLOCKS = {
     "KeyeVL2": "keye_vl2",
     "nemotron_h": "nemotron_h",
     "solar_open2": "solar_open2",
+    "sdar_moe": "sdar_moe",
 }
 
 

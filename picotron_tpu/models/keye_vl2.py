@@ -69,8 +69,8 @@ from jax import lax
 
 from picotron_tpu.config import Config, ModelConfig
 from picotron_tpu.inference import kv_cache
-from picotron_tpu.models import (STATS, live_rows, llama, served_whole,
-                                 support)
+from picotron_tpu.models import (STATS, live_rows, llama, normed_gqa_moe,
+                                 served_whole, support)
 from picotron_tpu.models import experts as expert_share
 from picotron_tpu.models.llama import param_bytes  # noqa: F401 - the seam
 from picotron_tpu.ops import select
@@ -90,7 +90,6 @@ STAT_NAMES = expert_share.STAT_NAMES + (
 UNSLICED = expert_share.UNSLICED
 LEAVES = ("kv", "ki")  # the cache's, beside "lengths"
 F32 = jnp.float32
-HIGHEST = lax.Precision.HIGHEST
 QUERY_BLOCK = 128  # queries a masked walk attends at a time
 # keys a decode step's one query a slot scores at a time: with so few rows a
 # block's work is small beside its start-up, and at the 2,048 a chunk's 512
@@ -132,8 +131,6 @@ def validate(cfg: Config, for_training: bool) -> None:
     m = cfg.model
     who = support.who(m)
     support.refuse(cfg, for_training, WHY)
-    support.positive(m, "num_experts", "num_experts_per_tok",
-                     "moe_intermediate_size", "ep_size")
     sa = m.sa_config or {}
     need = ("indexer_num_heads", "indexer_head_dim", "topk")
     if any(int(sa.get(n, 0)) < 1 for n in need) \
@@ -154,20 +151,7 @@ def validate(cfg: Config, for_training: bool) -> None:
             f"{who} needs model.rope_scaling of type 'default' with an "
             f"mrope_section of three counts that sum to head_dim / 2 = "
             f"{m.head_dim // 2} (got {m.rope_scaling!r})")
-    support.ep_share(m, "num_experts")
-    width = router_width(m)
-    support.check(m, (
-        m.num_local_experts not in (0, width),
-        f"num_local_experts {m.num_local_experts} is not the router's width "
-        f"{width} (num_experts x ep_size), which it repeats as published"))
-    support.held_layers(m, m.num_hidden_layers)
-    support.pinned(m, norm_topk_prob=True, decoder_sparse_step=1,
-                   tie_word_embeddings=False)
-    if m.mlp_only_layers:
-        raise ValueError(
-            f"{who} implements model.mlp_only_layers = [] only (got "
-            f"{m.mlp_only_layers!r}): every layer's MLP is the routed "
-            "experts")
+    normed_gqa_moe.validate_experts(cfg)
 
 
 # --------------------------------------------------------------------------- #
@@ -175,8 +159,7 @@ def validate(cfg: Config, for_training: bool) -> None:
 # --------------------------------------------------------------------------- #
 
 
-def router_width(m: ModelConfig) -> int:
-    return m.num_experts * m.ep_size
+router_width = normed_gqa_moe.router_width
 
 
 def indexer(m: ModelConfig) -> tuple:
@@ -189,14 +172,11 @@ def indexer(m: ModelConfig) -> tuple:
 def _group_shapes(m: ModelConfig) -> dict:
     """Matmul leaves of one layer, (in, out) like every weight here; the
     routed experts lead with the experts held."""
-    H, hd = m.hidden_size, m.head_dim
-    nh, nkv = m.num_attention_heads, m.num_key_value_heads
+    H = m.hidden_size
     ih, idim, _ = indexer(m)
-    E, I = m.num_experts, m.moe_intermediate_size
-    return {"wq": (H, nh * hd), "wk": (H, nkv * hd), "wv": (H, nkv * hd),
-            "wo": (nh * hd, H), "wi_q": (H, ih * idim), "wi_k": (H, idim),
-            "wi_w": (H, ih), "router": (H, router_width(m)),
-            "w1": (E, H, I), "w3": (E, H, I), "w2": (E, I, H)}
+    return {**normed_gqa_moe.attention_shapes(m),
+            **normed_gqa_moe.expert_shapes(m),
+            "wi_q": (H, ih * idim), "wi_k": (H, idim), "wi_w": (H, ih)}
 
 
 def layer_groups(m: ModelConfig) -> list:
@@ -213,33 +193,16 @@ def init_params(key, m: ModelConfig, pp_size: int = 1,
     U(+-``KI_BIAS``)."""
     if pp_size != 1 or interleave != 1:
         raise ValueError("KeyeVL2 is served on one stage (pp_size 1)")
-    dt = jnp.dtype(m.dtype)
-    H, V, n = m.hidden_size, m.vocab_size, m.num_hidden_layers
-    idim = indexer(m)[1]
-
-    def uniform(k, shape, fan_in, gain=1.0):
-        bound = gain * math.sqrt(1.0 / fan_in)
-        return jax.random.uniform(k, shape, dt, -bound, bound)
-
-    gkey = jax.random.fold_in(key, 2)
-    ones = lambda w: jnp.ones((n, w), dt)
-    layers = {"attn_norm": ones(H), "mlp_norm": ones(H),
-              "q_norm": ones(m.head_dim), "k_norm": ones(m.head_dim),
-              "ki_norm": ones(idim)}
-    shapes = sorted(_group_shapes(m).items())
-    for i, (name, shape) in enumerate(shapes):
-        layers[name] = uniform(jax.random.fold_in(gkey, i), (n,) + shape,
-                               shape[-2], INIT_GAIN.get(name, 1.0))
-    layers["ki_bias"] = jax.random.uniform(
-        jax.random.fold_in(gkey, len(shapes)), (n, idim), dt, -KI_BIAS,
-        KI_BIAS)
-    return {
-        "embed": jax.random.normal(jax.random.fold_in(key, 0), (V, H),
-                                   F32).astype(dt),
-        "final_norm": jnp.ones((H,), dt),
-        "lm_head": uniform(jax.random.fold_in(key, 1), (H, V), H),
-        "layers": layers,
-    }
+    H, n, idim = m.hidden_size, m.num_hidden_layers, indexer(m)[1]
+    shapes = _group_shapes(m)
+    params = normed_gqa_moe.draw_tree(
+        key, m, shapes,
+        {"attn_norm": H, "mlp_norm": H, "q_norm": m.head_dim,
+         "k_norm": m.head_dim, "ki_norm": idim}, INIT_GAIN)
+    params["layers"]["ki_bias"] = jax.random.uniform(
+        jax.random.fold_in(normed_gqa_moe.layers_key(key), len(shapes)),
+        (n, idim), jnp.dtype(m.dtype), -KI_BIAS, KI_BIAS)
+    return params
 
 
 param_pspecs, num_params, cache_pspecs = served_whole(
@@ -435,17 +398,14 @@ def attention(lp, x, cos, sin, m: ModelConfig, cache, pos, layer, live,
     sequence's first position; ``live`` [B, S] marks the queries that are
     counted."""
     B, S, _ = x.shape
-    nh, nkv, hd = m.num_attention_heads, m.num_key_value_heads, m.head_dim
+    nh, hd = m.num_attention_heads, m.head_dim
     ih, idim, topk = indexer(m)
-    eps = m.rms_norm_eps
     (cos_h, sin_h), (cos_i, sin_i) = _angles(cos, sin, m)
     if pos is None:
         pos = jnp.zeros((B,), jnp.int32)
     pos_q = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
 
-    q = rms_norm((x @ lp["wq"]).reshape(B, S, nh, hd), lp["q_norm"], eps)
-    k = rms_norm((x @ lp["wk"]).reshape(B, S, nkv, hd), lp["k_norm"], eps)
-    v = (x @ lp["wv"]).reshape(B, S, nkv, hd)
+    q, k, v = normed_gqa_moe.qkv(lp, x, m, rms_norm)
     q, k = apply_rope(q, cos_h, sin_h), apply_rope(k, cos_h, sin_h)
     with jax.named_scope("dsa_index"):
         qi = apply_rope((x @ lp["wi_q"]).reshape(B, S, ih, idim), cos_i,
@@ -512,19 +472,8 @@ def expert_mlp(lp, x, m: ModelConfig, live) -> tuple:
     """The expert half of a layer on the normed stream ``x`` [B, S, H]:
     (this chip's part of the routed sum, what ``models/experts.py::share``
     counted). Rows that are not ``live`` are routed nowhere."""
-    B, S, H = x.shape
-    x2 = x.reshape(B * S, H)
-    with jax.named_scope("keye/router"):
-        logits = jnp.dot(x2.astype(F32), lp["router"].astype(F32),
-                         precision=HIGHEST)
-        experts, weights = expert_share.route(
-            router_scores(logits), jnp.zeros((), F32),
-            k=m.num_experts_per_tok, scale=1.0)
-        w_held = expert_share.held_weights(
-            experts, weights, m.ep_rank * m.num_experts, m.num_experts) \
-            * live.reshape(B * S, 1).astype(F32)
-    y, counted = expert_share.share(lp, x2, w_held)
-    return y.reshape(B, S, H), counted
+    return normed_gqa_moe.expert_mlp(lp, x, m, live, router_scores,
+                                     "keye/router")
 
 
 # --------------------------------------------------------------------------- #

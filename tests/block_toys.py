@@ -107,6 +107,17 @@ TOYS = {
         num_experts_per_tok=2, moe_intermediate_size=32, n_shared_experts=1,
         routed_scaling_factor=1.0, norm_topk_prob=True,
         first_k_dense_replace=0),
+    "sdar_moe": dict(
+        name="toy-sdar", model_type="sdar_moe", num_hidden_layers=3,
+        hidden_size=64, num_attention_heads=8, num_key_value_heads=2,
+        head_dim=16, intermediate_size=96, vocab_size=256, rms_norm_eps=1e-6,
+        rope_theta=10000.0, max_position_embeddings=256, dtype="float32",
+        num_experts=4, num_local_experts=16, ep_size=4, ep_rank=0,
+        num_experts_per_tok=4, moe_intermediate_size=32, norm_topk_prob=True,
+        decoder_sparse_step=1, mlp_only_layers=[], first_layer=3,
+        total_layers=12, block_length=4, denoising_steps=4,
+        remasking="low_confidence_dynamic", confidence_threshold=0.9,
+        mask_token_id=255),
 }
 
 

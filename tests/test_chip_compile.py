@@ -1000,6 +1000,10 @@ def _cell_program(topo, prog, name):
         jitted = eng._program("decode_block")
         args = (arg((6, slots), I32),
                 arg((eng.decode_block_len, 2), jnp.uint32))
+    elif prog == "blocks":  # a round of blocks: a block's tokens, ``given``
+        jitted = eng._program("blocks")
+        args = (arg((cfg.model.block_length + 6, slots), I32),
+                arg((eng.decode_block_len, 2), jnp.uint32))
     else:
         jitted = eng._prefill_chunk_jit
         args = (arg((1, eng.prefill_chunk), I32),) + (arg((), I32),) * 3
@@ -1266,3 +1270,46 @@ def test_keye_cache_leaves_are_never_copied_whole(prog, topo, one_chip,
     mem = compiled.memory_analysis()
     assert round(mem.argument_size_in_bytes / 1e9, 2) == 12.75
     assert mem.temp_size_in_bytes < 0.8e9  # 0.23 a block, 0.61 a chunk
+
+
+@pytest.mark.parametrize("prog", ["blocks", "prefill_chunk"])
+def test_sdar_round_of_blocks_and_chunk_leave_the_cache_in_place(
+        prog, topo, one_chip, experts_on_chip, monkeypatch):
+    """The SDAR cell's programs at its own size (PR 62: 32 slots x 12,288
+    beside 2.43 GB of weights). The round of blocks: a denoise forward's
+    ``block_length`` rows a slot ride beside the query heads through the
+    stacked flash-decode kernel on the ``k``/``v`` leaves where they lie (one
+    call a layer in the loop's body, one in the commit), the sixteen held
+    experts through the pipelined pass at 128 rows, the loop over the steps a
+    ``while`` with the cache in its carry and no copy of a leaf. The chunk:
+    four K/V heads a token are half a register tile, and left free the
+    chunk's contractions re-laid both leaves with the tokens along the lanes
+    (two copies of 4.5 GB: the chunk did not fit); ``sdar_moe.attention``
+    pins them row-major. The programs' temporaries leave the 12.09 GB
+    resident room on the chip."""
+    from picotron_tpu.inference import kv_cache
+
+    monkeypatch.setattr(kv_cache, "on_tpu", lambda: True)
+    compiled = _cell_program(topo, prog, "sdar-30b-a3b-ep8-l12")
+    text = compiled.as_text()
+    _assert_expert_orders(text, prog, pipelined=True)
+    lines = text.splitlines()
+    kv = r"bf16\[12,32,12288,4,128\]"
+    copies = [l.strip()[:160] for l in lines
+              if re.search(rf"= {kv}\S* copy\(", l)]
+    assert not copies, "\n".join(copies)
+    for leaf in ("k", "v"):
+        params = [l for l in lines
+                  if re.search(rf"cache__{leaf}__\S* = {kv}", l)
+                  and " parameter(" in l]
+        assert len(params) == 1 and "{4,3,2,1,0" in params[0], params
+    kernels = [l for l in lines
+               if re.search(r"%flash_decode_attention\S* = ", l)]
+    assert len(kernels) == (2 if prog == "blocks" else 0), kernels
+    # 32 slots' 4 x 32 query rows of 128 lanes, the leaves whole
+    assert all("bf16[32,128,128]" in l and "bf16[12,32,49152,128]" in l
+               for l in kernels)
+    mem = compiled.memory_analysis()
+    assert round(mem.argument_size_in_bytes / 1e9, 2) == 12.09
+    assert mem.temp_size_in_bytes < (0.4e9 if prog == "blocks" else 0.9e9)
+
