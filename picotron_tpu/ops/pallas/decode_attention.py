@@ -56,18 +56,18 @@ pins all three call shapes in interpret mode; tests/test_chip_compile.py
 compiles every layout for a v5e at SmolLM widths; chip_smoke.py runs it
 compiled against the dense oracle).
 
-Block fetches are the Pallas pipeline's own double-buffered DMA: there is
-no hand-written copy or semaphore in this file. On CPU the kernel runs in
-Pallas interpret mode (``interpret=True``), which is how the parity suite
-and the tier-1 gate exercise it.
+Block fetches are the Pallas pipeline's own double-buffered DMA, but in the
+dense form of ``flash_decode_stacked``, which copies the blocks it walks
+itself (PR 63). On CPU the kernels run in Pallas interpret mode
+(``interpret=True``): the parity suite's and the tier-1 gate's path.
 
 ``flash_decode_stacked`` (PR 36, at the end of the file) is the kernel the
 shipped default ``inference.attend_impl: auto`` runs on a TPU for the plain
 decode step (``S == 1``, a contiguous bfloat16 cache of whole-lane rows): it
 takes the STACKED leaves and a layer index, so nothing is sliced or unpacked
 in front of it, contracts a block's (token, cache row) pairs against every
-query head in one matmul, and walks live rows only. A/B'd on the chip in
-``smollm-1.7b.serve-batch``: the decode step 9.5 -> 6.2 ms (PERF.md, PR 36).
+query head in one matmul, and walks live blocks only: a slot a grid step,
+its blocks a loop inside it (``_dense_kernel``; a ring: ``_stacked_kernel``).
 ``flash_decode_attention`` keeps every other shape under ``attend_impl:
 flash`` (verify, prefill chunks, int8, paged) and takes one layer's sliced
 block, a head a row; ``dense`` serves those under ``auto``.
@@ -508,10 +508,12 @@ def _ring_block(first, j, max_nb):
     return jnp.where(blk >= max_nb, blk - max_nb, blk)
 
 
-def _stacked_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg,
-                    max_nb, steps, window=None, sink=False):
-    """One (slot, kv-block) grid step of ``flash_decode_stacked``. The K
-    block is ``block_t`` tokens of one layer and slot as a plain matrix:
+def _stacked_kernel(len_ref, layer_ref, last_ref, *refs, scale, block_t, rows,
+                    pg, max_nb, steps, window, sink=False):
+    """One (slot, kv-block) grid step of ``flash_decode_stacked``'s ring form
+    (``window``; the dense form's ``_dense_kernel`` keeps this arithmetic and
+    walks a slot's blocks inside one step). The K block is ``block_t``
+    tokens of one layer and slot as a plain matrix:
     ``[block_t * rows, lanes]``, a row of it one (token, cache row) pair,
     every cache row of a token one after the other as the leaf holds them.
     ALL query heads meet ALL of those pairs in one NT matmul
@@ -522,15 +524,14 @@ def _stacked_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg,
     they do not). The other rows' products are exact discards: masked to
     NEG_INF before the softmax, an exact zero in the value matmul.
 
-    With a ``window`` the slot's strip is a ring of ``max_nb`` blocks and a
-    third scalar operand names the row the query's own key lies in: a row
-    is seen if it is live and fewer than ``window`` rows behind that one,
-    the ring's end joined to its start. The grid's second axis is then the
-    walk over the blocks that hold such a row (``_ring_blocks``: step ``j``
-    of the grid's ``steps`` is block ``first + j`` round the ring), not over
-    the blocks that are written: a block wholly out of the window is never
-    fetched. The order the blocks come in is
-    nothing to a running softmax.
+    The slot's strip is a ring of ``max_nb`` blocks and the third scalar
+    operand names the row the query's own key lies in: a row is seen if it
+    is live and fewer than ``window`` rows behind that one, the ring's end
+    joined to its start. The grid's second axis is the walk over the blocks
+    that hold such a row (``_ring_blocks``: step ``j`` of the grid's
+    ``steps`` is block ``first + j`` round the ring), not over the blocks
+    that are written: a block wholly out of the window is never fetched. The
+    order the blocks come in is nothing to a running softmax.
 
     With a ``sink`` a fourth operand holds one float32 a query row: the
     running softmax starts from it (its maximum, a denominator of exp(0),
@@ -538,19 +539,15 @@ def _stacked_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg,
     no value. V's lanes need not be K's: the accumulator and the output are
     as wide as V."""
     del layer_ref  # consumed by the index maps
-    last_ref, refs = (None, refs) if window is None else (refs[0], refs[1:])
     q_ref, refs = refs[0], refs[1:]
     sink_ref, refs = (refs[0], refs[1:]) if sink else (None, refs)
     k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, own_ref = refs
     b = pl.program_id(0)
     j = pl.program_id(1)
     L = len_ref[b]
-    if window is None:
-        nb, blk = _stacked_blocks(L, block_t, max_nb), j
-    else:
-        first, nb = _ring_blocks(L, last_ref[b], window=window,
-                                 block_t=block_t, max_nb=max_nb)
-        blk = _ring_block(first, j, max_nb)
+    first, nb = _ring_blocks(L, last_ref[b], window=window,
+                             block_t=block_t, max_nb=max_nb)
+    blk = _ring_block(first, j, max_nb)
     nq, cols = own_ref.shape
 
     @pl.when(j == 0)
@@ -574,10 +571,9 @@ def _stacked_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg,
             s = s * scale
         # token blk * block_t + own is visible iff it is below the length
         seen = own_ref[...] < L - blk * block_t
-        if window is not None:
-            # and, of a ring, fewer than ``window`` rows behind the query's
-            age = last_ref[b] - blk * block_t - own_ref[...]
-            seen &= jnp.where(age < 0, age + max_nb * block_t, age) < window
+        # and, of a ring, fewer than ``window`` rows behind the query's
+        age = last_ref[b] - blk * block_t - own_ref[...]
+        seen &= jnp.where(age < 0, age + max_nb * block_t, age) < window
         s = jnp.where(seen, s, NEG_INF)
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
@@ -623,14 +619,15 @@ def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
     (``flash_decode_attention`` | ``flash_decode_ring``, ``_sink`` behind
     either).
 
-    ``layer`` and ``lengths`` are scalar-prefetch operands and the K/V
-    index maps return ``(layer, b, walk(j), 0)`` into the leaf viewed as
-    ``[L, B, T * rows, p * D]`` (the same bytes: tokens and rows merge
-    into one major axis, which the compiled program shows as a bitcast):
-    no layer is sliced out, no head unpacked, no copy stands in front of
-    the custom call. A slot walks ``ceil(lengths[b] / block_t)`` blocks;
-    the steps past its walk repeat the last block index (no DMA) and skip
-    their compute; a free slot costs its block 0.
+    ``layer`` and ``lengths`` are scalar-prefetch operands and the leaves
+    are viewed as ``[L, B, T * rows, p * D]`` (the same bytes: tokens and
+    rows merge into one major axis, which the compiled program shows as a
+    bitcast): no layer is sliced out, no head unpacked, no copy stands in
+    front of the custom call. A slot walks ``ceil(lengths[b] / block_t)``
+    blocks and a call pays for those alone (``_dense_kernel``: the grid is
+    the slots, a slot's live blocks a loop inside its step, the next block,
+    the next slot's first at a slot's end, in flight while one is scored); a
+    free slot fetches nothing.
 
     With a ``window`` a slot's strip is a ring (``models/afmoe.py``: position
     ``p`` lies in row ``p mod T``) and the walk is over the blocks that hold
@@ -638,11 +635,12 @@ def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
     ring's end joined to its start (``_ring_blocks``), not over the blocks
     that are written: the grid's second extent is the most blocks a window
     can touch (``_ring_walk``), the index map returns the ``j``-th seen
-    block, and past the walk the last one again, as above. The default block
-    is no larger than the window (``_ring_block_rows``), since whatever a
-    block holds beside the window's rows crosses the HBM to be masked: a ring
-    of 640 rows under a window of 128 is read as one or two blocks of 128
-    where blocks of 320 read it whole. ``block_t`` overrides either default.
+    block, and past the walk the last one again (no DMA, the step's compute
+    skipped). The default block is no larger than the window
+    (``_ring_block_rows``), since whatever a block holds beside the window's
+    rows crosses the HBM to be masked: a ring of 640 rows under a window of
+    128 is read as one or two blocks of 128 where blocks of 320 read it
+    whole. ``block_t`` overrides either default.
 
     Packed rows are contracted whole, as ``kv_cache.decode_attention``
     does it: the ``p * g`` query heads of a cache row each sit in their
@@ -686,22 +684,38 @@ def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
     else:
         bt = _ring_block_rows(T, row_bytes, window)
     cols, max_nb = bt * rows, T // bt
-    steps = max_nb if window is None else _ring_walk(window, bt, max_nb)
     lengths = lengths.astype(jnp.int32)
-    prefetch = (lengths, jnp.asarray(layer, jnp.int32).reshape(1))
-    if window is not None:
-        # rows written, and the row of the query's own key
-        prefetch = (jnp.minimum(lengths, T), prefetch[1], (lengths - 1) % T)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def kv_index(b, j, len_ref, layer_ref, *last_ref):
-        if window is None:
-            nb = _stacked_blocks(len_ref[b], bt, max_nb)
-        else:
-            first, nb = _ring_blocks(len_ref[b], last_ref[0][b],
-                                     window=window, block_t=bt, max_nb=max_nb)
+    def own(out):  # [B, nq, lanes_v] -> the heads and lanes the query came in
+        out = out[:, :nh].reshape(B, 1, rows, pg, lanes_v)
+        if pack > 1:
+            out = _own_lanes(out, pack)
+        return out.reshape(B, 1, nh, lanes_v // pack)
+
+    def operands():
+        """The query rows, a sink a row, the leaves' tokens and rows merged."""
+        ops = [qm]
+        if sink is not None:
+            # a float32 a query row; the pad rows' is 0 (``own`` slices it off)
+            ops.append(jnp.pad(sink.astype(jnp.float32),
+                               (0, nq - nh)).reshape(nq, 1))
+        return ops + [k.reshape(nl, B, T * rows, lanes),
+                      v.reshape(nl, B, T * rows, lanes_v)]
+
+    if window is None:
+        return own(_dense_call(lengths, layer, operands(), scale=scale,
+                               block_t=bt, rows=rows, pg=pg,
+                               interpret=interpret))
+    steps = _ring_walk(window, bt, max_nb)
+    # rows written, and the row of the query's own key
+    prefetch = (jnp.minimum(lengths, T), layer, (lengths - 1) % T)
+
+    def kv_index(b, j, len_ref, layer_ref, last_ref):
+        first, nb = _ring_blocks(len_ref[b], last_ref[b],
+                                 window=window, block_t=bt, max_nb=max_nb)
         jj = jnp.maximum(jnp.minimum(j, nb - 1), 0)  # past the walk: no DMA
-        if window is not None:
-            jj = _ring_block(first, jj, max_nb)
+        jj = _ring_block(first, jj, max_nb)
         return (layer_ref[0], b, jj, 0)
 
     def row_spec(width):  # a slot's query rows, or what they come to
@@ -710,13 +724,10 @@ def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
     def kv_spec(width):
         return pl.BlockSpec((None, None, cols, width), kv_index)
 
-    operands, in_specs = [qm], [row_spec(lanes)]
+    in_specs = [row_spec(lanes)]
     if sink is not None:
-        # a float32 a query row; the pad rows' is 0 (sliced off below)
-        operands.append(jnp.pad(sink.astype(jnp.float32),
-                                (0, nq - nh)).reshape(nq, 1))
         in_specs.append(pl.BlockSpec((nq, 1), lambda b, j, *_: (0, 0)))
-    out = pl.pallas_call(
+    return own(pl.pallas_call(
         functools.partial(_stacked_kernel, scale=scale, block_t=bt,
                           rows=rows, pg=pg, max_nb=max_nb, steps=steps,
                           window=window, sink=sink is not None),
@@ -733,11 +744,177 @@ def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        name=("flash_decode_attention" if window is None
-              else "flash_decode_ring") + ("" if sink is None else "_sink"),
-    )(*prefetch, *operands, k.reshape(nl, B, T * rows, lanes),
-      v.reshape(nl, B, T * rows, lanes_v))
-    out = out[:, :nh].reshape(B, 1, rows, pg, lanes_v)
-    if pack > 1:
-        out = _own_lanes(out, pack)
-    return out.reshape(B, 1, nh, lanes_v // pack)
+        name="flash_decode_ring" + ("" if sink is None else "_sink"),
+    )(*prefetch, *operands()))
+
+
+# --------------------------------------------------------------------------- #
+# the dense form: a slot a grid step, its live blocks walked inside the kernel
+# --------------------------------------------------------------------------- #
+
+
+def _dense_parts(block_t: int, rows: int) -> int:
+    """Pieces a K block of the dense form is copied in: four, or two, where
+    a piece is whole tokens and whole bfloat16 tiles of 16 rows, else one."""
+    return next((n for n in (4, 2) if block_t % n == 0
+                 and block_t // n * rows % _BF16_ROWS == 0), 1)
+
+
+def _dense_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg, max_nb,
+                  parts, sink):
+    """Grid step ``b`` of the dense form: slot ``b``'s live K and V blocks,
+    ``_stacked_blocks(lengths[b])`` of them and no other, in
+    ``_stacked_kernel``'s arithmetic (a block as a plain matrix, every head
+    against every (token, cache row) pair, a head keeps its own row's), so
+    what a call pays follows the blocks that are live: a slot with two of
+    twelve runs two rounds of the loop, a free slot none.
+
+    K and V stay in HBM and a block comes into one of two VMEM buffers a
+    leaf by a copy of this kernel's own: while block ``j`` is scored, block
+    ``j + 1`` is in flight, and while a slot's last block is scored, the next
+    slot's first (a free slot hands the request on), so only the call's
+    first block is waited for with nothing to do. ``turn_ref`` carries the
+    buffer the next block goes to from one grid step to the next; the tile of
+    token offsets depends on the shapes alone and is filled at the call's
+    first step."""
+    q_ref, refs = refs[0], refs[1:]
+    sink_ref, refs = (refs[0], refs[1:]) if sink else (None, refs)
+    k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, own_ref, turn_ref = refs
+    b = pl.program_id(0)
+    last_slot = pl.num_programs(0) - 1
+    layer = layer_ref[0]
+    L = len_ref[b]
+    nb = _stacked_blocks(L, block_t, max_nb)
+    after = jnp.minimum(b + 1, last_slot)
+    # is there a first block to ask for when this slot's walk ends
+    follows = (b < last_slot) & (len_ref[after] > 0)
+    nq, cols = own_ref.shape
+
+    piece = cols // parts  # rows of K a copy moves
+
+    def copies(leaf, slot, blk, buf, act):
+        """``act`` on the copy of each piece of block ``blk`` of ``slot`` that
+        holds a live token (the first always does), into buffer ``buf`` of
+        ``leaf``: ``_dense_parts`` pieces a block, so a slot's last block
+        crosses the HBM up to its length's piece and not whole."""
+        hbm, vmem = ((k_hbm, k_buf), (v_hbm, v_buf))[leaf]
+        live = jnp.minimum(len_ref[slot] - blk * block_t, block_t) * rows
+        for i in range(parts):
+            copy = pltpu.make_async_copy(
+                hbm.at[layer, slot, pl.ds(blk * cols + i * piece, piece)],
+                vmem.at[buf, pl.ds(i * piece, piece)], sem.at[leaf, buf])
+            if i == 0:
+                act(copy)
+            else:
+                pl.when(live > i * piece)(functools.partial(act, copy))
+
+    def fetch(slot, blk, buf):
+        for leaf in (0, 1):
+            copies(leaf, slot, blk, buf, lambda copy: copy.start())
+
+    def wait(leaf, blk, buf):
+        copies(leaf, b, blk, buf, lambda copy: copy.wait())
+
+    @pl.when(b == 0)
+    def _():
+        head_row = lax.broadcasted_iota(jnp.int32, (nq, cols), 0) // pg
+        col = lax.broadcasted_iota(jnp.int32, (nq, cols), 1)
+        own_ref[...] = jnp.where(col % rows == head_row, col // rows,
+                                 jnp.iinfo(jnp.int32).max)
+        turn_ref[0] = 0
+        if parts > 1:
+            # a piece never copied is masked, but its V rows still meet a
+            # probability of exact zero: they must be numbers
+            v_buf[...] = jnp.zeros_like(v_buf)
+
+        @pl.when(nb > 0)
+        def _():
+            fetch(0, 0, 0)
+
+    turn = turn_ref[0]
+    q = q_ref[...]
+
+    def score(j, carry):
+        m, l, acc = carry
+        buf = (turn + j) & 1
+        ends = j == nb - 1
+
+        @pl.when(jnp.logical_not(ends) | follows)
+        def _():
+            fetch(jnp.where(ends, after, b), jnp.where(ends, 0, j + 1),
+                  1 - buf)
+
+        wait(0, j, buf)
+        s = _dot_nt(q, k_buf[buf])
+        if scale is not None:
+            s = s * scale
+        # token j * block_t + own is visible iff it is below the length
+        s = jnp.where(own_ref[...] < L - j * block_t, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        # every walked block holds a visible key for every head (token
+        # j * block_t of its own row), so m_new is finite and a masked
+        # score's exp underflows to an exact zero
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        wait(1, j, buf)
+        acc = acc * alpha + jnp.dot(p.astype(v_buf.dtype), v_buf[buf],
+                                    preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    if sink:
+        m0, l0 = sink_ref[...], jnp.ones((nq, 1), jnp.float32)
+    else:
+        m0 = jnp.full((nq, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((nq, 1), jnp.float32)
+    _, l, acc = lax.fori_loop(
+        0, nb, score, (m0, l0, jnp.zeros(o_ref.shape, jnp.float32)))
+
+    @pl.when((nb == 0) & follows)  # a free slot hands the request on
+    def _():
+        fetch(after, 0, turn)
+
+    turn_ref[0] = (turn + nb) & 1
+    out = acc / jnp.where(l > 0, l, 1.0)
+    o_ref[...] = jnp.where(l > 0, out, 0.0).astype(o_ref.dtype)
+
+
+def _dense_call(lengths, layer, operands, *, scale, block_t, rows, pg,
+                interpret):
+    """The dense form's ``pallas_call``: ``operands`` are the query rows
+    ``[B, nq, lanes]``, a sink a row ``[nq, 1]`` or nothing, and the K and V
+    leaves as ``[L, B, T * rows, lanes]``, which stay where they are
+    (``pl.ANY``: the kernel copies what it walks); returns ``[B, nq,
+    lanes_v]``. The grid is the slots, in order on one core: a step leaves
+    the next one's first block in flight."""
+    qm, k, v = operands[0], operands[-2], operands[-1]
+    (B, nq, lanes), lanes_v = qm.shape, v.shape[-1]
+    cols, sink = block_t * rows, len(operands) == 4
+
+    def row_spec(width):  # a slot's query rows, or what they come to
+        return pl.BlockSpec((None, nq, width), lambda b, *_: (b, 0, 0))
+
+    in_specs = [row_spec(lanes)]
+    if sink:
+        in_specs.append(pl.BlockSpec((nq, 1), lambda b, *_: (0, 0)))
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+    return pl.pallas_call(
+        functools.partial(_dense_kernel, scale=scale, block_t=block_t,
+                          rows=rows, pg=pg, max_nb=k.shape[2] // cols,
+                          parts=_dense_parts(block_t, rows), sink=sink),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=in_specs,
+            out_specs=row_spec(lanes_v),
+            scratch_shapes=[pltpu.VMEM((2, cols, lanes), k.dtype),
+                            pltpu.VMEM((2, cols, lanes_v), v.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.VMEM((nq, cols), jnp.int32),
+                            pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((B, nq, lanes_v), qm.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="flash_decode_attention" + ("_sink" if sink else ""),
+    )(lengths, layer, *operands)

@@ -177,10 +177,16 @@ def _decode(layout, b, s):
          tables, tables, lens]
 
 
-# the carried K/V leaves of the two Llama serving cells (BENCHMARK.json):
-# (layers, slots, window, rows, lanes), query heads, head size
+# the carried K/V leaves of the two Llama serving cells, of the SDAR cell and
+# of the Solar cell (BENCHMARK.json): (layers, slots, window, rows, lanes),
+# query heads, head size
 STACKED_LEAVES = {"smollm": ((24, 4, 2048, 16, 128), 32, 64),
-                  "mistral": ((16, 8, 2048, 8, 128), 32, 128)}
+                  "mistral": ((16, 8, 2048, 8, 128), 32, 128),
+                  # the two cells with the most slots: SDAR's round (a block
+                  # of 4 positions beside a kv head's 8 query heads: 128 query
+                  # rows a slot) and Solar's two GQA layers
+                  "sdar": ((12, 32, 12288, 4, 128), 128, 128),
+                  "solar": ((2, 64, 8192, 8, 128), 64, 128)}
 
 
 def _decode_stacked(cell):
@@ -215,13 +221,15 @@ MIMO_LEAVES = {
 }
 
 
-def _decode_mimo(kind):
+def _decode_mimo(kind, sink=None):
     """``mimo_v2``'s decode attend as the block calls it: K rows wider than
-    V rows, and for the rings a window and a sink a query head."""
+    V rows, and for the rings a window and a sink a query head (``sink``
+    given: with or without it whatever the kind, as ``decode_attend`` hands
+    a full layer's on)."""
     from picotron_tpu.ops.pallas import decode_attention as da
 
     k_leaf, v_leaf, window = MIMO_LEAVES[kind]
-    slots = k_leaf[1]
+    slots, with_sink = k_leaf[1], bool(window) if sink is None else sink
 
     def attend(q, k, v, pos, layer, sink):
         # blocks of 512 tokens, the largest divisor under 1 MiB of K, and
@@ -231,7 +239,7 @@ def _decode_mimo(kind):
                 "ring": da._ring_block_rows(T, row_bytes, 128) == 128}[kind]
         return flash_decode_stacked(
             q, k[:, :, :, None], v[:, :, :, None], pos + 1, 192 ** -0.5,
-            layer, window=window or None, sink=sink if window else None)
+            layer, window=window or None, sink=sink if with_sink else None)
 
     return attend, [((slots, 1, 64, 192), BF16), (k_leaf, BF16),
                     (v_leaf, BF16), ((slots,), I32), ((), I32),
@@ -295,6 +303,7 @@ CASES = {
        for cell in STACKED_LEAVES},
     "decode_ring_trinity": _decode_ring,
     "decode_full_mimo": lambda: _decode_mimo("full"),
+    "decode_full_sink_mimo": lambda: _decode_mimo("full", sink=True),
     "decode_ring_mimo": lambda: _decode_mimo("ring"),
     "quant_matmul_up": lambda: _quant(8, HID, FFN),
     "quant_matmul_down": lambda: _quant(8, FFN, HID),
@@ -590,6 +599,33 @@ def test_serving_program_leaves_cache_in_place(prog, layout, geometry, topo,
               and " parameter(" in l]
     assert len(params) == 2, params
     assert all("{4,3,2,1,0" in l for l in params), params
+
+
+@pytest.mark.parametrize("geometry", sorted(GEOMETRY))
+def test_decode_step_holds_one_op_of_the_readers_name_a_layer(
+        geometry, topo, one_chip, monkeypatch):
+    """The five dense-form roofline readers (``benchmarks/layer_metrics/
+    kernels.flash_decode_roofline*.py``, ``.block_decode_roofline.sdar``,
+    ``.full_decode_roofline.mimo``) divide a layer's live K/V bytes by the
+    mean device time of the ops whose name ends in ``flash_decode_attention``:
+    the decode block's compiled program holds exactly one instruction of
+    that name, a custom call in the body of the loop over the layers, and the
+    attend is no second kernel beside it."""
+    from picotron_tpu.inference import kv_cache
+
+    monkeypatch.setattr(kv_cache, "on_tpu", lambda: True)
+    compiled, _ = _serving_program(topo, "decode_block", "kernel", geometry)
+    comps = _computations(compiled.as_text())
+    named = [(c, n, op) for c, ins in comps.items() for n, _, op, _ in ins
+             if re.sub(r"[.\d]+$", "", n).endswith("flash_decode_attention")]
+    assert len(named) == 1 and named[0][2] == "custom-call", named
+    kernels = [n for ins in comps.values() for n, _, op, rest in ins
+               if op == "custom-call" and "\"tpu_custom_call\"" in rest]
+    assert kernels == [named[0][1]], kernels
+    bodies = [rest for ins in comps.values() for _, _, op, rest in ins
+              if op == "while"]
+    assert any(f"body=%{named[0][0]}" in rest for rest in bodies), \
+        (named, [b[-120:] for b in bodies])
 
 
 # --------------------------------------------------------------------------- #

@@ -17,6 +17,9 @@ generations — the wiring proof that ``attend_impl`` reaches all three call
 sites.
 """
 
+import os
+import re
+
 import numpy as np
 import pytest
 
@@ -547,6 +550,100 @@ def test_stacked_kernel_ignores_stale_rows_and_other_layers():
     junk = lambda x: jnp.where(stale[None], 1e4, x).at[0].set(-1e4).at[
         2].set(1e4).astype(x.dtype)
     np.testing.assert_array_equal(run(k, v), run(junk(k), junk(v)))
+
+
+# the dense form's walk inside the kernel (PR 63), over a strip of six blocks
+# of 32 tokens (copied in four pieces of 8 tokens where a token is two rows
+# or more, in two where it is one): the longest slot first and last, free
+# slots between live ones, and about a block's edge one token, one short of a
+# block, a block, one over, a last block that ends in its first, second and
+# third piece, three blocks, and a length past the strip (clamped)
+WALK_T, WALK_BLOCK = 192, 32
+WALK_LENGTHS = [192, 0, 1, 31, 32, 0, 33, 40, 41, 83, 96, 200, 0, 192]
+# (cache rows, kv heads a row, query heads a kv head, K head, V head, sink)
+WALK_CASES = {
+    "p1_g1": (2, 1, 1, 128, 128, False), "p2_g1": (2, 2, 1, 64, 64, False),
+    "p1_g4": (2, 1, 4, 128, 128, False), "p2_g4": (2, 2, 4, 64, 64, False),
+    # SDAR's call at toy size: a block of 4 positions beside the 8 query
+    # heads of a kv head, 128 query rows a slot against 4 cache rows
+    "sdar_128_rows": (4, 1, 32, 128, 128, False),
+    "sink": (2, 1, 4, 128, 128, True),
+    # MiMo's full layers: a row's four heads merged, K wider than V
+    "narrow_v": (1, 4, 4, 192, 128, False),
+    "narrow_v_sink": (1, 4, 4, 192, 128, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+def test_dense_form_walks_live_blocks_and_keeps_the_parents_bits(case):
+    """The dense form against the dense answer on the layer's block, and bit
+    for bit against the kernel it replaced (``parent_dense_decode``: a grid
+    step a block of the strip, live or not), whose blocks, order and
+    arithmetic it keeps: every length of ``WALK_LENGTHS`` in one call, so a
+    slot's first block is asked for by the slot before it, live or free."""
+    from parent_dense_decode import flash_decode_stacked as parent
+    from picotron_tpu.models import afmoe
+
+    rows, p, g, D, Dv, with_sink = WALK_CASES[case]
+    rng = np.random.default_rng(sum(map(ord, case)))
+    B, nh = len(WALK_LENGTHS), rows * p * g
+    bf16 = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+    q = bf16(B, 1, nh, D)
+    k, v = bf16(2, B, WALK_T, rows, p * D), bf16(2, B, WALK_T, rows, p * Dv)
+    sink = jnp.asarray(2.0 + rng.normal(size=nh), jnp.float32) \
+        if with_sink else None
+    lengths = jnp.asarray(WALK_LENGTHS, jnp.int32)
+    got = jax.jit(lambda *a: flash_decode_stacked(
+        *a, D ** -0.5, 1, block_t=WALK_BLOCK, interpret=True,
+        sink=sink))(q, k, v, lengths)
+    assert got.shape == (B, 1, nh, Dv) and got.dtype == jnp.bfloat16
+    k4 = k[1].reshape(B, WALK_T, rows * p, D)
+    v4 = v[1].reshape(B, WALK_T, rows * p, Dv)
+    if sink is None:
+        pad = jnp.pad(v4, ((0, 0),) * 3 + ((0, D - Dv),))
+        want = decode_attention(q, k4, pad, lengths, D ** -0.5)[..., :Dv]
+    else:
+        seen = jnp.arange(WALK_T)[None, :] < lengths[:, None]
+        want = afmoe.masked_attention(q, k4, v4, seen[:, None],
+                                      D ** -0.5, sink)
+    live = np.asarray(lengths) > 0
+    got32, want32 = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    np.testing.assert_allclose(got32[live], want32[live], rtol=2e-2,
+                               atol=2e-2)
+    assert np.all(got32[~live] == 0.0)
+    np.testing.assert_array_equal(got32, np.asarray(parent(
+        q, k, v, lengths, D ** -0.5, 1, block_t=WALK_BLOCK, sink=sink),
+        np.float32))
+
+
+# the sliding layers' rings of the Trinity cell, as
+# tests/test_chip_compile.py::_decode_ring has them
+RING_LEAF, RING_WINDOW, RING_HEADS = (7, 16, 4608, 8, 128), 4096, 48
+RING_LOWERED = os.path.join(os.path.dirname(__file__), "data",
+                            "ring_decode_trinity.lowered.txt")
+
+
+def _ring_lowered(kernel):
+    """The lowered text of ``kernel`` in its ring form at the Trinity
+    cell's shape, interpret mode, source locations stripped."""
+    BF16, I32 = jnp.bfloat16, jnp.int32
+    S = jax.ShapeDtypeStruct
+    text = jax.jit(lambda q, k, v, n, layer: kernel(
+        q, k, v, n, 128 ** -0.5, layer, window=RING_WINDOW,
+        interpret=True)).lower(
+            S((RING_LEAF[1], 1, RING_HEADS, 128), BF16), S(RING_LEAF, BF16),
+            S(RING_LEAF, BF16), S((RING_LEAF[1],), I32), S((), I32)).as_text()
+    return re.sub(r"\s*loc\(.*?\)$|^#loc.*\n", "", text, flags=re.M)
+
+
+def test_ring_form_lowers_to_the_program_it_was():
+    """PR 63 gave the dense form a kernel and a call of its own; the ring
+    form (Trinity's and MiMo's sliding layers) keeps the program it had,
+    operation for operation: its lowered text equals the copy saved from
+    the parent commit (``tests/data``, written by ``_ring_lowered`` over the
+    parent's module)."""
+    with open(RING_LOWERED) as f:
+        assert _ring_lowered(flash_decode_stacked) == f.read()
 
 
 def _routes(monkeypatch, on_tpu):
