@@ -75,7 +75,12 @@ factor of a leaf is read off its own shape (``leaf.shape[-1] //
 head_dim``); this module alone knows the layout, and whatever needs
 ``[.., n_kv_heads, head_dim]`` (the sliced flash decode kernel, the int8
 scales) takes ``unpack_heads``. With ``p == 1`` every function here runs the
-operations it ran before the packed row existed. The paged pool
+operations it ran before the packed row existed. Whole lanes a row are not
+enough to keep a leaf where it lies: a prefill chunk's contractions over one
+slot's strip re-laid both leaves WHOLE into their own orders on entry and
+back on exit, a head a row too (Mistral's chunk, PR 64: four copies of 537
+MB a chunk of any width), so ``cache_write`` holds whatever leaf it writes
+to the row-major layout (``row_major``), whatever ``p`` is. The paged pool
 (paged_kv.py) is not packed.
 """
 
@@ -278,6 +283,11 @@ def cache_write(cache: dict, k_new: jnp.ndarray, v_new: jnp.ndarray,
     int8 caches quantize on write; the scale rows land at the same
     positions in ``k_scale``/``v_scale``. The rows reach the leaf as it
     lies: ``p`` heads a row (``pack_heads``, a reshape of the new rows).
+    The written ``k`` / ``v`` leaf is held to the row-major layout it is
+    resident in (``row_major``), at every ``p`` and in every shape of
+    write: a constraint on the layout alone, which moves no value, so that
+    no program that attends over what it wrote (a prefill chunk's dense
+    contractions above all) has the whole leaf re-laid around its loop.
 
     RAGGED verify (the per-slot spec_len controller): a ``draft_valid``
     [B] int32 entry (spliced per dispatch by engine._verify_impl) caps
@@ -307,13 +317,11 @@ def cache_write(cache: dict, k_new: jnp.ndarray, v_new: jnp.ndarray,
         else:
             vals = new
         p = cache[name].shape[-1] // new.shape[-1]
-        out[name] = write_rows(cache, name, pack_heads(vals, p), pos, layer)
-        if p > 1:
-            # whole lanes a row: hold the carried leaf to the row-major
-            # layout it is resident in. Left free, a prefill chunk's
-            # contractions pull the whole leaf head-major on entry and
-            # push it back on exit (two copies of the cache a chunk)
-            out[name] = row_major(out[name])
+        # held where it lies: left free, a prefill chunk's contractions
+        # re-lay the WHOLE leaf on entry (K tokens-minor, V heads-major) and
+        # back on exit, to write and read one slot's strip
+        out[name] = row_major(
+            write_rows(cache, name, pack_heads(vals, p), pos, layer))
     return out
 
 

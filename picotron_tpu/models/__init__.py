@@ -136,7 +136,7 @@ def carry_state(cache, leaves, names, like, row, pos, h, mixer) -> tuple:
     # chunk's contractions must be held to that: left free they pull the
     # whole state leaf into their own order on entry and push it back on
     # exit (two copies of 2.4 GB a chunk in Granite's cell; as
-    # ``kv_cache.cache_write`` holds a packed K/V leaf)
+    # ``kv_cache.cache_write`` holds the K/V leaf it writes)
     pin = (lambda x: x) if decode else kv_cache.row_major
     zero = jnp.zeros((), jnp.int32)
     slot = None if decode else jnp.asarray(cache["slot"], jnp.int32)

@@ -217,13 +217,11 @@ def attention(lp, x, cos, sin, m: ModelConfig, cache, pos, layer,
         # one slot's chunk of whole blocks, or every slot's current block
         with jax.named_scope("sdar/attend_chunk" if "slot" in cache
                              else "sdar/attend_block"):
+            # four heads a token are half a register tile: left free, a
+            # chunk's contractions re-lay both leaves whole with the tokens
+            # along the lanes (two copies of 4.5 GB, and the chunk does not
+            # fit); ``cache_write`` holds every leaf it writes row-major
             out = kv_cache.cache_write(cache, k, v, pos, layer)
-            if "slot" in cache:
-                # four heads a token are half a register tile: left free, a
-                # chunk's contractions re-lay both leaves whole with the
-                # tokens along the lanes (two copies of 4.5 GB, and the
-                # chunk does not fit); ``cache_write`` pins packed rows only
-                out.update({n: kv_cache.row_major(out[n]) for n in LEAVES})
             a = kv_cache.attend(q, out, pos + S, scale, layer, impl=impl,
                                 block=Bd)
     return a.reshape(B, S, -1) @ lp["wo"], out

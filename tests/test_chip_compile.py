@@ -483,14 +483,16 @@ GEOMETRY = {"smollm": (HID, HEADS, HEADS, FFN, VOCAB),
             "mistral": (4096, 32, 8, 14336, 32768)}
 
 
-def _serving_program(topo, prog, layout, geometry="smollm", bucket=None):
-    """(lowered-and-compiled ``prog`` of a SERVE_LAYERS-layer engine at
-    one of ``GEOMETRY``'s head geometries on one described chip, the
-    abstract cache it was compiled for). Layout "kernel" is the contiguous
-    cache under ``attend_impl: flash`` (the caller steers ``on_tpu``, so
-    the kernels are compiled and not their interpreter), with a head of
-    1,024 rows: sampling over the whole vocabulary is four fifths of a
-    decode block's compile time and no part of what is read here."""
+def _serving_program(topo, prog, layout, geometry="smollm", bucket=None,
+                     layers=SERVE_LAYERS, slots=SERVE_SLOTS):
+    """(lowered-and-compiled ``prog`` of a ``layers``-layer engine of
+    ``slots`` slots at one of ``GEOMETRY``'s head geometries on one
+    described chip, the abstract cache it was compiled for). Layout
+    "kernel" is the contiguous cache under ``attend_impl: flash`` (the
+    caller steers ``on_tpu``, so the kernels are compiled and not their
+    interpreter), with a head of 1,024 rows: sampling over the whole
+    vocabulary is four fifths of a decode block's compile time and no part
+    of what is read here."""
     from picotron_tpu.config import Config
     from picotron_tpu.inference.engine import InferenceEngine
     from picotron_tpu.models import llama
@@ -502,14 +504,14 @@ def _serving_program(topo, prog, layout, geometry="smollm", bucket=None):
     cfg = Config.from_dict({
         "model": dict(hidden_size=hid, intermediate_size=ffn,
                       num_attention_heads=heads, num_key_value_heads=kv_heads,
-                      vocab_size=vocab, num_hidden_layers=SERVE_LAYERS,
+                      vocab_size=vocab, num_hidden_layers=layers,
                       max_position_embeddings=SEQ, dtype="bfloat16"),
         "inference": {"kv_layout": "paged" if layout == "paged"
                       else "contiguous", "kv_page_len": PAGE,
                       "attend_impl": "flash" if layout == "kernel"
                       else "dense"}})
     mesh = build_topology(1, 1, 1, 1, devices=topo.devices)
-    eng = InferenceEngine(cfg, mesh, slots=SERVE_SLOTS, max_seq_len=SEQ)
+    eng = InferenceEngine(cfg, mesh, slots=slots, max_seq_len=SEQ)
 
     def abstract(tree, specs):
         return jax.tree.map(
@@ -521,7 +523,7 @@ def _serving_program(topo, prog, layout, geometry="smollm", bucket=None):
     cache = abstract(jax.eval_shape(eng._init_cache_jit), eng._cspecs)
     rep = named_shardings(mesh, jax.sharding.PartitionSpec())
     arg = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep)
-    B = SERVE_SLOTS
+    B = slots
     if prog == "prefill":  # the one-shot prefill of one power-of-two bucket
         args = (arg((1, bucket), I32), arg((1,), I32))
         if eng.sample_on_device:
@@ -540,10 +542,25 @@ def _serving_program(topo, prog, layout, geometry="smollm", bucket=None):
     return jitted.lower(params, cache, *args).compile(), cache
 
 
+def _assert_leaves_lie_row_major_uncopied(text: str, kv: tuple):
+    """The program takes K and V (leaves of shape ``kv``) row-major and no
+    instruction of it, inside a loop or outside, copies a whole leaf."""
+    leaf = "bf16\\[" + ",".join(map(str, kv)) + "\\]"
+    lines = text.splitlines()
+    copies = [l.strip()[:160] for l in lines
+              if re.search(rf"= {leaf}\S* copy\(", l)]
+    assert not copies, "\n".join(copies)
+    params = [l for l in lines if re.search(rf"cache__[kv]__\S* = {leaf}", l)
+              and " parameter(" in l]
+    assert len(params) == 2, params
+    assert all("{4,3,2,1,0" in l for l in params), params
+
+
 @pytest.mark.parametrize("prog,layout,geometry", [
     ("decode_block", "contiguous", "smollm"),
     ("prefill_chunk", "contiguous", "smollm"),
     ("decode_block", "contiguous", "mistral"),
+    ("prefill_chunk", "contiguous", "mistral"),
     ("decode_block", "kernel", "smollm"), ("decode_block", "kernel", "mistral"),
     ("decode_block", "paged", "smollm"), ("prefill_chunk", "paged", "smollm")])
 def test_serving_program_leaves_cache_in_place(prog, layout, geometry, topo,
@@ -554,7 +571,9 @@ def test_serving_program_leaves_cache_in_place(prog, layout, geometry, topo,
     two to a row (with a head a row the resident leaf was laid out tokens
     minor-most and converted on the program's entry and exit: ``copy.18`` /
     ``.19`` / ``.25`` / ``.26`` of PR 30's trace, outside every loop), heads
-    of 128 one (``pack_factor`` 1: the leaf and the path they always had).
+    of 128 one (``pack_factor`` 1: the leaf they always had, which
+    ``cache_write`` holds row-major like the packed one since PR 64: left
+    free, Mistral's chunk re-laid both leaves on entry and on exit).
     With the kernel forced ("kernel": what ``attend_impl: auto`` runs on a
     TPU) the decode step hands the stacked leaves to ``flash_decode_stacked``
     as they lie: one custom call a layer, and nothing in front of it that
@@ -581,8 +600,9 @@ def test_serving_program_leaves_cache_in_place(prog, layout, geometry, topo,
             "1.5 lane-padded pools"
     elif prog == "decode_block":
         limit, what = kv_bytes, "one cache"
-    else:  # 134 MB of it the chunk's float32 scores, 512 x 2048 x 32
-        limit, what = 1.5 * kv_bytes, "1.5 caches"
+    else:  # the chunk's float32 scores, 512 x 2048 x 32 heads: 134 MB
+        limit, what = 4 * 512 * SEQ * GEOMETRY[geometry][1] \
+            + 0.5 * kv_bytes, "the chunk's scores and half a cache"
     assert temp < limit, (
         f"{prog}/{layout}: {temp / 1e6:.0f} MB of temporaries against "
         f"{what}, {limit / 1e6:.0f} MB")
@@ -590,15 +610,35 @@ def test_serving_program_leaves_cache_in_place(prog, layout, geometry, topo,
         return
     # 32 heads of 64 two to a row; 8 heads of 128 as they always lay
     assert kv[-2:] == {"smollm": (16, 128), "mistral": (8, 128)}[geometry]
-    leaf = "bf16\\[" + ",".join(map(str, kv)) + "\\]"
-    lines = text.splitlines()
-    copies = [l.strip()[:160] for l in lines
-              if re.search(rf"= {leaf}\S* copy\(", l)]
-    assert not copies, "\n".join(copies)
-    params = [l for l in lines if re.search(rf"cache__[kv]__\S* = {leaf}", l)
-              and " parameter(" in l]
-    assert len(params) == 2, params
-    assert all("{4,3,2,1,0" in l for l in params), params
+    _assert_leaves_lie_row_major_uncopied(text, kv)
+
+
+@pytest.mark.parametrize("prog,layout", [("prefill_chunk", "contiguous"),
+                                         ("decode_block", "kernel")])
+def test_mistral_at_the_cells_size_copies_no_leaf(prog, layout, topo,
+                                                  one_chip, monkeypatch):
+    """The two programs of Mistral's serving cells at the cells' own cache
+    (16 layers x 8 slots x 2,048: ``bf16[16,8,2048,8,128]``, 537 MB a leaf)
+    under ``attend_impl: auto`` as a TPU takes it: the chunk attends densely,
+    the decode block through the stacked kernel. Neither holds an instruction
+    that copies a leaf, inside a loop or outside. PR 63's chunk held four in
+    its ENTRY computation (K re-laid tokens-minor and V heads-major on entry,
+    both back on exit: 4.3 GB of HBM traffic a chunk of whatever width, 1,074
+    MB of temporaries); what is left of its temporaries is the chunk's
+    float32 scores (135 MB)."""
+    from picotron_tpu.inference import kv_cache
+
+    if layout == "kernel":
+        monkeypatch.setattr(kv_cache, "on_tpu", lambda: True)
+    compiled, cache = _serving_program(topo, prog, layout, "mistral",
+                                       layers=16, slots=8)
+    text, kv = compiled.as_text(), cache["k"].shape
+    assert kv == (16, 8, SEQ, 8, 128)
+    assert not _cache_movers(text, kv)
+    _assert_leaves_lie_row_major_uncopied(text, kv)
+    if prog == "prefill_chunk":
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 0.3e9, f"{temp / 1e6:.0f} MB of temporaries"
 
 
 @pytest.mark.parametrize("geometry", sorted(GEOMETRY))
@@ -1320,8 +1360,8 @@ def test_sdar_round_of_blocks_and_chunk_leave_the_cache_in_place(
     ``while`` with the cache in its carry and no copy of a leaf. The chunk:
     four K/V heads a token are half a register tile, and left free the
     chunk's contractions re-laid both leaves with the tokens along the lanes
-    (two copies of 4.5 GB: the chunk did not fit); ``sdar_moe.attention``
-    pins them row-major. The programs' temporaries leave the 12.09 GB
+    (two copies of 4.5 GB: the chunk did not fit); ``kv_cache.cache_write``
+    holds them row-major. The programs' temporaries leave the 12.09 GB
     resident room on the chip."""
     from picotron_tpu.inference import kv_cache
 
