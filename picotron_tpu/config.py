@@ -190,7 +190,12 @@ class ModelConfig:
     # delta rule with a decay for every key channel, ``gqa_interval`` of
     # them between two gated NoPE GQA layers by ``gqa_layers``, routed and
     # shared experts behind each — models/solar_open2.py, serving path only;
-    # its fields are the last). The fields below are the
+    # its fields are behind Nemotron's) or "sdar_moe" (generation by
+    # diffusion over blocks on Qwen3-MoE-shaped layers — models/sdar_moe.py)
+    # or "falcon_h1" (Falcon-H1's parallel hybrid: a Mamba-2 mixer and GQA
+    # attention side by side in every layer, muP multipliers on every
+    # projection — models/falcon_h1.py, serving path only; its fields are
+    # the last). The fields below are the
     # published ``config.json`` keys of the DeepSeek block, by their own
     # names, and are read by no other block (but ``num_experts_per_tok``,
     # ``ep_size`` and ``ep_rank``, which both expert blocks read).
@@ -399,6 +404,32 @@ class ModelConfig:
     remasking: str = "low_confidence_dynamic"
     confidence_threshold: float = 0.9
     mask_token_id: int = 0
+    # "falcon_h1" (Falcon-H1): a parallel hybrid, a Mamba-2 mixer and GQA
+    # attention side by side on one normed input in every layer and a SwiGLU
+    # behind them. The published keys of that block, beside ``head_dim``,
+    # ``embedding_multiplier``, ``rope_scaling``, ``tie_word_embeddings`` and
+    # the ``mamba_*`` above (``mamba_expand`` is read by no forward of it:
+    # the mixer's width is ``mamba_d_ssm`` = ``mamba_n_heads`` x
+    # ``mamba_d_head``, given, and not a multiple of ``hidden_size``).
+    # ``mamba_rms_norm``: the gated norm's mean square over each of
+    # ``mamba_n_groups`` groups' channels; ``mamba_norm_before_gate`` false:
+    # gate first. The multipliers are scalars of the forward pass, never
+    # folded into a weight: ``key_multiplier`` on ``k``,
+    # ``attention_in/out_multiplier`` and ``ssm_in/out_multiplier`` on either
+    # side of the two mixers, ``ssm_multipliers`` (z, x, B, C, dt) on the
+    # columns of ``in_proj``'s output, ``mlp_multipliers`` (gate, down),
+    # ``lm_head_multiplier`` on the logits.
+    mamba_d_ssm: int = 0
+    mamba_rms_norm: bool = True
+    mamba_norm_before_gate: bool = False
+    key_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: Optional[list] = None
+    mlp_multipliers: Optional[list] = None
+    lm_head_multiplier: float = 1.0
     # the published ``head_dim`` where it is not hidden_size / heads (afmoe:
     # 128 of 3072 / 48); 0: derived, and it follows ``hidden_size``
     head_dim: int = 0

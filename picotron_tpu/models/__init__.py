@@ -103,7 +103,7 @@ def live_rows(cache, live, h):
 
 
 # What the blocks that keep a recurrent state beside K/V (``granite_hybrid``,
-# ``nemotron_h``, ``minicpm_sala``, ``solar_open2``) share: the way from the
+# ``nemotron_h``, ``minicpm_sala``, ``solar_open2``, ``falcon_h1``) share: the way from the
 # state leaves to a mixer and back, and the three counters of such a layer.
 
 
@@ -214,6 +214,7 @@ BLOCKS = {
     "nemotron_h": "nemotron_h",
     "solar_open2": "solar_open2",
     "sdar_moe": "sdar_moe",
+    "falcon_h1": "falcon_h1",
 }
 
 

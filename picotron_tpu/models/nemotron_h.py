@@ -71,6 +71,7 @@ from picotron_tpu.inference import kv_cache
 from picotron_tpu.models import (STATS, carry_state, leaf_row, live_rows,
                                  llama, served_whole, state_counts, support)
 from picotron_tpu.models import experts as expert_share
+from picotron_tpu.models import mamba2
 from picotron_tpu.models.llama import param_bytes  # noqa: F401 - the seam
 from picotron_tpu.ops.rmsnorm import rms_norm
 from picotron_tpu.ops.ssm import ssm_scan, ssm_step
@@ -241,8 +242,7 @@ def init_params(key, m: ModelConfig, pp_size: int = 1,
     if pp_size != 1 or interleave != 1:
         raise ValueError("nemotron_h is served on one stage (pp_size 1)")
     dt = jnp.dtype(m.dtype)
-    H, nh, K = m.hidden_size, m.mamba_num_heads, m.conv_kernel
-    W, Di = conv_width(m), d_inner(m)
+    H, W, Di = m.hidden_size, conv_width(m), d_inner(m)
 
     def uniform(k, shape, fan_in, gain=1.0, dtype=dt):
         bound = gain * math.sqrt(1.0 / fan_in)
@@ -264,20 +264,10 @@ def init_params(key, m: ModelConfig, pp_size: int = 1,
             out["router_bias"] = jax.random.uniform(
                 ks[0], (n, router_width(m)), F32, -ROUTER_BIAS, ROUTER_BIAS)
         if kind == "M":
-            # B and C louder (BC_GAIN): their columns of in_proj
-            cols = jnp.arange(out["in_proj"].shape[-1])
-            bc = (cols >= 2 * Di) & (cols < Di + W)
-            out["in_proj"] = out["in_proj"] * jnp.where(
-                bc, BC_GAIN, 1.0).astype(dt)
+            out["in_proj"] = mamba2.louder_bc(out["in_proj"], Di, W, BC_GAIN)
             out["gate_norm"] = jnp.ones((n, Di), dt)
-            out["conv_w"] = uniform(ks[0], (n, W, K), K)
-            out["conv_b"] = uniform(ks[1], (n, W), 1, 0.1)
-            out["A_log"] = jnp.log(jax.random.uniform(
-                ks[2], (n, nh), F32, 1.0, 16.0))
-            step = jnp.exp(jax.random.uniform(
-                ks[3], (n, nh), F32, math.log(1e-3), math.log(1e-1)))
-            out["dt_bias"] = step + jnp.log(-jnp.expm1(-step))
-            out["D"] = jnp.ones((n, nh), F32)
+            out.update(mamba2.draw(ks, n, heads=m.mamba_num_heads, width=W,
+                                   d_conv=m.conv_kernel, dtype=dt))
         return out
 
     params = {
@@ -348,51 +338,14 @@ def init_cache(m: ModelConfig, slots: int, max_seq_len: int, dtype=None,
 
 def mamba_mixer(lp, x, conv_in, ssm_in, live, m: ModelConfig,
                 one_step: tuple) -> tuple:
-    """The mixer on the normed stream ``x`` [B, S, H] from the conv's last
-    inputs ``conv_in`` [B, conv_kernel - 1, width] and the state ``ssm_in``:
-    (output [B, S, H], the conv's last inputs and the state behind the last
-    ``live`` row). ``live`` [B, S] marks the real rows, a leading run of
-    each sequence. ``one_step`` is empty, or on a decode step ``(row,)``:
-    ``ssm_in`` is then the whole stacked leaf and so is the state returned,
-    that row of it advanced where it lies (``ops/ssm.py::ssm_step``)."""
-    B, S, _ = x.shape
-    nh, hd, N, K, G = (m.mamba_num_heads, m.mamba_head_dim, m.ssm_state_size,
-                       m.conv_kernel, m.n_groups)
-    Di = nh * hd
-    with jax.named_scope("ssm_proj"):
-        proj = x @ lp["in_proj"]
-        z, u, dt = proj[..., :Di], proj[..., Di:-nh], proj[..., -nh:]
-    with jax.named_scope("ssm_conv"):
-        padded = jnp.concatenate([conv_in.astype(u.dtype), u], axis=1)
-        w = lp["conv_w"].astype(F32)
-        conv = lp["conv_b"].astype(F32) + sum(
-            padded[:, j:j + S].astype(F32) * w[:, j] for j in range(K))
-        u = jax.nn.silu(conv).astype(x.dtype)
-        # the last inputs behind the last live row: rows n .. n + K - 2 of
-        # the padded block, n the live rows (0: the tail stays as it was)
-        at = jnp.sum(live, axis=1, dtype=jnp.int32)[:, None] \
-            + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
-        conv_out = jnp.take_along_axis(padded, at[:, :, None], axis=1)
-    xs = u[..., :Di].reshape(B, S, nh, hd)
-    Bm = u[..., Di:Di + G * N].reshape(B, S, G, N)
-    Cm = u[..., Di + G * N:].reshape(B, S, G, N)
-    dt = jax.nn.softplus(dt.astype(F32) + lp["dt_bias"]) \
-        * live[..., None].astype(F32)
-    A = -jnp.exp(lp["A_log"])
-    if one_step:
-        with jax.named_scope("nemotron/ssm_step"):
-            y, ssm_out = ssm_step(xs, dt, A, Bm, Cm, ssm_in, *one_step)
-    else:
-        with jax.named_scope("nemotron/ssm_scan"):
-            y, ssm_out = ssm_scan(xs, dt, A, Bm, Cm, ssm_in, m.chunk_size)
-    with jax.named_scope("ssm_gate_out"):
-        y = y + lp["D"][:, None] * xs.astype(F32)
-        y = y.reshape(B, S, Di) * jax.nn.silu(z.astype(F32))
-        # the mean square over each group's channels, not over all of them
-        y = rms_norm(y.reshape(B, S, G, Di // G),
-                     lp["gate_norm"].reshape(G, Di // G), m.rms_norm_eps)
-        out = y.reshape(B, S, Di).astype(x.dtype) @ lp["out_proj"]
-    return out, conv_out, ssm_out
+    """``mamba2.mixer`` at this block's keys: ``B`` and ``C`` a group of
+    heads, the gated norm's mean square over each group's channels."""
+    return mamba2.mixer(
+        lp, x, conv_in, ssm_in, live, one_step, heads=m.mamba_num_heads,
+        d_head=m.mamba_head_dim, d_state=m.ssm_state_size,
+        d_conv=m.conv_kernel, groups=m.n_groups, chunk=m.chunk_size,
+        eps=m.rms_norm_eps, scan=ssm_scan,
+        step=ssm_step, norm=rms_norm, scope="nemotron/")
 
 
 def mamba_sublayer(lp, h, cfg: Config, cache, out: dict, pos, return_kv,

@@ -23,12 +23,27 @@ from jax.experimental import pallas as pl
 def _pick_block(rows: int, h: int, itemsize: int) -> int:
     """Row-block sized so one block is ~512 KB: with Pallas double-buffering
     and the kernel's fp32 temporaries this keeps VMEM well under the 16 MB
-    budget at any hidden size."""
+    budget at any hidden size. Either the whole array, or a power of two of
+    at least 8 rows (whole sublane groups: Mosaic refuses a smaller block
+    that is not the whole array). Where none of those divides the rows the
+    answer does not either, and ``_pad_rows`` makes the rows up to it."""
     want = max(8, (512 * 1024) // max(h * itemsize, 1))
+    # a power of two, so that halving down to a divisor of the rows keeps
+    # whole sublane groups (a width of 5,120 asked for 51 rows, and 64 rows
+    # went down to blocks of one)
+    want = 1 << (want.bit_length() - 1)
     b = min(want, rows)
-    while rows % b:
+    while rows % b and b > 8:
         b //= 2
-    return max(b, 1)
+    return want if rows % b else b
+
+
+def _pad_rows(x2d, br: int):
+    """``x2d`` with zero rows behind it up to a whole number of blocks (a
+    zero row's norm is zero, and it adds nothing to the weight's
+    gradient)."""
+    pad = -x2d.shape[0] % br
+    return jnp.pad(x2d, ((0, pad), (0, 0))) if pad else x2d
 
 
 def _fwd_kernel(x_ref, w_ref, y_ref, *, eps):
@@ -58,17 +73,18 @@ def _bwd_kernel(x_ref, w_ref, dy_ref, dx_ref, dw_ref, *, eps):
 def _run_fwd(x2d, w, eps):
     rows, h = x2d.shape
     br = _pick_block(rows, h, x2d.dtype.itemsize)
+    x2d = _pad_rows(x2d, br)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
         name="rmsnorm_fwd",
-        grid=(rows // br,),
+        grid=(x2d.shape[0] // br,),
         in_specs=[
             pl.BlockSpec((br, h), lambda i: (i, 0)),
             pl.BlockSpec((1, h), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((br, h), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, h), x2d.dtype),
-    )(x2d, w)
+        out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
+    )(x2d, w)[:rows]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -84,10 +100,11 @@ def _bwd_rule(eps, res, dy):
     x2d, w = res
     rows, h = x2d.shape
     br = _pick_block(rows, h, x2d.dtype.itemsize)
+    x2d, dy = _pad_rows(x2d, br), _pad_rows(dy, br)
     dx, dw = pl.pallas_call(
         functools.partial(_bwd_kernel, eps=eps),
         name="rmsnorm_bwd",
-        grid=(rows // br,),
+        grid=(x2d.shape[0] // br,),
         in_specs=[
             pl.BlockSpec((br, h), lambda i: (i, 0)),
             pl.BlockSpec((1, h), lambda i: (0, 0)),
@@ -98,11 +115,11 @@ def _bwd_rule(eps, res, dy):
             pl.BlockSpec((1, h), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((rows, h), x2d.dtype),
+            jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
             jax.ShapeDtypeStruct((1, h), jnp.float32),
         ],
     )(x2d, w, dy)
-    return dx, dw.astype(w.dtype)  # [1, H], the primal w's own shape
+    return dx[:rows], dw.astype(w.dtype)  # [1, H], the primal w's own shape
 
 
 _rms_norm_2d.defvjp(_fwd_rule, _bwd_rule)

@@ -118,6 +118,23 @@ TOYS = {
         total_layers=12, block_length=4, denoising_steps=4,
         remasking="low_confidence_dynamic", confidence_threshold=0.9,
         mask_token_id=255),
+    # five query heads a K/V head, two B/C groups, d_ssm 96 != 2 x hidden 80:
+    # the published ratios; the multipliers as published
+    "falcon_h1": dict(
+        name="toy-falcon", model_type="falcon_h1", num_hidden_layers=3,
+        hidden_size=80, num_attention_heads=10, num_key_value_heads=2,
+        head_dim=16, intermediate_size=96, vocab_size=256, rms_norm_eps=1e-5,
+        rope_theta=1e11, max_position_embeddings=256, dtype="float32",
+        mamba_n_heads=8, mamba_d_head=12, mamba_d_ssm=96, mamba_d_state=16,
+        mamba_n_groups=2, mamba_d_conv=4, mamba_chunk_size=8, mamba_expand=2,
+        embedding_multiplier=5.656854249492381,
+        key_multiplier=0.011048543456039804, attention_in_multiplier=1.0,
+        attention_out_multiplier=0.0375, ssm_in_multiplier=0.25,
+        ssm_out_multiplier=0.08838834764831845,
+        ssm_multipliers=[0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                         0.3535533905932738],
+        mlp_multipliers=[0.1767766952966369, 0.011160714285714284],
+        lm_head_multiplier=0.0078125),
 }
 
 

@@ -142,9 +142,16 @@ def _rms_fwd():
     return rms_norm_pallas, [((4 * SEQ, HID), BF16), ((HID,), BF16)]
 
 
-def _rms_bwd():
+def _rms_fwd_rows(rows, hidden):
+    """The serving head's norm over a decode step's rows, one a slot, at a
+    width whose 512 KB block is not a power of two of rows (5,120: 51)."""
+    return rms_norm_pallas, [((rows, hidden), BF16), ((hidden,), BF16)]
+
+
+def _rms_bwd(rows=4 * SEQ, hidden=HID):
     return jax.grad(lambda x, w: _sum32(rms_norm_pallas(x, w)),
-                    argnums=(0, 1)), [((4 * SEQ, HID), BF16), ((HID,), BF16)]
+                    argnums=(0, 1)), [((rows, hidden), BF16),
+                                      ((hidden,), BF16)]
 
 
 def _decode(layout, b, s):
@@ -186,7 +193,10 @@ STACKED_LEAVES = {"smollm": ((24, 4, 2048, 16, 128), 32, 64),
                   # of 4 positions beside a kv head's 8 query heads: 128 query
                   # rows a slot) and Solar's two GQA layers
                   "sdar": ((12, 32, 12288, 4, 128), 128, 128),
-                  "solar": ((2, 64, 8192, 8, 128), 64, 128)}
+                  "solar": ((2, 64, 8192, 8, 128), 64, 128),
+                  # five query heads a K/V head: a score tile that is not a
+                  # whole sublane group
+                  "falcon": ((4, 64, 6144, 4, 128), 20, 128)}
 
 
 def _decode_stacked(cell):
@@ -253,8 +263,10 @@ def _quant(m, k, n):
 
 # the three recurrent cells' stacked float32 state leaves and one slot's B/C
 # rows: Nemotron's eight groups of sixteen heads, Granite's one row for all
-# heads, SALA's row a head (k and q)
+# heads, SALA's row a head (k and q), Falcon-H1's two groups of sixteen heads
+# of 128 x 256 (four times the others' state a head)
 SSM_LEAVES = {"nemotron": ((5, 128, 128, 64, 128), (8, 128)),
+              "falcon": ((4, 64, 32, 128, 256), (2, 256)),
               "granite": ((9, 64, 128, 64, 128), (128,)),
               "sala": ((9, 8, 32, 128, 128), (32, 128))}
 
@@ -295,6 +307,13 @@ CASES = {
        for cell in CELL_SHAPES for bwd in (False, True)},
     "rmsnorm_fwd": _rms_fwd,
     "rmsnorm_bwd": _rms_bwd,
+    "rmsnorm_fwd_falcon_decode": lambda: _rms_fwd_rows(64, 5120),
+    # rows no block of whole sublane groups divides: 20 under a block of 16
+    # (one block would be the whole array, half of one is 4 rows), 100 under
+    # 32; the rows are made up to whole blocks
+    "rmsnorm_fwd_rows_20": lambda: _rms_fwd_rows(20, 16384),
+    "rmsnorm_fwd_rows_100": lambda: _rms_fwd_rows(100, 5120),
+    "rmsnorm_bwd_rows_100": lambda: _rms_bwd(100, 5120),
     **{f"decode_{layout}_{name}":
        (lambda layout=layout, b=b, s=s: _decode(layout, b, s))
        for layout in ("contiguous", "int8", "paged", "hot_bf16")
@@ -1214,6 +1233,49 @@ def test_solar_state_and_kv_stay_in_place(prog, topo, one_chip,
               if re.search(r"= bf16\[(?:1,)?20,(?:4096,1280|1280,4096)\]", l)
               and " parameter(" not in l and "get-tuple-element" not in l]
     assert not sliced, "\n".join(sliced)
+
+
+@pytest.mark.parametrize("prog", ["decode_block", "prefill_chunk"])
+def test_falcon_state_and_kv_of_every_layer_stay_in_place(
+        prog, topo, one_chip, ssm_on_chip, monkeypatch):
+    """The Falcon-H1 cell's programs at its own size (PR 65: 64 slots x
+    6,144 beside 8.79 GB of weights, the whole 261,120-row head among them):
+    every layer keeps a K/V row AND a float32 state row, and all four leaves
+    stay row-major with no instruction copying one; a decode block steps
+    each layer's row of the state through the ``ssm_step`` kernel (32 heads
+    of 128 x 256 in two B/C groups), the leaf its operand and aliased
+    result, and attends through ``flash_decode_attention`` at five query
+    heads a K/V head, one call each a layer in the scanned body; a chunk
+    holds neither; and the programs' temporaries leave the resident 13.09 GB
+    its room in the chip's 15.75."""
+    from picotron_tpu.inference import kv_cache
+    from picotron_tpu.models import llama
+
+    monkeypatch.setattr(kv_cache, "on_tpu", lambda: True)
+    # the head's norm as on a TPU: the Pallas kernel over 64 rows of 5,120
+    monkeypatch.setattr(llama, "on_tpu", lambda: True)
+    compiled = _cell_program(topo, prog, "falcon-h1-34b-l4")
+    text = compiled.as_text()
+    lines = text.splitlines()
+    state = r"f32\[4,64,32,128,256\]"
+    kv = r"bf16\[4,64,6144,4,128\]"
+    _assert_state_steps_in_place(text, prog, state)
+    copies = [l.strip()[:160] for l in lines
+              if re.search(rf"= (?:{state}|{kv})\S* copy\(", l)]
+    assert not copies, "\n".join(copies)
+    for leaf, shape in (("ssm", state), ("k", kv), ("v", kv)):
+        params = [l for l in lines
+                  if re.search(rf"cache__{leaf}__\S* = {shape}", l)
+                  and " parameter(" in l]
+        assert len(params) == 1 and "{4,3,2,1,0" in params[0], params
+    kernels = [l for l in lines
+               if re.search(r"%flash_decode_attention\S* = ", l)]
+    assert len(kernels) == (1 if prog == "decode_block" else 0), kernels
+    assert "rmsnorm_fwd" in text
+    memory = compiled.memory_analysis()
+    assert round(memory.argument_size_in_bytes / 1e9, 2) == 13.09
+    assert memory.temp_size_in_bytes < 1.5e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0e9
 
 
 def test_trinity_decode_block_keeps_the_loop(topo, one_chip, monkeypatch,

@@ -259,8 +259,12 @@ def test_rmsnorm_matches_reference(dtype):
                                dtype == jnp.bfloat16 else 1e-6)
 
 
-def test_rmsnorm_grads_match_reference():
-    x = jax.random.normal(jax.random.PRNGKey(0), (8, 64, 128))
+@pytest.mark.parametrize("rows", [512, 20, 100, 1028])
+def test_rmsnorm_grads_match_reference(rows):
+    """512 rows: whole blocks. 20: one block, the whole array. 100 and 1,028
+    under a block of 1,024: rows no block of 8 or more divides, made up to
+    whole blocks with zero rows."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (rows, 128))
     w = jax.random.normal(jax.random.PRNGKey(1), (128,)) + 1.0
 
     def loss_pallas(x, w):
