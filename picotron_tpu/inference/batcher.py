@@ -293,6 +293,11 @@ class ContinuousBatcher:
             (n, engine.cfg.model.block_length if self._blocks else 1),
             np.int32)
         self._given_n = np.zeros(n, np.int32)
+        # and is handed back its WAITING block: the last block of its last
+        # round, which that round streamed and did not store (the slot's
+        # next round stores it inside its first forward,
+        # ``engine._fused_forward``); -1 throughout: nothing waits
+        self._waiting = np.full_like(self._given, -1)
         self._temp = np.zeros(n, np.float32)
         self._top_k = np.zeros(n, np.int32)
         self._top_p = np.ones(n, np.float32)
@@ -950,6 +955,7 @@ class ContinuousBatcher:
         self._cache = self.engine.release(self._cache, i)
         self._last_tok[i] = 0
         self._given_n[i] = 0
+        self._waiting[i] = -1  # a stream that ended owes its block nothing
         self._temp[i] = 0.0
         self._top_k[i] = 0
         self._top_p[i] = 1.0
@@ -1933,7 +1939,8 @@ class ContinuousBatcher:
                     self._eos, b, self._temp, self._top_k, self._top_p,
                     adapter_ids=(self._adapter if self.engine.adapters
                                  is not None else None), lanes=lanes,
-                    given=self._given_n if self._blocks else None)
+                    given=self._given_n if self._blocks else None,
+                    waiting=self._waiting if self._blocks else None)
                 # the fused lane's outputs wait for _lane_land, after the
                 # round delivers. An isolation re-dispatch re-runs the
                 # lane chunk too: same rows, same bytes, so restashing is
@@ -1965,7 +1972,8 @@ class ContinuousBatcher:
         for i, s in enumerate(self._slots):
             if s is not None and budget[i] > 0 and i not in failed:
                 s.dispatches += 1
-                self._given_n[i] = 0  # a blocks round took the remainder
+                if self._blocks:
+                    self._round_of_blocks_done(i, toks[i], int(counts[i]))
                 if self.controller is not None:
                     # policy tick AFTER this round's counters landed in
                     # the registry; idle slots advance their cooloff
@@ -1975,6 +1983,20 @@ class ContinuousBatcher:
                 self._finish(i, "error")
         self._deliver_round(toks, counts)
         self._lane_land(feeds)
+
+    def _round_of_blocks_done(self, i: int, toks, n: int) -> None:
+        """What a round of blocks leaves slot ``i`` to hand back: it took
+        the prompt's remainder, and its last block waits (``_waiting``) if
+        the round ran to its end for the slot, ``n`` tokens behind the given
+        ones making whole blocks: the last ``block_length`` of them. A round
+        cut short ended the stream, whose last block nobody reads (and
+        ``_retire`` clears what a stream that ends on a round's last token
+        leaves here)."""
+        run = self._given[i, :self._given_n[i]].tolist() + toks[:n].tolist()
+        self._given_n[i] = 0
+        Bd = self._given.shape[1]
+        self._waiting[i] = run[-Bd:] \
+            if len(run) == self.engine.decode_block_len else -1
 
     # ---- overlapped (zero-bubble) scheduling ------------------------------
 

@@ -1188,7 +1188,8 @@ class InferenceEngine:
         into each layer's cache dict alongside the paged metadata (the
         ragged verify's ``draft_valid`` write mask; a blocks round's own
         ``live`` rows). Without ``head`` the logits are None (a commit
-        forward's are read by nobody). Lengths are NOT
+        forward's are read by nobody; a fused forward runs the head itself,
+        on half of its rows). Lengths are NOT
         advanced here — callers apply their own activity rule."""
         cos_b, sin_b = rope_at_positions(self._cos, self._sin, rows)
         h = self._embed(params, tokens)
@@ -1440,24 +1441,71 @@ class InferenceEngine:
         block_length``, its K/V rows written there and then attended (each
         row sees the slot's stored prefix and the whole block), ``lengths``
         NOT advanced: the rows are provisional, beyond the length for every
-        later reader, and the next forward of the slot overwrites them (a
-        commit is this forward of the finished block, then the advance).
-        Slots that are not ``active`` [B] ride along unrouted and uncounted.
-        Returns (cache, logits [B, block_length, V] float32 or None without
-        ``head``, the layers' stats)."""
+        later reader, and the next forward of the slot overwrites them. A
+        round runs it for a block's denoise forwards behind the first
+        (``_fused_forward`` is the first); ``_commit`` is this forward of a
+        finished block, then the advance. Slots that are not ``active`` [B]
+        ride along unrouted and uncounted. Returns (cache, logits [B,
+        block_length, V] float32 or None without ``head``, the layers'
+        stats)."""
         pos = cache["lengths"]
         Bd = x.shape[1]
         rows = pos[:, None] + jnp.arange(Bd, dtype=jnp.int32)[None, :]
         new_leaves, logits, _ = self._model_block(
             params, cache, x, rows, pos, head=head, extra_meta={
                 "live": jnp.broadcast_to(active[:, None], rows.shape)})
-        stats = new_leaves.pop(models.STATS, jnp.zeros(
-            (self.cfg.model.num_hidden_layers, 0), jnp.int32))
+        stats = self._pop_stats(new_leaves)
         return self._rebuild(cache, new_leaves, pos), logits, stats
 
+    def _pop_stats(self, new_leaves) -> jnp.ndarray:
+        """What the layers of a forward counted, taken out of its leaves."""
+        return new_leaves.pop(models.STATS, jnp.zeros(
+            (self.cfg.model.num_hidden_layers, 0), jnp.int32))
+
+    def _fused_forward(self, params, cache, done, x, owed, active):
+        """The first denoise forward of a block with the commit of the
+        block before it inside: ``2 x block_length`` rows a slot at
+        positions ``lengths .. lengths + 2 x block_length``, all written and
+        then attended under the model's own band (``kv_cache.attend``: the
+        first ``block_length`` rows see the stored prefix and themselves,
+        the rest those and themselves), the keys read once for both halves.
+
+        Where a slot is ``owed`` [B] (a finished block ``done`` [B,
+        block_length] of it waits for its K/V, and the slot is ``active``)
+        the halves are ``done``, then ``x``, the block that starts: the
+        first half's rows are the rows a commit forward of ``done`` writes
+        (the same tokens at the same positions behind the same prefix), the
+        slot's length passes them, and ``x`` has been forwarded behind them
+        as its denoise forward is. Where nothing is owed (a slot's first
+        block; a slot that is not active) ``x`` rides in the FIRST half, at
+        the length where it belongs, the length stays, and the second half
+        is ``x`` once more, not live, its rows provisional beyond the block
+        (past the window they drop). The head runs on one half's rows a
+        slot: the half that holds ``x``. Returns (cache, logits [B,
+        block_length, V] float32 of ``x``, the layers' stats)."""
+        pos = cache["lengths"]
+        Bd = x.shape[1]
+        tokens = jnp.concatenate(
+            [jnp.where(owed[:, None], done, x), x], axis=1)
+        rows = pos[:, None] + jnp.arange(2 * Bd, dtype=jnp.int32)[None, :]
+        live = jnp.repeat(jnp.stack([active, owed], axis=1), Bd, axis=1)
+        new_leaves, _, h = self._model_block(
+            params, cache, tokens, rows, pos, head=False,
+            extra_meta={"live": live})
+        stats = self._pop_stats(new_leaves)
+        h = jnp.where(owed[:, None, None], h[:, Bd:], h[:, :Bd])
+        logits = tp_gather(self.model.head_logits(params, h, self.cfg))
+        return (self._rebuild(cache, new_leaves,
+                              jnp.where(owed, pos + Bd, pos)),
+                logits.astype(jnp.float32), stats)
+
     def _commit(self, params, cache, x, active):
-        """The commit forward of a finished block ``x``: its K/V stay, and
-        the ``active`` slots' lengths pass them. (cache, stats)."""
+        """The commit forward of a finished block ``x`` alone: its K/V stay,
+        and the ``active`` slots' lengths pass them. (cache, stats). A round
+        commits inside the next block's first forward (``_fused_forward``);
+        this is ``block_forward(commit=True)``'s, for the checks that run a
+        round's forwards one at a time and for a caller that wants a
+        waiting block stored without starting another."""
         with jax.named_scope("diffusion/commit"):
             cache, _, stats = self._block_forward(params, cache, x, active,
                                                   head=False)
@@ -1471,54 +1519,74 @@ class InferenceEngine:
         ``block_diffusion_generate``, a cache under it): ``decode_block_len /
         block_length`` blocks a slot, one after the other, in one program.
 
-        ``pack`` and ``rest`` are ``_round_operands``'s: tokens [B,
-        block_length] and given [B], the leading positions of each slot's
-        FIRST block of the round that are given (a prompt's remainder
-        behind its whole prefilled blocks; 0 in every later round), eos_id,
-        budget, the sampling rows, and the round's keys [decode_block_len,
-        2] (block ``j``'s denoise step ``s`` draws with ``keys[j *
-        block_length + s]``).
+        ``pack`` and ``rest`` are ``_round_operands``'s: tokens [B, 2 x
+        block_length] and given [B], eos_id, budget, the sampling rows, and
+        the round's keys [decode_block_len, 2] (block ``j``'s denoise step
+        ``s`` draws with ``keys[j * block_length + s]``). The tokens' second
+        half holds the leading positions of each slot's FIRST block of the
+        round that are given, ``given`` of them (a prompt's remainder behind
+        its whole prefilled blocks; 0 in every later round); their first
+        half is the slot's WAITING block, the last block of its last round,
+        whose tokens are final and streamed and whose K/V are not stored
+        yet (-1 throughout: nothing waits).
 
         A block starts as its given positions and ``mask_token_id`` behind
-        them; masked-ness is a flag a position, never an id compared. While
-        a position of a live slot is masked, for at most ``denoising_steps``
-        steps: one denoise forward of every slot's block (``_block_forward``:
-        written, attended, not counted in ``lengths``), a draw at every
-        position (``sampling.sample``: the argmax at temperature 0), and the
-        confidence rule (``sampling.confidence_unmask``) fixes some of the
-        masked positions at their draw. Then the commit forward of the
-        finished block stores its K/V and the slot's length passes it. The
-        block's new tokens (behind the given ones) are emitted up to the
-        budget and the first EOS among them, which end the slot's stream: it
-        rides the round's later blocks inactive (its provisional rows land
-        beyond its length). A slot is live while its budget lasts; a free
-        slot's is 0.
+        them; masked-ness is a flag a position, never an id compared. Its
+        first denoise forward carries the finished block before it in
+        front (``_fused_forward``: the waiting block in a round's first
+        block, the round's own last one after; a slot's length passes that
+        block there, and a slot with none rides the same forward with its
+        block alone). While a position of a live slot is still masked, up
+        to ``denoising_steps`` steps in all: one more denoise forward of
+        every slot's block (``_block_forward``: written, attended, not
+        counted in ``lengths``). Behind every denoise forward a draw at
+        every position (``sampling.sample``: the argmax at temperature 0),
+        and the confidence rule (``sampling.confidence_unmask``) fixes some
+        of the masked positions at their draw. No forward commits a block
+        alone: a round of two blocks is two fused forwards and six plain
+        ones where four denoising steps fix a block. The block's new tokens
+        (behind the given ones) are emitted up to the budget and the first
+        EOS among them, which end the slot's stream: it rides the round's
+        later blocks inactive (its provisional rows land beyond its length),
+        and its last block is never stored, since nothing reads it. The
+        round's last block WAITS: its rows in the cache are provisional, the
+        slot's length stops in front of them, and the caller hands its
+        tokens back with the slot's next round (``decode_block``'s
+        ``waiting``; the batcher keeps them), whose first forward stores
+        them. A slot is live while its budget lasts; a free slot's is 0.
 
         Returns ``_round_fields("blocks")``: cache, packed (tokens [B,
         decode_block_len], a slot's emitted run left-packed, and counts [B]
         side by side), and the stats in the order of ``stat_names``, one
         vector: the layers' own summed over the forwards and the layers, the
-        round's ``sampling.DIFFUSION_STATS`` behind them."""
+        round's ``sampling.DIFFUSION_STATS`` behind them (a fused forward
+        counts as one forward of ``kind="fused"``, the blocks it commits as
+        blocks, and ``block_length`` rows a live slot: the rows that can
+        gain a token)."""
         (tokens, given, eos_id, budget, temperature, top_k, top_p), \
             (keys, *lane) = self._unpack_rows("blocks", pack, rest,
                                               dev_tokens)
         m = self.cfg.model
         Bd, T = m.block_length, m.denoising_steps
         B, nb = tokens.shape[0], self.decode_block_len // Bd
+        waiting, tokens = tokens[:, :Bd], tokens[:, Bd:]
         cols = jnp.arange(Bd, dtype=jnp.int32)[None, :]
         per_row = lambda a: jnp.repeat(a, Bd)
 
         def block(carry, keys_j):
-            cache, given, budget, stats, counts = carry
+            cache, given, budget, stats, counts, done, was_live = carry
             active = budget > 0
+            owed = was_live & active
             x = jnp.where(cols < given[:, None], tokens, m.mask_token_id)
             masked = (cols >= given[:, None]) & active[:, None]
             n_live = jnp.sum(active, dtype=jnp.int32)
 
-            def denoise(c):
-                s, cache, x, masked, stats, counts = c
-                cache, logits, counted = self._block_forward(
-                    params, cache, x, active)
+            def unmask(c, forward, kind, blocks=0):
+                """A denoise forward's draw and the rule, on the loop's
+                state ``c``; ``forward`` is what the forward of ``kind``
+                returned, ``blocks`` how many blocks it committed."""
+                s, _, x, masked, stats, counts = c
+                cache, logits, counted = forward
                 if poison:
                     logits = jnp.full_like(logits, jnp.nan)
                 with jax.named_scope("diffusion/unmask"):
@@ -1530,17 +1598,21 @@ class InferenceEngine:
                         logits, x0, masked,
                         sampling.transfer_count(Bd, T, s), m.remasking,
                         m.confidence_threshold)
-                counts = counts + jnp.stack([
-                    1, 0, 0, jnp.sum(fix, dtype=jnp.int32),
-                    jnp.sum(passed), Bd * n_live])
+                counts = counts + sampling.diffusion_counts(
+                    kind, blocks=blocks, positions_unmasked=jnp.sum(fix),
+                    threshold_passes=jnp.sum(passed), rows=Bd * n_live)
                 return (s + 1, cache, jnp.where(fix, x0, x), masked & ~fix,
                         stats + counted, counts)
 
+            with jax.named_scope("diffusion/fused"):
+                fused = self._fused_forward(params, cache, done, x, owed,
+                                            active)
             _, cache, x, _, stats, counts = lax.while_loop(
-                lambda c: (c[0] < T) & jnp.any(c[3]), denoise,
-                (jnp.zeros((), jnp.int32), cache, x, masked, stats, counts))
-            cache, counted = self._commit(params, cache, x, active)
-            counts = counts + jnp.stack([0, 1, n_live, 0, 0, Bd * n_live])
+                lambda c: (c[0] < T) & jnp.any(c[3]),
+                lambda c: unmask(c, self._block_forward(
+                    params, c[1], c[2], active), "denoise"),
+                unmask((jnp.zeros((), jnp.int32), cache, x, masked, stats,
+                        counts), fused, "fused", jnp.sum(owed)))
             # the block's new tokens, up to the budget and the first EOS
             n = jnp.where(active, jnp.minimum(Bd - given, budget), 0)
             new = (cols >= given[:, None]) & (cols < (given + n)[:, None])
@@ -1549,14 +1621,15 @@ class InferenceEngine:
             n = jnp.where(hit, jnp.argmax(is_eos, axis=1) - given + 1, n)
             new &= cols < (given + n)[:, None]
             budget = jnp.where(hit, 0, budget - n)
-            return (cache, jnp.zeros_like(given), budget, stats + counted,
-                    counts), (jnp.where(new, x, 0), n)
+            return (cache, jnp.zeros_like(given), budget, stats, counts, x,
+                    active), (jnp.where(new, x, 0), n)
 
         zeros = lambda *shape: jnp.zeros(shape, jnp.int32)
-        (cache, _, _, stats, counts), (toks, ns) = lax.scan(
+        (cache, _, _, stats, counts, _, _), (toks, ns) = lax.scan(
             block, (cache, given, budget,
                     zeros(m.num_hidden_layers, self._n_stats),
-                    zeros(len(sampling.DIFFUSION_STATS))),
+                    zeros(len(sampling.DIFFUSION_STATS)),
+                    waiting, waiting[:, 0] >= 0),
             keys.reshape(nb, Bd, 2))
         # a slot's run lies behind its given positions, blocks end to end
         flat = jnp.swapaxes(toks, 0, 1).reshape(B, nb * Bd)
@@ -1586,8 +1659,9 @@ class InferenceEngine:
         (the keys, then the lane's). The float rows rode as their bits and
         are bit-cast back, so a sampled token is what separate float32
         operands gave. ``tokens`` is the leading row (a verify's leading
-        S, one a fed position; a blocks round's leading ``block_length``,
-        with how many of them are given as its ``valid``) unless the caller
+        S, one a fed position; a blocks round's leading ``2 x
+        block_length``, the waiting block and the next block's positions,
+        with how many of these are given as its ``valid``) unless the caller
         held it on the device: then it is the first of ``rest``, as it was
         handed in."""
         n = len(_PACK_ROWS)
@@ -2642,7 +2716,8 @@ class InferenceEngine:
 
     def decode_block(self, params, cache, tokens, keys, eos_id, budget,
                      temperature, top_k, top_p, adapter_ids=None,
-                     lead=None, lanes=None, given=None) -> RoundResult:
+                     lead=None, lanes=None, given=None,
+                     waiting=None) -> RoundResult:
         """``decode_block_len`` tokens for every slot in one dispatch.
         ``keys`` is [decode_block_len, 2] (one PRNG key per in-block step)
         on a round-keyed engine, or the per-slot BASE keys [slots, 2] on a
@@ -2667,7 +2742,16 @@ class InferenceEngine:
         of blocks here (``_blocks_impl``): ``tokens`` is then [slots,
         block_length], the positions of each slot's next block that are
         given, and ``given`` [slots] says how many lead it (a prompt's
-        remainder in the slot's first round, 0 after)."""
+        remainder in the slot's first round, 0 after). ``waiting`` [slots,
+        block_length] is each slot's waiting block: a round stores the K/V
+        of every block but its last, whose tokens the caller hands back
+        here with the slot's NEXT round (the given positions of that block,
+        if any, and the tokens the round emitted behind them: a stream that
+        goes on was handed the whole block), and that round's first forward
+        stores it. A row of -1 (None: every row) says nothing waits: the
+        slot's first round, or a caller that stored the block itself
+        (``block_forward(commit=True)``). A stream that ended owes
+        nothing."""
         if self.key_schedule != "slot":
             keys = jnp.asarray(keys)
             if keys.shape[0] != self.decode_block_len:
@@ -2681,14 +2765,18 @@ class InferenceEngine:
         if self.blocks:
             Bd = self.cfg.model.block_length
             given = np.asarray(given, np.int32)
+            waiting = np.full((self.slots, Bd), -1, np.int32) \
+                if waiting is None else np.asarray(waiting, np.int32)
             if tokens.shape != (self.slots, Bd) \
+                    or waiting.shape != tokens.shape \
                     or given.shape != (self.slots,) \
                     or np.any(given < 0) or np.any(given >= Bd):
                 raise ValueError(
-                    f"a round of blocks takes tokens [slots, block_length] "
-                    f"= [{self.slots}, {Bd}] and given [slots] in [0, "
-                    f"block_length); got {tokens.shape} and "
-                    f"{given.tolist()}")
+                    f"a round of blocks takes tokens and waiting [slots, "
+                    f"block_length] = [{self.slots}, {Bd}] and given "
+                    f"[slots] in [0, block_length); got {tokens.shape}, "
+                    f"{waiting.shape} and {given.tolist()}")
+            tokens = np.concatenate([waiting, tokens], axis=1)
             return self._round("blocks", params, cache, (tokens, given),
                                keys, eos_id, budget, temperature, top_k,
                                top_p, self.decode_block_len, budget,

@@ -378,16 +378,25 @@ def plain_decode(q: jnp.ndarray, cache: dict, block: int = 1) -> bool:
     """Whether an ``attend`` call is the plain decode shape the stacked
     flash-decode kernel serves: one fresh query a slot (or, with ``block``
     > 1, one whole aligned block of ``block`` fresh rows a slot, every row
-    of which sees every live key) against a contiguous bfloat16 cache of
-    whole-lane rows, every slot at its own length, with no addressing entry
-    spliced in (``slot`` / ``gate``: one slot's block; ``draft_valid``: a
-    ragged verify)."""
+    of which sees every live key; or two such blocks, the first of which
+    stops before the second's keys, where the kernel's form for two limits
+    fits the heads, ``decode_attention.early_fits``) against a contiguous
+    bfloat16 cache of whole-lane rows, every slot at its own length, with no
+    addressing entry spliced in (``slot`` / ``gate``: one slot's block;
+    ``draft_valid``: a ragged verify)."""
     k = cache.get("k")
-    return (q.shape[1] in (1, block) and k is not None and k.ndim == 5
+    S = q.shape[1]
+    if not (k is not None and k.ndim == 5
             and "block_tables" not in cache and not quantized(cache)
             and k.dtype == q.dtype == jnp.bfloat16
             and k.shape[-1] % LANE == 0
-            and not any(n in cache for n in ("slot", "gate", "draft_valid")))
+            and not any(n in cache for n in ("slot", "gate", "draft_valid"))):
+        return False
+    if S == 2 * block > 2:
+        from picotron_tpu.ops.pallas.decode_attention import early_fits
+
+        return early_fits(S * q.shape[2], k.shape[3])
+    return S in (1, block)
 
 
 def attend(q: jnp.ndarray, cache: dict, lengths: jnp.ndarray,
@@ -404,9 +413,13 @@ def attend(q: jnp.ndarray, cache: dict, lengths: jnp.ndarray,
     boundary where ``block`` > 1 (the caller's to hold: its window, chunk
     and round are whole blocks), so ``S == block`` rows a slot are one
     block, each sees every live key, and the call is the plain decode
-    shape with ``S`` times the query heads (``plain_decode``). The dense
-    rule and that shape hold a band; the sliced flash kernel and the paged
-    attends see the causal one alone and refuse another.
+    shape with ``S`` times the query heads (``plain_decode``); ``S == 2 x
+    block`` rows are two blocks (a finished block in front of the one a
+    round of blocks starts, ``engine._fused_forward``), still one call of
+    that shape, whose first half stops ``block`` keys early
+    (``_attend_whole_block``). The dense rule and that shape hold a band;
+    the sliced flash kernel and the paged attends see the causal one alone
+    and refuse another.
 
     ``impl`` picks the kernel (config ``inference.attend_impl``):
 
@@ -452,7 +465,7 @@ def attend(q: jnp.ndarray, cache: dict, lengths: jnp.ndarray,
         if q.shape[1] == 1:
             return flash_decode_stacked(q, cache["k"], cache["v"], lengths,
                                         scale, layer, interpret=not on_tpu())
-        return _attend_whole_block(q, cache, lengths, scale, layer)
+        return _attend_whole_block(q, cache, lengths, scale, layer, block)
     k, v, k_scale, v_scale = (layer_block(cache, n, layer)
                               for n in ("k", "v", "k_scale", "v_scale"))
     D = q.shape[-1]
@@ -480,7 +493,8 @@ def attend(q: jnp.ndarray, cache: dict, lengths: jnp.ndarray,
     return decode_attention(q, k, v, lengths, scale, block)
 
 
-def _attend_whole_block(q, cache: dict, lengths, scale: float, layer):
+def _attend_whole_block(q, cache: dict, lengths, scale: float, layer,
+                        block: int):
     """``attend``'s plain decode shape at ``S == block`` > 1: every one of a
     slot's S fresh rows sees every live key, so the rows ride beside the
     query heads of their cache row (``[B, S, rows, p, g, D] -> [B, 1, rows x
@@ -490,7 +504,14 @@ def _attend_whole_block(q, cache: dict, lengths, scale: float, layer):
     tile kept) made a call four times the grid steps, most of them past a
     slot's walk, and took half as long again (0.889 against 0.614 ms a layer
     at 5,000 tokens a slot, the step itself 0.569; PERF.md section 6, PR
-    62)."""
+    62).
+
+    At ``S == 2 x block`` the rows are two blocks, and the fold is the same:
+    the first block's ``block x g`` query heads of every kv head stop
+    ``block`` keys before ``lengths`` (they see the stored prefix and
+    themselves, not the second block), which the kernel holds as a static
+    form of the same pass (``flash_decode_stacked``'s ``early``), so both
+    blocks read the live keys once."""
     from picotron_tpu.ops.pallas.decode_attention import flash_decode_stacked
 
     B, S, nh, D = q.shape
@@ -498,8 +519,10 @@ def _attend_whole_block(q, cache: dict, lengths, scale: float, layer):
     rows, p = k.shape[3], k.shape[4] // D
     g = nh // (rows * p)
     fold = q.reshape(B, S, rows, p, g, D).transpose(0, 2, 3, 1, 4, 5)
-    out = flash_decode_stacked(fold.reshape(B, 1, S * nh, D), k, v, lengths,
-                               scale, layer, interpret=not on_tpu())
+    out = flash_decode_stacked(
+        fold.reshape(B, 1, S * nh, D), k, v, lengths, scale, layer,
+        interpret=not on_tpu(),
+        early=None if S == block else (block * g, block))
     out = out.reshape(B, rows, p, S, g, -1).transpose(0, 3, 1, 2, 4, 5)
     return out.reshape(B, S, nh, -1)
 

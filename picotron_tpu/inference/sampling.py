@@ -374,17 +374,34 @@ def speculative_match(logits: jnp.ndarray, draft: jnp.ndarray,
 # --------------------------------------------------------------------------- #
 
 # what a round of blocks counts (``engine._blocks_impl``), as (name, labels)
-# of a ``picotron_<name>_total`` counter each: forwards of either kind, blocks
-# committed and positions unmasked summed over the live slots, masked
-# positions whose confidence passed the threshold, slot-rows forwarded by
-# live slots. The engine hands them behind the block's own
-# (``engine.stat_names``)
+# of a ``picotron_<name>_total`` counter each: forwards by kind (a denoise
+# forward of a block alone; a commit forward of a finished block alone, which
+# a round no longer runs and ``engine.block_forward(commit=True)`` does; a
+# fused forward, a block's first denoise forward with the commit of the block
+# before it inside), blocks committed and positions unmasked summed over the
+# live slots, masked positions whose confidence passed the threshold,
+# slot-rows forwarded by live slots (``block_length`` a live slot and
+# forward of any kind: the rows that can gain a token, so a fused forward's
+# committed half is not among them). The engine hands them behind the
+# block's own (``engine.stat_names``)
 DIFFUSION_STATS = (("diffusion_forwards", {"kind": "denoise"}),
                    ("diffusion_forwards", {"kind": "commit"}),
+                   ("diffusion_forwards", {"kind": "fused"}),
                    ("diffusion_blocks", {}),
                    ("diffusion_positions_unmasked", {}),
                    ("diffusion_threshold_passes", {}),
                    ("diffusion_rows", {}))
+
+
+def diffusion_counts(kind: str, **counted) -> jnp.ndarray:
+    """One forward of ``kind`` as a row of ``DIFFUSION_STATS``: ``counted``
+    holds what it adds to the counters without a label, each under its name
+    less ``diffusion_`` (``blocks``, ``positions_unmasked``,
+    ``threshold_passes``, ``rows``; absent: 0)."""
+    return jnp.stack([
+        jnp.asarray(labels["kind"] == kind if labels
+                    else counted.get(name[len("diffusion_"):], 0), jnp.int32)
+        for name, labels in DIFFUSION_STATS])
 
 
 def transfer_count(block: int, steps: int, step):

@@ -596,10 +596,19 @@ def _stacked_kernel(len_ref, layer_ref, last_ref, *refs, scale, block_t, rows,
         o_ref[...] = jnp.where(l > 0, out, 0.0).astype(o_ref.dtype)
 
 
+def early_fits(heads: int, rows: int) -> bool:
+    """Whether ``flash_decode_stacked`` takes ``heads`` query heads against
+    ``rows`` cache rows a token in its ``early`` form: the rows pair up, and
+    a pair's query heads are whole bfloat16 tiles."""
+    return rows % 2 == 0 and heads % rows == 0 \
+        and heads // rows * 2 % _BF16_ROWS == 0
+
+
 def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
                          block_t: int | None = None,
                          interpret: bool = False,
-                         window: int | None = None, sink=None):
+                         window: int | None = None, sink=None,
+                         early: tuple | None = None):
     """The ``S == 1`` decode attend of a contiguous, unquantized cache,
     reading layer ``layer`` of the STACKED leaves in place: one pass over
     K and V, live rows only.
@@ -618,6 +627,17 @@ def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
     were, and the compiled kernel's name tells the forms apart
     (``flash_decode_attention`` | ``flash_decode_ring``, ``_sink`` behind
     either).
+
+    ``early`` = ``(n, by)`` (static; the dense form's alone) gives a call two
+    limits a slot: of each kv head's query heads the first ``n`` see ``by``
+    keys fewer than ``lengths`` says, the rest all of them. It is how two
+    blocks of fresh rows folded beside the query heads attend in one pass
+    over the keys (``kv_cache._attend_whole_block``: the first block's rows
+    stop before the second block's keys). The limits differ in the walk's
+    last one or two blocks; the difference rides the tile of token offsets
+    the kernel fills at its first step, and without ``early`` nothing that
+    is traced changes. With it the call scores a pair of cache rows at a
+    time (``_dense_kernel``), which takes ``early_fits``.
 
     ``layer`` and ``lengths`` are scalar-prefetch operands and the leaves
     are viewed as ``[L, B, T * rows, p * D]`` (the same bytes: tokens and
@@ -667,6 +687,14 @@ def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
     if q.dtype != k.dtype:
         raise ValueError(f"q is {q.dtype}, the cache leaves {k.dtype}")
     pg = nh // rows  # query heads a cache row: p * (heads a kv head)
+    if early is not None and (window is not None or sink is not None
+                              or not 0 < early[0] < pg // pack
+                              or not early_fits(nh, rows)
+                              or k.dtype != jnp.bfloat16):
+        raise ValueError(
+            f"early {early}: the dense form's, without a sink, fewer than a "
+            f"kv head's {pg // pack} query heads, bfloat16 leaves (two cache "
+            f"rows a 32-bit word) and ``early_fits``")
     qg = q.reshape(B, rows, pg, D)
     if pack > 1:
         qg = _own_lanes_only(qg, pack)
@@ -704,9 +732,10 @@ def flash_decode_stacked(q, k, v, lengths, scale, layer, *,
                       v.reshape(nl, B, T * rows, lanes_v)]
 
     if window is None:
-        return own(_dense_call(lengths, layer, operands(), scale=scale,
-                               block_t=bt, rows=rows, pg=pg,
-                               interpret=interpret))
+        return own(_dense_call(
+            lengths, layer, operands(), scale=scale, block_t=bt, rows=rows,
+            pg=pg, interpret=interpret,
+            early=early and (pg // pack, *early)))
     steps = _ring_walk(window, bt, max_nb)
     # rows written, and the row of the query's own key
     prefetch = (jnp.minimum(lengths, T), layer, (lengths - 1) % T)
@@ -761,7 +790,7 @@ def _dense_parts(block_t: int, rows: int) -> int:
 
 
 def _dense_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg, max_nb,
-                  parts, sink):
+                  parts, sink, early=None):
     """Grid step ``b`` of the dense form: slot ``b``'s live K and V blocks,
     ``_stacked_blocks(lengths[b])`` of them and no other, in
     ``_stacked_kernel``'s arithmetic (a block as a plain matrix, every head
@@ -776,7 +805,25 @@ def _dense_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg, max_nb,
     first block is waited for with nothing to do. ``turn_ref`` carries the
     buffer the next block goes to from one grid step to the next; the tile of
     token offsets depends on the shapes alone and is filled at the call's
-    first step."""
+    first step.
+
+    ``early`` = ``(of, n, by)``: of every ``of`` query rows (a kv head's) the
+    first ``n`` see ``by`` keys fewer. Their tokens' offsets stand ``by``
+    higher in the tile, so the one comparison with the length masks both
+    kinds of row, in whichever of the walk's blocks the two limits part (a
+    row that sees nothing of a block keeps the maximum it came with, and the
+    block's probabilities are exact zeros for it). Such a call holds twice
+    the query rows, and scoring every row against every (token, cache row)
+    pair of a block would cost more than the block's copy (5,473 bundles a
+    block of 1,024 tokens x 4 rows at 256 query rows, 4.0 us against 2.56;
+    PERF.md section 6, PR 66). So it scores by PAIRS of cache rows: a
+    bfloat16 buffer read as 32-bit words holds two neighbouring cache rows
+    a word, rows ``2 i`` and ``2 i + 1`` of a token are word row ``i``, and
+    a strided read of the words takes a pair's rows of every token out of
+    the block ([2 x block_t, lanes]); the pair's query rows meet those
+    alone, half of their scores their own where a quarter were (3,203
+    bundles, 2.3 us: under the copy again). The tile of offsets is then a
+    pair's, [2 x pg, 2 x block_t], the same for every pair."""
     q_ref, refs = refs[0], refs[1:]
     sink_ref, refs = (refs[0], refs[1:]) if sink else (None, refs)
     k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, own_ref, turn_ref = refs
@@ -788,7 +835,20 @@ def _dense_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg, max_nb,
     after = jnp.minimum(b + 1, last_slot)
     # is there a first block to ask for when this slot's walk ends
     follows = (b < last_slot) & (len_ref[after] > 0)
-    nq, cols = own_ref.shape
+    nq, cols = q_ref.shape[0], k_buf.shape[1]
+    # the query rows are scored whole against a block, or a pair of cache
+    # rows at a time against the pair's rows of it (``early``)
+    groups = 1 if early is None else rows // 2
+    nqg = nq // groups
+    if early is not None:
+        words = [buf.bitcast(jnp.uint32) for buf in (k_buf, v_buf)]
+
+    def block(leaf, buf, i):
+        """Block ``buf`` of ``leaf`` as group ``i``'s query rows meet it."""
+        if early is None:
+            return (k_buf, v_buf)[leaf][buf]
+        pair = words[leaf][buf, pl.ds(i, block_t, stride=groups)]
+        return pltpu.bitcast(pair, k_buf.dtype)
 
     piece = cols // parts  # rows of K a copy moves
 
@@ -817,10 +877,16 @@ def _dense_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg, max_nb,
 
     @pl.when(b == 0)
     def _():
-        head_row = lax.broadcasted_iota(jnp.int32, (nq, cols), 0) // pg
-        col = lax.broadcasted_iota(jnp.int32, (nq, cols), 1)
-        own_ref[...] = jnp.where(col % rows == head_row, col // rows,
-                                 jnp.iinfo(jnp.int32).max)
+        head = lax.broadcasted_iota(jnp.int32, own_ref.shape, 0)
+        head_row = head // pg
+        col = lax.broadcasted_iota(jnp.int32, own_ref.shape, 1)
+        if early is None:
+            own, token = col % rows == head_row, col // rows
+        else:  # a pair's tile: two cache rows a token, the early heads' later
+            of, n, by = early
+            own = col % 2 == head_row
+            token = col // 2 + jnp.where(head % of < n, by, 0)
+        own_ref[...] = jnp.where(own, token, jnp.iinfo(jnp.int32).max)
         turn_ref[0] = 0
         if parts > 1:
             # a piece never copied is masked, but its V rows still meet a
@@ -835,7 +901,6 @@ def _dense_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg, max_nb,
     q = q_ref[...]
 
     def score(j, carry):
-        m, l, acc = carry
         buf = (turn + j) & 1
         ends = j == nb - 1
 
@@ -845,30 +910,38 @@ def _dense_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg, max_nb,
                   1 - buf)
 
         wait(0, j, buf)
-        s = _dot_nt(q, k_buf[buf])
-        if scale is not None:
-            s = s * scale
-        # token j * block_t + own is visible iff it is below the length
-        s = jnp.where(own_ref[...] < L - j * block_t, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        # every walked block holds a visible key for every head (token
-        # j * block_t of its own row), so m_new is finite and a masked
-        # score's exp underflows to an exact zero
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        scored = []
+        for i, (m, l, acc) in enumerate(carry):
+            s = _dot_nt(q if groups == 1 else q[i * nqg:(i + 1) * nqg],
+                        block(0, buf, i))
+            if scale is not None:
+                s = s * scale
+            # token j * block_t + own is visible iff it is below the length
+            s = jnp.where(own_ref[...] < L - j * block_t, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            # every walked block holds a visible key for every head (token
+            # j * block_t of its own row), so m_new is finite and a masked
+            # score's exp underflows to an exact zero
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+            scored.append((m_new, l, alpha, acc, p))
         wait(1, j, buf)
-        acc = acc * alpha + jnp.dot(p.astype(v_buf.dtype), v_buf[buf],
-                                    preferred_element_type=jnp.float32)
-        return m_new, l, acc
+        return tuple(
+            (m_new, l, acc * alpha + jnp.dot(
+                p.astype(v_buf.dtype), block(1, buf, i),
+                preferred_element_type=jnp.float32))
+            for i, (m_new, l, alpha, acc, p) in enumerate(scored))
 
     if sink:
         m0, l0 = sink_ref[...], jnp.ones((nq, 1), jnp.float32)
     else:
-        m0 = jnp.full((nq, 1), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((nq, 1), jnp.float32)
-    _, l, acc = lax.fori_loop(
-        0, nb, score, (m0, l0, jnp.zeros(o_ref.shape, jnp.float32)))
+        m0 = jnp.full((nqg, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((nqg, 1), jnp.float32)
+    done = lax.fori_loop(0, nb, score, (
+        (m0, l0, jnp.zeros((nqg, o_ref.shape[1]), jnp.float32)),) * groups)
+    l, acc = (jnp.concatenate(x) if groups > 1 else x[0]
+              for x in ([g[1] for g in done], [g[2] for g in done]))
 
     @pl.when((nb == 0) & follows)  # a free slot hands the request on
     def _():
@@ -880,7 +953,7 @@ def _dense_kernel(len_ref, layer_ref, *refs, scale, block_t, rows, pg, max_nb,
 
 
 def _dense_call(lengths, layer, operands, *, scale, block_t, rows, pg,
-                interpret):
+                interpret, early=None):
     """The dense form's ``pallas_call``: ``operands`` are the query rows
     ``[B, nq, lanes]``, a sink a row ``[nq, 1]`` or nothing, and the K and V
     leaves as ``[L, B, T * rows, lanes]``, which stay where they are
@@ -901,7 +974,8 @@ def _dense_call(lengths, layer, operands, *, scale, block_t, rows, pg,
     return pl.pallas_call(
         functools.partial(_dense_kernel, scale=scale, block_t=block_t,
                           rows=rows, pg=pg, max_nb=k.shape[2] // cols,
-                          parts=_dense_parts(block_t, rows), sink=sink),
+                          parts=_dense_parts(block_t, rows), sink=sink,
+                          early=early),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B,),
@@ -910,7 +984,8 @@ def _dense_call(lengths, layer, operands, *, scale, block_t, rows, pg,
             scratch_shapes=[pltpu.VMEM((2, cols, lanes), k.dtype),
                             pltpu.VMEM((2, cols, lanes_v), v.dtype),
                             pltpu.SemaphoreType.DMA((2, 2)),
-                            pltpu.VMEM((nq, cols), jnp.int32),
+                            pltpu.VMEM((nq, cols) if early is None
+                                       else (2 * pg, 2 * block_t), jnp.int32),
                             pltpu.SMEM((1,), jnp.int32)]),
         out_shape=jax.ShapeDtypeStruct((B, nq, lanes_v), qm.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -199,12 +199,17 @@ STACKED_LEAVES = {"smollm": ((24, 4, 2048, 16, 128), 32, 64),
                   "falcon": ((4, 64, 6144, 4, 128), 20, 128)}
 
 
-def _decode_stacked(cell):
+def _decode_stacked(cell, early=None):
     """flash_decode_stacked, the ``S == 1`` step's kernel, on a cell's own
-    stacked leaf with a traced layer index."""
+    stacked leaf with a traced layer index; with ``early`` the form of two
+    limits a slot (twice the query heads, the first ``early[0]`` of each kv
+    head's stopping ``early[1]`` keys early: the strided read of a pair of
+    cache rows as 32-bit words has to lower)."""
     leaf, heads, d = STACKED_LEAVES[cell]
+    heads, form = (heads, {}) if early is None else (2 * heads,
+                                                     {"early": early})
     return (lambda q, k, v, n, layer: flash_decode_stacked(
-        q, k, v, n, d ** -0.5, layer)), \
+        q, k, v, n, d ** -0.5, layer, **form)), \
         [((leaf[1], 1, heads, d), BF16), (leaf, BF16), (leaf, BF16),
          ((leaf[1],), I32), ((), I32)]
 
@@ -320,6 +325,8 @@ CASES = {
        for name, (b, s) in DECODE_SHAPES.items()},
     **{f"decode_stacked_{cell}": (lambda cell=cell: _decode_stacked(cell))
        for cell in STACKED_LEAVES},
+    # SDAR's fused forward: two blocks of 4 rows beside a kv head's 8 heads
+    "decode_stacked_sdar_two_blocks": lambda: _decode_stacked("sdar", (32, 4)),
     "decode_ring_trinity": _decode_ring,
     "decode_full_mimo": lambda: _decode_mimo("full"),
     "decode_full_sink_mimo": lambda: _decode_mimo("full", sink=True),
@@ -948,16 +955,19 @@ def _assert_state_steps_in_place(text: str, prog: str, state: str,
         assert not moved, "\n".join(moved)
 
 
-def _assert_expert_orders(text: str, prog: str, pipelined: bool):
+def _assert_expert_orders(text: str, prog: str, pipelined: bool,
+                          wide: bool = False):
     """ISSUE 43: the 512-row chunk runs each held expert over its own rows
     (the kernel reads the stacks where they lie: the callers' ``sliced``
     lists hold it to that). ISSUE 44: the decode block's rows take the
     pipelined pass where ``experts.takes_pipelined`` says so (Granite's
     shape: ``pipelined``), one Pallas call a layer and no scan over the
     held experts beside it, and keep that scan where it does not
-    (DeepSeek's, Trinity's, MiMo's)."""
+    (DeepSeek's, Trinity's, MiMo's). ``wide``: the program holds a forward
+    past the rule's ridge beside its narrow ones (a round of blocks' fused
+    forward, 256 rows), which runs grouped too."""
     chunk = prog == "prefill_chunk"
-    assert ("moe_experts/grouped/grouped_experts" in text) == chunk
+    assert ("moe_experts/grouped/grouped_experts" in text) == (chunk or wide)
     assert ("moe_experts/pipelined/pipelined_experts" in text) == (
         pipelined and not chunk)
     # the loop's scan carries the held experts' weights a row ([held, rows]
@@ -1095,9 +1105,9 @@ def _cell_program(topo, prog, name):
         jitted = eng._program("decode_block")
         args = (arg((6, slots), I32),
                 arg((eng.decode_block_len, 2), jnp.uint32))
-    elif prog == "blocks":  # a round of blocks: a block's tokens, ``given``
-        jitted = eng._program("blocks")
-        args = (arg((cfg.model.block_length + 6, slots), I32),
+    elif prog == "blocks":  # a round of blocks: the waiting block's tokens
+        jitted = eng._program("blocks")  # and a block's, then ``given``
+        args = (arg((2 * cfg.model.block_length + 6, slots), I32),
                 arg((eng.decode_block_len, 2), jnp.uint32))
     else:
         jitted = eng._prefill_chunk_jit
@@ -1417,9 +1427,12 @@ def test_sdar_round_of_blocks_and_chunk_leave_the_cache_in_place(
     beside 2.43 GB of weights). The round of blocks: a denoise forward's
     ``block_length`` rows a slot ride beside the query heads through the
     stacked flash-decode kernel on the ``k``/``v`` leaves where they lie (one
-    call a layer in the loop's body, one in the commit), the sixteen held
-    experts through the pipelined pass at 128 rows, the loop over the steps a
-    ``while`` with the cache in its carry and no copy of a leaf. The chunk:
+    call a layer in the loop's body, 128 query rows a slot, and one in the
+    fused forward that starts a block and commits the one before it, 256:
+    ISSUE 66), the sixteen held experts through the pipelined pass at 128
+    rows and through the grouped kernel at the fused forward's 256, the loop
+    over the steps a ``while`` with the cache in its carry and no copy of a
+    leaf. The chunk:
     four K/V heads a token are half a register tile, and left free the
     chunk's contractions re-laid both leaves with the tokens along the lanes
     (two copies of 4.5 GB: the chunk did not fit); ``kv_cache.cache_write``
@@ -1430,7 +1443,7 @@ def test_sdar_round_of_blocks_and_chunk_leave_the_cache_in_place(
     monkeypatch.setattr(kv_cache, "on_tpu", lambda: True)
     compiled = _cell_program(topo, prog, "sdar-30b-a3b-ep8-l12")
     text = compiled.as_text()
-    _assert_expert_orders(text, prog, pipelined=True)
+    _assert_expert_orders(text, prog, pipelined=True, wide=prog == "blocks")
     lines = text.splitlines()
     kv = r"bf16\[12,32,12288,4,128\]"
     copies = [l.strip()[:160] for l in lines
@@ -1444,9 +1457,11 @@ def test_sdar_round_of_blocks_and_chunk_leave_the_cache_in_place(
     kernels = [l for l in lines
                if re.search(r"%flash_decode_attention\S* = ", l)]
     assert len(kernels) == (2 if prog == "blocks" else 0), kernels
-    # 32 slots' 4 x 32 query rows of 128 lanes, the leaves whole
-    assert all("bf16[32,128,128]" in l and "bf16[12,32,49152,128]" in l
-               for l in kernels)
+    # 32 slots' 4 x 32 query rows of 128 lanes (a block's forward) and
+    # 4 x 64 (the fused forward's two blocks), the leaves whole
+    assert all("bf16[12,32,49152,128]" in l for l in kernels)
+    assert sorted(re.search(r"= bf16\[32,(\d+),128\]", l).group(1)
+                  for l in kernels) == ["128", "256"][:len(kernels)]
     mem = compiled.memory_analysis()
     assert round(mem.argument_size_in_bytes / 1e9, 2) == 12.09
     assert mem.temp_size_in_bytes < (0.4e9 if prog == "blocks" else 0.9e9)

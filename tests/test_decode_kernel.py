@@ -646,6 +646,93 @@ def test_ring_form_lowers_to_the_program_it_was():
         assert _ring_lowered(flash_decode_stacked) == f.read()
 
 
+# the dense form as two cells call it: a decode step of Mistral's rows (a
+# head of 128 a row, four query heads each), and SDAR's denoise forward, a
+# block of 4 rows beside the 8 query heads of each of 4 kv heads
+DENSE_FORMS = {"S1_mistral": ((16, 8, 4096, 8, 128), 32),
+               "Sblock_sdar": ((12, 32, 12288, 4, 128), 128)}
+
+
+def _dense_lowered(kernel, form):
+    """The lowered text of ``kernel`` in its dense form at a cell's shape,
+    interpret mode, source locations stripped."""
+    leaf, heads = DENSE_FORMS[form]
+    S = jax.ShapeDtypeStruct
+    text = jax.jit(lambda q, k, v, n, layer: kernel(
+        q, k, v, n, 128 ** -0.5, layer, interpret=True)).lower(
+            S((leaf[1], 1, heads, 128), jnp.bfloat16), S(leaf, jnp.bfloat16),
+            S(leaf, jnp.bfloat16), S((leaf[1],), jnp.int32),
+            S((), jnp.int32)).as_text()
+    return re.sub(r"\s*loc\(.*?\)$|^#loc.*\n", "", text, flags=re.M)
+
+
+@pytest.mark.parametrize("form", sorted(DENSE_FORMS))
+def test_dense_form_without_early_lowers_to_the_program_it_was(form):
+    """PR 66 gave the dense form a second limit a slot (``early``: two
+    blocks of fresh rows in one call). A call without it, the ``S == 1``
+    step's and the ``S == block`` forward's, keeps the program it had,
+    operation for operation: its lowered text equals the copy saved from
+    the parent commit (``tests/data``, written by ``_dense_lowered`` over
+    the parent's module, gzipped: 340 KB of text a form)."""
+    import gzip
+
+    path = os.path.join(os.path.dirname(__file__), "data",
+                        f"dense_decode_{form}.lowered.txt.gz")
+    with gzip.open(path, "rt") as f:
+        assert _dense_lowered(flash_decode_stacked, form) == f.read()
+
+
+# two blocks of 4 fresh rows a slot against K blocks of 16 tokens, by where
+# the two limits (``lengths - 4`` and ``lengths``, whole blocks both) lie:
+# in one K block, the first or a later one; in two (the first half's limit
+# is a K block's end, so of the walk's last block it sees nothing); an empty
+# prefix; the window's end, past which the rows dropped (the kernel clamps
+# its walk)
+EARLY_T, EARLY_BLOCK_T = 64, 16
+EARLY_LENGTHS = {"one_block": [12, 16, 28, 48], "two_blocks": [20, 36, 52, 20],
+                 "empty_prefix": [8, 8, 8, 8], "window_end": [64, 68, 64, 68]}
+
+
+@pytest.mark.parametrize("heads_a_row", [1, 2])
+@pytest.mark.parametrize("case", sorted(EARLY_LENGTHS))
+def test_two_blocks_ride_one_call_with_a_limit_each(case, heads_a_row,
+                                                     monkeypatch):
+    """``attend(impl="flash", block=4)`` on ``2 x block`` rows a slot: the
+    stacked kernel's ``early`` form in interpret mode (the first block's
+    query heads stop 4 keys before ``lengths``) against
+    ``decode_attention(..., block=4)`` on the same rows, K/V a head a row
+    and two heads packed to a row."""
+    import picotron_tpu.ops.pallas.decode_attention as da
+
+    D, rows = 128 // heads_a_row, 2
+    monkeypatch.setattr(da, "_STACKED_KV_BLOCK",
+                        EARLY_BLOCK_T * rows * 128 * 2)
+    rng = np.random.default_rng(len(case) + heads_a_row)
+    B, nh = 4, rows * heads_a_row * 4
+    bf = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+    q, k, v = bf(B, 8, nh, D), bf(2, B, EARLY_T, rows, 128), \
+        bf(2, B, EARLY_T, rows, 128)
+    cache = {"k": k, "v": v}
+    assert kv_cache.plain_decode(q, cache, 4)
+    lengths = jnp.asarray(EARLY_LENGTHS[case], jnp.int32)
+    got = kv_cache.attend(q, cache, lengths, 0.25, 1, impl="flash", block=4)
+    k4, v4 = (x[1].reshape(B, EARLY_T, rows * heads_a_row, D)
+              for x in (k, v))
+    want = decode_attention(q, k4, v4, lengths, 0.25, 4)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+    # the halves differ: the first saw 4 keys fewer
+    whole = kv_cache.attend(q[:, 4:], cache, lengths, 0.25, 1, impl="flash",
+                            block=4)
+    np.testing.assert_allclose(got[:, 4:], np.asarray(whole, np.float32),
+                               rtol=2e-2, atol=2e-2)
+    early = kv_cache.attend(q[:, :4], cache, lengths - 4, 0.25, 1,
+                            impl="flash", block=4)
+    np.testing.assert_allclose(got[:, :4], np.asarray(early, np.float32),
+                               rtol=2e-2, atol=2e-2)
+
+
 def _routes(monkeypatch, on_tpu):
     """Stand-ins for the three places ``attend`` can send a call, each
     returning its own name, with ``on_tpu`` as given."""

@@ -239,9 +239,11 @@ def test_the_unmask_rule_is_the_references(rule):
 
 def test_confident_positions_leave_several_a_forward_and_are_counted():
     """A head drawn sixty times as wide makes every softmax a spike: the
-    dynamic rule fixes a whole block in its first forward (2 forwards a
-    block of 4 tokens where the floor takes 5), the counters say so, and the
-    tokens are still the published loop's."""
+    dynamic rule fixes a whole block in its first forward (a forward a
+    block of 4 tokens where the floor takes 4), the counters say so, and the
+    tokens are still the published loop's. Every block starts with a fused
+    forward, three of the four are committed inside the next one's, and the
+    stream's last block is owed nothing."""
     _, engine, params = make_engine(fresh=True)
     sharp = {**params, "lm_head": params["lm_head"] * 60.0}
     prompt = tokens(11, 32)
@@ -254,18 +256,21 @@ def test_confident_positions_leave_several_a_forward_and_are_counted():
            for line in text.splitlines()
            if line.startswith("picotron_diffusion")}
     denoise = got['picotron_diffusion_forwards_total{kind="denoise"}']
-    commit = got['picotron_diffusion_forwards_total{kind="commit"}']
-    assert got["picotron_diffusion_blocks_total"] == 4 and commit == 4
+    fused = got['picotron_diffusion_forwards_total{kind="fused"}']
+    assert got['picotron_diffusion_forwards_total{kind="commit"}'] == 0
+    assert got["picotron_diffusion_blocks_total"] == 3 and fused == 4
     assert got["picotron_diffusion_positions_unmasked_total"] == 16
     assert got["picotron_diffusion_threshold_passes_total"] >= 12
-    assert denoise < 16 and 16 / (denoise + commit) > 1.0
-    assert got["picotron_diffusion_rows_total"] == 4 * (denoise + commit)
+    assert denoise < 12 and 16 / (denoise + fused) > 1.0
+    assert got["picotron_diffusion_rows_total"] == 4 * (denoise + fused)
 
 
 def test_the_batcher_puts_the_counters_on_metrics():
     """On seeded weights no confidence passes 0.9: a block of 4 takes four
-    denoise forwards and a commit, 0.8 tokens a forward; the expert share's
-    counters count live rows alone."""
+    denoise forwards, the first of them fused with the commit of the block
+    before it, so a round of two blocks counts eight forwards, two of them
+    ``kind="fused"``, none ``kind="commit"``: a token a forward; the expert
+    share's counters count live rows alone."""
     _, engine, params = make_engine(fresh=True)
     prompts = [tokens(12, 32), tokens(13, 16)]
     ContinuousBatcher(engine, params, seed=0).run(
@@ -275,16 +280,19 @@ def test_the_batcher_puts_the_counters_on_metrics():
     value = lambda name: float(next(
         line for line in text.splitlines()
         if line.startswith(name + " ")).split(" ")[1])
-    assert value('picotron_diffusion_forwards_total{kind="denoise"}') == 8
-    assert value('picotron_diffusion_forwards_total{kind="commit"}') == 2
-    assert value("picotron_diffusion_blocks_total") == 4
+    assert value('picotron_diffusion_forwards_total{kind="denoise"}') == 6
+    assert value('picotron_diffusion_forwards_total{kind="fused"}') == 2
+    assert value('picotron_diffusion_forwards_total{kind="commit"}') == 0
+    # each slot's first block, inside its second's first forward; the second
+    # ended the stream
+    assert value("picotron_diffusion_blocks_total") == 2
     assert value("picotron_diffusion_positions_unmasked_total") == 16
     assert value("picotron_diffusion_threshold_passes_total") == 0
-    assert value("picotron_diffusion_rows_total") == 10 * 2 * 4
-    # two layers: 48 prompt rows (two chunks and a bucket) and 80 forwarded
-    # ones, four experts a token
-    assert value("picotron_moe_assignments_total") <= 2 * 128 * 4
-    assert value("picotron_moe_layer_steps_total") == 2 * (3 + 10)
+    assert value("picotron_diffusion_rows_total") == 8 * 2 * 4
+    # two layers: 48 prompt rows (two chunks and a bucket) and 72 forwarded
+    # ones (64 that can gain a token, 8 committed), four experts a token
+    assert value("picotron_moe_assignments_total") <= 2 * 120 * 4
+    assert value("picotron_moe_layer_steps_total") == 2 * (3 + 8)
     assert value('picotron_dispatch_total{kind="blocks"}') == 1
 
 
@@ -409,7 +417,7 @@ def test_the_engine_holds_its_window_to_whole_blocks():
         InferenceEngine(make_config(), slots=2, max_seq_len=126)
     _, engine, params = make_engine()
     assert engine.blocks and engine.store is None
-    with pytest.raises(ValueError, match="tokens \\[slots, block_length\\]"):
+    with pytest.raises(ValueError, match="tokens and waiting \\[slots, block_length\\]"):
         engine.decode_block(params, engine.init_cache(), np.zeros(3),
                             np.zeros((8, 2), np.uint32), *[np.zeros(3)] * 5,
                             given=np.zeros(3))
